@@ -1,0 +1,854 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — does the system still start on the chip?
+
+Drives fit, transform, serving, the streamed (checkpointed) fit, native
+ingest, the Pallas kernels and — on more than one chip — plan-sharded
+training once each, through the entry points a user calls, in ONE
+process, and checks every phase's result by the repo's own means. It is
+a bring-up check, not a benchmark: the ``wall_s`` it prints are not
+measurements of anything but this script.
+
+    python chip_smoke.py              # needs a TPU; exits non-zero without one
+    python chip_smoke.py --rehearse   # tiny sizes, any backend, Pallas interpreted
+
+Contract (what the driver checks): refuses to run unless
+``jax.default_backend() == "tpu"``; prints the device and the compile
+cache directory first, then one JSON line per phase; a phase that fails
+raises, so the run exits non-zero and the result line is never printed;
+the LAST stdout line of a passing run is
+``{"ok": true, "device": {"platform", "kind", "count"}}``. It starts no
+child process that imports JAX (a chip belongs to one process).
+
+Sizes: dense LR at BASELINE.json config 1's width (d = 123) with 2 GB
+resident; the Criteo-profile sparse LR (dim 1e6, 39 nnz/row); the
+bench's five-stage transform chain over 1M float32 rows; a replica pool
+with one replica per chip. ``--rehearse`` keeps every width and cuts
+rows/steps so tier-1 can run the same code on the CPU mesh.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import math
+import os
+import sys
+import tempfile
+import threading
+import time
+
+import numpy as np
+
+FULL = dict(
+    dense_n=4_194_304, dense_d=123, dense_gbs=262_144, dense_steps=32,
+    sparse_n=262_144, sparse_dim=1_000_000, sparse_nnz=39,
+    sparse_gbs=65_536, sparse_steps=8,
+    chain_n=1_048_576, chain_d=123, chain_sample=4_096,
+    serve_requests=400, serve_clients=8,
+    stream_batches=16, stream_rows=65_536, stream_interval=4,
+    stream_crash_epoch=10,
+    ingest_rows=65_536,
+    topk_shape=(1_024, 8_192), topk_k=16,
+    multichip_n=65_536, multichip_d=4_096, multichip_steps=8,
+)
+REHEARSAL = dict(
+    FULL,
+    dense_n=16_384, dense_gbs=4_096, dense_steps=8,
+    sparse_n=2_048, sparse_dim=65_536, sparse_gbs=512,
+    chain_n=8_192, chain_sample=512,
+    serve_requests=40, serve_clients=4,
+    stream_batches=8, stream_rows=256, stream_interval=2,
+    stream_crash_epoch=5,
+    ingest_rows=512,
+    topk_shape=(64, 512), topk_k=8,
+    multichip_n=2_048, multichip_d=256, multichip_steps=4,
+)
+
+
+def _check(cond: bool, what: str) -> None:
+    if not cond:
+        raise AssertionError(what)
+
+
+def _log_loss(margins, y) -> float:
+    """Mean logistic loss at float64 (LogisticGradient's form)."""
+    return float(np.mean(np.logaddexp(0.0, -margins * (2.0 * y - 1.0))))
+
+
+class _DeviceWatch:
+    """What a fit put on the devices, seen from outside: a thread polls
+    ``jax.live_arrays()`` while the fit runs and keeps, per array shape,
+    how many devices held it and whether it was sharded (not merely
+    replicated). The estimators expose no handle on their device data,
+    and this is the one observation that does not go through them. It
+    polls every millisecond until the first array of the watched size
+    shows (a rehearsal fit on a warm cache lives for a few tens of
+    milliseconds), then every 50 ms."""
+
+    def __init__(self, min_bytes: int):
+        self._min_bytes = min_bytes
+        self._stop = threading.Event()
+        self.seen = {}  # (shape, dtype) -> (n_devices, sharded)
+        self._thread = threading.Thread(target=self._run, daemon=True)
+
+    def _poll(self):
+        import jax
+
+        for a in jax.live_arrays():
+            if a.nbytes < self._min_bytes:
+                continue
+            key = (tuple(a.shape), str(a.dtype))
+            n = len(a.sharding.device_set)
+            sharded = n > 1 and not a.sharding.is_fully_replicated
+            prev = self.seen.get(key, (0, False))
+            self.seen[key] = (max(prev[0], n), prev[1] or sharded)
+
+    def _run(self):
+        while not self._stop.is_set():
+            self._poll()
+            new = self.seen.keys() - self._before
+            self._stop.wait(0.05 if new else 0.001)
+
+    def __enter__(self):
+        self._poll()  # earlier phases' leftovers: not what ends fast polling
+        self._before = set(self.seen)
+        self._thread.start()
+        return self
+
+    def __exit__(self, *exc):
+        self._stop.set()
+        self._thread.join(timeout=10)
+
+    def largest(self):
+        """``(shape, n_devices, sharded)`` of the biggest array seen."""
+        _check(bool(self.seen), "no device array was observed during fit")
+        key = max(self.seen, key=lambda k: math.prod(k[0]))
+        return key[0], self.seen[key][0], self.seen[key][1]
+
+
+def _assert_spread(watch: _DeviceWatch, n_devices: int, what: str) -> dict:
+    shape, used, sharded = watch.largest()
+    _check(used == n_devices,
+           f"{what}: largest device array {shape} sat on {used} of "
+           f"{n_devices} devices")
+    _check(sharded or n_devices == 1,
+           f"{what}: largest device array {shape} was replicated, not "
+           "sharded")
+    return {"largest_array": list(shape), "devices_used": used}
+
+
+# -- phases ------------------------------------------------------------------
+
+
+def phase_train_dense(ctx) -> dict:
+    import jax
+
+    from flinkml_tpu.models import LogisticRegression
+    from flinkml_tpu.table import Table
+
+    z = ctx.sizes
+    n, d = z["dense_n"], z["dense_d"]
+    rng = np.random.default_rng([ctx.seed, 1])
+    x = rng.standard_normal((n, d), dtype=np.float32)
+    true = rng.standard_normal(d, dtype=np.float32)
+    y = (x @ true > 0).astype(np.float32)
+    est = (LogisticRegression()
+           .set_global_batch_size(z["dense_gbs"])
+           .set_max_iter(z["dense_steps"])
+           .set_learning_rate(0.5).set_tol(0.0).set_seed(ctx.seed))
+    with _DeviceWatch(min_bytes=x.nbytes // (2 * len(jax.devices()))) as w:
+        model = est.fit(Table({"features": x, "label": y}))
+    coef = np.asarray(model.coefficient, np.float64)
+    _check(coef.shape == (d,) and np.isfinite(coef).all(),
+           "coefficients not finite")
+    sample = slice(0, min(n, 65_536))
+    margins = x[sample].astype(np.float64) @ coef
+    loss = _log_loss(margins, y[sample])
+    acc = float(np.mean((margins >= 0) == (y[sample] > 0)))
+    _check(loss < math.log(2.0), f"log-loss {loss} not below ln 2")
+    _check(acc > 0.9, f"accuracy {acc} on the planted labels <= 0.9")
+    spread = _assert_spread(w, len(jax.devices()), "train_dense")
+    return {"rows": n, "dim": d, "steps": z["dense_steps"],
+            "log_loss": loss, "accuracy": acc, **spread}
+
+
+def _criteo_table(n, dim, nnz, seed):
+    """``bench.make_criteo_csr`` as a Table with a SparseVector column
+    (rows that drew one column twice merge the two values, which is what
+    the CSR margins that planted the labels did)."""
+    import bench
+    from flinkml_tpu.linalg import SparseVector
+    from flinkml_tpu.table import Table
+
+    _, indices, values, y, _ = bench.make_criteo_csr(
+        n, dim=dim, nnz=nnz, seed=seed)
+    idx = indices.reshape(n, nnz).astype(np.int64)
+    val = values.reshape(n, nnz).astype(np.float64)
+    col = np.empty(n, object)
+    for i in range(n):
+        u, inv = np.unique(idx[i], return_inverse=True)
+        if u.size == nnz:
+            col[i] = SparseVector(dim, idx[i], val[i])
+        else:
+            col[i] = SparseVector(dim, u, np.bincount(inv, weights=val[i]))
+    return Table({"features": col, "label": y}), idx, val, y
+
+
+def phase_train_sparse(ctx) -> dict:
+    import jax
+
+    from flinkml_tpu.models import LogisticRegression
+
+    z = ctx.sizes
+    n, dim, nnz = z["sparse_n"], z["sparse_dim"], z["sparse_nnz"]
+    table, idx, val, y = _criteo_table(n, dim, nnz, ctx.seed)
+    est = (LogisticRegression()
+           .set_global_batch_size(z["sparse_gbs"])
+           .set_max_iter(z["sparse_steps"])
+           .set_learning_rate(20.0).set_tol(0.0).set_seed(ctx.seed))
+    with _DeviceWatch(min_bytes=n * nnz * 4 // (4 * len(jax.devices()))) as w:
+        model = est.fit(table)
+    coef = np.asarray(model.coefficient, np.float64)
+    _check(coef.shape == (dim,) and np.isfinite(coef).all(),
+           "coefficients not finite")
+    margins = (val * coef[idx]).sum(axis=1)
+    loss = _log_loss(margins, y)
+    acc = float(np.mean((margins >= 0) == (y > 0)))
+    _check(loss < math.log(2.0), f"log-loss {loss} not below ln 2")
+    _check(acc > 0.9, f"accuracy {acc} on the planted labels <= 0.9")
+    spread = _assert_spread(w, len(jax.devices()), "train_sparse")
+    return {"rows": n, "dim": dim, "nnz_per_row": nnz,
+            "steps": z["sparse_steps"], "log_loss": loss, "accuracy": acc,
+            **spread}
+
+
+def _chain_reference(model, x64: np.ndarray):
+    """The five-stage chain in plain NumPy float64, built from each
+    fitted stage's model data (``get_model_data`` and params) — nothing
+    of ``pipeline_fusion`` or the stages' own transforms."""
+    std_m, mm_m, ma_m, rb_m, lr_m = model.stages
+    (t,) = std_m.get_model_data()
+    mean, std = (np.asarray(t.column(c), np.float64)[0]
+                 for c in ("mean", "std"))
+    out = x64
+    if std_m.get(std_m.WITH_MEAN):
+        out = out - mean
+    if std_m.get(std_m.WITH_STD):
+        out = out / np.where(std > 0, std, 1.0)
+    (t,) = mm_m.get_model_data()
+    dmin, dmax = (np.asarray(t.column(c), np.float64)[0]
+                  for c in ("dataMin", "dataMax"))
+    span = dmax - dmin
+    unit = np.where(span > 0, (out - dmin) / np.where(span > 0, span, 1.0),
+                    0.5)
+    lo, hi = mm_m.get(mm_m.MIN), mm_m.get(mm_m.MAX)
+    out = unit * (hi - lo) + lo
+    (t,) = ma_m.get_model_data()
+    ma = np.asarray(t.column("maxAbs"), np.float64)[0]
+    out = out / np.where(ma > 0, ma, 1.0)
+    (t,) = rb_m.get_model_data()
+    med, rng_ = (np.asarray(t.column(c), np.float64)[0]
+                 for c in ("median", "range"))
+    if rb_m.get(rb_m.WITH_CENTERING):
+        out = out - med
+    if rb_m.get(rb_m.WITH_SCALING):
+        out = out / np.where(rng_ > 0, rng_, 1.0)
+    (t,) = lr_m.get_model_data()
+    coef = np.asarray(t.column("coefficient"), np.float64)[0]
+    dot = out @ coef
+    p = 1.0 / (1.0 + np.exp(-dot))
+    return dot, (dot >= 0).astype(np.float64), np.stack([1.0 - p, p], -1)
+
+
+def _assert_chain_close(what, dot, ref_pred, ref_raw, pred, raw,
+                        raw_tol: float) -> dict:
+    """Outputs vs the float64 reference: ``rawPrediction`` within
+    ``raw_tol`` absolute (they are probabilities), predictions equal
+    wherever the reference margin is not within reach of 0."""
+    pred = np.asarray(pred, np.float64).reshape(-1)
+    raw = np.asarray(raw, np.float64)
+    _check(np.isfinite(raw).all(), f"{what}: rawPrediction not finite")
+    err = float(np.max(np.abs(raw - ref_raw)))
+    _check(err <= raw_tol,
+           f"{what}: rawPrediction off the float64 reference by {err} "
+           f"(> {raw_tol})")
+    # |dp/dm| <= 1/4: a margin further than 4*raw_tol from 0 cannot have
+    # crossed it.
+    away = np.abs(dot) > 4.0 * raw_tol
+    _check(bool(np.array_equal(pred[away], ref_pred[away])),
+           f"{what}: predictions differ away from the 0.5 boundary")
+    return {"raw_max_abs_err": err, "rows_checked": int(raw.shape[0]),
+            "rows_away_from_boundary": int(away.sum())}
+
+
+def phase_transform(ctx) -> dict:
+    import jax
+
+    import bench
+    from flinkml_tpu.table import Table
+    from flinkml_tpu.utils.metrics import metrics
+
+    z = ctx.sizes
+    model, x = bench._five_stage_model(
+        z["chain_n"], z["chain_d"], seed=ctx.seed, dtype=np.float32)
+    _check(x.dtype == np.float32, "chain features are not float32")
+    # The chain's own two-step LR fit leaves every margin near +40:
+    # probabilities saturate at 1 and a comparison of them is blind.
+    # Plant a zero-sum coefficient (the scaled features share an
+    # offset), so margins straddle 0 and every stage's error shows.
+    g = np.random.default_rng([ctx.seed, 2]).standard_normal(z["chain_d"])
+    g -= g.mean()
+    model.stages[-1].set_model_data(
+        Table({"coefficient": (2.0 * g / np.linalg.norm(g))[None, :]}))
+    table = Table({"features": x})
+    fusion = metrics.group("pipeline.fusion")
+
+    def compiles() -> float:
+        return fusion.snapshot()["counters"].get("compiles", 0.0)
+
+    (out,) = model.transform(table)
+    pred = np.asarray(out.column("prediction"))
+    raw = np.asarray(out.column("rawPrediction"))
+    after_first = compiles()
+    _check(after_first >= 1, "the fused executor compiled nothing")
+    (again,) = model.transform(Table({"features": x}))
+    np.asarray(again.column("prediction"))
+    _check(compiles() == after_first,
+           "pipeline.fusion compiles rose on a second transform")
+    _check(pred.shape == (z["chain_n"],) and raw.shape == (z["chain_n"], 2),
+           f"unexpected output shapes {pred.shape} {raw.shape}")
+    rows = np.random.default_rng([ctx.seed, 3]).choice(
+        z["chain_n"], size=z["chain_sample"], replace=False)
+    dot, ref_pred, ref_raw = _chain_reference(
+        model, x[rows].astype(np.float64))
+    close = _assert_chain_close("transform", dot, ref_pred, ref_raw,
+                                pred[rows], raw[rows], ctx.raw_tol)
+    ctx.chain = dict(model=model, x=x, pred=pred, raw=raw)
+    devices = sorted(d.id for d in out.device_column("prediction").devices())
+    return {"rows": z["chain_n"], "dim": z["chain_d"],
+            "input_dtype": str(x.dtype), "output_dtype": str(raw.dtype),
+            "compiles": after_first, **close,
+            # Outside a pool the fused executor uploads with a bare
+            # jnp.asarray: one chip, whatever the host has.
+            "device_ids_used": devices}
+
+
+def phase_serve(ctx) -> dict:
+    import jax
+
+    from flinkml_tpu.serving import ReplicaPool
+    from flinkml_tpu.table import Table
+    from flinkml_tpu.utils.metrics import metrics
+
+    z = ctx.sizes
+    model, x = ctx.chain["model"], ctx.chain["x"]
+    pred, raw = ctx.chain["pred"], ctx.chain["raw"]
+    n_dev = len(jax.devices())
+    fusion = metrics.group("pipeline.fusion")
+    cc = metrics.group("compile_cache")
+    cc_before = dict(cc.snapshot()["counters"])
+    pool = ReplicaPool(
+        model, Table({"features": x[:4]}), n_replicas=n_dev,
+        output_cols=("prediction", "rawPrediction"), name="chip_smoke",
+    ).start()
+    try:
+        placed = [r.device.id for r in pool.replicas]
+        _check(len(set(placed)) == n_dev,
+               f"replicas sit on devices {placed}, not on {n_dev} distinct")
+        warm = fusion.snapshot()["counters"].get("compiles", 0.0)
+        per_client = z["serve_requests"] // z["serve_clients"]
+        errors, worst = [], [0.0]
+        lock = threading.Lock()
+
+        def client(tid: int) -> None:
+            rng = np.random.default_rng([ctx.seed, 4, tid])
+            try:
+                for _ in range(per_client):
+                    rows = int(rng.integers(1, 33))
+                    lo = int(rng.integers(0, x.shape[0] - rows))
+                    resp = pool.predict({"features": x[lo:lo + rows]})
+                    got_p = np.asarray(resp.columns["prediction"])
+                    got_r = np.asarray(resp.columns["rawPrediction"])
+                    # Same model, same rows, another batch shape: equal
+                    # to phase 3's outputs up to float32 rounding.
+                    err = float(np.max(np.abs(
+                        got_r.astype(np.float64) - raw[lo:lo + rows])))
+                    flips = got_p.reshape(-1) != pred[lo:lo + rows]
+                    near = np.abs(raw[lo:lo + rows, 1] - 0.5) <= 1e-4
+                    if err > 1e-5 or bool(np.any(flips & ~near)):
+                        raise AssertionError(
+                            f"rows {lo}:{lo + rows} differ from the "
+                            f"transform output (raw err {err})")
+                    with lock:
+                        worst[0] = max(worst[0], err)
+            except BaseException as e:  # noqa: BLE001 — reported below
+                errors.append(repr(e))
+
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(z["serve_clients"])]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=600)
+        _check(not any(t.is_alive() for t in threads),
+               "a serving client did not finish")
+        _check(not errors, f"serving errors: {errors[:3]}")
+        steady = fusion.snapshot()["counters"].get("compiles", 0.0) - warm
+        _check(steady == 0, f"{steady} compiles after warm-up")
+        stats = pool.stats()
+        served = [int(r["counters"].get("requests", 0))
+                  for r in stats["per_replica"].values()]
+    finally:
+        pool.stop()
+    cc_after = cc.snapshot()["counters"]
+    delta = {k: cc_after.get(k, 0.0) - cc_before.get(k, 0.0)
+             for k in ("hits", "misses", "retarget_loads",
+                       "corrupt_entries", "fallbacks")}
+    _check(delta["corrupt_entries"] == 0,
+           f"compile_cache corrupt_entries rose by {delta['corrupt_entries']}")
+    if n_dev > 1:
+        _check(delta["retarget_loads"] >= n_dev - 1,
+               f"retarget_loads {delta['retarget_loads']} < replicas - 1")
+    return {"replicas": n_dev, "replica_device_ids": placed,
+            "requests": per_client * z["serve_clients"],
+            "requests_per_replica": served,
+            "compiles_after_warmup": steady,
+            "max_abs_diff_vs_transform": worst[0],
+            "compile_cache": delta}
+
+
+def phase_stream(ctx) -> dict:
+    from flinkml_tpu import faults
+    from flinkml_tpu.data import Dataset
+    from flinkml_tpu.iteration import CheckpointManager
+    from flinkml_tpu.models import OnlineLogisticRegression
+    from flinkml_tpu.table import Table
+
+    z = ctx.sizes
+    rows, d = z["stream_rows"], z["dense_d"]
+    true = np.random.default_rng([ctx.seed, 5]).standard_normal(d)
+
+    def make_batch(index, rng):
+        x = rng.standard_normal((rows, d), dtype=np.float32)
+        return Table({"features": x,
+                      "label": (x @ true > 0).astype(np.float32)})
+
+    def feed():
+        return Dataset.synthetic(
+            make_batch, z["stream_batches"], seed=ctx.seed
+        ).prefetch(depth=2)
+
+    def est():
+        return OnlineLogisticRegression().set_alpha(0.5).set_reg(0.01)
+
+    golden = est().fit_stream(feed())
+    with tempfile.TemporaryDirectory(prefix="chip-smoke-ckpt-") as td:
+        mgr = CheckpointManager(td, max_to_keep=10)
+        crashed = False
+        with faults.armed(faults.FaultPlan(
+                faults.RaiseAtEpoch(z["stream_crash_epoch"]))):
+            try:
+                est().fit_stream(feed(), checkpoint_manager=mgr,
+                                 checkpoint_interval=z["stream_interval"])
+            except faults.FaultInjected:
+                crashed = True
+        _check(crashed, "the injected mid-stream crash did not fire")
+        snapshot_epoch = mgr.latest_epoch()
+        _check(snapshot_epoch is not None
+               and 0 < snapshot_epoch < z["stream_batches"],
+               f"no mid-stream snapshot (latest epoch {snapshot_epoch})")
+        resumed = est().fit_stream(
+            feed(), checkpoint_manager=mgr,
+            checkpoint_interval=z["stream_interval"], resume=True)
+    a = np.asarray(golden.coefficient)
+    b = np.asarray(resumed.coefficient)
+    _check(np.isfinite(a).all(), "streamed coefficients not finite")
+    _check(a.tobytes() == b.tobytes(),
+           "resumed coefficients are not bit-equal to the uninterrupted "
+           f"run's (max diff {np.max(np.abs(a - b))})")
+    _check(resumed.model_version == z["stream_batches"],
+           f"resumed model consumed {resumed.model_version} batches")
+    return {"batches": z["stream_batches"], "rows_per_batch": rows,
+            "dim": d, "resumed_from_epoch": int(snapshot_epoch),
+            "bit_equal": True}
+
+
+def phase_ingest(ctx) -> dict:
+    from flinkml_tpu.io import _native, libsvm
+
+    z = ctx.sizes
+    n, d, nnz = z["ingest_rows"], 123, 14  # a9a's profile
+    rng = np.random.default_rng([ctx.seed, 6])
+    cols = np.sort(rng.random((n, d)).argsort(axis=1)[:, :nnz], axis=1)
+    vals = rng.standard_normal((n, nnz)).astype(np.float32)
+    labels = rng.integers(0, 2, n).astype(np.float64)
+    so = _native.artifact_path("libsvm_parser")
+    if os.path.exists(so):
+        os.remove(so)  # a library from an earlier run must not pass
+    with tempfile.TemporaryDirectory(prefix="chip-smoke-svm-") as td:
+        path = os.path.join(td, "data.svm")
+        with open(path, "w") as fh:
+            for i in range(n):
+                feats = " ".join(
+                    f"{c + 1}:{float(v)!r}" for c, v in zip(cols[i], vals[i]))
+                fh.write(f"{int(labels[i])} {feats}\n")
+        t0 = time.time()
+        got_y, indptr, indices, values, nf = libsvm.read_libsvm(
+            path, n_features=d)
+    _check(libsvm._load_native() is not None,
+           "the native libsvm parser is not loaded (Python fallback ran)")
+    _check(os.path.exists(so) and os.path.getmtime(so) >= t0 - 2.0,
+           f"{so} was not built in this run from flinkml_tpu/native/*.cpp")
+    _check(np.array_equal(got_y, labels), "labels differ")
+    _check(np.array_equal(indptr, np.arange(n + 1) * nnz), "indptr differs")
+    _check(np.array_equal(indices, cols.reshape(-1)), "indices differ")
+    _check(np.array_equal(values, vals.reshape(-1)), "values differ")
+    return {"rows": n, "nnz": int(indptr[-1]), "n_features": int(nf),
+            "native_library": os.path.basename(so), "built_this_run": True}
+
+
+def phase_kernels(ctx) -> dict:
+    """Each Pallas site at the shape phases 2-4 hand it: it compiles
+    (``interpret=False``) and agrees with the XLA lowering, or its
+    ``unsupported_reason`` names why not and an explicit request raises
+    :class:`KernelUnsupportedError` with that reason."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from flinkml_tpu import kernels, pipeline_fusion
+    from flinkml_tpu.kernels import KernelUnsupportedError
+    from flinkml_tpu.kernels import chain as k_chain
+    from flinkml_tpu.kernels import segsum as k_segsum
+    from flinkml_tpu.kernels import topk as k_topk
+    from flinkml_tpu.table import Table
+
+    k_spmv = importlib.import_module("flinkml_tpu.kernels.spmv")
+    z = ctx.sizes
+    interpret = kernels.interpret_mode()
+    if ctx.rehearse:
+        _check(interpret or jax.default_backend() == "tpu",
+               "rehearsal off the TPU must interpret the kernels")
+    else:
+        _check(not interpret,
+               "kernels would run interpreted on the TPU backend "
+               f"({kernels.ENV_INTERPRET_VAR} is set?)")
+    rng = np.random.default_rng([ctx.seed, 7])
+    ran = "interpreted" if interpret else "compiled"
+    sites = {}
+
+    def run_site(site, reason, explicit, diff_vs_xla):
+        """``explicit()`` is the dispatcher under ``backend="pallas"``;
+        ``diff_vs_xla()`` runs the kernel and returns its max abs
+        difference to the XLA lowering."""
+        if reason is None:
+            sites[site] = {"status": ran,
+                           "max_abs_diff_vs_xla": float(diff_vs_xla())}
+            return
+        try:
+            explicit()
+        except KernelUnsupportedError as e:
+            _check(reason in str(e),
+                   f"{site}: refusal does not name its reason: {e}")
+            sites[site] = {"status": "refused", "refusal": reason}
+        else:
+            raise AssertionError(
+                f"{site}: unsupported_reason says {reason!r} but an "
+                "explicit pallas request ran")
+
+    # sparse trainer, one device's step (phase 2): forward SpMV and the
+    # gradient scatter into [dim].
+    rows = z["sparse_gbs"] // len(jax.devices())
+    width, dim = 64, z["sparse_dim"]  # 39 nnz pads to the 64-wide ELL
+    ib = jnp.asarray(rng.integers(0, dim, (rows, width)), jnp.int32)
+    vb = jnp.asarray(rng.standard_normal((rows, width), dtype=np.float32))
+    w = jnp.asarray(rng.standard_normal(dim, dtype=np.float32))
+    xla_spmv = jax.jit(
+        lambda i, v, ww: jnp.sum(v * jnp.take(ww, i, axis=0), axis=1))
+
+    def spmv_diff():
+        got = jax.jit(lambda i, v, ww: k_spmv.pallas_spmv(
+            i, v, ww, interpret=interpret))(ib, vb, w)
+        return np.max(np.abs(np.asarray(got) - np.asarray(
+            xla_spmv(ib, vb, w))))
+
+    run_site("spmv", k_spmv.unsupported_reason(ib, vb, w, interpret),
+             lambda: kernels.spmv(ib, vb, w, backend="pallas"), spmv_diff)
+
+    contrib, ids = vb.reshape(-1), ib.reshape(-1)
+
+    def segsum_diff(values, seg_ids, nseg):
+        got = jax.jit(lambda v, i: k_segsum.pallas_segment_sum(
+            v, i, nseg, interpret=interpret))(values, seg_ids)
+        ref = jax.jit(lambda v, i: jax.ops.segment_sum(
+            v, i, num_segments=nseg))(values, seg_ids)
+        return np.max(np.abs(np.asarray(got) - np.asarray(ref)))
+
+    run_site("segment_sum",
+             k_segsum.unsupported_reason(contrib, ids, dim, interpret),
+             lambda: kernels.segment_sum(contrib, ids, dim,
+                                         backend="pallas"),
+             lambda: segsum_diff(contrib, ids, dim))
+    # ... and at the largest [num_segments, 16] row payload the compiled
+    # path admits (the embedding-exchange shape), where it must run.
+    nseg = k_segsum.MAX_COMPILED_CELLS // 128
+    cells = 4 * k_segsum.BLOCK_CELLS + 1_000
+    if ctx.rehearse:
+        nseg, cells = 512, k_segsum.BLOCK_CELLS + 100
+    payload = jnp.asarray(rng.standard_normal((cells, 16), dtype=np.float32))
+    pids = jnp.asarray(rng.integers(0, nseg, cells), jnp.int32)
+    run_site("segment_sum_row_payload",
+             k_segsum.unsupported_reason(payload, pids, nseg, interpret),
+             lambda: kernels.segment_sum(payload, pids, nseg,
+                                         backend="pallas"),
+             lambda: segsum_diff(payload, pids, nseg))
+
+    xq = jnp.asarray(rng.standard_normal(z["topk_shape"], dtype=np.float32))
+
+    def topk_diff():
+        pv, pi = jax.jit(lambda q: k_topk.pallas_top_k(
+            q, z["topk_k"], interpret=interpret))(xq)
+        rv, ri = jax.jit(lambda q: jax.lax.top_k(q, z["topk_k"]))(xq)
+        _check(bool(np.array_equal(np.asarray(pi), np.asarray(ri))),
+               "topk indices differ from lax.top_k")
+        return np.max(np.abs(np.asarray(pv) - np.asarray(rv)))
+
+    run_site("topk", k_topk.unsupported_reason(xq, z["topk_k"], interpret),
+             lambda: kernels.top_k(xq, z["topk_k"], backend="pallas"),
+             topk_diff)
+
+    # fused chain (phases 3-4): the five-stage model at a serving bucket,
+    # through the executor's own gate.
+    model, x = ctx.chain["model"], ctx.chain["x"]
+    batch = Table({"features": x[:1_024]})
+
+    def chain_outputs(backend):
+        os.environ[kernels.ENV_VAR] = f"fused_chain={backend}"
+        try:
+            pipeline_fusion.reset_cache()
+            (out,) = model.transform(batch)
+            return (np.asarray(out.column("prediction")),
+                    np.asarray(out.column("rawPrediction")))
+        finally:
+            del os.environ[kernels.ENV_VAR]
+            pipeline_fusion.reset_cache()
+
+    ks, _ = pipeline_fusion.collect_run(batch, model.stages, 0)
+    with jax.enable_x64(True):
+        # As the executor uploads them: model data keeps its float64.
+        consts64 = tuple(
+            tuple(jnp.asarray(k.constants[c]) for c in sorted(k.constants))
+            for k in ks)
+    names = (("features",), ("prediction", "rawPrediction"))
+
+    def chain_reason(consts):
+        with jax.enable_x64(True):
+            return k_chain.unsupported_reason(
+                ks, *names, 1_024, None, (jnp.asarray(x[:1_024]),), consts,
+                interpret)
+
+    def chain_diff():
+        xp, xr = chain_outputs("xla")
+        pp, pr = chain_outputs("pallas")
+        # Both run float32; predictions may only differ where the XLA
+        # probability is within float32 rounding of 0.5.
+        near = np.abs(xr[:, 1] - 0.5) <= 1e-4
+        _check(bool(np.array_equal(pp[~near], xp[~near])),
+               "fused_chain predictions differ from the XLA chain")
+        return np.max(np.abs(pr.astype(np.float64) - xr))
+
+    run_site("fused_chain", chain_reason(consts64),
+             lambda: chain_outputs("pallas"), chain_diff)
+    # ... and the kernel itself on the same chain with float32 model
+    # constants (the fitted models store float64, which the compiled
+    # path refuses; the stages cast them to float32 anyway).
+    consts32 = tuple(tuple(c.astype(jnp.float32) for c in cv)
+                     for cv in consts64)
+
+    def chain_f32_diff():
+        xs = (jnp.asarray(x[:1_024]),)
+        got = jax.jit(k_chain.pallas_chain_fn(ks, *names, 1_024, None))(
+            xs, consts32, np.int32(1_000))
+        ref = jax.jit(pipeline_fusion._chain_fn(ks, *names, 1_024, None))(
+            xs, consts32, np.int32(1_000))
+        gp, rp = (np.asarray(o["prediction"])[:1_000] for o in (got, ref))
+        gr, rr = (np.asarray(o["rawPrediction"])[:1_000] for o in (got, ref))
+        near = np.abs(rr[:, 1] - 0.5) <= 1e-4
+        _check(bool(np.array_equal(gp[~near], rp[~near])),
+               "fused_chain (f32 constants) predictions differ from XLA")
+        return np.max(np.abs(gr.astype(np.float64) - rr))
+
+    def refused():
+        raise AssertionError("the float32-constant chain must be supported")
+
+    run_site("fused_chain_f32_constants", chain_reason(consts32), refused,
+             chain_f32_diff)
+    for site, rec in sites.items():
+        if rec["status"] != "refused":
+            _check(rec["max_abs_diff_vs_xla"] <= 1e-4,
+                   f"{site}: pallas differs from XLA by "
+                   f"{rec['max_abs_diff_vs_xla']}")
+    # Two counts of the four kernels: compiled at the shape phases 2-4
+    # hand the site (what the product can select), and compiled at some
+    # operands the kernel admits (what Mosaic can build at all).
+    elsewhere = {"fused_chain": "fused_chain_f32_constants", "spmv": "spmv",
+                 "topk": "topk", "segment_sum": "segment_sum_row_payload"}
+
+    def compiled(site):
+        return sites[site]["status"] == "compiled"
+
+    return {"interpret": bool(interpret), "sites": sites,
+            "compiled_at_product_shape": sum(map(compiled, elsewhere)),
+            "compiled_at_some_admitted_shape": sum(
+                compiled(s) or compiled(e) for s, e in elsewhere.items())}
+
+
+def phase_multichip(ctx) -> dict:
+    import jax
+
+    import __graft_entry__
+    from flinkml_tpu.parallel import DeviceMesh
+    from flinkml_tpu.sharding.apply import train_linear_plan
+    from flinkml_tpu.sharding.plan import FSDP
+
+    z = ctx.sizes
+    n_dev = len(jax.devices())
+    n, d = z["multichip_n"], z["multichip_d"]
+    rng = np.random.default_rng([ctx.seed, 8])
+    x = rng.standard_normal((n, d), dtype=np.float32)
+    true = rng.standard_normal(d, dtype=np.float32)
+    y = (x @ true > 0).astype(np.float32)
+    mesh = DeviceMesh.for_plan(FSDP)
+    with _DeviceWatch(min_bytes=d) as w:
+        coef = train_linear_plan(
+            x, y, None, FSDP, mesh, loss="logistic",
+            max_iter=z["multichip_steps"], learning_rate=0.5)
+    _check(coef.shape == (d,) and np.isfinite(coef).all(),
+           "FSDP coefficients not finite")
+    margins = x.astype(np.float64) @ coef.astype(np.float64)
+    loss = _log_loss(margins, y)
+    _check(loss < math.log(2.0), f"FSDP log-loss {loss} not below ln 2")
+    # Parameters and the momentum slot are both [d] float32: sharded
+    # over every device, not replicated.
+    state = w.seen.get(((d,), "float32"))
+    _check(state is not None, "no [d] parameter array was observed")
+    _check(state == (n_dev, True),
+           f"parameter/optimizer state [d] sat on {state[0]} of {n_dev} "
+           f"devices, sharded={state[1]}")
+    batch_shape, used, sharded = w.largest()
+    _check(used == n_dev and sharded,
+           f"FSDP batch {batch_shape} sat on {used} devices")
+    with contextlib.redirect_stdout(sys.stderr):  # it prints a summary
+        __graft_entry__.dryrun_multichip(n_dev)
+    return {"devices_used": n_dev, "dim": d, "plan": "fsdp",
+            "log_loss": loss, "dryrun_multichip": n_dev}
+
+
+PHASES = (
+    ("train_dense", phase_train_dense),
+    ("train_sparse", phase_train_sparse),
+    ("transform", phase_transform),
+    ("serve", phase_serve),
+    ("stream", phase_stream),
+    ("ingest", phase_ingest),
+    ("kernels", phase_kernels),
+    ("multichip", phase_multichip),
+)
+
+
+class _Context:
+    def __init__(self, seed: int, rehearse: bool):
+        self.seed = seed
+        self.rehearse = rehearse
+        self.sizes = REHEARSAL if rehearse else FULL
+        self.chain = None  # phase 3's model and outputs, for phases 4 and 7
+        # How far a float32 probability may sit from the float64
+        # reference: float32 rounding through five stages (measured
+        # 1.4e-6 on a v5e and 4.6e-7 on XLA:CPU, PR 21).
+        self.raw_tol = 1e-4
+
+
+def _count_cache_events() -> dict:
+    """Start counting JAX's persistent-compilation-cache hits and misses
+    (the listener lives as long as the process, like the script)."""
+    import jax
+
+    counts = {"hits": 0, "misses": 0}
+
+    def listener(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            counts["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            counts["misses"] += 1
+
+    jax.monitoring.register_event_listener(listener)
+    return counts
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--rehearse", action="store_true",
+                        help="tiny sizes on whatever backend is present")
+    args = parser.parse_args(argv)
+
+    import jax
+
+    from flinkml_tpu.utils import jax_cache
+
+    backend = jax.default_backend()
+    if backend != "tpu" and not args.rehearse:
+        print(f"chip_smoke: needs a TPU, and jax.default_backend() is "
+              f"{backend!r}; nothing was run (--rehearse runs tiny sizes "
+              "on any backend)", file=sys.stderr)
+        return 2
+    cache_dir = jax_cache.enable()
+    dev0 = jax.devices()[0]
+    device = {"platform": dev0.platform, "kind": dev0.device_kind,
+              "count": len(jax.devices())}
+    stamp = {"platform": dev0.platform}
+    if args.rehearse:
+        stamp["rehearsal"] = True
+
+    def emit(record: dict) -> None:
+        print(json.dumps({**record, **stamp}), flush=True)
+
+    emit({"phase": "start", "device": device, "seed": args.seed,
+          "compile_cache_dir": cache_dir,
+          "compile_cache_entries": _n_entries(cache_dir),
+          "jax": jax.__version__})
+    ctx = _Context(args.seed, args.rehearse)
+    t_start = time.perf_counter()
+    cache = _count_cache_events()
+    for name, phase in PHASES:
+        if name == "multichip" and device["count"] < 2:
+            continue
+        t0 = time.perf_counter()
+        try:
+            result = phase(ctx)
+        except BaseException as e:
+            emit({"phase": name, "ok": False,
+                  "error": f"{type(e).__name__}: {e}"[:2000]})
+            raise
+        emit({"phase": name, "ok": True,
+              "wall_s": round(time.perf_counter() - t0, 2), **result})
+    emit({"phase": "summary", "ok": True,
+          "wall_s": round(time.perf_counter() - t_start, 2),
+          "persistent_cache_hits": cache["hits"],
+          "persistent_cache_misses": cache["misses"],
+          "compile_cache_entries": _n_entries(cache_dir)})
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+def _n_entries(cache_dir: str) -> int:
+    """Executables in JAX's persistent cache directory."""
+    if not os.path.isdir(cache_dir):
+        return 0
+    return sum(1 for f in os.listdir(cache_dir) if f.endswith("-cache"))
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -228,10 +228,6 @@ if __name__ == "__main__":
         )
     if "--worker" in sys.argv:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        import jax
-
-        if os.environ.get("JAX_PLATFORMS") == "cpu":
-            jax.config.update("jax_platforms", "cpu")
         worker(sys.argv[sys.argv.index("--worker") + 1])
     elif "--local-demo" in sys.argv:
         _local_demo()

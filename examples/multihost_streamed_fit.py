@@ -31,9 +31,6 @@ import tempfile
 
 def worker(workdir: str) -> None:
     import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from flinkml_tpu.models import KMeans, LogisticRegression

@@ -41,21 +41,13 @@ from __future__ import annotations
 import os
 
 # Device-free by construction: pin the CPU backend before anything can
-# import jax (the TPU plugin may override JAX_PLATFORMS at import time;
-# re-pinned via jax.config below for that case).
+# import jax (an explicit JAX_PLATFORMS in the environment wins).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import argparse
 import sys
 
 from flinkml_tpu.analysis.findings import RULES, Report
-
-
-def _pin_cpu() -> None:
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 
 def _pass_lint(py_targets, report: Report) -> None:
@@ -86,7 +78,6 @@ def _pass_plans(plan_targets, report: Report) -> None:
 def _pass_policies(policy_targets, report: Report) -> None:
     from flinkml_tpu.analysis.precision import check_policy_file
 
-    _pin_cpu()  # example programs trace jaxprs (abstract, device-free)
     for path in policy_targets:
         report.extend(check_policy_file(path))
 
@@ -94,7 +85,6 @@ def _pass_policies(policy_targets, report: Report) -> None:
 def _pass_scatters(scatter_targets, report: Report) -> None:
     from flinkml_tpu.analysis.sorted_scatter import check_scatter_file
 
-    _pin_cpu()  # probe programs trace jaxprs (abstract, device-free)
     for path in scatter_targets:
         report.extend(check_scatter_file(path))
 
@@ -109,7 +99,6 @@ def _pass_features(features_targets, report: Report) -> None:
 def _pass_memory(memory_targets, report: Report) -> None:
     from flinkml_tpu.analysis.memory import check_memory_file
 
-    _pin_cpu()  # probe programs trace jaxprs (abstract, device-free)
     for path in memory_targets:
         report.extend(check_memory_file(path))
 
@@ -137,7 +126,6 @@ def _pass_retrace_selfcheck(report: Report) -> None:
     the bucket-policy contract, checked device-free."""
     import numpy as np
 
-    _pin_cpu()
     from flinkml_tpu.analysis.guard import TransferRetraceGuard
     from flinkml_tpu.models.logistic_regression import LogisticRegressionModel
     from flinkml_tpu.models.scalers import (

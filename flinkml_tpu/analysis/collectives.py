@@ -8,7 +8,7 @@ program shapes break that:
      collective sequences differ — rank 0 waits in a psum while rank 1
      waits in an all_gather, forever. :func:`extract_collectives` pulls
      the ordered collective sequence out of any traceable function's
-     jaxpr (recursing through pjit/shard_map/scan/while/cond), and
+     jaxpr (recursing through jit/shard_map/scan/while/cond), and
      :func:`check_rank_order` compares sequences across ranks.
 
   2. **Unlocked concurrent dispatch** (FML302): two host *threads* each
@@ -42,6 +42,20 @@ COLLECTIVE_PRIMITIVES = frozenset({
     "all_to_all", "reduce_scatter", "psum_scatter", "pgather",
 })
 
+#: Under ``shard_map(check_vma=True)`` (the default) jax traces the same
+#: rendezvous under a second primitive name; sequences are compared on
+#: the canonical one so a manifest does not depend on the flag.
+_CANONICAL = {
+    "psum_invariant": "psum",
+    "all_gather_invariant": "all_gather",
+}
+
+
+def collective_name(primitive_name: str) -> Optional[str]:
+    """The canonical collective a jaxpr primitive is, or None."""
+    name = _CANONICAL.get(primitive_name, primitive_name)
+    return name if name in COLLECTIVE_PRIMITIVES else None
+
 
 @dataclasses.dataclass(frozen=True)
 class CollectiveOp:
@@ -71,8 +85,8 @@ def _axes_of(params: Mapping[str, Any]) -> Tuple[str, ...]:
 
 def _walk_jaxpr(jaxpr, out: List[CollectiveOp]) -> None:
     for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name in COLLECTIVE_PRIMITIVES:
+        name = collective_name(eqn.primitive.name)
+        if name is not None:
             out.append(CollectiveOp(name, _axes_of(eqn.params)))
         for v in eqn.params.values():
             _walk_param(v, out)

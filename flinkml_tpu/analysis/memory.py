@@ -4,7 +4,7 @@ HBM capacity is the axis the rest of the analyzer reasons about worst:
 FML503 screens parameters + optimizer slots at one scalar width, blind
 to per-leaf precision, the int8 tier, and every activation a program
 materializes. This pass walks jaxprs device-free (``jax.make_jaxpr``,
-recursing pjit/scan/while/cond exactly like the precision pass) and
+recursing jit/scan/while/cond exactly like the precision pass) and
 computes a **per-device peak-live-bytes estimate** for a program under
 a ``(ShardingPlan, quant tier)`` pair:
 
@@ -273,7 +273,7 @@ class _LiveWalk:
         )
         inner_exempt = frozenset()
         if any(ov in exempt_outvars for ov in eqn.outvars):
-            # Direct chain: a pjit whose outputs ARE the program's state
+            # Direct chain: a jit whose outputs ARE the program's state
             # outputs passes the exemption to its sub-jaxpr outvars.
             pass  # handled per-branch below via _map_exempt
 
@@ -321,7 +321,7 @@ class _LiveWalk:
                             sub_peak(sub, inner_bytes, _map_exempt(sub)))
         elif "jaxpr" in params and hasattr(
                 getattr(params["jaxpr"], "jaxpr", None), "eqns"):
-            sub = params["jaxpr"].jaxpr  # pjit / closed_call wrappers
+            sub = params["jaxpr"].jaxpr  # jit / closed_call wrappers
             inner_bytes = [
                 (sizes[a] if _is_var(a) and a in sizes
                  else self.value_bytes(v.aval) if hasattr(v, "aval") else 0)

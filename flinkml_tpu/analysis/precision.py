@@ -5,7 +5,7 @@ where a program is allowed to round: ``compute`` is where the hot work
 runs (bf16 on TPU), ``accum`` is the floor under every accumulation,
 ``params`` is the storage width of parameters and optimizer state. This
 pass abstract-interprets jaxprs — device-free, recursing through
-pjit/scan/while/cond exactly like the collective extractor
+jit/scan/while/cond exactly like the collective extractor
 (:mod:`flinkml_tpu.analysis.collectives`) — tracking per-value **dtype
 provenance** against the declared policy:
 
@@ -68,7 +68,7 @@ from typing import (
 
 import numpy as np
 
-from flinkml_tpu.analysis.collectives import COLLECTIVE_PRIMITIVES
+from flinkml_tpu.analysis.collectives import collective_name
 from flinkml_tpu.analysis.findings import Finding
 from flinkml_tpu.precision import (
     PrecisionPolicy,
@@ -197,7 +197,7 @@ class _Flow:
         out_is_float = out_dt is not None and _is_float(out_dt)
 
         # FML604 — narrow cross-rank collective without explicit pre-cast.
-        if name in COLLECTIVE_PRIMITIVES:
+        if collective_name(name) is not None:
             for a in eqn.invars:
                 dt = self._dtype(a)
                 if not _is_float(dt) or _bits(dt) >= accum_bits:
@@ -355,7 +355,7 @@ class _Flow:
             out_provs = out_provs or []
         elif "jaxpr" in params and hasattr(
                 getattr(params["jaxpr"], "jaxpr", None), "eqns"):
-            # pjit / closed_call / checkpoint-style wrappers.
+            # jit / closed_call / checkpoint-style wrappers.
             out_provs = self.walk(params["jaxpr"].jaxpr, in_provs)
         elif "call_jaxpr" in params:
             cj = params["call_jaxpr"]

@@ -59,7 +59,7 @@ ORDER_PRESERVING = frozenset({
 
 #: Call primitives recursed one level (the gate / jit wrappers the
 #: sparse trainers put around their scatters).
-_CALL_PRIMITIVES = frozenset({"pjit", "closed_call", "core_call",
+_CALL_PRIMITIVES = frozenset({"jit", "closed_call", "core_call",
                               "custom_jvp_call", "custom_vjp_call",
                               "remat", "checkpoint"})
 
@@ -109,7 +109,7 @@ def _walk(jaxpr, sorted_vars: set, location: Optional[str],
                     iv for iv, ov in zip(sub.invars, eqn.invars)
                     if _is_var(ov) and ov in sorted_vars
                 }
-                # Approximation: invars of pjit map positionally onto
+                # Approximation: invars of jit map positionally onto
                 # the sub-jaxpr's invars (true for the wrappers we
                 # recurse; consts ride constvars).
                 _walk(sub, inner_sorted | sorted_vars, location,

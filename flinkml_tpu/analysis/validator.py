@@ -165,7 +165,7 @@ def kernel_output_specs(kernel, schema: TableSchema,
         for k, v in kernel.constants.items()
     }
     valid = jax.ShapeDtypeStruct((rows,), np.float32)
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         out = jax.eval_shape(kernel.fn, cols, consts, valid)
     return {
         name: ColumnSpec(np.dtype(s.dtype), tuple(s.shape[1:]))
@@ -230,7 +230,7 @@ def _kernel_jaxpr(kernel, schema: TableSchema, rows: int = EVAL_ROWS):
             for k, v in kernel.constants.items()
         }
         valid = jax.ShapeDtypeStruct((rows,), np.float32)
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             return jax.make_jaxpr(kernel.fn)(cols, consts, valid)
     except Exception:
         return None
@@ -478,7 +478,7 @@ def _fused_promotion_findings(run_kernels, schema: TableSchema,
             )
             for k in run_kernels
         )
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             abstract = jax.eval_shape(
                 chain, ext_vals, const_vals, np.int32(EVAL_ROWS)
             )
@@ -489,7 +489,7 @@ def _fused_promotion_findings(run_kernels, schema: TableSchema,
             # finding is certain (promotion_findings' contract). A
             # trace failure degrades to an unlocalized message.
             try:
-                with jax.experimental.enable_x64(True):
+                with jax.enable_x64(True):
                     return jax.make_jaxpr(chain)(
                         ext_vals, const_vals, np.int32(EVAL_ROWS)
                     )

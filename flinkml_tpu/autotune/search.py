@@ -794,10 +794,9 @@ def search_knobs(knobs: Optional[Sequence[str]] = None, *,
     :meth:`TuningTable.set_knob`."""
     from flinkml_tpu.autotune.table import load_table
 
-    try:
-        committed_mesh = mesh_key()
-    except Exception:  # noqa: BLE001 — no backend: static incumbents
-        committed_mesh = None
+    # The measurers need the backend anyway: one that fails to come up
+    # raises here rather than reading as "no committed incumbent".
+    committed_mesh = mesh_key()
     table = load_table()
     results: Dict[str, dict] = {}
     for knob in (knobs or list(MEASURERS)):
@@ -811,9 +810,8 @@ def search_knobs(knobs: Optional[Sequence[str]] = None, *,
         if knob == "infer_plan_order":
             value: Any = order_presets(candidates)
         else:
-            committed = (table.value(committed_mesh, knob)
-                         if committed_mesh else None)
-            value = settle(knob, candidates, incumbent=committed)
+            value = settle(knob, candidates,
+                           incumbent=table.value(committed_mesh, knob))
         _log.info(
             "autotune: %s -> %r in %.1fs (candidates: %s)", knob, value,
             time.perf_counter() - t0,

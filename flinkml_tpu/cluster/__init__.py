@@ -36,6 +36,7 @@ from flinkml_tpu.cluster.errors import (
     ConnectionClosedError,
     FrameError,
     OversizedFrameError,
+    ProcessRuntimeBackendError,
     RemoteError,
     TransportError,
     TransportTimeoutError,
@@ -60,6 +61,7 @@ __all__ = [
     "ElasticProcessWorld",
     "FrameError",
     "OversizedFrameError",
+    "ProcessRuntimeBackendError",
     "RemoteEngine",
     "RemoteError",
     "TransportError",

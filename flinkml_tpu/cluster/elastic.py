@@ -28,7 +28,7 @@ import subprocess
 import time
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
-from flinkml_tpu.cluster.errors import ClusterError
+from flinkml_tpu.cluster.errors import ClusterError, require_cpu_parent
 from flinkml_tpu.utils.logging import get_logger
 
 _log = get_logger("cluster.elastic")
@@ -76,6 +76,7 @@ class ElasticProcessWorld:
         workdir: Optional[str] = None,
         round_timeout_s: float = 300.0,
     ):
+        require_cpu_parent("ElasticProcessWorld")
         self._argv_for_rank = argv_for_rank
         self._base_env = dict(env) if env is not None else None
         self._workdir = workdir
@@ -89,7 +90,7 @@ class ElasticProcessWorld:
         logs: List[str] = []
         for rank in range(world):
             env = rendezvous_env(rank, world, port, base=self._base_env)
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env["JAX_PLATFORMS"] = "cpu"  # ranks are CPU-device processes
             log_path = None
             stderr = subprocess.DEVNULL
             if self._workdir is not None:

@@ -77,6 +77,31 @@ class WorkerSpawnError(ClusterError):
     the spawn deadline, or it exited during startup)."""
 
 
+class ProcessRuntimeBackendError(ClusterError):
+    """The process runtime was asked to start under a parent whose JAX
+    backend is not CPU. Its children are virtual-CPU-device processes
+    (``JAX_PLATFORMS=cpu`` + ``xla_force_host_platform_device_count``):
+    under a parent that holds a TPU they would quietly serve from CPU
+    workers. On a TPU host one process drives every chip — use the
+    in-process :class:`~flinkml_tpu.serving.pool.ReplicaPool`."""
+
+
+def require_cpu_parent(what: str) -> None:
+    """Raise :class:`ProcessRuntimeBackendError` unless this process's
+    JAX backend is CPU."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise ProcessRuntimeBackendError(
+            f"{what} launches virtual-CPU-device worker processes, but "
+            f"this process's JAX backend is {backend!r}: a chip belongs "
+            "to one process, so the workers could only run on the host "
+            "CPU. Use the in-process serving.ReplicaPool (one process "
+            "drives all chips), or run the parent with JAX_PLATFORMS=cpu."
+        )
+
+
 class RemoteError(ClusterError):
     """The worker raised an exception type unknown to this process;
     carries the remote type name and message."""

@@ -28,12 +28,12 @@ and ``reconnects_total`` — see ``docs/development/cluster.md``.
 from __future__ import annotations
 
 import os
-import tempfile
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Union
 
 import numpy as np
 
 from flinkml_tpu.cluster.client import WorkerClient
+from flinkml_tpu.cluster.errors import require_cpu_parent
 from flinkml_tpu.cluster.remote import RemoteEngine
 from flinkml_tpu.serving.engine import ServingConfig
 from flinkml_tpu.serving.health import HealthPolicy, ReplicaHealth
@@ -73,6 +73,7 @@ class ClusterPool(ReplicaPool):
     ):
         if n_workers < 1:
             raise ValueError(f"n_workers must be >= 1, got {n_workers}")
+        require_cpu_parent("ClusterPool")
         self._init_core(
             source, example, config=config, output_cols=output_cols,
             name=name, health_policy=health_policy,
@@ -83,13 +84,14 @@ class ClusterPool(ReplicaPool):
         self._spawn_timeout_s = float(spawn_timeout_s)
         # One shared DISK store for every worker (a memory-only store
         # cannot cross a process boundary): explicit arg, else the
-        # configured env store, else a pool-owned tempdir.
+        # configured env store, else aot/ inside the jax cache directory.
         from flinkml_tpu.compile_cache import ENV_DIR_VAR
+        from flinkml_tpu.utils import jax_cache
 
         self._compile_cache_dir = (
             compile_cache_dir
             or os.environ.get(ENV_DIR_VAR)
-            or tempfile.mkdtemp(prefix=f"flinkml-cluster-{name}-cache-")
+            or jax_cache.aot_dir()
         )
         self.cluster_metrics = metrics.group(f"cluster.{name}")
         self._transport_window = LatencyWindow(self.cluster_metrics)

@@ -116,7 +116,9 @@ class WorkerProcess:
             os.path.join(self._workdir, "spec.pkl")
         )
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # Workers are virtual-CPU-device processes, whatever the parent
+        # runs on (ClusterPool refuses a non-CPU parent outright).
+        env["JAX_PLATFORMS"] = "cpu"
         if self.devices_per_worker is not None:
             # The child's device slice: its OWN virtual-device count,
             # not the parent's (a worker is its own XLA world).

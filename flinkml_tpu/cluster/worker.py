@@ -375,7 +375,7 @@ def main(argv=None) -> int:
     with open(argv[0], "rb") as f:
         spec = pickle.load(f)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"  # a worker is a CPU-device world
     if spec.get("compile_cache_dir"):
         from flinkml_tpu.compile_cache import ENV_DIR_VAR
 

@@ -23,7 +23,6 @@ from flinkml_tpu.compile_cache.store import (  # noqa: F401
     ensure_store,
     env_fingerprint,
     reset,
-    serialization_supported,
     stable_key_repr,
 )
 
@@ -35,6 +34,5 @@ __all__ = [
     "ensure_store",
     "env_fingerprint",
     "reset",
-    "serialization_supported",
     "stable_key_repr",
 ]

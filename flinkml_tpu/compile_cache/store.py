@@ -33,10 +33,9 @@ Invalidation rules
 - torn/corrupt entry (unpicklable, wrong format, sha mismatch) →
   **miss**, logged loudly, the entry is deleted, and the caller's fresh
   compile rewrites it (counted ``corrupt_entries``) — never a crash;
-- serialization unsupported (older jax, or a backend whose executables
-  refuse ``serialize``) → the store degrades to compile-only, logged
-  loudly ONCE (counted ``fallbacks``): behavior is exactly the
-  in-memory jit path.
+- an executable that refuses ``serialize``, or whose artifact fails
+  its post-serialize load check → that program stays in-memory only,
+  logged loudly (counted ``fallbacks``).
 
 Concurrency: entries are written to a temp file in the cache directory
 and published with ``os.replace`` (the ``CheckpointManager`` idiom), so
@@ -80,33 +79,6 @@ _log = get_logger("compile_cache")
 ENV_DIR_VAR = "FLINKML_TPU_COMPILE_CACHE"
 
 _FORMAT = 1
-
-_SUPPORT = [None]  # tri-state probe cache: None unknown, True/False known
-_WARNED_UNSUPPORTED = [False]
-
-
-def serialization_supported() -> bool:
-    """Whether this jax build exposes the AOT executable serialization
-    API (``jax.experimental.serialize_executable``). Probed once; a
-    False answer downgrades every store to compile-only with one loud
-    log line (the in-memory jit behavior, unchanged)."""
-    if _SUPPORT[0] is None:
-        try:
-            from jax.experimental import serialize_executable as se
-
-            _SUPPORT[0] = callable(getattr(se, "serialize", None)) and \
-                callable(getattr(se, "deserialize_and_load", None))
-        except Exception:  # noqa: BLE001 — any import failure = unsupported
-            _SUPPORT[0] = False
-        if not _SUPPORT[0] and not _WARNED_UNSUPPORTED[0]:
-            _WARNED_UNSUPPORTED[0] = True
-            _log.warning(
-                "jax.experimental.serialize_executable unavailable in this "
-                "jax build; the compile cache degrades to in-memory jit "
-                "(every process pays its own compiles)"
-            )
-    return bool(_SUPPORT[0])
-
 
 def env_fingerprint() -> Dict[str, str]:
     """The environment half of the artifact key (see module docstring).
@@ -169,37 +141,44 @@ def _key_hash(key: Any) -> str:
     return hashlib.sha256(stable_key_repr(key).encode()).hexdigest()[:24]
 
 
-class _RemapUnpickler(pickle.Unpickler):
-    """``serialize_executable``'s unpickler with the device ids remapped:
-    the payload's persistent ids carry ``('device', id)`` markers and the
-    PJRT executable blob, and PJRT's ``deserialize_executable`` accepts a
-    replacement device assignment — so ONE single-device artifact loads
-    onto ANY device of the same kind (the pool's one-compile-per-N-
-    replicas fix). Falls back to a fresh compile on any failure."""
+def _load_retargeted(entry: Dict[str, Any], device):
+    """Load a single-device artifact onto ``device`` instead of the
+    device it was compiled for.
 
-    def __init__(self, file, backend, device_map: Dict[int, int]):
-        super().__init__(file)
-        self._backend = backend
-        self._map = device_map
-        self._by_id = {d.id: d for d in backend.devices()}
+    ``serialize_executable.deserialize_and_load(...,
+    execution_devices=[device])`` alone cannot do this on jax 0.9.0: its
+    unpickler looks the RECORDED device id up among the execution
+    devices (KeyError), and XLA:CPU keeps the compile-time device
+    assignment unless the compile options carry the new one. So this
+    subclasses jax's own unpickler and overrides exactly those two
+    things."""
+    import jax
+    import numpy as np
+    from jax._src.lib import xla_client as xc
+    from jax.experimental import serialize_executable as se
 
-    def persistent_load(self, pid):
-        import numpy as np
+    (src,) = entry["device_ids"]
 
-        from jax._src.lib import xla_client as xc
+    class _Retarget(se._JaxPjrtUnpickler):
+        def persistent_load(self, pid):
+            if pid[0] == "exec":
+                opts = xc.CompileOptions()
+                opts.device_assignment = xc.DeviceAssignment.create(
+                    np.asarray([[device.id]], dtype=np.int32)
+                )
+                return self.backend.deserialize_executable(
+                    pid[1], self.execution_devices, opts
+                )
+            return super().persistent_load(pid)
 
-        if pid[0] == "exec":
-            ids = [self._map[i] for i in sorted(self._map)]
-            opts = xc.CompileOptions()
-            opts.device_assignment = xc.DeviceAssignment.create(
-                np.asarray([[i] for i in ids], dtype=np.int32)
-            )
-            return self._backend.deserialize_executable(pid[1], opts)
-        if pid[0] == "device":
-            return self._by_id[self._map.get(pid[1], pid[1])]
-        if pid[0] == "client":
-            return self._backend
-        raise pickle.UnpicklingError(f"unknown persistent id {pid!r}")
+    unpickler = _Retarget(io.BytesIO(entry["payload"]), device.client,
+                          [device])
+    unpickler.devices_by_id = {int(src): device}
+    unloaded, args_info_flat, no_kwargs = unpickler.load()
+    return jax.stages.Compiled(
+        unloaded.load(), [], entry["in_tree"].unflatten(args_info_flat),
+        entry["out_tree"], no_kwargs=no_kwargs,
+    )
 
 
 class CompileCacheStore:
@@ -291,24 +270,19 @@ class CompileCacheStore:
 
         src = [int(i) for i in entry["device_ids"]]
         dst = src if device_ids is None else [int(i) for i in device_ids]
+        by_id = {d.id: d for d in jax.devices()}
         if dst == src:
             return se.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"]
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=[by_id[i] for i in src] or None,
             )
         if len(src) != 1 or len(dst) != 1:
             # An SPMD executable's collective schedule is baked for one
             # device set; retargeting is single-device only.
             return None
-        backend = jax.devices()[0].client
-        unloaded, args_info_flat, no_kwargs = _RemapUnpickler(
-            io.BytesIO(entry["payload"]), backend, {src[0]: dst[0]}
-        ).load()
-        args_info = entry["in_tree"].unflatten(args_info_flat)
+        program = _load_retargeted(entry, by_id[dst[0]])
         self._metrics.counter("retarget_loads")
-        return jax.stages.Compiled(
-            unloaded.load(), args_info, entry["out_tree"],
-            no_kwargs=no_kwargs,
-        )
+        return program
 
     # -- disk --------------------------------------------------------------
     def _read_disk(self, key: Any) -> Optional[Dict[str, Any]]:
@@ -396,12 +370,9 @@ class CompileCacheStore:
         placement the returned program must execute on — recorded at
         store time, retarget-matched at load time. Returns ``(program,
         outcome)`` with outcome one of ``"memory"``, ``"disk"``,
-        ``"compiled"``, ``"uncached"`` (serialization unavailable or
-        failed; the program came from ``build`` and was not stored).
+        ``"compiled"``, ``"uncached"`` (serialization failed; the
+        program came from ``build`` and was not stored).
         """
-        if not serialization_supported():
-            self._metrics.counter("fallbacks")
-            return build(), "uncached"
         khash = _key_hash(key)
         with self._key_lock(khash):
             outcome = "memory"
@@ -460,22 +431,22 @@ class CompileCacheStore:
 
     @staticmethod
     def _build_fresh(build: Callable[[], Any]):
-        """Run ``build`` with jax's own persistent compilation cache
-        disabled: an executable that XLA:CPU loads from that cache
-        serializes WITHOUT its jit-compiled symbols ("Symbols not
-        found" at deserialize — reproduced on jax 0.4.37), so an
-        artifact must always come from a fresh backend compile. This
-        store replaces what the jax cache would have saved anyway."""
+        """Run ``build``; on the CPU backend, with jax's persistent
+        compilation cache suspended: an executable XLA:CPU LOADS from
+        that cache re-serializes without its jit-compiled functions
+        (jax 0.9.0: the artifact loads, then fails at execution with
+        "Function ... not found"), so an artifact must come from a fresh
+        backend compile. A TPU executable loaded from the cache
+        re-serializes whole (chip run, PR 21), so there the cache stays
+        on and a warm process's first replica hits it."""
         import jax
 
-        prev = jax.config.jax_compilation_cache_dir
-        if prev is None:
+        from flinkml_tpu.utils import jax_cache
+
+        if jax.default_backend() != "cpu":
             return build()
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
+        with jax_cache.suspended():
             return build()
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
 
     def _verify_entry(self, entry: Dict[str, Any],
                       device_ids: Optional[Sequence[int]],

@@ -69,7 +69,7 @@ def pad_place_table(table: Table, place=None) -> Table:
     n = table.num_rows
     bucket = row_bucket(n)
     cols = {}
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         for name in table.column_names:
             arr = table.column(name)
             if arr.dtype == object:

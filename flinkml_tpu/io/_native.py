@@ -5,19 +5,30 @@ sources ship inside the wheel via package-data); the first import compiles
 it with the system ``g++`` into a ``build/`` dir next to the sources — or,
 when the installed package is read-only, into a per-user cache dir —
 (atomic rename so concurrent processes never dlopen a half-written file)
-and caches the handle. Callers fall back to pure Python when no compiler
-is available — the native path is a throughput optimization, never a
-functional requirement.
+and caches the handle. The artifact is named by a hash of the source and
+the compiler flags, so only a library built from exactly the committed
+``.cpp`` can load; a stale or copied-in ``.so`` is never trusted.
+Callers fall back to pure Python when no compiler is available — the
+native path is a throughput optimization, never a functional
+requirement — and the fallback is logged once at WARNING.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
 import threading
 from typing import Callable, Dict, Optional
+
+from flinkml_tpu.utils.logging import get_logger
+
+_log = get_logger("io.native")
+
+_COMPILE = ("g++", "-O3", "-std=c++17", "-shared", "-fPIC")
+_LINK = ("-lpthread",)
 
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -40,6 +51,17 @@ def _build_dir() -> str:
     os.makedirs(fallback, exist_ok=True)
     return fallback
 
+
+
+def artifact_path(name: str) -> str:
+    """Where ``<name>.cpp``'s library lives: ``<build dir>/<name>-<sha256
+    of source + compile command>.so``."""
+    digest = hashlib.sha256(" ".join(_COMPILE + _LINK).encode())
+    with open(os.path.join(_NATIVE_DIR, f"{name}.cpp"), "rb") as fh:
+        digest.update(fh.read())
+    return os.path.join(_build_dir(), f"{name}-{digest.hexdigest()[:16]}.so")
+
+
 _lock = threading.Lock()
 _cache: Dict[str, Optional[ctypes.CDLL]] = {}
 
@@ -47,26 +69,23 @@ _cache: Dict[str, Optional[ctypes.CDLL]] = {}
 def compile_and_load(
     name: str, declare: Callable[[ctypes.CDLL], None]
 ) -> Optional[ctypes.CDLL]:
-    """Compile ``flinkml_tpu/native/<name>.cpp`` (if stale) and load it.
+    """Compile ``flinkml_tpu/native/<name>.cpp`` (unless its artifact
+    exists) and load it.
 
     ``declare`` sets restype/argtypes on the fresh handle. Returns None if
     compilation or loading fails (callers use their Python fallback);
-    the failure is cached so we do not retry per call.
+    the failure is logged and cached so we do not retry per call.
     """
     with _lock:
         if name in _cache:
             return _cache[name]
         src = os.path.join(_NATIVE_DIR, f"{name}.cpp")
-        so = os.path.join(_build_dir(), f"{name}.so")
         try:
-            if not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(src):
-                os.makedirs(os.path.dirname(so), exist_ok=True)
+            so = artifact_path(name)
+            if not os.path.exists(so):
                 tmp_so = f"{so}.tmp.{os.getpid()}"
                 subprocess.run(
-                    [
-                        "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-                        "-o", tmp_so, src, "-lpthread",
-                    ],
+                    [*_COMPILE, "-o", tmp_so, src, *_LINK],
                     check=True,
                     capture_output=True,
                 )
@@ -74,6 +93,12 @@ def compile_and_load(
             lib = ctypes.CDLL(so)
             declare(lib)
             _cache[name] = lib
-        except (OSError, subprocess.CalledProcessError):
+        except (OSError, subprocess.CalledProcessError) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            _log.warning(
+                "native parser %s unavailable (%s: %s %s); using the "
+                "pure-Python parser", name, type(e).__name__, e,
+                detail.decode(errors="replace")[-500:],
+            )
             _cache[name] = None
         return _cache[name]

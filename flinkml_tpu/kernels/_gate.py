@@ -143,6 +143,17 @@ def interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def out_struct(shape, dtype, *operands):
+    """The ``out_shape`` entry for a ``pallas_call`` whose output varies
+    over the same manual mesh axes as ``operands``: inside
+    ``jax.shard_map`` (``check_vma=True``, the default) an output must
+    declare its ``vma``; outside one the set is empty."""
+    import jax
+
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, vma=vma)
+
+
 def refuse_or_fallback(site: str, explicit: bool, reason: str) -> str:
     """The refusal contract: explicit Pallas + unsupported → raise
     :class:`KernelUnsupportedError`; table-chosen Pallas + unsupported

@@ -43,9 +43,13 @@ from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple
 
-#: Row-tile ceiling: small buckets run as one tile (shape-identical to
-#: the XLA program); larger buckets tile at 128 rows (MXU-friendly).
-MAX_ROW_TILE = 128
+#: Row-tile ceiling: buckets up to here run as one tile (shape-identical
+#: to the XLA program); larger buckets tile at 1024 rows — the block a
+#: rank-1 float32 output column (``prediction [bucket]``) must have on
+#: the TPU, where XLA lays rank-1 arrays out in tiles of 1024 (a 128-row
+#: tile is refused: "XLA layout T(1024) does not match Mosaic layout
+#: T(128)").
+MAX_ROW_TILE = 1024
 
 
 def row_tile(bucket: int) -> int:
@@ -266,7 +270,8 @@ def pallas_chain_fn(kernels, ext_names: Sequence[str],
                 ),
                 out_specs=tuple(tiled(s.shape) for s in out_struct),
                 out_shape=tuple(
-                    jax.ShapeDtypeStruct(s.shape, s.dtype)
+                    _gate.out_struct(s.shape, s.dtype, *ext_c,
+                                     *flat_consts)
                     for s in out_struct
                 ),
                 interpret=interpret,

@@ -26,12 +26,20 @@ cell count. The sorted run-flush carry rides two tiny extra output refs
 (current id + accumulator row) between grid steps, so a run spanning a
 block boundary is still added left-to-right and flushed exactly once.
 The remaining supported-shape ceiling (``MAX_COMPILED_CELLS``) is the
-OUTPUT block ``num_segments * k``, which must stay VMEM-resident for
-the whole pass; the compiled path refuses sizes past it rather than
-compiling something that spills. The device re-tune (bench stage
-``pallas``) decides whether this beats XLA's scatter on hardware — the
-gate (:mod:`flinkml_tpu.kernels._gate`) keeps XLA the default until a
-measured win is committed.
+OUTPUT block, which must stay VMEM-resident for the whole pass; the
+compiled path refuses sizes past it rather than compiling something
+that spills.
+
+Placement on the TPU (what Mosaic on a v5e accepts): the ids are read
+one scalar at a time, so they live in SMEM (a scalar read from a VMEM
+vector does not lower), as does the sorted variant's carried id; a
+``[rows, k]`` float32 VMEM block tiles to ``(8, 128)``, so every row
+costs 128 lanes whatever ``k`` is — the ceilings below count PADDED
+cells. At the sparse trainers' own shape (``k = 1``, one segment per
+feature) that is a 128x blow-up: ``dim = 1e6`` segments would need
+512 MB of VMEM, so the compiled path refuses it by name and XLA's
+scatter keeps that site. The gate (:mod:`flinkml_tpu.kernels._gate`)
+keeps XLA the default everywhere until a measured win is committed.
 """
 
 from __future__ import annotations
@@ -40,16 +48,25 @@ import functools
 from typing import Optional
 
 #: Supported-shape ceiling for the COMPILED (non-interpret) path, in
-#: cells of the OUTPUT block (``num_segments * k``): the segment axis
-#: must fit one VMEM block; the cell axis streams through the grid and
-#: has no ceiling.
-MAX_COMPILED_CELLS = 1 << 22
+#: PADDED cells of the OUTPUT block (:func:`padded_cells` of
+#: ``[num_segments, k]``; 8 MiB of float32): the segment axis must fit
+#: one VMEM block; the cell axis streams through the grid and has no
+#: ceiling.
+MAX_COMPILED_CELLS = 1 << 21
 
-#: Cells per grid step. One block up to here (the committed-measurement
-#: shape); larger inputs grid over ``ceil(cells / BLOCK_CELLS)`` steps.
-BLOCK_CELLS = 1 << 19
+#: Cells per grid step: one ``[BLOCK_CELLS, k]`` value block is 2 MiB of
+#: VMEM at k <= 128 (double-buffered), its ids 16 KiB of SMEM. Inputs
+#: up to here run as one block; larger ones grid over
+#: ``ceil(cells / BLOCK_CELLS)`` steps.
+BLOCK_CELLS = 1 << 12
 
 _FLOAT_KINDS = "f"  # jnp dtype.kind for floating
+
+
+def padded_cells(rows: int, k: int) -> int:
+    """float32 cells a ``[rows, k]`` VMEM block occupies once tiled to
+    ``(8, 128)``."""
+    return (-(-rows // 8) * 8) * (-(-k // 128) * 128)
 
 
 def unsupported_reason(values, ids, num_segments: int,
@@ -74,15 +91,21 @@ def unsupported_reason(values, ids, num_segments: int,
     if num_segments < 1:
         return f"num_segments must be >= 1, got {num_segments}"
     if not interpret:
-        if v.dtype == jnp.float64:
-            return "float64 is interpreter-only (TPU has no f64 lanes)"
+        if v.dtype != jnp.float32:
+            return (f"values dtype {v.dtype}: the compiled kernel "
+                    "updates one float32 row at a dynamic sublane; "
+                    "packed (bfloat16) and float64 rows are "
+                    "interpreter-only")
         k = 1 if v.ndim == 1 else v.shape[1]
-        if num_segments * k > MAX_COMPILED_CELLS:
-            return (f"output block num_segments*k = {num_segments * k} "
-                    f"exceeds the one-block compiled ceiling of "
-                    f"{MAX_COMPILED_CELLS} (MAX_COMPILED_CELLS); the "
-                    "grid streams the cell axis, but the segment axis "
-                    "must fit one VMEM-resident block")
+        padded = padded_cells(num_segments, k)
+        if padded > MAX_COMPILED_CELLS:
+            return (f"output block [{num_segments}, {k}] tiles to "
+                    f"{padded} padded cells ({padded * 4 >> 20} MiB of "
+                    f"VMEM at 128 lanes per row), above the one-block "
+                    f"compiled ceiling of {MAX_COMPILED_CELLS} "
+                    "(MAX_COMPILED_CELLS); the grid streams the cell "
+                    "axis, but the segment axis must fit one "
+                    "VMEM-resident block")
     return None
 
 
@@ -112,25 +135,25 @@ def _sorted_body(ids_ref, val_ref, out_ref):
     out_ref[...] = jnp.zeros_like(out_ref)
     cells = val_ref.shape[0]
 
+    # The accumulator stays a [1, k] row throughout: Mosaic has no
+    # layout for the rank-1 [k] vector a squeezed row would be.
     def body(j, carry):
         cur, acc = carry
         idx = ids_ref[j]
-        v = val_ref[pl.ds(j, 1), :][0]
+        v = val_ref[pl.ds(j, 1), :]
         flush = idx != cur
 
         @pl.when(flush)
         def _():
-            out_ref[pl.ds(cur, 1), :] = (
-                out_ref[pl.ds(cur, 1), :] + acc[None, :]
-            )
+            out_ref[pl.ds(cur, 1), :] = out_ref[pl.ds(cur, 1), :] + acc
 
         return idx, jnp.where(flush, v, acc + v)
 
     cur, acc = jax.lax.fori_loop(
         0, cells, body,
-        (ids_ref[0], jnp.zeros_like(val_ref[pl.ds(0, 1), :][0])),
+        (ids_ref[0], jnp.zeros_like(val_ref[pl.ds(0, 1), :])),
     )
-    out_ref[pl.ds(cur, 1), :] = out_ref[pl.ds(cur, 1), :] + acc[None, :]
+    out_ref[pl.ds(cur, 1), :] = out_ref[pl.ds(cur, 1), :] + acc
 
 
 def _unsorted_grid_body(ids_ref, val_ref, out_ref, *, total_cells: int):
@@ -184,39 +207,35 @@ def _sorted_grid_body(ids_ref, val_ref, out_ref, carry_id_ref,
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
         carry_id_ref[0, 0] = ids_ref[0]
-        carry_acc_ref[0, :] = jnp.zeros_like(carry_acc_ref[0, :])
+        carry_acc_ref[...] = jnp.zeros_like(carry_acc_ref)
 
     def body(j, carry):
         cur, acc = carry
         valid = i * block + j < total_cells
         idx = ids_ref[j]
-        v = val_ref[pl.ds(j, 1), :][0]
+        v = val_ref[pl.ds(j, 1), :]
         flush = (idx != cur) & valid
 
         @pl.when(flush)
         def _():
-            out_ref[pl.ds(cur, 1), :] = (
-                out_ref[pl.ds(cur, 1), :] + acc[None, :]
-            )
+            out_ref[pl.ds(cur, 1), :] = out_ref[pl.ds(cur, 1), :] + acc
 
         ncur = jnp.where(valid, idx, cur)
         nacc = jnp.where(valid, jnp.where(flush, v, acc + v), acc)
         return ncur, nacc
 
     cur, acc = jax.lax.fori_loop(
-        0, block, body, (carry_id_ref[0, 0], carry_acc_ref[0, :])
+        0, block, body, (carry_id_ref[0, 0], carry_acc_ref[...])
     )
 
     @pl.when(i == last)
     def _():
-        out_ref[pl.ds(cur, 1), :] = (
-            out_ref[pl.ds(cur, 1), :] + acc[None, :]
-        )
+        out_ref[pl.ds(cur, 1), :] = out_ref[pl.ds(cur, 1), :] + acc
 
     @pl.when(i != last)
     def _():
         carry_id_ref[0, 0] = cur
-        carry_acc_ref[0, :] = acc
+        carry_acc_ref[...] = acc
 
 
 def pallas_segment_sum(values, ids, num_segments: int, *,
@@ -232,6 +251,7 @@ def pallas_segment_sum(values, ids, num_segments: int, *,
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     from flinkml_tpu.kernels import _gate
 
@@ -252,11 +272,12 @@ def pallas_segment_sum(values, ids, num_segments: int, *,
         out = pl.pallas_call(
             body,
             in_specs=[
-                pl.BlockSpec((cells,), lambda: (0,)),
+                pl.BlockSpec((cells,), lambda: (0,),
+                             memory_space=pltpu.SMEM),
                 pl.BlockSpec((cells, k), lambda: (0, 0)),
             ],
             out_specs=pl.BlockSpec((num_segments, k), lambda: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((num_segments, k), v2.dtype),
+            out_shape=_gate.out_struct((num_segments, k), v2.dtype, ids32, v2),
             interpret=interpret,
         )(ids32, v2)
         return out[:, 0] if flat else out
@@ -268,7 +289,8 @@ def pallas_segment_sum(values, ids, num_segments: int, *,
         ids32 = jnp.concatenate([ids32, jnp.zeros((pad,), jnp.int32)])
         v2 = jnp.concatenate([v2, jnp.zeros((pad, k), v2.dtype)])
     in_specs = [
-        pl.BlockSpec((BLOCK_CELLS,), lambda i: (i,)),
+        pl.BlockSpec((BLOCK_CELLS,), lambda i: (i,),
+                     memory_space=pltpu.SMEM),
         pl.BlockSpec((BLOCK_CELLS, k), lambda i: (i, 0)),
     ]
     out_spec = pl.BlockSpec((num_segments, k), lambda i: (0, 0))
@@ -279,13 +301,14 @@ def pallas_segment_sum(values, ids, num_segments: int, *,
             in_specs=in_specs,
             out_specs=(
                 out_spec,
-                pl.BlockSpec((1, 1), lambda i: (0, 0)),
+                pl.BlockSpec((1, 1), lambda i: (0, 0),
+                             memory_space=pltpu.SMEM),
                 pl.BlockSpec((1, k), lambda i: (0, 0)),
             ),
             out_shape=(
-                jax.ShapeDtypeStruct((num_segments, k), v2.dtype),
-                jax.ShapeDtypeStruct((1, 1), jnp.int32),
-                jax.ShapeDtypeStruct((1, k), v2.dtype),
+                _gate.out_struct((num_segments, k), v2.dtype, ids32, v2),
+                _gate.out_struct((1, 1), jnp.int32, ids32, v2),
+                _gate.out_struct((1, k), v2.dtype, ids32, v2),
             ),
             interpret=interpret,
         )(ids32, v2)
@@ -295,7 +318,7 @@ def pallas_segment_sum(values, ids, num_segments: int, *,
             grid=(grid,),
             in_specs=in_specs,
             out_specs=out_spec,
-            out_shape=jax.ShapeDtypeStruct((num_segments, k), v2.dtype),
+            out_shape=_gate.out_struct((num_segments, k), v2.dtype, ids32, v2),
             interpret=interpret,
         )(ids32, v2)
     return out[:, 0] if flat else out

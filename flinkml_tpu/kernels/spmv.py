@@ -14,11 +14,19 @@ bit-identical to the JITTED reference at every dtype (the product path
 is always jitted; an eager reference can differ in the last f32 bit
 because XLA's unfused reduce uses a different association tree).
 
-``w`` stays whole in one block (every row may touch every feature), so
-the compiled path refuses ``dim`` past the one-block ceiling
-(``MAX_COMPILED_DIM``). The gate (:mod:`flinkml_tpu.kernels._gate`,
-site ``spmv``) keeps XLA the default; the bench's ``sparse_hot_loops``
-stage measures the ratio and the device re-tune decides.
+**Interpreter-only.** The kernel does not compile on a TPU (checked
+against Mosaic for a v5e, jax 0.9.0): its body is
+``jnp.take(w_ref[...], idx_ref[...])``, an arbitrary-index gather from
+a ``[dim]`` vector, and Mosaic lowers only a same-shape 2-D
+``take_along_axis`` (``NotImplementedError: Only 2D gather is
+supported``); its rank-1 output blocks of ``ROW_TILE`` rows are refused
+too (rank-1 blocks must be multiples of 128). A compiled SpMV needs a
+different design (per-row DMA gathers or a one-hot matmul), so
+``unsupported_reason(..., interpret=False)`` says so and an explicit
+``FLINKML_TPU_KERNELS=pallas`` on the chip raises
+:class:`KernelUnsupportedError` at this site instead of failing inside
+the compiler. The gate (:mod:`flinkml_tpu.kernels._gate`, site
+``spmv``) keeps XLA the default.
 """
 
 from __future__ import annotations
@@ -29,9 +37,14 @@ from typing import Optional
 #: multiple with zero rows that are sliced off after the call.
 ROW_TILE = 8
 
-#: One-block ceiling for ``w`` on the COMPILED (non-interpret) path:
-#: the weight vector must stay VMEM-resident for every row tile.
-MAX_COMPILED_DIM = 1 << 22
+#: Why there is no compiled path (module docstring); lands verbatim in
+#: :class:`KernelUnsupportedError`.
+NO_TPU_LOWERING = (
+    "the kernel does not compile on TPU: its body gathers w[indices] "
+    "from a [dim] vector with arbitrary indices, and Mosaic lowers only "
+    "a same-shape 2-D take_along_axis ('Only 2D gather is supported'); "
+    "it runs under the interpreter only"
+)
 
 
 def unsupported_reason(indices, values, w, interpret: bool) -> Optional[str]:
@@ -55,12 +68,7 @@ def unsupported_reason(indices, values, w, interpret: bool) -> Optional[str]:
     if values.dtype != w.dtype:
         return f"values dtype {values.dtype} != w dtype {w.dtype}"
     if not interpret:
-        if values.dtype == jnp.float64:
-            return "float64 is interpreter-only (TPU has no f64 lanes)"
-        if w.shape[0] > MAX_COMPILED_DIM:
-            return (f"dim {w.shape[0]} exceeds the one-block compiled "
-                    f"ceiling of {MAX_COMPILED_DIM} (MAX_COMPILED_DIM) "
-                    "for the VMEM-resident weight vector")
+        return NO_TPU_LOWERING
     return None
 
 
@@ -76,7 +84,6 @@ def pallas_spmv(indices, values, w, *, interpret: Optional[bool] = None):
     bit-compatible with the XLA reference at every dtype. Unsupported
     operands raise :class:`KernelUnsupportedError` (same typed refusal
     as the gated dispatcher)."""
-    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -108,7 +115,8 @@ def pallas_spmv(indices, values, w, *, interpret: Optional[bool] = None):
             pl.BlockSpec((w.shape[0],), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((ROW_TILE,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((idx32.shape[0],), values.dtype),
+        out_shape=_gate.out_struct(
+            (idx32.shape[0],), values.dtype, idx32, values, w),
         interpret=interpret,
     )(idx32, values, w)
     return out[:rows] if pad else out

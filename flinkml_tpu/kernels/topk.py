@@ -45,8 +45,9 @@ def unsupported_reason(x, k: int, interpret: bool) -> Optional[str]:
         return f"k={k} outside [1, n={n}]"
     if k > MAX_K:
         return f"k={k} exceeds the unrolled-pass ceiling of {MAX_K}"
-    if not interpret and x.dtype == jnp.float64:
-        return "float64 is interpreter-only (TPU has no f64 lanes)"
+    if not interpret and x.dtype != jnp.float32:
+        return (f"operand dtype {x.dtype}: Mosaic's argmax lowers "
+                "float32 only; other float widths are interpreter-only")
     return None
 
 
@@ -69,7 +70,10 @@ def _topk_body(x_ref, val_ref, idx_ref, *, k: int):
         # All untaken entries at -inf: the masked and unmasked values
         # tie, so argmax must not land on an already-taken column —
         # take the first UNTAKEN index instead.
-        first_untaken = jnp.argmax(~taken, axis=1).astype(jnp.int32)
+        # f32 operand: Mosaic's argmax lowers float32 only (not bool).
+        first_untaken = jnp.argmax(
+            jnp.where(taken, 0.0, 1.0).astype(jnp.float32), axis=1
+        ).astype(jnp.int32)
         a = jnp.where(jnp.isneginf(m), first_untaken, a)
         val_ref[:, j] = m
         idx_ref[:, j] = a
@@ -108,8 +112,8 @@ def pallas_top_k(x, k: int, *, interpret: Optional[bool] = None) -> Tuple:
             pl.BlockSpec((ROW_TILE, k), lambda i: (i, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((x2.shape[0], k), x2.dtype),
-            jax.ShapeDtypeStruct((x2.shape[0], k), jnp.int32),
+            _gate.out_struct((x2.shape[0], k), x2.dtype, x2),
+            _gate.out_struct((x2.shape[0], k), jnp.int32, x2),
         ),
         interpret=interpret,
     )(x2)

@@ -142,10 +142,9 @@ def _window(arr, epoch, local_bs):
 def make_dense_step(loss: str, local_bs: int, axis: str):
     """Per-device epoch: window → margin grad on MXU → psum → prox update.
 
-    A hand-fused Pallas version of this step was measured LOSING to this
-    plain lowering at every shape (0.70-0.82x; BASELINE.md "Kernel-path
-    verdict") and was removed — XLA's forward + back-product pair is the
-    fast path on current TPU generations."""
+    A hand-fused Pallas version of this step lost to this plain
+    lowering and was removed (not re-measured on the current chip) —
+    XLA's forward + back-product pair is the product path."""
 
     def step(coef, epoch, xl, yl, wl, learning_rate, reg_l2, reg_l1):
         xb = _window(xl, epoch, local_bs)
@@ -1598,10 +1597,16 @@ def _sorted_column_stepper(loss: str, dim: int,
         dot = kernels.spmv(ib, vb, coef, backend=spmv_backend)
         mult, per_ex = _margin_grad(loss, dot, yb, wb)
         contrib = (vb * mult[:, None]).reshape(-1)
-        grad = kernels.segment_sum(
+        scattered = kernels.segment_sum(
             jnp.take(contrib, perm), seg, dim,
             indices_are_sorted=True, backend=segsum_backend,
-        ) + 2.0 * reg_l2 * coef
+        )
+        # Without the barrier XLA folds the L2 term into the scatter's
+        # init operand (each coefficient's sum would START from it);
+        # the CSR stream's psum keeps it last. Same order, same bits.
+        grad = jax.lax.optimization_barrier(scattered) + (
+            2.0 * reg_l2 * coef
+        )
         loss_sum = jnp.sum(per_ex.astype(acc)) + (
             reg_l2 * jnp.sum(jnp.square(coef.astype(acc)))
         )

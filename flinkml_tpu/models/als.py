@@ -77,7 +77,7 @@ def _als_layout() -> str:
     ``segment`` (default): per-chunk ``segment_sum`` of the ``[rows, k,
     k]`` outer products — XLA's sort-based lowering drags the 4 KB
     per-row payload through a sort every chunk of every half-step
-    (measured 1.4% of the streaming bound, BASELINE.md "rooflines").
+    (bound in BASELINE.md "Roofline"; its share not measured on the chip).
     ``cumsum``: the rating→target assignment is STATIC across
     iterations, so the in-RAM fit sorts the COO by target once at pack
     time and each chunk reduces at precomputed run boundaries with

@@ -489,7 +489,7 @@ class _FMModelBase(_FMParams, Model):
             )
         if vb.shape[1] == 0:  # all-empty rows: margin is the intercept
             return np.full(vb.shape[0], self._w0)
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             linear = np.asarray(kernels.spmv(ib, vb, self._w))
         gathered = self._v[ib]                       # [n, s, k]
         xv = np.einsum("ns,nsk->nk", vb, gathered)

@@ -130,8 +130,8 @@ def _hist_layout() -> str:
 
     ``segment`` (default): one ``segment_sum`` over ``n·d`` cells per
     level — XLA's sort-based lowering re-sorts every cell at every level
-    of every tree (measured 0.22% of the streaming bound, BASELINE.md
-    "rooflines": the same sort class as sparse LR). ``cumsum``: the
+    of every tree (bound in BASELINE.md "Roofline"; its share is not
+    measured on the chip — the same sort class as sparse LR). ``cumsum``: the
     (feature, bin) half of the key is STATIC per fit, so cells are
     sorted once at pack time (:func:`gbt_hist_tables`) and each level
     reduces ``2^level``-wide one-hot-expanded (grad, hess) columns with

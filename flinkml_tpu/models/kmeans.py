@@ -257,10 +257,10 @@ class KMeansModel(_KMeansParams, Model):
 def _kmeans_trainer(mesh, k: int, axis: str):
     """Whole Lloyd loop as one XLA program, cached per (mesh, k).
 
-    Round-2 measured a hand-fused Pallas Lloyd pass losing to this plain
-    lowering at every shape (0.39-0.72x; BASELINE.md "Kernel-path
-    verdict"), so the argmin + one-hot-matmul form below IS the fast
-    path — XLA's fusion already reads the points once per pass."""
+    A hand-fused Pallas Lloyd pass was built, lost to this plain
+    lowering and was removed (not re-measured on the current chip), so
+    the argmin + one-hot-matmul form below IS the product path — XLA's
+    fusion already reads the points once per pass."""
 
     def per_device(xl, wl, init_centroids, max_iter):
         def body(_, centroids):

@@ -164,7 +164,7 @@ class MinHashLSHModel(HasInputCol, HasOutputCol, HasSeed, Model):
 
             # x64 keeps the ranking in float64, matching the host
             # distances exactly (no f32 rounding could reorder ties).
-            with jax.experimental.enable_x64(True):
+            with jax.enable_x64(True):
                 _, order = kernels.top_k(
                     jax.numpy.asarray(-dists), k_eff,
                     backend=kernels.topk_backend(),

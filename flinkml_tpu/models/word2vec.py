@@ -33,7 +33,6 @@ import functools
 import os
 from typing import Dict, List, Optional, Tuple
 
-import flinkml_tpu._jax_compat  # noqa: F401  (jax version shims; install before first jax use)
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 from typing import Callable
 
-import flinkml_tpu._jax_compat  # noqa: F401  (jax version shims; install before first jax use)
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P

@@ -26,7 +26,6 @@ import os
 import time
 from typing import Optional, Tuple
 
-import flinkml_tpu._jax_compat  # noqa: F401  (jax version shims; install before first jax use)
 import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -143,7 +142,6 @@ def init_distributed(
             raise ValueError(f"max_attempts must be >= 1, got {max_attempts}")
         if deadline_s is not None and deadline_s <= 0:
             raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
-        _enable_cpu_collectives()
         t0 = time.monotonic()
         for attempt in range(1, max_attempts + 1):
             try:
@@ -190,32 +188,6 @@ def init_distributed(
     index, count = jax.process_index(), jax.process_count()
     flog.set_rank(index, count)  # pin the log tag to the real rank
     return index, count
-
-
-def _enable_cpu_collectives() -> None:
-    """Select a cross-process collectives backend for multi-process CPU
-    meshes (the virtual-pod dev/test path; TPU pods use ICI and never get
-    here). XLA:CPU defaults to no collectives implementation and raises
-    "Multiprocess computations aren't implemented on the CPU backend" at
-    first cross-process dispatch, so pick gloo when this jaxlib ships it.
-    Must run before the CPU backend is created; an explicit user setting
-    wins."""
-    platforms = jax.config.jax_platforms or os.environ.get(
-        "JAX_PLATFORMS", ""
-    )
-    if "cpu" not in str(platforms).split(","):
-        return
-    current = getattr(jax.config, "jax_cpu_collectives_implementation", None)
-    if current not in (None, "none"):
-        return
-    try:
-        from jax._src.lib import xla_client
-
-        if not hasattr(xla_client._xla, "make_gloo_tcp_collectives"):
-            return
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover — best effort on exotic builds
-        return
 
 
 def host_barrier(mesh=None, tag: int = 0) -> int:

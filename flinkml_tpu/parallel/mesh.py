@@ -16,7 +16,6 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Sequence, Tuple
 
-import flinkml_tpu._jax_compat  # noqa: F401  (jax version shims; install before first jax use)
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

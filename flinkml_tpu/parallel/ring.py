@@ -24,7 +24,6 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
-import flinkml_tpu._jax_compat  # noqa: F401  (jax version shims; install before first jax use)
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P

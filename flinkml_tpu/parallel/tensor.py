@@ -27,7 +27,6 @@ import functools
 import math
 from typing import Callable, Optional
 
-import flinkml_tpu._jax_compat  # noqa: F401  (jax version shims; install before first jax use)
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P

@@ -45,7 +45,7 @@ DCE'd program for just that column through the same compile cache. Typical
 inference (read the prediction column only) therefore costs one program
 that computes nothing it doesn't need.
 
-Precision: programs trace and execute under ``jax.experimental.enable_x64``
+Precision: programs trace and execute under ``jax.enable_x64``
 so kernels reproduce each stage's host-path dtypes exactly (scalers run in
 float64 like their numpy transform; predict kernels capture the *ambient*
 x64 flag at kernel-build time and cast to the same dtypes ``jnp.asarray``
@@ -557,7 +557,7 @@ def _chain_support_checked(kernels, ext_names, out_names, bucket, policy,
             "supported by the pallas chain backend",
         )
 
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         reason = _pchain.unsupported_reason(
             kernels, ext_names, out_names, bucket, policy,
             ext_vals, const_vals, _gate.interpret_mode(),
@@ -684,7 +684,7 @@ def _run_program(kernels, ext_names, out_names, ext_specs, const_specs,
         # Validation ALWAYS walks the XLA-reference chain — the Pallas
         # backend runs the same kernel fns, and the FML6xx jaxpr walker
         # must see their math, not an opaque pallas_call.
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             _validate_chain(
                 _chain_fn(kernels, ext_names, out_names, bucket, policy),
                 ext_vals, const_vals, kernels, policy,
@@ -692,7 +692,7 @@ def _run_program(kernels, ext_names, out_names, ext_specs, const_specs,
     compiled = False
     if program is None and store is not None:
         def _build():
-            with jax.experimental.enable_x64(True):
+            with jax.enable_x64(True):
                 return jax.jit(
                     _build_chain(kernels, ext_names, out_names, bucket,
                                  policy, backend)
@@ -724,7 +724,7 @@ def _run_program(kernels, ext_names, out_names, ext_specs, const_specs,
             hook(key)
     else:
         group.counter("cache_hits")
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         return program(
             tuple(ext_vals), const_vals, np.int32(n)
         )
@@ -773,7 +773,7 @@ def execute_kernel_chain(table: Table, kernels: Sequence[ColumnKernel]) -> Table
     eager_names = list(_closure_outputs(kernels, terminal))
     lazy_names = [c for c in out_names if c not in eager_names]
 
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         ext_vals = []
         ext_specs = []
         for name in ext:

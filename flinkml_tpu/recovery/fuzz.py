@@ -526,7 +526,7 @@ def run_worker_schedule(plan: "faults_mod.FaultPlan", golden: GoldenCache,
                 "x64": bool(jax.config.jax_enable_x64),
             }))
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"  # the soak child is a CPU process
         env["PYTHONPATH"] = os.pathsep.join(
             x for x in (repo_root, env.get("PYTHONPATH")) if x
         )

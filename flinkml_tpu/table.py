@@ -365,7 +365,7 @@ class Table:
             # stays float64 even when the ambient x64 flag is off): the
             # fused executor's bit-parity contract depends on the device
             # copy being the same bits as the host column.
-            with jax.experimental.enable_x64(True):
+            with jax.enable_x64(True):
                 self._device_cache[name] = jnp.asarray(col)
         return self._device_cache[name]
 
@@ -406,7 +406,7 @@ class Table:
 
                 buf = np.zeros((int(rows),) + raw.shape[1:], raw.dtype)
                 buf[:raw.shape[0]] = raw
-                with jax.experimental.enable_x64(True):
+                with jax.enable_x64(True):
                     self._device_cache[key] = jnp.asarray(buf)
             else:
                 import jax
@@ -415,7 +415,7 @@ class Table:
                 arr = self.device_column(name)
                 pad = int(rows) - arr.shape[0]
                 if pad > 0:
-                    with jax.experimental.enable_x64(True):
+                    with jax.enable_x64(True):
                         arr = jnp.concatenate(
                             [arr, jnp.zeros((pad,) + arr.shape[1:], arr.dtype)]
                         )

@@ -25,7 +25,6 @@ def main() -> None:
         ).strip()
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)
 
     import numpy as np

@@ -67,7 +67,6 @@ def main() -> None:
         ).strip()
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)  # match the suite semantics
 
     import numpy as np

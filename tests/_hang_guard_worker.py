@@ -30,8 +30,6 @@ port, pid, nproc, workdir = (
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 from flinkml_tpu.iteration.checkpoint import CheckpointManager  # noqa: E402

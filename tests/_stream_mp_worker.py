@@ -22,8 +22,6 @@ port, pid, nproc, workdir = (
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))

@@ -20,8 +20,6 @@ port, pid, nproc = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402

@@ -157,8 +157,12 @@ def test_closed_loop_load_triple_recovers_p99_without_operator():
     unbounded per-(rows,bucket) pad-compile bug this PR fixed in
     ``Table.device_column_padded`` degraded exactly this scenario >10x.
     (The zero-compile half of the acceptance runs in the clean child
-    process above — the suite conftest's jax pcache forces in-process
-    scale-ups to degrade to compile-only.)"""
+    process above.)
+
+    KNOWN FAILING since PR 21 (CHANGES.md): in-suite scale-ups now load
+    the shared AOT artifact instead of compiling in-process, so the
+    spike window lost its compile stall (p99 7.6-13.8 ms) and the three
+    GIL-sharing replicas sit at 2.1-3.4x of it. The 2x bound is kept."""
     x, y = _data()
     pm = _chain(x, y)
     pool = _pool(pm, x, n_replicas=1, name="loop_pool",

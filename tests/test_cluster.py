@@ -417,11 +417,6 @@ def _patch_rendezvous(monkeypatch):
 
     monkeypatch.setattr(jax.distributed, "initialize", fake_initialize)
     monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
-    # With a FAKE rendezvous there is no distributed client, so the gloo
-    # collectives pick would poison the first real backend init.
-    import flinkml_tpu.parallel.distributed as dist
-
-    monkeypatch.setattr(dist, "_enable_cpu_collectives", lambda: None)
     return calls
 
 
@@ -468,6 +463,25 @@ def test_init_distributed_explicit_args_beat_env(monkeypatch):
         "coordinator_address": "10.2.2.2:2222",
         "num_processes": 2, "process_id": 0,
     }]
+
+
+def test_process_runtime_refuses_a_non_cpu_parent(monkeypatch):
+    """The launchers start virtual-CPU-device children; under a parent
+    whose backend is a TPU they would quietly serve from CPU workers, so
+    ClusterPool and ElasticProcessWorld refuse with a typed error that
+    points at the in-process ReplicaPool."""
+    from flinkml_tpu.cluster import (
+        ClusterPool,
+        ElasticProcessWorld,
+        ProcessRuntimeBackendError,
+    )
+    from flinkml_tpu.table import Table
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ProcessRuntimeBackendError, match="ReplicaPool"):
+        ClusterPool(object(), Table({"features": np.zeros((2, 2))}))
+    with pytest.raises(ProcessRuntimeBackendError, match="'tpu'"):
+        ElasticProcessWorld(lambda rank, world, rnd: ["true"])
 
 
 def test_rendezvous_env_exports_the_family():
@@ -579,6 +593,9 @@ def test_cluster_metrics_published(cluster_child_report):
     assert rep["spawn_ms_samples"] == 3, rep  # 2 initial + 1 respawn
 
 
+# slow (PR 21): a process-spawning case of 20-30 s; tier-1's 870 s limit is
+# tight with a cold compile cache. tools/ci.sh's full suite still runs it.
+@pytest.mark.slow
 def test_elastic_world_shrinks_and_resumes_bit_exact(tmp_path):
     """World size = PROCESS count: a 2-process world loses its highest
     rank to a WorkerCrash, the supervisor relaunches the survivor as

@@ -220,25 +220,6 @@ def test_memory_store_shares_within_process():
     assert store.entry_path(("k",)) is None
 
 
-def test_serialization_unsupported_degrades(tmp_path, monkeypatch):
-    """With the AOT serialization API unavailable the store degrades to
-    compile-only: same results, nothing persisted, loud counter."""
-    from flinkml_tpu.compile_cache import store as store_mod
-
-    monkeypatch.setattr(store_mod, "_SUPPORT", [False])
-    monkeypatch.setattr(store_mod, "_WARNED_UNSUPPORTED", [False])
-    scaler, t = _fitted_mini_chain()
-    compile_cache.configure(str(tmp_path))
-    pipeline_fusion.reset_cache()
-    (out,) = scaler.transform(t)
-    assert out.column("scaled") is not None
-    assert not [f for _, _, fs in os.walk(tmp_path)
-                for f in fs if f.endswith(".aot")]
-    assert metrics.group("compile_cache").snapshot()["counters"].get(
-        "fallbacks", 0
-    ) > 0
-
-
 def test_stable_key_repr_and_hash():
     from flinkml_tpu.precision import resolve_policy
     from flinkml_tpu.sharding.plan import FSDP, FSDP_TP

@@ -209,6 +209,9 @@ def test_two_process_streamed_fit(tmp_path):
     _streamed_fit_check(tmp_path, nproc=2, local_devices=2)
 
 
+# slow (PR 21): a process-spawning case of 20-30 s; tier-1's 870 s limit is
+# tight with a cold compile cache. tools/ci.sh's full suite still runs it.
+@pytest.mark.slow
 def test_four_process_streamed_fit(tmp_path):
     """The same full streamed/online catalog on a 4-process pod: the
     agreement layer (schedules, vocab unions, pooled init, failure
@@ -361,7 +364,9 @@ def _launch_multiprocess_workers(
     # Workers share the suite's persistent XLA cache: repeat runs (and
     # retries) skip recompiling the cross-process programs, which
     # otherwise dominate these tests' wall clock.
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_test_cache")
+    from flinkml_tpu.utils import jax_cache
+
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", jax_cache.cache_dir())
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
     def attempt(workdir):

@@ -18,7 +18,9 @@ def _run_example(name, args, token):
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
     # Share the suite's persistent XLA cache (see test_distributed.py).
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_test_cache")
+    from flinkml_tpu.utils import jax_cache
+
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", jax_cache.cache_dir())
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     out = subprocess.run(
         [sys.executable, example, *args],

@@ -357,7 +357,7 @@ def test_chain_refuses_weak_typed_constant():
     through strong-typed Pallas refs — refused, never silently wrong."""
     from flinkml_tpu.api import ColumnKernel
 
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         weak = jnp.asarray(2.0)   # weak float
         assert weak.weak_type
         k = ColumnKernel(

@@ -32,6 +32,39 @@ def test_native_parser_compiles():
     assert _load_native() is not None, "g++ compile of native parser failed"
 
 
+def test_native_artifact_is_named_by_source_hash(monkeypatch, caplog):
+    """Only a library built from exactly the committed source (and
+    compile command) can load: the artifact name carries their hash, a
+    stale ``<name>.so`` in the build dir is never looked at, and a build
+    that fails falls back to Python with ONE warning."""
+    import logging
+
+    from flinkml_tpu.io import _native
+
+    so = _native.artifact_path("libsvm_parser")
+    assert os.path.basename(so).startswith("libsvm_parser-")
+    assert os.path.basename(so) != "libsvm_parser.so"
+    stale = os.path.join(os.path.dirname(so), "libsvm_parser.so")
+    with open(stale, "wb") as fh:
+        fh.write(b"not a shared object")
+    try:
+        monkeypatch.setattr(_native, "_cache", {})
+        assert _native.compile_and_load("libsvm_parser", lambda lib: None)
+    finally:
+        os.remove(stale)
+    # another compile command names another artifact ...
+    monkeypatch.setattr(_native, "_COMPILE", ("false",))
+    other = _native.artifact_path("libsvm_parser")
+    assert other != so and not os.path.exists(other)
+    # ... and when that build fails the fallback is logged, once.
+    monkeypatch.setattr(_native, "_cache", {})
+    with caplog.at_level(logging.WARNING, logger="flinkml_tpu.io.native"):
+        assert _native.compile_and_load("libsvm_parser", lambda lib: None) is None
+        assert _native.compile_and_load("libsvm_parser", lambda lib: None) is None
+    warned = [r for r in caplog.records if "pure-Python parser" in r.getMessage()]
+    assert len(warned) == 1
+
+
 @pytest.mark.parametrize("use_native", [True, False])
 def test_against_sklearn_golden(svm_file, use_native):
     from sklearn.datasets import load_svmlight_file

@@ -41,22 +41,17 @@ from flinkml_tpu.table import Table
 
 
 @pytest.fixture(scope="module", autouse=True)
-def _fresh_compile_cache(tmp_path_factory):
+def _fresh_compile_cache():
     """Bit-parity assertions require every compared program to be compiled
     in THIS session: XLA's persistent compilation cache can serve a binary
     compiled under an earlier session whose codegen conditions differed,
     and two such binaries for the same HLO may disagree by 1 ulp in
-    transcendental lowering (observed on sigmoid). A fresh cache dir for
-    this module keeps both sides of every comparison same-session."""
-    import jax
+    transcendental lowering (observed on sigmoid). The cache is suspended
+    for this module so both sides of every comparison are same-session."""
+    from flinkml_tpu.utils import jax_cache
 
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        str(tmp_path_factory.mktemp("fusion_xla_cache")),
-    )
-    yield
-    jax.config.update("jax_compilation_cache_dir", old)
+    with jax_cache.suspended():
+        yield
 
 
 @pytest.fixture(autouse=True)

@@ -730,7 +730,7 @@ def _promoting_kernel(in_col="a", out_col="b"):
 
 def test_promotion_findings_localize_widening_site():
     k = _promoting_kernel()
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(k.fn)(
             {"a": jax.ShapeDtypeStruct((8,), np.float32)}, {},
             jax.ShapeDtypeStruct((8,), np.float32),

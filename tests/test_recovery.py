@@ -900,6 +900,9 @@ def test_chaos_soak_small_budget_green():
     assert len(report.results) == 8
 
 
+# slow (PR 21): a process-spawning case of 20-30 s; tier-1's 870 s limit is
+# tight with a cold compile cache. tools/ci.sh's full suite still runs it.
+@pytest.mark.slow
 def test_worker_soak_restarts_across_process_boundary():
     """The ``cluster.worker`` seam in the soak: schedules draw REAL
     ``os._exit`` worker crashes, each trainer incarnation is a child

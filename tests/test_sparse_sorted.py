@@ -18,7 +18,7 @@ from flinkml_tpu.kernels import ENV_VAR, KernelUnsupportedError
 from flinkml_tpu.kernels import segsum as _segsum
 
 # The package re-exports the spmv DISPATCHER under the submodule's
-# name; import the module itself for ROW_TILE / MAX_COMPILED_DIM.
+# name; import the module itself for ROW_TILE / NO_TPU_LOWERING.
 _spmv = importlib.import_module("flinkml_tpu.kernels.spmv")
 from flinkml_tpu.linalg import SparseVector
 from flinkml_tpu.table import SortedSparseColumn, Table
@@ -96,13 +96,13 @@ def test_segsum_multiblock_ragged_tail_parity():
 
 def test_segsum_output_ceiling_refusal_names_constant(monkeypatch):
     """The ONLY remaining compiled-path ceiling is the OUTPUT block
-    (num_segments * k): an explicit pallas request above it refuses
-    typed, naming MAX_COMPILED_CELLS — through the dispatcher AND the
-    direct kernel entry point."""
+    ([num_segments, k] padded to 128 lanes per row): an explicit pallas
+    request above it refuses typed, naming MAX_COMPILED_CELLS — through
+    the dispatcher AND the direct kernel entry point."""
     monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")
     vals = jnp.ones(8, jnp.float32)
     ids = jnp.zeros(8, jnp.int32)
-    over = _segsum.MAX_COMPILED_CELLS + 1
+    over = _segsum.MAX_COMPILED_CELLS // 128 + 8
     with pytest.raises(KernelUnsupportedError, match="MAX_COMPILED_CELLS"):
         kernels.segment_sum(vals, ids, over, backend="pallas")
     with pytest.raises(KernelUnsupportedError, match="MAX_COMPILED_CELLS"):
@@ -120,11 +120,17 @@ def test_segsum_exchange_shape_above_old_ceiling_accepted_compiled():
     vals = jax.ShapeDtypeStruct((cells, k), jnp.float32)
     ids = jax.ShapeDtypeStruct((cells,), jnp.int32)
     assert cells * k > _segsum.MAX_COMPILED_CELLS
+    assert _segsum.padded_cells(shard_rows, k) == _segsum.MAX_COMPILED_CELLS
     assert _segsum.unsupported_reason(
         vals, ids, shard_rows, interpret=False) is None
-    # the output ceiling still applies to the same shape:
+    # the output ceiling still applies to the same shape (one more
+    # sublane tile of rows):
     assert "MAX_COMPILED_CELLS" in _segsum.unsupported_reason(
-        vals, ids, (_segsum.MAX_COMPILED_CELLS // k) + 1, interpret=False)
+        vals, ids, shard_rows + 8, interpret=False)
+    # the sparse trainers' own shape (k = 1, dim 1e6) is refused by name:
+    flat = jax.ShapeDtypeStruct((cells,), jnp.float32)
+    assert "488 MiB" in _segsum.unsupported_reason(
+        flat, ids, 1_000_000, interpret=False)
 
 
 # -- CSR SpMV ---------------------------------------------------------------
@@ -158,12 +164,15 @@ def test_spmv_refusals(monkeypatch):
                      jnp.ones(8, jnp.int32), backend="pallas")
     with pytest.raises(KernelUnsupportedError, match="!= w dtype"):
         kernels.spmv(ib, vb, jnp.ones(8, jnp.float64), backend="pallas")
-    # the one-block weight ceiling holds on the compiled path only,
-    # named after its constant (checked abstractly — no 32 MB alloc).
-    big_w = jax.ShapeDtypeStruct((_spmv.MAX_COMPILED_DIM + 1,), jnp.float32)
-    reason = _spmv.unsupported_reason(ib, vb, big_w, interpret=False)
-    assert reason is not None and "MAX_COMPILED_DIM" in reason
-    assert _spmv.unsupported_reason(ib, vb, big_w, interpret=True) is None
+    # there is no compiled path at any shape: the refusal names what
+    # Mosaic said about the in-kernel gather; the interpreter accepts.
+    w = jnp.ones(8, jnp.float32)
+    reason = _spmv.unsupported_reason(ib, vb, w, interpret=False)
+    assert reason == _spmv.NO_TPU_LOWERING and "2D gather" in reason
+    assert _spmv.unsupported_reason(ib, vb, w, interpret=True) is None
+    monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")
+    with pytest.raises(KernelUnsupportedError, match="does not compile"):
+        kernels.spmv(ib, vb, w, backend="pallas")
 
 
 def test_spmv_gate_threaded_vs_explicit(tmp_path, monkeypatch):
@@ -180,7 +189,7 @@ def test_spmv_gate_threaded_vs_explicit(tmp_path, monkeypatch):
     path = str(tmp_path / "table.json")
     table.save(path)
     monkeypatch.setenv(ENV_TABLE_VAR, path)
-    monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")  # f64 unsupported
+    monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")  # compiled: refused
     rng = np.random.default_rng(4)
     ib = jnp.asarray(rng.integers(0, 32, (4, 3)), jnp.int32)
     vb = jnp.asarray(rng.normal(size=(4, 3)))            # float64
@@ -191,7 +200,12 @@ def test_spmv_gate_threaded_vs_explicit(tmp_path, monkeypatch):
     ref = jax.jit(
         lambda i, v, ww: jnp.sum(v * jnp.take(ww, i, axis=0), axis=1)
     )(ib, vb, w)
-    out = kernels.spmv(ib, vb, w, backend=threaded)      # degrades
+    # Jitted like the reference (and like every product call site):
+    # the degraded XLA expression run EAGERLY reduces unfused and
+    # differs from the fused program in the last bit on this XLA:CPU.
+    out = jax.jit(
+        lambda i, v, ww: kernels.spmv(i, v, ww, backend=threaded)
+    )(ib, vb, w)                                         # degrades
     assert np.asarray(ref).tobytes() == np.asarray(out).tobytes()
     monkeypatch.setenv(ENV_VAR, "spmv=xla")              # gate says xla
     with pytest.raises(KernelUnsupportedError):
@@ -272,11 +286,16 @@ def test_prefetcher_sorted_columns_zero_retraces_across_buckets():
     drive(coef)              # guarded replay: zero new compiles
 
 
+_STREAM_HYPER = dict(loss="logistic", max_iter=4, learning_rate=0.5,
+                     reg=1e-3, elastic_net=0.3, tol=0.0)
+
+
 def test_sorted_stream_fit_bitwise_matches_csr_stream():
     """End-to-end acceptance: the sorted-column stream (device Tables
     from pad_place_table, zero densify / zero step-time sort) produces
     the BIT-IDENTICAL model to the CSR stream reference over a
-    multi-epoch weighted elastic-net logistic fit."""
+    multi-epoch weighted elastic-net logistic fit, for batches both
+    paths pad to the same row count (ragged batches: the next test)."""
     from flinkml_tpu.data.prefetch import pad_place_table
     from flinkml_tpu.models._linear_sgd import (
         streamed_linear_fit,
@@ -286,30 +305,81 @@ def test_sorted_stream_fit_bitwise_matches_csr_stream():
 
     rng = np.random.default_rng(7)
     dim, nnz = 512, 8
-    tabs = [_sparse_table(rng, rows, dim, nnz) for rows in (24, 48, 33)]
-    hyper = dict(loss="logistic", max_iter=4, learning_rate=0.5, reg=1e-3,
-                 elastic_net=0.3, tol=0.0)
+    tabs = [_sparse_table(rng, rows, dim, nnz) for rows in (32, 64, 16)]
     # The contract is at the pipeline's f32 dtype on a single-device
     # reference mesh: the conftest's global x64 flag and 8-device psum
     # order would each perturb the CSR reference in the last bit.
     mesh1 = DeviceMesh(devices=jax.devices()[:1])
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         ref = streamed_linear_fit(
             list(tabs), features_col="features", label_col="y",
-            weight_col="w", mesh=mesh1, **hyper,
+            weight_col="w", mesh=mesh1, **_STREAM_HYPER,
         )
         dev = [pad_place_table(t) for t in tabs]
         got = train_linear_model_sorted_stream(dev, "features", "y", "w",
-                                               **hyper)
+                                               **_STREAM_HYPER)
         assert np.asarray(ref, np.float32).tobytes() == \
             np.asarray(got, np.float32).tobytes()
         # routing: streamed_linear_fit recognizes the device tables too.
         routed = streamed_linear_fit(
             [t for t in dev], features_col="features", label_col="y",
-            weight_col="w", mesh=mesh1, **hyper,
+            weight_col="w", mesh=mesh1, **_STREAM_HYPER,
         )
         assert np.asarray(routed, np.float32).tobytes() == \
             np.asarray(got, np.float32).tobytes()
+
+
+def test_sorted_stream_ragged_batches_differ_by_row_padding_only():
+    """Ragged batches (24/48/33 rows): the CSR stream pads rows to a
+    multiple of 8 per device (24/48/40), the sorted column to its
+    power-of-two bucket (32/64/64). On the installed XLA:CPU a 1-D sum
+    is a tree (reduce-window + reduce in the optimized HLO), so a longer
+    zero tail re-associates ``sum(w)`` and the loss sum in the last bit,
+    and ``step = lr / sum(w)`` carries that into every coefficient. Both
+    halves are pinned: the two step PROGRAMS are bit-equal at every step
+    on the same padded block, and the end-to-end fits agree to 2 ulp of
+    the largest coefficient per step."""
+    from flinkml_tpu.data.prefetch import pad_place_table
+    from flinkml_tpu.models import _linear_sgd as sgd
+    from flinkml_tpu.parallel import DeviceMesh
+
+    rng = np.random.default_rng(7)
+    dim, nnz = 512, 8
+    tabs = [_sparse_table(rng, rows, dim, nnz) for rows in (24, 48, 33)]
+    mesh1 = DeviceMesh(devices=jax.devices()[:1])
+    with jax.enable_x64(False):
+        dev = [pad_place_table(t) for t in tabs]
+        f32 = jnp.float32
+        hy = (jnp.asarray(0.5, f32), jnp.asarray(1e-3 * 0.7, f32),
+              jnp.asarray(1e-3 * 0.3, f32))
+        csr_step = sgd._sparse_stream_stepper(
+            mesh1.mesh, "logistic", DeviceMesh.DATA_AXIS, dim)
+        sorted_step = sgd._sorted_column_stepper("logistic", dim)
+        coef = jnp.zeros(dim, f32)
+        for t in dev * 2:
+            col = t._raw_column("features")
+            yb = t._raw_column("y").buf.astype(f32)
+            wb = t._raw_column("w").buf.astype(f32)
+            masked = jnp.where(jnp.arange(wb.shape[0]) < col.rows, wb, 0)
+            a = csr_step(coef, col.indices, col.buf, yb, masked, *hy)
+            b = sorted_step(coef, col.indices, col.buf, col.perm,
+                            col.segment_ids, yb, wb,
+                            jnp.asarray(col.rows, jnp.int32), *hy)
+            for x, y in zip(a, b):      # new coef, loss sum, weight sum
+                assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+            coef = a[0]
+
+        ref = sgd.streamed_linear_fit(
+            list(tabs), features_col="features", label_col="y",
+            weight_col="w", mesh=mesh1, **_STREAM_HYPER,
+        )
+        got = sgd.train_linear_model_sorted_stream(
+            dev, "features", "y", "w", **_STREAM_HYPER)
+    steps = _STREAM_HYPER["max_iter"] * len(tabs)
+    ulp = float(np.spacing(np.abs(np.asarray(ref, np.float32)).max()))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=0, atol=2 * steps * ulp)
 
 
 def test_sorted_stream_refuses_checkpointing():

@@ -16,7 +16,6 @@ import time
 
 import numpy as np
 
-from flinkml_tpu.utils.device_lock import device_client_lock
 
 N_USERS, N_ITEMS, NNZ, RANK, ITERS = 16_384, 16_384, 1 << 21, 32, 10
 
@@ -53,5 +52,4 @@ def main():
 
 
 if __name__ == "__main__":
-    with device_client_lock():
-        main()
+    main()

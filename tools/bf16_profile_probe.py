@@ -21,7 +21,6 @@ import time
 
 import numpy as np
 
-from flinkml_tpu.utils.device_lock import device_client_lock
 
 N, BS, STEPS = 1_000_000, 262_144, 200
 
@@ -115,5 +114,4 @@ def main():
 
 
 if __name__ == "__main__":
-    with device_client_lock():
-        main()
+    main()

@@ -17,7 +17,6 @@ import time
 
 import numpy as np
 
-from flinkml_tpu.utils.device_lock import device_client_lock
 
 N, D, BINS, DEPTH, TREES = 262_144, 16, 32, 4, 20
 
@@ -68,5 +67,4 @@ def main():
 
 
 if __name__ == "__main__":
-    with device_client_lock():
-        main()
+    main()

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flinkml_tpu.utils.device_lock import device_client_lock
 
 n_cells, dim, steps = 262_144 * 39, 1_000_000, 20
 rng = np.random.default_rng(0)
@@ -55,5 +54,4 @@ def main():
 
 
 if __name__ == "__main__":
-    with device_client_lock():
-        main()
+    main()

@@ -21,7 +21,6 @@ import time
 
 import numpy as np
 
-from flinkml_tpu.utils.device_lock import device_client_lock
 
 N, NNZ, DIM, STEPS = 262_144, 39, 1_000_000, 50
 
@@ -86,5 +85,4 @@ def main():
 
 
 if __name__ == "__main__":
-    with device_client_lock():
-        main()
+    main()

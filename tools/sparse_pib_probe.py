@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flinkml_tpu.utils.device_lock import device_client_lock
 
 n_rows, nnz, dim, steps = 262_144, 39, 1_000_000, 20
 rng = np.random.default_rng(0)
@@ -66,5 +65,4 @@ def main():
 
 
 if __name__ == "__main__":
-    with device_client_lock():
-        main()
+    main()

@@ -22,7 +22,6 @@ import time
 
 import numpy as np
 
-from flinkml_tpu.utils.device_lock import device_client_lock
 
 VOCAB, DIM, BS, N_NEG, STEPS = 32_768, 128, 8_192, 5, 100
 
@@ -67,5 +66,4 @@ def main():
 
 
 if __name__ == "__main__":
-    with device_client_lock():
-        main()
+    main()
