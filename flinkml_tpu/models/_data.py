@@ -14,6 +14,7 @@ import numpy as np
 
 from flinkml_tpu.linalg import SparseVector, Vector, stack_vectors
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.profiling import span
 
 
 def features_matrix(
@@ -46,16 +47,17 @@ def labeled_data(
     weight_col: Optional[str] = None,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Extract (X [n,d], y [n], w [n]); weight defaults to 1.0 per row."""
-    x = features_matrix(table, features_col)
-    y = np.asarray(table.column(label_col), dtype=np.float64).reshape(-1)
-    if y.shape[0] != x.shape[0]:
-        raise ValueError(
-            f"label column {label_col!r} has {y.shape[0]} rows, features have {x.shape[0]}"
-        )
-    if weight_col is not None:
-        w = np.asarray(table.column(weight_col), dtype=np.float64).reshape(-1)
-    else:
-        w = np.ones(x.shape[0], dtype=np.float64)
+    with span("hostdata.ingest"):
+        x = features_matrix(table, features_col)
+        y = np.asarray(table.column(label_col), dtype=np.float64).reshape(-1)
+        if y.shape[0] != x.shape[0]:
+            raise ValueError(
+                f"label column {label_col!r} has {y.shape[0]} rows, features have {x.shape[0]}"
+            )
+        if weight_col is not None:
+            w = np.asarray(table.column(weight_col), dtype=np.float64).reshape(-1)
+        else:
+            w = np.ones(x.shape[0], dtype=np.float64)
     return x, y, w
 
 

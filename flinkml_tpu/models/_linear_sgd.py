@@ -38,6 +38,7 @@ from jax.sharding import PartitionSpec as P
 from flinkml_tpu.ops.losses import margin_terms as _margin_grad
 from flinkml_tpu.ops.sparse import chunked_run_totals
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
+from flinkml_tpu.utils.profiling import span
 
 _LOSS_KEYS = ("logistic", "hinge", "squared")
 
@@ -514,26 +515,31 @@ def _run_chunked(
         jnp.asarray(reg_l1, dt),
         jnp.asarray(tol, dt),
     )
-    while epoch < max_iter and cur_loss > tol:
-        epoch_end = min(epoch + chunk, max_iter)
-        coef, ep_dev, loss_dev = trainer(
-            coef, jnp.asarray(epoch, jnp.int32), jnp.asarray(cur_loss, dt),
-            *data_args, *hy, jnp.asarray(epoch_end, jnp.int32),
-        )
-        epoch = int(ep_dev)
-        cur_loss = float(loss_dev)
-        coef_host = np.asarray(coef)
+    with span("trainer.loop"):
+        while epoch < max_iter and cur_loss > tol:
+            epoch_end = min(epoch + chunk, max_iter)
+            coef, ep_dev, loss_dev = trainer(
+                coef, jnp.asarray(epoch, jnp.int32),
+                jnp.asarray(cur_loss, dt),
+                *data_args, *hy, jnp.asarray(epoch_end, jnp.int32),
+            )
+            epoch = int(ep_dev)
+            cur_loss = float(loss_dev)
+            coef_host = np.asarray(coef)
+            if checkpoint_manager is not None:
+                checkpoint_manager.save(
+                    (coef_host, np.float64(cur_loss)), epoch
+                )
+            for listener in listeners:
+                listener.on_epoch_watermark_incremented(epoch - 1, coef_host)
+    with span("trainer.readback"):
+        result = np.asarray(coef)
         if checkpoint_manager is not None:
-            checkpoint_manager.save((coef_host, np.float64(cur_loss)), epoch)
+            # Drain any in-flight async write so a failed final snapshot
+            # surfaces here, not silently at interpreter exit.
+            checkpoint_manager.wait()
         for listener in listeners:
-            listener.on_epoch_watermark_incremented(epoch - 1, coef_host)
-    result = np.asarray(coef)
-    if checkpoint_manager is not None:
-        # Drain any in-flight async write so a failed final snapshot
-        # surfaces here, not silently at interpreter exit.
-        checkpoint_manager.wait()
-    for listener in listeners:
-        listener.on_iteration_terminated(result)
+            listener.on_iteration_terminated(result)
     return result
 
 
@@ -622,14 +628,15 @@ def train_linear_model(
             checkpoint_interval=checkpoint_interval, resume=resume,
         )
     p_size = mesh.axis_size()
-    if dtype is not None:
-        x, y, w = x.astype(dtype), y.astype(dtype), w.astype(dtype)
-    perm = np.random.default_rng(seed).permutation(n)
-    x, y, w = x[perm], y[perm], w[perm]
-    row_tile = p_size  # pad exactly to the mesh: identical windows always
-    x_pad, _ = pad_to_multiple(x, row_tile)
-    y_pad, _ = pad_to_multiple(y, row_tile)
-    w_pad, _ = pad_to_multiple(w, row_tile)
+    with span("hostdata.shuffle"):
+        if dtype is not None:
+            x, y, w = x.astype(dtype), y.astype(dtype), w.astype(dtype)
+        perm = np.random.default_rng(seed).permutation(n)
+        x, y, w = x[perm], y[perm], w[perm]
+        row_tile = p_size  # pad exactly to the mesh: identical windows always
+        x_pad, _ = pad_to_multiple(x, row_tile)
+        y_pad, _ = pad_to_multiple(y, row_tile)
+        w_pad, _ = pad_to_multiple(w, row_tile)
     xd = mesh.shard_batch(x_pad)
     yd = mesh.shard_batch(y_pad)
     wd = mesh.shard_batch(w_pad)

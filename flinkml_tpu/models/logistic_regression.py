@@ -64,6 +64,7 @@ from flinkml_tpu.models._data import (
 )
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.profiling import span
 
 
 class _LogisticRegressionParams(
@@ -104,10 +105,14 @@ class LogisticRegression(StreamingEstimatorMixin, _LogisticRegressionParams, Est
 
     def fit(self, *inputs) -> "LogisticRegressionModel":
         (table,) = inputs
-        multi_class = self.get(_LogisticRegressionParams.MULTI_CLASS)
-        features_col = self.get(_LogisticRegressionParams.FEATURES_COL)
         if not isinstance(table, Table):
             return self._fit_stream(table)
+        with span("fit"):
+            return self._fit_table(table)
+
+    def _fit_table(self, table: Table) -> "LogisticRegressionModel":
+        multi_class = self.get(_LogisticRegressionParams.MULTI_CLASS)
+        features_col = self.get(_LogisticRegressionParams.FEATURES_COL)
         hyper = dict(
             mesh=self.mesh or DeviceMesh(),
             max_iter=self.get(_LogisticRegressionParams.MAX_ITER),

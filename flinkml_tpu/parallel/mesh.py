@@ -20,6 +20,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from flinkml_tpu.utils.profiling import span
+
 
 class DeviceMesh:
     """A named device mesh plus sharding conveniences.
@@ -156,7 +158,13 @@ class DeviceMesh:
                 f"batch dimension {array.shape[0]} not divisible by data-axis "
                 f"size {n}; pad with pad_to_multiple first"
             )
-        return jax.device_put(array, self.data_sharding())
+        with span("mesh.shard_batch") as phase:
+            placed = jax.device_put(array, self.data_sharding())
+            # The placed array's bytes, not the host array's: device_put
+            # narrows float64 to float32 on the host where x64 is off,
+            # and returns before the bytes have landed on the device.
+            phase.add(bytes=placed.nbytes)
+        return placed
 
     def replicate(self, tree):
         """Replicate a pytree of arrays onto every device (broadcast-model)."""

@@ -32,6 +32,7 @@ from typing import List, Sequence, Tuple
 from flinkml_tpu.api import AlgoOperator, Estimator, Model, Stage
 from flinkml_tpu.io import read_write
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.profiling import span
 
 
 class Pipeline(Estimator):
@@ -108,6 +109,10 @@ class PipelineModel(Model):
         return list(self._stages)
 
     def transform(self, *inputs: Table) -> Tuple[Table, ...]:
+        with span("transform"):
+            return self._transform(inputs)
+
+    def _transform(self, inputs: Tuple[Table, ...]) -> Tuple[Table, ...]:
         from flinkml_tpu import pipeline_fusion
 
         outputs: Tuple[Table, ...] = tuple(inputs)

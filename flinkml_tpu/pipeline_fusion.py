@@ -114,6 +114,7 @@ from flinkml_tpu.api import ColumnKernel
 from flinkml_tpu.linalg import next_pow2
 from flinkml_tpu.table import LazyDeviceColumn, PaddedDeviceColumn, Table
 from flinkml_tpu.utils.metrics import metrics
+from flinkml_tpu.utils.profiling import span
 
 #: Smallest row bucket: tiny tables all share one program.
 MIN_ROW_BUCKET = 8
@@ -836,10 +837,12 @@ def execute_kernel_chain(table: Table, kernels: Sequence[ColumnKernel]) -> Table
         # DIFFERENTLY inside the program (weak * f32 -> f32, strong * f32
         # -> f64), so two chains differing only there must not alias one
         # cached executable.
-        const_pairs = tuple(
-            tuple(_const_entry(c, k.constants[c]) for c in sorted(k.constants))
-            for k in kernels
-        )
+        with span("fusion.constants"):
+            const_pairs = tuple(
+                tuple(_const_entry(c, k.constants[c])
+                      for c in sorted(k.constants))
+                for k in kernels
+            )
         const_vals = tuple(tuple(v for v, _ in kc) for kc in const_pairs)
         const_specs = tuple(tuple(s for _, s in kc) for kc in const_pairs)
 
@@ -868,10 +871,11 @@ def execute_kernel_chain(table: Table, kernels: Sequence[ColumnKernel]) -> Table
             with _LOCK:
                 _CACHE[spec_key] = specs
 
-    outs = _run_program(
-        kernels, ext, eager_names, ext_specs, const_specs,
-        ext_vals, const_vals, bucket, n, policy,
-    )
+    with span("fusion.dispatch"):
+        outs = _run_program(
+            kernels, ext, eager_names, ext_specs, const_specs,
+            ext_vals, const_vals, bucket, n, policy,
+        )
 
     group.counter("fused_segments")
     group.counter("fused_stages", float(len(kernels)))

@@ -239,6 +239,13 @@ def _materialization_metrics():
     return metrics.group("table")
 
 
+def _span(name: str):
+    """A program span (lazy import, for the same reason)."""
+    from flinkml_tpu.utils.profiling import span
+
+    return span(name)
+
+
 class Table:
     """Immutable named-column container backed by host numpy arrays and/or
     device-resident ``jax.Array`` columns.
@@ -327,10 +334,11 @@ class Table:
         if not _is_device_backed(col):
             return col
         if name not in self._host_cache:
-            if isinstance(col, PaddedDeviceColumn):
-                host = col.to_host()
-            else:
-                host = np.asarray(col)
+            with _span("table.to_host"):
+                if isinstance(col, PaddedDeviceColumn):
+                    host = col.to_host()
+                else:
+                    host = np.asarray(col)
             group = _materialization_metrics()
             group.counter("device_to_host_materializations")
             group.counter("device_to_host_bytes", float(host.nbytes))
@@ -365,7 +373,7 @@ class Table:
             # stays float64 even when the ambient x64 flag is off): the
             # fused executor's bit-parity contract depends on the device
             # copy being the same bits as the host column.
-            with jax.enable_x64(True):
+            with _span("table.to_device"), jax.enable_x64(True):
                 self._device_cache[name] = jnp.asarray(col)
         return self._device_cache[name]
 
@@ -404,10 +412,11 @@ class Table:
                 import jax
                 import jax.numpy as jnp
 
-                buf = np.zeros((int(rows),) + raw.shape[1:], raw.dtype)
-                buf[:raw.shape[0]] = raw
-                with jax.enable_x64(True):
-                    self._device_cache[key] = jnp.asarray(buf)
+                with _span("table.to_device"):
+                    buf = np.zeros((int(rows),) + raw.shape[1:], raw.dtype)
+                    buf[:raw.shape[0]] = raw
+                    with jax.enable_x64(True):
+                        self._device_cache[key] = jnp.asarray(buf)
             else:
                 import jax
                 import jax.numpy as jnp

@@ -4,9 +4,9 @@ SURVEY.md §5: the reference has no bespoke observability subsystem — it
 re-registers Flink ``InternalOperatorMetricGroup``s per wrapped operator
 (``AbstractWrapperOperator.java:103``) and per-round ``LatencyStats``
 (``AbstractPerRoundWrapperOperator.java:106,500-553``), and leans on Flink
-metric reporters. The TPU equivalents live here: a metrics registry with
-per-step timers (:mod:`flinkml_tpu.utils.metrics`) and ``jax.profiler``
-integration (:mod:`flinkml_tpu.utils.profiling`).
+metric reporters. The TPU equivalents live here: a metrics registry
+(:mod:`flinkml_tpu.utils.metrics`) and the program's spans plus
+``jax.profiler`` capture (:mod:`flinkml_tpu.utils.profiling`).
 """
 
 from flinkml_tpu.utils.logging import enable_console, get_logger, rank_tag
@@ -19,11 +19,7 @@ from flinkml_tpu.utils.metrics import (
     metrics,
 )
 from flinkml_tpu.utils.preemption import ElasticResumePlan, PreemptionWatchdog
-from flinkml_tpu.utils.profiling import (
-    StepTimer,
-    annotate,
-    trace,
-)
+from flinkml_tpu.utils.profiling import span, trace
 
 __all__ = [
     "EpochMetricsListener",
@@ -32,8 +28,7 @@ __all__ = [
     "MetricsRegistry",
     "default_registry",
     "metrics",
-    "StepTimer",
-    "annotate",
+    "span",
     "trace",
     "enable_console",
     "get_logger",

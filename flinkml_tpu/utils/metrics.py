@@ -10,8 +10,8 @@ attach an :class:`EpochMetricsListener` to record epoch wall-times,
 criteria values, and throughput without touching the loop code.
 
 Everything is plain host-side Python — metrics never enter jitted code.
-Record values AFTER ``block_until_ready`` if you need device-accurate
-timing (see :class:`flinkml_tpu.utils.profiling.StepTimer`).
+Host phases are timed by :func:`flinkml_tpu.utils.profiling.span` (group
+``span``); device times come from a profile, not from the host clock.
 """
 
 from __future__ import annotations

@@ -1,22 +1,29 @@
-"""Tracing / profiling: ``jax.profiler`` integration + device-accurate timers.
+"""Tracing: the program's one span facility and ``jax.profiler`` capture.
 
 SURVEY.md §5 "Tracing / profiling": the reference relies on Flink operator
-metrics and latency markers; the TPU equivalent is ``jax.profiler`` traces
-(viewable in XProf/TensorBoard) plus per-step wall timing that accounts for
-JAX's async dispatch. These helpers degrade gracefully: if the profiler
-cannot start (e.g. unsupported on the backend), ``trace`` becomes a no-op
-rather than failing the training job.
+metrics and latency markers. Here :func:`span` is the ONLY way the program
+records a span: a host phase that is both a ``jax.profiler``
+``TraceAnnotation`` (so it lies on the device trace's clock, over the
+chip's ``XLA Ops`` rows) and a few counters in ``metrics.group("span")``
+(so it is read with no profiler at all). :func:`trace` captures a profile
+and degrades gracefully: if the profiler cannot start (e.g. unsupported
+on the backend) it becomes a no-op rather than failing the training job.
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
-from typing import Iterator, Optional
+from typing import Iterator
 
 import jax
 
-from flinkml_tpu.utils.metrics import MetricGroup
+from flinkml_tpu.utils.metrics import metrics
+
+#: Prefix of every span's ``TraceAnnotation`` in a profile.
+SPAN_PREFIX = "flinkml:"
+#: The metric group the spans count into.
+SPAN_GROUP = "span"
 
 
 @contextlib.contextmanager
@@ -46,54 +53,61 @@ def trace(log_dir: str, ignore_errors: bool = True) -> Iterator[None]:
                     raise
 
 
-def annotate(name: str):
-    """Named region visible in profiler timelines (host + device).
+class span(contextlib.ContextDecorator):
+    """One host phase of the program: ``with span("mesh.shard_batch",
+    bytes=n):`` (or ``@span("name")`` around a function).
 
-    Thin alias of ``jax.profiler.TraceAnnotation`` usable as a context
-    manager or decorator.
+    - In a profile it is the ``TraceAnnotation`` ``flinkml:<name>`` on
+      ``/host:CPU``, on the same clock as the device's operations;
+      nesting on a thread is the parent link.
+    - On exit it adds to ``metrics.group("span")`` the counters
+      ``<name>.seconds`` (host ``perf_counter``), ``<name>.calls``, one
+      ``<name>.<key>`` per count passed here or through :meth:`add`
+      inside the block, and ``<name>.errors`` if an exception closed it
+      (the exception propagates).
+
+    Always on: no flag, no level. With no profiler running a span costs
+    two clock reads, one ``TraceMe`` activity check and one locked add
+    per counter.
+    It adds NO synchronisation: where the enclosed call returns before
+    its device work is done (an asynchronous upload or dispatch), the
+    span ends when the host was released, not when the chip was.
+
+    A new span comes with the metric or runbook line that reads it
+    (``docs/development/observability.md``).
     """
-    return jax.profiler.TraceAnnotation(name)
 
-
-class StepTimer:
-    """Device-accurate step timing under async dispatch.
-
-    ``jit`` calls return before the device finishes; naive wall-clock
-    timing measures dispatch, not execution. ``StepTimer`` blocks on the
-    step's outputs (``block_until_ready``) before reading the clock and optionally records into a metric group::
-
-        timer = StepTimer(group=metrics.group("train"))
-        for batch in data:
-            with timer:
-                state = step(state, batch)
-                timer.observe(state)   # block target
-    """
-
-    def __init__(self, group: Optional[MetricGroup] = None,
-                 series: str = "step_seconds"):
-        self.group = group
-        self.series = series
-        self.times = []
-        self._pending = None
+    def __init__(self, name: str, **counts: float):
+        self.name = name
+        self._counts = counts
+        self._annotation = None
         self._t0 = 0.0
 
-    def observe(self, value) -> None:
-        """Register the step output to block on at exit."""
-        self._pending = value
+    def _recreate_cm(self) -> "span":
+        # As a decorator every call gets its own instance, so recursive
+        # and concurrent calls of the decorated function do not share
+        # a start time.
+        return span(self.name, **self._counts)
 
-    def __enter__(self) -> "StepTimer":
-        self._pending = None
+    def add(self, **counts: float) -> None:
+        """Add to this span's counts from inside the block (a size known
+        only once the work is done)."""
+        for key, value in counts.items():
+            self._counts[key] = self._counts.get(key, 0.0) + value
+
+    def __enter__(self) -> "span":
+        self._annotation = jax.profiler.TraceAnnotation(SPAN_PREFIX + self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        if exc_type is None and self._pending is not None:
-            jax.block_until_ready(self._pending)
-        dt = time.perf_counter() - self._t0
-        self.times.append(dt)
-        if self.group is not None:
-            self.group.record(self.series, dt)
-
-    @property
-    def mean(self) -> float:
-        return sum(self.times) / len(self.times) if self.times else 0.0
+        seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        group, name = metrics.group(SPAN_GROUP), self.name
+        group.counter(f"{name}.seconds", seconds)
+        group.counter(f"{name}.calls")
+        for key, value in self._counts.items():
+            group.counter(f"{name}.{key}", float(value))
+        if exc_type is not None:
+            group.counter(f"{name}.errors")

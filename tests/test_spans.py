@@ -1,0 +1,269 @@
+"""``profiling.span``: the program's one span facility, and the host
+spans of ``LogisticRegression.fit(Table)`` and ``PipelineModel.transform``
+that the benchmark's per-layer metrics read (docs/development/
+observability.md, "Spans")."""
+
+import contextlib
+import glob
+import os
+import sys
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+from flinkml_tpu import pipeline_fusion
+from flinkml_tpu.models import LogisticRegression, _linear_sgd
+from flinkml_tpu.models.logistic_regression import LogisticRegressionModel
+from flinkml_tpu.models.scalers import MaxAbsScalerModel, StandardScalerModel
+from flinkml_tpu.pipeline import PipelineModel
+from flinkml_tpu.table import Table
+from flinkml_tpu.utils import metrics, profiling
+from flinkml_tpu.utils.profiling import span
+
+FIT_SPANS = {"fit": 1, "hostdata.ingest": 1, "hostdata.shuffle": 1,
+             "mesh.shard_batch": 3, "trainer.loop": 1, "trainer.readback": 1}
+
+
+def _counters(group="span"):
+    return dict(metrics.group(group).snapshot()["counters"])
+
+
+@contextlib.contextmanager
+def _delta(group="span"):
+    """What the block added to a metric group's counters."""
+    before, out = _counters(group), {}
+    yield out
+    for k, v in _counters(group).items():
+        if v != before.get(k, 0.0):
+            out[k] = v - before.get(k, 0.0)
+
+
+def _calls(delta):
+    return {k[:-len(".calls")]: v for k, v in delta.items()
+            if k.endswith(".calls")}
+
+
+def test_span_adds_seconds_calls_and_counts():
+    with _delta() as d:
+        with span("t.basic", bytes=10, rows=2) as s:
+            s.add(bytes=5, steps=7)
+    assert d["t.basic.calls"] == 1
+    assert d["t.basic.bytes"] == 15 and d["t.basic.rows"] == 2
+    assert d["t.basic.steps"] == 7
+    assert 0 < d["t.basic.seconds"] < 1.0
+    assert "t.basic.errors" not in d
+    text = metrics.render_text()
+    assert 'flinkml_t_basic_seconds{group="span"}' in text
+
+
+def test_nested_spans_both_count_and_the_child_fits_in_the_parent():
+    with _delta() as d:
+        with span("t.parent"):
+            with span("t.child"):
+                sum(range(20000))
+            with span("t.child"):
+                pass
+    assert d["t.parent.calls"] == 1 and d["t.child.calls"] == 2
+    assert 0 < d["t.child.seconds"] <= d["t.parent.seconds"]
+
+
+def test_span_closed_by_an_exception_counts_and_reraises():
+    with _delta() as d:
+        with pytest.raises(KeyError, match="boom"):
+            with span("t.raises", rows=4):
+                raise KeyError("boom")
+    assert d["t.raises.calls"] == 1 and d["t.raises.errors"] == 1
+    assert d["t.raises.rows"] == 4 and d["t.raises.seconds"] > 0
+
+
+def test_span_as_a_decorator_gives_every_call_its_own_instance():
+    @span("t.decorated", rows=1)
+    def depth(n):
+        return 0 if n == 0 else 1 + depth(n - 1)
+
+    with _delta() as d:
+        assert depth(3) == 3
+    assert d["t.decorated.calls"] == 4 and d["t.decorated.rows"] == 4
+
+
+def test_spans_from_many_threads_lose_no_update():
+    n_threads, per_thread = 16, 500
+    barrier = threading.Barrier(n_threads)
+
+    def work():
+        barrier.wait(timeout=30)
+        for _ in range(per_thread):
+            with span("t.threads", rows=3):
+                with span("t.threads.inner"):
+                    pass
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with _delta() as d:
+            threads = [threading.Thread(target=work) for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    total = n_threads * per_thread
+    assert d["t.threads.calls"] == total and d["t.threads.rows"] == 3 * total
+    assert d["t.threads.inner.calls"] == total
+
+
+def test_span_is_a_flinkml_annotation_in_a_profile(tmp_path):
+    with profiling.trace(str(tmp_path), ignore_errors=False):
+        with span("t.visible"):
+            with span("t.visible.child"):
+                pass
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    found = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+             for plane in data.planes if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name.startswith(profiling.SPAN_PREFIX)}
+    assert set(found) == {"flinkml:t.visible", "flinkml:t.visible.child"}
+    (p0, p1), (c0, c1) = found["flinkml:t.visible"], found["flinkml:t.visible.child"]
+    assert p0 <= c0 and c1 <= p1  # nesting on a thread is the parent link
+
+
+def _lr_table(rows=1003, dim=5, seed=0):
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(rows, dim)).astype(np.float32)
+    y = (x @ rng.normal(size=dim) > 0).astype(np.float64)
+    return Table({"features": x, "label": y})
+
+
+def _fit(table):
+    est = (LogisticRegression().set_max_iter(6).set_global_batch_size(256)
+           .set_learning_rate(0.5).set_seed(7))
+    return np.asarray(est.fit(table).coefficient)
+
+
+def test_fit_produces_each_fit_span_once():
+    table = _lr_table()
+    with _delta() as d:
+        _fit(table)
+    assert _calls(d) == FIT_SPANS
+    # The three placed arrays: rows padded to the mesh, at the width the
+    # device holds (float64 narrows to float32 where x64 is off).
+    p = len(jax.devices())
+    padded = -(-1003 // p) * p
+    width = jax.dtypes.canonicalize_dtype(np.float64).itemsize
+    assert d["mesh.shard_batch.bytes"] == padded * (5 + 2) * width
+    children = sum(v for k, v in d.items() if k.endswith(".seconds")
+                   and not k.startswith("fit."))
+    assert children <= d["fit.seconds"]
+    # seconds and calls apiece, and the one count a metric reads: a name
+    # nothing reads is not added
+    assert set(d) == ({f"{s}.{c}" for s in FIT_SPANS for c in ("seconds", "calls")}
+                      | {"mesh.shard_batch.bytes"})
+
+
+def _chain(dim=5, seed=1):
+    rng = np.random.default_rng(seed)
+
+    def row(**cols):
+        return Table({k: np.asarray(v, np.float64)[None, :] for k, v in cols.items()})
+
+    s1 = StandardScalerModel().set(StandardScalerModel.INPUT_COL, "features") \
+        .set(StandardScalerModel.OUTPUT_COL, "s1")
+    s1.set_model_data(row(mean=rng.normal(size=dim), std=1 + rng.random(dim)))
+    s2 = MaxAbsScalerModel().set(MaxAbsScalerModel.INPUT_COL, "s1") \
+        .set(MaxAbsScalerModel.OUTPUT_COL, "s2")
+    s2.set_model_data(row(maxAbs=1 + rng.random(dim)))
+    lr = LogisticRegressionModel().set(LogisticRegressionModel.FEATURES_COL, "s2")
+    lr.set_model_data(row(coefficient=rng.normal(size=dim)))
+    return PipelineModel([s1, s2, lr])
+
+
+def _score(model, table):
+    (out,) = model.transform(table)
+    return out, np.asarray(out.column("prediction"))
+
+
+def test_transform_produces_each_transform_span_once():
+    model = _chain()
+    table = Table({"features": _lr_table(rows=600).column("features")})
+    with _delta() as d, _delta("pipeline.fusion") as fusion, _delta("table") as tab:
+        _score(model, table)
+    assert _calls(d) == {"transform": 1, "table.to_device": 1,
+                         "fusion.constants": 1, "fusion.dispatch": 1,
+                         "table.to_host": 1}
+    assert all(k.endswith((".seconds", ".calls")) for k in d)
+    # the bytes either way stay where they were counted before the spans
+    assert fusion["host_to_device_bytes"] == 600 * 5 * 4
+    assert tab["device_to_host_bytes"] == 600 * 8
+    with _delta() as again:
+        _score(model, table)  # the Table keeps its device copy
+    assert "table.to_device.calls" not in again
+    assert _calls(again) == {"transform": 1, "fusion.constants": 1,
+                             "fusion.dispatch": 1, "table.to_host": 1}
+
+
+def test_spans_live_in_their_own_group():
+    """The groups other tests snapshot keep exactly the counters they had."""
+    model, table = _chain(), Table({"features": _lr_table(rows=64).column("features")})
+    with _delta("pipeline.fusion") as fusion, _delta("table") as tab:
+        _score(model, table)
+    known = {"host_to_device_transfers", "host_to_device_bytes", "compiles",
+             "cache_hits", "fused_segments", "fused_stages",
+             "host_transfer_bytes_avoided", "aot_loads", "pallas_compiles"}
+    assert set(fusion) <= known
+    assert set(tab) == {"device_to_host_materializations", "device_to_host_bytes"}
+    assert not any(k.endswith((".seconds", ".calls")) for k in (*fusion, *tab))
+
+
+class _NoSpan:
+    """What the parent commit had at every span site: nothing."""
+
+    def __init__(self, *a, **k):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def add(self, **counts):
+        pass
+
+
+def _without_spans(monkeypatch):
+    from flinkml_tpu import pipeline
+    from flinkml_tpu.models import _data, logistic_regression
+    from flinkml_tpu.parallel import mesh
+
+    for mod in (_data, _linear_sgd, logistic_regression, mesh, pipeline,
+                pipeline_fusion, profiling):
+        monkeypatch.setattr(mod, "span", _NoSpan)
+    _linear_sgd._dense_trainer.cache_clear()
+    pipeline_fusion.reset_cache()
+
+
+def test_spans_change_no_result(monkeypatch):
+    table = _lr_table()
+    model, rows = _chain(), Table({"features": _lr_table(rows=600).column("features")})
+    _linear_sgd._dense_trainer.cache_clear()
+    pipeline_fusion.reset_cache()
+    coef = _fit(table)
+    out, pred = _score(model, rows)
+    raw = np.asarray(out.column("rawPrediction"))
+    with monkeypatch.context() as m:
+        _without_spans(m)
+        with _delta() as d:
+            coef0 = _fit(table)
+            out0, pred0 = _score(model, Table({"features": rows.column("features")}))
+            raw0 = np.asarray(out0.column("rawPrediction"))
+        assert d == {}  # the parent's path really ran: no span counted
+    _linear_sgd._dense_trainer.cache_clear()
+    pipeline_fusion.reset_cache()
+    assert coef.tobytes() == coef0.tobytes()
+    assert pred.tobytes() == pred0.tobytes() and raw.tobytes() == raw0.tobytes()
