@@ -45,10 +45,17 @@ def labeled_data(
     features_col: str,
     label_col: str,
     weight_col: Optional[str] = None,
+    features_dtype=np.float64,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Extract (X [n,d], y [n], w [n]); weight defaults to 1.0 per row."""
+    """Extract (X [n,d], y [n], w [n]); weight defaults to 1.0 per row.
+
+    ``features_dtype`` is :func:`features_matrix`'s ``dtype``: None keeps
+    a floating column as the table has it (a contiguous one is not
+    copied), for a caller that casts on the way to the device
+    (``_linear_sgd._place_shuffled``) and computes nothing on the host.
+    Labels and weights are float64 either way."""
     with span("hostdata.ingest"):
-        x = features_matrix(table, features_col)
+        x = features_matrix(table, features_col, dtype=features_dtype)
         y = np.asarray(table.column(label_col), dtype=np.float64).reshape(-1)
         if y.shape[0] != x.shape[0]:
             raise ValueError(

@@ -543,6 +543,26 @@ def _run_chunked(
     return result
 
 
+def _place_shuffled(x, y, w, mesh: DeviceMesh, seed: int, dtype):
+    """The training table on the mesh in the row order ``seed`` fixes,
+    padded with zero rows (weight 0) to the mesh: exactly what
+    ``shard_batch(pad(a.astype(dtype)[perm]))`` places for each of the
+    three (``dtype`` None: each array's own). The features go up in one
+    chunked pass (:meth:`DeviceMesh.shard_rows`), never copied whole on
+    the host; labels and weights are small and go as they always did."""
+    p_size = mesh.axis_size()  # pad exactly to the mesh: identical windows always
+    with span("hostdata.shuffle"):
+        perm = np.random.default_rng(seed).permutation(x.shape[0])
+        small = []
+        for a in (y, w):
+            if dtype is not None:
+                a = a.astype(dtype, copy=False)
+            small.append(pad_to_multiple(a[perm], p_size)[0])
+    xd = mesh.shard_rows(x, perm, dtype if dtype is not None else x.dtype)
+    yd, wd = (mesh.shard_batch(a) for a in small)
+    return xd, yd, wd
+
+
 def train_linear_model(
     x: np.ndarray,
     y: np.ndarray,
@@ -628,18 +648,7 @@ def train_linear_model(
             checkpoint_interval=checkpoint_interval, resume=resume,
         )
     p_size = mesh.axis_size()
-    with span("hostdata.shuffle"):
-        if dtype is not None:
-            x, y, w = x.astype(dtype), y.astype(dtype), w.astype(dtype)
-        perm = np.random.default_rng(seed).permutation(n)
-        x, y, w = x[perm], y[perm], w[perm]
-        row_tile = p_size  # pad exactly to the mesh: identical windows always
-        x_pad, _ = pad_to_multiple(x, row_tile)
-        y_pad, _ = pad_to_multiple(y, row_tile)
-        w_pad, _ = pad_to_multiple(w, row_tile)
-    xd = mesh.shard_batch(x_pad)
-    yd = mesh.shard_batch(y_pad)
-    wd = mesh.shard_batch(w_pad)
+    xd, yd, wd = _place_shuffled(x, y, w, mesh, seed, dtype)
     n_local = xd.shape[0] // p_size
     local_bs = align_local_bs(global_batch_size, p_size, n_local)
     trainer = _dense_trainer(mesh.mesh, loss, local_bs, DeviceMesh.DATA_AXIS)
@@ -1021,18 +1030,10 @@ def train_softmax_model(
     if n == 0:
         raise ValueError("training table is empty")
     p_size = mesh.axis_size()
-    if dtype is not None:
-        x = x.astype(dtype)
-    w = np.asarray(w, dtype=x.dtype)
-    y = np.asarray(y, dtype=x.dtype)
-    perm = np.random.default_rng(seed).permutation(n)
-    x, y, w = x[perm], y[perm], w[perm]
-    x_pad, _ = pad_to_multiple(x, p_size)
-    y_pad, _ = pad_to_multiple(y, p_size)
-    w_pad, _ = pad_to_multiple(w, p_size)
-    xd = mesh.shard_batch(x_pad)
-    yd = mesh.shard_batch(y_pad)
-    wd = mesh.shard_batch(w_pad)
+    # Labels and weights at the features' width, as the step expects.
+    xd, yd, wd = _place_shuffled(
+        x, y, w, mesh, seed, dtype if dtype is not None else x.dtype
+    )
     n_local = xd.shape[0] // p_size
     local_bs = min(max(1, math.ceil(global_batch_size / p_size)), n_local)
     trainer = _softmax_trainer(
@@ -1431,6 +1432,27 @@ def streamed_linear_fit(
     return train_linear_model_stream(batches(), **kwargs)
 
 
+def dense_table_data(table, features_col: str, label_col: str,
+                     weight_col: Optional[str], replicated: bool):
+    """``labeled_data`` for a dense trainer, and the ``dtype`` to hand it:
+    ``(x, y, w, dtype)``.
+
+    The replicated trainers (:func:`train_linear_model` without a plan,
+    :func:`train_softmax_model`) cast the column chunk by chunk on its
+    way to the device, so they take it as the table has it, and are told
+    the width ``labeled_data``'s float64 copy gave every table (float32
+    on the device where x64 is off). The plan-sharded trainer computes
+    on the host arrays and derives its own width from them: it keeps the
+    copy, and ``dtype`` is None."""
+    from flinkml_tpu.models._data import labeled_data
+
+    x, y, w = labeled_data(
+        table, features_col, label_col, weight_col,
+        features_dtype=None if replicated else np.float64,
+    )
+    return x, y, w, (np.float64 if replicated else None)
+
+
 def train_linear_model_from_table(
     table,
     features_col: str,
@@ -1451,11 +1473,7 @@ def train_linear_model_from_table(
     plan loudly. ``precision`` (the FML6xx-gated mixed-precision
     policy) rides the same dense-only route and is refused just as
     loudly on the sparse branch."""
-    from flinkml_tpu.models._data import (
-        labeled_data,
-        labeled_sparse_data,
-        sparse_features,
-    )
+    from flinkml_tpu.models._data import labeled_sparse_data, sparse_features
 
     if sparse_features(table, features_col) is not None:
         if sharding_plan is not None:
@@ -1478,11 +1496,15 @@ def train_linear_model_from_table(
         return train_linear_model_sparse_csr(
             indptr, indices, values, dim, y, w, **hyper
         )
-    x, y, w = labeled_data(table, features_col, label_col, weight_col)
+    x, y, w, dtype = dense_table_data(
+        table, features_col, label_col, weight_col,
+        replicated=sharding_plan is None and precision is None,
+    )
     if x.shape[0] == 0:
         raise ValueError("training table is empty")
     if label_check is not None:
         label_check(y)
+    hyper.setdefault("dtype", dtype)
     return train_linear_model(x, y, w, sharding_plan=sharding_plan,
                               precision=precision, **hyper)
 

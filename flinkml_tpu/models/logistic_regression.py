@@ -58,7 +58,6 @@ from flinkml_tpu.models._coefficient import CoefficientModelMixin
 from flinkml_tpu.models._data import (
     check_binary_labels,
     features_matrix,
-    labeled_data,
     labeled_sparse_data,
     sparse_features,
 )
@@ -161,11 +160,13 @@ class LogisticRegression(StreamingEstimatorMixin, _LogisticRegressionParams, Est
                 y, w, loss="logistic", elastic_net=0.0, **hyper,
             )
         else:
-            x, y, w = labeled_data(
+            x, y, w, hyper["dtype"] = _linear_sgd.dense_table_data(
                 table,
                 features_col,
                 self.get(_LogisticRegressionParams.LABEL_COL),
                 self.get(_LogisticRegressionParams.WEIGHT_COL),
+                replicated=(self.sharding_plan is None
+                            and self.precision is None),
             )
             if x.shape[0] == 0:
                 raise ValueError("training table is empty")

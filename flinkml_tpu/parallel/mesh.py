@@ -14,13 +14,32 @@ sharding can be layered on without changing this substrate.
 
 from __future__ import annotations
 
+import functools
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flinkml_tpu.utils.profiling import span
+
+#: :meth:`DeviceMesh.shard_rows` stages the table through host buffers
+#: of this many bytes (all shards' rows of one round together). Read on
+#: a v5e's host (PERF.md §5, PR 25): 64 and 128 MiB place a 4.64 GB
+#: table equally fast; at 32 MiB the per-round dispatch shows, from 256
+#: MiB the buffers' first-touch page faults do (≈ 0.9 s a GiB, every
+#: fit). Every transfer is far below the runtime's ≈ 4 GiB pre-mapped
+#: limit, above which it runs ten times slower.
+_STAGE_BYTES = 64 << 20
+#: Staging buffers in rotation: one gathered into while the other is on
+#: its way to the device. A third bought nothing.
+_STAGE_BUFFERS = 2
+#: Threads one round's gather is split over (``ndarray.take`` releases
+#: the interpreter lock). One thread gathers 492-byte rows at 4.5 GB/s
+#: and sets the pace; eight reach the transfer's own 8-9 GB/s.
+_GATHER_THREADS = 8
 
 
 class DeviceMesh:
@@ -166,6 +185,68 @@ class DeviceMesh:
             phase.add(bytes=placed.nbytes)
         return placed
 
+    def shard_rows(self, x: np.ndarray, order: np.ndarray, dtype) -> jax.Array:
+        """``shard_batch(pad_to_multiple(x.astype(dtype)[order], p)[0])``,
+        bit for bit, without its three full-size host arrays: shard ``s``
+        holds positions ``[s * n_local, (s + 1) * n_local)`` of the
+        reordered table, zero rows past its end.
+
+        One pass, round by round: each shard's next rows are gathered
+        (and cast, if ``x`` is not a ``dtype`` array already) into a
+        reused staging buffer, which ``device_put`` sends on its way while
+        the next round is gathered into the other buffer; a donated
+        in-place write puts the round at its offset in the table's
+        device array, so the device holds the table plus the rounds in
+        flight. Multi-process, every process holds all of ``x`` and
+        places its addressable shards, as :meth:`shard_batch` does.
+        """
+        p = self.axis_size(self.DATA_AXIS)
+        # ndarray.take copies a strided source whole, every call.
+        x = np.ascontiguousarray(x)
+        # The width device_put would have narrowed to where x64 is off.
+        dt = np.dtype(jax.dtypes.canonicalize_dtype(dtype))
+        row = x.shape[1:]
+        n = order.shape[0]
+        n_local = -(-n // p)
+        row_bytes = max(1, int(np.prod(row)) * dt.itemsize)
+        chunk = min(n_local, max(1, _STAGE_BYTES // (p * row_bytes)))
+        rounds = -(-n_local // chunk)
+        stages = [np.empty((p * chunk,) + row, dt)
+                  for _ in range(min(_STAGE_BUFFERS, rounds))]
+        scratch = None if x.dtype == dt else np.empty_like(stages[0], x.dtype)
+        consumed = [None] * len(stages)
+        sharding = self.data_sharding()
+        placed = jnp.zeros((p * n_local,) + row, dt, device=sharding)
+        write = _row_writer(self.mesh, self.DATA_AXIS)
+        shard_starts = np.arange(p)[:, None] * n_local
+        with ThreadPoolExecutor(_GATHER_THREADS) as pool:
+            for r in range(rounds):
+                # The last round steps back to end at the shard's end, so
+                # every round has one shape (one program): it re-sends
+                # rows the round before already placed.
+                offset = min(r * chunk, n_local - chunk)
+                slot = r % len(stages)
+                stage = stages[slot]
+                with span("hostdata.stage_wait"):
+                    # device_put neither snapshots the host buffer nor
+                    # (on CPU) need copy it at all: the buffer is free
+                    # only once the write that read it has run.
+                    if consumed[slot] is not None:
+                        consumed[slot].block_until_ready()
+                with span("hostdata.shuffle"):
+                    # Positions rise with the staging row, so the rows
+                    # past the table's end are the buffer's tail.
+                    pos = (shard_starts + (offset + np.arange(chunk))).reshape(-1)
+                    valid = int(np.searchsorted(pos, n))
+                    _gather_rows(pool, x, order[pos[:valid]], stage[:valid],
+                                 scratch)
+                    stage[valid:] = 0
+                with span("mesh.shard_batch") as phase:
+                    sent = jax.device_put(stage, sharding)
+                    placed, consumed[slot] = write(placed, sent, np.int32(offset))
+                    phase.add(bytes=sent.nbytes)
+        return placed
+
     def replicate(self, tree):
         """Replicate a pytree of arrays onto every device (broadcast-model)."""
         sharding = self.replicated_sharding()
@@ -227,6 +308,44 @@ class DeviceMesh:
         return jax.make_array_from_process_local_data(
             self.data_sharding(), local_rows
         )
+
+
+@functools.lru_cache(maxsize=128)
+def _row_writer(mesh: Mesh, axis: str):
+    """The in-place write of :meth:`DeviceMesh.shard_rows`: every shard
+    of ``table`` takes its shard of ``rows`` at local row ``offset``.
+    ``table`` is donated. The second result is ready when the write has
+    run, i.e. when ``rows`` (and the host buffer under it) has been read;
+    the table itself is donated to the next write and cannot be waited on."""
+
+    def write(table, rows, offset):
+        written = jax.lax.dynamic_update_slice_in_dim(table, rows, offset, 0)
+        return written, rows[:1].reshape(-1)[:1]
+
+    return jax.jit(
+        jax.shard_map(write, mesh=mesh, in_specs=(P(axis), P(axis), P()),
+                      out_specs=(P(axis), P(axis))),
+        donate_argnums=0,
+    )
+
+
+def _gather_rows(pool, x, index, out, scratch) -> None:
+    """``out[:] = x[index]`` cast to ``out``'s dtype, split over the
+    pool's threads. ``scratch`` (``x``'s dtype, at least ``out``'s rows)
+    takes the rows first where a cast is needed, else is None."""
+    bounds = np.linspace(0, index.shape[0], _GATHER_THREADS + 1).astype(np.intp)
+
+    def part(lo, hi):
+        if scratch is None:
+            # mode="clip": the default buffers `out` whole to be able to
+            # raise; `index` is a slice of a permutation.
+            x.take(index[lo:hi], axis=0, out=out[lo:hi], mode="clip")
+        else:
+            x.take(index[lo:hi], axis=0, out=scratch[lo:hi], mode="clip")
+            np.copyto(out[lo:hi], scratch[lo:hi], casting="unsafe")
+
+    # list(): an executor keeps a task's exception until its result is read.
+    list(pool.map(part, bounds[:-1], bounds[1:]))
 
 
 def pad_to_multiple(array: np.ndarray, multiple: int, axis: int = 0):

@@ -1,0 +1,229 @@
+"""The dense trainers' one-pass placement (``DeviceMesh.shard_rows`` under
+``_linear_sgd._place_shuffled``): the table reaches the mesh in the
+seeded row order, chunk by chunk through rotating staging buffers, and is
+bit for bit what the three full-size host passes it replaced placed:
+``shard_batch(pad(a.astype(dtype)[perm]))``."""
+
+import jax
+import numpy as np
+import pytest
+
+from flinkml_tpu.models import (
+    LinearRegression,
+    LinearSVC,
+    LogisticRegression,
+    _linear_sgd,
+)
+from flinkml_tpu.parallel import DeviceMesh, mesh as mesh_mod, pad_to_multiple
+from flinkml_tpu.table import Table
+from flinkml_tpu.utils import metrics
+
+DIM = 5
+#: Staging bytes that cut a 1003-row table into 10-21 rounds (102 float32
+#: or 51 float64 rows a round on one device, 12 or 6 a shard on eight):
+#: more rounds than buffers, rows not a multiple of the round.
+TINY_STAGE = 2048
+
+
+def _mesh(devices):
+    return DeviceMesh(devices=jax.devices()[:devices])
+
+
+def _column(kind, rows, seed=0):
+    rng = np.random.default_rng(seed)
+    if kind == "int64":
+        return rng.integers(-9, 9, size=(rows, DIM))
+    if kind == "strided":  # every other column of a wider float32 array
+        return rng.normal(size=(rows, 2 * DIM)).astype(np.float32)[:, ::2]
+    return rng.normal(size=(rows, DIM)).astype(kind)
+
+
+def _old_pattern(x, y, w, mesh, seed, dtype):
+    """What ``train_linear_model`` did before the one-pass placement."""
+    if dtype is not None:
+        x, y, w = x.astype(dtype), y.astype(dtype), w.astype(dtype)
+    perm = np.random.default_rng(seed).permutation(x.shape[0])
+    p = mesh.axis_size()
+    return tuple(mesh.shard_batch(pad_to_multiple(a[perm], p)[0])
+                 for a in (x, y, w))
+
+
+def _assert_same_placement(got, want):
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.sharding == want.sharding
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    for g, e in zip(got.addressable_shards, want.addressable_shards):
+        assert g.device == e.device and g.index == e.index
+        assert np.asarray(g.data).tobytes() == np.asarray(e.data).tobytes()
+
+
+@pytest.mark.parametrize("stage_bytes", [TINY_STAGE, None],
+                         ids=["many-rounds", "one-round"])
+@pytest.mark.parametrize("devices", [1, 8])
+@pytest.mark.parametrize("rows", [1003, 37])
+@pytest.mark.parametrize("dtype", [None, np.float32], ids=["asis", "f32"])
+@pytest.mark.parametrize("kind", ["float32", "float64", "int64", "strided"])
+def test_one_pass_placement_equals_the_three_host_passes(
+        monkeypatch, kind, dtype, rows, devices, stage_bytes):
+    if stage_bytes is not None:
+        monkeypatch.setattr(mesh_mod, "_STAGE_BYTES", stage_bytes)
+    mesh = _mesh(devices)
+    x = _column(kind, rows)
+    assert x.flags.c_contiguous == (kind != "strided")
+    rng = np.random.default_rng(1)
+    y, w = rng.integers(0, 2, rows).astype(np.float64), rng.random(rows)
+    got = _linear_sgd._place_shuffled(x, y, w, mesh, 11, dtype)
+    want = _old_pattern(x, y, w, mesh, 11, dtype)
+    for g, e in zip(got, want):
+        _assert_same_placement(g, e)
+    if rows % devices:  # rows past the table's end are zero
+        tail = np.asarray(got[0])[rows:]
+        assert tail.shape[0] == -rows % devices and not tail.any()
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_rounds_rotate_through_the_staging_buffers(monkeypatch, devices):
+    """More rounds than buffers: every round waits, gathers and places
+    once, and gathers into a buffer only after waiting for the write that
+    read it last (``device_put`` does not snapshot a host buffer and may
+    alias it; too early a reuse would show as wrong rows only where it
+    does, so the order itself is checked here)."""
+    monkeypatch.setattr(mesh_mod, "_STAGE_BYTES", TINY_STAGE)
+    gathered, waited = [], []  # staging buffer of each round; rounds waited for
+    unread = {}  # staging buffer -> the round whose write read it last
+    real_writer, real_gather = mesh_mod._row_writer, mesh_mod._gather_rows
+
+    class Token:
+        def __init__(self, token, rnd):
+            self.token, self.rnd = token, rnd
+
+        def block_until_ready(self):
+            self.token.block_until_ready()
+            waited.append(self.rnd)
+
+    def writer(*key):
+        def write(table, rows, offset):
+            table, token = real_writer(*key)(table, rows, offset)
+            unread[gathered[-1]] = len(gathered) - 1
+            return table, Token(token, len(gathered) - 1)
+        return write
+
+    def gather(pool, x, index, out, scratch):
+        buffer = (out if out.base is None else out.base).ctypes.data
+        if buffer in unread:
+            assert unread.pop(buffer) in waited
+        gathered.append(buffer)
+        real_gather(pool, x, index, out, scratch)
+
+    monkeypatch.setattr(mesh_mod, "_row_writer", writer)
+    monkeypatch.setattr(mesh_mod, "_gather_rows", gather)
+    mesh, rows = _mesh(devices), 1003
+    x = _column("float32", rows)
+    perm = np.random.default_rng(3).permutation(rows)
+    counters = metrics.group("span")
+    before = dict(counters.snapshot()["counters"])
+    placed = mesh.shard_rows(x, perm, np.float32)
+    after = counters.snapshot()["counters"]
+    calls = {k: after[f"{k}.calls"] - before.get(f"{k}.calls", 0)
+             for k in ("hostdata.stage_wait", "hostdata.shuffle", "mesh.shard_batch")}
+    n_local = -(-rows // devices)
+    chunk = TINY_STAGE // (devices * DIM * 4)
+    rounds = -(-n_local // chunk)
+    assert rounds > mesh_mod._STAGE_BUFFERS
+    assert calls == dict.fromkeys(calls, rounds)
+    assert len(gathered) == rounds
+    assert len(set(gathered)) == mesh_mod._STAGE_BUFFERS
+    assert waited == list(range(rounds - mesh_mod._STAGE_BUFFERS))
+    sent = (after["mesh.shard_batch.bytes"]
+            - before.get("mesh.shard_batch.bytes", 0))
+    assert sent == rounds * devices * chunk * DIM * 4
+    want = mesh.shard_batch(pad_to_multiple(x[perm], devices)[0])
+    _assert_same_placement(placed, want)
+
+
+def test_a_narrower_training_dtype_is_cast_in_the_staging_pass(monkeypatch):
+    """float64 -> float32 goes through one scratch buffer of the source's
+    dtype, reused by every round, and rounds as ``astype`` does."""
+    monkeypatch.setattr(mesh_mod, "_STAGE_BYTES", TINY_STAGE)
+    mesh, rows = _mesh(8), 1003
+    x = _column("float64", rows) * 1e-3 + 1.0  # values float32 cannot hold
+    perm = np.random.default_rng(5).permutation(rows)
+    placed = mesh.shard_rows(x, perm, np.float32)
+    assert placed.dtype == np.float32
+    want = mesh.shard_batch(pad_to_multiple(x.astype(np.float32)[perm], 8)[0])
+    _assert_same_placement(placed, want)
+
+
+# Coefficients of the PARENT commit (36477c5: float64 copy, x[perm],
+# shard_batch) for `_seeded_table`, run under tests/conftest.py (x64 on,
+# eight CPU devices), as float.hex. A fit trains on the same bytes in the
+# same rows, so it reproduces them; rtol 1e-12 leaves room for another
+# CPU's instruction selection and for nothing else (a float32 fit of the
+# same table differs by 1e-8).
+PARENT_COEFFICIENTS = {
+    ("lr", "float32"): [
+        '0x1.4440df8b07e32p-4', '-0x1.e342b58e80211p-5',
+        '0x1.b2c5c99aa2e17p-5', '-0x1.961d7f887d304p-4',
+        '-0x1.5578b5f024e47p-3',
+    ],
+    ("lr", "float64"): [
+        '0x1.4440df8ccc4ffp-4', '-0x1.e342b5c5f8d9ap-5',
+        '0x1.b2c5c98387ab7p-5', '-0x1.961d7f82b8ebbp-4',
+        '-0x1.5578b5f91d89ap-3',
+    ],
+    ("softmax", "float32"): [
+        '-0x1.404f3c1d27507p-4', '0x1.dce67373d4e67p-5',
+        '-0x1.7d20e896ef245p-5', '0x1.852a0ebeb775fp-4',
+        '0x1.3ed82cd659590p-3', '0x1.260a5816e54c7p-8',
+        '-0x1.ac28a5a8f3b18p-8', '0x1.8bc2adb31aac4p-8',
+        '0x1.1115095f3a69ap-7', '0x1.cdc3557b47a44p-8',
+        '0x1.2dee969bb8fbap-4', '-0x1.a7615ebeb6703p-5',
+        '0x1.4ba892e08bcedp-5', '-0x1.a74cafea9ec33p-4',
+        '-0x1.4d46478233962p-3',
+    ],
+    ("svc", "float32"): [
+        '0x1.57d9aff4bac78p-3', '-0x1.f06042e8c85d1p-4',
+        '0x1.c47adc7035b62p-4', '-0x1.a394b7c91e1edp-3',
+        '-0x1.65ce8d99010aap-2',
+    ],
+    ("linreg-sgd", "float32"): [
+        '0x1.607e3a21a93d8p-2', '-0x1.11ca77bc6d094p-2',
+        '0x1.e99aea88609c8p-3', '-0x1.05161793b5494p-1',
+        '-0x1.8cc47cd0d7ffdp-1',
+    ],
+}
+
+
+def _seeded_table(dtype, classes=2):
+    rng = np.random.default_rng(2025)
+    x = rng.normal(size=(1003, DIM)).astype(dtype)
+    margin = x.astype(np.float64) @ rng.normal(size=DIM)
+    if classes == 2:
+        y = (margin > 0).astype(np.float64)
+    else:
+        y = np.digitize(margin, [-0.5, 0.5]).astype(np.float64)
+    return Table({"features": x, "label": y, "target": margin})
+
+
+def _fit_seeded(name, dtype):
+    if name == "lr":
+        est, table = LogisticRegression(), _seeded_table(dtype)
+    elif name == "softmax":
+        est, table = LogisticRegression(), _seeded_table(dtype, classes=3)
+    elif name == "svc":
+        est, table = LinearSVC(), _seeded_table(dtype)
+    else:
+        est, table = LinearRegression(), _seeded_table(dtype)
+        est.set_label_col("target")  # solver "sgd", the default
+    est.set_max_iter(6).set_global_batch_size(256).set_learning_rate(0.1)
+    est.set_seed(7)
+    return np.asarray(est.fit(table).coefficient, np.float64)
+
+
+@pytest.mark.parametrize("name,dtype", list(PARENT_COEFFICIENTS))
+def test_fit_reproduces_the_parent_commits_coefficients(monkeypatch, name, dtype):
+    monkeypatch.setattr(mesh_mod, "_STAGE_BYTES", TINY_STAGE)
+    want = np.array([float.fromhex(h) for h in
+                     np.ravel(PARENT_COEFFICIENTS[(name, dtype)])])
+    got = _fit_seeded(name, dtype)
+    np.testing.assert_allclose(got.ravel(), want, rtol=1e-12, atol=0)

@@ -22,8 +22,12 @@ from flinkml_tpu.table import Table
 from flinkml_tpu.utils import metrics, profiling
 from flinkml_tpu.utils.profiling import span
 
-FIT_SPANS = {"fit": 1, "hostdata.ingest": 1, "hostdata.shuffle": 1,
-             "mesh.shard_batch": 3, "trainer.loop": 1, "trainer.readback": 1}
+# A table of one staging round (DeviceMesh.shard_rows): the permutation
+# with the labels' and weights' gathers, then the round's wait, gather and
+# placement, then the labels' and the weights' placements.
+FIT_SPANS = {"fit": 1, "hostdata.ingest": 1, "hostdata.shuffle": 2,
+             "hostdata.stage_wait": 1, "mesh.shard_batch": 3,
+             "trainer.loop": 1, "trainer.readback": 1}
 
 
 def _counters(group="span"):
@@ -146,17 +150,29 @@ def _fit(table):
     return np.asarray(est.fit(table).coefficient)
 
 
-def test_fit_produces_each_fit_span_once():
+@pytest.mark.parametrize("stage_bytes", [None, 4096], ids=["one-round", "many-rounds"])
+def test_fit_produces_each_fit_span_once(monkeypatch, stage_bytes):
+    from flinkml_tpu.parallel import mesh
+
+    p = len(jax.devices())
+    n_local = -(-1003 // p)
+    chunk, width = n_local, jax.dtypes.canonicalize_dtype(np.float64).itemsize
+    if stage_bytes is not None:
+        monkeypatch.setattr(mesh, "_STAGE_BYTES", stage_bytes)
+        chunk = stage_bytes // (p * 5 * width)
+    rounds = -(-n_local // chunk)
+    assert (rounds > 1) == (stage_bytes is not None)
     table = _lr_table()
     with _delta() as d:
         _fit(table)
-    assert _calls(d) == FIT_SPANS
-    # The three placed arrays: rows padded to the mesh, at the width the
-    # device holds (float64 narrows to float32 where x64 is off).
-    p = len(jax.devices())
-    padded = -(-1003 // p) * p
-    width = jax.dtypes.canonicalize_dtype(np.float64).itemsize
-    assert d["mesh.shard_batch.bytes"] == padded * (5 + 2) * width
+    assert _calls(d) == {**FIT_SPANS, "hostdata.shuffle": 1 + rounds,
+                         "hostdata.stage_wait": rounds,
+                         "mesh.shard_batch": rounds + 2}
+    # What was placed: the features round by round (the last round steps
+    # back over rows already sent), labels and weights padded to the mesh,
+    # at the width the device holds (float32 where x64 is off).
+    assert d["mesh.shard_batch.bytes"] == (rounds * chunk * 5 + 2 * n_local) * p * width
+    # The phases are siblings on the fit's thread, so they add up to it.
     children = sum(v for k, v in d.items() if k.endswith(".seconds")
                    and not k.startswith("fit."))
     assert children <= d["fit.seconds"]
