@@ -36,7 +36,7 @@ from flinkml_tpu.api import (
     Model,
     Estimator,
 )
-from flinkml_tpu.table import Table
+from flinkml_tpu.table import CsrColumn, Table
 from flinkml_tpu.pipeline import Pipeline, PipelineModel
 from flinkml_tpu.graph import GraphBuilder, Graph, GraphModel, TableId
 from flinkml_tpu.tuning import (
@@ -67,6 +67,7 @@ __all__ = [
     "Model",
     "Estimator",
     "Table",
+    "CsrColumn",
     "Pipeline",
     "PipelineModel",
     "GraphBuilder",

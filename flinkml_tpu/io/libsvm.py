@@ -173,25 +173,31 @@ def read_libsvm_table(
     label_col: str = "label",
     **kw,
 ):
-    """Parse into a :class:`~flinkml_tpu.table.Table` with a SparseVector
-    features column — the bridge from libsvm ingest straight into the
-    O(nnz) sparse estimators (LogisticRegression / LinearSVC /
-    LinearRegression fit + transform), never densifying.
+    """Parse into a :class:`~flinkml_tpu.table.Table` whose features
+    column is the :class:`~flinkml_tpu.table.CsrColumn` the parser built
+    — the bridge from libsvm ingest straight into the O(nnz) sparse
+    estimators (LogisticRegression / LinearSVC / LinearRegression fit +
+    transform), never densifying and with no object a row
+    (``table.column(features_col)`` still gives ``SparseVector`` rows).
 
     Rows are sorted by feature index on the way in (libsvm does not
-    guarantee ordering); a duplicate index within a row raises, keeping
-    SparseVector's sorted-unique invariant intact.
+    guarantee ordering), and only if some row needs it; a duplicate
+    index within a row raises, keeping SparseVector's sorted-unique
+    invariant intact (the column's own validation).
     """
-    from flinkml_tpu.linalg import SparseVector
-    from flinkml_tpu.table import Table
+    from flinkml_tpu.table import CsrColumn, Table
 
     labels, indptr, indices, values, dim = read_libsvm(
         path, n_features=n_features, **kw
     )
-    n = labels.shape[0]
-    rows = np.repeat(np.arange(n), np.diff(indptr))
-    order = np.lexsort((indices, rows))
-    if indices.size > 1:
+    try:
+        column = CsrColumn(indptr, indices, values, dim)
+    except ValueError:
+        # Some row is unsorted or repeats an index (or an index is out of
+        # range, which the second look raises again): sort within rows; a
+        # repeated index then sits beside its twin.
+        rows = np.repeat(np.arange(labels.shape[0]), np.diff(indptr))
+        order = np.lexsort((indices, rows))
         srows, sidx = rows[order], indices[order]
         dup = (np.diff(sidx) == 0) & (np.diff(srows) == 0)
         if dup.any():
@@ -202,14 +208,5 @@ def read_libsvm_table(
                 f"(0-based) on data line {int(srows[1:][dup][0]) + 1} "
                 f"of {path}"
             )
-    idx64 = indices[order].astype(np.int64)
-    val64 = values[order].astype(np.float64)
-    idx64.setflags(write=False)
-    val64.setflags(write=False)
-    vecs = np.empty(n, dtype=object)
-    for i in range(n):
-        sl = slice(indptr[i], indptr[i + 1])
-        # Trusted construction over frozen sorted views: per-row
-        # validation would dominate at dataset scale.
-        vecs[i] = SparseVector._from_sorted(dim, idx64[sl], val64[sl])
-    return Table({features_col: vecs, label_col: labels})
+        column = CsrColumn(indptr, sidx, values[order], dim)
+    return Table({features_col: column, label_col: labels})

@@ -32,6 +32,70 @@ def next_pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
+#: Cells a chunk of :func:`check_csr_structure` looks at: the check's
+#: temporaries stay at this size whatever the column's.
+_CSR_CHECK_CELLS = 1 << 24
+
+
+def check_csr_structure(indptr, indices, dim: int,
+                        ascending: bool = False) -> np.ndarray:
+    """Structural CSR validation, vectorised; returns ``nnz =
+    diff(indptr)``. The one implementation behind the sparse stream
+    paths' pass-0 check and :class:`~flinkml_tpu.table.CsrColumn`.
+
+    A non-monotone indptr passes the ragged check (``indices.size ==
+    indptr[-1]``) but later raises rank-locally inside the ELL fill
+    (``np.repeat`` with negative counts) on the prefetch thread at place
+    time — the exact mid-collective hang class pass-0 validation exists
+    to prevent — so it must be rejected HERE, where the failure rides the
+    held-error rendezvous like every other ingest check. Out-of-range
+    column indices never raise at all: the jitted gather/scatter clamps
+    them, silently misattributing gradient mass to boundary columns.
+
+    ``ascending`` also holds every row to :class:`SparseVector`'s
+    invariant: indices strictly ascending within a row (so sorted and
+    distinct). The cells are walked in chunks of ``_CSR_CHECK_CELLS``."""
+    indptr, indices = np.asarray(indptr), np.asarray(indices)
+    nnz = np.diff(indptr)
+    if indptr.size == 0 or indptr[0] != 0 or np.any(nnz < 0):
+        raise ValueError(
+            "invalid CSR batch: indptr must start at 0 and be "
+            "non-decreasing"
+        )
+    cells = indices.shape[0]
+    lo_seen, hi_seen = 0, -1
+    for lo in range(0, cells, _CSR_CHECK_CELLS):
+        part = indices[lo:lo + _CSR_CHECK_CELLS]
+        lo_seen = min(lo_seen, int(part.min()))
+        hi_seen = max(hi_seen, int(part.max()))
+    if lo_seen < 0 or hi_seen >= dim:
+        raise ValueError(
+            "invalid CSR batch: column indices must lie in "
+            f"[0, {dim}); got range [{lo_seen}, {hi_seen}]"
+        )
+    if ascending:
+        for lo in range(0, cells - 1, _CSR_CHECK_CELLS):
+            hi = min(lo + _CSR_CHECK_CELLS, cells - 1)
+            # Pair k compares cells k and k + 1; it may fall only where
+            # cell k + 1 opens a row.
+            falls = np.flatnonzero(indices[lo + 1:hi + 1] <= indices[lo:hi])
+            if falls.size == 0:
+                continue
+            cell = falls + (lo + 1)
+            row = np.searchsorted(indptr, cell, side="right") - 1
+            inside = indptr[row] != cell
+            if inside.any():
+                at, r = int(cell[inside][0]), int(row[inside][0])
+                what = ("duplicate" if indices[at] == indices[at - 1]
+                        else "unsorted")
+                raise ValueError(
+                    f"invalid CSR batch: {what} index {int(indices[at])} "
+                    f"in row {r}; indices must be strictly ascending "
+                    "within a row"
+                )
+    return nnz
+
+
 class Vector:
     """Abstract vector. Parity: ``ml/linalg/Vector.java``."""
 

@@ -72,7 +72,15 @@ def sparse_features(table: Table, features_col: str):
     """The features column if EVERY row is a SparseVector, else None —
     the dispatch every linear model uses to pick the O(nnz) sparse path
     over densification. A mixed Sparse/Dense vector column returns None
-    and takes the densifying path (which handles any Vector)."""
+    and takes the densifying path (which handles any Vector).
+
+    A :class:`~flinkml_tpu.table.CsrColumn` answers for itself, with no
+    row scanned or built: it is returned as it is, and
+    :func:`labeled_sparse_data`, ``ops.sparse.csr_from_sparse_vectors``
+    and ``ops.sparse.sparse_margins`` take its arrays."""
+    csr = table.csr_column(features_col)
+    if csr is not None:
+        return csr if len(csr) else None
     col = table.column(features_col)
     if (
         col.dtype == object
@@ -140,20 +148,27 @@ def labeled_sparse_data(
 ):
     """Sparse analog of :func:`labeled_data`: host CSR arrays + labels.
 
-    Returns ``(indptr, indices, values, dim, y, w)``.
+    Returns ``(indptr, indices, values, dim, y, w)``; the whole of it is
+    the fit's ``hostdata.ingest`` span, as :func:`labeled_data` is the
+    dense fit's.
     """
     from flinkml_tpu.ops.sparse import csr_from_sparse_vectors
 
-    col = table.column(features_col)
-    indptr, indices, values, dim = csr_from_sparse_vectors(col, dtype=dtype)
-    y = np.asarray(table.column(label_col), dtype=dtype).reshape(-1)
-    if y.shape[0] != indptr.size - 1:
-        raise ValueError(
-            f"label column {label_col!r} has {y.shape[0]} rows, features "
-            f"have {indptr.size - 1}"
-        )
-    if weight_col is not None:
-        w = np.asarray(table.column(weight_col), dtype=dtype).reshape(-1)
-    else:
-        w = np.ones(y.shape[0], dtype=dtype)
+    with span("hostdata.ingest"):
+        col = table.csr_column(features_col)
+        if col is None:
+            col = table.column(features_col)
+        # A CsrColumn's arrays come back as they are where the dtypes
+        # fit: no copy of a Criteo-sized column.
+        indptr, indices, values, dim = csr_from_sparse_vectors(col, dtype=dtype)
+        y = np.asarray(table.column(label_col), dtype=dtype).reshape(-1)
+        if y.shape[0] != indptr.size - 1:
+            raise ValueError(
+                f"label column {label_col!r} has {y.shape[0]} rows, features "
+                f"have {indptr.size - 1}"
+            )
+        if weight_col is not None:
+            w = np.asarray(table.column(weight_col), dtype=dtype).reshape(-1)
+        else:
+            w = np.ones(y.shape[0], dtype=dtype)
     return indptr, indices, values, dim, y, w

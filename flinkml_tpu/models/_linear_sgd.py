@@ -35,6 +35,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+# The structural CSR check the sparse stream paths run in pass 0: the one
+# a ``CsrColumn`` runs at construction (without its row-order part).
+from flinkml_tpu.linalg import check_csr_structure as _check_csr_structure
 from flinkml_tpu.ops.losses import margin_terms as _margin_grad
 from flinkml_tpu.ops.sparse import chunked_run_totals
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
@@ -825,7 +828,12 @@ def prepare_sparse_buckets(
 
     ``seed`` shuffles rows *within* each bucket (bucket membership depends
     only on nnz, so this is the reference's partition shuffle applied
-    post-bucketing — no re-gather of the full CSR needed).
+    post-bucketing — no re-gather of the full CSR needed). Rows of one
+    width are one bucket, so their order is
+    ``default_rng(seed).permutation(rows)``, the dense fit's. Under the
+    default layout each bucket's two blocks reach the mesh in that order
+    through :meth:`DeviceMesh.shard_rows`, round by round, with no
+    permuted copy on the host.
     ``layout`` selects the gradient-reduction layout (see
     :func:`_sparse_layout`): ``sorted`` adds the per-window sort tables
     (+8 B/cell of HBM), ``cumsum`` the sorted-cell value/row tables and
@@ -833,32 +841,53 @@ def prepare_sparse_buckets(
     sort AND permutation gather (see ``make_sparse_step_bucketed``).
     """
     from flinkml_tpu.ops.sparse import pack_ell_buckets
+    from flinkml_tpu.utils.metrics import metrics
 
     indptr = np.asarray(indptr, dtype=np.int64)
     n = indptr.size - 1
     y = np.asarray(y, dtype=dtype)
     w = np.asarray(w, dtype=dtype)
     p_size = mesh.axis_size()
-    buckets, row_ids = pack_ell_buckets(
-        indptr, indices, values, dim, max_buckets=max_buckets, dtype=dtype,
-    )
+    with span("hostdata.sparse_pack"):
+        # Bucket choice and ELL fill. Rows of one width (hashed
+        # categorical data) are one bucket of two views: nothing is
+        # filled, and the span is the look at ``indptr``.
+        buckets, row_ids = pack_ell_buckets(
+            indptr, indices, values, dim, max_buckets=max_buckets, dtype=dtype,
+        )
+    counts = metrics.group("hostdata.sparse")
+    counts.counter("cells", float(indptr[-1]))
+    counts.counter("padded_cells",
+                   float(sum(b["indices"].size for b in buckets)))
+    counts.counter("buckets", float(len(buckets)))
     rng = np.random.default_rng(seed) if seed is not None else None
     data_args: list = []
     local_bss: list = []
     for bucket, rows in zip(buckets, row_ids):
         bi, bv = bucket["indices"], bucket["values"]
-        if rng is not None:
-            order = rng.permutation(rows.size)
-            bi, bv, rows = bi[order], bv[order], rows[order]
-        idx_pad, _ = pad_to_multiple(bi, p_size)
-        val_pad, _ = pad_to_multiple(bv, p_size)
-        yb_pad, _ = pad_to_multiple(y[rows], p_size)
-        wb_pad, _ = pad_to_multiple(w[rows], p_size)
-        data_args += [
-            mesh.shard_batch(idx_pad), mesh.shard_batch(val_pad),
-            mesh.shard_batch(yb_pad), mesh.shard_batch(wb_pad),
-        ]
-        n_local = idx_pad.shape[0] // p_size
+        with span("hostdata.shuffle"):
+            order = (rng.permutation(rows.size) if rng is not None
+                     else np.arange(rows.size))
+            picked = rows[order]
+            yb_pad, _ = pad_to_multiple(y[picked], p_size)
+            wb_pad, _ = pad_to_multiple(w[picked], p_size)
+        if layout == "unsorted":
+            # One pass, as the dense fit's: the seeded order gathered
+            # round by round on its way to the device, no permuted copy
+            # of the block on the host (DeviceMesh.shard_rows places
+            # exactly shard_batch(pad(block[order]))).
+            idxd = mesh.shard_rows(bi, order, np.int32)
+            vald = mesh.shard_rows(bv, order, dtype)
+        else:
+            # The window tables are built on the host from the permuted,
+            # padded block: these layouts keep the copy.
+            with span("hostdata.shuffle"):
+                idx_pad, _ = pad_to_multiple(bi[order], p_size)
+                val_pad, _ = pad_to_multiple(bv[order], p_size)
+            idxd, vald = mesh.shard_batch(idx_pad), mesh.shard_batch(val_pad)
+        data_args += [idxd, vald,
+                      mesh.shard_batch(yb_pad), mesh.shard_batch(wb_pad)]
+        n_local = idxd.shape[0] // p_size
         share = max(1, math.ceil(global_batch_size * rows.size / (n * p_size)))
         local_bs = min(share, n_local)
         local_bss.append(local_bs)
@@ -1807,35 +1836,6 @@ def _ell_width_for(max_nnz: int) -> int:
     stream's per-batch nnz variation maps to a log-bounded set of
     compiled step shapes, not one per batch."""
     return 1 << max(int(max_nnz) - 1, 0).bit_length()
-
-
-def _check_csr_structure(indptr, indices, sparse_dim: int):
-    """Structural CSR validation shared by both sparse stream paths;
-    returns ``nnz = diff(indptr)``.
-
-    A non-monotone indptr passes the ragged check (``indices.size ==
-    indptr[-1]``) but later raises rank-locally inside the ELL fill
-    (``np.repeat`` with negative counts) on the prefetch thread at place
-    time — the exact mid-collective hang class pass-0 validation exists
-    to prevent — so it must be rejected HERE, where the failure rides the
-    held-error rendezvous like every other ingest check. Out-of-range
-    column indices never raise at all: the jitted gather/scatter clamps
-    them, silently misattributing gradient mass to boundary columns."""
-    nnz = np.diff(indptr)
-    if indptr.size == 0 or indptr[0] != 0 or np.any(nnz < 0):
-        raise ValueError(
-            "invalid CSR batch: indptr must start at 0 and be "
-            "non-decreasing"
-        )
-    if indices.size and (
-        int(indices.min()) < 0 or int(indices.max()) >= sparse_dim
-    ):
-        raise ValueError(
-            "invalid CSR batch: column indices must lie in "
-            f"[0, {sparse_dim}); got range "
-            f"[{int(indices.min())}, {int(indices.max())}]"
-        )
-    return nnz
 
 
 def _pack_uniform_ell(indptr, indices, values, dtype, width=None):

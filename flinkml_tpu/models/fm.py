@@ -458,9 +458,9 @@ class _FMModelBase(_FMParams, Model):
     def _margin(self, table: Table) -> np.ndarray:
         from flinkml_tpu.models._data import sparse_features
 
-        vecs = sparse_features(table, self.get(self.FEATURES_COL))
-        if vecs is not None:
-            return self._margin_sparse(vecs)
+        if sparse_features(table, self.get(self.FEATURES_COL)) is not None:
+            # Row objects: a CsrColumn's are built here, on demand.
+            return self._margin_sparse(table.column(self.get(self.FEATURES_COL)))
         x = features_matrix(table, self.get(self.FEATURES_COL))
         xv = x @ self._v
         x2v2 = (x * x) @ (self._v * self._v)

@@ -260,7 +260,9 @@ _SCORING_CHUNK_ELEMS = 16 << 20
 
 def sparse_margins(vectors: Sequence[SparseVector], coef,
                    max_buckets: int = 4) -> np.ndarray:
-    """Row-wise dots ``X @ coef`` for SparseVector rows, skew-proof.
+    """Row-wise dots ``X @ coef`` for SparseVector rows (or a
+    :class:`~flinkml_tpu.table.CsrColumn`, whose arrays are taken as
+    they are), skew-proof.
 
     ``coef`` may be a vector ``[d]`` (returns ``[n]``) or a class matrix
     ``[k, d]`` (returns ``[n, k]`` — multinomial scoring). Inference-side
@@ -324,7 +326,17 @@ def csr_from_sparse_vectors(vectors: Sequence[SparseVector],
 
     ``dtype`` bounds host staging memory — at Criteo scale (~1e9 nnz)
     float32 staging halves the transient footprint vs float64.
+
+    A :class:`~flinkml_tpu.table.CsrColumn` already is those arrays: they
+    come back by reference (``values`` cast only if its dtype differs).
     """
+    from flinkml_tpu.table import CsrColumn
+
+    if isinstance(vectors, CsrColumn):
+        if len(vectors) == 0:
+            raise ValueError("empty batch")
+        return (vectors.indptr, vectors.indices,
+                vectors.values.astype(dtype, copy=False), vectors.dim)
     vectors = list(vectors)
     if not vectors:
         raise ValueError("empty batch")
@@ -403,6 +415,26 @@ def choose_ell_widths(nnz: np.ndarray, max_buckets: int = 4,
     return sorted(set(bounds))
 
 
+#: Rows a chunk of :func:`uniform_row_width` compares: a whole-column
+#: ``diff`` of 16.8 M row pointers cost 0.44 s a fit in fresh temporaries.
+_WIDTH_CHECK_ROWS = 1 << 20
+
+
+def uniform_row_width(indptr: np.ndarray):
+    """``k`` if every CSR row holds exactly ``k >= 1`` cells (hashed
+    categorical data: Criteo's 39), else None. Then ``indices`` and
+    ``values`` reshape to ``[rows, k]`` ELL blocks with no padding."""
+    n = indptr.shape[0] - 1
+    k = int(indptr[1]) if n > 0 else 0
+    if k < 1 or int(indptr[-1]) != n * k:
+        return None
+    for lo in range(0, n, _WIDTH_CHECK_ROWS):
+        hi = min(lo + _WIDTH_CHECK_ROWS, n)
+        if np.any(indptr[lo + 1:hi + 1] - indptr[lo:hi] != k):
+            return None
+    return k
+
+
 def fill_ell(bi, bv, row_starts, counts, indices, values) -> None:
     """Vectorized CSR→ELL fill: write each row's ``counts[r]`` cells
     (sourced at ``row_starts[r]``) into the padded blocks ``bi``/``bv``
@@ -432,6 +464,16 @@ def pack_ell_buckets(indptr, indices, values, dim: int,
     """
     indptr = np.asarray(indptr, dtype=np.int64)
     n = indptr.size - 1
+    width = uniform_row_width(indptr)
+    if width is not None:
+        # Every row as wide as the block: CSR already is the one ELL
+        # block, so it is two views and no cell is copied.
+        block = {
+            "indices": np.asarray(indices, np.int32).reshape(n, width),
+            "values": np.asarray(values).astype(dtype, copy=False)
+                        .reshape(n, width),
+        }
+        return [block], [np.arange(n)]
     nnz = np.diff(indptr)
     bucket_widths = choose_ell_widths(nnz, max_buckets=max_buckets)
     edges = np.asarray(bucket_widths, dtype=np.int64)

@@ -227,6 +227,169 @@ class SortedSparseColumn(PaddedDeviceColumn):
         return out
 
 
+class CsrColumn:
+    """A host SPARSE column that *is* CSR: row ``r`` holds the cells
+    ``indices[indptr[r]:indptr[r + 1]]`` / ``values[...]`` of a
+    ``dim``-wide vector — what a LIBSVM parser or a hashing stage
+    produces in bulk, carried by a :class:`Table` without one
+    ``SparseVector`` object a row.
+
+    ``indptr`` is int64, ``indices`` int32, ``values`` any float dtype;
+    arrays that already fit are kept by reference, never copied. Every
+    row keeps :class:`~flinkml_tpu.linalg.SparseVector`'s invariant
+    (indices in ``[0, dim)``, strictly ascending), validated ONCE here,
+    vectorised (:func:`~flinkml_tpu.linalg.check_csr_structure`); the
+    row operations below build from a validated column and do not look
+    again. The sparse estimators (``LogisticRegression``, ``LinearSVC``,
+    ``LinearRegression`` fit and transform) take the arrays as they are;
+    a row-wise consumer asks :meth:`Table.column`, which builds the
+    object array of ``SparseVector``s on demand (:meth:`to_vectors`).
+    """
+
+    __slots__ = ("indptr", "indices", "values", "dim")
+
+    def __init__(self, indptr, indices, values, dim: int):
+        from flinkml_tpu.linalg import check_csr_structure
+
+        indptr = np.asarray(indptr, dtype=np.int64)
+        values = np.asarray(values)
+        if indptr.ndim != 1 or np.ndim(indices) != 1 or values.ndim != 1:
+            raise ValueError("indptr, indices and values must be 1-D")
+        if values.shape[0] != np.shape(indices)[0]:
+            raise ValueError(
+                f"indices hold {np.shape(indices)[0]} cells, values "
+                f"{values.shape[0]}"
+            )
+        if values.dtype.kind != "f":
+            values = values.astype(np.float64)
+        # Range first, on the caller's integers: a cast to int32 must
+        # not wrap an index that is out of range anyway.
+        check_csr_structure(indptr, indices, int(dim), ascending=True)
+        if indptr.size and int(indptr[-1]) != values.shape[0]:
+            raise ValueError(
+                f"indptr ends at {int(indptr[-1])}, the column holds "
+                f"{values.shape[0]} cells"
+            )
+        self._set(indptr, np.asarray(indices, dtype=np.int32), values, dim)
+        # The count of rows built for row-wise consumers exists, at 0,
+        # from the first column on: "none built" is a reading.
+        _materialization_metrics().counter("csr_rows_materialized", 0.0)
+
+    def _set(self, indptr, indices, values, dim) -> "CsrColumn":
+        self.indptr, self.indices, self.values = indptr, indices, values
+        self.dim = int(dim)
+        return self
+
+    @classmethod
+    def _trusted(cls, indptr, indices, values, dim) -> "CsrColumn":
+        """Rows of a validated column: no second look."""
+        return object.__new__(cls)._set(indptr, indices, values, dim)
+
+    @classmethod
+    def from_vectors(cls, vectors, dtype=np.float64) -> "CsrColumn":
+        """The column holding a sequence of ``SparseVector`` rows."""
+        from flinkml_tpu.linalg import SparseVector
+
+        vectors = list(vectors)
+        if not vectors or not all(isinstance(v, SparseVector) for v in vectors):
+            raise ValueError("from_vectors needs at least one SparseVector")
+        dim = vectors[0].size()
+        if any(v.size() != dim for v in vectors):
+            raise ValueError("rows differ in dim")
+        indptr = np.zeros(len(vectors) + 1, np.int64)
+        np.cumsum([v.indices.size for v in vectors], out=indptr[1:])
+        return cls._trusted(
+            indptr,
+            np.concatenate([v.indices for v in vectors]).astype(np.int32),
+            np.concatenate([v.values for v in vectors]).astype(dtype),
+            dim,
+        )
+
+    # What a Table asks of any column.
+    @property
+    def shape(self):
+        return (self.indptr.shape[0] - 1,)
+
+    ndim = 1
+    dtype = np.dtype(object)
+
+    def __len__(self) -> int:
+        return self.indptr.shape[0] - 1
+
+    # -- row operations (on the arrays; no per-row object) ----------------
+    def __getitem__(self, rows) -> "CsrColumn":
+        """``column[a:b]`` and ``column[row numbers or mask]``, as an array
+        column answers them; a single row is :meth:`Table.column`'s to
+        build."""
+        if isinstance(rows, slice):
+            if rows.step not in (None, 1):
+                return self.take(rows)
+            return self.slice(rows.start, rows.stop)
+        if np.ndim(rows) == 0:
+            raise TypeError(
+                "a CsrColumn is indexed by a slice, row numbers or a mask; "
+                "Table.column(name)[i] gives row i as a SparseVector"
+            )
+        return self.take(rows)
+
+    def slice(self, start, stop) -> "CsrColumn":
+        start, stop, _ = slice(start, stop).indices(len(self))
+        stop = max(start, stop)
+        lo, hi = int(self.indptr[start]), int(self.indptr[stop])
+        return CsrColumn._trusted(
+            self.indptr[start:stop + 1] - lo, self.indices[lo:hi],
+            self.values[lo:hi], self.dim,
+        )
+
+    def take(self, rows) -> "CsrColumn":
+        """The rows ``rows`` (anything that indexes an array of row
+        numbers: integers, negative ones, a boolean mask), in that
+        order."""
+        rows = np.arange(len(self))[rows].reshape(-1)
+        counts = self.indptr[rows + 1] - self.indptr[rows]
+        indptr = np.zeros(rows.shape[0] + 1, np.int64)
+        np.cumsum(counts, out=indptr[1:])
+        # Cell j of the result is cell (its row's first cell + its slot).
+        src = (np.repeat(self.indptr[rows] - indptr[:-1], counts)
+               + np.arange(int(indptr[-1]), dtype=np.int64))
+        return CsrColumn._trusted(
+            indptr, self.indices[src], self.values[src], self.dim)
+
+    def concat(self, other: "CsrColumn") -> "CsrColumn":
+        if not isinstance(other, CsrColumn) or other.dim != self.dim:
+            raise ValueError(
+                "a CsrColumn concatenates with a CsrColumn of its own dim")
+        return CsrColumn._trusted(
+            np.concatenate([self.indptr, other.indptr[1:] + self.indptr[-1]]),
+            np.concatenate([self.indices, other.indices]),
+            np.concatenate([self.values, other.values]),
+            self.dim,
+        )
+
+    def to_vectors(self) -> np.ndarray:
+        """The object array of ``SparseVector`` rows (int64 indices,
+        float64 values, views of two whole-column casts), for row-wise
+        consumers; counted in ``table.csr_rows_materialized``."""
+        from flinkml_tpu.linalg import SparseVector
+
+        n = len(self)
+        idx = self.indices.astype(np.int64)
+        val = self.values.astype(np.float64)
+        idx.setflags(write=False)
+        val.setflags(write=False)
+        bounds = self.indptr.tolist()
+        out = np.empty(n, dtype=object)
+        for r in range(n):
+            sl = slice(bounds[r], bounds[r + 1])
+            out[r] = SparseVector._from_sorted(self.dim, idx[sl], val[sl])
+        _materialization_metrics().counter("csr_rows_materialized", float(n))
+        return out
+
+    def __repr__(self) -> str:  # pragma: no cover
+        return (f"CsrColumn({len(self)} rows, dim {self.dim}, "
+                f"{self.indices.shape[0]} cells, {self.values.dtype})")
+
+
 def _is_device_backed(x: Any) -> bool:
     return _is_device_array(x) or isinstance(x, PaddedDeviceColumn)
 
@@ -254,7 +417,10 @@ class Table:
       - 1-D arrays (scalar columns: labels, weights, categories),
       - N-D arrays (vector/matrix columns: features ``[rows, dim]``),
       - object arrays (ragged data, e.g. sparse vectors before densify),
-      - ``jax.Array`` buffers (device-resident columns; see module docstring).
+      - ``jax.Array`` buffers (device-resident columns; see module docstring),
+      - a :class:`CsrColumn` (a sparse column as CSR arrays): carried by
+        reference, row-indexed on its arrays; :meth:`column` builds its
+        ``SparseVector`` rows on demand, :meth:`csr_column` hands it over.
     """
 
     def __init__(self, columns: Mapping[str, Any]):
@@ -263,7 +429,7 @@ class Table:
         conv: Dict[str, Any] = {}
         n_rows: Optional[int] = None
         for name, col in columns.items():
-            if isinstance(col, np.ndarray) or _is_device_backed(col):
+            if isinstance(col, (np.ndarray, CsrColumn)) or _is_device_backed(col):
                 arr = col
             else:
                 arr = _to_array(col)
@@ -331,6 +497,10 @@ class Table:
         transfer, cached); until this call they cost no host bandwidth.
         """
         col = self._raw_column(name)
+        if isinstance(col, CsrColumn):
+            if name not in self._host_cache:
+                self._host_cache[name] = col.to_vectors()
+            return self._host_cache[name]
         if not _is_device_backed(col):
             return col
         if name not in self._host_cache:
@@ -346,6 +516,18 @@ class Table:
         return self._host_cache[name]
 
     __getitem__ = column
+
+    def csr_column(self, name: str) -> Optional[CsrColumn]:
+        """The column's :class:`CsrColumn` if it is one, else None; no
+        row is built."""
+        col = self._raw_column(name)
+        return col if isinstance(col, CsrColumn) else None
+
+    def _host_rows(self, name: str):
+        """What the row-indexed ops index: a CsrColumn as it is, any
+        other column as :meth:`column` gives it."""
+        col = self._raw_column(name)
+        return col if isinstance(col, CsrColumn) else self.column(name)
 
     def device_column(self, name: str):
         """The column as a device-resident ``jax.Array`` — no host copy for
@@ -438,7 +620,7 @@ class Table:
 
     def with_column(self, name: str, values: Any) -> "Table":
         cols = dict(self._columns)
-        if isinstance(values, np.ndarray) or _is_device_backed(values):
+        if isinstance(values, (np.ndarray, CsrColumn)) or _is_device_backed(values):
             cols[name] = values
         else:
             cols[name] = _to_array(values)
@@ -453,17 +635,22 @@ class Table:
 
     # Row-indexed ops operate on the host representation.
     def take(self, indices: np.ndarray) -> "Table":
-        return Table({n: self.column(n)[indices] for n in self._columns})
+        return Table({n: self._host_rows(n)[indices] for n in self._columns})
 
     def slice(self, start: int, stop: int) -> "Table":
-        return Table({n: self.column(n)[start:stop] for n in self._columns})
+        return Table({n: self._host_rows(n)[start:stop] for n in self._columns})
 
     def concat(self, other: "Table") -> "Table":
         if set(self.column_names) != set(other.column_names):
             raise ValueError("concat requires identical column sets")
-        return Table(
-            {n: np.concatenate([self.column(n), other.column(n)]) for n in self.column_names}
-        )
+
+        def join(n):
+            a, b = self.csr_column(n), other.csr_column(n)
+            if a is not None and b is not None:
+                return a.concat(b)
+            return np.concatenate([self.column(n), other.column(n)])
+
+        return Table({n: join(n) for n in self.column_names})
 
     # -- iteration ---------------------------------------------------------
     def batches(self, batch_size: int, drop_remainder: bool = False) -> Iterator["Table"]:

@@ -207,7 +207,11 @@ def test_tracked_lock_tokens_and_reentrancy():
     assert "lock:test" not in held_lock_tokens()
 
 
-def test_per_mesh_lock_registry(mesh):
+def test_per_mesh_lock_registry(mesh, monkeypatch):
+    # An empty registry: a device set that overlaps this mesh's, left by
+    # an earlier test of the same worker process, would make every call a
+    # new composite (which files share a worker changes with the suite).
+    monkeypatch.setattr(dispatch, "_MESH_LOCKS", {})
     # Same device set -> same lock object.
     assert local_execution_lock(mesh) is local_execution_lock(mesh)
     mesh_token = local_execution_lock(mesh).token

@@ -227,3 +227,103 @@ def test_fit_reproduces_the_parent_commits_coefficients(monkeypatch, name, dtype
                      np.ravel(PARENT_COEFFICIENTS[(name, dtype)])])
     got = _fit_seeded(name, dtype)
     np.testing.assert_allclose(got.ravel(), want, rtol=1e-12, atol=0)
+
+
+# -- the sparse trainer's blocks (PR 26): int32 indices and float32 values,
+# two arrays in one row order ------------------------------------------------
+
+SPARSE_DIM, SPARSE_NNZ = 512, 39
+
+
+def _sparse_rows(rows, uniform, seed=0):
+    """CSR of ``rows`` rows (39 cells each, or 0 to 59), labels, weights."""
+    rng = np.random.default_rng(seed)
+    nnz = (np.full(rows, SPARSE_NNZ) if uniform
+           else rng.integers(0, 60, size=rows))
+    indptr = np.concatenate([[0], np.cumsum(nnz)]).astype(np.int64)
+    indices = np.concatenate(
+        [np.sort(rng.choice(SPARSE_DIM, k, replace=False)) for k in nnz]
+    ).astype(np.int32)
+    values = rng.normal(size=indices.size).astype(np.float32)
+    return (indptr, indices, values,
+            rng.integers(0, 2, rows).astype(np.float32),
+            (rng.random(rows) + 0.5).astype(np.float32))
+
+
+def _old_sparse_pattern(indptr, indices, values, y, w, mesh, seed, batch):
+    """What ``prepare_sparse_buckets`` did before the one-pass placement:
+    each bucket's block permuted whole on the host, padded, placed in one
+    transfer an array."""
+    from flinkml_tpu.ops.sparse import pack_ell_buckets
+
+    p, n = mesh.axis_size(), indptr.size - 1
+    buckets, row_ids = pack_ell_buckets(indptr, indices, values, SPARSE_DIM)
+    rng = np.random.default_rng(seed)
+    placed, sizes = [], []
+    for bucket, rows in zip(buckets, row_ids):
+        order = rng.permutation(rows.size)
+        for a in (bucket["indices"][order], bucket["values"][order],
+                  y[rows[order]], w[rows[order]]):
+            placed.append(mesh.shard_batch(pad_to_multiple(a, p)[0]))
+        share = max(1, -(-batch * rows.size // (n * p)))
+        sizes.append(min(share, placed[-1].shape[0] // p))
+    return tuple(placed), tuple(sizes)
+
+
+@pytest.mark.parametrize("stage_bytes", [TINY_STAGE, None],
+                         ids=["many-rounds", "one-round"])
+@pytest.mark.parametrize("devices", [1, 8])
+@pytest.mark.parametrize("rows", [1003, 37])
+@pytest.mark.parametrize("uniform", [True, False], ids=["uniform", "ragged"])
+def test_sparse_blocks_are_placed_as_the_whole_array_passes_placed_them(
+        monkeypatch, uniform, rows, devices, stage_bytes):
+    if stage_bytes is not None:
+        monkeypatch.setattr(mesh_mod, "_STAGE_BYTES", stage_bytes)
+    mesh = _mesh(devices)
+    indptr, indices, values, y, w = _sparse_rows(rows, uniform)
+    got, got_sizes = _linear_sgd.prepare_sparse_buckets(
+        indptr, indices, values, SPARSE_DIM, y, w, mesh, 256, seed=11)
+    want, want_sizes = _old_sparse_pattern(
+        indptr, indices, values, y, w, mesh, 11, 256)
+    assert got_sizes == want_sizes and len(got) == len(want)
+    assert len(got) == (4 if uniform else 4 * len(got_sizes))
+    assert got[0].dtype == np.int32 and got[1].dtype == np.float32
+    for g, e in zip(got, want):
+        _assert_same_placement(g, e)
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_an_int32_block_goes_through_the_staging_rounds(monkeypatch, devices):
+    """Indices take the dense features' path: rounds through the rotating
+    buffers, no cast, no scratch; rows past the table's end are zero."""
+    monkeypatch.setattr(mesh_mod, "_STAGE_BYTES", TINY_STAGE)
+    mesh, rows = _mesh(devices), 1003
+    block = np.random.default_rng(2).integers(
+        0, 1 << 20, size=(rows, SPARSE_NNZ)).astype(np.int32)
+    perm = np.random.default_rng(3).permutation(rows)
+    counters = metrics.group("span")
+    before = counters.snapshot()["counters"].get("mesh.shard_batch.calls", 0)
+    placed = mesh.shard_rows(block, perm, np.int32)
+    rounds = counters.snapshot()["counters"]["mesh.shard_batch.calls"] - before
+    chunk = max(1, TINY_STAGE // (devices * SPARSE_NNZ * 4))
+    assert rounds == -(-(-(-rows // devices)) // chunk) > mesh_mod._STAGE_BUFFERS
+    assert placed.dtype == np.int32
+    want = mesh.shard_batch(pad_to_multiple(block[perm], devices)[0])
+    _assert_same_placement(placed, want)
+
+
+@pytest.mark.parametrize("layout", ["sorted", "cumsum"])
+def test_the_other_layouts_keep_the_whole_array_placement(monkeypatch, layout):
+    """Their window tables are built on the host from the permuted block;
+    the blocks themselves still equal the unsorted layout's."""
+    mesh = _mesh(8)
+    indptr, indices, values, y, w = _sparse_rows(1003, True)
+    plain, sizes = _linear_sgd.prepare_sparse_buckets(
+        indptr, indices, values, SPARSE_DIM, y, w, mesh, 256, seed=11)
+    got, got_sizes = _linear_sgd.prepare_sparse_buckets(
+        indptr, indices, values, SPARSE_DIM, y, w, mesh, 256, seed=11,
+        layout=layout)
+    assert got_sizes == sizes
+    assert len(got) == _linear_sgd._SPARSE_ARGS_PER_BUCKET[layout]
+    for g, e in zip(got[:4], plain):
+        _assert_same_placement(g, e)
