@@ -14,6 +14,7 @@ import numpy as np
 
 from flinkml_tpu.linalg import SparseVector, Vector, stack_vectors
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.metrics import metrics
 from flinkml_tpu.utils.profiling import span
 
 
@@ -45,27 +46,109 @@ def labeled_data(
     features_col: str,
     label_col: str,
     weight_col: Optional[str] = None,
-    features_dtype=np.float64,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Extract (X [n,d], y [n], w [n]); weight defaults to 1.0 per row.
+    """Extract (X [n,d], y [n], w [n]) as float64 host arrays; weight
+    defaults to 1.0 per row.
 
-    ``features_dtype`` is :func:`features_matrix`'s ``dtype``: None keeps
-    a floating column as the table has it (a contiguous one is not
-    copied), for a caller that casts on the way to the device
-    (``_linear_sgd._place_shuffled``) and computes nothing on the host.
-    Labels and weights are float64 either way."""
+    For a caller that computes on the host. The trainers that only place
+    the table on the mesh (``_linear_sgd._place_shuffled``) take
+    :func:`fit_columns` instead: no float64 copy, no vector of ones."""
     with span("hostdata.ingest"):
-        x = features_matrix(table, features_col, dtype=features_dtype)
+        x = features_matrix(table, features_col)
         y = np.asarray(table.column(label_col), dtype=np.float64).reshape(-1)
-        if y.shape[0] != x.shape[0]:
-            raise ValueError(
-                f"label column {label_col!r} has {y.shape[0]} rows, features have {x.shape[0]}"
-            )
+        _check_rows(label_col, y, x.shape[0])
         if weight_col is not None:
             w = np.asarray(table.column(weight_col), dtype=np.float64).reshape(-1)
         else:
             w = np.ones(x.shape[0], dtype=np.float64)
     return x, y, w
+
+
+def _check_rows(label_col: str, y: np.ndarray, rows: int) -> None:
+    if y.shape[0] != rows:
+        raise ValueError(
+            f"label column {label_col!r} has {y.shape[0]} rows, features have {rows}"
+        )
+
+
+#: Labels one step of :class:`LabelFacts`' pass compares: its temporaries
+#: stay in the cache, and a column of millions costs no fresh pages.
+_LABEL_SCAN_ROWS = 1 << 18
+
+
+class LabelFacts:
+    """A label column as the table holds it (``values``, 1-D, no copy of
+    a numeric column) and what ONE chunked pass over it says: ``binary``
+    (every label is 0 or 1); ``lo`` and ``hi``; ``integral``. A NaN label
+    makes ``binary`` and ``integral`` false and is ``lo`` and ``hi``.
+
+    The fits' label checks answer from these. The distinct values
+    themselves (:meth:`distinct`: the one sort of the column) are asked
+    for only where the labels are not all 0 or 1."""
+
+    __slots__ = ("values", "binary", "lo", "hi", "integral", "_distinct")
+
+    def __init__(self, column):
+        y = np.asarray(column).reshape(-1)
+        if y.dtype.kind not in "biuf":
+            y = y.astype(np.float64)
+        self.values, self._distinct = y, None
+        self.binary = self.integral = True
+        self.lo, self.hi = float("inf"), float("-inf")
+        exact = y.dtype.kind != "f"  # bool and integer columns
+        for start in range(0, y.shape[0], _LABEL_SCAN_ROWS):
+            part = y[start:start + _LABEL_SCAN_ROWS]
+            lo, hi = float(part.min()), float(part.max())
+            # np.minimum, not min(): a NaN stays.
+            self.lo = float(np.minimum(self.lo, lo))
+            self.hi = float(np.maximum(self.hi, hi))
+            if lo >= 0 and hi <= 1 and (
+                    exact or bool(np.all((part == 0) | (part == 1)))):
+                continue
+            self.binary = False
+            if not exact and self.integral:
+                self.integral = bool(np.all(part == np.rint(part)))
+
+    def distinct(self) -> np.ndarray:
+        """The sorted distinct labels (``np.unique`` over the column,
+        floating as every fit has printed them; a fit that asks counts
+        one ``label_unique_fallbacks``)."""
+        if self._distinct is None:
+            metrics.group("hostdata").counter("label_unique_fallbacks")
+            found = np.unique(self.values)
+            if found.dtype.kind != "f":
+                found = found.astype(np.float64)
+            self._distinct = found
+        return self._distinct
+
+
+def _label_and_weight_columns(table: Table, label_col: str,
+                              weight_col: Optional[str], rows: int):
+    """``(LabelFacts, weights)`` as the table holds them; ``weights`` is
+    None where there is no weight column (the trainer makes unit weights
+    on the device)."""
+    labels = LabelFacts(table.column(label_col))
+    _check_rows(label_col, labels.values, rows)
+    if weight_col is None:
+        return labels, None
+    return labels, np.asarray(table.column(weight_col)).reshape(-1)
+
+
+def fit_columns(table: Table, features_col: str, label_col: str,
+                weight_col: Optional[str] = None):
+    """:func:`labeled_data` for a trainer that places the table on the
+    mesh and computes nothing on the host (``_linear_sgd._place_shuffled``
+    casts each column chunk by chunk on its way up): ``(x, labels, w)``.
+
+    Every column is taken as the table has it (a contiguous floating
+    features column is not copied); ``labels`` is the column's
+    :class:`LabelFacts`, read once here; ``w`` is None without a weight
+    column. The whole of it is the fit's ``hostdata.ingest`` span."""
+    with span("hostdata.ingest"):
+        x = features_matrix(table, features_col, dtype=None)
+        labels, w = _label_and_weight_columns(
+            table, label_col, weight_col, x.shape[0])
+    return x, labels, w
 
 
 def sparse_features(table: Table, features_col: str):
@@ -130,12 +213,15 @@ def hashed_feature_matrix(
     return flat.reshape(n, num_buckets).astype(dtype)
 
 
-def check_binary_labels(y: np.ndarray, model_name: str) -> None:
-    """Validate labels ∈ {0, 1} (shared by the binomial classifiers)."""
-    labels = np.unique(y)
-    if not np.all(np.isin(labels, (0.0, 1.0))):
+def check_binary_labels(labels, model_name: str) -> None:
+    """Validate labels ∈ {0, 1} (shared by the binomial classifiers).
+    ``labels`` is a label column, or the :class:`LabelFacts` a fit's
+    ingest has made of it already (then the column is not read again)."""
+    if not isinstance(labels, LabelFacts):
+        labels = LabelFacts(labels)
+    if not labels.binary:
         raise ValueError(
-            f"{model_name} requires labels in {{0, 1}}, got {labels}"
+            f"{model_name} requires labels in {{0, 1}}, got {labels.distinct()}"
         )
 
 
@@ -148,27 +234,48 @@ def labeled_sparse_data(
 ):
     """Sparse analog of :func:`labeled_data`: host CSR arrays + labels.
 
-    Returns ``(indptr, indices, values, dim, y, w)``; the whole of it is
-    the fit's ``hostdata.ingest`` span, as :func:`labeled_data` is the
-    dense fit's.
+    Returns ``(indptr, indices, values, dim, y, w)`` with ``y`` and ``w``
+    host arrays of ``dtype`` (the streamed fits cache them); the whole of
+    it is the fit's ``hostdata.ingest`` span, as :func:`labeled_data` is
+    the dense fit's. The table-level sparse fits take
+    :func:`sparse_fit_columns` instead.
     """
-    from flinkml_tpu.ops.sparse import csr_from_sparse_vectors
-
     with span("hostdata.ingest"):
-        col = table.csr_column(features_col)
-        if col is None:
-            col = table.column(features_col)
-        # A CsrColumn's arrays come back as they are where the dtypes
-        # fit: no copy of a Criteo-sized column.
-        indptr, indices, values, dim = csr_from_sparse_vectors(col, dtype=dtype)
+        indptr, indices, values, dim = _csr_arrays(table, features_col, dtype)
         y = np.asarray(table.column(label_col), dtype=dtype).reshape(-1)
-        if y.shape[0] != indptr.size - 1:
-            raise ValueError(
-                f"label column {label_col!r} has {y.shape[0]} rows, features "
-                f"have {indptr.size - 1}"
-            )
+        _check_rows(label_col, y, indptr.size - 1)
         if weight_col is not None:
             w = np.asarray(table.column(weight_col), dtype=dtype).reshape(-1)
         else:
             w = np.ones(y.shape[0], dtype=dtype)
     return indptr, indices, values, dim, y, w
+
+
+def _csr_arrays(table: Table, features_col: str, dtype):
+    from flinkml_tpu.ops.sparse import csr_from_sparse_vectors
+
+    col = table.csr_column(features_col)
+    if col is None:
+        col = table.column(features_col)
+    # A CsrColumn's arrays come back as they are where the dtypes fit: no
+    # copy of a Criteo-sized column.
+    return csr_from_sparse_vectors(col, dtype=dtype)
+
+
+def sparse_fit_columns(
+    table: Table,
+    features_col: str,
+    label_col: str,
+    weight_col: Optional[str] = None,
+    dtype=np.float32,
+):
+    """Sparse analog of :func:`fit_columns`, for
+    ``_linear_sgd.prepare_sparse_buckets``: ``(indptr, indices, values,
+    dim, labels, w)``, labels and weights as the table holds them
+    (``labels`` a :class:`LabelFacts`, ``w`` None without a weight
+    column). The fit's ``hostdata.ingest`` span."""
+    with span("hostdata.ingest"):
+        indptr, indices, values, dim = _csr_arrays(table, features_col, dtype)
+        labels, w = _label_and_weight_columns(
+            table, label_col, weight_col, indptr.size - 1)
+    return indptr, indices, values, dim, labels, w

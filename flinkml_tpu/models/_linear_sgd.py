@@ -39,8 +39,9 @@ from jax.sharding import PartitionSpec as P
 # a ``CsrColumn`` runs at construction (without its row-order part).
 from flinkml_tpu.linalg import check_csr_structure as _check_csr_structure
 from flinkml_tpu.ops.losses import margin_terms as _margin_grad
-from flinkml_tpu.ops.sparse import chunked_run_totals
+from flinkml_tpu.ops.sparse import chunked_run_totals, pack_ell_buckets
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
+from flinkml_tpu.utils.metrics import metrics
 from flinkml_tpu.utils.profiling import span
 
 _LOSS_KEYS = ("logistic", "hinge", "squared")
@@ -550,20 +551,34 @@ def _place_shuffled(x, y, w, mesh: DeviceMesh, seed: int, dtype):
     """The training table on the mesh in the row order ``seed`` fixes,
     padded with zero rows (weight 0) to the mesh: exactly what
     ``shard_batch(pad(a.astype(dtype)[perm]))`` places for each of the
-    three (``dtype`` None: each array's own). The features go up in one
-    chunked pass (:meth:`DeviceMesh.shard_rows`), never copied whole on
-    the host; labels and weights are small and go as they always did."""
-    p_size = mesh.axis_size()  # pad exactly to the mesh: identical windows always
+    three (``dtype`` None: each array's own). Every column goes up in one
+    chunked pass (:meth:`DeviceMesh.shard_rows`: gathered on the pool's
+    threads, cast in the gather, never copied whole on the host), the
+    labels and a weight column as one round of rows of width ``()``.
+    ``w`` None is unit weights: made on the device
+    (:meth:`DeviceMesh.shard_ones`), no host array at all."""
     with span("hostdata.shuffle"):
         perm = np.random.default_rng(seed).permutation(x.shape[0])
-        small = []
-        for a in (y, w):
-            if dtype is not None:
-                a = a.astype(dtype, copy=False)
-            small.append(pad_to_multiple(a[perm], p_size)[0])
-    xd = mesh.shard_rows(x, perm, dtype if dtype is not None else x.dtype)
-    yd, wd = (mesh.shard_batch(a) for a in small)
-    return xd, yd, wd
+    _count_unit_weights(w)
+    return (mesh.shard_rows(x, perm, dtype), mesh.shard_rows(y, perm, dtype),
+            _place_weights(w, perm, mesh, dtype))
+
+
+def _count_unit_weights(w) -> None:
+    """``hostdata.unit_weights_on_device``: fits that had no weight
+    column, whose weights the device made."""
+    if w is None:
+        metrics.group("hostdata").counter("unit_weights_on_device")
+
+
+def _place_weights(w, order, mesh: DeviceMesh, dtype):
+    """A weight column in the row order ``order``, as the labels go; unit
+    weights (``w`` None; float64 where ``dtype`` names no width, as
+    ``labeled_data``'s ones were) made on the device."""
+    if w is not None:
+        return mesh.shard_rows(w, order, dtype)
+    return mesh.shard_ones(
+        order.shape[0], dtype if dtype is not None else np.float64)
 
 
 def train_linear_model(
@@ -588,6 +603,10 @@ def train_linear_model(
     precision=None,
 ) -> np.ndarray:
     """Dense distributed training; returns the coefficient on host.
+
+    ``y`` and ``w`` are 1-D columns of any numeric dtype, cast to the
+    training dtype on their way to the device; ``w`` None is a weight of
+    1.0 a row (see :func:`_place_shuffled`).
 
     ``reg``/``elastic_net`` follow the sklearn/Spark convention:
     l1 = reg * elastic_net, l2 = reg * (1 - elastic_net).
@@ -641,8 +660,9 @@ def train_linear_model(
                 devices=list(mesh.mesh.devices.reshape(-1)),
             )
         perm = np.random.default_rng(seed).permutation(n)
+        w = np.ones(n) if w is None else w[perm]
         return train_linear_plan(
-            x[perm], y[perm], w[perm], sharding_plan, mesh, loss=loss,
+            x[perm], y[perm], w, sharding_plan, mesh, loss=loss,
             max_iter=max_iter, learning_rate=learning_rate,
             global_batch_size=global_batch_size, reg=reg,
             elastic_net=elastic_net, tol=tol, dtype=dtype,
@@ -830,23 +850,24 @@ def prepare_sparse_buckets(
     only on nnz, so this is the reference's partition shuffle applied
     post-bucketing — no re-gather of the full CSR needed). Rows of one
     width are one bucket, so their order is
-    ``default_rng(seed).permutation(rows)``, the dense fit's. Under the
-    default layout each bucket's two blocks reach the mesh in that order
-    through :meth:`DeviceMesh.shard_rows`, round by round, with no
-    permuted copy on the host.
+    ``default_rng(seed).permutation(rows)``, the dense fit's, and the
+    bucket's rows are the table's: no row ids are made or gathered.
+    Under the default layout each bucket's two blocks reach the mesh in
+    that order through :meth:`DeviceMesh.shard_rows`, round by round,
+    with no permuted copy on the host. The labels ``y`` (any numeric
+    dtype, as the table holds them) and a weight column ``w`` go the same
+    way, cast to ``dtype`` in the gather; ``w`` None is unit weights,
+    made on the device (:meth:`DeviceMesh.shard_ones`), as in
+    :func:`_place_shuffled`.
     ``layout`` selects the gradient-reduction layout (see
     :func:`_sparse_layout`): ``sorted`` adds the per-window sort tables
     (+8 B/cell of HBM), ``cumsum`` the sorted-cell value/row tables and
     run boundaries (+12 B/cell) that remove the per-step cells-sized
     sort AND permutation gather (see ``make_sparse_step_bucketed``).
     """
-    from flinkml_tpu.ops.sparse import pack_ell_buckets
-    from flinkml_tpu.utils.metrics import metrics
-
     indptr = np.asarray(indptr, dtype=np.int64)
     n = indptr.size - 1
-    y = np.asarray(y, dtype=dtype)
-    w = np.asarray(w, dtype=dtype)
+    y = np.asarray(y)
     p_size = mesh.axis_size()
     with span("hostdata.sparse_pack"):
         # Bucket choice and ELL fill. Rows of one width (hashed
@@ -860,17 +881,19 @@ def prepare_sparse_buckets(
     counts.counter("padded_cells",
                    float(sum(b["indices"].size for b in buckets)))
     counts.counter("buckets", float(len(buckets)))
+    _count_unit_weights(w)
     rng = np.random.default_rng(seed) if seed is not None else None
     data_args: list = []
     local_bss: list = []
     for bucket, rows in zip(buckets, row_ids):
         bi, bv = bucket["indices"], bucket["values"]
+        n_bucket = bi.shape[0]
         with span("hostdata.shuffle"):
-            order = (rng.permutation(rows.size) if rng is not None
-                     else np.arange(rows.size))
-            picked = rows[order]
-            yb_pad, _ = pad_to_multiple(y[picked], p_size)
-            wb_pad, _ = pad_to_multiple(w[picked], p_size)
+            order = (rng.permutation(n_bucket) if rng is not None
+                     else np.arange(n_bucket))
+            # The table's rows this bucket's positions hold: where every
+            # row has one width the bucket's rows ARE the table's.
+            picked = order if rows is None else rows[order]
         if layout == "unsorted":
             # One pass, as the dense fit's: the seeded order gathered
             # round by round on its way to the device, no permuted copy
@@ -885,10 +908,10 @@ def prepare_sparse_buckets(
                 idx_pad, _ = pad_to_multiple(bi[order], p_size)
                 val_pad, _ = pad_to_multiple(bv[order], p_size)
             idxd, vald = mesh.shard_batch(idx_pad), mesh.shard_batch(val_pad)
-        data_args += [idxd, vald,
-                      mesh.shard_batch(yb_pad), mesh.shard_batch(wb_pad)]
+        data_args += [idxd, vald, mesh.shard_rows(y, picked, dtype),
+                      _place_weights(w, picked, mesh, dtype)]
         n_local = idxd.shape[0] // p_size
-        share = max(1, math.ceil(global_batch_size * rows.size / (n * p_size)))
+        share = max(1, math.ceil(global_batch_size * n_bucket / (n * p_size)))
         local_bs = min(share, n_local)
         local_bss.append(local_bs)
         if layout == "sorted":
@@ -937,7 +960,8 @@ def train_linear_model_sparse_csr(
     the worst row. Each step takes a proportional window from every
     bucket (stratified batch); with batch ≥ n this is exactly the
     full-dataset gradient, so results match the uniform path bit-for-bit
-    up to summation order.
+    up to summation order. ``y`` and ``w`` as in
+    :func:`prepare_sparse_buckets` (``w`` None: unit weights).
     """
     if loss not in _LOSS_KEYS:
         raise ValueError(f"loss must be one of {_LOSS_KEYS}, got {loss!r}")
@@ -1463,23 +1487,26 @@ def streamed_linear_fit(
 
 def dense_table_data(table, features_col: str, label_col: str,
                      weight_col: Optional[str], replicated: bool):
-    """``labeled_data`` for a dense trainer, and the ``dtype`` to hand it:
-    ``(x, y, w, dtype)``.
+    """A dense trainer's columns and the ``dtype`` to hand it: ``(x,
+    labels, w, dtype)``, ``labels`` the label column's
+    :class:`~flinkml_tpu.models._data.LabelFacts` (the label checks
+    answer from it; ``labels.values`` is the trainer's ``y``).
 
     The replicated trainers (:func:`train_linear_model` without a plan,
-    :func:`train_softmax_model`) cast the column chunk by chunk on its
-    way to the device, so they take it as the table has it, and are told
-    the width ``labeled_data``'s float64 copy gave every table (float32
-    on the device where x64 is off). The plan-sharded trainer computes
-    on the host arrays and derives its own width from them: it keeps the
-    copy, and ``dtype`` is None."""
-    from flinkml_tpu.models._data import labeled_data
+    :func:`train_softmax_model`) cast every column chunk by chunk on its
+    way to the device, so they take each as the table has it
+    (``_data.fit_columns``; ``w`` None without a weight column), and are
+    told the width ``labeled_data``'s float64 copy gave every table
+    (float32 on the device where x64 is off). The plan-sharded trainer
+    computes on the host arrays and derives its own width from them: it
+    keeps ``labeled_data``'s copies, and ``dtype`` is None."""
+    from flinkml_tpu.models._data import LabelFacts, fit_columns, labeled_data
 
-    x, y, w = labeled_data(
-        table, features_col, label_col, weight_col,
-        features_dtype=None if replicated else np.float64,
-    )
-    return x, y, w, (np.float64 if replicated else None)
+    if replicated:
+        return (*fit_columns(table, features_col, label_col, weight_col),
+                np.float64)
+    x, y, w = labeled_data(table, features_col, label_col, weight_col)
+    return x, LabelFacts(y), w, None
 
 
 def train_linear_model_from_table(
@@ -1494,15 +1521,15 @@ def train_linear_model_from_table(
 ) -> np.ndarray:
     """One fit dispatch for every linear estimator: SparseVector columns
     take the nnz-bucketed CSR trainer, everything else densifies into the
-    dense trainer. ``label_check(y)`` (optional) validates labels on
-    either branch. ``hyper`` passes straight to the trainers (loss, mesh,
+    dense trainer. ``label_check(labels)`` (optional) validates the
+    label column's ``LabelFacts`` on either branch. ``hyper`` passes straight to the trainers (loss, mesh,
     max_iter, ...). ``sharding_plan`` routes the DENSE branch through
     the plan-sharded trainer (see :func:`train_linear_model`); the
     sparse trainer keeps its replicated ``[dim]`` model and refuses a
     plan loudly. ``precision`` (the FML6xx-gated mixed-precision
     policy) rides the same dense-only route and is refused just as
     loudly on the sparse branch."""
-    from flinkml_tpu.models._data import labeled_sparse_data, sparse_features
+    from flinkml_tpu.models._data import sparse_features, sparse_fit_columns
 
     if sparse_features(table, features_col) is not None:
         if sharding_plan is not None:
@@ -1517,24 +1544,24 @@ def train_linear_model_from_table(
                 "trainer's gather/segment-sum kernels are not yet "
                 "policy-gated"
             )
-        indptr, indices, values, dim, y, w = labeled_sparse_data(
+        indptr, indices, values, dim, labels, w = sparse_fit_columns(
             table, features_col, label_col, weight_col
         )
         if label_check is not None:
-            label_check(y)
+            label_check(labels)
         return train_linear_model_sparse_csr(
-            indptr, indices, values, dim, y, w, **hyper
+            indptr, indices, values, dim, labels.values, w, **hyper
         )
-    x, y, w, dtype = dense_table_data(
+    x, labels, w, dtype = dense_table_data(
         table, features_col, label_col, weight_col,
         replicated=sharding_plan is None and precision is None,
     )
     if x.shape[0] == 0:
         raise ValueError("training table is empty")
     if label_check is not None:
-        label_check(y)
+        label_check(labels)
     hyper.setdefault("dtype", dtype)
-    return train_linear_model(x, y, w, sharding_plan=sharding_plan,
+    return train_linear_model(x, labels.values, w, sharding_plan=sharding_plan,
                               precision=precision, **hyper)
 
 

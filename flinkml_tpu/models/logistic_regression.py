@@ -56,10 +56,11 @@ from flinkml_tpu.iteration import IterationConfig, TerminateOnMaxIterOrTol, iter
 from flinkml_tpu.models import _linear_sgd
 from flinkml_tpu.models._coefficient import CoefficientModelMixin
 from flinkml_tpu.models._data import (
+    LabelFacts,
     check_binary_labels,
     features_matrix,
-    labeled_sparse_data,
     sparse_features,
+    sparse_fit_columns,
 )
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
@@ -143,24 +144,24 @@ class LogisticRegression(StreamingEstimatorMixin, _LogisticRegressionParams, Est
                     "the sparse trainer's gather/segment-sum kernels "
                     "are not yet policy-gated"
                 )
-            indptr, indices, values, dim, y, w = labeled_sparse_data(
+            indptr, indices, values, dim, labels, w = sparse_fit_columns(
                 table, features_col,
                 self.get(_LogisticRegressionParams.LABEL_COL),
                 self.get(_LogisticRegressionParams.WEIGHT_COL),
             )
-            if _resolve_multi_class(multi_class, y) == "multinomial":
+            if _resolve_multi_class(multi_class, labels) == "multinomial":
                 raise ValueError(
                     "multinomial logistic regression supports dense "
                     "features only; one-hot/sparse inputs train one "
                     "binomial model per concept"
                 )
-            _check_binomial_labels(y)
+            _check_binomial_labels(labels)
             coef = _linear_sgd.train_linear_model_sparse_csr(
                 indptr, indices, values, dim,
-                y, w, loss="logistic", elastic_net=0.0, **hyper,
+                labels.values, w, loss="logistic", elastic_net=0.0, **hyper,
             )
         else:
-            x, y, w, hyper["dtype"] = _linear_sgd.dense_table_data(
+            x, labels, w, hyper["dtype"] = _linear_sgd.dense_table_data(
                 table,
                 features_col,
                 self.get(_LogisticRegressionParams.LABEL_COL),
@@ -170,7 +171,7 @@ class LogisticRegression(StreamingEstimatorMixin, _LogisticRegressionParams, Est
             )
             if x.shape[0] == 0:
                 raise ValueError("training table is empty")
-            if _resolve_multi_class(multi_class, y) == "multinomial":
+            if _resolve_multi_class(multi_class, labels) == "multinomial":
                 # Softmax cross-entropy over integer classes 0..k-1:
                 # coefficient is [k, d] (beyond the reference snapshot,
                 # which rejects multinomial outright).
@@ -186,15 +187,15 @@ class LogisticRegression(StreamingEstimatorMixin, _LogisticRegressionParams, Est
                         "only (the softmax trainer is not yet "
                         "policy-gated)"
                     )
-                num_classes = _check_multinomial_labels(y)
+                num_classes = _check_multinomial_labels(labels)
                 coef = _linear_sgd.train_softmax_model(
-                    x, y, w, num_classes=num_classes, elastic_net=0.0,
-                    **hyper,
+                    x, labels.values, w, num_classes=num_classes,
+                    elastic_net=0.0, **hyper,
                 )
             else:
-                _check_binomial_labels(y)
+                _check_binomial_labels(labels)
                 coef = train_logistic_regression(
-                    x, y, w, sharding_plan=self.sharding_plan,
+                    x, labels.values, w, sharding_plan=self.sharding_plan,
                     precision=self.precision, **hyper,
                 )
 
@@ -391,8 +392,9 @@ class LogisticRegressionModel(CoefficientModelMixin, _LogisticRegressionParams, 
 
 
 
-def _check_binomial_labels(y: np.ndarray) -> None:
-    check_binary_labels(y, "binomial logistic regression")
+def _check_binomial_labels(labels) -> None:
+    """``labels``: a label column or its ``LabelFacts``."""
+    check_binary_labels(labels, "binomial logistic regression")
 
 
 def _check_stream_labels(y: np.ndarray) -> None:
@@ -407,30 +409,33 @@ def _check_stream_labels(y: np.ndarray) -> None:
         ) from None
 
 
-def _resolve_multi_class(multi_class: str, y: np.ndarray) -> str:
+def _resolve_multi_class(multi_class: str, labels: LabelFacts) -> str:
     """'auto' follows the label cardinality (≤2 → binomial), like the
-    wider flink-ml family; explicit settings are honored as-is."""
+    wider flink-ml family; explicit settings are honored as-is. Labels
+    all 0 or 1 say so without a sort."""
     if multi_class != "auto":
         return multi_class
-    return "multinomial" if np.unique(y).size > 2 else "binomial"
+    if labels.binary:
+        return "binomial"
+    return "multinomial" if labels.distinct().size > 2 else "binomial"
 
 
-def _check_multinomial_labels(y: np.ndarray) -> int:
+def _check_multinomial_labels(labels: LabelFacts) -> int:
     """Labels must be exactly the integers 0..k-1 (every class present);
     returns k. Guards against phantom classes and against a single
     outlier label silently allocating a huge [maxLabel+1, d] matrix."""
-    uniq = np.unique(y)
+    uniq = labels.distinct()
     if (
-        not np.all(uniq == np.round(uniq))
-        or uniq.min() < 0
-        or uniq.size != int(uniq.max()) + 1
+        not labels.integral
+        or labels.lo < 0
+        or uniq.size != int(labels.hi) + 1
     ):
         raise ValueError(
             "multinomial logistic regression requires integer labels "
             f"covering 0..k-1 exactly, got {uniq[:6]}"
             f"{'...' if uniq.size > 6 else ''}"
         )
-    return int(uniq.max()) + 1
+    return int(labels.hi) + 1
 
 
 @jax.jit

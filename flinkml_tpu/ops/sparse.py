@@ -295,24 +295,26 @@ def sparse_margins(vectors: Sequence[SparseVector], coef,
     coef_dev = jnp.asarray(coef.T if multinomial else coef, jnp.float32)
     out = np.empty((n, k) if multinomial else n, dtype=np.float32)
     for bucket, rows in zip(buckets, row_ids):
-        width = bucket["indices"].shape[1]
+        n_bucket, width = bucket["indices"].shape
         # The per-dispatch working set ([chunk, slots] values + indices +
         # the gathered coefficients) is bounded so scoring a million-row
         # batch cannot blow host/HBM memory, on either branch.
         chunk = max(1, _SCORING_CHUNK_ELEMS // max(1, width * k))
-        for lo in range(0, rows.size, chunk):
+        for lo in range(0, n_bucket, chunk):
             sl = slice(lo, lo + chunk)
             vb = jnp.asarray(bucket["values"][sl])       # [c, s]
             ib = jnp.asarray(bucket["indices"][sl])      # [c, s]
+            # One width: the bucket's rows are the caller's, in order.
+            dest = sl if rows is None else rows[sl]
             if multinomial:
                 # Gather [c, s, k], contract the slot axis.
-                out[rows[sl]] = np.asarray(
+                out[dest] = np.asarray(
                     jnp.einsum("rs,rsk->rk", vb, coef_dev[ib])
                 )
             else:
                 from flinkml_tpu import kernels
 
-                out[rows[sl]] = np.asarray(kernels.spmv(ib, vb, coef_dev))
+                out[dest] = np.asarray(kernels.spmv(ib, vb, coef_dev))
     return out
 
 
@@ -458,7 +460,9 @@ def pack_ell_buckets(indptr, indices, values, dim: int,
     ``indices [n_b, w_b] int32`` / ``values [n_b, w_b] dtype`` (padding
     entries index 0 / value 0, exactly as :class:`BatchedCSR`), and
     ``row_ids`` is a list of int64 arrays mapping bucket rows back to the
-    caller's row order (for gathering labels/weights). Total padded cells
+    caller's row order (for gathering labels/weights); rows of one width
+    are one bucket whose rows are the caller's, and its entry is None
+    (no ``arange`` of a Criteo-sized table is made). Total padded cells
     = the DP optimum of :func:`choose_ell_widths` — ≈ total nnz for any
     realistic skew, vs ``n · max_nnz`` for uniform ELL.
     """
@@ -473,7 +477,7 @@ def pack_ell_buckets(indptr, indices, values, dim: int,
             "values": np.asarray(values).astype(dtype, copy=False)
                         .reshape(n, width),
         }
-        return [block], [np.arange(n)]
+        return [block], [None]
     nnz = np.diff(indptr)
     bucket_widths = choose_ell_widths(nnz, max_buckets=max_buckets)
     edges = np.asarray(bucket_widths, dtype=np.int64)

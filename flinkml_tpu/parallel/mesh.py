@@ -185,11 +185,13 @@ class DeviceMesh:
             phase.add(bytes=placed.nbytes)
         return placed
 
-    def shard_rows(self, x: np.ndarray, order: np.ndarray, dtype) -> jax.Array:
+    def shard_rows(self, x: np.ndarray, order: np.ndarray, dtype=None) -> jax.Array:
         """``shard_batch(pad_to_multiple(x.astype(dtype)[order], p)[0])``,
         bit for bit, without its three full-size host arrays: shard ``s``
         holds positions ``[s * n_local, (s + 1) * n_local)`` of the
-        reordered table, zero rows past its end.
+        reordered table, zero rows past its end. ``x`` is a table of rows
+        of any shape: a 1-D column (labels, weights) is one of rows of
+        width ``()``. ``dtype`` None is ``x``'s own.
 
         One pass, round by round: each shard's next rows are gathered
         (and cast, if ``x`` is not a ``dtype`` array already) into a
@@ -204,7 +206,8 @@ class DeviceMesh:
         # ndarray.take copies a strided source whole, every call.
         x = np.ascontiguousarray(x)
         # The width device_put would have narrowed to where x64 is off.
-        dt = np.dtype(jax.dtypes.canonicalize_dtype(dtype))
+        dt = np.dtype(jax.dtypes.canonicalize_dtype(
+            dtype if dtype is not None else x.dtype))
         row = x.shape[1:]
         n = order.shape[0]
         n_local = -(-n // p)
@@ -218,7 +221,6 @@ class DeviceMesh:
         sharding = self.data_sharding()
         placed = jnp.zeros((p * n_local,) + row, dt, device=sharding)
         write = _row_writer(self.mesh, self.DATA_AXIS)
-        shard_starts = np.arange(p)[:, None] * n_local
         with ThreadPoolExecutor(_GATHER_THREADS) as pool:
             for r in range(rounds):
                 # The last round steps back to end at the shard's end, so
@@ -234,18 +236,33 @@ class DeviceMesh:
                     if consumed[slot] is not None:
                         consumed[slot].block_until_ready()
                 with span("hostdata.shuffle"):
-                    # Positions rise with the staging row, so the rows
-                    # past the table's end are the buffer's tail.
-                    pos = (shard_starts + (offset + np.arange(chunk))).reshape(-1)
-                    valid = int(np.searchsorted(pos, n))
-                    _gather_rows(pool, x, order[pos[:valid]], stage[:valid],
-                                 scratch)
+                    # Shard s takes positions [s * n_local + offset, +
+                    # chunk) of the order: a slice of it. Positions rise
+                    # with the staging row, so a shard cut short by the
+                    # table's end is the last with any row, and the rows
+                    # past the end are the buffer's tail.
+                    parts = [order[min(lo, n):min(lo + chunk, n)] for lo in
+                             range(offset, offset + p * n_local, n_local)]
+                    index = parts[0] if p == 1 else np.concatenate(parts)
+                    valid = index.shape[0]
+                    _gather_rows(pool, x, index, stage[:valid], scratch)
                     stage[valid:] = 0
                 with span("mesh.shard_batch") as phase:
                     sent = jax.device_put(stage, sharding)
                     placed, consumed[slot] = write(placed, sent, np.int32(offset))
                     phase.add(bytes=sent.nbytes)
         return placed
+
+    def shard_ones(self, n: int, dtype) -> jax.Array:
+        """``shard_batch(pad_to_multiple(np.ones(n, dtype), p)[0])``, made
+        on the device: 1 at the positions below ``n``, 0 at the padding.
+        The unit weights of a table with no weight column, which on the
+        host were a vector built, permuted into itself and uploaded.
+        Multi-process it is one SPMD program every process calls."""
+        p = self.axis_size(self.DATA_AXIS)
+        dt = np.dtype(jax.dtypes.canonicalize_dtype(dtype))
+        ones = _ones_below(self.mesh, self.DATA_AXIS)
+        return ones(np.int32(n), p * -(-n // p), dt)
 
     def replicate(self, tree):
         """Replicate a pytree of arrays onto every device (broadcast-model)."""
@@ -327,6 +344,19 @@ def _row_writer(mesh: Mesh, axis: str):
                       out_specs=(P(axis), P(axis))),
         donate_argnums=0,
     )
+
+
+@functools.lru_cache(maxsize=128)
+def _ones_below(mesh: Mesh, axis: str):
+    """The program of :meth:`DeviceMesh.shard_ones`, built as
+    :func:`_row_writer` is: one jitted function a mesh, so the compile
+    cache keeps it and a fit's first call compiles it."""
+
+    def ones(n, rows, dtype):
+        return (jnp.arange(rows) < n).astype(dtype)
+
+    return jax.jit(ones, static_argnums=(1, 2),
+                   out_shardings=NamedSharding(mesh, P(axis)))
 
 
 def _gather_rows(pool, x, index, out, scratch) -> None:

@@ -230,7 +230,7 @@ def test_uniform_rows_are_one_block_of_two_views():
         column.indptr, column.indices, column.values, DIM)
     assert np.shares_memory(block["indices"], column.indices)
     assert np.shares_memory(block["values"], column.values)
-    np.testing.assert_array_equal(rows, np.arange(57))
+    assert rows is None  # the block's rows are the column's: no row ids
     ragged = _tables(False, rows=57)[0].csr_column("features")
     assert sparse_ops.uniform_row_width(ragged.indptr) is None
     assert sparse_ops.uniform_row_width(np.zeros(5, np.int64)) is None
@@ -348,13 +348,13 @@ def test_the_sparse_fit_has_its_spans_and_counters(uniform):
     assert added["cells"] == column.indices.size
     assert (added["padded_cells"] == added["cells"] if uniform
             else added["padded_cells"] > added["cells"])
-    # A bucket: its permutation with the labels' and weights' gathers,
-    # then two arrays of one staging round each (wait, gather, place),
-    # then the labels' and the weights' placements.
+    # A bucket: its permutation, then three arrays of one staging round
+    # each (wait, gather, place): indices, values, labels. No weight
+    # column: the unit weights are made on the device, under no span.
     assert calls == {"fit": 1, "hostdata.ingest": 1, "hostdata.sparse_pack": 1,
-                     "hostdata.shuffle": 3 * buckets,
-                     "hostdata.stage_wait": 2 * buckets,
-                     "mesh.shard_batch": 4 * buckets,
+                     "hostdata.shuffle": 4 * buckets,
+                     "hostdata.stage_wait": 3 * buckets,
+                     "mesh.shard_batch": 3 * buckets,
                      "trainer.loop": 1, "trainer.readback": 1}
 
 

@@ -22,11 +22,12 @@ from flinkml_tpu.table import Table
 from flinkml_tpu.utils import metrics, profiling
 from flinkml_tpu.utils.profiling import span
 
-# A table of one staging round (DeviceMesh.shard_rows): the permutation
-# with the labels' and weights' gathers, then the round's wait, gather and
-# placement, then the labels' and the weights' placements.
-FIT_SPANS = {"fit": 1, "hostdata.ingest": 1, "hostdata.shuffle": 2,
-             "hostdata.stage_wait": 1, "mesh.shard_batch": 3,
+# A table of one staging round a column (DeviceMesh.shard_rows): the
+# permutation, then the features' and the labels' rounds (wait, gather,
+# placement each). The names are the parent's (2ba32a5); with no weight
+# column the weights are made on the device and open no span.
+FIT_SPANS = {"fit": 1, "hostdata.ingest": 1, "hostdata.shuffle": 3,
+             "hostdata.stage_wait": 2, "mesh.shard_batch": 2,
              "trainer.loop": 1, "trainer.readback": 1}
 
 
@@ -141,45 +142,106 @@ def _lr_table(rows=1003, dim=5, seed=0):
     rng = np.random.default_rng(seed)
     x = rng.normal(size=(rows, dim)).astype(np.float32)
     y = (x @ rng.normal(size=dim) > 0).astype(np.float64)
-    return Table({"features": x, "label": y})
+    return Table({"features": x, "label": y, "w": rng.random(rows) + 0.5})
 
 
-def _fit(table):
+def _fit(table, weight_col=None):
     est = (LogisticRegression().set_max_iter(6).set_global_batch_size(256)
            .set_learning_rate(0.5).set_seed(7))
+    if weight_col is not None:
+        est.set_weight_col(weight_col)
     return np.asarray(est.fit(table).coefficient)
 
 
+@pytest.mark.parametrize("weight_col", [None, "w"], ids=["unweighted", "weighted"])
 @pytest.mark.parametrize("stage_bytes", [None, 4096], ids=["one-round", "many-rounds"])
-def test_fit_produces_each_fit_span_once(monkeypatch, stage_bytes):
+def test_fit_produces_each_fit_span_once(monkeypatch, stage_bytes, weight_col):
     from flinkml_tpu.parallel import mesh
 
     p = len(jax.devices())
     n_local = -(-1003 // p)
-    chunk, width = n_local, jax.dtypes.canonicalize_dtype(np.float64).itemsize
+    width = jax.dtypes.canonicalize_dtype(np.float64).itemsize
+    chunk = small_chunk = n_local  # rows a shard sends a round: features; labels
     if stage_bytes is not None:
         monkeypatch.setattr(mesh, "_STAGE_BYTES", stage_bytes)
         chunk = stage_bytes // (p * 5 * width)
-    rounds = -(-n_local // chunk)
+        small_chunk = min(n_local, stage_bytes // (p * width))
+    rounds, small_rounds = -(-n_local // chunk), -(-n_local // small_chunk)
     assert (rounds > 1) == (stage_bytes is not None)
+    # the labels, and a weight column the same way; none: made on the device
+    small = 1 if weight_col is None else 2
     table = _lr_table()
-    with _delta() as d:
-        _fit(table)
-    assert _calls(d) == {**FIT_SPANS, "hostdata.shuffle": 1 + rounds,
-                         "hostdata.stage_wait": rounds,
-                         "mesh.shard_batch": rounds + 2}
-    # What was placed: the features round by round (the last round steps
-    # back over rows already sent), labels and weights padded to the mesh,
-    # at the width the device holds (float32 where x64 is off).
-    assert d["mesh.shard_batch.bytes"] == (rounds * chunk * 5 + 2 * n_local) * p * width
+    with _delta() as d, _delta("hostdata") as made:
+        _fit(table, weight_col)
+    placed = rounds + small * small_rounds
+    assert _calls(d) == {**FIT_SPANS, "hostdata.shuffle": 1 + placed,
+                         "hostdata.stage_wait": placed,
+                         "mesh.shard_batch": placed}
+    assert made == ({"unit_weights_on_device": 1} if weight_col is None else {})
+    # What was placed: every column round by round (the last round steps
+    # back over rows already sent), padded to the mesh, at the width the
+    # device holds (float32 where x64 is off).
+    assert d["mesh.shard_batch.bytes"] == (
+        rounds * chunk * 5 + small * small_rounds * small_chunk) * p * width
     # The phases are siblings on the fit's thread, so they add up to it.
     children = sum(v for k, v in d.items() if k.endswith(".seconds")
                    and not k.startswith("fit."))
     assert children <= d["fit.seconds"]
     # seconds and calls apiece, and the one count a metric reads: a name
-    # nothing reads is not added
+    # nothing reads is not added, and no name the parent did not have
     assert set(d) == ({f"{s}.{c}" for s in FIT_SPANS for c in ("seconds", "calls")}
                       | {"mesh.shard_batch.bytes"})
+
+
+def test_the_fits_spans_are_siblings_under_fit(tmp_path):
+    """None nested in another: on the fit's thread every phase starts
+    after the one before it has ended, inside ``fit``."""
+    table = _lr_table()
+    _fit(table)  # compiled before the profile
+    with profiling.trace(str(tmp_path), ignore_errors=False):
+        _fit(table)
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    events = sorted(
+        (e.start_ns, e.start_ns + e.duration_ns, e.name[len(profiling.SPAN_PREFIX):])
+        for plane in data.planes if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events
+        if e.name.startswith(profiling.SPAN_PREFIX))
+    (fit_start, fit_end, name), *phases = events
+    assert name == "fit" and {n for _, _, n in phases} == set(FIT_SPANS) - {"fit"}
+    end = fit_start
+    for start, stop, name in phases:
+        assert end <= start <= stop <= fit_end, name
+        end = stop
+
+
+def test_the_unit_weights_metric_reads_one_a_fit():
+    """``benchmark/metrics/hostdata.unit_weights_on_device_per_fit.json``
+    through the benchmark's ``counter_ratio`` reader, over the counters of
+    three small fits as ``benchmark/run.py`` flattens them."""
+    import json
+
+    from benchmark.readers import counter_ratio
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "metrics",
+                           "hostdata.unit_weights_on_device_per_fit.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "counter_ratio"
+    table = _lr_table()
+
+    def read(fits, weight_col):
+        with _delta("hostdata") as made:
+            for _ in range(fits):
+                _fit(table, weight_col)
+        counters = {f"hostdata.{k}": v for k, v in made.items()}
+        return counter_ratio.read(
+            spec["params"], {"counters": counters, "setup_counters": {},
+                             "units": {"fits": fits}})
+
+    assert read(3, None) == 1.0
+    assert read(2, "w") is None  # as on the parent: no count, no metric
 
 
 def _chain(dim=5, seed=1):
