@@ -58,9 +58,8 @@ def make_criteo_csr(n, dim=1_000_000, nnz=39, seed=0, n_active=256):
     """Synthetic Criteo-profile CSR: ``nnz`` uniform-random columns per
     row over ``dim``, labels planted by a sparse true model with
     ``n_active`` nonzero coefficients. ONE definition shared by the
-    sparse throughput stage, the sparse convergence stage, and
-    ``tools/sparse_layout_probe.py`` so every sparse measurement sees
-    the same distribution."""
+    sparse throughput stage and the sparse convergence stage so every
+    sparse measurement sees the same distribution."""
     rng = np.random.default_rng(seed)
     indptr = np.arange(n + 1, dtype=np.int64) * nnz
     indices = rng.integers(0, dim, size=n * nnz).astype(np.int32)
@@ -164,17 +163,13 @@ def bench_tpu_sparse(indptr, indices, values, dim, y, w,
 
     mesh = DeviceMesh()
     p = mesh.axis_size()
-    # Same pack/pad/shard/batching policy as the product fit path —
-    # including the FLINKML_TPU_SPARSE_LAYOUT A/B gate, so setting it
-    # really benchmarks the selected gradient layout.
-    layout = _linear_sgd._sparse_layout()
+    # Same pack/pad/shard/batching policy as the product fit path.
     data_args, local_bss = _linear_sgd.prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
-        seed=0, layout=layout,
+        seed=0,
     )
     trainer = _linear_sgd._sparse_trainer_bucketed(
         mesh.mesh, "logistic", local_bss, DeviceMesh.DATA_AXIS, int(dim),
-        layout,
     )
     f32 = lambda v: jnp.asarray(v, jnp.float32)
     carry0 = (
@@ -1858,8 +1853,7 @@ def _inner_converge_sparse() -> dict:
     1e6, 39 nnz/row, n=65_536, global batch 16_384, lr=20, seeded. Tol
     calibrated on the seeded config (CPU, f32): loss 0.693 at start,
     0.265 after 80 epochs, 0.153 after 160 — tol 0.25 lands at ~85
-    epochs. Uses the product sparse trainer at the product layout gate,
-    so the number tracks the active layout."""
+    epochs. Uses the product sparse trainer."""
     _setup_jax_cache()
     import jax.numpy as jnp
     from flinkml_tpu.models import _linear_sgd
@@ -1868,13 +1862,11 @@ def _inner_converge_sparse() -> dict:
     n, dim, gbs, tol, max_steps = 65_536, 1_000_000, 16_384, 0.25, 2_000
     indptr, indices, values, y, w = make_criteo_csr(n, dim)
     mesh = DeviceMesh()
-    layout = _linear_sgd._sparse_layout()
     data_args, local_bss = _linear_sgd.prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, gbs, seed=0,
-        layout=layout,
     )
     trainer = _linear_sgd._sparse_trainer_bucketed(
-        mesh.mesh, "logistic", local_bss, DeviceMesh.DATA_AXIS, dim, layout,
+        mesh.mesh, "logistic", local_bss, DeviceMesh.DATA_AXIS, dim,
     )
     f32 = lambda v: jnp.asarray(v, jnp.float32)
     carry0 = (
@@ -2207,13 +2199,13 @@ def _inner_autotune_cpu() -> dict:
 
 
 def _pallas_stage() -> dict:
-    """Kernel-vs-XLA A/B for the four Pallas sites (ROADMAP item 2 /
+    """Kernel-vs-XLA A/B for the three Pallas sites (ROADMAP item 2 /
     ISSUEs 13, 16): per-site ``pallas/xla`` throughput ratio through the same
     measurers the autotune search commits from, gated by a bitwise
     parity probe per site — a wrong kernel must never emit a ratio. On
     the CPU mesh the Pallas candidates run under the interpreter
     (``interpret: 1`` in the record — the number audits the harness,
-    not the hardware). CPU-only: on a v5e three of the four sites
+    not the hardware). CPU-only: on a v5e two of the three sites
     refuse these shapes compiled (``main``'s comment on the order)."""
     import numpy as np
 
@@ -2226,7 +2218,6 @@ def _pallas_stage() -> dict:
         _serving_model,
         measure_kernel_backend_fused_chain,
         measure_kernel_backend_segment_sum,
-        measure_kernel_backend_spmv,
         measure_kernel_backend_topk,
     )
     from flinkml_tpu.table import Table
@@ -2244,17 +2235,6 @@ def _pallas_stage() -> dict:
     b = np.asarray(kernels.segment_sum(
         vals, sids, 512, indices_are_sorted=True, backend="pallas"))
     assert a.tobytes() == b.tobytes(), "sorted segment_sum parity violation"
-    sib = jnp.asarray(rng.integers(0, 512, (256, 16)), jnp.int32)
-    svb = jnp.asarray(rng.normal(size=(256, 16)).astype(np.float32))
-    sw = jnp.asarray(rng.normal(size=512).astype(np.float32))
-    # Parity contract is vs the JITTED reference (the product path is
-    # always jitted; eager XLA's unfused reduce can differ in the last
-    # f32 bit).
-    a = np.asarray(jax.jit(
-        lambda i, v, w: jnp.sum(v * jnp.take(w, i, axis=0), axis=1)
-    )(sib, svb, sw))
-    b = np.asarray(kernels.spmv(sib, svb, sw, backend="pallas"))
-    assert a.tobytes() == b.tobytes(), "spmv parity violation"
     xq = jnp.asarray(rng.normal(size=(64, 512)).astype(np.float32))
     rv, ri = jax.lax.top_k(xq, 8)
     pv, pi = kernels.top_k(xq, 8, backend="pallas")
@@ -2283,7 +2263,6 @@ def _pallas_stage() -> dict:
     sites = {
         "fused_chain": measure_kernel_backend_fused_chain,
         "segment_sum": measure_kernel_backend_segment_sum,
-        "spmv": measure_kernel_backend_spmv,
         "topk": measure_kernel_backend_topk,
     }
     ratios, rates = {}, {}
@@ -2725,8 +2704,8 @@ def main():
     # children from a process that holds the backend (see their
     # docstrings) — on a TPU host those children cannot reach the chip;
     # autotune and pallas time each kernel site's Pallas backend at
-    # shapes the compiled kernels refuse on a v5e (spmv everywhere,
-    # segment_sum at [65536, 1], the chain's float64 constants — see
+    # shapes the compiled kernels refuse on a v5e (segment_sum at
+    # [65536, 1], the chain's float64 constants — see
     # docs/development/kernels.md), so they exist as *_cpu stages only;
     # sharded_embedding proves "128 MiB does not fit a 24 MiB budget
     # until split eight ways", which no 1- or 4-chip mesh satisfies.

@@ -513,8 +513,6 @@ def phase_kernels(ctx) -> dict:
     (``interpret=False``) and agrees with the XLA lowering, or its
     ``unsupported_reason`` names why not and an explicit request raises
     :class:`KernelUnsupportedError` with that reason."""
-    import importlib
-
     import jax
     import jax.numpy as jnp
 
@@ -525,7 +523,6 @@ def phase_kernels(ctx) -> dict:
     from flinkml_tpu.kernels import topk as k_topk
     from flinkml_tpu.table import Table
 
-    k_spmv = importlib.import_module("flinkml_tpu.kernels.spmv")
     z = ctx.sizes
     interpret = kernels.interpret_mode()
     if ctx.rehearse:
@@ -558,25 +555,12 @@ def phase_kernels(ctx) -> dict:
                 f"{site}: unsupported_reason says {reason!r} but an "
                 "explicit pallas request ran")
 
-    # sparse trainer, one device's step (phase 2): forward SpMV and the
-    # gradient scatter into [dim].
+    # sparse trainer, one device's step (phase 2): the gradient scatter
+    # into [dim].
     rows = z["sparse_gbs"] // len(jax.devices())
     width, dim = 64, z["sparse_dim"]  # 39 nnz pads to the 64-wide ELL
     ib = jnp.asarray(rng.integers(0, dim, (rows, width)), jnp.int32)
     vb = jnp.asarray(rng.standard_normal((rows, width), dtype=np.float32))
-    w = jnp.asarray(rng.standard_normal(dim, dtype=np.float32))
-    xla_spmv = jax.jit(
-        lambda i, v, ww: jnp.sum(v * jnp.take(ww, i, axis=0), axis=1))
-
-    def spmv_diff():
-        got = jax.jit(lambda i, v, ww: k_spmv.pallas_spmv(
-            i, v, ww, interpret=interpret))(ib, vb, w)
-        return np.max(np.abs(np.asarray(got) - np.asarray(
-            xla_spmv(ib, vb, w))))
-
-    run_site("spmv", k_spmv.unsupported_reason(ib, vb, w, interpret),
-             lambda: kernels.spmv(ib, vb, w, backend="pallas"), spmv_diff)
-
     contrib, ids = vb.reshape(-1), ib.reshape(-1)
 
     def segsum_diff(values, seg_ids, nseg):
@@ -690,10 +674,10 @@ def phase_kernels(ctx) -> dict:
             _check(rec["max_abs_diff_vs_xla"] <= 1e-4,
                    f"{site}: pallas differs from XLA by "
                    f"{rec['max_abs_diff_vs_xla']}")
-    # Two counts of the four kernels: compiled at the shape phases 2-4
+    # Two counts of the three kernels: compiled at the shape phases 2-4
     # hand the site (what the product can select), and compiled at some
     # operands the kernel admits (what Mosaic can build at all).
-    elsewhere = {"fused_chain": "fused_chain_f32_constants", "spmv": "spmv",
+    elsewhere = {"fused_chain": "fused_chain_f32_constants",
                  "topk": "topk", "segment_sum": "segment_sum_row_payload"}
 
     def compiled(site):

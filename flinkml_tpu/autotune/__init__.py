@@ -3,10 +3,10 @@
 The heuristics that pick the executor's cache identities are guesses:
 ``infer_plan``'s ascending-communication-cost preset order, the serving
 engine's power-of-two dispatch bucket cap and batching window, and the
-four sort-class layout gates (sparse-LR ``FLINKML_TPU_SPARSE_LAYOUT``,
-GBT ``FLINKML_TPU_GBT_HISTOGRAM``, ALS ``FLINKML_TPU_ALS_REDUCTION``,
-W2V ``FLINKML_TPU_W2V_ACCUM``) that have been "flip on a measured win"
-since they landed. This package measures them
+three sort-class layout gates (GBT ``FLINKML_TPU_GBT_HISTOGRAM``, ALS
+``FLINKML_TPU_ALS_REDUCTION``, W2V ``FLINKML_TPU_W2V_ACCUM``) that have
+been "flip on a measured win" since they landed. This package measures
+them
 (:mod:`flinkml_tpu.autotune.search`) and pins winners into a committed,
 mesh-keyed tuning table (:mod:`flinkml_tpu.autotune.table`) consulted at
 key-construction time: an explicit env var or argument always wins, the

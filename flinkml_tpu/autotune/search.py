@@ -11,7 +11,7 @@ flip-flop a committed default — exactly the "measured, not guessed, and
 not noise either" discipline VERDICT's sort-class item asks for.
 
 The layout knobs are driven through their existing env-var gates
-(``FLINKML_TPU_SPARSE_LAYOUT`` etc.), so the search measures precisely
+(``FLINKML_TPU_GBT_HISTOGRAM`` etc.), so the search measures precisely
 the code path a user selecting that candidate would run.
 
 ``quick=True`` shrinks every scenario to smoke-test size (CI and unit
@@ -41,7 +41,6 @@ RATIO_FLOOR = 1.10
 #: The static (pre-autotune) defaults — the incumbents hysteresis
 #: protects, and the fallbacks consumers use when a mesh has no entry.
 STATIC_DEFAULTS: Dict[str, Any] = {
-    "sparse_layout": "unsorted",
     "gbt_histogram": "segment",
     "als_reduction": "segment",
     "w2v_accum": "scatter",
@@ -50,7 +49,6 @@ STATIC_DEFAULTS: Dict[str, Any] = {
     "serving_window_ms": 2.0,
     "kernel_backend_fused_chain": "xla",
     "kernel_backend_segment_sum": "xla",
-    "kernel_backend_spmv": "xla",
     "kernel_backend_topk": "xla",
     "embedding_exchange": "ring",
     "serving_scale_up_backlog": 0.5,
@@ -104,58 +102,7 @@ def _timed_rate(fn: Callable[[], float]) -> float:
     return max(fn(), fn())
 
 
-# -- the four sort-class layout knobs ----------------------------------------
-
-
-def measure_sparse_layout(quick: bool = False) -> Dict[str, float]:
-    """Sparse-LR samples/s per gradient layout (the
-    ``make_sparse_step_bucketed`` A/B, Criteo-profile data)."""
-    import jax.numpy as jnp
-
-    from flinkml_tpu.models import _linear_sgd
-    from flinkml_tpu.parallel import DeviceMesh
-
-    n, dim, nnz = (8_192, 65_536, 16) if quick else (32_768, 262_144, 24)
-    steps = 20 if quick else 100
-    rng = np.random.default_rng(0)
-    indptr = np.arange(n + 1, dtype=np.int64) * nnz
-    indices = rng.integers(0, dim, size=n * nnz).astype(np.int32)
-    values = rng.normal(size=n * nnz).astype(np.float32)
-    y = (rng.random(n) > 0.5).astype(np.float32)
-    w = np.ones(n, dtype=np.float32)
-    mesh = DeviceMesh()
-    p = mesh.axis_size()
-    out: Dict[str, float] = {}
-    for layout in _linear_sgd._SPARSE_LAYOUTS:
-        with _env("FLINKML_TPU_SPARSE_LAYOUT", layout):
-            data_args, local_bss = _linear_sgd.prepare_sparse_buckets(
-                indptr, indices, values, dim, y, w, mesh, n,
-                seed=0, layout=layout,
-            )
-            trainer = _linear_sgd._sparse_trainer_bucketed(
-                mesh.mesh, "logistic", local_bss, DeviceMesh.DATA_AXIS,
-                int(dim), layout,
-            )
-            f32 = lambda v: jnp.asarray(v, jnp.float32)  # noqa: E731
-            carry0 = (jnp.zeros(dim, jnp.float32),
-                      jnp.asarray(0, jnp.int32),
-                      jnp.asarray(jnp.inf, jnp.float32))
-            hy = (f32(0.1), f32(0.0), f32(0.0), f32(0.0))
-            np.asarray(trainer(*carry0, *data_args, *hy,
-                               jnp.asarray(2, jnp.int32))[0])  # warmup
-
-            def rate() -> float:
-                t0 = time.perf_counter()
-                coef, steps_out, _ = trainer(
-                    *carry0, *data_args, *hy, jnp.asarray(steps, jnp.int32)
-                )
-                np.asarray(coef)
-                return sum(local_bss) * p * int(steps_out) / (
-                    time.perf_counter() - t0
-                )
-
-            out[layout] = _timed_rate(rate)
-    return out
+# -- the three sort-class layout knobs ----------------------------------------
 
 
 def measure_gbt_histogram(quick: bool = False) -> Dict[str, float]:
@@ -625,38 +572,6 @@ def measure_kernel_backend_segment_sum(quick: bool = False
     return out
 
 
-def measure_kernel_backend_spmv(quick: bool = False) -> Dict[str, float]:
-    """Sparse forward-margin rows/s per SpMV backend at the sparse
-    trainer's per-step shape (padded-ELL ``[rows, width]`` block
-    against a dense ``[dim]`` coefficient)."""
-    import jax
-    import jax.numpy as jnp
-
-    from flinkml_tpu import kernels
-
-    rows, width, dim = (1 << 11, 16, 1 << 14) if quick \
-        else (1 << 13, 32, 1 << 16)
-    reps = 5 if quick else 20
-    rng = np.random.default_rng(0)
-    ib = jnp.asarray(rng.integers(0, dim, (rows, width)), jnp.int32)
-    vb = jnp.asarray(rng.normal(size=(rows, width)).astype(np.float32))
-    w = jnp.asarray(rng.normal(size=dim).astype(np.float32))
-    out: Dict[str, float] = {}
-    for backend in ("xla", "pallas"):
-        fn = jax.jit(functools.partial(kernels.spmv, backend=backend))
-        np.asarray(fn(ib, vb, w))  # compile + warmup
-
-        def rate() -> float:
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                r = fn(ib, vb, w)
-            np.asarray(r)
-            return rows * reps / (time.perf_counter() - t0)
-
-        out[backend] = _timed_rate(rate)
-    return out
-
-
 def measure_kernel_backend_topk(quick: bool = False) -> Dict[str, float]:
     """KNN-shaped queries/s per top-k backend (``[nq, n]`` distance
     matrix, k of the bench's neighbor-query size)."""
@@ -767,7 +682,6 @@ def measure_embedding_exchange(quick: bool = False) -> Dict[str, float]:
 # -- the search harness ------------------------------------------------------
 
 MEASURERS: Dict[str, Callable[[bool], Dict[str, float]]] = {
-    "sparse_layout": measure_sparse_layout,
     "gbt_histogram": measure_gbt_histogram,
     "als_reduction": measure_als_reduction,
     "w2v_accum": measure_w2v_accum,
@@ -776,7 +690,6 @@ MEASURERS: Dict[str, Callable[[bool], Dict[str, float]]] = {
     "serving_window_ms": measure_serving_window_ms,
     "kernel_backend_fused_chain": measure_kernel_backend_fused_chain,
     "kernel_backend_segment_sum": measure_kernel_backend_segment_sum,
-    "kernel_backend_spmv": measure_kernel_backend_spmv,
     "kernel_backend_topk": measure_kernel_backend_topk,
     "embedding_exchange": measure_embedding_exchange,
     "serving_scale_up_backlog": measure_serving_scale_up_backlog,

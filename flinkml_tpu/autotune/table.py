@@ -60,7 +60,6 @@ ENV_DISABLE_VAR = "FLINKML_TPU_AUTOTUNE"
 #: measured in — ``--check`` refuses unknown knobs so a typo'd entry
 #: cannot sit silently unconsulted.
 KNOWN_KNOBS: Dict[str, str] = {
-    "sparse_layout": "samples_per_sec",
     "gbt_histogram": "row_trees_per_sec",
     "als_reduction": "rating_visits_per_sec",
     "w2v_accum": "pairs_per_sec",
@@ -73,7 +72,6 @@ KNOWN_KNOBS: Dict[str, str] = {
     # `pallas`) is what can flip these.
     "kernel_backend_fused_chain": "rows_per_sec",
     "kernel_backend_segment_sum": "cells_per_sec",
-    "kernel_backend_spmv": "rows_per_sec",
     "kernel_backend_topk": "queries_per_sec",
     # The sharded-embedding exchange (flinkml_tpu.embeddings): ring vs
     # all_to_all row routing, with dense_psum (replicated table, dense

@@ -1,7 +1,7 @@
 """Hand-written Pallas kernels for the hot inner loops (ROADMAP item 2).
 
 PAPER.md's blueprint is a "JAX/XLA/pjit/**Pallas** design"; this package
-is the Pallas half: a gated second backend for the four loops where the
+is the Pallas half: a gated second backend for the three loops where the
 executor's speed was hostage to XLA codegen (the bf16 fused-chain CPU
 ratio of 0.24–0.49 in PR 10 is the motivating number):
 
@@ -11,9 +11,6 @@ ratio of 0.24–0.49 in PR 10 is the motivating number):
 - ``segment_sum`` — the padded-ELL sparse gradient scatter-accumulate
   with an ``indices_are_sorted`` run-flush specialization and a
   multi-block cell grid (:mod:`flinkml_tpu.kernels.segsum`);
-- ``spmv`` — the padded-ELL CSR matvec behind the sparse trainers'
-  forward margins and ``BatchedCSR.matvec``, row-tiled so the gather
-  never materializes off-block (:mod:`flinkml_tpu.kernels.spmv`);
 - ``topk`` — the bucketed top-k behind KNN voting and LSH candidate
   ranking as k masked row-max passes (:mod:`flinkml_tpu.kernels.topk`).
 
@@ -52,13 +49,6 @@ from flinkml_tpu.kernels.segsum import (  # noqa: F401
 from flinkml_tpu.kernels.segsum import (  # noqa: F401
     factory_backend as segsum_backend,
 )
-from flinkml_tpu.kernels.spmv import (  # noqa: F401
-    pallas_spmv,
-    spmv,
-)
-from flinkml_tpu.kernels.spmv import (  # noqa: F401
-    factory_backend as spmv_backend,
-)
 from flinkml_tpu.kernels.topk import (  # noqa: F401
     pallas_top_k,
     top_k,
@@ -80,9 +70,6 @@ __all__ = [
     "pallas_segment_sum",
     "segment_sum",
     "segsum_backend",
-    "pallas_spmv",
-    "spmv",
-    "spmv_backend",
     "pallas_top_k",
     "top_k",
     "topk_backend",

@@ -1,21 +1,17 @@
-"""The kernel-backend gate — the established measured-default idiom
-(:func:`flinkml_tpu.models._linear_sgd._sparse_layout`) applied to the
-choice between XLA's lowering and the hand-written Pallas kernels.
+"""The kernel-backend gate — a measured default for the choice between
+XLA's lowering and the hand-written Pallas kernels.
 
-Four *sites* exist, one per hot inner loop:
+Three *sites* exist, one per hot inner loop:
 
 - ``fused_chain``  — the fused pipeline executor's per-bucket chain
   program (:mod:`flinkml_tpu.kernels.chain`),
 - ``segment_sum``  — the padded-ELL sparse gradient scatter-accumulate
   shared by the linear SGD trainers, ``BatchedCSR.rmatvec``, and the
   Word2Vec embedding accumulator (:mod:`flinkml_tpu.kernels.segsum`),
-- ``spmv``         — the padded-ELL CSR matvec behind the sparse
-  trainers' forward margins and ``BatchedCSR.matvec``
-  (:mod:`flinkml_tpu.kernels.spmv`),
 - ``topk``         — the bucketed top-k behind KNN voting and LSH
   candidate ranking (:mod:`flinkml_tpu.kernels.topk`).
 
-Lookup precedence per site (exactly the sort-class layout gates'):
+Lookup precedence per site:
 ``FLINKML_TPU_KERNELS`` env var > the mesh-keyed autotune table's
 ``kernel_backend_<site>`` knob > the static default ``"xla"``. The env
 var takes either one backend for every site (``pallas``/``xla``) or a
@@ -45,8 +41,8 @@ from flinkml_tpu.utils.logging import get_logger
 
 _log = get_logger("kernels")
 
-#: The four gated sites (one per hot inner loop — module docstring).
-SITES = ("fused_chain", "segment_sum", "spmv", "topk")
+#: The three gated sites (one per hot inner loop — module docstring).
+SITES = ("fused_chain", "segment_sum", "topk")
 
 #: Known backends. ``xla`` is the static default everywhere; ``pallas``
 #: must win a measured A/B (the autotune ``kernel_backend_*`` knobs) or

@@ -3,11 +3,9 @@
 The sparse trainers' dominant op at Criteo scale is one flat
 ``segment_sum`` per step: ``contrib [cells]`` (or ``[cells, k]`` for the
 row-payload W2V accumulator) scatter-added into ``[num_segments]`` by
-``ids [cells]``. XLA lowers the unsorted case through a per-step bitonic
-sort over every cell (the round-4 A/B in
-:func:`flinkml_tpu.models._linear_sgd._sparse_layout`); this kernel
-streams the cells once instead, accumulating into the VMEM-resident
-output block:
+``ids [cells]``. XLA lowers the unsorted case as an element-serial
+scatter-add; this kernel streams the cells once instead, accumulating
+into the VMEM-resident output block:
 
 - **unsorted**: one sequential pass, ``out[ids[j]] += v[j]`` — addition
   order equals XLA's CPU scatter order (element order), so the f32

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -39,81 +38,23 @@ from jax.sharding import PartitionSpec as P
 # a ``CsrColumn`` runs at construction (without its row-order part).
 from flinkml_tpu.linalg import check_csr_structure as _check_csr_structure
 from flinkml_tpu.ops.losses import margin_terms as _margin_grad
-from flinkml_tpu.ops.sparse import chunked_run_totals, pack_ell_buckets
-from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
+from flinkml_tpu.ops.sparse import ell_matvec, pack_ell_buckets
+from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.utils.metrics import metrics
 from flinkml_tpu.utils.profiling import span
 
 _LOSS_KEYS = ("logistic", "hinge", "squared")
 
 
-_SPARSE_LAYOUTS = ("unsorted", "sorted", "cumsum")
-
-
-def _sparse_layout() -> str:
-    """Measured-default gate for the sparse gradient layout.
-
-    Three candidates for the Criteo-scale gradient reduction (the step's
-    dominant cost at dim ~1e6 — BASELINE.md "Sparse roofline"):
-
-    - ``unsorted`` (default): one fused ``segment_sum`` per step. Round-4
-      device A/B: 69.1 ms/step — the measured winner of the first two.
-    - ``sorted`` (round-3 layout): pack-time per-window sort +
-      ``indices_are_sorted=True``, at the cost of a per-step O(cells)
-      random gather of the contributions. Round-4 device A/B: 90.9
-      ms/step (0.76x) — the permutation gather costs more than the sort
-      it removes. Kept for A/B repeatability.
-    - ``cumsum`` (round-5 layout): cells pre-sorted by column at pack
-      time WITH their values, so the step never touches a cells-sized
-      random permutation: contributions = sorted values x a gather of
-      ``mult`` from the [local_bs]-sized (VMEM-resident) table, segment
-      totals = one associative scan + a gather at precomputed static run
-      boundaries, and the only scatter left is ``<= distinct columns per
-      window`` sorted unique adds into [dim] — O(cells) streaming passes
-      instead of the per-step bitonic sort over every cell.
-
-    ``FLINKML_TPU_SPARSE_LAYOUT`` selects; the legacy
-    ``FLINKML_TPU_SORTED_SCATTER=1`` gate maps to ``sorted``. Numerics
-    across layouts are pinned by ``tests/test_sparse_scale.py``
-    (bit-exact for sorted/unsorted; allclose for cumsum, whose
-    running-sum-difference changes f32 summation order)."""
-    layout = os.environ.get("FLINKML_TPU_SPARSE_LAYOUT")
-    if layout is not None:
-        if layout not in _SPARSE_LAYOUTS:
-            raise ValueError(
-                f"FLINKML_TPU_SPARSE_LAYOUT={layout!r}: "
-                f"expected one of {_SPARSE_LAYOUTS}"
-            )
-        return layout
-    if os.environ.get("FLINKML_TPU_SORTED_SCATTER", "0") == "1":
-        return "sorted"
-    # No explicit gate: the measured default for this mesh (committed by
-    # the autotune search; docs/development/compile_cache.md), falling
-    # back to the historical "unsorted".
-    from flinkml_tpu.autotune import tuned_default
-
-    return tuned_default("sparse_layout", "unsorted",
-                         allowed=_SPARSE_LAYOUTS)
-
-
 def _segsum_backend() -> str:
     """The kernel-backend gate for the gradient scatter-accumulate
     (:mod:`flinkml_tpu.kernels`, site ``segment_sum``): env var >
-    autotune table > ``"xla"``. Resolved at FIT time like
-    :func:`_sparse_layout` and threaded through the trainer factories'
-    lru keys, so flipping the gate re-keys the jitted trainer."""
+    autotune table > ``"xla"``. Resolved at FIT time and threaded
+    through the trainer factories' lru keys, so flipping the gate
+    re-keys the jitted trainer."""
     from flinkml_tpu import kernels
 
     return kernels.segsum_backend()
-
-
-def _spmv_backend() -> str:
-    """The kernel-backend gate for the forward ELL matvec
-    (:mod:`flinkml_tpu.kernels`, site ``spmv``) — same fit-time
-    resolution and lru-key threading as :func:`_segsum_backend`."""
-    from flinkml_tpu import kernels
-
-    return kernels.spmv_backend()
 
 
 def _soft_threshold(x, t):
@@ -176,135 +117,45 @@ def make_dense_step(loss: str, local_bs: int, axis: str):
     return step
 
 
-def make_sparse_step(loss: str, local_bs: int, axis: str, dim: int,
-                     segsum_backend: str = "xla",
-                     spmv_backend: str = "xla"):
-    """Sparse (padded-ELL) variant: gather forward, segment-sum gradient.
-
-    ``segsum_backend`` selects the scatter-accumulate lowering and
-    ``spmv_backend`` the forward matvec lowering (XLA or the Pallas
-    kernels, :mod:`flinkml_tpu.kernels`); each resolved ONCE at fit
-    time and threaded through the trainer factories' lru keys so a
-    gate flip re-keys the jitted step."""
-    from flinkml_tpu import kernels
-
-    def step(coef, epoch, idxl, vall, yl, wl, learning_rate, reg_l2, reg_l1):
-        ib = _window(idxl, epoch, local_bs)
-        vb = _window(vall, epoch, local_bs)
-        yb = _window(yl, epoch, local_bs)
-        wb = _window(wl, epoch, local_bs)
-        acc = _acc_dt(vb.dtype)
-        dot = kernels.spmv(ib, vb, coef, backend=spmv_backend)
-        mult, per_ex = _margin_grad(loss, dot, yb, wb)
-        contrib = (vb * mult[:, None]).reshape(-1)
-        grad_local = kernels.segment_sum(
-            contrib, ib.reshape(-1), dim, backend=segsum_backend
-        )
-        grad = jax.lax.psum(grad_local, axis)
-        loss_sum = jax.lax.psum(jnp.sum(per_ex.astype(acc)), axis)
-        wsum = jax.lax.psum(jnp.sum(wb.astype(acc)), axis)
-        grad = grad + 2.0 * reg_l2 * coef
-        loss_sum = loss_sum + reg_l2 * jnp.sum(jnp.square(coef.astype(acc)))
-        step_size = learning_rate.astype(acc) / wsum
-        new_coef = _soft_threshold(
-            coef - step_size.astype(coef.dtype) * grad,
-            step_size.astype(coef.dtype) * reg_l1,
-        )
-        return new_coef, (loss_sum / wsum).astype(coef.dtype)
-
-    return step
-
-
-_SPARSE_ARGS_PER_BUCKET = {"unsorted": 4, "sorted": 6, "cumsum": 8}
-
-
 def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
                               axis: str, dim: int,
-                              layout: str = "unsorted",
-                              segsum_backend: str = "xla",
-                              spmv_backend: str = "xla"):
-    """nnz-bucketed sparse step: one window per bucket, fused scatters.
+                              segsum_backend: str = "xla"):
+    """nnz-bucketed sparse (padded-ELL) step: gather forward, one fused
+    segment-sum gradient over every bucket's cells.
 
     The batch is stratified across the nnz buckets (``ops.sparse.
     pack_ell_buckets``): each bucket contributes a window sized
     proportionally to its row count, so every step sees a representative
-    nnz mix and every epoch covers every bucket's rows.
-
-    ``layout`` selects the gradient reduction (measured A/B history in
-    :func:`_sparse_layout`):
-
-    - ``unsorted``: one fused ``segment_sum`` over every bucket's cells —
-      XLA's lowering pays a per-step bitonic sort over all cells.
-    - ``sorted`` (round-3): pack-time per-window sort + ``indices_are_
-      sorted=True``; the step pays an O(cells) random permutation gather
-      of the contributions instead (round-4 device A/B: the gather costs
-      MORE than the sort it removes — 0.76x).
-    - ``cumsum`` (round-5): the pack step stores each window's cells
-      column-sorted WITH their values and row indices
-      (:func:`_window_cumsum_tables`), so the step is sort-free AND
-      cells-sized-gather-free: contributions come from ``svals * mult[
-      srows]`` (``mult`` is a [local_bs] table — VMEM-resident), segment
-      totals from one running sum differenced at the precomputed run
-      boundaries, and the only scatter is ``<= max_d`` ascending unique
-      column adds. Every cells-sized op is a streaming pass.
-    """
-
+    nnz mix and every epoch covers every bucket's rows. The data args
+    are four sharded arrays a bucket (indices, values, y, w).
+    ``segsum_backend`` selects the scatter-accumulate lowering (XLA or
+    the Pallas kernel, :mod:`flinkml_tpu.kernels`), resolved ONCE at fit
+    time and threaded through the trainer factory's lru key so a gate
+    flip re-keys the jitted step."""
     from flinkml_tpu import kernels
 
-    def step(coef, epoch, blocks, learning_rate, reg_l2, reg_l1):
+    def step(coef, epoch, *rest):
+        *blocks, learning_rate, reg_l2, reg_l1 = rest
         acc = _acc_dt(coef.dtype)
-        per_bucket = _SPARSE_ARGS_PER_BUCKET[layout]
-
-        def window_of(table2d, ep):
-            n_windows, width = table2d.shape
-            wnum = jnp.asarray(ep, jnp.int32) % n_windows
-            return jax.lax.dynamic_slice(
-                table2d, (wnum, jnp.zeros((), jnp.int32)), (1, width)
-            ).reshape(-1)
-
         contribs, flat_idx = [], []
-        grad_local = jnp.zeros((dim,), coef.dtype)
         loss_l = jnp.zeros((), acc)
         wsum_l = jnp.zeros((), acc)
         for b, local_bs in enumerate(local_bss):
-            block = blocks[per_bucket * b : per_bucket * (b + 1)]
-            idxl, vall, yl, wl = block[:4]
+            idxl, vall, yl, wl = blocks[4 * b : 4 * (b + 1)]
             ib = _window(idxl, epoch, local_bs)
             vb = _window(vall, epoch, local_bs)
             yb = _window(yl, epoch, local_bs)
             wb = _window(wl, epoch, local_bs)
-            dot = kernels.spmv(ib, vb, coef, backend=spmv_backend)
+            dot = ell_matvec(ib, vb, coef)
             mult, per_ex = _margin_grad(loss, dot, yb, wb)
-            if layout == "sorted":
-                contrib = (vb * mult[:, None]).reshape(-1)
-                perm_w = window_of(block[4], epoch)
-                sids_w = window_of(block[5], epoch)
-                grad_local = grad_local + kernels.segment_sum(
-                    jnp.take(contrib, perm_w), sids_w, dim,
-                    indices_are_sorted=True, backend=segsum_backend,
-                )
-            elif layout == "cumsum":
-                srowsl, svalsl, endsl, colsl = block[4:]
-                srows_w = window_of(srowsl, epoch)
-                svals_w = window_of(svalsl, epoch)
-                ends_w = window_of(endsl, epoch)
-                cols_w = window_of(colsl, epoch)
-                contrib = svals_w * jnp.take(mult, srows_w)
-                seg = chunked_run_totals(contrib.astype(acc), ends_w)
-                grad_local = grad_local.at[cols_w].add(
-                    seg.astype(coef.dtype), indices_are_sorted=True,
-                )
-            else:
-                contrib = (vb * mult[:, None]).reshape(-1)
-                contribs.append(contrib)
-                flat_idx.append(ib.reshape(-1))
+            contribs.append((vb * mult[:, None]).reshape(-1))
+            flat_idx.append(ib.reshape(-1))
             loss_l = loss_l + jnp.sum(per_ex.astype(acc))
             wsum_l = wsum_l + jnp.sum(wb.astype(acc))
-        if layout == "unsorted":
-            grad_local = kernels.segment_sum(
-                jnp.concatenate(contribs), jnp.concatenate(flat_idx),
-                dim, backend=segsum_backend,
-            )
+        grad_local = kernels.segment_sum(
+            jnp.concatenate(contribs), jnp.concatenate(flat_idx),
+            dim, backend=segsum_backend,
+        )
         grad = jax.lax.psum(grad_local, axis)
         loss_sum = jax.lax.psum(loss_l, axis)
         wsum = jax.lax.psum(wsum_l, axis)
@@ -320,55 +171,11 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
     return step
 
 
-@functools.lru_cache(maxsize=128)
-def _sparse_trainer_bucketed(mesh, loss: str, local_bss: Tuple[int, ...],
-                             axis: str, dim: int,
-                             layout: str = "unsorted",
-                             segsum_backend: str = "xla",
-                             spmv_backend: str = "xla"):
-    """Bucketed counterpart of :func:`_sparse_trainer` — same carry-style
-    contract; the data args are ``k·len(local_bss)`` sharded arrays where
-    ``k = _SPARSE_ARGS_PER_BUCKET[layout]`` (indices, values, y, w, plus
-    the layout's pack-time tables). ``segsum_backend`` and
-    ``spmv_backend`` are lru-key material: an XLA-kernel trainer and a
-    Pallas-kernel trainer never alias one jitted program."""
-    local_step = make_sparse_step_bucketed(
-        loss, local_bss, axis, dim, layout, segsum_backend, spmv_backend
-    )
-    n_args = _SPARSE_ARGS_PER_BUCKET[layout] * len(local_bss)
-
-    def per_device(coef, epoch, cur_loss, *rest):
-        blocks = rest[:n_args]
-        learning_rate, reg_l2, reg_l1, tol, epoch_end = rest[n_args:]
-
-        def cond(carry):
-            _, ep, cur = carry
-            return jnp.logical_and(ep < epoch_end, cur > tol)
-
-        def body(carry):
-            c, ep, _ = carry
-            new_coef, mean_loss = local_step(
-                c, ep, blocks, learning_rate, reg_l2, reg_l1
-            )
-            return new_coef, ep + 1, mean_loss
-
-        return jax.lax.while_loop(cond, body, (coef, epoch, cur_loss))
-
-    return jax.jit(
-        jax.shard_map(
-            per_device,
-            mesh=mesh,
-            in_specs=(P(), P(), P()) + (P(axis),) * n_args + (P(),) * 5,
-            out_specs=(P(), P(), P()),
-        )
-    )
-
-
-@functools.lru_cache(maxsize=128)
-def _dense_trainer(mesh, loss: str, local_bs: int, axis: str):
-    """Carry-style whole-loop trainer: runs epochs from ``epoch`` up to
-    ``epoch_end`` (or until ``loss <= tol``) entirely on device and returns
-    the full carry ``(coef, epoch, loss)``.
+def _whole_loop(mesh, step, n_sharded: int, axis: str):
+    """Carry-style whole-loop trainer around one per-device ``step``: runs
+    epochs from ``epoch`` up to ``epoch_end`` (or until ``loss <= tol``)
+    entirely on device and returns the full carry ``(coef, epoch, loss)``.
+    The data args are ``n_sharded`` arrays sharded along ``axis``.
 
     Because the carry and ``epoch_end`` are runtime values, the SAME
     compiled executable serves both the one-dispatch fit (epoch_end =
@@ -378,18 +185,19 @@ def _dense_trainer(mesh, loss: str, local_bs: int, axis: str):
     TPU-native answer to the reference's always-on mid-iteration
     checkpointing (``Checkpoints.java:43-211``): the unit of recovery is
     the dispatch, and the only state is the carry."""
-    local_step = make_dense_step(loss, local_bs, axis)
 
-    def per_device(coef, epoch, cur_loss, xl, yl, wl,
-                   learning_rate, reg_l2, reg_l1, tol, epoch_end):
+    def per_device(coef, epoch, cur_loss, *rest):
+        data = rest[:n_sharded]
+        learning_rate, reg_l2, reg_l1, tol, epoch_end = rest[n_sharded:]
+
         def cond(carry):
             _, ep, cur = carry
             return jnp.logical_and(ep < epoch_end, cur > tol)
 
         def body(carry):
             c, ep, _ = carry
-            new_coef, mean_loss = local_step(
-                c, ep, xl, yl, wl, learning_rate, reg_l2, reg_l1
+            new_coef, mean_loss = step(
+                c, ep, *data, learning_rate, reg_l2, reg_l1
             )
             return new_coef, ep + 1, mean_loss
 
@@ -399,48 +207,29 @@ def _dense_trainer(mesh, loss: str, local_bs: int, axis: str):
         jax.shard_map(
             per_device,
             mesh=mesh,
-            in_specs=(P(), P(), P(), P(axis), P(axis), P(axis),
-                      P(), P(), P(), P(), P()),
+            in_specs=(P(), P(), P()) + (P(axis),) * n_sharded + (P(),) * 5,
             out_specs=(P(), P(), P()),
         )
     )
 
 
 @functools.lru_cache(maxsize=128)
-def _sparse_trainer(mesh, loss: str, local_bs: int, axis: str, dim: int,
-                    segsum_backend: str = "xla",
-                    spmv_backend: str = "xla"):
-    """Sparse counterpart of :func:`_dense_trainer` — same carry-style
-    contract (see there for the chunked-checkpointing rationale).
-    ``segsum_backend``/``spmv_backend`` are lru-key material (kernel
-    gate idiom)."""
-    local_step = make_sparse_step(loss, local_bs, axis, dim,
-                                  segsum_backend, spmv_backend)
+def _dense_trainer(mesh, loss: str, local_bs: int, axis: str):
+    """The dense whole-loop trainer (:func:`_whole_loop`)."""
+    return _whole_loop(mesh, make_dense_step(loss, local_bs, axis), 3, axis)
 
-    def per_device(coef, epoch, cur_loss, idxl, vall, yl, wl,
-                   learning_rate, reg_l2, reg_l1, tol, epoch_end):
-        def cond(carry):
-            _, ep, cur = carry
-            return jnp.logical_and(ep < epoch_end, cur > tol)
 
-        def body(carry):
-            c, ep, _ = carry
-            new_coef, mean_loss = local_step(
-                c, ep, idxl, vall, yl, wl, learning_rate, reg_l2, reg_l1
-            )
-            return new_coef, ep + 1, mean_loss
-
-        return jax.lax.while_loop(cond, body, (coef, epoch, cur_loss))
-
-    return jax.jit(
-        jax.shard_map(
-            per_device,
-            mesh=mesh,
-            in_specs=(P(), P(), P(), P(axis), P(axis), P(axis), P(axis),
-                      P(), P(), P(), P(), P()),
-            out_specs=(P(), P(), P()),
-        )
-    )
+@functools.lru_cache(maxsize=128)
+def _sparse_trainer_bucketed(mesh, loss: str, local_bss: Tuple[int, ...],
+                             axis: str, dim: int,
+                             segsum_backend: str = "xla"):
+    """The bucketed sparse whole-loop trainer (:func:`_whole_loop`) over
+    four sharded arrays a bucket. ``segsum_backend`` is lru-key
+    material: an XLA-kernel trainer and a Pallas-kernel trainer never
+    alias one jitted program."""
+    step = make_sparse_step_bucketed(loss, local_bss, axis, dim,
+                                     segsum_backend)
+    return _whole_loop(mesh, step, 4 * len(local_bss), axis)
 
 
 def _restore_carry(checkpoint_manager, dim: int, dtype, mesh=None):
@@ -685,166 +474,18 @@ def train_linear_model(
     )
 
 
-def train_linear_model_sparse(
-    indices: np.ndarray,
-    values: np.ndarray,
-    dim: int,
-    y: np.ndarray,
-    w: np.ndarray,
-    loss: str,
-    mesh: DeviceMesh,
-    max_iter: int,
-    learning_rate: float,
-    global_batch_size: int,
-    reg: float,
-    elastic_net: float,
-    tol: float,
-    seed: int,
-    checkpoint_manager=None,
-    checkpoint_interval: int = 0,
-    resume: bool = False,
-    listeners=(),
-) -> np.ndarray:
-    """Sparse (padded-ELL rows) distributed training — the Criteo-scale
-    path: per-step cost scales with nnz, the model stays a dense [dim]
-    array updated by segment-sum scatter-adds. Chunked checkpointing as in
-    :func:`train_linear_model`."""
-    if loss not in _LOSS_KEYS:
-        raise ValueError(f"loss must be one of {_LOSS_KEYS}, got {loss!r}")
-    n = indices.shape[0]
-    if n == 0:
-        raise ValueError("training table is empty")
-    p_size = mesh.axis_size()
-    perm = np.random.default_rng(seed).permutation(n)
-    indices, values, y, w = indices[perm], values[perm], y[perm], w[perm]
-    idx_pad, _ = pad_to_multiple(indices, p_size)
-    val_pad, _ = pad_to_multiple(values, p_size)
-    y_pad, _ = pad_to_multiple(y, p_size)
-    w_pad, _ = pad_to_multiple(w, p_size)
-    idxd = mesh.shard_batch(idx_pad)
-    vald = mesh.shard_batch(val_pad)
-    yd = mesh.shard_batch(y_pad)
-    wd = mesh.shard_batch(w_pad)
-    n_local = idxd.shape[0] // p_size
-    local_bs = min(max(1, math.ceil(global_batch_size / p_size)), n_local)
-    trainer = _sparse_trainer(
-        mesh.mesh, loss, local_bs, DeviceMesh.DATA_AXIS, int(dim),
-        _segsum_backend(), _spmv_backend(),
-    )
-    return _run_chunked(
-        trainer, (idxd, vald, yd, wd), int(dim), vald.dtype,
-        learning_rate, reg * (1.0 - elastic_net), reg * elastic_net,
-        tol, max_iter, mesh,
-        checkpoint_manager=checkpoint_manager,
-        checkpoint_interval=checkpoint_interval,
-        resume=resume, listeners=listeners,
-    )
-
-
-def _window_sort_tables(
-    idx_pad: np.ndarray, p_size: int, local_bs: int
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Per-device, per-window scatter sort tables for the sorted-scatter
-    layout: ``(perm, sorted_ids)``, each ``[p * n_windows, local_bs *
-    width]``, sharded so device d sees its own ``[n_windows, cells]``.
-
-    Window w on a device covers local rows ``min(w·bs, n_local−bs) ..
-    +bs`` — exactly :func:`_window`'s clamped rotating tile — and its
-    flattened cells are argsorted by column id once here, so the step's
-    ``segment_sum`` can assert ``indices_are_sorted``.
-    """
-    n_total, width = idx_pad.shape
-    n_local = n_total // p_size
-    n_windows = max(-(-n_local // local_bs), 1)
-    cells = local_bs * width
-    perm = np.empty((p_size * n_windows, cells), np.int32)
-    sids = np.empty((p_size * n_windows, cells), np.int32)
-    for d in range(p_size):
-        shard = idx_pad[d * n_local:(d + 1) * n_local]
-        for wnum in range(n_windows):
-            start = min(wnum * local_bs, max(n_local - local_bs, 0))
-            flat = shard[start:start + local_bs].reshape(-1)
-            order = np.argsort(flat, kind="stable").astype(np.int32)
-            row = d * n_windows + wnum
-            perm[row] = order
-            sids[row] = flat[order]
-    return perm, sids
-
-
-def _window_cumsum_tables(
-    idx_pad: np.ndarray, val_pad: np.ndarray, p_size: int, local_bs: int
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Per-device, per-window tables for the ``cumsum`` sparse layout:
-    ``(srows, svals, ends, cols)``.
-
-    Window w on a device covers local rows ``min(w·bs, n_local−bs) ..
-    +bs`` (exactly :func:`_window`'s clamped rotating tile). Its
-    flattened cells are sorted by column id ONCE here, and the step
-    consumes them without any cells-sized permutation:
-
-    - ``srows [p·n_windows, cells] int32``: the within-window ROW of each
-      sorted cell — the step gathers ``mult`` (a [local_bs] table) by it.
-    - ``svals [p·n_windows, cells] f32``: the cell values, pre-sorted.
-    - ``ends [p·n_windows, max_d] int32``: inclusive cell index of each
-      column run's last cell, padded by repeating ``cells−1`` (the
-      running-sum difference of a repeated boundary is 0).
-    - ``cols [p·n_windows, max_d] int32``: the column id of each run,
-      ascending; padding repeats the last real column id, whose repeated
-      boundary contributes exactly 0.
-
-    ``max_d`` is the max distinct-column count over every (device,
-    window) so the stacked array is rectangular.
-    """
-    n_total, width = idx_pad.shape
-    n_local = n_total // p_size
-    n_windows = max(-(-n_local // local_bs), 1)
-    cells = local_bs * width
-    srows = np.empty((p_size * n_windows, cells), np.int32)
-    svals = np.empty((p_size * n_windows, cells), val_pad.dtype)
-    per_window = []
-    for d in range(p_size):
-        ishard = idx_pad[d * n_local:(d + 1) * n_local]
-        vshard = val_pad[d * n_local:(d + 1) * n_local]
-        for wnum in range(n_windows):
-            start = min(wnum * local_bs, max(n_local - local_bs, 0))
-            flat_i = ishard[start:start + local_bs].reshape(-1)
-            flat_v = vshard[start:start + local_bs].reshape(-1)
-            order = np.argsort(flat_i, kind="stable")
-            sids = flat_i[order]
-            row = d * n_windows + wnum
-            srows[row] = (order // width).astype(np.int32)
-            svals[row] = flat_v[order]
-            # Inclusive run ends: positions where the sorted id changes.
-            is_end = np.empty(cells, np.bool_)
-            is_end[:-1] = sids[:-1] != sids[1:]
-            is_end[-1] = True
-            e = np.nonzero(is_end)[0].astype(np.int32)
-            per_window.append((row, e, sids[e]))
-    max_d = max(e.size for _, e, _ in per_window)
-    ends = np.full((p_size * n_windows, max_d), cells - 1, np.int32)
-    cols = np.empty((p_size * n_windows, max_d), np.int32)
-    for row, e, c in per_window:
-        ends[row, : e.size] = e
-        cols[row, : e.size] = c
-        # Pad runs repeat the LAST real run's end (difference 0) and dump
-        # their zero contribution onto the last real column id — harmless
-        # (adds 0) and keeps the ids ascending for the sorted scatter.
-        cols[row, e.size:] = c[-1] if c.size else 0
-    return srows, svals, ends, cols
-
-
 def prepare_sparse_buckets(
     indptr, indices, values, dim: int, y, w, mesh: DeviceMesh,
     global_batch_size: int, max_buckets: int = 4, dtype=np.float32,
-    seed: Optional[int] = None, layout: str = "unsorted",
+    seed: Optional[int] = None,
 ) -> Tuple[Tuple, Tuple[int, ...]]:
     """Pack, shuffle, pad, and shard CSR data for the bucketed trainer.
 
     Returns ``(data_args, local_bss)``: the flat per-bucket sharded arrays
-    (indices, values, y, w[, window-sort perm, sorted ids] per bucket) and
-    each bucket's per-device window size (proportional share of
-    ``global_batch_size``, ≥ 1). The single source of the batching policy
-    — the bench measures exactly what the product trains with.
+    (indices, values, y, w per bucket) and each bucket's per-device
+    window size (proportional share of ``global_batch_size``, ≥ 1). The
+    single source of the batching policy — the bench measures exactly
+    what the product trains with.
 
     ``seed`` shuffles rows *within* each bucket (bucket membership depends
     only on nnz, so this is the reference's partition shuffle applied
@@ -852,18 +493,12 @@ def prepare_sparse_buckets(
     width are one bucket, so their order is
     ``default_rng(seed).permutation(rows)``, the dense fit's, and the
     bucket's rows are the table's: no row ids are made or gathered.
-    Under the default layout each bucket's two blocks reach the mesh in
-    that order through :meth:`DeviceMesh.shard_rows`, round by round,
-    with no permuted copy on the host. The labels ``y`` (any numeric
-    dtype, as the table holds them) and a weight column ``w`` go the same
-    way, cast to ``dtype`` in the gather; ``w`` None is unit weights,
-    made on the device (:meth:`DeviceMesh.shard_ones`), as in
-    :func:`_place_shuffled`.
-    ``layout`` selects the gradient-reduction layout (see
-    :func:`_sparse_layout`): ``sorted`` adds the per-window sort tables
-    (+8 B/cell of HBM), ``cumsum`` the sorted-cell value/row tables and
-    run boundaries (+12 B/cell) that remove the per-step cells-sized
-    sort AND permutation gather (see ``make_sparse_step_bucketed``).
+    Each bucket's two blocks reach the mesh in that order through
+    :meth:`DeviceMesh.shard_rows`, round by round, with no permuted copy
+    on the host. The labels ``y`` (any numeric dtype, as the table holds
+    them) and a weight column ``w`` go the same way, cast to ``dtype`` in
+    the gather; ``w`` None is unit weights, made on the device
+    (:meth:`DeviceMesh.shard_ones`), as in :func:`_place_shuffled`.
     """
     indptr = np.asarray(indptr, dtype=np.int64)
     n = indptr.size - 1
@@ -894,37 +529,18 @@ def prepare_sparse_buckets(
             # The table's rows this bucket's positions hold: where every
             # row has one width the bucket's rows ARE the table's.
             picked = order if rows is None else rows[order]
-        if layout == "unsorted":
-            # One pass, as the dense fit's: the seeded order gathered
-            # round by round on its way to the device, no permuted copy
-            # of the block on the host (DeviceMesh.shard_rows places
-            # exactly shard_batch(pad(block[order]))).
-            idxd = mesh.shard_rows(bi, order, np.int32)
-            vald = mesh.shard_rows(bv, order, dtype)
-        else:
-            # The window tables are built on the host from the permuted,
-            # padded block: these layouts keep the copy.
-            with span("hostdata.shuffle"):
-                idx_pad, _ = pad_to_multiple(bi[order], p_size)
-                val_pad, _ = pad_to_multiple(bv[order], p_size)
-            idxd, vald = mesh.shard_batch(idx_pad), mesh.shard_batch(val_pad)
+        # One pass, as the dense fit's: the seeded order gathered round
+        # by round on its way to the device, no permuted copy of the
+        # block on the host (DeviceMesh.shard_rows places exactly
+        # shard_batch(pad(block[order]))).
+        idxd = mesh.shard_rows(bi, order, np.int32)
+        vald = mesh.shard_rows(bv, order, dtype)
         data_args += [idxd, vald, mesh.shard_rows(y, picked, dtype),
                       _place_weights(w, picked, mesh, dtype)]
         n_local = idxd.shape[0] // p_size
         share = max(1, math.ceil(global_batch_size * n_bucket / (n * p_size)))
         local_bs = min(share, n_local)
         local_bss.append(local_bs)
-        if layout == "sorted":
-            perm, sids = _window_sort_tables(idx_pad, p_size, local_bs)
-            data_args += [mesh.shard_batch(perm), mesh.shard_batch(sids)]
-        elif layout == "cumsum":
-            srows, svals, ends, cols = _window_cumsum_tables(
-                idx_pad, val_pad, p_size, local_bs
-            )
-            data_args += [
-                mesh.shard_batch(srows), mesh.shard_batch(svals),
-                mesh.shard_batch(ends), mesh.shard_batch(cols),
-            ]
     return tuple(data_args), tuple(local_bss)
 
 
@@ -953,14 +569,12 @@ def train_linear_model_sparse_csr(
 ) -> np.ndarray:
     """Skew-proof sparse training from host CSR arrays.
 
-    Replaces the uniform padded-ELL layout (pad every row to the dataset
-    max nnz — pathological under skewed nnz, round-1 VERDICT "weak" #3)
-    with nnz-bucketed ELL blocks (``ops.sparse.pack_ell_buckets``): total
-    padded cells ≈ total nnz, so HBM cost scales with the data, not with
-    the worst row. Each step takes a proportional window from every
-    bucket (stratified batch); with batch ≥ n this is exactly the
-    full-dataset gradient, so results match the uniform path bit-for-bit
-    up to summation order. ``y`` and ``w`` as in
+    Rows go into nnz-bucketed ELL blocks (``ops.sparse.pack_ell_buckets``)
+    and not one block padded to the dataset's max nnz (pathological under
+    skewed nnz): total padded cells ≈ total nnz, so HBM cost scales with
+    the data, not with the worst row. Each step takes a proportional
+    window from every bucket (stratified batch); with batch ≥ n this is
+    exactly the full-dataset gradient. ``y`` and ``w`` as in
     :func:`prepare_sparse_buckets` (``w`` None: unit weights).
     """
     if loss not in _LOSS_KEYS:
@@ -968,15 +582,13 @@ def train_linear_model_sparse_csr(
     n = np.asarray(indptr).size - 1
     if n == 0:
         raise ValueError("training table is empty")
-    layout = _sparse_layout()
     data_args, local_bss = prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
         max_buckets=max_buckets, dtype=dtype, seed=seed,
-        layout=layout,
     )
     trainer = _sparse_trainer_bucketed(
         mesh.mesh, loss, tuple(local_bss), DeviceMesh.DATA_AXIS, int(dim),
-        layout, _segsum_backend(), _spmv_backend(),
+        _segsum_backend(),
     )
     return _run_chunked(
         trainer, tuple(data_args), int(dim), jnp.dtype(dtype),
@@ -1024,34 +636,9 @@ def make_softmax_step(num_classes: int, local_bs: int, axis: str):
 
 @functools.lru_cache(maxsize=128)
 def _softmax_trainer(mesh, num_classes: int, local_bs: int, axis: str):
-    """Carry-style whole-loop softmax trainer — same contract as
-    :func:`_dense_trainer` (chunked checkpointing included)."""
-    local_step = make_softmax_step(num_classes, local_bs, axis)
-
-    def per_device(coef, epoch, cur_loss, xl, yl, wl,
-                   learning_rate, reg_l2, reg_l1, tol, epoch_end):
-        def cond(carry):
-            _, ep, cur = carry
-            return jnp.logical_and(ep < epoch_end, cur > tol)
-
-        def body(carry):
-            c, ep, _ = carry
-            new_coef, mean_loss = local_step(
-                c, ep, xl, yl, wl, learning_rate, reg_l2, reg_l1
-            )
-            return new_coef, ep + 1, mean_loss
-
-        return jax.lax.while_loop(cond, body, (coef, epoch, cur_loss))
-
-    return jax.jit(
-        jax.shard_map(
-            per_device,
-            mesh=mesh,
-            in_specs=(P(), P(), P(), P(axis), P(axis), P(axis),
-                      P(), P(), P(), P(), P()),
-            out_specs=(P(), P(), P()),
-        )
-    )
+    """The softmax whole-loop trainer (:func:`_whole_loop`)."""
+    return _whole_loop(
+        mesh, make_softmax_step(num_classes, local_bs, axis), 3, axis)
 
 
 def train_softmax_model(
@@ -1219,7 +806,7 @@ def _train_linear_sparse_stream_multiprocess(
     row_tile = p_size * 8
     axis = DeviceMesh.DATA_AXIS
     stepper = _sparse_stream_stepper(mesh.mesh, loss, axis, int(sparse_dim),
-                                 _segsum_backend(), _spmv_backend())
+                                     _segsum_backend())
     l2 = reg * (1.0 - elastic_net)
     l1 = reg * elastic_net
 
@@ -1604,20 +1191,17 @@ def _stream_stepper(mesh, loss: str, axis: str):
 
 @functools.lru_cache(maxsize=64)
 def _sparse_stream_stepper(mesh, loss: str, axis: str, dim: int,
-                           segsum_backend: str = "xla",
-                           spmv_backend: str = "xla"):
+                           segsum_backend: str = "xla"):
     """Sparse sibling of :func:`_stream_stepper`: the batch arrives as a
     sharded padded-ELL block (indices/values), the dense ``[dim]``
-    coefficient stays replicated. SpMV forward + one ``segment_sum``
-    gradient scatter (the streamed path has no static windows, so the
-    pack-time-sorted ``cumsum`` layout cannot apply here — each batch's
-    cells are seen once per epoch in stream order). ``segsum_backend``
-    and ``spmv_backend`` are lru-key material (kernel gate idiom)."""
+    coefficient stays replicated. ELL matvec forward + one
+    ``segment_sum`` gradient scatter. ``segsum_backend`` is lru-key
+    material (kernel gate idiom)."""
     from flinkml_tpu import kernels
 
     def per_device(coef, ib, vb, yb, wb, learning_rate, reg_l2, reg_l1):
         acc = _acc_dt(vb.dtype)
-        dot = kernels.spmv(ib, vb, coef, backend=spmv_backend)
+        dot = ell_matvec(ib, vb, coef)
         mult, per_ex = _margin_grad(loss, dot, yb, wb)
         contrib = (vb * mult[:, None]).reshape(-1)
         grad = jax.lax.psum(
@@ -1649,8 +1233,7 @@ def _sparse_stream_stepper(mesh, loss: str, axis: str, dim: int,
 
 @functools.lru_cache(maxsize=64)
 def _sorted_column_stepper(loss: str, dim: int,
-                           segsum_backend: str = "xla",
-                           spmv_backend: str = "xla"):
+                           segsum_backend: str = "xla"):
     """Step factory for :func:`train_linear_model_sorted_stream`: one
     SGD step over a prefetched :class:`~flinkml_tpu.table
     .SortedSparseColumn` batch. Pure ``jax.jit`` — the column's global
@@ -1658,7 +1241,7 @@ def _sorted_column_stepper(loss: str, dim: int,
     block, which does not shard by rows, so the replicated single-
     program step is the correct shape here (psum-free).
 
-    The forward is the gated SpMV over the padded-ELL block; the
+    The forward is the ELL matvec over the padded-ELL block; the
     gradient scatter replays the pack-time sort —
     ``segment_sum(take(contrib, perm), segment_ids,
     indices_are_sorted=True)`` — so the step contains ZERO runtime
@@ -1666,8 +1249,8 @@ def _sorted_column_stepper(loss: str, dim: int,
     thread). Row-bucket padding is neutralized in-jit: the weight
     column is masked by the traced ``n_valid`` row count (weight 0 ⇒
     exact zero contribution to grad/loss/wsum), so batch-size jitter
-    inside a bucket never retraces. Backends are lru-key material
-    (kernel gate idiom)."""
+    inside a bucket never retraces. ``segsum_backend`` is lru-key
+    material (kernel gate idiom)."""
     from flinkml_tpu import kernels
 
     def step(coef, ib, vb, perm, seg, yb, wb, n_valid, learning_rate,
@@ -1679,7 +1262,7 @@ def _sorted_column_stepper(loss: str, dim: int,
             wb.astype(vb.dtype),
             jnp.zeros((), vb.dtype),
         )
-        dot = kernels.spmv(ib, vb, coef, backend=spmv_backend)
+        dot = ell_matvec(ib, vb, coef)
         mult, per_ex = _margin_grad(loss, dot, yb, wb)
         contrib = (vb * mult[:, None]).reshape(-1)
         scattered = kernels.segment_sum(
@@ -1788,7 +1371,7 @@ def train_linear_model_sorted_stream(
         if dim is None:
             dim = col.dim
             stepper = _sorted_column_stepper(
-                loss, dim, _segsum_backend(), _spmv_backend()
+                loss, dim, _segsum_backend()
             )
             coef = jnp.zeros(dim, dt)
         elif col.dim != dim:
@@ -2209,7 +1792,7 @@ def train_linear_model_stream(
     axis = DeviceMesh.DATA_AXIS
     stepper = (
         _sparse_stream_stepper(mesh.mesh, loss, axis, int(sparse_dim),
-                               _segsum_backend(), _spmv_backend())
+                               _segsum_backend())
         if sparse_dim is not None
         else _stream_stepper(mesh.mesh, loss, axis)
     )

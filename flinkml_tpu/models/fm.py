@@ -470,7 +470,7 @@ class _FMModelBase(_FMParams, Model):
         """O(nnz·k) sparse margin over a padded-ELL block — the FM
         identity only ever touches the nonzero columns, so an all-
         SparseVector column never densifies to ``[n, dim]`` (ruinous at
-        hashed-feature dims). Linear term rides the gated SpMV kernel;
+        hashed-feature dims). Linear term is the plain ELL matvec;
         the pairwise term gathers factor rows (``v[indices]`` is
         O(nnz·k)) and contracts with two einsums. ELL padding (index 0
         / value 0) is exact: value 0 zeroes both the gather product and
@@ -478,8 +478,7 @@ class _FMModelBase(_FMParams, Model):
         parameters keep full precision, matching the dense path."""
         import jax
 
-        from flinkml_tpu import kernels
-        from flinkml_tpu.ops.sparse import BatchedCSR
+        from flinkml_tpu.ops.sparse import BatchedCSR, ell_matvec
 
         ib, vb, d = BatchedCSR.pack_sparse_vectors(vecs, dtype=np.float64)
         if d != self._w.shape[0]:
@@ -490,7 +489,7 @@ class _FMModelBase(_FMParams, Model):
         if vb.shape[1] == 0:  # all-empty rows: margin is the intercept
             return np.full(vb.shape[0], self._w0)
         with jax.enable_x64(True):
-            linear = np.asarray(kernels.spmv(ib, vb, self._w))
+            linear = np.asarray(ell_matvec(ib, vb, self._w))
         gathered = self._v[ib]                       # [n, s, k]
         xv = np.einsum("ns,nsk->nk", vb, gathered)
         x2v2 = np.einsum("ns,nsk->nk", vb * vb, gathered * gathered)

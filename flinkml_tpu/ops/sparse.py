@@ -24,6 +24,14 @@ import numpy as np
 from flinkml_tpu.linalg import SparseVector, next_pow2
 
 
+def ell_matvec(indices, values, w) -> jax.Array:
+    """Row-wise dot of a padded ELL block with a dense vector:
+    ``out[r] = sum_s values[r, s] * w[indices[r, s]]``, ``[rows]``. Padded
+    cells (index 0, value 0) add exactly 0; a block of zero rows or zero
+    width gives zeros. The forward margin of every sparse trainer."""
+    return jnp.sum(values * jnp.take(w, indices, axis=0), axis=1)
+
+
 class BatchedCSR:
     """Padded batch of sparse rows with static shapes.
 
@@ -119,20 +127,9 @@ class BatchedCSR:
         rows = jnp.repeat(jnp.arange(n), self.max_nnz)
         return out.at[rows, self.indices.reshape(-1)].add(self.values.reshape(-1))
 
-    def matvec(self, w, backend=None) -> jax.Array:
-        """Row-wise sparse dot against a dense vector: [n].
-
-        Routes through the kernel-backend gate
-        (:mod:`flinkml_tpu.kernels`, site ``spmv``): the XLA
-        gather-multiply-reduce by default, the row-tiled Pallas kernel —
-        which bounds the gathered block to VMEM instead of materializing
-        the whole ``[n, max_nnz]`` gather — when the gate or an explicit
-        ``backend=`` selects it.
-        """
-        from flinkml_tpu import kernels
-
-        w = jnp.asarray(w)
-        return kernels.spmv(self.indices, self.values, w, backend=backend)
+    def matvec(self, w) -> jax.Array:
+        """Row-wise sparse dot against a dense vector: [n]."""
+        return ell_matvec(self.indices, self.values, jnp.asarray(w))
 
     def rmatvec(self, coeffs, backend=None) -> jax.Array:
         """Transpose product: X^T @ coeffs -> dense [dim].
@@ -312,9 +309,7 @@ def sparse_margins(vectors: Sequence[SparseVector], coef,
                     jnp.einsum("rs,rsk->rk", vb, coef_dev[ib])
                 )
             else:
-                from flinkml_tpu import kernels
-
-                out[dest] = np.asarray(kernels.spmv(ib, vb, coef_dev))
+                out[dest] = np.asarray(ell_matvec(ib, vb, coef_dev))
     return out
 
 
@@ -508,8 +503,8 @@ def chunked_run_totals(contrib, ends):
     """Totals of contiguous runs of ``contrib`` (1-D ``[cells]`` or 2-D
     ``[cells, k]``, reduced over axis 0 per column) ending at inclusive
     indices ``ends`` (ascending; a repeated end differences to exactly
-    0) — the sort-free segmented reduction behind the ``cumsum`` sparse
-    gradient layout and the GBT histogram fast path.
+    0) — the sort-free segmented reduction behind the ``cumsum`` layouts
+    of the GBT histogram and the ALS reduction.
 
     A single global running sum would give every boundary difference
     absolute error ~eps·|global prefix|; the two-level decomposition

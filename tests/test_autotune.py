@@ -49,16 +49,16 @@ def tuned(tmp_path, monkeypatch):
 
 def test_table_roundtrip_and_check(tmp_path):
     table = TuningTable()
-    table.set_knob("cpu/cpu/8", "sparse_layout", "cumsum",
-                   candidates={"unsorted": 1.0, "cumsum": 2.0},
+    table.set_knob("cpu/cpu/8", "gbt_histogram", "cumsum",
+                   candidates={"segment": 1.0, "cumsum": 2.0},
                    source="test")
     path = str(tmp_path / "t.json")
     table.save(path)
     loaded = load_table(path)
-    assert loaded.value("cpu/cpu/8", "sparse_layout") == "cumsum"
+    assert loaded.value("cpu/cpu/8", "gbt_histogram") == "cumsum"
     assert loaded.check() == []
-    rec = loaded.record("cpu/cpu/8", "sparse_layout")
-    assert rec["candidates"] == {"unsorted": 1.0, "cumsum": 2.0}
+    rec = loaded.record("cpu/cpu/8", "gbt_histogram")
+    assert rec["candidates"] == {"segment": 1.0, "cumsum": 2.0}
     assert rec["source"] == "test"
 
 
@@ -72,7 +72,7 @@ def test_table_check_flags_problems(tmp_path):
                     "not_a_knob": {"value": 1, "candidates": {"x": 1.0},
                                    "measured_at": "", "source": "",
                                    "unit": ""},
-                    "sparse_layout": {"value": "cumsum", "candidates": {},
+                    "gbt_histogram": {"value": "cumsum", "candidates": {},
                                       "measured_at": "", "source": "",
                                       "unit": ""},
                 },
@@ -94,49 +94,44 @@ def test_unreadable_table_degrades_to_empty(tmp_path, monkeypatch):
     path = tmp_path / "garbage.json"
     path.write_text("{not json")
     monkeypatch.setenv(ENV_TABLE_VAR, str(path))
-    assert tuned_default("sparse_layout", "unsorted") == "unsorted"
+    assert tuned_default("gbt_histogram", "segment") == "segment"
 
 
 # -- lookup precedence -------------------------------------------------------
 
 
 def test_tuned_default_precedence(tuned, monkeypatch):
-    tuned({"sparse_layout": "cumsum"})
-    assert tuned_default("sparse_layout", "unsorted") == "cumsum"
+    tuned({"gbt_histogram": "cumsum"})
+    assert tuned_default("gbt_histogram", "segment") == "cumsum"
     # FLINKML_TPU_AUTOTUNE=0 turns the table layer off.
     monkeypatch.setenv(ENV_DISABLE_VAR, "0")
-    assert tuned_default("sparse_layout", "unsorted") == "unsorted"
+    assert tuned_default("gbt_histogram", "segment") == "segment"
     monkeypatch.delenv(ENV_DISABLE_VAR)
     # a value outside `allowed` degrades to the fallback, loudly-once.
-    assert tuned_default("sparse_layout", "unsorted",
-                         allowed=("unsorted", "sorted")) == "unsorted"
+    assert tuned_default("gbt_histogram", "segment",
+                         allowed=("segment",)) == "segment"
     # another mesh's entry is invisible here.
-    tuned({"sparse_layout": "cumsum"}, mesh="tpu/TPU_v4/8")
-    assert tuned_default("sparse_layout", "unsorted") == "unsorted"
+    tuned({"gbt_histogram": "cumsum"}, mesh="tpu/TPU_v4/8")
+    assert tuned_default("gbt_histogram", "segment") == "segment"
 
 
 def test_gates_consult_table_env_wins(tuned, monkeypatch):
-    from flinkml_tpu.models._linear_sgd import _sparse_layout
     from flinkml_tpu.models.als import _als_layout
     from flinkml_tpu.models.gbt import _hist_layout
     from flinkml_tpu.models.word2vec import _w2v_accum
 
     tuned({
-        "sparse_layout": "cumsum",
         "gbt_histogram": "cumsum",
         "als_reduction": "cumsum",
         "w2v_accum": "onehot",
     })
-    assert _sparse_layout() == "cumsum"
     assert _hist_layout() == "cumsum"
     assert _als_layout() == "cumsum"
     assert _w2v_accum() == "onehot"
     # the explicit env gate beats the table everywhere.
-    monkeypatch.setenv("FLINKML_TPU_SPARSE_LAYOUT", "sorted")
     monkeypatch.setenv("FLINKML_TPU_GBT_HISTOGRAM", "segment")
     monkeypatch.setenv("FLINKML_TPU_ALS_REDUCTION", "segment")
     monkeypatch.setenv("FLINKML_TPU_W2V_ACCUM", "scatter")
-    assert _sparse_layout() == "sorted"
     assert _hist_layout() == "segment"
     assert _als_layout() == "segment"
     assert _w2v_accum() == "scatter"
@@ -195,11 +190,11 @@ def test_serving_config_consults_table(tuned):
 
 def test_settle_hysteresis():
     # within the floor: incumbent keeps the seat (noise cannot flip).
-    assert settle("sparse_layout",
-                  {"unsorted": 100.0, "cumsum": 105.0}) == "unsorted"
+    assert settle("gbt_histogram",
+                  {"segment": 100.0, "cumsum": 105.0}) == "segment"
     # decisive win: challenger takes it.
-    assert settle("sparse_layout",
-                  {"unsorted": 100.0, "cumsum": 100.0 * RATIO_FLOOR * 1.05}
+    assert settle("gbt_histogram",
+                  {"segment": 100.0, "cumsum": 100.0 * RATIO_FLOOR * 1.05}
                   ) == "cumsum"
     # numeric knobs keep their type.
     assert settle("serving_max_batch_rows",
@@ -209,12 +204,12 @@ def test_settle_hysteresis():
     # a COMMITTED winner defends the seat, not the static default: a
     # near-floor measurement cannot flip-flop it back (reverting needs
     # its own decisive win).
-    assert settle("sparse_layout",
-                  {"unsorted": 105.0, "cumsum": 100.0},
+    assert settle("gbt_histogram",
+                  {"segment": 105.0, "cumsum": 100.0},
                   incumbent="cumsum") == "cumsum"
-    assert settle("sparse_layout",
-                  {"unsorted": 100.0 * RATIO_FLOOR * 1.05, "cumsum": 100.0},
-                  incumbent="cumsum") == "unsorted"
+    assert settle("gbt_histogram",
+                  {"segment": 100.0 * RATIO_FLOOR * 1.05, "cumsum": 100.0},
+                  incumbent="cumsum") == "segment"
 
 
 def test_order_presets_promotion():
@@ -234,7 +229,7 @@ def test_order_presets_promotion():
 
 def test_committed_table_has_measured_values_for_this_mesh():
     """The acceptance pin: the committed table carries MEASURED (not
-    guessed) values — winner + candidate measurements — for the four
+    guessed) values — winner + candidate measurements — for the three
     sort-class cumsum defaults, the serving bucket/window, and the
     infer_plan order, on the CI mesh (the 8-virtual-device CPU host the
     whole suite runs on)."""
@@ -249,9 +244,7 @@ def test_committed_table_has_measured_values_for_this_mesh():
         )
         assert rec["candidates"], f"{knob}: no measured candidates"
         assert rec["measured_at"], knob
-    # The four sort-class knobs each measured every landed layout.
-    assert set(table.record(mesh, "sparse_layout")["candidates"]) == \
-        {"unsorted", "sorted", "cumsum"}
+    # The three sort-class knobs each measured every landed layout.
     assert set(table.record(mesh, "gbt_histogram")["candidates"]) == \
         {"segment", "cumsum"}
     assert set(table.record(mesh, "als_reduction")["candidates"]) == \

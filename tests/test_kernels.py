@@ -132,8 +132,8 @@ def test_sparse_step_backend_bitwise():
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     outs = {}
     for backend in ("xla", "pallas"):
-        step = _linear_sgd.make_sparse_step("logistic", bs, "data", dim,
-                                            backend)
+        step = _linear_sgd.make_sparse_step_bucketed(
+            "logistic", (bs,), "data", dim, backend)
         f = jax.jit(jax.shard_map(
             lambda c, e, i, v, yy, ww, _s=step: _s(
                 c, e, i, v, yy, ww, jnp.float32(0.1), jnp.float32(0.0),

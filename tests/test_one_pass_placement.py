@@ -420,23 +420,6 @@ def test_an_int32_block_goes_through_the_staging_rounds(monkeypatch, devices):
     _assert_same_placement(placed, want)
 
 
-@pytest.mark.parametrize("layout", ["sorted", "cumsum"])
-def test_the_other_layouts_keep_the_whole_array_placement(monkeypatch, layout):
-    """Their window tables are built on the host from the permuted block;
-    the blocks themselves still equal the unsorted layout's."""
-    mesh = _mesh(8)
-    indptr, indices, values, y, w = _sparse_rows(1003, True)
-    plain, sizes = _linear_sgd.prepare_sparse_buckets(
-        indptr, indices, values, SPARSE_DIM, y, w, mesh, 256, seed=11)
-    got, got_sizes = _linear_sgd.prepare_sparse_buckets(
-        indptr, indices, values, SPARSE_DIM, y, w, mesh, 256, seed=11,
-        layout=layout)
-    assert got_sizes == sizes
-    assert len(got) == _linear_sgd._SPARSE_ARGS_PER_BUCKET[layout]
-    for g, e in zip(got[:4], plain):
-        _assert_same_placement(g, e)
-
-
 # -- the small columns (PR 27): what a fit hands its trainer for labels
 # and weights, against shard_batch(pad(a.astype(dtype)[perm])) ---------------
 

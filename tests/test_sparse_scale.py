@@ -17,7 +17,7 @@ import pytest
 
 from flinkml_tpu.models import LogisticRegression
 from flinkml_tpu.models._linear_sgd import (
-    train_linear_model_sparse,
+    train_linear_model,
     train_linear_model_sparse_csr,
 )
 from flinkml_tpu.ops.sparse import choose_ell_widths, pack_ell_buckets
@@ -82,27 +82,23 @@ def test_choose_ell_widths_beats_uniform(rng):
 
 
 def test_bucketed_matches_uniform_ell_full_batch(rng, mesh):
-    """Full batch ⇒ every step uses the whole dataset in both layouts ⇒
-    identical GD trajectories up to float summation order."""
+    """Full batch ⇒ every step uses the whole dataset ⇒ the bucketed
+    sparse fit and the DENSE trainer on the densified rows (the layout
+    with nothing to bucket) run identical GD trajectories up to float
+    summation order."""
     n, dim = 96, 40
-    indptr, indices, values, nnz = _skewed_csr(
+    indptr, indices, values, _ = _skewed_csr(
         rng, n, dim, head_nnz=(1, 5), tail_frac=0.05, tail_nnz=20
     )
     y = rng.integers(0, 2, n).astype(np.float64)
     w = np.ones(n)
-    # Uniform ELL pack of the same rows.
-    width = int(nnz.max())
-    ell_i = np.zeros((n, width), dtype=np.int32)
-    ell_v = np.zeros((n, width), dtype=np.float64)
-    for r in range(n):
-        k = int(indptr[r + 1] - indptr[r])
-        ell_i[r, :k] = indices[indptr[r]:indptr[r + 1]]
-        ell_v[r, :k] = values[indptr[r]:indptr[r + 1]]
     hyper = dict(
         loss="logistic", mesh=mesh, max_iter=40, learning_rate=0.5,
         global_batch_size=n, reg=0.01, elastic_net=0.25, tol=0.0, seed=3,
     )
-    uniform = train_linear_model_sparse(ell_i, ell_v, dim, y, w, **hyper)
+    dense = train_linear_model(
+        _densify(indptr, indices, values, n, dim), y, w, **hyper
+    )
     bucketed = train_linear_model_sparse_csr(
         indptr, indices, values, dim, y, w, dtype=np.float64, **hyper
     )
@@ -112,7 +108,7 @@ def test_bucketed_matches_uniform_ell_full_batch(rng, mesh):
     import jax
 
     atol = 1e-10 if jax.config.jax_enable_x64 else 1e-6
-    np.testing.assert_allclose(bucketed, uniform, atol=atol)
+    np.testing.assert_allclose(bucketed, dense, atol=atol)
 
 
 def test_criteo_scale_dim_1e6_within_memory_budget(rng, mesh):
@@ -292,94 +288,6 @@ def test_estimator_sparse_vectors_use_bucketed_path(rng):
     assert np.mean(out["prediction"] == np.array(labels)) > 0.9
 
 
-def test_sorted_scatter_layout_matches_unsorted(mesh, monkeypatch):
-    """Round-3 sort-elimination layout: pre-sorted per-window scatter with
-    indices_are_sorted=True must train to the same model as the per-step
-    sort layout (identical up to f32 summation order)."""
-    from flinkml_tpu.models import _linear_sgd
-
-    rng = np.random.default_rng(5)
-    n, dim, nnz = 512, 2000, 7
-    indptr = np.arange(n + 1, dtype=np.int64) * nnz
-    indices = rng.integers(0, dim, size=n * nnz).astype(np.int32)
-    values = rng.normal(size=n * nnz).astype(np.float32)
-    beta = np.zeros(dim, np.float32)
-    beta[rng.choice(dim, 50, replace=False)] = rng.normal(size=50)
-    margins = (values.reshape(n, nnz) * beta[indices.reshape(n, nnz)]).sum(1)
-    y = (margins > 0).astype(np.float32)
-    w = np.ones(n, np.float32)
-
-    def train(flag):
-        monkeypatch.setenv("FLINKML_TPU_SORTED_SCATTER", flag)
-        return _linear_sgd.train_linear_model_sparse_csr(
-            indptr, indices, values, dim, y, w, loss="logistic",
-            mesh=mesh, max_iter=30, learning_rate=0.5,
-            global_batch_size=256, reg=0.01, elastic_net=0.0, tol=0.0,
-            seed=3,
-        )
-
-    unsorted_coef = train("0")
-    sorted_coef = train("1")
-    np.testing.assert_allclose(sorted_coef, unsorted_coef, atol=1e-5)
-    # And the sorted run actually learns.
-    acc = np.mean(
-        ((values.reshape(n, nnz)
-          * sorted_coef[indices.reshape(n, nnz)]).sum(1) > 0) == y
-    )
-    assert acc > 0.9, acc
-
-
-def test_cumsum_layout_matches_unsorted(mesh, monkeypatch):
-    """Round-5 sort-free layout: pack-time column-sorted cells + running-
-    sum boundary differences must train to the same model (allclose —
-    the running-sum difference changes f32 summation order)."""
-    from flinkml_tpu.models import _linear_sgd
-
-    rng = np.random.default_rng(7)
-    n, dim = 640, 3000
-    # Skewed nnz so multiple ELL buckets exist, plus a Zipfian column
-    # distribution (the Criteo profile the layout exists for).
-    nnz = np.clip(rng.geometric(0.2, size=n), 1, 40)
-    indptr = np.zeros(n + 1, np.int64)
-    np.cumsum(nnz, out=indptr[1:])
-    total = int(indptr[-1])
-    indices = np.minimum(
-        rng.zipf(1.3, size=total) - 1, dim - 1
-    ).astype(np.int32)
-    values = rng.normal(size=total).astype(np.float32)
-    beta = np.zeros(dim, np.float32)
-    beta[rng.choice(dim, 50, replace=False)] = rng.normal(size=50)
-    y = np.zeros(n, np.float32)
-    for r in range(n):
-        sl = slice(indptr[r], indptr[r + 1])
-        y[r] = float((values[sl] * beta[indices[sl]]).sum() > 0)
-    w = np.ones(n, np.float32)
-
-    def train(layout):
-        monkeypatch.setenv("FLINKML_TPU_SPARSE_LAYOUT", layout)
-        return _linear_sgd.train_linear_model_sparse_csr(
-            indptr, indices, values, dim, y, w, loss="logistic",
-            mesh=mesh, max_iter=30, learning_rate=0.5,
-            global_batch_size=256, reg=0.01, elastic_net=0.1, tol=0.0,
-            seed=3,
-        )
-
-    base = train("unsorted")
-    cum = train("cumsum")
-    np.testing.assert_allclose(cum, base, atol=2e-4, rtol=2e-4)
-
-
-def test_sparse_layout_env_validation(monkeypatch):
-    from flinkml_tpu.models._linear_sgd import _sparse_layout
-
-    monkeypatch.setenv("FLINKML_TPU_SPARSE_LAYOUT", "bogus")
-    with pytest.raises(ValueError, match="FLINKML_TPU_SPARSE_LAYOUT"):
-        _sparse_layout()
-    monkeypatch.delenv("FLINKML_TPU_SPARSE_LAYOUT")
-    monkeypatch.setenv("FLINKML_TPU_SORTED_SCATTER", "1")
-    assert _sparse_layout() == "sorted"
-
-
 def test_chunked_segment_totals_precision_at_bench_scale():
     """The two-level running sum must hold f32 precision at the REAL
     Criteo cell count: a single global f32 prefix sum random-walks to
@@ -419,60 +327,6 @@ def test_chunked_segment_totals_precision_at_bench_scale():
     naive = t32 - np.concatenate([[np.float32(0)], t32[:-1]])
     naive_rel = np.abs(naive - seg64) / denom
     assert rel.max() < naive_rel.max() / 5, (rel.max(), naive_rel.max())
-
-
-def test_window_cumsum_tables_reconstruct_segment_sums():
-    """The pack-time tables must reproduce an exact per-window histogram:
-    sum(svals[run] * mult[srows[run]]) grouped by cols == dense reference."""
-    from flinkml_tpu.models._linear_sgd import _window_cumsum_tables
-
-    rng = np.random.default_rng(0)
-    p, n_local, width, local_bs, dim = 2, 12, 3, 5, 17
-    idx_pad = rng.integers(0, dim, size=(p * n_local, width)).astype(np.int32)
-    val_pad = rng.normal(size=(p * n_local, width)).astype(np.float64)
-    srows, svals, ends, cols = _window_cumsum_tables(
-        idx_pad, val_pad, p, local_bs
-    )
-    n_windows = -(-n_local // local_bs)
-    assert srows.shape == (p * n_windows, local_bs * width)
-    for d in range(p):
-        mult = rng.normal(size=local_bs)
-        for wnum in range(n_windows):
-            row = d * n_windows + wnum
-            start = min(wnum * local_bs, n_local - local_bs)
-            ib = idx_pad[d * n_local + start:d * n_local + start + local_bs]
-            vb = val_pad[d * n_local + start:d * n_local + start + local_bs]
-            expect = np.zeros(dim)
-            np.add.at(expect, ib.reshape(-1),
-                      (vb * mult[:, None]).reshape(-1))
-            contrib = svals[row] * mult[srows[row]]
-            csum = np.cumsum(contrib)
-            t = csum[ends[row]]
-            seg = t - np.concatenate([[0.0], t[:-1]])
-            got = np.zeros(dim)
-            np.add.at(got, cols[row], seg)
-            np.testing.assert_allclose(got, expect, atol=1e-12)
-            assert (np.diff(cols[row]) >= 0).all()
-
-
-def test_window_sort_tables_are_sorted_and_permute_back():
-    from flinkml_tpu.models._linear_sgd import _window_sort_tables
-
-    rng = np.random.default_rng(0)
-    p, n_local, width, local_bs = 2, 12, 3, 5
-    idx_pad = rng.integers(0, 100, size=(p * n_local, width)).astype(np.int32)
-    perm, sids = _window_sort_tables(idx_pad, p, local_bs)
-    n_windows = -(-n_local // local_bs)
-    assert perm.shape == (p * n_windows, local_bs * width)
-    for d in range(p):
-        shard = idx_pad[d * n_local:(d + 1) * n_local]
-        for wnum in range(n_windows):
-            row = d * n_windows + wnum
-            start = min(wnum * local_bs, n_local - local_bs)
-            flat = shard[start:start + local_bs].reshape(-1)
-            # sids is flat permuted by perm, and non-decreasing.
-            np.testing.assert_array_equal(flat[perm[row]], sids[row])
-            assert (np.diff(sids[row]) >= 0).all()
 
 
 def test_chunked_run_totals_small_input_avoids_full_chunk_pad():

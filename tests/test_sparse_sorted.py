@@ -1,11 +1,8 @@
 """Sorted-by-design sparse hot loops (ISSUE 16): the multi-block
-segment-sum grid above the retired one-block input ceiling, the CSR
-SpMV chain kernel (parity contract: the JITTED XLA twin), the
+segment-sum grid above the retired one-block input ceiling, the
 SortedSparseColumn pack/prefetch format with zero retraces across
 buckets, the sorted-column stream fit's bitwise parity with the CSR
 stream, and the FML404 sorted-scatter provenance gate."""
-
-import importlib
 
 import numpy as np
 import pytest
@@ -14,12 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from flinkml_tpu import kernels
-from flinkml_tpu.kernels import ENV_VAR, KernelUnsupportedError
+from flinkml_tpu.kernels import KernelUnsupportedError
 from flinkml_tpu.kernels import segsum as _segsum
-
-# The package re-exports the spmv DISPATCHER under the submodule's
-# name; import the module itself for ROW_TILE / NO_TPU_LOWERING.
-_spmv = importlib.import_module("flinkml_tpu.kernels.spmv")
 from flinkml_tpu.linalg import SparseVector
 from flinkml_tpu.table import SortedSparseColumn, Table
 
@@ -131,90 +124,6 @@ def test_segsum_exchange_shape_above_old_ceiling_accepted_compiled():
     flat = jax.ShapeDtypeStruct((cells,), jnp.float32)
     assert "488 MiB" in _segsum.unsupported_reason(
         flat, ids, 1_000_000, interpret=False)
-
-
-# -- CSR SpMV ---------------------------------------------------------------
-
-
-@pytest.mark.parametrize("dtype", ["float32", "float64", "bfloat16"])
-def test_spmv_parity_vs_jitted_twin(dtype):
-    """Bitwise vs the JITTED XLA reference (the parity contract — an
-    eager reference can differ in the last f32 bit because XLA's
-    unfused reduce uses a different association tree), including a row
-    count that is not a multiple of ROW_TILE."""
-    rng = np.random.default_rng(3)
-    rows, width, dim = _spmv.ROW_TILE * 4 + 3, 16, 512
-    ib = jnp.asarray(rng.integers(0, dim, (rows, width)), jnp.int32)
-    vb = jnp.asarray(rng.normal(size=(rows, width))).astype(dtype)
-    w = jnp.asarray(rng.normal(size=dim)).astype(dtype)
-    twin = jax.jit(
-        lambda i, v, ww: jnp.sum(v * jnp.take(ww, i, axis=0), axis=1)
-    )
-    ref = twin(ib, vb, w)
-    out = kernels.spmv(ib, vb, w, backend="pallas")
-    assert out.dtype == ref.dtype
-    assert np.asarray(ref).tobytes() == np.asarray(out).tobytes()
-
-
-def test_spmv_refusals(monkeypatch):
-    ib = jnp.zeros((4, 2), jnp.int32)
-    vb = jnp.ones((4, 2), jnp.float32)
-    with pytest.raises(KernelUnsupportedError, match="not floating"):
-        kernels.spmv(ib, jnp.ones((4, 2), jnp.int32),
-                     jnp.ones(8, jnp.int32), backend="pallas")
-    with pytest.raises(KernelUnsupportedError, match="!= w dtype"):
-        kernels.spmv(ib, vb, jnp.ones(8, jnp.float64), backend="pallas")
-    # there is no compiled path at any shape: the refusal names what
-    # Mosaic said about the in-kernel gather; the interpreter accepts.
-    w = jnp.ones(8, jnp.float32)
-    reason = _spmv.unsupported_reason(ib, vb, w, interpret=False)
-    assert reason == _spmv.NO_TPU_LOWERING and "2D gather" in reason
-    assert _spmv.unsupported_reason(ib, vb, w, interpret=True) is None
-    monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")
-    with pytest.raises(KernelUnsupportedError, match="does not compile"):
-        kernels.spmv(ib, vb, w, backend="pallas")
-
-
-def test_spmv_gate_threaded_vs_explicit(tmp_path, monkeypatch):
-    """The lru-key idiom for the 4th site: a TABLE-chosen pallas
-    threaded through ``backend=`` keeps warn-and-fallback on
-    unsupported operands; a backend DISAGREEING with the gate is an
-    explicit request and refuses loudly."""
-    from flinkml_tpu.autotune import TuningTable, mesh_key
-    from flinkml_tpu.autotune.table import ENV_TABLE_VAR
-
-    table = TuningTable()
-    table.set_knob(mesh_key(), "kernel_backend_spmv", "pallas",
-                   candidates={"xla": 1.0, "pallas": 2.0}, source="test")
-    path = str(tmp_path / "table.json")
-    table.save(path)
-    monkeypatch.setenv(ENV_TABLE_VAR, path)
-    monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")  # compiled: refused
-    rng = np.random.default_rng(4)
-    ib = jnp.asarray(rng.integers(0, 32, (4, 3)), jnp.int32)
-    vb = jnp.asarray(rng.normal(size=(4, 3)))            # float64
-    w = jnp.asarray(rng.normal(size=32))
-    assert vb.dtype == jnp.float64
-    threaded = kernels.spmv_backend()
-    assert threaded == "pallas"
-    ref = jax.jit(
-        lambda i, v, ww: jnp.sum(v * jnp.take(ww, i, axis=0), axis=1)
-    )(ib, vb, w)
-    # Jitted like the reference (and like every product call site):
-    # the degraded XLA expression run EAGERLY reduces unfused and
-    # differs from the fused program in the last bit on this XLA:CPU.
-    out = jax.jit(
-        lambda i, v, ww: kernels.spmv(i, v, ww, backend=threaded)
-    )(ib, vb, w)                                         # degrades
-    assert np.asarray(ref).tobytes() == np.asarray(out).tobytes()
-    monkeypatch.setenv(ENV_VAR, "spmv=xla")              # gate says xla
-    with pytest.raises(KernelUnsupportedError):
-        kernels.spmv(ib, vb, w, backend="pallas")        # arg disagrees
-
-
-def test_spmv_in_gate_sites_and_factory():
-    assert "spmv" in kernels.SITES
-    assert kernels.spmv_backend() == "xla"   # opt-in by measurement
 
 
 # -- SortedSparseColumn pack + prefetch --------------------------------------

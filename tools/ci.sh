@@ -1228,12 +1228,9 @@ stage "pallas smoke (3-kernel interpret parity + gate-off + bench ratio)" \
     pallas_smoke
 
 # Sparse smoke (ISSUE 16 acceptance): interpret-mode bitwise parity for
-# the two sorted-hot-loop kernels — the multi-block segment-sum on a
-# grid with cells > BLOCK_CELLS (above the retired one-block ceiling)
-# and the CSR SpMV chain kernel vs its JITTED XLA twin (the parity
-# contract — eager XLA fuses the reduce tree differently in the last
-# f32 bit; docs/development/kernels.md); the typed ceiling refusal must
-# name MAX_COMPILED_CELLS; the FML404 sorted-scatter fixtures must be
+# the multi-block segment-sum on a grid with cells > BLOCK_CELLS (above
+# the retired one-block ceiling); the typed ceiling refusal must name
+# MAX_COMPILED_CELLS; the FML404 sorted-scatter fixtures must be
 # flagged (bad) and pass (good) by name; then the sparse_hot_loops_cpu
 # bench stage is parsed with a >=1.0x no-regression tripwire on sorted
 # sparse-LR rows/s vs the densified baseline (measured ~16x on an idle
@@ -1271,15 +1268,6 @@ b = np.asarray(kernels.segment_sum(vals, ids, nseg,
                                    backend="pallas"))
 assert a.tobytes() == b.tobytes(), "multi-block sorted segsum parity"
 
-# CSR SpMV vs the JITTED XLA twin, bitwise.
-ib = jnp.asarray(rng.integers(0, 512, size=(256, 16)), jnp.int32)
-vb = jnp.asarray(rng.normal(size=(256, 16)).astype(np.float32))
-w = jnp.asarray(rng.normal(size=512).astype(np.float32))
-twin = jax.jit(lambda i, v, w: jnp.sum(v * jnp.take(w, i, axis=0), axis=1))
-a = np.asarray(twin(ib, vb, w))
-b = np.asarray(kernels.spmv(ib, vb, w, backend="pallas"))
-assert a.tobytes() == b.tobytes(), "spmv parity vs jitted XLA twin"
-
 # Typed ceiling refusal on the compiled path: the OUTPUT ceiling
 # (num_segments * k > MAX_COMPILED_CELLS) must refuse loudly, naming
 # the constant — never a silent fallback for an explicit request.
@@ -1292,7 +1280,7 @@ except kernels.KernelUnsupportedError as e:
     assert "MAX_COMPILED_CELLS" in str(e), e
 finally:
     del os.environ[kernels.ENV_INTERPRET_VAR]
-print("sparse smoke: multi-block segsum + spmv interpret parity bitwise,"
+print("sparse smoke: multi-block segsum interpret parity bitwise,"
       " ceiling refusal typed and named")
 EOF
     # The FML404 sorted-scatter gate has teeth: the seeded fixture must
@@ -1324,7 +1312,7 @@ print('sparse smoke: sorted sparse-LR', rec['sparse_sorted_rows_per_sec'],
       rec['dim'], 'nnz/row', rec['nnz_per_row'])
 "
 }
-stage "sparse smoke (multi-block segsum + spmv parity + FML404 + bench)" \
+stage "sparse smoke (multi-block segsum parity + FML404 + bench)" \
     sparse_smoke
 
 # Autoscale smoke (ISSUE 15 acceptance, device-free): (1) closed-loop
