@@ -20,7 +20,11 @@ of BASELINE.json config #3.
 
 The sparse path consumes padded ELL batches (``flinkml_tpu.ops.sparse``):
 forward = gather+row-sum, gradient = flat segment-sum scatter — the
-Criteo-scale path (config #5).
+Criteo-scale path (config #5). Where a table's ELL slots each keep to a
+short range of columns (one cell a field, the fields on blocks of the
+feature vector), those slots do without the gather and the scatter: a
+per-slot plan read off the cells in every fit, and a step that follows it
+(:func:`make_sparse_step_bucketed`).
 """
 
 from __future__ import annotations
@@ -38,8 +42,19 @@ from jax.sharding import PartitionSpec as P
 # a ``CsrColumn`` runs at construction (without its row-order part).
 from flinkml_tpu.linalg import check_csr_structure as _check_csr_structure
 from flinkml_tpu.ops.losses import margin_terms as _margin_grad
-from flinkml_tpu.ops.sparse import ell_matvec, pack_ell_buckets
+from flinkml_tpu.ops.sparse import (
+    LANES,
+    align_ragged_rows,
+    block_accumulate,
+    block_groups,
+    block_lookup,
+    ell_matvec,
+    one_width_block,
+    pack_ell_buckets,
+    slot_block_plan,
+)
 from flinkml_tpu.parallel import DeviceMesh
+from flinkml_tpu.parallel.mesh import gather_pool
 from flinkml_tpu.utils.metrics import metrics
 from flinkml_tpu.utils.profiling import span
 
@@ -117,11 +132,28 @@ def make_dense_step(loss: str, local_bs: int, axis: str):
     return step
 
 
+def _lane_rows(x):
+    """``x [dim]`` as rows of 128 lanes, zeros past its end: what a
+    block, which starts at a row, is cut out of."""
+    rows = -(-x.shape[0] // LANES)
+    return jnp.pad(x, (0, rows * LANES - x.shape[0])).reshape(rows, LANES)
+
+
+def _slot_major(block, slots):
+    """``block[:, slots].T`` as static slices (an index array would be a
+    gather): ``[len(slots), rows]``, the rows along the lanes."""
+    if not slots:
+        return block[:, :0].T
+    return jnp.stack([block[:, j] for j in slots])
+
+
 def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
                               axis: str, dim: int,
-                              segsum_backend: str = "xla"):
+                              segsum_backend: str = "xla",
+                              slot_plan: Tuple = ()):
     """nnz-bucketed sparse (padded-ELL) step: gather forward, one fused
-    segment-sum gradient over every bucket's cells.
+    segment-sum gradient over every bucket's cells; under a plan, the
+    slots whose columns sit in a block of their own do without either.
 
     The batch is stratified across the nnz buckets (``ops.sparse.
     pack_ell_buckets``): each bucket contributes a window sized
@@ -131,13 +163,35 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
     ``segsum_backend`` selects the scatter-accumulate lowering (XLA or
     the Pallas kernel, :mod:`flinkml_tpu.kernels`), resolved ONCE at fit
     time and threaded through the trainer factory's lru key so a gate
-    flip re-keys the jitted step."""
+    flip re-keys the jitted step.
+
+    ``slot_plan`` is what :func:`prepare_sparse_buckets` read off a
+    one-width table's cells (``ops.sparse.slot_block_plan``: a block
+    length or None per ELL slot; the one bucket's). Under a plan the
+    step takes one more array after the bucket's four, the blocks'
+    starts (``[width] int32`` in rows of 128 columns, a runtime operand:
+    tables of one schema share the program wherever their columns
+    start). A blocked slot's block is ``length / 128`` consecutive rows
+    of the coefficients laid 128 a row; its cells' coefficients are
+    looked up in it by a one-hot product (``ops.sparse.block_lookup``:
+    the values ``coef[idx]`` bit for bit) and its gradient accumulated
+    the same way (``block_accumulate``) and added to those rows; the
+    other slots gather and share the one segment-sum, as every slot does
+    under an empty plan, whose program is the one this function built
+    before there were plans. Blocks may overlap: the gradient adds."""
     from flinkml_tpu import kernels
+
+    if any(slot_plan) and len(local_bss) != 1:
+        raise ValueError("a slot plan is a one-bucket table's")
+    groups = block_groups(slot_plan, local_bss[0])
+    general = [j for j, length in enumerate(slot_plan) if length is None]
 
     def step(coef, epoch, *rest):
         *blocks, learning_rate, reg_l2, reg_l1 = rest
+        if groups:
+            *blocks, slot_starts = blocks
         acc = _acc_dt(coef.dtype)
-        contribs, flat_idx = [], []
+        contribs, flat_idx, block_grads = [], [], []
         loss_l = jnp.zeros((), acc)
         wsum_l = jnp.zeros((), acc)
         for b, local_bs in enumerate(local_bss):
@@ -146,8 +200,28 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
             vb = _window(vall, epoch, local_bs)
             yb = _window(yl, epoch, local_bs)
             wb = _window(wl, epoch, local_bs)
+            # Each group's cells, slot-major, indexed from their blocks'
+            # first rows; what is left of ib, vb are the general slots.
+            cells = []
+            for length, slots in groups:
+                first = jnp.stack([slot_starts[j] for j in slots])
+                rows = first[:, None] + jnp.arange(length // LANES)
+                cells.append((rows,
+                              _slot_major(ib, slots) - LANES * first[:, None],
+                              _slot_major(vb, slots)))
+            if groups:
+                ib, vb = _slot_major(ib, general).T, _slot_major(vb, general).T
+                tiled = _lane_rows(coef)
             dot = ell_matvec(ib, vb, coef)
+            for rows, local, vals in cells:
+                looked = block_lookup(
+                    tiled[rows].reshape(rows.shape[0], -1), local)
+                dot = dot + jnp.sum(vals * looked, axis=0)
             mult, per_ex = _margin_grad(loss, dot, yb, wb)
+            block_grads += [
+                (rows, block_accumulate(
+                    local, vals * mult[None, :], LANES * rows.shape[1]))
+                for rows, local, vals in cells]
             contribs.append((vb * mult[:, None]).reshape(-1))
             flat_idx.append(ib.reshape(-1))
             loss_l = loss_l + jnp.sum(per_ex.astype(acc))
@@ -156,6 +230,11 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
             jnp.concatenate(contribs), jnp.concatenate(flat_idx),
             dim, backend=segsum_backend,
         )
+        if block_grads:
+            tiled = _lane_rows(grad_local)
+            for rows, sums in block_grads:
+                tiled = tiled.at[rows].add(sums.reshape(rows.shape + (LANES,)))
+            grad_local = tiled.reshape(-1)[:dim]
         grad = jax.lax.psum(grad_local, axis)
         loss_sum = jax.lax.psum(loss_l, axis)
         wsum = jax.lax.psum(wsum_l, axis)
@@ -222,14 +301,21 @@ def _dense_trainer(mesh, loss: str, local_bs: int, axis: str):
 @functools.lru_cache(maxsize=128)
 def _sparse_trainer_bucketed(mesh, loss: str, local_bss: Tuple[int, ...],
                              axis: str, dim: int,
-                             segsum_backend: str = "xla"):
+                             segsum_backend: str = "xla",
+                             slot_plan: Tuple = ()):
     """The bucketed sparse whole-loop trainer (:func:`_whole_loop`) over
     four sharded arrays a bucket. ``segsum_backend`` is lru-key
     material: an XLA-kernel trainer and a Pallas-kernel trainer never
-    alias one jitted program."""
+    alias one jitted program. So is ``slot_plan`` (:func:`make_sparse_
+    step_bucketed`): static, a block length or None per slot, up a short
+    ladder so that a configuration's tables share one; where the blocks
+    start is a fifth sharded array (every device's shard the same
+    ``[width]`` starts) and keys nothing. A table with no blocked slot
+    has the empty plan ``()``, no fifth array, and the program every
+    sparse fit had before."""
     step = make_sparse_step_bucketed(loss, local_bss, axis, dim,
-                                     segsum_backend)
-    return _whole_loop(mesh, step, 4 * len(local_bss), axis)
+                                     segsum_backend, slot_plan)
+    return _whole_loop(mesh, step, 4 * len(local_bss) + bool(slot_plan), axis)
 
 
 def _restore_carry(checkpoint_manager, dim: int, dtype, mesh=None):
@@ -478,14 +564,31 @@ def prepare_sparse_buckets(
     indptr, indices, values, dim: int, y, w, mesh: DeviceMesh,
     global_batch_size: int, max_buckets: int = 4, dtype=np.float32,
     seed: Optional[int] = None,
-) -> Tuple[Tuple, Tuple[int, ...]]:
+) -> Tuple[Tuple, Tuple[int, ...], Tuple]:
     """Pack, shuffle, pad, and shard CSR data for the bucketed trainer.
 
-    Returns ``(data_args, local_bss)``: the flat per-bucket sharded arrays
-    (indices, values, y, w per bucket) and each bucket's per-device
-    window size (proportional share of ``global_batch_size``, ≥ 1). The
-    single source of the batching policy — the bench measures exactly
-    what the product trains with.
+    Returns ``(data_args, local_bss, slot_plan)``: the flat per-bucket
+    sharded arrays (indices, values, y, w per bucket; under a plan the
+    blocks' starts after them), each bucket's per-device window size
+    (proportional share of ``global_batch_size``, ≥ 1), and the plan the
+    step follows. The single source of the batching policy — the bench
+    measures exactly what the product trains with.
+
+    ``slot_plan`` is one observation of the cells, made here in every
+    fit (``ops.sparse.slot_block_plan``, inside ``hostdata.sparse_pack``):
+    where every row has one width, ELL slot ``j`` of every row is the
+    row's ``j``-th cell, and a slot whose columns all lie in one short
+    range (a field with a range of columns of its own) is *blocked*.
+    The plan holds each blocked slot's block length and keys the program
+    (:func:`_sparse_trainer_bucketed`); where the blocks start is the
+    last of ``data_args``, an operand. Such a table with cells missing
+    has ragged rows: it is laid one field a slot first
+    (``ops.sparse.align_ragged_rows``, in place of the buckets' fill)
+    and planned the same way. The cells of a one-width table are placed
+    as they are. A table with no blocked slot (rows hashed over all of
+    ``dim``, text rows in several buckets) has the empty plan ``()`` and
+    nothing after its buckets' arrays. ``hostdata.sparse.blocked_slots``
+    and ``.blocked_cells`` count what the plan covers.
 
     ``seed`` shuffles rows *within* each bucket (bucket membership depends
     only on nnz, so this is the reference's partition shuffle applied
@@ -507,12 +610,38 @@ def prepare_sparse_buckets(
     with span("hostdata.sparse_pack"):
         # Bucket choice and ELL fill. Rows of one width (hashed
         # categorical data) are one bucket of two views: nothing is
-        # filled, and the span is the look at ``indptr``.
-        buckets, row_ids = pack_ell_buckets(
-            indptr, indices, values, dim, max_buckets=max_buckets, dtype=dtype,
-        )
+        # filled, and the span is the look at ``indptr`` and the plan.
+        # (The block products move float32 unrounded, and no other
+        # width: any other training dtype has no plan.)
+        planned = np.dtype(dtype) == np.float32
+        slot_plan, starts, aligned = (), None, None
+        block = one_width_block(indptr, indices, values, dtype)
+        with gather_pool() as pool:
+            if block is None and planned:
+                block = aligned = align_ragged_rows(
+                    indptr, indices, values, dtype, pool)
+            if block is not None and planned:
+                # One block whose slot j holds every row's j-th cell (or
+                # its j-th field's).
+                slot_plan, starts = slot_block_plan(
+                    block["indices"], dim,
+                    math.ceil(global_batch_size / p_size), pool)
+        if block is None or (aligned is not None and not slot_plan):
+            # Ragged rows that keep to no fields (or to fields too wide
+            # to block): padded buckets, as before there were plans.
+            aligned = None
+            buckets, row_ids = pack_ell_buckets(
+                indptr, indices, values, dim, max_buckets=max_buckets,
+                dtype=dtype)
+        else:
+            buckets, row_ids = [block], [None]
     counts = metrics.group("hostdata.sparse")
     counts.counter("cells", float(indptr[-1]))
+    blocked = [j for j, length in enumerate(slot_plan) if length is not None]
+    counts.counter("blocked_slots", float(len(blocked)))
+    counts.counter("blocked_cells", float(
+        len(blocked) * n if aligned is None
+        else aligned["slot_cells"][blocked].sum()))
     counts.counter("padded_cells",
                    float(sum(b["indices"].size for b in buckets)))
     counts.counter("buckets", float(len(buckets)))
@@ -541,7 +670,12 @@ def prepare_sparse_buckets(
         share = max(1, math.ceil(global_batch_size * n_bucket / (n * p_size)))
         local_bs = min(share, n_local)
         local_bss.append(local_bs)
-    return tuple(data_args), tuple(local_bss)
+    if slot_plan:
+        # Every device's shard the same [width] starts (a few bytes:
+        # under no span, as the unit weights are).
+        data_args.append(jax.device_put(
+            np.tile(starts, p_size), mesh.data_sharding()))
+    return tuple(data_args), tuple(local_bss), slot_plan
 
 
 def train_linear_model_sparse_csr(
@@ -582,13 +716,13 @@ def train_linear_model_sparse_csr(
     n = np.asarray(indptr).size - 1
     if n == 0:
         raise ValueError("training table is empty")
-    data_args, local_bss = prepare_sparse_buckets(
+    data_args, local_bss, slot_plan = prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
         max_buckets=max_buckets, dtype=dtype, seed=seed,
     )
     trainer = _sparse_trainer_bucketed(
         mesh.mesh, loss, tuple(local_bss), DeviceMesh.DATA_AXIS, int(dim),
-        _segsum_backend(),
+        _segsum_backend(), slot_plan,
     )
     return _run_chunked(
         trainer, tuple(data_args), int(dim), jnp.dtype(dtype),

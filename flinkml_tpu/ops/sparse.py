@@ -9,8 +9,11 @@ no-ops in every product below — no masking needed).
 
 This is the Criteo-scale path (BASELINE.json config #5): forward = gather +
 row-sum; gradient = flat ``segment_sum`` scatter-add into the dense model,
-both of which XLA lowers to efficient HBM gathers/scatters without a Pallas
-kernel until profiling says otherwise.
+both of which XLA lowers to HBM gathers/scatters that run one element at
+a time on a TPU (7 ns a cell). A slot whose columns sit in a block of its
+own (:func:`slot_block_plan`) is served by :func:`block_lookup` and
+:func:`block_accumulate` instead: two-level one-hot products on the MXU,
+exact in float32.
 """
 
 from __future__ import annotations
@@ -30,6 +33,64 @@ def ell_matvec(indices, values, w) -> jax.Array:
     cells (index 0, value 0) add exactly 0; a block of zero rows or zero
     width gives zeros. The forward margin of every sparse trainer."""
     return jnp.sum(values * jnp.take(w, indices, axis=0), axis=1)
+
+
+#: Lanes of a vector register: a block's local index is ``128 * hi + lo``.
+LANES = 128
+
+
+def _block_one_hots(local, k: int, dtype):
+    """``local = 128 * hi + lo`` as the one-hot of ``hi`` over a block's
+    ``k`` rows of 128 (an operand of the MXU; None where ``k`` is 1) and
+    the mask of lane ``lo`` (a select on the VPU)."""
+    lanes = jax.nn.one_hot(local % LANES, LANES, dtype=jnp.bool_)
+    if k == 1:
+        return None, lanes
+    return jax.nn.one_hot(local // LANES, k, dtype=dtype), lanes
+
+
+def block_lookup(blocks, local) -> jax.Array:
+    """``blocks[s, local[s, b]]`` for ``blocks [S, R]`` float32, ``R`` a
+    multiple of 128, and ``local [S, B]`` int32: a gather written as a
+    two-level one-hot product, ``onehot(hi) [B, R/128] @ block [R/128,
+    128]`` on the MXU and a select of lane ``lo``, so it does not run
+    one element at a time. ``Precision.HIGHEST`` passes the float32
+    operand through the MXU in bfloat16 pieces that sum back to it, and a
+    0/1 operand is exact in bfloat16: the result is the gather bit for
+    bit (any lower precision rounds it to bfloat16). A block of 128
+    columns needs no product. An index outside ``[0, R)`` reads 0 or some
+    element of its block, so such a cell must carry the value 0 (the zero
+    rows a shard is padded with do)."""
+    s, r = blocks.shape
+    k = r // LANES
+    rows_of, lanes = _block_one_hots(local, k, blocks.dtype)
+    if rows_of is None:
+        rows = blocks[:, None, :]
+    else:
+        rows = jnp.einsum(
+            "sbk,skl->sbl", rows_of, blocks.reshape(s, k, LANES),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=blocks.dtype)
+    return jnp.sum(jnp.where(lanes, rows, 0), axis=-1)
+
+
+def block_accumulate(local, contrib, length: int) -> jax.Array:
+    """``zeros([S, length]).at[s, local[s, b]].add(contrib[s, b])``, the
+    transpose of :func:`block_lookup`: ``onehot(hi)ᵀ [R/128, B] @
+    (onehot(lo) · contrib) [B, 128]``, the products exact, accumulated in
+    float32, the same bits every time. A cell whose index lies outside
+    ``[0, length)`` must contribute 0."""
+    s, _ = local.shape
+    k = length // LANES
+    rows_of, lanes = _block_one_hots(local, k, contrib.dtype)
+    spread = jnp.where(lanes, contrib[..., None], 0)
+    if rows_of is None:
+        return jnp.sum(spread, axis=1)
+    out = jnp.einsum(
+        "sbk,sbl->skl", rows_of, spread,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=contrib.dtype)
+    return out.reshape(s, length)
 
 
 class BatchedCSR:
@@ -432,6 +493,22 @@ def uniform_row_width(indptr: np.ndarray):
     return k
 
 
+def one_width_block(indptr, indices, values, dtype):
+    """Rows of one width (:func:`uniform_row_width`) as the one ELL
+    block ``{"indices": [rows, width] int32, "values": [rows, width]}``:
+    CSR already is that block, so it is two views and no cell is copied.
+    None for ragged rows."""
+    width = uniform_row_width(indptr)
+    if width is None:
+        return None
+    n = indptr.shape[0] - 1
+    return {
+        "indices": np.asarray(indices, np.int32).reshape(n, width),
+        "values": np.asarray(values).astype(dtype, copy=False)
+                    .reshape(n, width),
+    }
+
+
 def fill_ell(bi, bv, row_starts, counts, indices, values) -> None:
     """Vectorized CSR→ELL fill: write each row's ``counts[r]`` cells
     (sourced at ``row_starts[r]``) into the padded blocks ``bi``/``bv``
@@ -462,16 +539,8 @@ def pack_ell_buckets(indptr, indices, values, dim: int,
     realistic skew, vs ``n · max_nnz`` for uniform ELL.
     """
     indptr = np.asarray(indptr, dtype=np.int64)
-    n = indptr.size - 1
-    width = uniform_row_width(indptr)
-    if width is not None:
-        # Every row as wide as the block: CSR already is the one ELL
-        # block, so it is two views and no cell is copied.
-        block = {
-            "indices": np.asarray(indices, np.int32).reshape(n, width),
-            "values": np.asarray(values).astype(dtype, copy=False)
-                        .reshape(n, width),
-        }
+    block = one_width_block(indptr, indices, values, dtype)
+    if block is not None:
         return [block], [None]
     nnz = np.diff(indptr)
     bucket_widths = choose_ell_widths(nnz, max_buckets=max_buckets)
@@ -489,6 +558,186 @@ def pack_ell_buckets(indptr, indices, values, dim: int,
         buckets.append({"indices": bi, "values": bv})
         row_ids.append(rows)
     return buckets, row_ids
+
+
+#: Widest block, in columns, that a slot is looked up in as a block
+#: (:func:`block_lookup`); a slot that spans more goes through the
+#: gather and the scatter-add. The longest block a whole step on a v5e
+#: has run and won with (PERF.md §5, PR 29: 65,536 rows a step, Criteo
+#: laid out field by field, 34 fields of at most 59,697 columns and five
+#: of 138,801 to 193,949): the five blocked, lengths 139,264 to 194,560,
+#: 7.7 ms a step against 8.6 with them gathered and scattered, 36.8
+#: with no plan. One slot alone (lookup and accumulate): 0.16 ms up to
+#: 13,312 columns, 0.22 at 26,624, 0.50 at 65,536, 0.71 at 131,072,
+#: 1.27-1.31 at 262,144, against 1.03 for its gather and scatter-add
+#: whatever its columns.
+BLOCK_MAX_COLUMNS = 194_560
+
+#: The one-hot of one product may have this many elements (a step's rows
+#: x the slots served together x a block's rows of 128; 512 MiB in
+#: float32): a longer block stays general, a larger group is split.
+_BLOCK_ONE_HOT_ELEMENTS = 1 << 27
+
+#: Rows a task of :func:`slot_block_plan` reads, and the rows it folds
+#: into one to give the reduction a long inner loop (39-wide rows reduce
+#: three times slower unfolded; smaller tasks fight over the interpreter
+#: lock).
+_PLAN_TASK_ROWS, _PLAN_FOLD = 1 << 18, 32
+
+
+def _column_ranges(block: np.ndarray):
+    """Lowest and highest entry of each column of ``block [n, width]``."""
+    n, width = block.shape
+    whole = n // _PLAN_FOLD * _PLAN_FOLD
+    lows, highs = [block[whole:]], [block[whole:]]
+    if whole:
+        folded = block[:whole].reshape(whole // _PLAN_FOLD, _PLAN_FOLD * width)
+        lows.append(folded.min(axis=0).reshape(_PLAN_FOLD, width))
+        highs.append(folded.max(axis=0).reshape(_PLAN_FOLD, width))
+    return (np.concatenate(lows).min(axis=0),
+            np.concatenate(highs).max(axis=0))
+
+
+def _block_length(span: int) -> int:
+    """``span`` columns rounded up a short ladder of block lengths (128,
+    256, 512, 1024, then multiples of 1024): what two samples of one
+    table almost surely agree on, and few lengths a program."""
+    k = -(-span // LANES)
+    return LANES * (next_pow2(k) if k <= 8 else -(-k // 8) * 8)
+
+
+def slot_block_plan(indices: np.ndarray, dim: int, step_rows: int, pool):
+    """The block each ELL slot's columns sit in, read off the cells:
+    ``(plan, starts)`` for ``indices [rows, width]``. ``plan`` is the
+    static half, what keys a program: per slot the block's length (a
+    multiple of 128 up the ladder of :func:`_block_length`, at most
+    :data:`BLOCK_MAX_COLUMNS`) or None for a slot whose columns span
+    more, or whose one-hot over a step's ``step_rows`` rows would pass
+    :data:`_BLOCK_ONE_HOT_ELEMENTS`. ``starts [width] int32`` is the
+    runtime half, an operand of the program, in rows of 128 columns:
+    every index of a blocked slot lies in ``[128 * start, 128 * start +
+    length)``, the start the row of the slot's lowest index, or the last
+    that keeps the block inside ``dim`` rounded up to whole rows; 0 for
+    the other slots. So the plan knows only how many rows each slot
+    spans: two tables of one schema share a program wherever their
+    columns lie, until a span crosses a rung of the ladder. Fields laid
+    on ranges of their own (one cell a field) have such blocks; rows
+    hashed over all of a large ``dim`` have none, and theirs is the
+    empty plan ``((), None)``. One chunked pass over the cells (at least
+    one row) on ``pool``'s threads."""
+    rows = indices.shape[0]
+    parts = list(pool.map(
+        lambda lo: _column_ranges(indices[lo:lo + _PLAN_TASK_ROWS]),
+        range(0, rows, _PLAN_TASK_ROWS)))
+    lows = np.min([p[0] for p in parts], axis=0)
+    highs = np.max([p[1] for p in parts], axis=0)
+    padded_dim = -(-dim // LANES) * LANES
+    longest = min(BLOCK_MAX_COLUMNS, padded_dim,
+                  _BLOCK_ONE_HOT_ELEMENTS // step_rows * LANES)
+    plan, starts = [], []
+    for low, high in zip(lows.tolist(), highs.tolist()):
+        first = low // LANES
+        length = _block_length(high + 1 - LANES * first)
+        blocked = low >= 0 and high < dim and length <= longest
+        plan.append(length if blocked else None)
+        starts.append(min(first, (padded_dim - length) // LANES)
+                      if blocked else 0)
+    if not any(plan):
+        return (), None
+    return tuple(plan), np.asarray(starts, np.int32)
+
+
+def block_groups(slot_plan: tuple, step_rows: int):
+    """The blocked slots of a plan, those of one block length together
+    (one product serves them) as far as :data:`_BLOCK_ONE_HOT_ELEMENTS`
+    allows over ``step_rows`` rows: ``[(length, slots)]``."""
+    by_length: dict = {}
+    for slot, length in enumerate(slot_plan):
+        if length is not None:
+            by_length.setdefault(length, []).append(slot)
+    groups = []
+    for length, slots in sorted(by_length.items()):
+        most = max(1, _BLOCK_ONE_HOT_ELEMENTS
+                   // (step_rows * (length // LANES)))
+        groups += [(length, slots[i:i + most])
+                   for i in range(0, len(slots), most)]
+    return groups
+
+
+#: A ragged table is laid one cell a slot (:func:`align_ragged_rows`)
+#: only where that pads it by at most a cell in this many.
+_ALIGN_PAD_SHARE = 8
+
+#: Rows a task of :func:`align_ragged_rows` lays: its temporaries (three
+#: ``int64`` a cell) stay small enough to be reused from task to task,
+#: where at 262,144 rows each is fresh memory and its page faults are
+#: most of the pass (four times slower on eight threads).
+_ALIGN_TASK_ROWS = 1 << 15
+
+
+def align_ragged_rows(indptr, indices, values, dtype, pool):
+    """Ragged rows of a table whose cells keep to fields, as the one ELL
+    block ``{"indices": [rows, width], "values": [rows, width]}`` with
+    field ``f`` in slot ``f`` of every row (and ``"slot_cells"``, the
+    cells each slot holds), or None where the table is not of that kind.
+    A field-blocked table with cells missing (a one-hot encoder that
+    drops a category, a file that leaves zeros out) has rows of several
+    widths, and padded at the rows' ends (:func:`pack_ell_buckets`) a
+    slot would hold a different field from row to row; aligned,
+    :func:`slot_block_plan` finds each slot on its field's block. The fields are read off the widest rows (``width`` cells:
+    slot ``f``'s lowest column is where field ``f`` starts), every cell
+    goes to the field its column falls in, and a missing cell is the
+    field's first column with the value 0, which adds nothing to any
+    product. Not of that kind, and None: padding past a cell in
+    :data:`_ALIGN_PAD_SHARE`, rows that are not ascending, or any row
+    with two cells in one field (rows hashed over ``dim``). One chunked
+    pass on ``pool``'s threads; it takes the place of
+    :func:`pack_ell_buckets`' fill, not a place beside it."""
+    indptr = np.asarray(indptr, np.int64)
+    n, cells = indptr.size - 1, int(indptr[-1])
+    nnz = np.diff(indptr)
+    width = int(nnz.max())
+    if n * width > cells + cells // _ALIGN_PAD_SHARE:
+        return None
+    spans = range(0, n, _ALIGN_TASK_ROWS)
+    across = np.arange(width)
+
+    def lows_of(lo):
+        widest = lo + np.flatnonzero(nnz[lo:lo + _ALIGN_TASK_ROWS] == width)
+        if not widest.size:
+            return np.full(width, np.iinfo(np.int64).max)
+        return indices[indptr[widest][:, None] + across].min(axis=0)
+
+    starts = np.min(list(pool.map(lows_of, spans)), axis=0)
+    if np.any(np.diff(starts) <= 0):
+        return None
+    out_i = np.empty((n, width), np.int32)
+    out_v = np.empty((n, width), dtype)
+    refused = []
+
+    def fill(lo):
+        if refused:
+            return None
+        hi = min(lo + _ALIGN_TASK_ROWS, n)
+        cols = indices[indptr[lo]:indptr[hi]]
+        field = np.searchsorted(starts, cols, side="right") - 1
+        at = np.repeat(np.arange(hi - lo) * width, nnz[lo:hi]) + field
+        # Ascending rows: ``at`` rises from cell to cell unless a cell
+        # shares a field with its neighbour (or lies under the first).
+        if at.size and (field.min() < 0 or np.any(at[1:] <= at[:-1])):
+            refused.append(lo)
+            return None
+        out_i[lo:hi] = starts
+        out_v[lo:hi] = 0
+        out_i[lo:hi].reshape(-1)[at] = cols
+        out_v[lo:hi].reshape(-1)[at] = values[indptr[lo]:indptr[hi]]
+        return np.bincount(field, minlength=width)
+
+    filled = list(pool.map(fill, spans))
+    if refused:
+        return None
+    return {"indices": out_i, "values": out_v,
+            "slot_cells": np.sum(filled, axis=0)}
 
 
 # Chunk width of the two-level running sum in chunked_run_totals. Within-

@@ -42,6 +42,12 @@ _STAGE_BUFFERS = 2
 _GATHER_THREADS = 8
 
 
+def gather_pool() -> ThreadPoolExecutor:
+    """A pool of as many threads as a staging round's gather is split
+    over, for a fit's other chunked passes over its host columns."""
+    return ThreadPoolExecutor(_GATHER_THREADS)
+
+
 class DeviceMesh:
     """A named device mesh plus sharding conveniences.
 
