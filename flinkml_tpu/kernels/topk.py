@@ -11,11 +11,14 @@ elements and ``argmax`` takes the FIRST maximum, so values AND indices
 are bit-identical to ``lax.top_k`` (both break ties toward the lower
 index).
 
-The gate (:mod:`flinkml_tpu.kernels._gate`, site ``topk``) keeps XLA the
-default; the bench's ``pallas[_cpu]`` stage measures the ratio and the
-device re-tune decides. Callers thread the resolved backend into their
-``jax.jit`` static args (``knn._knn_vote``) so a gate flip re-keys the
-program instead of silently reusing the old one.
+The KNN search calls :func:`pallas_top_k` itself, for the tiles of its
+distance matrix: on a v5e it is that search's fastest exact top-k (a
+call of 10,000 queries against 2,025,000 rows: 1.589 s with it, 1.791
+with ``lax.top_k`` over the same tiles; PERF.md §5, PR 30). LSH goes
+through :func:`top_k` and the gate (:mod:`flinkml_tpu.kernels._gate`,
+site ``topk``), which keeps XLA the default there; a caller that does
+threads the resolved backend into its own compile key, so a gate flip
+re-keys the program instead of silently reusing the old one.
 """
 
 from __future__ import annotations
@@ -66,14 +69,15 @@ def _topk_body(x_ref, val_ref, idx_ref, *, k: int):
     for j in range(k):
         cand = jnp.where(taken, neg_inf, work)
         m = jnp.max(cand, axis=1)
-        a = jnp.argmax(cand, axis=1).astype(jnp.int32)
+        # lax.argmax with its index dtype said: under x64 jnp.argmax asks
+        # for int64 indices, which Mosaic does not lower.
+        a = jax.lax.argmax(cand, 1, jnp.int32)
         # All untaken entries at -inf: the masked and unmasked values
         # tie, so argmax must not land on an already-taken column —
         # take the first UNTAKEN index instead.
         # f32 operand: Mosaic's argmax lowers float32 only (not bool).
-        first_untaken = jnp.argmax(
-            jnp.where(taken, 0.0, 1.0).astype(jnp.float32), axis=1
-        ).astype(jnp.int32)
+        first_untaken = jax.lax.argmax(
+            jnp.where(taken, jnp.float32(0.0), jnp.float32(1.0)), 1, jnp.int32)
         a = jnp.where(jnp.isneginf(m), first_untaken, a)
         val_ref[:, j] = m
         idx_ref[:, j] = a
@@ -84,6 +88,7 @@ def pallas_top_k(x, k: int, *, interpret: Optional[bool] = None) -> Tuple:
     """``(values, indices)`` of the k largest entries of each row of
     ``x`` — bit-compatible with ``jax.lax.top_k(x, k)`` (descending
     values, ties toward the lower index, int32 indices)."""
+    import contextlib
     import functools
 
     import jax
@@ -103,20 +108,27 @@ def pallas_top_k(x, k: int, *, interpret: Optional[bool] = None) -> Tuple:
             [x2, jnp.full((pad, n), -jnp.inf, x2.dtype)]
         )
     grid = (x2.shape[0] // ROW_TILE,)
-    vals, idxs = pl.pallas_call(
-        functools.partial(_topk_body, k=k),
-        grid=grid,
-        in_specs=[pl.BlockSpec((ROW_TILE, n), lambda i: (i, 0))],
-        out_specs=(
-            pl.BlockSpec((ROW_TILE, k), lambda i: (i, 0)),
-            pl.BlockSpec((ROW_TILE, k), lambda i: (i, 0)),
-        ),
-        out_shape=(
-            _gate.out_struct((x2.shape[0], k), x2.dtype, x2),
-            _gate.out_struct((x2.shape[0], k), jnp.int32, x2),
-        ),
-        interpret=interpret,
-    )(x2)
+    # For Mosaic, traced in 32-bit mode whatever the caller's: under x64
+    # the block index maps' literal 0 and the body's Python constants come
+    # out 64 bits wide, and Mosaic does not lower those (a float64 block
+    # aborts the process in its layout pass). Its operand is float32
+    # (unsupported_reason); the interpreter keeps the caller's mode, and
+    # with it the float64 operands it alone ranks.
+    with contextlib.nullcontext() if interpret else jax.enable_x64(False):
+        vals, idxs = pl.pallas_call(
+            functools.partial(_topk_body, k=k),
+            grid=grid,
+            in_specs=[pl.BlockSpec((ROW_TILE, n), lambda i: (i, 0))],
+            out_specs=(
+                pl.BlockSpec((ROW_TILE, k), lambda i: (i, 0)),
+                pl.BlockSpec((ROW_TILE, k), lambda i: (i, 0)),
+            ),
+            out_shape=(
+                _gate.out_struct((x2.shape[0], k), x2.dtype, x2),
+                _gate.out_struct((x2.shape[0], k), jnp.int32, x2),
+            ),
+            interpret=interpret,
+        )(x2)
     if pad:
         vals, idxs = vals[:rows], idxs[:rows]
     if squeeze:

@@ -1,0 +1,102 @@
+"""Programs of the benchmark's main paths compiled FOR the chip, at their
+real sizes, without the chip: the TPU's compiler is installed here and
+compiles for a described v5e (``on-chip-measurement`` guide, section 2).
+What interpret mode cannot show fails here: a Mosaic kernel the chip's
+compiler refuses, a program that does not fit the device.
+
+Nothing runs, so no result and no time is read. The topology is
+described inside a fixture (never while a module is imported), and every
+such test lives in this one file: only one process may hold the TPU's
+library.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def test_knn_search_at_the_cells_size(one_chip, no_compile_cache, monkeypatch):
+    """``knn-mnist8m.transform``'s one program: 10,000 queries against
+    2,025,000 x 784 resident rows, k 5, with the Pallas top-k compiled by
+    Mosaic (not interpreted), inside a v5e's 16 GB."""
+    from jax.experimental.layout import Format, Layout
+
+    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.models import knn
+
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    rows, dim, queries, k = 2_025_000, 784, 10_000, 5
+
+    def on_chip(shape, dtype):
+        # As a v5e holds them (read off placed arrays there, PR 30): a
+        # float32 [n, 784] array lies with its ROWS along the lanes.
+        rows_minor = Layout(major_to_minor=tuple(reversed(range(len(shape)))))
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=Format(rows_minor, one_chip))
+
+    # Under the suite's x64, as a user with ``jax_enable_x64`` on calls it:
+    # the program is traced in 32-bit mode all the same. One 64-bit block
+    # inside the kernel and Mosaic ABORTS the process, so the traced
+    # program is read first and a failure here is an assertion.
+    with jax.enable_x64(True):
+        traced = knn._knn_vote.trace(
+            on_chip((queries, dim), jnp.float32), on_chip((rows, dim), jnp.float32),
+            on_chip((rows,), jnp.float32), on_chip((rows,), jnp.int32),
+            k=k, num_classes=10, chunk=knn._chunk_rows(queries, knn.KnnModel.CHUNK),
+            tile=knn._tile_rows(rows, k), precision=knn.PRODUCT_PRECISION)
+        wide = [line.strip() for line in str(traced.jaxpr).splitlines()
+                if re.search(r"\b[fiu]64\[", line)]
+        assert wide == []
+        compiled = traced.lower().compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 0.25 * 16e9 < held < 0.5 * 16e9
+    # neither the [queries, rows] matrix (81 GB) nor a relaid copy of the
+    # train set (7.3 GB) is asked for: a tile's distances and little else
+    assert memory.temp_size_in_bytes < 1e9
+
+
+@pytest.mark.parametrize("x64", [False, True])
+def test_top_k_kernel_compiles_whatever_x64_says(one_chip, no_compile_cache, x64):
+    """``kernels.topk.pallas_top_k`` by itself (LSH's route to it, through
+    the gate): its ``pallas_call`` holds no 64-bit value under x64 (Mosaic
+    refuses an int64 block index and aborts on a float64 block), so it
+    compiles in both modes."""
+    from flinkml_tpu.kernels import topk
+
+    with jax.enable_x64(x64):
+        traced = jax.jit(lambda x: topk.pallas_top_k(x, 5, interpret=False)).trace(
+            jax.ShapeDtypeStruct((64, 4096), jnp.float32, sharding=one_chip))
+        assert not re.search(r"\b[fiu]64\[", str(traced.jaxpr))
+        compiled = traced.lower().compile()
+    assert "tpu_custom_call" in compiled.as_text()
