@@ -27,6 +27,10 @@ tolerance under bf16), and loud refusal on unsupported dtypes/shapes
 (:class:`KernelUnsupportedError` on explicit requests, warn-once XLA
 fallback for table-chosen backends).
 
+Outside the gate, :mod:`flinkml_tpu.kernels.knn_search` is the KNN
+search's product and ranking in one kernel; ``models.knn.nearest`` takes
+it wherever it applies (a TPU, float32 rows, ``k`` ≤ 128).
+
 See ``docs/development/kernels.md`` for the supported-shape tables,
 the equivalence-test recipe, and the device re-tune runbook.
 """

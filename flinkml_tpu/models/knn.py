@@ -15,16 +15,19 @@ and ``KnnModel.java:51-197``, rebuilt TPU-first:
     ``transform`` uploads its queries only.
   - Prediction: the reference broadcasts the whole model and, per query
     row, runs gemv-style distances + a top-k priority queue
-    (``KnnModel.java:72-197``). Here ONE program a call: for each chunk
-    of queries, tiles of train rows; a tile's squared distances are one
-    [chunk, d] @ [d, tile] MXU product at float32 accuracy
-    (``Precision.HIGHEST``: the default, one bfloat16 pass, does not
-    rank near neighbours) in the ‖x‖² - 2xy + ‖y‖² expansion; the tile's
-    exact ``k`` best (on a TPU the Pallas masked-pass top-k of
-    :mod:`flinkml_tpu.kernels`, no sort; ``lax.top_k``, bit for bit the
-    same, wherever Mosaic does not compile it) join a running ``k``
-    best; then a one-hot vote. The [queries, train rows] matrix never
-    exists. The program is traced in 32-bit mode whatever
+    (``KnnModel.java:72-197``). Here ONE program a call: blocks of
+    queries against blocks of train rows; a block's squared distances
+    are one MXU product at float32 accuracy (``Precision.HIGHEST``: the
+    default, one bfloat16 pass, does not rank near neighbours) in the
+    ‖x‖² - 2xy + ‖y‖² expansion, and its exact ``k`` best join a running
+    ``k`` best; then a one-hot vote. On a TPU the product and the
+    ranking are one Pallas kernel
+    (:mod:`flinkml_tpu.kernels.knn_search`): a block of distances is
+    ranked in fast memory against the running ``k``-th best and never
+    reaches HBM. Wherever that kernel does not apply (another backend,
+    ``k`` over 128, rows that are not float32 or too wide) the tiled XLA
+    search below runs, the same answer. The [queries, train rows] matrix
+    never exists. The program is traced in 32-bit mode whatever
     ``jax_enable_x64`` says: every operand is float32 or int32.
   - Exact: neighbours are the ``k`` smallest by (distance, train row),
     ties to the LOWER row; the vote's ties go to the smaller class.
@@ -50,6 +53,7 @@ from flinkml_tpu.common_params import (
     HasPredictionCol,
 )
 from flinkml_tpu.kernels import _gate
+from flinkml_tpu.kernels import knn_search
 from flinkml_tpu.kernels import topk as topk_kernel
 from flinkml_tpu.models._data import features_matrix
 from flinkml_tpu.parallel.mesh import DeviceMesh
@@ -57,8 +61,8 @@ from flinkml_tpu.table import Table
 from flinkml_tpu.utils.metrics import metrics
 from flinkml_tpu.utils.profiling import span
 
-#: Train rows a tile of the search ranks at once: the [chunk, tile]
-#: float32 distances are the program's one large temporary. Read on a
+#: Train rows a tile of the tiled XLA search ranks at once: the [chunk,
+#: tile] float32 distances are that program's one large temporary. Read on a
 #: v5e at 2,025,000 x 784 (PERF.md §5, PR 30): 16,384 -> 1.668 s a call
 #: of 10,000 queries, 32,768 -> 1.589, 65,536 -> 1.554 (whose 2 MB blocks
 #: leave the top-k kernel little of its fast memory).
@@ -164,8 +168,9 @@ class KnnModel(_KnnParams, Model):
         chunk, tile = _chunk_rows(x.shape[0], self.CHUNK), _tile_rows(n_train, k)
         with span("knn.search"):
             with span("knn.dispatch"):
+                queries = jnp.asarray(x, dtype=jnp.float32)
                 ids = _knn_vote(
-                    jnp.asarray(x, dtype=jnp.float32), model.features,
+                    queries, model.features,
                     model.norms, model.class_ids, k=k,
                     num_classes=len(model.classes), chunk=chunk, tile=tile,
                     precision=PRODUCT_PRECISION)
@@ -174,8 +179,11 @@ class KnnModel(_KnnParams, Model):
             ids.block_until_ready()
         group = metrics.group("knn")
         group.counter("query_rows", float(x.shape[0]))
-        group.counter("train_tiles",
-                      float(-(-x.shape[0] // chunk) * -(-n_train // tile)))
+        # Which search the program held: the kernel's, or the tiled one's.
+        fused = _ranks_in_the_product(queries, model.features, k)
+        group.counter("fused_query_rows", float(x.shape[0]) if fused else 0.0)
+        group.counter("train_tiles", 0.0 if fused else float(
+            -(-x.shape[0] // chunk) * -(-n_train // tile)))
         with span("knn.readback"):
             pred = model.classes[np.asarray(ids)]
         return (table.with_column(self.get(_KnnParams.PREDICTION_COL), pred),)
@@ -198,8 +206,7 @@ def _chunk_rows(n_queries: int, most: int) -> int:
     """Query rows a chunk: the call's rows in the fewest equal chunks of
     at most ``most``, up to a multiple of 8 (10,000 rows are three
     chunks of 3,336, not two of 4,096 and one of 1,808 padded to it)."""
-    chunks = max(1, -(-n_queries // most))
-    return max(8, -(-n_queries // (8 * chunks)) * 8)
+    return knn_search.query_block_rows(n_queries, most)
 
 
 def _tile_rows(n_train: int, k: int) -> int:
@@ -232,11 +239,25 @@ def _tile_top_k(d2, k: int):
     return -neg, at
 
 
+def _ranks_in_the_product(queries, train_x, k: int) -> bool:
+    """Whether :func:`nearest` takes the fused kernel for these operands:
+    on a TPU (elsewhere it would run interpreted, a Python loop over
+    blocks), and where the kernel takes their type, width and ``k``."""
+    return (not _gate.interpret_mode()
+            and knn_search.unsupported_reason(queries, train_x, k) is None)
+
+
 def nearest(queries, train_x, train_sq, k: int, *, chunk: int, tile: int,
             precision):
     """``(d2, rows)``, both [queries, k]: each query's ``k`` nearest rows
     of ``train_x`` ([n, d], ``train_sq`` its rows' squared norms) by
     (squared distance, row), and those distances.
+
+    Where it applies (:func:`_ranks_in_the_product`), one kernel forms
+    the distances block by block and ranks each block where the product
+    leaves it (:func:`flinkml_tpu.kernels.knn_search.fused_nearest`,
+    which sizes its own blocks: ``chunk`` and ``tile`` size the search
+    below). Everywhere else, and as what that kernel is tested against:
 
     Chunks of ``chunk`` queries (a multiple of 8); for each, tiles of
     ``tile`` train rows in ascending order. The last tile steps back to
@@ -251,6 +272,9 @@ def nearest(queries, train_x, train_sq, k: int, *, chunk: int, tile: int,
     the transpose is free and a tile a run of lanes; cut from the rows
     the compiler first relaid the whole train set, 7.7 GB of scratch a
     call beside the 6.4 GB resident (PERF.md §5, PR 30)."""
+    if _ranks_in_the_product(queries, train_x, k):
+        return knn_search.fused_nearest(queries, train_x, train_sq, k,
+                                        precision=precision)
     n_queries, dim = queries.shape
     n_train = train_x.shape[0]
     n_tiles = -(-n_train // tile)

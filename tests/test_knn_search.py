@@ -1,7 +1,10 @@
 """The KNN search (PR 30) against ``benchmark/reference/knn.py`` (NumPy
 float64, direct sums), index for index: tiles that do not divide the
 rows, planted exact ties, products rounded to bfloat16 shown to differ,
-four row shares merged into the whole; and the model data's one upload."""
+four row shares merged into the whole; and the model data's one upload.
+Both searches (PR 31): the tiled XLA one, which this backend runs, and
+the fused product-and-ranking kernel a TPU runs, interpreted here at
+blocks small enough to cut these searches into many."""
 
 import functools
 
@@ -11,6 +14,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import knn as reference
+from flinkml_tpu.kernels import knn_search
 from flinkml_tpu.models import Knn, KnnModel, knn
 from flinkml_tpu.table import Table
 from flinkml_tpu.utils.metrics import metrics
@@ -44,10 +48,25 @@ def _assert_same_neighbours(got, want, want_d2, k):
     return stable.mean(), ordered.mean()
 
 
-def _search(queries, train, k, tile, chunk=64):
+def _tiled(queries, train, k, tile, chunk=64, precision=knn.PRODUCT_PRECISION):
+    """The tiled XLA search, as every backend but a TPU runs it."""
     x = jnp.asarray(train, jnp.float32)
-    run = jax.jit(functools.partial(knn.nearest, k=k, chunk=chunk, tile=tile,
-                                    precision=knn.PRODUCT_PRECISION))
+    run = jax.jit(functools.partial(knn.nearest, k=k, chunk=chunk,
+                                    tile=min(tile, train.shape[0]),
+                                    precision=precision))
+    d2, rows = run(jnp.asarray(queries, jnp.float32), x, jnp.sum(x * x, axis=-1))
+    return np.asarray(d2), np.asarray(rows)
+
+
+def _fused(queries, train, k, tile, chunk=24, precision=knn.PRODUCT_PRECISION):
+    """The fused kernel, interpreted: train blocks of ``tile`` rows up to
+    whole lanes, query blocks of at most ``chunk`` rows (70 queries are
+    three blocks of 24: the last one is padded)."""
+    x = jnp.asarray(train, jnp.float32)
+    run = jax.jit(functools.partial(
+        knn_search.fused_nearest, k=k, precision=precision, query_block=chunk,
+        train_block=-(-tile // knn_search.LANES) * knn_search.LANES,
+        interpret=True))
     d2, rows = run(jnp.asarray(queries, jnp.float32), x, jnp.sum(x * x, axis=-1))
     return np.asarray(d2), np.asarray(rows)
 
@@ -56,13 +75,22 @@ SHAPES = [  # rows, dim, k, tile: the tile never divides the rows
     (1000, 16, 5, 384), (777, 33, 1, 256), (300, 8, 7, 300),
     (513, 20, 5, 128), (2050, 12, 130, 512), (90, 5, 5, 128),
 ]
+# The kernel's: k 1, 5 and 8; a partial last block; fewer rows than one
+# block (90, 100); query rows that do not fill their blocks.
+FUSED_SHAPES = [
+    (1000, 16, 5, 384), (777, 33, 1, 256), (300, 8, 8, 300),
+    (513, 20, 5, 128), (2050, 12, 8, 512), (90, 5, 5, 128), (100, 7, 1, 256),
+]
+BOTH = ([pytest.param(_tiled, *shape, id="tiled-%d-%d-%d-%d" % shape)
+         for shape in SHAPES]
+        + [pytest.param(_fused, *shape, id="fused-%d-%d-%d-%d" % shape)
+           for shape in FUSED_SHAPES])
 
 
-@pytest.mark.parametrize("rows,dim,k,tile", SHAPES)
-def test_neighbours_equal_the_float64_reference(rng, rows, dim, k, tile):
+@pytest.mark.parametrize("search,rows,dim,k,tile", BOTH)
+def test_neighbours_equal_the_float64_reference(rng, search, rows, dim, k, tile):
     train, queries = _levels(rng, rows, dim), _levels(rng, 70, dim)
-    tile = min(tile, rows)
-    d2, got = _search(queries, train, k, tile)
+    d2, got = search(queries, train, k, tile)
     want, want_d2 = reference.k_nearest(queries, train, k)
     stable, ordered = _assert_same_neighbours(got, want, want_d2, k)
     assert stable > 0.9 and (ordered > 0.9 or k > 7)
@@ -80,27 +108,27 @@ def test_the_kernel_and_the_sort_rank_a_tile_alike(rng, monkeypatch):
     train = _small_integers(rng, 700, 6)
     train[400:] = train[:300]
     queries = _small_integers(rng, 40, 6)
-    sorted_d2, sorted_rows = _search(queries, train, 5, 256)
+    sorted_d2, sorted_rows = _tiled(queries, train, 5, 256)
 
     def by_kernel(d2, k):
         neg, at = topk.pallas_top_k(-d2, k, interpret=True)
         return -neg, at
 
     monkeypatch.setattr(knn, "_tile_top_k", by_kernel)
-    kernel_d2, kernel_rows = _search(queries, train, 5, 256)
+    kernel_d2, kernel_rows = _tiled(queries, train, 5, 256)
     np.testing.assert_array_equal(kernel_rows, sorted_rows)
     np.testing.assert_array_equal(kernel_d2, sorted_d2)
 
 
-@pytest.mark.parametrize("rows,dim,k,tile", SHAPES)
-def test_exact_ties_go_to_the_lower_row(rng, rows, dim, k, tile):
+@pytest.mark.parametrize("search,rows,dim,k,tile", BOTH)
+def test_exact_ties_go_to_the_lower_row(rng, search, rows, dim, k, tile):
     """Integer rows (every product exact, ties at most k-th places) with
     a third of the rows planted again further down: the duplicate's
-    distance is the original's, bit for bit, in another tile."""
+    distance is the original's, bit for bit, in another tile or block."""
     train = _small_integers(rng, rows, dim)
     train[2 * rows // 3:] = train[:rows - 2 * rows // 3]
     queries = _small_integers(rng, 70, dim)
-    d2, got = _search(queries, train, k, min(tile, rows))
+    d2, got = search(queries, train, k, tile)
     want, want_d2 = reference.k_nearest(queries, train, k)
     assert (want_d2[:, k - 1] == want_d2[:, k]).mean() > 0.3  # ties at the edge
     np.testing.assert_array_equal(got, want[:, :k])
@@ -114,26 +142,104 @@ def _near_one_image(rng, rows, dim, base):
             / 255).astype(np.float32)
 
 
+@pytest.mark.parametrize("search", [_tiled, _fused])
 @pytest.mark.parametrize("rows,dim,k,tile", [
     (2000, 64, 5, 384), (1501, 96, 1, 256), (2500, 48, 7, 1024)])
-def test_products_in_bfloat16_fail_the_same_comparison(rng, rows, dim, k, tile):
+def test_products_in_bfloat16_fail_the_same_comparison(rng, search, rows, dim, k, tile):
     """What one bfloat16 pass of the MXU computes: both operands rounded
     to bfloat16, float32 sums. The neighbours then differ from the
     reference's for many queries the reference calls stable, on rows the
-    float32 search ranks as the reference does."""
+    float32 search ranks as the reference does. The rounded operands go
+    through at ``Precision.DEFAULT``, the static argument the benchmark's
+    control gives (on this backend a product's precision changes
+    nothing: the rounding is what fails)."""
     base = rng.integers(0, 256, dim)
     train = _near_one_image(rng, rows, dim, base)
     queries = _near_one_image(rng, 70, dim, base)
-    _, sound = _search(queries, train, k, min(tile, rows))
+    _, sound = search(queries, train, k, tile)
     low = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
     assert not np.array_equal(low(train), train)
     want, want_d2 = reference.k_nearest(queries, train, k)
-    _, got = _search(low(queries), low(train), k, min(tile, rows))
+    _, got = search(low(queries), low(train), k, tile,
+                    precision=jax.lax.Precision.DEFAULT)
     stable, _ = _assert_same_neighbours(sound, want, want_d2, k)
     assert stable > 0.5
     stable = ~reference.unstable(want_d2, k, TOL)
     wrong = (np.sort(got[stable], axis=1) != np.sort(want[stable, :k], axis=1)).any(axis=1)
     assert wrong.sum() >= 3        # the comparison allows none
+
+
+def _centred(rng, rows, dim, order):
+    """Train rows around one point, and queries at it: rows in no order,
+    nearest first (after the first block no row enters: the screen alone
+    runs) or farthest first (every block replaces all ``k``)."""
+    centre = rng.integers(100, 156, dim)
+    step = rng.permutation(rows) if order == "none" else np.arange(rows)
+    if order == "farthest_first":
+        step = step[::-1]
+    # Row i lies 4 + step[i] / 2 levels off the centre in one pixel and a
+    # little in the others: its distance grows with step[i], by more than
+    # float32 rounding moves it.
+    train = np.tile(centre, (rows, 1)).astype(np.float64)
+    train[:, 0] += step * 0.5 + 4.0
+    train[:, 1:] += rng.integers(0, 2, (rows, dim - 1)) * 1e-3
+    queries = centre + rng.integers(0, 2, (70, dim)) * 1e-3
+    return (train / 255).astype(np.float32), (queries / 255).astype(np.float32)
+
+
+@pytest.mark.parametrize("k", [1, 5, 8])
+@pytest.mark.parametrize("order", ["nearest_first", "farthest_first", "none"])
+def test_the_fused_search_in_any_order_of_the_stream(rng, order, k):
+    """The kernel against the tiled search, index for index and distance
+    for distance, and both against the reference: 700 rows in blocks of
+    128 (the last one of 60), where no later block holds an entrant, and
+    where every block holds ``k``."""
+    train, queries = _centred(rng, 700, 9, order)
+    d2, got = _fused(queries, train, k, 128)
+    tiled_d2, tiled = _tiled(queries, train, k, 256)
+    np.testing.assert_array_equal(got, tiled)
+    np.testing.assert_array_equal(d2, tiled_d2)
+    want, want_d2 = reference.k_nearest(queries, train, k)
+    _assert_same_neighbours(got, want, want_d2, k)
+    if order != "none":
+        first = np.arange(k) if order == "nearest_first" else 699 - np.arange(k)
+        stable = ~reference.unstable(want_d2, k, TOL)
+        assert stable.mean() > 0.9
+        np.testing.assert_array_equal(np.sort(got[stable], axis=1),
+                                      np.tile(np.sort(first), (stable.sum(), 1)))
+
+
+@pytest.mark.parametrize("k", [1, 5, 8])
+def test_all_equal_distances_keep_the_first_rows(k):
+    """Every row the same: every distance ties, and the ``k`` lowest rows
+    are the answer, block after block (300 rows in blocks of 128)."""
+    train = np.full((300, 6), 0.25, np.float32)
+    queries = np.full((20, 6), 0.75, np.float32)
+    for search in (_tiled, _fused):
+        d2, got = search(queries, train, k, 128)
+        np.testing.assert_array_equal(got, np.tile(np.arange(k), (20, 1)))
+        np.testing.assert_array_equal(d2, np.full((20, k), 1.5, np.float32))
+
+
+def test_where_the_fused_kernel_applies():
+    """What ``nearest`` can see decides: float32 operands, ``k`` within one
+    vreg of running best, rows that fit fast memory and that the chip
+    holds along its lanes; and never on a backend that would interpret
+    the kernel (this one)."""
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    assert knn_search.unsupported_reason(f32(10, 784), f32(99, 784), 5) is None
+    assert knn_search.unsupported_reason(f32(10, 784), f32(99, 784), 128) is None
+    for queries, train, k, why in [
+            (f32(10, 784), f32(99, 784), 129, "k=129"),
+            (jax.ShapeDtypeStruct((10, 784), jnp.float64), f32(99, 784), 5, "float64"),
+            (f32(10, 784), jax.ShapeDtypeStruct((99, 784), jnp.bfloat16), 5, "bfloat16"),
+            (f32(10, 2000), f32(99, 2000), 5, "dim=2000"),
+            (f32(10, 256), f32(99, 256), 5, "dim=256")]:
+        assert why in knn_search.unsupported_reason(queries, train, k)
+    assert not knn._ranks_in_the_product(f32(10, 784), f32(99, 784), 5)
+    assert knn_search.query_block_rows(10_000) == 1000
+    assert knn_search.query_block_rows(5) == 8
+    assert knn_search.query_block_rows(70, 24) == 24
 
 
 @pytest.mark.parametrize("maker", [_levels, _small_integers])
@@ -144,7 +250,7 @@ def test_four_shares_merged_are_the_whole(rng, maker):
     rows, dim, k = 1203, 16, 5
     train, queries = maker(rng, rows, dim), maker(rng, 50, dim)
     bounds = np.linspace(0, rows, 5).astype(int)
-    found = [_search(queries, train[lo:hi], k, 128)
+    found = [_tiled(queries, train[lo:hi], k, 128)
              for lo, hi in zip(bounds[:-1], bounds[1:])]
     merged, merged_d2 = reference.merge_shares(
         [r + lo for (_, r), lo in zip(found, bounds[:-1])],
@@ -240,6 +346,8 @@ def test_second_transform_uploads_no_model_and_compiles_nothing(rng, monkeypatch
     assert second["model_uploads"] == first["model_uploads"]
     assert second["query_rows"] - first["query_rows"] == 64
     assert second["train_tiles"] - first["train_tiles"] == 1
+    # this backend ran the tiled search: the count exists, and stands still
+    assert second["fused_query_rows"] == first["fused_query_rows"]
     assert len(uniques) == 1 and lowered == []
     spans = metrics.group("span").snapshot()["counters"]
     moved = lambda name: spans[name] - spans_before.get(name, 0)
@@ -252,6 +360,70 @@ def test_second_transform_uploads_no_model_and_compiles_nothing(rng, monkeypatch
     model.set_model_data(Table({"features": train[:100], "labels": labels[:100]}))
     model.transform(Table({"features": queries}))
     assert group.snapshot()["counters"]["model_uploads"] == second["model_uploads"] + 1
+
+
+def test_transform_counts_the_rows_the_kernel_searched(rng, monkeypatch):
+    """``KnnModel.transform`` as a TPU runs it, the kernel interpreted: the
+    predictions are the reference's vote, and ``knn.fused_query_rows``
+    moves with ``knn.query_rows`` (``knn.fused_search_share`` reads 1.0)."""
+    from flinkml_tpu.kernels import _gate
+
+    train, queries = _levels(rng, 430, 13), _levels(rng, 52, 13)   # shapes of
+    labels = rng.integers(0, 3, 430).astype(np.float64)   # this test alone
+    table, asked = Table({"features": train, "label": labels}), Table({"features": queries})
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(knn_search, "fused_nearest", functools.partial(
+        knn_search.fused_nearest, interpret=True, query_block=16, train_block=128))
+    before = metrics.group("knn").snapshot()["counters"]
+    got = Knn().set_k(5).fit(table).transform(asked)[0]["prediction"]
+    after = metrics.group("knn").snapshot()["counters"]
+    assert after["fused_query_rows"] - before["fused_query_rows"] == 52
+    assert after["query_rows"] - before["query_rows"] == 52
+    assert after["train_tiles"] == before["train_tiles"]
+    rows, d2 = reference.k_nearest(queries, train, 5)
+    stable = ~reference.unstable(d2, 5, TOL)
+    assert stable.mean() > 0.9
+    np.testing.assert_array_equal(got[stable], reference.vote(labels, rows, 5)[stable])
+
+
+def test_the_fused_share_metric_and_its_entry():
+    """``knn.fused_search_share`` as ``BENCHMARK.json`` has it: within the
+    file's limits of form, listing cells that exist, read by
+    ``counter_ratio`` as 1.0 where every row went through the kernel, 0.0
+    where none did, nothing where the program has no such count."""
+    import json
+    import os
+    import re
+
+    from benchmark.readers import counter_ratio
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = bench["per_layer"][-1]
+    assert entry == {
+        "name": "knn.fused_search_share", "unit": "rows/row", "better": "higher",
+        "source": "program_counter", "layer": "KNN search",
+        "moves": "transform_rows_per_s", "workloads": ["knn-mnist8m.transform"]}
+    assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", entry["name"])
+    assert re.fullmatch(r"[A-Za-z0-9_/%.\-]{1,16}", entry["unit"])
+    for line in (entry["layer"], entry["moves"], entry["source"]):
+        assert 1 <= len(line) <= 200 and line.isascii() and line.isprintable()
+    cells = {w["name"] for w in bench["workloads"]}
+    assert set(entry["workloads"]) <= cells
+    rate = next(m for m in bench["end_to_end"] if m["name"] == entry["moves"])
+    assert set(entry["workloads"]) <= set(rate["workloads"])
+    assert entry["layer"] in {m["layer"] for m in bench["per_layer"][:-1]}
+    assert [m["name"] for m in bench["per_layer"]].count(entry["name"]) == 1
+    assert len(json.dumps(bench)) < 64 * 1024
+    with open(os.path.join(root, "benchmark", "metrics", entry["name"] + ".json")) as f:
+        how = json.load(f)
+    assert how["reader"] == "counter_ratio" and set(how) == {"what", "reader", "params"}
+    obs = lambda counters: {"counters": counters, "setup_counters": {}, "units": {"calls": 7}}
+    read = lambda counters: counter_ratio.read(how["params"], obs(counters))
+    assert read({"knn.fused_query_rows": 70_000.0, "knn.query_rows": 70_000.0}) == 1.0
+    assert read({"knn.fused_query_rows": 0.0, "knn.query_rows": 70_000.0}) == 0.0
+    assert read({"knn.query_rows": 70_000.0}) is None          # the parent's program
 
 
 def test_save_load_and_model_data_round_trip(tmp_path, rng):
