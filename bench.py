@@ -376,20 +376,20 @@ def _kmeans_stage(n, dim, k, iters) -> float:
     config #2)."""
     _setup_jax_cache()
     import jax.numpy as jnp
-    from flinkml_tpu.models.kmeans import _kmeans_trainer, prepare_kmeans_data
+    from flinkml_tpu.models.kmeans import _kmeans_trainer, _place_rows
     from flinkml_tpu.parallel import DeviceMesh
     rng = np.random.default_rng(0)
     x = rng.normal(size=(n, dim)).astype(np.float32)
     mesh = DeviceMesh()
-    # Same pad/mask/shard + kernel gate as the product fit path.
-    xd, wd, _ = prepare_kmeans_data(x, mesh)
+    # The product fit path's own placement (rows, norms, mask) and program.
+    placed = _place_rows(x, mesh)
     cent0 = jnp.asarray(x[rng.choice(n, size=k, replace=False)])
     trainer = _kmeans_trainer(mesh.mesh, k, DeviceMesh.DATA_AXIS)
     _log("kmeans: compiling + warm-up dispatch ...")
-    np.asarray(trainer(xd, wd, cent0, jnp.asarray(3, jnp.int32)))
+    np.asarray(trainer(*placed, cent0, jnp.asarray(3, jnp.int32)))
     _log("kmeans: measuring ...")
     start = time.perf_counter()
-    np.asarray(trainer(xd, wd, cent0, jnp.asarray(iters, jnp.int32)))
+    np.asarray(trainer(*placed, cent0, jnp.asarray(iters, jnp.int32)))
     elapsed = time.perf_counter() - start
     return n * iters / elapsed
 

@@ -17,12 +17,24 @@ Capability parity with ``flink-ml-lib/.../clustering/kmeans/KMeans.java:79-335``
     tol-based stop for KMeans.
   - Empty clusters keep their previous centroid (the reference's keyed
     reduce simply never emits for an empty cluster, leaving it unchanged).
+  - The reference's points stay cached across rounds (ListState); here
+    the in-RAM fit's rows stay on the mesh across FITS: the features
+    column is placed as the table holds it (a float32 column is not
+    widened, padded or copied on the host) through
+    :meth:`DeviceMesh.shard_rows`, and kept with the ``Table``
+    (:func:`_rows_on_mesh`), so Lloyd restarted from another seed on the
+    same table uploads its ``[k, d]`` start and nothing else.
+  - Both products of a round (the distances' and the per-cluster sums')
+    run at a stated precision, float32 accuracy
+    (:data:`PRODUCT_PRECISION`): a TPU's default, one bfloat16 pass,
+    assigns thousands of a large table's rows to another centroid than
+    the reference's float64 does.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +57,15 @@ from flinkml_tpu.ops import blas
 from flinkml_tpu.ops.distance import DistanceMeasure
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.metrics import metrics
+from flinkml_tpu.utils.profiling import span
+
+#: The precision of a round's two products, a static argument of both
+#: trainers: float32 accuracy, the distance expansion's own
+#: (``ops.blas``). A builder's control on the chip passes ``DEFAULT``
+#: (one bfloat16 pass) to show that the benchmark's check tells the two
+#: apart.
+PRODUCT_PRECISION = blas.DISTANCE_PRECISION
 
 
 class _KMeansParams(
@@ -97,25 +118,33 @@ class KMeans(StreamingEstimatorMixin, _KMeansParams, Estimator):
             self._reject_in_ram_checkpointing(
                 "the in-RAM fit runs as one whole-loop device program"
             )
-            x = features_matrix(table, self.get(_KMeansParams.FEATURES_COL))
-            if x.shape[0] < k:
-                raise ValueError(
-                    f"k={k} exceeds number of points {x.shape[0]}"
-                )
-            centroids = train_kmeans(
-                x,
-                k=k,
-                mesh=self.mesh or DeviceMesh(),
-                max_iter=self.get(_KMeansParams.MAX_ITER),
-                seed=self.get_seed(),
-                init_mode=self.get(_KMeansParams.INIT_MODE),
-            )
+            with span("fit"):
+                centroids = self._fit_table(table, k)
         else:
             centroids = self._fit_stream(table, k)
         model = KMeansModel()
         model.copy_params_from(self)
         model.set_model_data(Table({"centroids": centroids[None, :, :]}))
         return model
+
+    def _fit_table(self, table: Table, k: int) -> np.ndarray:
+        """The in-RAM fit: the features column as the table holds it
+        (float32 stays float32 and computes in float32 whatever
+        ``jax_enable_x64`` says; nothing of the table's size is copied
+        on the host), on the mesh once a table (:func:`_rows_on_mesh`),
+        the start centroids read from the host column, the whole loop
+        one program."""
+        features_col = self.get(_KMeansParams.FEATURES_COL)
+        x = features_matrix(table, features_col, dtype=None)
+        if x.shape[0] < k:
+            raise ValueError(f"k={k} exceeds number of points {x.shape[0]}")
+        mesh = self.mesh or DeviceMesh()
+        placed = _rows_on_mesh(table, features_col, x, mesh)
+        with span("kmeans.init"):
+            start = _start_centroids(
+                x, k, self.get_seed(), self.get(_KMeansParams.INIT_MODE))
+        return _lloyd(placed, start, mesh, k,
+                      self.get(_KMeansParams.MAX_ITER))
 
     def _fit_stream(self, source, k: int) -> np.ndarray:
         from flinkml_tpu.iteration.datacache import DataCache
@@ -253,30 +282,59 @@ class KMeansModel(_KMeansParams, Model):
         return model
 
 
+def _share_sums(xl, sq, wl, centroids, k: int, precision):
+    """``(sums [k, d], counts [k])`` of one share of the rows: each row
+    (``sq`` its squared norm, ``wl`` 1, or 0 for a padded row, which
+    then counts for nothing) goes to its nearest centroid, a tie to the
+    lower cluster; both products at ``precision``. One piece of
+    mathematics for the whole-loop trainer and the streamed one.
+
+    XLA fuses the argmin into the distances' product and the one-hot
+    into the sums': nothing of ``[rows, k]`` reaches HBM, and a round
+    reads the rows twice (PERF.md §5, PR 32)."""
+    d2 = blas.squared_distances(xl, centroids, precision=precision, xs_sq=sq)
+    assign = jnp.argmin(d2, axis=-1)
+    # Per-cluster sums via one-hot matmul (k is small; a matmul beats
+    # scatter on TPU). The one-hot side is exact in any precision; the
+    # rows are not, in one bfloat16 pass.
+    onehot = jax.nn.one_hot(assign, k, dtype=xl.dtype) * wl[:, None]
+    counts = jnp.sum(onehot, axis=0)
+    # The rows are summed as deviations from a pivot near them (the mean
+    # of the centroids; XLA fuses the subtraction into the product's
+    # operand): a float32 sum of 400,000 pixels loses a part in 5,000 to
+    # its own accumulator, and the deviations' partial sums are a few
+    # times smaller. Read on a v5e at 2,025,000 x 784: the centroids' gap
+    # to float64 Lloyd 1.1e-4 -> 2.1-2.9e-5 for 1.5 % of a round
+    # (PERF.md §5, PR 32).
+    pivot = jnp.mean(centroids, axis=0)
+    sums = jnp.matmul(onehot.T, xl - pivot, precision=precision)
+    return sums + counts[:, None] * pivot, counts
+
+
+def _moved(sums, counts, centroids):
+    """A round's centroids: the mean of each cluster's rows; an empty
+    cluster keeps its last centroid."""
+    safe = jnp.maximum(counts, 1.0)[:, None]
+    return jnp.where(counts[:, None] > 0, sums / safe, centroids)
+
+
 @functools.lru_cache(maxsize=64)
-def _kmeans_trainer(mesh, k: int, axis: str):
-    """Whole Lloyd loop as one XLA program, cached per (mesh, k).
+def _kmeans_trainer(mesh, k: int, axis: str, precision=PRODUCT_PRECISION):
+    """Whole Lloyd loop as one XLA program, cached per (mesh, k,
+    precision): ``(rows, their squared norms, mask, start, max_iter) ->
+    centroids``, exactly ``max_iter`` rounds, one ``psum`` of the
+    shares' sums and counts a round.
 
     A hand-fused Pallas Lloyd pass was built, lost to this plain
     lowering and was removed (not re-measured on the current chip), so
-    the argmin + one-hot-matmul form below IS the product path — XLA's
-    fusion already reads the points once per pass."""
+    the argmin + one-hot-matmul form of :func:`_share_sums` IS the
+    product path."""
 
-    def per_device(xl, wl, init_centroids, max_iter):
+    def per_device(xl, sq, wl, init_centroids, max_iter):
         def body(_, centroids):
-            # Assignment: argmin over pairwise squared distances (MXU).
-            d2 = blas.squared_distances(xl, centroids)
-            assign = jnp.argmin(d2, axis=-1)
-            # Per-cluster sums via one-hot matmul; padded rows have w=0.
-            onehot = jax.nn.one_hot(assign, k, dtype=xl.dtype) * wl[:, None]
-            sums = jax.lax.psum(onehot.T @ xl, axis)
-            counts = jax.lax.psum(jnp.sum(onehot, axis=0), axis)
-            # Empty clusters keep their previous centroid.
-            safe = jnp.maximum(counts, 1.0)[:, None]
-            new_centroids = jnp.where(
-                counts[:, None] > 0, sums / safe, centroids
-            )
-            return new_centroids
+            sums, counts = _share_sums(xl, sq, wl, centroids, k, precision)
+            return _moved(jax.lax.psum(sums, axis), jax.lax.psum(counts, axis),
+                          centroids)
 
         return jax.lax.fori_loop(0, max_iter, body, init_centroids)
 
@@ -284,10 +342,69 @@ def _kmeans_trainer(mesh, k: int, axis: str):
         jax.shard_map(
             per_device,
             mesh=mesh,
-            in_specs=(P(axis), P(axis), P(), P()),
+            in_specs=(P(axis), P(axis), P(axis), P(), P()),
             out_specs=P(),
         )
     )
+
+
+class _Placed(NamedTuple):
+    """A table's rows as the mesh holds them, in the table's own order."""
+
+    rows: jax.Array   # [p * n_local, d], zero rows past the table's end
+    norms: jax.Array  # [p * n_local] each row's squared norm
+    mask: jax.Array   # [p * n_local] 1 for a row of the table, 0 for padding
+
+
+def _place_rows(x: np.ndarray, mesh: DeviceMesh) -> _Placed:
+    """``x``'s rows on the mesh through :meth:`DeviceMesh.shard_rows` in
+    identity order (staged rounds far under the runtime's ≈ 4 GiB
+    transfer cliff; no padded or widened host copy), the mask made on
+    the device, the squared norms computed there once."""
+    with span("kmeans.table_to_device") as phase:
+        rows = mesh.shard_rows(x, np.arange(x.shape[0]))
+        mask = mesh.shard_ones(x.shape[0], rows.dtype)
+        norms = blas.squared_norms(rows)
+        phase.add(bytes=rows.nbytes)
+    group = metrics.group("kmeans")
+    group.counter("table_uploads")
+    group.counter("table_h2d_bytes", float(rows.nbytes))
+    return _Placed(rows, norms, mask)
+
+
+def _rows_on_mesh(table: Table, features_col: str, x: np.ndarray,
+                  mesh: DeviceMesh) -> _Placed:
+    """The features column on ``mesh``, placed at the table's first fit
+    and kept WITH the table (:meth:`Table.device_resident`, under a key
+    of the column, the mesh and the dtype): the table holds the only
+    reference to the device copy (6.35 GB for 2,025,000 x 784 float32
+    rows, with 16 MB of norms and mask), a later fit on the same table
+    uploads nothing, and dropping the table frees it."""
+    key = ("rows_on_mesh", features_col, mesh.mesh, x.dtype.name)
+    return table.device_resident(key, lambda: _place_rows(x, mesh))
+
+
+def _lloyd(placed: _Placed, start: np.ndarray, mesh: DeviceMesh, k: int,
+           max_iter: int, precision=PRODUCT_PRECISION) -> np.ndarray:
+    """``max_iter`` rounds from ``start`` over the placed rows: the one
+    whole-loop program dispatched and waited for, the ``[k, d]``
+    centroids read back."""
+    trainer = _kmeans_trainer(mesh.mesh, k, DeviceMesh.DATA_AXIS, precision)
+    with span("kmeans.loop"):
+        with span("kmeans.dispatch"):
+            centroids = trainer(
+                placed.rows, placed.norms, placed.mask,
+                jnp.asarray(start, placed.rows.dtype),
+                jnp.asarray(max_iter, jnp.int32))
+        # The caller reads the centroids next, so waiting here costs
+        # nothing and gives the loop a span its device time lies in.
+        centroids.block_until_ready()
+    group = metrics.group("kmeans")
+    group.counter("fits")
+    group.counter("rounds", float(max_iter))
+    group.counter("rows", float(placed.rows.shape[0]))
+    with span("kmeans.readback"):
+        return np.asarray(centroids)
 
 
 def _kmeans_pp_init(x: np.ndarray, k: int, rng: np.random.Generator) -> np.ndarray:
@@ -303,6 +420,18 @@ def _kmeans_pp_init(x: np.ndarray, k: int, rng: np.random.Generator) -> np.ndarr
     return np.stack(centroids)
 
 
+def _start_centroids(x: np.ndarray, k: int, seed: int, init_mode: str) -> np.ndarray:
+    """``[k, d]`` start centroids from the host rows: ``random`` is ``k``
+    distinct rows, ``default_rng(seed).choice(n, size=k, replace=False)``
+    (the documented rule: ``benchmark/reference/kmeans.py`` states the
+    same one); ``k-means++`` the seeding over all rows."""
+    rng = np.random.default_rng(seed)
+    if init_mode == "k-means++":
+        return _kmeans_pp_init(x, k, rng)
+    return np.ascontiguousarray(
+        x[rng.choice(x.shape[0], size=k, replace=False)])
+
+
 def train_kmeans(
     x: np.ndarray,
     k: int,
@@ -312,24 +441,15 @@ def train_kmeans(
     init_mode: str = "random",
     initial_centroids: Optional[np.ndarray] = None,
 ) -> np.ndarray:
-    """Returns centroids [k, d]; the full loop runs on device.
+    """Returns centroids [k, d]; the full loop runs on device. The rows
+    are placed for this call alone (``KMeans.fit`` keeps a table's).
     ``initial_centroids`` overrides the seeded init (used by tests and by
     warm restarts)."""
-    rng = np.random.default_rng(seed)
     if initial_centroids is not None:
-        init_centroids = np.asarray(initial_centroids, x.dtype)
-    elif init_mode == "k-means++":
-        init_centroids = _kmeans_pp_init(x, k, rng)
+        start = np.asarray(initial_centroids, x.dtype)
     else:
-        init_idx = rng.choice(x.shape[0], size=k, replace=False)
-        init_centroids = np.ascontiguousarray(x[init_idx])
-
-    xd, wd, _ = prepare_kmeans_data(x, mesh)
-    trainer = _kmeans_trainer(mesh.mesh, k, DeviceMesh.DATA_AXIS)
-    centroids = trainer(
-        xd, wd, jnp.asarray(init_centroids), jnp.asarray(max_iter, jnp.int32)
-    )
-    return np.asarray(centroids)
+        start = _start_centroids(x, k, seed, init_mode)
+    return _lloyd(_place_rows(x, mesh), start, mesh, k, max_iter)
 
 
 @functools.lru_cache(maxsize=64)
@@ -337,17 +457,14 @@ def _kmeans_partial_fn(mesh, k: int, axis: str):
     """Per-batch Lloyd partials: psum'd per-cluster (sums, counts) for one
     sharded batch against replicated centroids. The streamed trainer
     accumulates these across batches, then updates centroids once per
-    epoch — identical math to :func:`_kmeans_trainer`'s body with the
-    batch axis split."""
+    epoch: :func:`_share_sums`, the whole-loop trainer's own round, with
+    the batch axis split (a batch's norms are computed with it: a batch
+    is seen once an epoch)."""
 
     def per_device(xb, wb, centroids):
-        d2 = blas.squared_distances(xb, centroids)
-        assign = jnp.argmin(d2, axis=-1)
-        onehot = jax.nn.one_hot(assign, k, dtype=xb.dtype) * wb[:, None]
-        return (
-            jax.lax.psum(onehot.T @ xb, axis),
-            jax.lax.psum(jnp.sum(onehot, axis=0), axis),
-        )
+        sums, counts = _share_sums(
+            xb, jnp.sum(xb * xb, axis=-1), wb, centroids, k, PRODUCT_PRECISION)
+        return jax.lax.psum(sums, axis), jax.lax.psum(counts, axis)
 
     return jax.jit(
         jax.shard_map(
@@ -647,8 +764,7 @@ def train_kmeans_stream(
             if sums is None:
                 raise ValueError("training stream is empty")
             counts = guard.flush(counts)
-            safe = jnp.maximum(counts, 1.0)[:, None]
-            cent_dev = jnp.where(counts[:, None] > 0, sums / safe, cent_dev)
+            cent_dev = _moved(sums, counts, cent_dev)
             if should_snapshot(checkpoint_manager, checkpoint_interval,
                                epoch + 1, max_iter):
                 if multi:
@@ -670,16 +786,3 @@ def train_kmeans_stream(
     for listener in listeners:
         listener.on_iteration_terminated(cent_dev)
     return np.asarray(cent_dev)
-
-
-def prepare_kmeans_data(x: np.ndarray, mesh: DeviceMesh):
-    """Pad/mask/shard points for the Lloyd trainer; returns
-    ``(xd, wd, n_valid)``. The single source of the padding policy — the
-    bench measures exactly what :func:`train_kmeans` runs."""
-    p_size = mesh.axis_size()
-    # 8-row tile: keeps local shards sublane-aligned; zero-weight rows
-    # are exact no-ops.
-    x_pad, n_valid = pad_to_multiple(x, p_size * 8)
-    w = np.zeros(x_pad.shape[0], dtype=x.dtype)
-    w[:n_valid] = 1.0  # mask: padded rows never influence centroids
-    return mesh.shard_batch(x_pad), mesh.shard_batch(w), n_valid

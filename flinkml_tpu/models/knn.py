@@ -56,6 +56,7 @@ from flinkml_tpu.kernels import _gate
 from flinkml_tpu.kernels import knn_search
 from flinkml_tpu.kernels import topk as topk_kernel
 from flinkml_tpu.models._data import features_matrix
+from flinkml_tpu.ops import blas
 from flinkml_tpu.parallel.mesh import DeviceMesh
 from flinkml_tpu.table import Table
 from flinkml_tpu.utils.metrics import metrics
@@ -70,9 +71,10 @@ TRAIN_TILE = 32_768
 #: A tile's width is padded to a multiple of the TPU's lane width.
 LANES = 128
 #: The product's precision, a static argument of the search: float32
-#: accuracy. A builder's control on the chip passes ``DEFAULT`` (one
-#: bfloat16 pass) to show that the benchmark's check tells the two apart.
-PRODUCT_PRECISION = jax.lax.Precision.HIGHEST
+#: accuracy, the distance expansion's own (``ops.blas``). A builder's
+#: control on the chip passes ``DEFAULT`` (one bfloat16 pass) to show
+#: that the benchmark's check tells the two apart.
+PRODUCT_PRECISION = blas.DISTANCE_PRECISION
 
 
 class _KnnParams(HasFeaturesCol, HasLabelCol, HasPredictionCol, HasK):
@@ -143,7 +145,7 @@ class KnnModel(_KnnParams, Model):
                 # of the label column, here and not in every call.
                 classes, ids = np.unique(self._labels, return_inverse=True)
                 class_ids = jnp.asarray(ids.reshape(-1), dtype=jnp.int32)
-                norms = _squared_norms(features)
+                norms = blas.squared_norms(features)
                 nbytes = features.nbytes + class_ids.nbytes
                 phase.add(bytes=nbytes)
             group = metrics.group("knn")
@@ -215,11 +217,6 @@ def _tile_rows(n_train: int, k: int) -> int:
     return min(n_train, max(TRAIN_TILE, -(-k // LANES) * LANES))
 
 
-@jax.jit
-def _squared_norms(features):
-    return jnp.sum(features * features, axis=-1)
-
-
 def _tile_top_k(d2, k: int):
     """``(values, positions)`` of each row's ``k`` smallest entries of
     ``d2`` ([rows, width]), ties to the lower position: exact.
@@ -284,7 +281,7 @@ def nearest(queries, train_x, train_sq, k: int, *, chunk: int, tile: int,
     train_t = train_x.T
 
     def one_chunk(q):
-        q_sq = jnp.sum(q * q, axis=-1, keepdims=True)
+        q_sq = jnp.sum(q * q, axis=-1)
 
         def one_tile(i, best):
             best_d, best_rows = best
@@ -293,9 +290,8 @@ def nearest(queries, train_x, train_sq, k: int, *, chunk: int, tile: int,
             x_t = jax.lax.dynamic_slice_in_dim(train_t, start, tile, 1)
             x_sq = jax.lax.dynamic_slice_in_dim(train_sq, start, tile, 0)
             # ‖q‖² - 2 q·x + ‖x‖²: one [chunk, d] @ [d, tile] product.
-            d2 = jnp.maximum(
-                q_sq - 2.0 * jnp.matmul(q, x_t, precision=precision)
-                + x_sq[None, :], 0.0)
+            d2 = blas.squared_distances(q, x_t.T, precision=precision,
+                                        xs_sq=q_sq, ys_sq=x_sq)
             ranked_before = start + jnp.arange(tile, dtype=jnp.int32) < lo
             d2 = jnp.where(ranked_before[None, :], jnp.inf, d2)
             d2 = jnp.pad(d2, ((0, 0), (0, pad_cols)), constant_values=jnp.inf)

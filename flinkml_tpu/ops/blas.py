@@ -13,7 +13,8 @@ TPU-first additions: the reference calls gemv per row (e.g.
 
 Functions accept jax or numpy arrays and return jax arrays. Precision policy:
 computations run in the input dtype; algorithms choose float32 (TPU-native)
-and tests may use float64 on CPU (x64 enabled in conftest).
+and tests may use float64 on CPU (x64 enabled in conftest). The distance
+expansion states its product's precision (:data:`DISTANCE_PRECISION`).
 """
 
 from __future__ import annotations
@@ -74,16 +75,42 @@ def batch_dot(xs, y) -> Array:
     return xs @ y
 
 
-def squared_distances(xs, ys) -> Array:
+@jax.jit
+def squared_norms(xs) -> Array:
+    """Each row's squared L2 norm: [n, d] -> [n]. One program a shape,
+    for a caller that keeps a placed table's norms beside it (KNN's model
+    data, a KMeans table) and hands them to :func:`squared_distances`."""
+    return jnp.sum(xs * xs, axis=-1)
+
+
+#: The precision of the one product in :func:`squared_distances`: float32
+#: accuracy. A TPU's default is ONE bfloat16 pass, which rounds both
+#: operands to 8 bits of mantissa: distances off by a part in a few
+#: hundred, enough to move thousands of a large table's rows to another
+#: centroid or a query's neighbours to other rows (PERF.md §2).
+DISTANCE_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def squared_distances(xs, ys, *, precision=DISTANCE_PRECISION,
+                      xs_sq=None, ys_sq=None) -> Array:
     """Pairwise squared L2 distances: [n, d] x [m, d] -> [n, m].
 
-    Uses the (‖x‖² - 2x·y + ‖y‖²) expansion so the dominant cost is one
-    [n,d]@[d,m] matmul on the MXU instead of an O(n·m·d) elementwise
-    broadcast that would blow HBM.
+    THE expansion (‖x‖² - 2x·y + ‖y‖²) of the program: the dominant cost
+    is one [n,d]@[d,m] matmul on the MXU instead of an O(n·m·d)
+    elementwise broadcast that would blow HBM. KMeans (both trainers and
+    the model), KNN's tiled search and every other caller share it, so
+    they share its ``precision``: the product's, stated, float32 accuracy
+    unless a caller (a builder's control) passes another. On a CPU every
+    precision is the same arithmetic.
+
+    ``xs_sq`` ([n]) and ``ys_sq`` ([m]) are the rows' squared norms where
+    the caller has them already (a table's, computed once and not once a
+    round); a caller that holds ``ys`` as columns passes ``ys_t.T``, and
+    the two transposes cancel before the compiler lays anything out.
     """
     xs = jnp.asarray(xs)
     ys = jnp.asarray(ys)
-    x2 = jnp.sum(xs * xs, axis=-1, keepdims=True)
-    y2 = jnp.sum(ys * ys, axis=-1, keepdims=True).T
-    d2 = x2 - 2.0 * (xs @ ys.T) + y2
+    x2 = (jnp.sum(xs * xs, axis=-1) if xs_sq is None else xs_sq)[:, None]
+    y2 = (jnp.sum(ys * ys, axis=-1) if ys_sq is None else ys_sq)[None, :]
+    d2 = x2 - 2.0 * jnp.matmul(xs, ys.T, precision=precision) + y2
     return jnp.maximum(d2, 0.0)

@@ -613,6 +613,20 @@ class Table:
                 self._device_cache[key] = arr
         return self._device_cache[key]
 
+    def device_resident(self, key: tuple, place):
+        """What ``place()`` put on a device for this table, kept WITH the
+        table under ``key`` (a tuple that names the column, the mesh and
+        the dtype, and so cannot meet :meth:`device_column`'s keys): an
+        estimator that places a column its own way (``KMeans.fit``: the
+        rows over a mesh, their norms and mask beside them) finds it
+        again at its next fit on this table and uploads nothing. The
+        table holds the only reference: tables are immutable, so the
+        copy cannot go stale, and it is freed when the table is dropped
+        (every relational op returns a NEW table, without it)."""
+        if key not in self._device_cache:
+            self._device_cache[key] = place()
+        return self._device_cache[key]
+
     # -- relational ops ----------------------------------------------------
     # Zero-copy on device-backed columns: buffers are rebound, never fetched.
     def select(self, *names: str) -> "Table":

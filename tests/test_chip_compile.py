@@ -110,3 +110,40 @@ def test_top_k_kernel_compiles_whatever_x64_says(one_chip, no_compile_cache, x64
         assert not re.search(r"\b[fiu]64\[", str(traced.jaxpr))
         compiled = traced.lower().compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kmeans_whole_loop_at_the_cells_size(topo, no_compile_cache):
+    """``kmeans-mnist8m.fit``'s one program: twenty Lloyd rounds over
+    2,025,000 x 784 resident float32 rows, k 10, both products at
+    ``HIGHEST``, on a one-chip mesh (the ``psum`` included). XLA fuses the
+    argmin into the distances' product and the one-hot into the sums', so
+    beside the resident rows, norms and mask the program holds a few
+    megabytes: no ``[rows, k]`` array (81 MB, 1.04 GB padded to 128
+    lanes), no bfloat16 parts of the table (9.5 GB), no relaid copy of it
+    (6.35 GB)."""
+    import numpy as np
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flinkml_tpu.models import kmeans
+
+    rows, dim, k = 2_025_000, 784, 10
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    # As a v5e holds it (read off the placed array there, PR 32): the
+    # table lies with its ROWS along the lanes.
+    table = jax.ShapeDtypeStruct(
+        (rows, dim), jnp.float32,
+        sharding=Format(Layout(major_to_minor=(1, 0)), by_rows))
+    per_row = jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=by_rows)
+    assert kmeans.PRODUCT_PRECISION == jax.lax.Precision.HIGHEST
+    compiled = kmeans._kmeans_trainer(mesh, k, "data").trace(
+        table, per_row, per_row,
+        jax.ShapeDtypeStruct((k, dim), jnp.float32, sharding=whole),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).lower().compile()
+    text = compiled.as_text()
+    assert text.count("operand_precision={highest,highest}") == 2
+    assert "tpu_custom_call" not in text          # XLA's lowering, no kernel
+    memory = compiled.memory_analysis()
+    assert 0.39 * 16e9 < memory.argument_size_in_bytes < 0.41 * 16e9
+    assert memory.temp_size_in_bytes < 0.05e9

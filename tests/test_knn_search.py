@@ -400,7 +400,7 @@ def test_the_fused_share_metric_and_its_entry():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
+    entry = next(m for m in bench["per_layer"] if m["name"] == "knn.fused_search_share")
     assert entry == {
         "name": "knn.fused_search_share", "unit": "rows/row", "better": "higher",
         "source": "program_counter", "layer": "KNN search",

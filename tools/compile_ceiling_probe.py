@@ -39,17 +39,14 @@ def _inner(spec: str) -> None:
     mesh = DeviceMesh()
     t0 = time.perf_counter()
     if kind == "kmeans":
-        from flinkml_tpu.models.kmeans import (
-            _kmeans_trainer,
-            prepare_kmeans_data,
-        )
+        from flinkml_tpu.models.kmeans import _kmeans_trainer, _place_rows
 
         n, k = 65_536, 64
         x = np.zeros((n, d), np.float32)
-        xd, wd, _ = prepare_kmeans_data(x, mesh)
+        placed = _place_rows(x, mesh)
         trainer = _kmeans_trainer(mesh.mesh, k, DeviceMesh.DATA_AXIS)
         lowered = trainer.lower(
-            xd, wd, jnp.zeros((k, d), jnp.float32),
+            *placed, jnp.zeros((k, d), jnp.float32),
             jnp.asarray(3, jnp.int32),
         )
         t_lower = time.perf_counter() - t0
