@@ -164,10 +164,11 @@ def bench_tpu_sparse(indptr, indices, values, dim, y, w,
     mesh = DeviceMesh()
     p = mesh.axis_size()
     # Same pack/pad/shard/batching policy as the product fit path.
-    data_args, local_bss, slot_plan = _linear_sgd.prepare_sparse_buckets(
+    place, local_bss, slot_plan = _linear_sgd.prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
         seed=0,
     )
+    data_args = _linear_sgd._placed(place(0, n_steps))
     trainer = _linear_sgd._sparse_trainer_bucketed(
         mesh.mesh, "logistic", local_bss, DeviceMesh.DATA_AXIS, int(dim),
         slot_plan=slot_plan,
@@ -1863,9 +1864,10 @@ def _inner_converge_sparse() -> dict:
     n, dim, gbs, tol, max_steps = 65_536, 1_000_000, 16_384, 0.25, 2_000
     indptr, indices, values, y, w = make_criteo_csr(n, dim)
     mesh = DeviceMesh()
-    data_args, local_bss, slot_plan = _linear_sgd.prepare_sparse_buckets(
+    place, local_bss, slot_plan = _linear_sgd.prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, gbs, seed=0,
     )
+    data_args = _linear_sgd._placed(place(0, max_steps))
     trainer = _linear_sgd._sparse_trainer_bucketed(
         mesh.mesh, "logistic", local_bss, DeviceMesh.DATA_AXIS, dim,
         slot_plan=slot_plan,

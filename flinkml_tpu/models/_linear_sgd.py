@@ -100,6 +100,54 @@ def _window(arr, epoch, local_bs):
     return jax.lax.dynamic_slice(arr, (start, zero), (local_bs, arr.shape[1]))
 
 
+def _reach_rows(n_local: int, local_bs: int, first: int, last: int) -> int:
+    """One past the last local row that steps ``[first, last)`` read
+    through :func:`_window` (its arithmetic, tail clamping included):
+    what a placement has to send for them. Step ``k`` reads window ``k
+    mod ceil(n_local / local_bs)``; a span of steps that wraps, or takes
+    the last window (clamped to end at the shard's end), reaches it all."""
+    n_windows = max(-(-n_local // local_bs), 1)
+    if last <= first:
+        return 0
+    top = first % n_windows + (last - first)  # one past the highest window
+    return n_local if top >= n_windows else top * local_bs
+
+
+def _steps_ready(n_local: int, local_bs: int, first: int, last: int,
+                 complete: int) -> int:
+    """The epoch the loop can run to from ``first`` (at most ``last``)
+    when the leading ``complete`` local rows of every shard have landed:
+    every step before it reads a window that is whole."""
+    if complete >= n_local:
+        return last
+    n_windows = max(-(-n_local // local_bs), 1)
+    # complete < n_local: whole windows only, none of them the clamped last
+    ahead = complete // local_bs - first % n_windows
+    return min(last, first + max(0, ahead))
+
+
+def _follow_windows(place_rows, n_local: int, local_bs: int):
+    """The loop's demand on a placement. ``place_rows(reach_rows)`` gives
+    the rounds of ``(arrays, complete local rows)`` (:meth:`DeviceMesh.
+    stage_rows`); the result is what :func:`_run_chunked` takes:
+    ``place(first, last)``, the rounds of ``(arrays, epoch the loop can
+    run to)`` for steps ``[first, last)``, which stop at the last row
+    those steps can read."""
+
+    def place(first: int, last: int):
+        rounds = place_rows(_reach_rows(n_local, local_bs, first, last))
+        return ((arrays, _steps_ready(n_local, local_bs, first, last, complete))
+                for arrays, complete in rounds)
+
+    return place
+
+
+def _placed(rounds) -> Tuple:
+    """A placement run to its last round: the arrays, whole."""
+    *_, (arrays, _) = rounds
+    return arrays
+
+
 def make_dense_step(loss: str, local_bs: int, axis: str):
     """Per-device epoch: window → margin grad on MXU → psum → prox update.
 
@@ -342,7 +390,7 @@ def _restore_carry(checkpoint_manager, dim: int, dtype, mesh=None):
 
 def _run_chunked(
     trainer,
-    data_args: Tuple,
+    place,
     dim: int,
     dt,
     learning_rate: float,
@@ -356,20 +404,39 @@ def _run_chunked(
     resume: bool = False,
     listeners=(),
 ) -> np.ndarray:
-    """Drive a carry-style trainer in K-epoch dispatches with carry
-    snapshots between dispatches.
+    """Drive a carry-style trainer in dispatches that follow its table's
+    placement, or the boundaries the caller asked for.
 
-    - No checkpoint manager (or interval 0): ONE dispatch runs the whole
-      loop — the fastest path, unchanged.
-    - With a manager + interval K: each dispatch runs K epochs, then the
-      carry ``(coef, loss)`` is snapshotted at its epoch. Failure loses at
-      most one chunk; ``resume=True`` restores the carry and re-enters the
-      same executable, so the resumed trajectory is exactly the
-      uninterrupted one (reference contract: ``Checkpoints.java:43-211``
-      exactly-once feedback logging → here, bit-exact carry replay).
-    - ``listeners`` fire at chunk boundaries (epoch granularity requires
-      the host loop in ``iterate``; the device loop surfaces only chunk
-      boundaries to the host).
+    ``place(first, last)`` starts the placement of the trainer's data
+    args for steps ``[first, last)`` (:func:`_follow_windows`) and gives
+    its rounds: ``(data_args, epoch the loop can run to)``.
+
+    - No checkpoint manager and no listeners: after each round, if a
+      further whole window has landed on every shard, the trainer is
+      dispatched from the carry ON THE DEVICE up to the steps the landed
+      windows allow, and runs while the next round is gathered and sent;
+      the last round's dispatch runs to ``max_iter``. The carry is read
+      back once, at the end: the loop's own condition stops every chunk
+      whose entering loss is at or under ``tol``, so the result is bit
+      for bit the one-dispatch fit's. A table of one round is one
+      dispatch. ``trainer.loop`` spans all of it, the rounds' spans
+      (``hostdata.stage_wait``, ``hostdata.shuffle``,
+      ``mesh.shard_batch``) inside it.
+    - With a manager or listeners the host takes the carry at every
+      boundary, so the placement completes first; then each dispatch
+      runs K epochs (the manager's interval; all of them without one)
+      and the carry ``(coef, loss)`` is snapshotted at its epoch.
+      Failure loses at most one chunk; ``resume=True`` restores the
+      carry and re-enters the same executable, so the resumed trajectory
+      is exactly the uninterrupted one (reference contract:
+      ``Checkpoints.java:43-211`` exactly-once feedback logging → here,
+      bit-exact carry replay). ``listeners`` fire at chunk boundaries
+      (epoch granularity requires the host loop in ``iterate``; the
+      device loop surfaces only chunk boundaries to the host).
+
+    ``metrics.group("trainer")`` counts ``steps`` (run) and
+    ``pipelined_steps`` (of them, dispatched before the placement's last
+    round was sent).
     """
     from flinkml_tpu.iteration.checkpoint import begin_resume
 
@@ -383,36 +450,51 @@ def _run_chunked(
         )
         coef = jnp.asarray(coef_h, dt)
 
-    chunk = (
-        checkpoint_interval
-        if checkpoint_manager is not None and checkpoint_interval > 0
-        else max_iter
-    )
     hy = (
         jnp.asarray(learning_rate, dt),
         jnp.asarray(reg_l2, dt),
         jnp.asarray(reg_l1, dt),
         jnp.asarray(tol, dt),
     )
+    first = epoch
+    rounds = place(first, max_iter)
+    at_host = checkpoint_manager is not None or bool(listeners)
+    if at_host:
+        data_args = _placed(rounds)
+        chunk = (checkpoint_interval if checkpoint_manager is not None
+                 and checkpoint_interval > 0 else max_iter)
+        rounds = ((data_args, min(end, max_iter))
+                  for end in range(first + chunk, max_iter + chunk, chunk))
+    # On the mesh as the trainer returns it, so that a chunk entered from
+    # the chunk before is the program the first one compiled.
+    carry = mesh.replicate(
+        (coef, jnp.asarray(epoch, jnp.int32), jnp.asarray(cur_loss, dt)))
+    # Steps [first, before) went out ahead of the last dispatch.
+    before = sent = first
     with span("trainer.loop"):
-        while epoch < max_iter and cur_loss > tol:
-            epoch_end = min(epoch + chunk, max_iter)
-            coef, ep_dev, loss_dev = trainer(
-                coef, jnp.asarray(epoch, jnp.int32),
-                jnp.asarray(cur_loss, dt),
-                *data_args, *hy, jnp.asarray(epoch_end, jnp.int32),
-            )
-            epoch = int(ep_dev)
-            cur_loss = float(loss_dev)
-            coef_host = np.asarray(coef)
-            if checkpoint_manager is not None:
-                checkpoint_manager.save(
-                    (coef_host, np.float64(cur_loss)), epoch
-                )
-            for listener in listeners:
-                listener.on_epoch_watermark_incremented(epoch - 1, coef_host)
+        for data_args, ready in rounds:
+            if ready <= sent or not cur_loss > tol:
+                continue
+            carry = trainer(*carry, *data_args, *hy, np.int32(ready))
+            before, sent = sent, ready
+            if at_host:
+                coef_host = np.asarray(carry[0])
+                epoch, cur_loss = int(carry[1]), float(carry[2])
+                if checkpoint_manager is not None:
+                    checkpoint_manager.save(
+                        (coef_host, np.float64(cur_loss)), epoch
+                    )
+                for listener in listeners:
+                    listener.on_epoch_watermark_incremented(epoch - 1, coef_host)
+        # The one wait of a pipelined fit: every chunk has run, on every
+        # device, before the span closes.
+        epoch = int(jax.block_until_ready(carry)[1])
+    counts = metrics.group("trainer")
+    counts.counter("steps", float(epoch - first))
+    counts.counter("pipelined_steps",
+                   0.0 if at_host else float(min(epoch, before) - first))
     with span("trainer.readback"):
-        result = np.asarray(coef)
+        result = np.asarray(carry[0])
         if checkpoint_manager is not None:
             # Drain any in-flight async write so a failed final snapshot
             # surfaces here, not silently at interpreter exit.
@@ -422,21 +504,36 @@ def _run_chunked(
     return result
 
 
-def _place_shuffled(x, y, w, mesh: DeviceMesh, seed: int, dtype):
-    """The training table on the mesh in the row order ``seed`` fixes,
-    padded with zero rows (weight 0) to the mesh: exactly what
-    ``shard_batch(pad(a.astype(dtype)[perm]))`` places for each of the
-    three (``dtype`` None: each array's own). Every column goes up in one
-    chunked pass (:meth:`DeviceMesh.shard_rows`: gathered on the pool's
-    threads, cast in the gather, never copied whole on the host), the
-    labels and a weight column as one round of rows of width ``()``.
-    ``w`` None is unit weights: made on the device
+def _place_shuffled(x, y, w, mesh: DeviceMesh, seed: int, dtype,
+                    reach_rows: Optional[int] = None):
+    """The training table on its way to the mesh in the row order
+    ``seed`` fixes, padded with zero rows (weight 0) to the mesh: the
+    rounds of ``((xd, yd, wd), complete local rows)`` of ONE lockstep
+    placement of its columns (:meth:`DeviceMesh.stage_rows`: gathered on
+    the pool's threads, cast in the gather, never copied whole on the
+    host; ``dtype`` None: each array's own). Run to its last round
+    (:func:`_placed`) the three arrays are exactly what
+    ``shard_batch(pad(a.astype(dtype)[perm]))`` places, on the local
+    rows below ``reach_rows`` (None: all), zero above. The permutation
+    is computed whole, here, before the first round is asked for. ``w``
+    None is unit weights: made on the device
     (:meth:`DeviceMesh.shard_ones`), no host array at all."""
     with span("hostdata.shuffle"):
         perm = np.random.default_rng(seed).permutation(x.shape[0])
     _count_unit_weights(w)
-    return (mesh.shard_rows(x, perm, dtype), mesh.shard_rows(y, perm, dtype),
-            _place_weights(w, perm, mesh, dtype))
+    columns = [(x, perm, dtype), (y, perm, dtype)]
+    if w is not None:
+        return mesh.stage_rows(columns + [(w, perm, dtype)], reach_rows)
+    ones = _unit_weights(x.shape[0], mesh, dtype)
+    return ((arrays + (ones,), complete)
+            for arrays, complete in mesh.stage_rows(columns, reach_rows))
+
+
+def _placed_dtype(x, dtype):
+    """The dtype the device holds ``x`` at: ``dtype`` (None: ``x``'s
+    own) as ``device_put`` narrows it where x64 is off."""
+    return jnp.dtype(jax.dtypes.canonicalize_dtype(
+        dtype if dtype is not None else x.dtype))
 
 
 def _count_unit_weights(w) -> None:
@@ -446,14 +543,11 @@ def _count_unit_weights(w) -> None:
         metrics.group("hostdata").counter("unit_weights_on_device")
 
 
-def _place_weights(w, order, mesh: DeviceMesh, dtype):
-    """A weight column in the row order ``order``, as the labels go; unit
-    weights (``w`` None; float64 where ``dtype`` names no width, as
-    ``labeled_data``'s ones were) made on the device."""
-    if w is not None:
-        return mesh.shard_rows(w, order, dtype)
-    return mesh.shard_ones(
-        order.shape[0], dtype if dtype is not None else np.float64)
+def _unit_weights(n: int, mesh: DeviceMesh, dtype):
+    """The weights of ``n`` rows with no weight column (float64 where
+    ``dtype`` names no width, as ``labeled_data``'s ones were), made on
+    the device."""
+    return mesh.shard_ones(n, dtype if dtype is not None else np.float64)
 
 
 def train_linear_model(
@@ -546,12 +640,14 @@ def train_linear_model(
             checkpoint_interval=checkpoint_interval, resume=resume,
         )
     p_size = mesh.axis_size()
-    xd, yd, wd = _place_shuffled(x, y, w, mesh, seed, dtype)
-    n_local = xd.shape[0] // p_size
+    n_local = -(-n // p_size)
     local_bs = align_local_bs(global_batch_size, p_size, n_local)
     trainer = _dense_trainer(mesh.mesh, loss, local_bs, DeviceMesh.DATA_AXIS)
+    place = _follow_windows(
+        functools.partial(_place_shuffled, x, y, w, mesh, seed, dtype),
+        n_local, local_bs)
     return _run_chunked(
-        trainer, (xd, yd, wd), x.shape[1], xd.dtype,
+        trainer, place, x.shape[1], _placed_dtype(x, dtype),
         learning_rate, reg * (1.0 - elastic_net), reg * elastic_net,
         tol, max_iter, mesh,
         checkpoint_manager=checkpoint_manager,
@@ -565,14 +661,27 @@ def prepare_sparse_buckets(
     global_batch_size: int, max_buckets: int = 4, dtype=np.float32,
     seed: Optional[int] = None,
 ) -> Tuple[Tuple, Tuple[int, ...], Tuple]:
-    """Pack, shuffle, pad, and shard CSR data for the bucketed trainer.
+    """Pack CSR data for the bucketed trainer, and say how it is
+    shuffled, padded and sharded onto the mesh.
 
-    Returns ``(data_args, local_bss, slot_plan)``: the flat per-bucket
-    sharded arrays (indices, values, y, w per bucket; under a plan the
-    blocks' starts after them), each bucket's per-device window size
+    Returns ``(place, local_bss, slot_plan)``: the placement
+    :func:`_run_chunked` follows, each bucket's per-device window size
     (proportional share of ``global_batch_size``, ≥ 1), and the plan the
     step follows. The single source of the batching policy — the bench
     measures exactly what the product trains with.
+
+    ``place(first, last)`` starts the placement for steps ``[first,
+    last)`` and gives its rounds, ``(data_args, epoch the loop can run
+    to)``; ``data_args`` are the flat per-bucket sharded arrays (indices,
+    values, y, w per bucket; under a plan the blocks' starts after
+    them). A bucket's columns go up in lockstep through
+    :meth:`DeviceMesh.stage_rows`, as far as the steps' windows of THAT
+    bucket reach (:func:`_reach_rows` with its own rows and window).
+    Rows of one width are one bucket, whose rounds the loop follows.
+    Several ragged buckets (``pack_ell_buckets``): a step reads a window
+    of every bucket, so all but the last land whole and the loop
+    follows the last one's rounds; lockstep across buckets of different
+    widths would cost more code than the buckets before the last hide.
 
     ``slot_plan`` is one observation of the cells, made here in every
     fit (``ops.sparse.slot_block_plan``, inside ``hostdata.sparse_pack``):
@@ -595,11 +704,10 @@ def prepare_sparse_buckets(
     post-bucketing — no re-gather of the full CSR needed). Rows of one
     width are one bucket, so their order is
     ``default_rng(seed).permutation(rows)``, the dense fit's, and the
-    bucket's rows are the table's: no row ids are made or gathered.
-    Each bucket's two blocks reach the mesh in that order through
-    :meth:`DeviceMesh.shard_rows`, round by round, with no permuted copy
-    on the host. The labels ``y`` (any numeric dtype, as the table holds
-    them) and a weight column ``w`` go the same way, cast to ``dtype`` in
+    bucket's rows are the table's: no row ids are made or gathered. The
+    orders are computed whole when ``place`` is called, before the first
+    round. The labels ``y`` (any numeric dtype, as the table holds them)
+    and a weight column ``w`` go in the same rounds, cast to ``dtype`` in
     the gather; ``w`` None is unit weights, made on the device
     (:meth:`DeviceMesh.shard_ones`), as in :func:`_place_shuffled`.
     """
@@ -645,37 +753,61 @@ def prepare_sparse_buckets(
     counts.counter("padded_cells",
                    float(sum(b["indices"].size for b in buckets)))
     counts.counter("buckets", float(len(buckets)))
-    _count_unit_weights(w)
-    rng = np.random.default_rng(seed) if seed is not None else None
-    data_args: list = []
-    local_bss: list = []
-    for bucket, rows in zip(buckets, row_ids):
-        bi, bv = bucket["indices"], bucket["values"]
-        n_bucket = bi.shape[0]
-        with span("hostdata.shuffle"):
-            order = (rng.permutation(n_bucket) if rng is not None
-                     else np.arange(n_bucket))
-            # The table's rows this bucket's positions hold: where every
-            # row has one width the bucket's rows ARE the table's.
-            picked = order if rows is None else rows[order]
-        # One pass, as the dense fit's: the seeded order gathered round
-        # by round on its way to the device, no permuted copy of the
-        # block on the host (DeviceMesh.shard_rows places exactly
-        # shard_batch(pad(block[order]))).
-        idxd = mesh.shard_rows(bi, order, np.int32)
-        vald = mesh.shard_rows(bv, order, dtype)
-        data_args += [idxd, vald, mesh.shard_rows(y, picked, dtype),
-                      _place_weights(w, picked, mesh, dtype)]
-        n_local = idxd.shape[0] // p_size
-        share = max(1, math.ceil(global_batch_size * n_bucket / (n * p_size)))
-        local_bs = min(share, n_local)
-        local_bss.append(local_bs)
-    if slot_plan:
-        # Every device's shard the same [width] starts (a few bytes:
-        # under no span, as the unit weights are).
-        data_args.append(jax.device_put(
-            np.tile(starts, p_size), mesh.data_sharding()))
-    return tuple(data_args), tuple(local_bss), slot_plan
+    local_bss = tuple(
+        min(max(1, math.ceil(global_batch_size * b["indices"].shape[0]
+                             / (n * p_size))),
+            -(-b["indices"].shape[0] // p_size))
+        for b in buckets)
+
+    def place(first: int, last: int):
+        _count_unit_weights(w)
+        rng = np.random.default_rng(seed) if seed is not None else None
+        placements = []
+        for bucket, rows, local_bs in zip(buckets, row_ids, local_bss):
+            bi, bv = bucket["indices"], bucket["values"]
+            n_bucket = bi.shape[0]
+            with span("hostdata.shuffle"):
+                order = (rng.permutation(n_bucket) if rng is not None
+                         else np.arange(n_bucket))
+                # The table's rows this bucket's positions hold: where
+                # every row has one width the bucket's rows ARE the
+                # table's.
+                picked = order if rows is None else rows[order]
+            # One pass, as the dense fit's: the seeded order gathered
+            # round by round on its way to the device, no permuted copy
+            # of the block on the host (DeviceMesh.stage_rows places
+            # exactly shard_batch(pad(block[order])) below the reach).
+            columns = [(bi, order, np.int32), (bv, order, dtype),
+                       (y, picked, dtype)]
+            if w is not None:
+                columns.append((w, picked, dtype))
+            follow = _follow_windows(
+                functools.partial(mesh.stage_rows, columns),
+                -(-n_bucket // p_size), local_bs)
+            placements.append((follow(first, last), n_bucket))
+
+        def rounds():
+            # A step reads a window of every bucket: all but the last
+            # bucket land whole (as far as the steps reach), and the
+            # loop follows the last one's rounds.
+            units = [() if w is not None
+                     else (_unit_weights(n_bucket, mesh, dtype),)
+                     for _, n_bucket in placements]
+            head = ()
+            for (placement, _), unit in zip(placements[:-1], units):
+                head += _placed(placement) + unit
+            tail = units[-1]
+            if slot_plan:
+                # Every device's shard the same [width] starts (a few
+                # bytes: under no span, as the unit weights are).
+                tail += (jax.device_put(
+                    np.tile(starts, p_size), mesh.data_sharding()),)
+            for arrays, ready in placements[-1][0]:
+                yield head + arrays + tail, ready
+
+        return rounds()
+
+    return place, local_bss, slot_plan
 
 
 def train_linear_model_sparse_csr(
@@ -716,7 +848,7 @@ def train_linear_model_sparse_csr(
     n = np.asarray(indptr).size - 1
     if n == 0:
         raise ValueError("training table is empty")
-    data_args, local_bss, slot_plan = prepare_sparse_buckets(
+    place, local_bss, slot_plan = prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
         max_buckets=max_buckets, dtype=dtype, seed=seed,
     )
@@ -725,7 +857,7 @@ def train_linear_model_sparse_csr(
         _segsum_backend(), slot_plan,
     )
     return _run_chunked(
-        trainer, tuple(data_args), int(dim), jnp.dtype(dtype),
+        trainer, place, int(dim), jnp.dtype(dtype),
         learning_rate, reg * (1.0 - elastic_net), reg * elastic_net,
         tol, max_iter, mesh,
         checkpoint_manager=checkpoint_manager,
@@ -804,17 +936,19 @@ def train_softmax_model(
     if n == 0:
         raise ValueError("training table is empty")
     p_size = mesh.axis_size()
-    # Labels and weights at the features' width, as the step expects.
-    xd, yd, wd = _place_shuffled(
-        x, y, w, mesh, seed, dtype if dtype is not None else x.dtype
-    )
-    n_local = xd.shape[0] // p_size
-    local_bs = min(max(1, math.ceil(global_batch_size / p_size)), n_local)
+    n_local = -(-n // p_size)
+    local_bs = align_local_bs(global_batch_size, p_size, n_local)
     trainer = _softmax_trainer(
         mesh.mesh, int(num_classes), local_bs, DeviceMesh.DATA_AXIS
     )
+    # Labels and weights at the features' width, as the step expects.
+    dtype = dtype if dtype is not None else x.dtype
+    place = _follow_windows(
+        functools.partial(_place_shuffled, x, y, w, mesh, seed, dtype),
+        n_local, local_bs)
     return _run_chunked(
-        trainer, (xd, yd, wd), (int(num_classes), x.shape[1]), xd.dtype,
+        trainer, place, (int(num_classes), x.shape[1]),
+        _placed_dtype(x, dtype),
         learning_rate, reg * (1.0 - elastic_net), reg * elastic_net,
         tol, max_iter, mesh,
         checkpoint_manager=checkpoint_manager,

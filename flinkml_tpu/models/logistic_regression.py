@@ -513,8 +513,10 @@ def train_logistic_regression(
     Two modes:
       - ``device`` (default): the ENTIRE epoch loop — sampling, gradient,
         psum, update, termination test — compiles into one XLA program
-        (``lax.while_loop`` inside ``shard_map``). One dispatch per fit;
-        zero host round-trips per epoch. This is the design inversion of the
+        (``lax.while_loop`` inside ``shard_map``). One dispatch per
+        staging round that completed a window (one per fit where the
+        table is one round), the carry never leaving the device between
+        them; zero host round-trips per epoch. This is the design inversion of the
         reference's per-epoch feedback/alignment machinery (SURVEY.md §3.2):
         where Flink crosses task, network, and RPC boundaries every epoch,
         the TPU loop never leaves the chip. With a ``checkpoint_manager`` +

@@ -23,18 +23,25 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from flinkml_tpu.utils.metrics import metrics
 from flinkml_tpu.utils.profiling import span
 
-#: :meth:`DeviceMesh.shard_rows` stages the table through host buffers
-#: of this many bytes (all shards' rows of one round together). Read on
+#: :meth:`DeviceMesh.stage_rows` stages a table through host buffers of
+#: this many bytes a column (all shards' rows of one round together; the
+#: widest column's rows set a round's length). Read on
 #: a v5e's host (PERF.md §5, PR 25): 64 and 128 MiB place a 4.64 GB
 #: table equally fast; at 32 MiB the per-round dispatch shows, from 256
 #: MiB the buffers' first-touch page faults do (≈ 0.9 s a GiB, every
 #: fit). Every transfer is far below the runtime's ≈ 4 GiB pre-mapped
 #: limit, above which it runs ten times slower.
 _STAGE_BYTES = 64 << 20
-#: Staging buffers in rotation: one gathered into while the other is on
-#: its way to the device. A third bought nothing.
+#: Sets of staging buffers in rotation: one gathered into while the other
+#: is on its way to the device. Reusing a set waits for the write that
+#: read it, which in a pipelined fit queues behind the chunk of steps
+#: dispatched before it (≈ 24 ms of steps a round of 156-byte rows). A
+#: third set shortened that wait (0.24 → 0.21 s a fit) and bought
+#: nothing: the device loop sets the pace of the pipelined phase either
+#: way (fits of 1.463 s with two, 1.460 with three; PERF.md §6, PR 33).
 _STAGE_BUFFERS = 2
 #: Threads one round's gather is split over (``ndarray.take`` releases
 #: the interpreter lock). One thread gathers 492-byte rows at 4.5 GB/s
@@ -199,65 +206,105 @@ class DeviceMesh:
         of any shape: a 1-D column (labels, weights) is one of rows of
         width ``()``. ``dtype`` None is ``x``'s own.
 
-        One pass, round by round: each shard's next rows are gathered
-        (and cast, if ``x`` is not a ``dtype`` array already) into a
-        reused staging buffer, which ``device_put`` sends on its way while
-        the next round is gathered into the other buffer; a donated
-        in-place write puts the round at its offset in the table's
-        device array, so the device holds the table plus the rounds in
-        flight. Multi-process, every process holds all of ``x`` and
-        places its addressable shards, as :meth:`shard_batch` does.
+        One column with nothing consuming it as it lands (a model's rows,
+        a table kept on the chip): :meth:`stage_rows` with every row in
+        reach, run to its last round."""
+        *_, ((placed,), _) = self.stage_rows([(x, order, dtype)])
+        return placed
+
+    def stage_rows(self, columns, reach_rows: Optional[int] = None):
+        """Several columns of one table placed in lockstep, round by
+        round, for a consumer that reads them as they land. ``columns``
+        is a sequence of ``(x, order, dtype)`` as :meth:`shard_rows`
+        takes them (orders of one length); a generator of ``(placed,
+        complete)``: after each round the columns' device arrays, and how
+        many leading local rows of EVERY shard of every column hold their
+        final values. The last item's arrays are, column for column, what
+        :meth:`shard_rows` documents, on the local rows below
+        ``reach_rows`` (None: all of them), and zero above: rows the
+        consumer says no step of its can read are not gathered and not
+        sent. The arrays keep their full shapes whatever the reach.
+
+        One pass: each round gathers local rows ``[offset, offset +
+        chunk)`` of every shard of every column (cast, where a column is
+        not a ``dtype`` array already) into reused staging buffers, one
+        ``device_put`` sends them on their way while the next round is
+        gathered into the other set, and ONE donated in-place write puts
+        them at their offset in the device arrays. The arrays handed out
+        after a round are donated to the next round's write: a consumer
+        dispatches what reads them before it asks for the next item, and
+        the runtime orders the write behind that program on the device,
+        so the host waits for neither. ``chunk`` is what fits
+        ``_STAGE_BYTES`` of the widest column's rows. Multi-process,
+        every process holds all of every column and places its
+        addressable shards, as :meth:`shard_batch` does.
         """
         p = self.axis_size(self.DATA_AXIS)
         # ndarray.take copies a strided source whole, every call.
-        x = np.ascontiguousarray(x)
+        xs = [np.ascontiguousarray(x) for x, _, _ in columns]
+        orders = [order for _, order, _ in columns]
         # The width device_put would have narrowed to where x64 is off.
-        dt = np.dtype(jax.dtypes.canonicalize_dtype(
+        dts = [np.dtype(jax.dtypes.canonicalize_dtype(
             dtype if dtype is not None else x.dtype))
-        row = x.shape[1:]
-        n = order.shape[0]
+            for x, (_, _, dtype) in zip(xs, columns)]
+        n = orders[0].shape[0]
         n_local = -(-n // p)
-        row_bytes = max(1, int(np.prod(row)) * dt.itemsize)
-        chunk = min(n_local, max(1, _STAGE_BYTES // (p * row_bytes)))
-        rounds = -(-n_local // chunk)
-        stages = [np.empty((p * chunk,) + row, dt)
+        reach = n_local if reach_rows is None else max(0, min(n_local, reach_rows))
+        row_bytes = max(max(1, int(np.prod(x.shape[1:])) * dt.itemsize)
+                        for x, dt in zip(xs, dts))
+        chunk = max(1, min(reach, _STAGE_BYTES // (p * row_bytes)))
+        rounds = -(-reach // chunk)
+        stages = [[np.empty((p * chunk,) + x.shape[1:], dt)
+                   for x, dt in zip(xs, dts)]
                   for _ in range(min(_STAGE_BUFFERS, rounds))]
-        scratch = None if x.dtype == dt else np.empty_like(stages[0], x.dtype)
+        scratch = [None if x.dtype == dt
+                   else np.empty((p * chunk,) + x.shape[1:], x.dtype)
+                   for x, dt in zip(xs, dts)]
         consumed = [None] * len(stages)
         sharding = self.data_sharding()
-        placed = jnp.zeros((p * n_local,) + row, dt, device=sharding)
+        placed = tuple(jnp.zeros((p * n_local,) + x.shape[1:], dt, device=sharding)
+                       for x, dt in zip(xs, dts))
+        counts = metrics.group("hostdata.stage")
+        counts.counter("rows", float(p * n_local))
+        if not rounds:
+            yield placed, 0
+            return
         write = _row_writer(self.mesh, self.DATA_AXIS)
         with ThreadPoolExecutor(_GATHER_THREADS) as pool:
             for r in range(rounds):
-                # The last round steps back to end at the shard's end, so
+                # The last round steps back to end at the reach, so
                 # every round has one shape (one program): it re-sends
                 # rows the round before already placed.
-                offset = min(r * chunk, n_local - chunk)
+                offset = min(r * chunk, reach - chunk)
                 slot = r % len(stages)
-                stage = stages[slot]
                 with span("hostdata.stage_wait"):
-                    # device_put neither snapshots the host buffer nor
-                    # (on CPU) need copy it at all: the buffer is free
-                    # only once the write that read it has run.
+                    # device_put neither snapshots the host buffers nor
+                    # (on CPU) need copy them at all: they are free
+                    # only once the write that read them has run.
                     if consumed[slot] is not None:
                         consumed[slot].block_until_ready()
                 with span("hostdata.shuffle"):
-                    # Shard s takes positions [s * n_local + offset, +
-                    # chunk) of the order: a slice of it. Positions rise
-                    # with the staging row, so a shard cut short by the
-                    # table's end is the last with any row, and the rows
-                    # past the end are the buffer's tail.
-                    parts = [order[min(lo, n):min(lo + chunk, n)] for lo in
-                             range(offset, offset + p * n_local, n_local)]
-                    index = parts[0] if p == 1 else np.concatenate(parts)
-                    valid = index.shape[0]
-                    _gather_rows(pool, x, index, stage[:valid], scratch)
-                    stage[valid:] = 0
+                    for x, order, stage, spare in zip(
+                            xs, orders, stages[slot], scratch):
+                        # Shard s takes positions [s * n_local + offset,
+                        # + chunk) of the order: a slice of it. Positions
+                        # rise with the staging row, so a shard cut
+                        # short by the table's end is the last with any
+                        # row, and the rows past the end are the
+                        # buffer's tail.
+                        parts = [order[min(lo, n):min(lo + chunk, n)] for lo in
+                                 range(offset, offset + p * n_local, n_local)]
+                        index = parts[0] if p == 1 else np.concatenate(parts)
+                        valid = index.shape[0]
+                        _gather_rows(pool, x, index, stage[:valid], spare)
+                        stage[valid:] = 0
                 with span("mesh.shard_batch") as phase:
-                    sent = jax.device_put(stage, sharding)
-                    placed, consumed[slot] = write(placed, sent, np.int32(offset))
-                    phase.add(bytes=sent.nbytes)
-        return placed
+                    sent = jax.device_put(stages[slot], sharding)
+                    placed, consumed[slot] = write(
+                        placed, tuple(sent), np.int32(offset))
+                    phase.add(bytes=sum(s.nbytes for s in sent))
+                counts.counter("rows_sent", float(p * min(chunk, reach - r * chunk)))
+                yield placed, offset + chunk
 
     def shard_ones(self, n: int, dtype) -> jax.Array:
         """``shard_batch(pad_to_multiple(np.ones(n, dtype), p)[0])``, made
@@ -335,20 +382,25 @@ class DeviceMesh:
 
 @functools.lru_cache(maxsize=128)
 def _row_writer(mesh: Mesh, axis: str):
-    """The in-place write of :meth:`DeviceMesh.shard_rows`: every shard
-    of ``table`` takes its shard of ``rows`` at local row ``offset``.
-    ``table`` is donated. The second result is ready when the write has
-    run, i.e. when ``rows`` (and the host buffer under it) has been read;
-    the table itself is donated to the next write and cannot be waited on."""
+    """The in-place write of :meth:`DeviceMesh.stage_rows`: every shard
+    of each of ``tables`` takes its shard of the same-numbered of
+    ``rows`` at local row ``offset``. ``tables`` are donated. The second
+    result is ready when the write has run, i.e. when ``rows`` (and the
+    host buffers under them) have been read; the tables themselves are
+    donated to the next write and cannot be waited on."""
 
-    def write(table, rows, offset):
-        written = jax.lax.dynamic_update_slice_in_dim(table, rows, offset, 0)
-        return written, rows[:1].reshape(-1)[:1]
+    def write(tables, rows, offset):
+        written = tuple(
+            jax.lax.dynamic_update_slice_in_dim(table, block, offset, 0)
+            for table, block in zip(tables, rows))
+        return written, rows[0][:1].reshape(-1)[:1]
 
+    # Stated, not inferred: on a one-device mesh the inferred sharding of
+    # a 1-D table among several is P(), another key for the trainer.
     return jax.jit(
         jax.shard_map(write, mesh=mesh, in_specs=(P(axis), P(axis), P()),
                       out_specs=(P(axis), P(axis))),
-        donate_argnums=0,
+        donate_argnums=0, out_shardings=NamedSharding(mesh, P(axis)),
     )
 
 

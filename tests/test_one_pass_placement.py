@@ -75,7 +75,8 @@ def test_one_pass_placement_equals_the_three_host_passes(
     assert x.flags.c_contiguous == (kind != "strided")
     rng = np.random.default_rng(1)
     y, w = rng.integers(0, 2, rows).astype(np.float64), rng.random(rows)
-    got = _linear_sgd._place_shuffled(x, y, w, mesh, 11, dtype)
+    got = _linear_sgd._placed(
+        _linear_sgd._place_shuffled(x, y, w, mesh, 11, dtype))
     want = _old_pattern(x, y, w, mesh, 11, dtype)
     for g, e in zip(got, want):
         _assert_same_placement(g, e)
@@ -389,8 +390,10 @@ def test_sparse_blocks_are_placed_as_the_whole_array_passes_placed_them(
         monkeypatch.setattr(mesh_mod, "_STAGE_BYTES", stage_bytes)
     mesh = _mesh(devices)
     indptr, indices, values, y, w = _sparse_rows(rows, uniform)
-    got, got_sizes, plan = _linear_sgd.prepare_sparse_buckets(
+    place, got_sizes, plan = _linear_sgd.prepare_sparse_buckets(
         indptr, indices, values, SPARSE_DIM, y, w, mesh, 256, seed=11)
+    # every window read: the whole table placed
+    got = _linear_sgd._placed(place(0, rows))
     if plan:  # the blocks' starts come after the buckets' arrays
         got = got[:-1]
     want, want_sizes = _old_sparse_pattern(
@@ -441,11 +444,12 @@ def _fit_table(layout, rows, seed=4):
 
 
 def _received(monkeypatch, table, weight_col, devices, seed=11):
-    """The arrays ``LogisticRegression.fit`` hands the device loop."""
+    """The arrays ``LogisticRegression.fit`` hands the device loop, for
+    steps that read every window."""
     seen = []
 
-    def capture(trainer, data_args, dim, dt, *args, **kwargs):
-        seen.append(data_args)
+    def capture(trainer, place, dim, dt, *args, **kwargs):
+        seen.append(_linear_sgd._placed(place(0, 1 << 30)))
         return np.zeros(dim, dt)
 
     monkeypatch.setattr(_linear_sgd, "_run_chunked", capture)

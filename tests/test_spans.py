@@ -17,18 +17,21 @@ from flinkml_tpu import pipeline_fusion
 from flinkml_tpu.models import LogisticRegression, _linear_sgd
 from flinkml_tpu.models.logistic_regression import LogisticRegressionModel
 from flinkml_tpu.models.scalers import MaxAbsScalerModel, StandardScalerModel
+from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.pipeline import PipelineModel
 from flinkml_tpu.table import Table
 from flinkml_tpu.utils import metrics, profiling
 from flinkml_tpu.utils.profiling import span
 
-# A table of one staging round a column (DeviceMesh.shard_rows): the
-# permutation, then the features' and the labels' rounds (wait, gather,
-# placement each). The names are the parent's (2ba32a5); with no weight
-# column the weights are made on the device and open no span.
-FIT_SPANS = {"fit": 1, "hostdata.ingest": 1, "hostdata.shuffle": 3,
-             "hostdata.stage_wait": 2, "mesh.shard_batch": 2,
+# A table of one staging round (DeviceMesh.stage_rows places a fit's
+# columns in lockstep): the permutation, then the round's wait, gather and
+# placement. The names are the parent's (2ba32a5); with no weight column
+# the weights are made on the device and open no span.
+FIT_SPANS = {"fit": 1, "hostdata.ingest": 1, "hostdata.shuffle": 2,
+             "hostdata.stage_wait": 1, "mesh.shard_batch": 1,
              "trainer.loop": 1, "trainer.readback": 1}
+#: The rounds' spans, which ``trainer.loop`` holds (PR 33).
+ROUND_SPANS = ("hostdata.stage_wait", "hostdata.shuffle", "mesh.shard_batch")
 
 
 def _counters(group="span"):
@@ -161,59 +164,142 @@ def test_fit_produces_each_fit_span_once(monkeypatch, stage_bytes, weight_col):
     p = len(jax.devices())
     n_local = -(-1003 // p)
     width = jax.dtypes.canonicalize_dtype(np.float64).itemsize
-    chunk = small_chunk = n_local  # rows a shard sends a round: features; labels
+    chunk = n_local  # rows a shard sends a round, of every column
     if stage_bytes is not None:
         monkeypatch.setattr(mesh, "_STAGE_BYTES", stage_bytes)
-        chunk = stage_bytes // (p * 5 * width)
-        small_chunk = min(n_local, stage_bytes // (p * width))
-    rounds, small_rounds = -(-n_local // chunk), -(-n_local // small_chunk)
+        chunk = stage_bytes // (p * 5 * width)  # by the widest: the features
+    rounds = -(-n_local // chunk)
     assert (rounds > 1) == (stage_bytes is not None)
     # the labels, and a weight column the same way; none: made on the device
     small = 1 if weight_col is None else 2
     table = _lr_table()
     with _delta() as d, _delta("hostdata") as made:
         _fit(table, weight_col)
-    placed = rounds + small * small_rounds
-    assert _calls(d) == {**FIT_SPANS, "hostdata.shuffle": 1 + placed,
-                         "hostdata.stage_wait": placed,
-                         "mesh.shard_batch": placed}
+    # Six steps of four windows a shard read every row: every round goes.
+    assert _calls(d) == {**FIT_SPANS, "hostdata.shuffle": 1 + rounds,
+                         "hostdata.stage_wait": rounds,
+                         "mesh.shard_batch": rounds}
     assert made == ({"unit_weights_on_device": 1} if weight_col is None else {})
-    # What was placed: every column round by round (the last round steps
-    # back over rows already sent), padded to the mesh, at the width the
-    # device holds (float32 where x64 is off).
-    assert d["mesh.shard_batch.bytes"] == (
-        rounds * chunk * 5 + small * small_rounds * small_chunk) * p * width
-    # The phases are siblings on the fit's thread, so they add up to it.
-    children = sum(v for k, v in d.items() if k.endswith(".seconds")
-                   and not k.startswith("fit."))
-    assert children <= d["fit.seconds"]
+    # What was placed: the columns in lockstep, round by round (the last
+    # round steps back over rows already sent), padded to the mesh, at
+    # the width the device holds (float32 where x64 is off).
+    assert d["mesh.shard_batch.bytes"] == rounds * chunk * (5 + small) * p * width
+    # The phases on the fit's thread add up to it; the rounds lie inside
+    # the loop, all but the permutation's share of hostdata.shuffle.
+    top = sum(d[f"{k}.seconds"] for k in
+              ("hostdata.ingest", "trainer.loop", "trainer.readback"))
+    assert top <= d["fit.seconds"]
+    assert (d["hostdata.stage_wait.seconds"] + d["mesh.shard_batch.seconds"]
+            <= d["trainer.loop.seconds"])
     # seconds and calls apiece, and the one count a metric reads: a name
     # nothing reads is not added, and no name the parent did not have
     assert set(d) == ({f"{s}.{c}" for s in FIT_SPANS for c in ("seconds", "calls")}
                       | {"mesh.shard_batch.bytes"})
 
 
-def test_the_fits_spans_are_siblings_under_fit(tmp_path):
-    """None nested in another: on the fit's thread every phase starts
-    after the one before it has ended, inside ``fit``."""
-    table = _lr_table()
-    _fit(table)  # compiled before the profile
+def _profiled_spans(tmp_path, run):
+    """``(start, end, name)`` of every program span of ``run()`` under a
+    profile, in start order."""
     with profiling.trace(str(tmp_path), ignore_errors=False):
-        _fit(table)
+        run()
     (path,) = glob.glob(os.path.join(
         str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
     data = jax.profiler.ProfileData.from_file(path)
-    events = sorted(
+    return sorted(
         (e.start_ns, e.start_ns + e.duration_ns, e.name[len(profiling.SPAN_PREFIX):])
         for plane in data.planes if plane.name == "/host:CPU"
         for line in plane.lines for e in line.events
         if e.name.startswith(profiling.SPAN_PREFIX))
-    (fit_start, fit_end, name), *phases = events
+
+
+@pytest.mark.parametrize("stage_bytes", [None, 4096], ids=["one-round", "many-rounds"])
+def test_the_loop_holds_the_rounds_spans_and_the_rest_are_siblings(
+        monkeypatch, tmp_path, stage_bytes):
+    """On the fit's thread: ``hostdata.ingest``, the permutation's
+    ``hostdata.shuffle``, ``trainer.loop`` and ``trainer.readback`` one
+    after another inside ``fit``; every round's wait, gather and
+    placement one after another inside ``trainer.loop`` (PR 33: the loop
+    runs on the windows that have landed while the rest are gathered)."""
+    from flinkml_tpu.parallel import mesh
+
+    if stage_bytes is not None:
+        monkeypatch.setattr(mesh, "_STAGE_BYTES", stage_bytes)
+    table = _lr_table()
+    _fit(table)  # compiled before the profile
+    (fit_start, fit_end, name), *phases = _profiled_spans(
+        tmp_path, lambda: _fit(table))
     assert name == "fit" and {n for _, _, n in phases} == set(FIT_SPANS) - {"fit"}
-    end = fit_start
-    for start, stop, name in phases:
-        assert end <= start <= stop <= fit_end, name
-        end = stop
+    (loop,) = [(a, b) for a, b, n in phases if n == "trainer.loop"]
+    inside = [ph for ph in phases if loop[0] <= ph[0] and ph[1] <= loop[1]
+              and ph[2] != "trainer.loop"]
+    outside = [ph for ph in phases if ph not in inside]
+    assert [n for _, _, n in outside] == [
+        "hostdata.ingest", "hostdata.shuffle", "trainer.loop", "trainer.readback"]
+    rounds, n_local = len(inside) // 3, -(-1003 // len(jax.devices()))
+    width = jax.dtypes.canonicalize_dtype(np.float64).itemsize
+    assert rounds == (1 if stage_bytes is None else -(-n_local // (
+        stage_bytes // (len(jax.devices()) * 5 * width))))
+    assert [n for _, _, n in inside] == list(ROUND_SPANS) * rounds
+    for group, (lo, hi) in ((outside, (fit_start, fit_end)), (inside, loop)):
+        end = lo
+        for start, stop, name in group:
+            assert end <= start <= stop <= hi, name
+            end = stop
+
+
+@pytest.mark.parametrize("boundaries", ["pipelined", "listeners"])
+def test_no_step_of_a_fit_runs_outside_a_loop_span(monkeypatch, boundaries):
+    """Every dispatch of the trainer happens while ``trainer.loop`` is
+    open, and what the last one returned is on the host before the span
+    closes: the chip's busy time inside the span holds every step, so a
+    step read from it cannot come out short (nor a roofline share high)."""
+    from flinkml_tpu.parallel import mesh
+
+    monkeypatch.setattr(mesh, "_STAGE_BYTES", 4096)
+    open_spans, dispatched, at_close = [], [], []
+
+    class Span(span):
+        def __enter__(self):
+            open_spans.append(self.name)
+            return super().__enter__()
+
+        def __exit__(self, *exc):
+            if self.name == "trainer.loop":
+                at_close.extend(out[0].is_ready() for _, out in dispatched)
+            assert open_spans.pop() == self.name
+            return super().__exit__(*exc)
+
+    real = _linear_sgd._dense_trainer
+
+    def dense_trainer(*key):
+        trainer = real(*key)
+
+        def run(*args):
+            out = trainer(*args)
+            dispatched.append((list(open_spans), out))
+            return out
+
+        return run
+
+    monkeypatch.setattr(_linear_sgd, "span", Span)
+    monkeypatch.setattr(_linear_sgd, "_dense_trainer", dense_trainer)
+    table = _lr_table()
+
+    class Listener:
+        def on_epoch_watermark_incremented(self, epoch, coef):
+            pass
+
+        def on_iteration_terminated(self, coef):
+            pass
+
+    listeners = (Listener(),) if boundaries == "listeners" else ()
+    _linear_sgd.train_linear_model(
+        table.column("features"), table.column("label"), None, "logistic",
+        DeviceMesh(), 6, 0.5, 256, 0.0, 0.0, 0.0, 7, dtype=np.float32,
+        listeners=listeners)
+    assert len(dispatched) == (1 if listeners else 4)
+    assert all(stack == ["trainer.loop"] for stack, _ in dispatched)
+    assert at_close == [True] * len(dispatched)
 
 
 def test_the_unit_weights_metric_reads_one_a_fit():

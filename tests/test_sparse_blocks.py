@@ -297,9 +297,10 @@ def _prepare(mesh, indptr, indices, dim, values=None):
     before = read()
     if values is None:
         values = np.ones(indices.size, np.float32)
-    data, sizes, plan = _linear_sgd.prepare_sparse_buckets(
+    place, sizes, plan = _linear_sgd.prepare_sparse_buckets(
         indptr, indices, values, dim, np.zeros(n, np.float32), None, mesh, 64,
         seed=0)
+    data = _linear_sgd._placed(place(0, 1))
     assert len(data) == 4 * len(sizes) + bool(plan)
     return plan, data, {k: v - before[k] for k, v in read().items()}
 
@@ -332,11 +333,11 @@ def test_a_field_blocked_table_is_planned_and_counted(mesh):
 def test_another_training_dtype_has_no_plan(mesh):
     indices = _field_rows(8, rows=512)
     rows, width = indices.shape
-    data, sizes, plan = _linear_sgd.prepare_sparse_buckets(
+    place, sizes, plan = _linear_sgd.prepare_sparse_buckets(
         np.arange(rows + 1, dtype=np.int64) * width, indices.reshape(-1),
         np.ones(indices.size), PLAN_DIM, np.zeros(rows), None, mesh, 64,
         dtype=jnp.bfloat16, seed=0)
-    assert plan == () and len(data) == 4
+    assert plan == () and len(_linear_sgd._placed(place(0, 1))) == 4
 
 
 # -- ragged rows ----------------------------------------------------------------
