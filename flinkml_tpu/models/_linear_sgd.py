@@ -56,7 +56,7 @@ from flinkml_tpu.ops.sparse import (
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.parallel.mesh import gather_pool
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import span
+from flinkml_tpu.utils.profiling import named_program, span
 
 _LOSS_KEYS = ("logistic", "hinge", "squared")
 
@@ -298,11 +298,12 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
     return step
 
 
-def _whole_loop(mesh, step, n_sharded: int, axis: str):
+def _whole_loop(mesh, step, n_sharded: int, axis: str, name: str):
     """Carry-style whole-loop trainer around one per-device ``step``: runs
     epochs from ``epoch`` up to ``epoch_end`` (or until ``loss <= tol``)
     entirely on device and returns the full carry ``(coef, epoch, loss)``.
-    The data args are ``n_sharded`` arrays sharded along ``axis``.
+    The data args are ``n_sharded`` arrays sharded along ``axis``; the
+    program's ``name`` is what a profile calls each dispatch of it.
 
     Because the carry and ``epoch_end`` are runtime values, the SAME
     compiled executable serves both the one-dispatch fit (epoch_end =
@@ -332,7 +333,7 @@ def _whole_loop(mesh, step, n_sharded: int, axis: str):
 
     return jax.jit(
         jax.shard_map(
-            per_device,
+            named_program(name, per_device),
             mesh=mesh,
             in_specs=(P(), P(), P()) + (P(axis),) * n_sharded + (P(),) * 5,
             out_specs=(P(), P(), P()),
@@ -343,7 +344,8 @@ def _whole_loop(mesh, step, n_sharded: int, axis: str):
 @functools.lru_cache(maxsize=128)
 def _dense_trainer(mesh, loss: str, local_bs: int, axis: str):
     """The dense whole-loop trainer (:func:`_whole_loop`)."""
-    return _whole_loop(mesh, make_dense_step(loss, local_bs, axis), 3, axis)
+    return _whole_loop(mesh, make_dense_step(loss, local_bs, axis), 3, axis,
+                       "lr_dense_loop")
 
 
 @functools.lru_cache(maxsize=128)
@@ -363,7 +365,8 @@ def _sparse_trainer_bucketed(mesh, loss: str, local_bss: Tuple[int, ...],
     sparse fit had before."""
     step = make_sparse_step_bucketed(loss, local_bss, axis, dim,
                                      segsum_backend, slot_plan)
-    return _whole_loop(mesh, step, 4 * len(local_bss) + bool(slot_plan), axis)
+    return _whole_loop(mesh, step, 4 * len(local_bss) + bool(slot_plan), axis,
+                       "lr_sparse_loop")
 
 
 def _restore_carry(checkpoint_manager, dim: int, dtype, mesh=None):
@@ -518,7 +521,7 @@ def _place_shuffled(x, y, w, mesh: DeviceMesh, seed: int, dtype,
     is computed whole, here, before the first round is asked for. ``w``
     None is unit weights: made on the device
     (:meth:`DeviceMesh.shard_ones`), no host array at all."""
-    with span("hostdata.shuffle"):
+    with span("hostdata.shuffle"), span("hostdata.permute"):
         perm = np.random.default_rng(seed).permutation(x.shape[0])
     _count_unit_weights(w)
     columns = [(x, perm, dtype), (y, perm, dtype)]
@@ -766,7 +769,7 @@ def prepare_sparse_buckets(
         for bucket, rows, local_bs in zip(buckets, row_ids, local_bss):
             bi, bv = bucket["indices"], bucket["values"]
             n_bucket = bi.shape[0]
-            with span("hostdata.shuffle"):
+            with span("hostdata.shuffle"), span("hostdata.permute"):
                 order = (rng.permutation(n_bucket) if rng is not None
                          else np.arange(n_bucket))
                 # The table's rows this bucket's positions hold: where
@@ -904,7 +907,8 @@ def make_softmax_step(num_classes: int, local_bs: int, axis: str):
 def _softmax_trainer(mesh, num_classes: int, local_bs: int, axis: str):
     """The softmax whole-loop trainer (:func:`_whole_loop`)."""
     return _whole_loop(
-        mesh, make_softmax_step(num_classes, local_bs, axis), 3, axis)
+        mesh, make_softmax_step(num_classes, local_bs, axis), 3, axis,
+        "lr_softmax_loop")
 
 
 def train_softmax_model(

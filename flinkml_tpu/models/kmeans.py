@@ -58,7 +58,7 @@ from flinkml_tpu.ops.distance import DistanceMeasure
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import span
+from flinkml_tpu.utils.profiling import named_program, span
 
 #: The precision of a round's two products, a static argument of both
 #: trainers: float32 accuracy, the distance expansion's own
@@ -340,7 +340,7 @@ def _kmeans_trainer(mesh, k: int, axis: str, precision=PRODUCT_PRECISION):
 
     return jax.jit(
         jax.shard_map(
-            per_device,
+            named_program("kmeans_lloyd", per_device),
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(), P()),
             out_specs=P(),

@@ -60,7 +60,7 @@ from flinkml_tpu.ops import blas
 from flinkml_tpu.parallel.mesh import DeviceMesh
 from flinkml_tpu.table import Table
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import span
+from flinkml_tpu.utils.profiling import named_program, span
 
 #: Train rows a tile of the tiled XLA search ranks at once: the [chunk,
 #: tile] float32 distances are that program's one large temporary. Read on a
@@ -155,6 +155,7 @@ class KnnModel(_KnnParams, Model):
                                        classes.astype(np.float64))
         return self._resident
 
+    @span("transform")
     def transform(self, *inputs: Table) -> Tuple[Table, ...]:
         (table,) = inputs
         self._require_model()
@@ -312,6 +313,7 @@ def nearest(queries, train_x, train_sq, k: int, *, chunk: int, tile: int,
 @functools.partial(
     jax.jit,
     static_argnames=("k", "num_classes", "chunk", "tile", "precision"))
+@functools.partial(named_program, "knn_vote")
 def _knn_vote(queries, train_x, train_sq, train_class_ids, *, k: int,
               num_classes: int, chunk: int, tile: int, precision):
     """The ``k`` nearest rows (:func:`nearest`), then a majority vote of

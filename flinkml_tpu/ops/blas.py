@@ -19,8 +19,12 @@ expansion states its product's precision (:data:`DISTANCE_PRECISION`).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from flinkml_tpu.utils.profiling import named_program
 
 Array = jax.Array
 
@@ -76,6 +80,7 @@ def batch_dot(xs, y) -> Array:
 
 
 @jax.jit
+@functools.partial(named_program, "rows_sq")
 def squared_norms(xs) -> Array:
     """Each row's squared L2 norm: [n, d] -> [n]. One program a shape,
     for a caller that keeps a placed table's norms beside it (KNN's model

@@ -24,7 +24,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import span
+from flinkml_tpu.utils.profiling import named_program, span
 
 #: :meth:`DeviceMesh.stage_rows` stages a table through host buffers of
 #: this many bytes a column (all shards' rows of one round together; the
@@ -262,7 +262,8 @@ class DeviceMesh:
                    for x, dt in zip(xs, dts)]
         consumed = [None] * len(stages)
         sharding = self.data_sharding()
-        placed = tuple(jnp.zeros((p * n_local,) + x.shape[1:], dt, device=sharding)
+        zeros = _zero_rows(self.mesh, self.DATA_AXIS)
+        placed = tuple(zeros((p * n_local,) + x.shape[1:], dt)
                        for x, dt in zip(xs, dts))
         counts = metrics.group("hostdata.stage")
         counts.counter("rows", float(p * n_local))
@@ -398,10 +399,25 @@ def _row_writer(mesh: Mesh, axis: str):
     # Stated, not inferred: on a one-device mesh the inferred sharding of
     # a 1-D table among several is P(), another key for the trainer.
     return jax.jit(
-        jax.shard_map(write, mesh=mesh, in_specs=(P(axis), P(axis), P()),
+        jax.shard_map(named_program("stage_write", write), mesh=mesh,
+                      in_specs=(P(axis), P(axis), P()),
                       out_specs=(P(axis), P(axis))),
         donate_argnums=0, out_shardings=NamedSharding(mesh, P(axis)),
     )
+
+
+@functools.lru_cache(maxsize=128)
+def _zero_rows(mesh: Mesh, axis: str):
+    """The zero fill of the arrays :meth:`DeviceMesh.stage_rows` writes
+    its rounds into, built as :func:`_row_writer` is, so that a profile
+    names it (``jnp.zeros`` runs as a ``broadcast_in_dim`` like any
+    other)."""
+
+    def zeros(shape, dtype):
+        return jnp.zeros(shape, dtype)
+
+    return jax.jit(named_program("stage_zeros", zeros), static_argnums=(0, 1),
+                   out_shardings=NamedSharding(mesh, P(axis)))
 
 
 @functools.lru_cache(maxsize=128)
@@ -413,7 +429,7 @@ def _ones_below(mesh: Mesh, axis: str):
     def ones(n, rows, dtype):
         return (jnp.arange(rows) < n).astype(dtype)
 
-    return jax.jit(ones, static_argnums=(1, 2),
+    return jax.jit(named_program("stage_ones", ones), static_argnums=(1, 2),
                    out_shardings=NamedSharding(mesh, P(axis)))
 
 

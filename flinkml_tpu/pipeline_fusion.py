@@ -114,7 +114,7 @@ from flinkml_tpu.api import ColumnKernel
 from flinkml_tpu.linalg import next_pow2
 from flinkml_tpu.table import LazyDeviceColumn, PaddedDeviceColumn, Table
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import span
+from flinkml_tpu.utils.profiling import named_program, span
 
 #: Smallest row bucket: tiny tables all share one program.
 MIN_ROW_BUCKET = 8
@@ -586,13 +586,15 @@ def _chain_backend(kernels, ext_names, out_names, bucket, policy,
 def _build_chain(kernels, ext_names, out_names, bucket, policy,
                  backend: str):
     """The chain callable for ``backend`` — ``_chain_fn`` under XLA,
-    the row-tiled Pallas kernel otherwise (same cols→cols contract)."""
+    the row-tiled Pallas kernel otherwise (same cols→cols contract) —
+    under the name a profile calls the program's runs, ``fused_chain``."""
     if backend == "pallas":
         from flinkml_tpu.kernels.chain import pallas_chain_fn
 
-        return pallas_chain_fn(kernels, ext_names, out_names, bucket,
-                               policy)
-    return _chain_fn(kernels, ext_names, out_names, bucket, policy)
+        chain = pallas_chain_fn(kernels, ext_names, out_names, bucket, policy)
+    else:
+        chain = _chain_fn(kernels, ext_names, out_names, bucket, policy)
+    return named_program("fused_chain", chain)
 
 
 def _placement_ids(ext_vals) -> Tuple[int, ...]:

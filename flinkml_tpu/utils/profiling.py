@@ -5,7 +5,9 @@ metrics and latency markers. Here :func:`span` is the ONLY way the program
 records a span: a host phase that is both a ``jax.profiler``
 ``TraceAnnotation`` (so it lies on the device trace's clock, over the
 chip's ``XLA Ops`` rows) and a few counters in ``metrics.group("span")``
-(so it is read with no profiler at all). :func:`trace` captures a profile
+(so it is read with no profiler at all). :func:`named_program` gives a
+jitted program the name a profile's ``XLA Modules`` row shows for each of
+its runs on the chip. :func:`trace` captures a profile
 and degrades gracefully: if the profiler cannot start (e.g. unsupported
 on the backend) it becomes a no-op rather than failing the training job.
 """
@@ -13,8 +15,9 @@ on the backend) it becomes a no-op rather than failing the training job.
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
-from typing import Iterator
+from typing import Callable, Iterator
 
 import jax
 
@@ -24,6 +27,35 @@ from flinkml_tpu.utils.metrics import metrics
 SPAN_PREFIX = "flinkml:"
 #: The metric group the spans count into.
 SPAN_GROUP = "span"
+
+# The spans open on this thread, outermost first: a span's parent is the
+# one under it.
+_OPEN = threading.local()
+
+try:
+    # The profiler's own activity flag (False with no session, True
+    # between start_trace and stop_trace, whoever started it). Private to
+    # jaxlib, so imported here alone: where a jaxlib lacks it no span
+    # writes a ``traced_*`` counter.
+    from jax._src.lib import _profiler
+
+    _recording = _profiler.TraceMe.is_enabled
+    _recording()
+except Exception:  # noqa: BLE001 — any jaxlib without the flag
+    def _recording() -> bool:
+        return False
+
+
+def named_program(name: str, fn: Callable) -> Callable:
+    """``fn`` under the name ``jax.jit`` takes its module's from
+    (``jit_<name>``: the event of each of its runs on a profile's ``XLA
+    Modules`` row, and the first line of its lowered text). Called on the
+    function a program is built from, before ``shard_map`` or ``jit``
+    wraps it; ``fn`` itself is renamed, so hand it a function built for
+    that one program. The name is part of the compile cache's key.
+    ``docs/development/observability.md`` ("Programs") lists the names."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 @contextlib.contextmanager
@@ -61,14 +93,22 @@ class span(contextlib.ContextDecorator):
       ``/host:CPU``, on the same clock as the device's operations;
       nesting on a thread is the parent link.
     - On exit it adds to ``metrics.group("span")`` the counters
-      ``<name>.seconds`` (host ``perf_counter``), ``<name>.calls``, one
+      ``<name>.seconds`` (host ``perf_counter``), ``<name>.self_seconds``
+      (its seconds less those of the spans that closed directly under it
+      on this thread: over a tree of spans on one thread the self seconds
+      add up to the root's seconds), ``<name>.calls``, one
       ``<name>.<key>`` per count passed here or through :meth:`add`
       inside the block, and ``<name>.errors`` if an exception closed it
       (the exception propagates).
+    - Where a profiler was recording when it opened or closed, it adds
+      the same three again as ``<name>.traced_seconds``,
+      ``.traced_self_seconds`` and ``.traced_calls``: ``seconds`` less
+      ``traced_seconds`` is the time of the spans no profiler saw.
 
     Always on: no flag, no level. With no profiler running a span costs
-    two clock reads, one ``TraceMe`` activity check and one locked add
-    per counter.
+    two clock reads, a push and a pop of this thread's list of open
+    spans, one ``TraceMe`` activity check, two reads of the profiler's
+    flag and one locked add per counter.
     It adds NO synchronisation: where the enclosed call returns before
     its device work is done (an asynchronous upload or dispatch), the
     span ends when the host was released, not when the chip was.
@@ -82,6 +122,8 @@ class span(contextlib.ContextDecorator):
         self._counts = counts
         self._annotation = None
         self._t0 = 0.0
+        self._children = 0.0
+        self._traced = False
 
     def _recreate_cm(self) -> "span":
         # As a decorator every call gets its own instance, so recursive
@@ -96,6 +138,12 @@ class span(contextlib.ContextDecorator):
             self._counts[key] = self._counts.get(key, 0.0) + value
 
     def __enter__(self) -> "span":
+        try:
+            _OPEN.spans.append(self)
+        except AttributeError:
+            _OPEN.spans = [self]
+        self._children = 0.0
+        self._traced = _recording()
         self._annotation = jax.profiler.TraceAnnotation(SPAN_PREFIX + self.name)
         self._annotation.__enter__()
         self._t0 = time.perf_counter()
@@ -104,9 +152,19 @@ class span(contextlib.ContextDecorator):
     def __exit__(self, exc_type, exc, tb) -> None:
         seconds = time.perf_counter() - self._t0
         self._annotation.__exit__(exc_type, exc, tb)
+        open_spans = _OPEN.spans
+        open_spans.pop()
+        if open_spans:
+            open_spans[-1]._children += seconds
+        own = seconds - self._children
         group, name = metrics.group(SPAN_GROUP), self.name
         group.counter(f"{name}.seconds", seconds)
+        group.counter(f"{name}.self_seconds", own)
         group.counter(f"{name}.calls")
+        if self._traced or _recording():
+            group.counter(f"{name}.traced_seconds", seconds)
+            group.counter(f"{name}.traced_self_seconds", own)
+            group.counter(f"{name}.traced_calls")
         for key, value in self._counts.items():
             group.counter(f"{name}.{key}", float(value))
         if exc_type is not None:

@@ -348,11 +348,13 @@ def test_the_sparse_fit_has_its_spans_and_counters(uniform):
     assert added["cells"] == column.indices.size
     assert (added["padded_cells"] == added["cells"] if uniform
             else added["padded_cells"] > added["cells"])
-    # A bucket: its permutation, then ONE staging round (wait, gather,
-    # place) of its three arrays in lockstep: indices, values, labels. No
-    # weight column: the unit weights are made on the device, under no span.
+    # A bucket: its permutation (``hostdata.permute`` inside a
+    # ``hostdata.shuffle``), then ONE staging round (wait, gather, place)
+    # of its three arrays in lockstep: indices, values, labels. No weight
+    # column: the unit weights are made on the device, under no span.
     assert calls == {"fit": 1, "hostdata.ingest": 1, "hostdata.sparse_pack": 1,
                      "hostdata.shuffle": 2 * buckets,
+                     "hostdata.permute": buckets,
                      "hostdata.stage_wait": buckets,
                      "mesh.shard_batch": buckets,
                      "trainer.loop": 1, "trainer.readback": 1}

@@ -24,12 +24,16 @@ from flinkml_tpu.utils import metrics, profiling
 from flinkml_tpu.utils.profiling import span
 
 # A table of one staging round (DeviceMesh.stage_rows places a fit's
-# columns in lockstep): the permutation, then the round's wait, gather and
-# placement. The names are the parent's (2ba32a5); with no weight column
-# the weights are made on the device and open no span.
+# columns in lockstep): the permutation (``hostdata.permute`` inside a
+# ``hostdata.shuffle`` of its own), then the round's wait, gather and
+# placement. With no weight column the weights are made on the device and
+# open no span.
 FIT_SPANS = {"fit": 1, "hostdata.ingest": 1, "hostdata.shuffle": 2,
+             "hostdata.permute": 1,
              "hostdata.stage_wait": 1, "mesh.shard_batch": 1,
              "trainer.loop": 1, "trainer.readback": 1}
+#: What every span adds with no profiler recording.
+PLAIN_FIELDS = ("seconds", "self_seconds", "calls")
 #: The rounds' spans, which ``trainer.loop`` holds (PR 33).
 ROUND_SPANS = ("hostdata.stage_wait", "hostdata.shuffle", "mesh.shard_batch")
 
@@ -191,9 +195,12 @@ def test_fit_produces_each_fit_span_once(monkeypatch, stage_bytes, weight_col):
     assert top <= d["fit.seconds"]
     assert (d["hostdata.stage_wait.seconds"] + d["mesh.shard_batch.seconds"]
             <= d["trainer.loop.seconds"])
-    # seconds and calls apiece, and the one count a metric reads: a name
-    # nothing reads is not added, and no name the parent did not have
-    assert set(d) == ({f"{s}.{c}" for s in FIT_SPANS for c in ("seconds", "calls")}
+    # The permutation is all of the hostdata.shuffle it sits in, so the
+    # gather is that span's self time.
+    assert d["hostdata.permute.seconds"] <= d["hostdata.shuffle.seconds"]
+    # seconds, self seconds and calls apiece (no profiler: no traced_*),
+    # and the one count a metric reads: a name nothing reads is not added
+    assert set(d) == ({f"{s}.{c}" for s in FIT_SPANS for c in PLAIN_FIELDS}
                       | {"mesh.shard_batch.bytes"})
 
 
@@ -233,8 +240,13 @@ def test_the_loop_holds_the_rounds_spans_and_the_rest_are_siblings(
     inside = [ph for ph in phases if loop[0] <= ph[0] and ph[1] <= loop[1]
               and ph[2] != "trainer.loop"]
     outside = [ph for ph in phases if ph not in inside]
-    assert [n for _, _, n in outside] == [
+    # (the permutation's own span starts inside its hostdata.shuffle)
+    assert [n for _, _, n in outside if n != "hostdata.permute"] == [
         "hostdata.ingest", "hostdata.shuffle", "trainer.loop", "trainer.readback"]
+    (permute,) = [ph for ph in outside if ph[2] == "hostdata.permute"]
+    shuffle = next(ph for ph in outside if ph[2] == "hostdata.shuffle")
+    assert shuffle[0] <= permute[0] <= permute[1] <= shuffle[1]
+    outside.remove(permute)
     rounds, n_local = len(inside) // 3, -(-1003 // len(jax.devices()))
     width = jax.dtypes.canonicalize_dtype(np.float64).itemsize
     assert rounds == (1 if stage_bytes is None else -(-n_local // (
@@ -360,7 +372,7 @@ def test_transform_produces_each_transform_span_once():
     assert _calls(d) == {"transform": 1, "table.to_device": 1,
                          "fusion.constants": 1, "fusion.dispatch": 1,
                          "table.to_host": 1}
-    assert all(k.endswith((".seconds", ".calls")) for k in d)
+    assert all(k.endswith((".seconds", ".self_seconds", ".calls")) for k in d)
     # the bytes either way stay where they were counted before the spans
     assert fusion["host_to_device_bytes"] == 600 * 5 * 4
     assert tab["device_to_host_bytes"] == 600 * 8
@@ -426,8 +438,335 @@ def test_spans_change_no_result(monkeypatch):
             coef0 = _fit(table)
             out0, pred0 = _score(model, Table({"features": rows.column("features")}))
             raw0 = np.asarray(out0.column("rawPrediction"))
-        assert d == {}  # the parent's path really ran: no span counted
+        assert d == {}  # no span counted: no seconds, no self or traced_* field
     _linear_sgd._dense_trainer.cache_clear()
     pipeline_fusion.reset_cache()
     assert coef.tobytes() == coef0.tobytes()
     assert pred.tobytes() == pred0.tobytes() and raw.tobytes() == raw0.tobytes()
+
+
+# -- the span tree: parent links as self time, the profiler's flag ---------
+
+def _burn(n=20000):
+    return sum(range(n))
+
+
+def _self_sum(d):
+    return sum(v for k, v in d.items() if k.endswith(".self_seconds")
+               and not k.endswith(".traced_self_seconds"))
+
+
+def test_self_seconds_of_a_three_level_tree_sum_to_the_roots_seconds():
+    with _delta() as d:
+        with span("t.tree.root"):
+            _burn()
+            with span("t.tree.mid"):
+                _burn()
+                with span("t.tree.leaf"):
+                    _burn()
+    assert d["t.tree.leaf.self_seconds"] == d["t.tree.leaf.seconds"]
+    assert d["t.tree.mid.self_seconds"] == pytest.approx(
+        d["t.tree.mid.seconds"] - d["t.tree.leaf.seconds"], abs=1e-12)
+    # only the DIRECT child is taken from the root, not the grandchild again
+    assert d["t.tree.root.self_seconds"] == pytest.approx(
+        d["t.tree.root.seconds"] - d["t.tree.mid.seconds"], abs=1e-12)
+    assert all(d[f"t.tree.{n}.self_seconds"] > 0 for n in ("root", "mid", "leaf"))
+    assert _self_sum(d) == pytest.approx(d["t.tree.root.seconds"], abs=1e-12)
+
+
+def test_siblings_and_repeated_children_all_come_off_the_parent():
+    with _delta() as d:
+        with span("t.sib.root"):
+            for _ in range(3):
+                with span("t.sib.a"):
+                    _burn()
+            with span("t.sib.b"):
+                with span("t.sib.a"):  # the same name one level further down
+                    _burn()
+    assert d["t.sib.a.calls"] == 4 and d["t.sib.b.calls"] == 1
+    assert 0 < d["t.sib.root.self_seconds"] < d["t.sib.root.seconds"]
+    assert 0 < d["t.sib.b.self_seconds"] < d["t.sib.b.seconds"]
+    assert d["t.sib.a.self_seconds"] == d["t.sib.a.seconds"]
+    assert _self_sum(d) == pytest.approx(d["t.sib.root.seconds"], abs=1e-12)
+
+
+def test_a_child_on_another_thread_is_not_taken_from_this_threads_parent():
+    def child():
+        with span("t.thread.child"):
+            _burn(200000)
+
+    with _delta() as d:
+        with span("t.thread.parent"):
+            worker = threading.Thread(target=child)
+            worker.start()
+            worker.join(timeout=60)
+    assert d["t.thread.child.seconds"] > 0
+    # the worker's span is a root of its own thread's tree
+    assert d["t.thread.child.self_seconds"] == d["t.thread.child.seconds"]
+    assert d["t.thread.parent.self_seconds"] == d["t.thread.parent.seconds"]
+    assert d["t.thread.parent.seconds"] >= d["t.thread.child.seconds"]
+
+
+def test_an_exception_in_a_child_pops_it_and_the_parents_self_time_is_right():
+    with _delta() as d:
+        with span("t.exc.root"):
+            with pytest.raises(KeyError):
+                with span("t.exc.child"):
+                    _burn()
+                    raise KeyError("boom")
+            with span("t.exc.after"):  # a sibling, not a child of the dead one
+                _burn()
+    assert d["t.exc.child.errors"] == 1 and "t.exc.root.errors" not in d
+    assert d["t.exc.after.self_seconds"] == d["t.exc.after.seconds"]
+    assert d["t.exc.child.self_seconds"] == d["t.exc.child.seconds"]
+    assert d["t.exc.root.self_seconds"] == pytest.approx(
+        d["t.exc.root.seconds"] - d["t.exc.child.seconds"]
+        - d["t.exc.after.seconds"], abs=1e-12)
+    assert profiling._OPEN.spans == []
+
+
+def test_decorated_and_recursive_spans_keep_the_tree():
+    @span("t.rec")
+    def depth(n):
+        _burn(2000)
+        return 0 if n == 0 else 1 + depth(n - 1)
+
+    with _delta() as d:
+        with span("t.rec.root"):
+            assert depth(3) == 3
+    assert d["t.rec.calls"] == 4
+    # each level's seconds hold the levels under it; their self seconds do not
+    assert d["t.rec.self_seconds"] < d["t.rec.seconds"]
+    assert _self_sum(d) == pytest.approx(d["t.rec.root.seconds"], abs=1e-12)
+    assert d["t.rec.root.self_seconds"] > 0
+
+
+def test_traced_fields_count_only_the_spans_a_profiler_saw(tmp_path):
+    def tree():
+        with span("t.traced.root"):
+            with span("t.traced.child"):
+                _burn()
+
+    with _delta() as plain:
+        tree()
+    assert not any(".traced_" in k for k in plain)
+    with _delta() as d:
+        tree()
+        with _delta() as seen:
+            with profiling.trace(str(tmp_path), ignore_errors=False):
+                tree()
+                tree()
+        with _delta() as after:
+            tree()
+    assert not any(".traced_" in k for k in after)  # stop_trace ends it
+    for name in ("t.traced.root", "t.traced.child"):
+        assert d[f"{name}.calls"] == 4 and d[f"{name}.traced_calls"] == 2
+        for field in ("seconds", "self_seconds"):
+            assert seen[f"{name}.traced_{field}"] == seen[f"{name}.{field}"]
+            # what is left when the traced spans are taken off: the plain ones'
+            assert d[f"{name}.{field}"] - d[f"{name}.traced_{field}"] == pytest.approx(
+                d[f"{name}.{field}"] - seen[f"{name}.{field}"], abs=1e-12)
+    assert d["t.traced.root.traced_self_seconds"] == pytest.approx(
+        d["t.traced.root.traced_seconds"] - d["t.traced.child.traced_seconds"],
+        abs=1e-12)
+
+
+def test_a_span_open_when_the_profiler_starts_or_stops_counts_as_traced(tmp_path):
+    with _delta() as d:
+        with span("t.edge.opens"):
+            jax.profiler.start_trace(str(tmp_path))
+        with span("t.edge.closes"):
+            jax.profiler.stop_trace()
+        with span("t.edge.later"):
+            pass
+    assert d["t.edge.opens.traced_calls"] == 1
+    assert d["t.edge.closes.traced_calls"] == 1
+    assert "t.edge.later.traced_calls" not in d
+
+
+def test_a_jaxlib_without_the_flag_writes_no_traced_field(monkeypatch, tmp_path):
+    monkeypatch.setattr(profiling, "_recording", lambda: False)
+    with _delta() as d:
+        with profiling.trace(str(tmp_path), ignore_errors=False):
+            with span("t.noflag"):
+                pass
+    assert set(d) == {f"t.noflag.{f}" for f in PLAIN_FIELDS}
+
+
+def _sparse_table(rows=1003, fields=6, stratum=64, seed=0):
+    from flinkml_tpu.table import CsrColumn
+
+    rng = np.random.default_rng(seed)
+    indices = (rng.integers(0, stratum, (rows, fields))
+               + np.arange(fields) * stratum).astype(np.int32).reshape(-1)
+    indptr = (np.arange(rows + 1) * fields).astype(np.int64)
+    values = rng.normal(size=indices.size).astype(np.float32)
+    return Table({"features": CsrColumn(indptr, indices, values, fields * stratum),
+                  "label": rng.integers(0, 2, rows).astype(np.float32)})
+
+
+@pytest.mark.parametrize("kind", ["dense", "sparse"])
+@pytest.mark.parametrize("stage_bytes", [None, 4096], ids=["one-round", "many-rounds"])
+def test_a_whole_fits_tree_adds_up_to_the_fit(monkeypatch, kind, stage_bytes):
+    """The fit's thread opens every span of a fit, so their self seconds
+    are a split of ``fit.seconds``: nothing counted twice (the rounds lie
+    inside ``trainer.loop``), nothing negative."""
+    from flinkml_tpu.parallel import mesh
+
+    if stage_bytes is not None:
+        monkeypatch.setattr(mesh, "_STAGE_BYTES", stage_bytes)
+    table = _lr_table() if kind == "dense" else _sparse_table()
+    _fit(table)  # compiled first
+    with _delta() as d:
+        _fit(table)
+    names = {k[:-len(".calls")] for k in d if k.endswith(".calls")}
+    assert names >= set(FIT_SPANS) and (kind == "dense") == (
+        "hostdata.sparse_pack" not in names)
+    assert all(d[f"{n}.self_seconds"] >= 0 for n in names)
+    assert d["fit.self_seconds"] > 0
+    assert _self_sum(d) == pytest.approx(d["fit.seconds"], rel=1e-9)
+    # one permutation a fit (one a bucket), inside a hostdata.shuffle
+    assert d["hostdata.permute.calls"] == 1
+    assert d["hostdata.shuffle.calls"] == 1 + d["hostdata.stage_wait.calls"]
+    assert d["hostdata.shuffle.seconds"] >= d["hostdata.permute.seconds"]
+    assert d["hostdata.shuffle.self_seconds"] == pytest.approx(
+        d["hostdata.shuffle.seconds"] - d["hostdata.permute.seconds"], abs=1e-12)
+    # the loop's own time: what the rounds' three spans leave of it
+    assert 0 < d["trainer.loop.self_seconds"] <= (
+        d["trainer.loop.seconds"] - d["hostdata.stage_wait.seconds"]
+        - d["mesh.shard_batch.seconds"])
+
+
+def _struct(shape, dtype, sharding=None):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _lowered_programs():
+    """name -> a function that lowers that program of the hot path (the
+    table "Programs" of docs/development/observability.md) to its text."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flinkml_tpu.models import kmeans, knn
+    from flinkml_tpu.ops import blas
+    from flinkml_tpu.parallel import mesh as mesh_mod
+
+    def m():
+        return DeviceMesh().mesh
+
+    def shardings():
+        return NamedSharding(m(), P()), NamedSharding(m(), P("data"))
+
+    f32, i32 = jnp.float32, jnp.int32
+
+    def carry_and_tail(coef_shape):
+        rep, _ = shardings()
+        return ([_struct(coef_shape, f32, rep), _struct((), i32, rep),
+                 _struct((), f32, rep)],
+                [_struct((), f32, rep)] * 4 + [_struct((), i32, rep)])
+
+    def dense(trainer, coef_shape):
+        _, rows = shardings()
+        head, tail = carry_and_tail(coef_shape)
+        data = [_struct((64, 5), f32, rows), _struct((64,), f32, rows),
+                _struct((64,), f32, rows)]
+        return trainer.lower(*head, *data, *tail).as_text()
+
+    def sparse():
+        _, rows = shardings()
+        head, tail = carry_and_tail((300,))
+        data = [_struct((128, 7), i32, rows), _struct((128, 7), f32, rows),
+                _struct((128,), f32, rows), _struct((128,), f32, rows)]
+        trainer = _linear_sgd._sparse_trainer_bucketed(
+            m(), "logistic", (8,), "data", 300, "xla")
+        return trainer.lower(*head, *data, *tail).as_text()
+
+    def stage_write():
+        _, rows = shardings()
+        tables = (_struct((64, 5), f32, rows), _struct((64,), f32, rows))
+        blocks = (_struct((16, 5), f32, rows), _struct((16,), f32, rows))
+        return mesh_mod._row_writer(m(), "data").lower(
+            tables, blocks, _struct((), i32)).as_text()
+
+    def kmeans_lloyd():
+        rep, rows = shardings()
+        return kmeans._kmeans_trainer(m(), 3, "data").lower(
+            _struct((64, 5), f32, rows), _struct((64,), f32, rows),
+            _struct((64,), f32, rows), _struct((3, 5), f32, rep),
+            _struct((), i32, rep)).as_text()
+
+    def knn_vote():
+        return knn._knn_vote.lower(
+            _struct((16, 5), f32), _struct((64, 5), f32), _struct((64,), f32),
+            _struct((64,), i32), k=3, num_classes=2, chunk=16, tile=64,
+            precision=knn.PRODUCT_PRECISION).as_text()
+
+    def fused_chain():
+        seen = []
+        real = pipeline_fusion._run_program
+
+        def run(kernels, ext, outs, ext_specs, const_specs, ext_vals,
+                const_vals, bucket, n, policy=None):
+            seen.append((kernels, ext, outs, ext_vals, const_vals, bucket, n, policy))
+            return real(kernels, ext, outs, ext_specs, const_specs, ext_vals,
+                        const_vals, bucket, n, policy)
+
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(pipeline_fusion, "_run_program", run)
+            _score(_chain(), Table({"features": _lr_table(rows=64).column("features")}))
+        kernels, ext, outs, ext_vals, const_vals, bucket, n, policy = seen[0]
+        with jax.enable_x64(True):
+            return jax.jit(pipeline_fusion._build_chain(
+                kernels, ext, outs, bucket, policy, "xla")).lower(
+                    tuple(ext_vals), const_vals, np.int32(n)).as_text()
+
+    return {
+        "lr_dense_loop": lambda: dense(
+            _linear_sgd._dense_trainer(m(), "logistic", 8, "data"), (5,)),
+        "lr_sparse_loop": sparse,
+        "lr_softmax_loop": lambda: dense(
+            _linear_sgd._softmax_trainer(m(), 3, 8, "data"), (3, 5)),
+        "stage_write": stage_write,
+        "stage_zeros": lambda: mesh_mod._zero_rows(m(), "data").lower(
+            (64, 5), np.dtype(np.float32)).as_text(),
+        "stage_ones": lambda: mesh_mod._ones_below(m(), "data").lower(
+            _struct((), i32), 64, np.dtype(np.float32)).as_text(),
+        "kmeans_lloyd": kmeans_lloyd,
+        "knn_vote": knn_vote,
+        "rows_sq": lambda: blas.squared_norms.lower(_struct((64, 5), f32)).as_text(),
+        "fused_chain": fused_chain,
+    }
+
+
+PROGRAMS = ("lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop", "stage_write",
+            "stage_zeros", "stage_ones", "kmeans_lloyd", "knn_vote", "rows_sq",
+            "fused_chain")
+
+
+@pytest.mark.parametrize("name", PROGRAMS)
+def test_a_named_programs_module_carries_its_name(name):
+    """What a profile's ``XLA Modules`` row calls each run of the
+    program: ``jit_<name>``, the lowered module's own name."""
+    text = _lowered_programs()[name]()
+    assert text.splitlines()[0].startswith(f"module @jit_{name} ")
+
+
+def test_the_docs_programs_table_lists_exactly_the_named_programs():
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "docs", "development", "observability.md")) as f:
+        doc = f.read()
+    table = doc[doc.index("### Programs"):]
+    table = table[:table.index("\n## ")] if "\n## " in table else table
+    listed = set(re.findall(r"^\| `([a-z_]+)` \|", table, flags=re.M))
+    assert listed == set(PROGRAMS)
+    named = set()
+    for dirpath, _, files in os.walk(os.path.join(root, "flinkml_tpu")):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(dirpath, name)) as f:
+                    named |= set(re.findall(
+                        r"named_program[,(]\s*\"([a-z_]+)\"", f.read()))
+    # _whole_loop's three callers hand their names down to its one call
+    assert named | {"lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop"} == set(PROGRAMS)
