@@ -4,21 +4,41 @@ ONE kernel, so a block of distances never leaves fast memory.
 The tiled XLA search (:func:`flinkml_tpu.models.knn.nearest`) writes
 every ``[chunk, tile]`` block of squared distances to HBM and the top-k
 kernel reads it back to keep ``k`` of each row: 81 GB a call at the KNN
-cell's size. Here the grid runs over (query block, train block), the
-train blocks innermost and in ascending row order; a step forms its
-``[bq, bt]`` block ``‖q‖² − 2 q·x + ‖x‖²`` (clamped at 0) in VMEM from a
-``[bq, d] @ [d, bt]`` product at the caller's precision, and ranks it
-there against the query block's running ``k`` best, which live in the
-output blocks (revisited along the train axis, written back once):
+cell's size. Here the kernel runs once a chunk of query blocks, its grid
+over (train block, query block of the chunk): the train blocks in
+ascending row order, and INSIDE each the chunk's query blocks, whose
+running ``k`` best (the one output block, written back once) stay in
+fast memory all the while. A step forms its ``[bq, bt]`` block ``‖q‖² −
+2 q·x + ‖x‖²`` (clamped at 0) in VMEM and ranks it there against its
+query block's running ``k`` best.
+
+*The product* is the kernel's own (PR 35). A float32 ``v`` is exactly
+three bfloat16 parts, ``hi + mid + lo`` (:func:`_bf16_parts`), and the
+float32 product ``q·x`` at ``Precision.HIGHEST`` is the six bfloat16
+products a six-pass contraction makes (``q_lo·x_hi``, ``q_mid·x_mid``,
+``q_hi·x_lo``, ``q_mid·x_hi``, ``q_hi·x_mid``, ``q_hi·x_hi``: each exact
+in float32, summed in float32; the three it drops are under 2⁻²⁴ of the
+product). They share their shapes, so they are ONE bfloat16 ``dot``: the
+queries' parts side by side along the lanes (made once a chunk, outside
+the kernel), the train block's parts one under the other along the
+sublanes, the small terms first. At ``d`` 784 that is a contraction of
+6 × 784 = 4,704, 37 MXU tiles of 128 where six contractions of 784 take
+42. A train block's parts are made at its first query block, once for
+all the query blocks of the chunk, into a VMEM scratch: the train set
+stays the float32 rows the model holds. ``Precision.DEFAULT`` is one
+pass, the ``hi`` parts alone (what the benchmark's control runs); any
+other precision is ``jnp.dot``'s on the float32 blocks.
+
+*The ranking*, a block at a time:
 
   - *the screen*: each row's block minimum against its current ``k``-th
     best distance. A row group (8 rows, one sublane group) with no entry
-    STRICTLY under its rows' ``k``-th distances is done: train blocks
-    come in ascending row order and ties go to the lower row, so an
-    entry equal to the ``k``-th best belongs to a higher row and stays
-    out. In a stream in no particular order the chance that block ``i``
-    holds an entrant for a row is ≈ k / i: after the first few blocks
-    almost every group is done here.
+    STRICTLY under its rows' ``k``-th distances is done: a query block
+    meets the train blocks in ascending row order and ties go to the
+    lower row, so an entry equal to the ``k``-th best belongs to a higher
+    row and stays out. In a stream in no particular order the chance
+    that block ``i`` holds an entrant for a row is ≈ k / i: after the
+    first few blocks almost every group is done here.
   - *the passes*: the groups that hold an entrant are listed, and taken
     :data:`WAYS` at a time through masked passes, one for each entrant
     and no more: the rows' minimum and its FIRST column, inserted into
@@ -46,25 +66,43 @@ GROUP = 8
 #: Lanes of a vreg: a row's running best lie along one vreg's lanes, so
 #: ``k`` is at most this.
 LANES = 128
-#: Most query rows a block holds, most train rows a block holds (a
-#: multiple of :data:`LANES`; the unit the screen decides on), and the
-#: row groups whose passes run side by side. Read on a v5e at 10,000
-#: queries against 2,025,000 x 784 rows, k 5 (PERF.md §5, PR 31; s a
-#: call): 504 x 2,048 -> 1.535, 1,000 x 2,048 -> 1.407 (the product
-#: splits its train block into bfloat16 parts once a step, whatever the
-#: query rows that share it), 1,000 x 4,096 -> 1.345, 1,672 x 4,096 ->
-#: 1.208 at half as much again to compile; passes one group at a time
-#: 121 ms of a call, two 68, four 39, eight 34.
-QUERY_BLOCK = 1024
-TRAIN_BLOCK = 4096
+#: Most query rows a block holds, most train rows a block holds (whole
+#: :data:`SPLIT_LANES`; the unit the screen decides on), and the row
+#: groups whose passes run side by side. Read on a v5e at 10,000 queries
+#: against 2,025,000 x 784 rows, k 5 (PERF.md §5; s a call). PR 31, the
+#: product Mosaic's ``fp32`` ``dot``, a train block split by it at every
+#: step: 1,000 x 2,048 -> 1.407, 1,000 x 4,096 -> 1.226. PR 35, the
+#: kernel's own parts: 1,000 x 1,024 -> 1.235, 1,000 x 2,048 -> 1.128,
+#: 1,000 x 4,096 -> 1.094, 2,000 x 2,048 -> 1.081, 1,672 x 2,048 -> 1.096
+#: (more query rows a block keep the MXU fuller, more train rows are
+#: fewer grid steps; neither the split, once a train block, nor the
+#: queries' parts streamed at every step shows: 0.0002 and 0.002 s a
+#: call; the two before the last need 116 and 120 of the
+#: chip's 128 MiB, 1,672 x 2,048 under 100, and compiles in 24 s for
+#: their 35-45). Passes one group at a time 121 ms of a call, two 68,
+#: four 39, eight 34 (PR 31).
+QUERY_BLOCK = 1672
+TRAIN_BLOCK = 2048
 WAYS = 4
-#: Widest rows the kernel takes: two [dim, TRAIN_BLOCK] train blocks (the
-#: pipeline's), two query blocks and two [QUERY_BLOCK, TRAIN_BLOCK]
-#: blocks of distances have to fit :data:`VMEM_LIMIT_BYTES`.
+#: Most query blocks a chunk. A chunk is the kernel once: its queries'
+#: parts are made for it (10,032 x 4,736 bfloat16, 95 MB), a train block
+#: is split into its parts once for all its query blocks, and its running
+#: best stay in fast memory (10,032 x 128 float32 and int32: 10.3 MB, two
+#: buffers each). 10,000 queries are six blocks of 1,672.
+QUERY_BLOCKS = 6
+#: Sublanes of a bfloat16 vreg: a part's rows come up to whole tiles.
+PART_ROWS = 16
+#: Lanes of a sublane tile of train rows split at once: eight float32
+#: vregs, whose three parts and what is left between them stay in
+#: registers (the whole block at once, through fast memory: + 23 ms a call).
+SPLIT_LANES = 512
+#: Widest rows the kernel takes: :func:`train_block_rows` still finds
+#: them train blocks of 1,536 rows.
 MAX_DIM = 1024
 #: Fast memory the kernel may use: a v5e has 128 MiB, the compiler's own
-#: limit is 16. At 784-wide rows the blocks take ≈ 75 MiB.
-VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+#: limit is 16. :func:`train_block_rows` counts 108 MiB at the cell's
+#: blocks (Mosaic took under 100).
+VMEM_LIMIT_BYTES = 112 * 1024 * 1024
 
 
 def unsupported_reason(queries, train_x, k: int) -> Optional[str]:
@@ -129,27 +167,90 @@ def _entrants(low, best_d, k: int):
     return jnp.max(jnp.where(low < best_d[:, k - 1:k], 1, 0))
 
 
+def _bf16_parts(v, *, in_kernel: bool):
+    """``(hi, mid, lo)``, bfloat16, of a float32 ``v``: ``hi`` is ``v``
+    rounded, ``mid`` what is left of it rounded, ``lo`` what is left then;
+    three times 8 bits of mantissa hold float32's 24, so in float32
+    ``hi + mid + lo`` is ``v`` again, bit for bit.
+
+    Outside a kernel the roundings are ``lax.reduce_precision``: inside
+    one fusion XLA keeps a bfloat16 value it has just made at float32
+    ("excess precision"), so ``rest - float32(bfloat16(rest))`` came out
+    0 on a v5e and ``lo`` with it (PERF.md §6, PR 35). Mosaic lowers the
+    casts alone, and keeps them."""
+    import jax
+    import jax.numpy as jnp
+
+    def rounded(a):
+        """``a`` at bfloat16's precision, as bfloat16 and as float32."""
+        if in_kernel:
+            low = a.astype(jnp.bfloat16)
+            return low, low.astype(jnp.float32)
+        a = jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+        return a.astype(jnp.bfloat16), a
+
+    hi, hi_of = rounded(v)
+    mid, mid_of = rounded(v - hi_of)
+    return hi, mid, ((v - hi_of) - mid_of).astype(jnp.bfloat16)
+
+
+def _products(precision):
+    """The (query part, train part) pairs of :func:`_bf16_parts` whose
+    bfloat16 products, each exact in float32 and summed in float32, are
+    the product at ``precision``; the small terms first. ``HIGHEST`` is
+    the six XLA's and Mosaic's ``fp32`` contraction make (the three it
+    drops are under 2^-24 of the product), ``DEFAULT`` the one pass over
+    both operands rounded to bfloat16; None = neither, ``jnp.dot``'s."""
+    import jax
+
+    if precision == jax.lax.Precision.HIGHEST:
+        return ((2, 0), (1, 1), (0, 2), (1, 0), (0, 1), (0, 0))
+    if precision is None or precision == jax.lax.Precision.DEFAULT:
+        return ((0, 0),)
+    return None
+
+
+def splits_the_product(precision) -> bool:
+    """Whether the kernel makes the product at ``precision`` from several
+    bfloat16 parts of its own (``HIGHEST``): not the one pass, not
+    ``jnp.dot``'s product."""
+    return len(_products(precision) or ()) > 1
+
+
+def _stacked_width(dim: int, products) -> Tuple[int, int]:
+    """``(rows a part takes, length of the one contraction)``: a part's
+    ``dim`` rows up to whole bfloat16 sublane tiles, the parts of all
+    ``products`` end to end, up to whole MXU tiles."""
+    part = -(-dim // PART_ROWS) * PART_ROWS
+    return part, -(-len(products) * part // LANES) * LANES
+
+
 def _search_body(q_ref, qsq_ref, xt_ref, xsq_ref, best_d_ref, best_r_ref,
-                 d2_ref, low_ref, todo_ref, *, k: int, n_train: int, precision):
+                 parts_ref, d2_ref, low_ref, todo_ref, *, k: int, dim: int,
+                 n_train: int, precision):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(1)
+    j, i = pl.program_id(0), pl.program_id(1)
     bq, bt = d2_ref.shape
+    # This query block's running best, in the chunk's resident ones.
+    first = pl.multiple_of(i * bq, GROUP)
+    mine = pl.ds(first, bq)
 
     @pl.when(j == 0)
     def _():
-        best_d_ref[...] = jnp.full(best_d_ref.shape, jnp.inf, jnp.float32)
-        best_r_ref[...] = jnp.zeros(best_r_ref.shape, jnp.int32)
+        best_d_ref[mine, :] = jnp.full((bq, LANES), jnp.inf, jnp.float32)
+        best_r_ref[mine, :] = jnp.zeros((bq, LANES), jnp.int32)
 
     def rank_groups(t, _):
         """``WAYS`` listed row groups at once: their passes are chains of
         lane reductions, each waiting for the last, and run side by side."""
-        rows = [pl.ds(pl.multiple_of(todo_ref[t * WAYS + w] * GROUP, GROUP), GROUP)
-                for w in range(WAYS)]
-        state = [(d2_ref[r, :], low_ref[r, :], best_d_ref[r, :], best_r_ref[r, :])
-                 for r in rows]
+        groups = [pl.multiple_of(todo_ref[t * WAYS + w] * GROUP, GROUP)
+                  for w in range(WAYS)]
+        rows = [(pl.ds(g, GROUP), pl.ds(first + g, GROUP)) for g in groups]
+        state = [(d2_ref[r, :], low_ref[r, :], best_d_ref[b, :], best_r_ref[b, :])
+                 for r, b in rows]
 
         def passes(carry):
             state, _ = carry
@@ -160,19 +261,52 @@ def _search_body(q_ref, qsq_ref, xt_ref, xsq_ref, best_d_ref, best_r_ref,
         # A listed group holds an entrant: the first pass needs no asking.
         state, _ = jax.lax.while_loop(lambda c: c[1] > 0, passes,
                                       (state, jnp.int32(1)))
-        for r, (_, _, best_d, best_r) in zip(rows, state):
-            best_d_ref[r, :] = best_d
-            best_r_ref[r, :] = best_r
+        for (_, b), (_, _, best_d, best_r) in zip(rows, state):
+            best_d_ref[b, :] = best_d
+            best_r_ref[b, :] = best_r
         return 0
 
+    products = _products(precision)
+    if products is None:
+        product = jnp.dot(q_ref[...], xt_ref[...], precision=precision,
+                          preferred_element_type=jnp.float32)
+    else:
+        part = xt_ref.shape[0]   # dim, up to whole sublane tiles
+
+        @pl.when(i == 0)
+        def _():
+            # The train block's parts, once for all the query blocks that
+            # follow: each product's train part under the one before, whole
+            # sublane tiles each, zeros under the last up to whole MXU tiles.
+            # A sublane tile of rows and SPLIT_LANES lanes at a time: what
+            # the registers hold, so no part goes to fast memory and back.
+            def split_rows(t, _):
+                at = pl.multiple_of(t * PART_ROWS, PART_ROWS)
+                for lo in range(0, bt, SPLIT_LANES):
+                    lanes = slice(lo, min(lo + SPLIT_LANES, bt))
+                    x = xt_ref[pl.ds(at, PART_ROWS), lanes]
+                    if part != dim:   # the block's rows past the array's: anything
+                        row = at + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+                        x = jnp.where(row < dim, x, 0.0)
+                    x_parts = _bf16_parts(x, in_kernel=True)
+                    for s, (_, of_x) in enumerate(products):
+                        parts_ref[pl.ds(s * part + at, PART_ROWS), lanes] = x_parts[of_x]
+                return 0
+
+            jax.lax.fori_loop(0, part // PART_ROWS, split_rows, 0)
+            rest = parts_ref.shape[0] - len(products) * part
+            if rest:
+                parts_ref[len(products) * part:, :] = jnp.zeros((rest, bt),
+                                                                jnp.bfloat16)
+
+        product = jnp.dot(q_ref[...], parts_ref[...],
+                          preferred_element_type=jnp.float32)
     # ‖q‖² - 2 q·x + ‖x‖², the expansion `nearest` forms.
-    product = jnp.dot(q_ref[...], xt_ref[...], precision=precision,
-                      preferred_element_type=jnp.float32)
     d2 = jnp.maximum(qsq_ref[...] - 2.0 * product + xsq_ref[...], 0.0)
     d2_ref[...] = d2
     low_ref[...] = jnp.min(d2, axis=1, keepdims=True)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == pl.num_programs(0) - 1)
     def _():
         # A partial last block: its padding holds whatever was there.
         row = j * bt + jax.lax.broadcasted_iota(jnp.int32, (bq, bt), 1)
@@ -185,7 +319,7 @@ def _search_body(q_ref, qsq_ref, xt_ref, xsq_ref, best_d_ref, best_r_ref,
     # 4-bit field of their own (8 rows at most, so nothing carries).
     row = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     field = jnp.left_shift(1, 4 * ((row // GROUP) % 8))
-    entrant = jnp.where(low_ref[...] < best_d_ref[:, k - 1:k], field, 0)
+    entrant = jnp.where(low_ref[...] < best_d_ref[mine, k - 1:k], field, 0)
     groups = bq // GROUP
     n = jnp.int32(0)
     for g0 in range(0, groups, 8):
@@ -203,17 +337,37 @@ def _search_body(q_ref, qsq_ref, xt_ref, xsq_ref, best_d_ref, best_r_ref,
     jax.lax.fori_loop(0, (n + WAYS - 1) // WAYS, rank_groups, 0)
 
 
+def train_block_rows(x_rows: int, k_width: int, bq: int, resident: int,
+                     most: int) -> int:
+    """Train rows a block: ``most``, fewer (whole :data:`SPLIT_LANES`)
+    where the kernel's blocks would not fit :data:`VMEM_LIMIT_BYTES`.
+    Counted as Mosaic was read to lay them out (PR 35, compiles for a
+    described v5e): two buffers of each input and output block (the
+    queries' stacked parts, ``k_width`` bfloat16 lanes a row; their norms
+    a vreg's lanes wide; the float32 train block of ``x_rows`` rows; the
+    ``resident`` rows' running best), the train block's parts, the
+    distances and the product they are made from."""
+    fixed = 2 * bq * (2 * k_width + 4 * LANES) + 2 * 2 * resident * 4 * LANES
+    a_lane = 2 * k_width + 2 * 4 * x_rows + 2 * 4 * bq + 2 * 4 * GROUP
+    fits = (VMEM_LIMIT_BYTES - fixed) // a_lane // SPLIT_LANES * SPLIT_LANES
+    return max(LANES, min(most, fits))
+
+
 def fused_nearest(queries, train_x, train_sq, k: int, *, precision,
                   query_block: int = QUERY_BLOCK,
                   train_block: int = TRAIN_BLOCK,
+                  query_blocks: int = QUERY_BLOCKS,
                   interpret: Optional[bool] = None) -> Tuple:
     """``(d2, rows)``, both [queries, k]: each query's ``k`` nearest rows
     of ``train_x`` ([n, d] float32, ``train_sq`` its rows' squared norms)
     by (squared distance, row), ties to the lower row, and those
     distances; what ``lax.top_k`` over the whole row of distances gives.
 
-    ``query_block`` and ``train_block`` are the most rows a block holds
-    (tests pass small ones, to cut small searches into many blocks)."""
+    ``query_block`` and ``train_block`` are the most rows a block holds,
+    ``query_blocks`` the most query blocks a chunk (tests pass small
+    ones, to cut small searches into many blocks and chunks). Chunks run
+    one after the other, each the kernel once: a chunk's stacked parts
+    (six bfloat16 copies of its rows) exist while it runs."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -227,37 +381,66 @@ def fused_nearest(queries, train_x, train_sq, k: int, *, precision,
     n_train = train_x.shape[0]
     bq = query_block_rows(n_queries, query_block)
     q_blocks = -(-n_queries // bq)
-    bt = min(train_block, -(-n_train // LANES) * LANES)
-    queries = jnp.pad(queries, ((0, q_blocks * bq - n_queries), (0, 0)))
-    q_sq = jnp.sum(queries * queries, axis=-1, keepdims=True)
-    body = functools.partial(_search_body, k=k, n_train=n_train,
+    chunks = -(-q_blocks // query_blocks)
+    per_chunk = -(-q_blocks // chunks)
+    products = _products(precision)
+    if products is None:   # jnp.dot's own: the float32 blocks, no parts
+        x_rows, width = dim, 2 * dim   # a float32 row, in bfloat16 lanes
+    else:
+        x_rows, width = _stacked_width(dim, products)
+    bt = min(train_block_rows(x_rows, width, bq, per_chunk * bq, train_block),
+             -(-n_train // LANES) * LANES)
+    parts_shape = (width, bt) if products else (PART_ROWS, LANES)
+    body = functools.partial(_search_body, k=k, dim=dim, n_train=n_train,
                              precision=precision)
-    # Traced in 32-bit mode whatever the caller's (every operand is
-    # float32 or int32): Mosaic lowers no 64-bit block index or constant.
-    with jax.enable_x64(False):
+    of_queries, of_train = (lambda j, i: (i, 0)), (lambda j, i: (0, j))
+    whole = lambda j, i: (0, 0)
+    train_t, train_sq = train_x.T, train_sq[None, :]
+
+    def one_chunk(q):
+        q_sq = jnp.sum(q * q, axis=-1, keepdims=True)
+        if products is not None:
+            # The queries' parts side by side, each product's over its
+            # train part's rows: the six products are ONE contraction.
+            q_parts = _bf16_parts(jnp.pad(q, ((0, 0), (0, x_rows - dim))),
+                                  in_kernel=False)
+            q = jnp.concatenate([q_parts[of_q] for of_q, _ in products], axis=1)
+            q = jnp.pad(q, ((0, 0), (0, width - q.shape[1])))
         best_d, best_r = pl.pallas_call(
             body,
-            grid=(q_blocks, -(-n_train // bt)),
+            grid=(-(-n_train // bt), per_chunk),
             in_specs=[
-                pl.BlockSpec((bq, dim), lambda i, j: (i, 0)),
-                pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec((dim, bt), lambda i, j: (0, j)),
-                pl.BlockSpec((1, bt), lambda i, j: (0, j)),
+                pl.BlockSpec((bq, q.shape[1]), of_queries),
+                pl.BlockSpec((bq, 1), of_queries),
+                pl.BlockSpec((x_rows, bt), of_train),
+                pl.BlockSpec((1, bt), of_train),
             ],
             out_specs=(
-                pl.BlockSpec((bq, LANES), lambda i, j: (i, 0)),
-                pl.BlockSpec((bq, LANES), lambda i, j: (i, 0)),
+                pl.BlockSpec((per_chunk * bq, LANES), whole),
+                pl.BlockSpec((per_chunk * bq, LANES), whole),
             ),
             out_shape=(
-                _gate.out_struct((q_blocks * bq, LANES), jnp.float32, queries),
-                _gate.out_struct((q_blocks * bq, LANES), jnp.int32, queries),
+                _gate.out_struct((per_chunk * bq, LANES), jnp.float32, q),
+                _gate.out_struct((per_chunk * bq, LANES), jnp.int32, q),
             ),
-            scratch_shapes=[pltpu.VMEM((bq, bt), jnp.float32),
+            scratch_shapes=[pltpu.VMEM(parts_shape, jnp.bfloat16),
+                            pltpu.VMEM((bq, bt), jnp.float32),
                             pltpu.VMEM((bq, 1), jnp.float32),
                             pltpu.SMEM((bq // GROUP + WAYS,), jnp.int32)],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"),
+                dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
-        )(queries, q_sq, train_x.T, train_sq[None, :])
-    return best_d[:n_queries, :k], best_r[:n_queries, :k]
+        )(q, q_sq, train_t, train_sq)
+        return best_d[:, :k], best_r[:, :k]
+
+    # Traced in 32-bit mode whatever the caller's (every operand is
+    # float32, bfloat16 or int32): Mosaic lowers no 64-bit block index or
+    # constant.
+    with jax.enable_x64(False):
+        padded = chunks * per_chunk * bq
+        queries = jnp.pad(queries, ((0, padded - n_queries), (0, 0)))
+        best_d, best_r = jax.lax.map(
+            one_chunk, queries.reshape(chunks, per_chunk * bq, dim))
+    return (best_d.reshape(padded, k)[:n_queries],
+            best_r.reshape(padded, k)[:n_queries])

@@ -185,6 +185,9 @@ class KnnModel(_KnnParams, Model):
         # Which search the program held: the kernel's, or the tiled one's.
         fused = _ranks_in_the_product(queries, model.features, k)
         group.counter("fused_query_rows", float(x.shape[0]) if fused else 0.0)
+        # ... and whether it made the float32 product from its own parts.
+        split = fused and knn_search.splits_the_product(PRODUCT_PRECISION)
+        group.counter("split_product_query_rows", float(x.shape[0]) if split else 0.0)
         group.counter("train_tiles", 0.0 if fused else float(
             -(-x.shape[0] // chunk) * -(-n_train // tile)))
         with span("knn.readback"):

@@ -4,7 +4,10 @@ rows, planted exact ties, products rounded to bfloat16 shown to differ,
 four row shares merged into the whole; and the model data's one upload.
 Both searches (PR 31): the tiled XLA one, which this backend runs, and
 the fused product-and-ranking kernel a TPU runs, interpreted here at
-blocks small enough to cut these searches into many."""
+blocks small enough to cut these searches into many. Since PR 35 the
+kernel forms its float32 product from bfloat16 parts it makes itself,
+one long contraction, train blocks outermost: the parts, the product at
+widths that do and do not fill their tiles, and the new loop order."""
 
 import functools
 
@@ -58,15 +61,18 @@ def _tiled(queries, train, k, tile, chunk=64, precision=knn.PRODUCT_PRECISION):
     return np.asarray(d2), np.asarray(rows)
 
 
-def _fused(queries, train, k, tile, chunk=24, precision=knn.PRODUCT_PRECISION):
+def _fused(queries, train, k, tile, chunk=24, precision=knn.PRODUCT_PRECISION,
+           chunk_blocks=2):
     """The fused kernel, interpreted: train blocks of ``tile`` rows up to
-    whole lanes, query blocks of at most ``chunk`` rows (70 queries are
-    three blocks of 24: the last one is padded)."""
+    whole lanes, query blocks of at most ``chunk`` rows, ``chunk_blocks``
+    of them sharing a train block's parts (70 queries are three blocks of
+    24, the last one padded, in two chunks of two, the last block all
+    padding)."""
     x = jnp.asarray(train, jnp.float32)
     run = jax.jit(functools.partial(
         knn_search.fused_nearest, k=k, precision=precision, query_block=chunk,
         train_block=-(-tile // knn_search.LANES) * knn_search.LANES,
-        interpret=True))
+        query_blocks=chunk_blocks, interpret=True))
     d2, rows = run(jnp.asarray(queries, jnp.float32), x, jnp.sum(x * x, axis=-1))
     return np.asarray(d2), np.asarray(rows)
 
@@ -190,15 +196,16 @@ def _centred(rng, rows, dim, order):
 @pytest.mark.parametrize("k", [1, 5, 8])
 @pytest.mark.parametrize("order", ["nearest_first", "farthest_first", "none"])
 def test_the_fused_search_in_any_order_of_the_stream(rng, order, k):
-    """The kernel against the tiled search, index for index and distance
-    for distance, and both against the reference: 700 rows in blocks of
-    128 (the last one of 60), where no later block holds an entrant, and
-    where every block holds ``k``."""
+    """The kernel against the tiled search, index for index, and both
+    against the reference: 700 rows in blocks of 128 (the last one of
+    60), where no later block holds an entrant, and where every block
+    holds ``k``. The distances to float32's tolerance: the kernel's own
+    six bfloat16 products and XLA's round differently (PR 35)."""
     train, queries = _centred(rng, 700, 9, order)
     d2, got = _fused(queries, train, k, 128)
     tiled_d2, tiled = _tiled(queries, train, k, 256)
     np.testing.assert_array_equal(got, tiled)
-    np.testing.assert_array_equal(d2, tiled_d2)
+    np.testing.assert_allclose(d2, tiled_d2, atol=TOL)
     want, want_d2 = reference.k_nearest(queries, train, k)
     _assert_same_neighbours(got, want, want_d2, k)
     if order != "none":
@@ -221,6 +228,141 @@ def test_all_equal_distances_keep_the_first_rows(k):
         np.testing.assert_array_equal(d2, np.full((20, k), 1.5, np.float32))
 
 
+PARTS_OF = {
+    "levels": lambda rng: _levels(rng, 64, 784),
+    "normal": lambda rng: rng.normal(size=(64, 100)).astype(np.float32),
+    "wide_range": lambda rng: (rng.choice([-1.0, 1.0], (64, 130))
+                               * 2.0 ** rng.uniform(-20, 20, (64, 130))).astype(np.float32),
+    "zeros": lambda rng: np.zeros((8, 16), np.float32),
+    "negatives": lambda rng: -_levels(rng, 64, 33) - np.float32(1e-3),
+}
+
+
+@pytest.mark.parametrize("in_kernel", [True, False])
+@pytest.mark.parametrize("values", sorted(PARTS_OF))
+def test_three_bfloat16_parts_are_the_float32(rng, values, in_kernel):
+    """``hi + mid + lo`` is the float32 again, bit for bit, jitted as the
+    kernel's callers run it, with the roundings a kernel makes and with
+    the ones its caller makes, and each part is what is left of the one
+    before: nothing of a value is lost that a one-pass product would
+    keep."""
+    v = PARTS_OF[values](rng)
+    split = functools.partial(knn_search._bf16_parts, in_kernel=in_kernel)
+    hi, mid, lo = (np.asarray(p.astype(jnp.float32))
+                   for p in jax.jit(split)(jnp.asarray(v)))
+    np.testing.assert_array_equal((hi + mid) + lo, v)
+    low = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(hi, low(v))
+    np.testing.assert_array_equal(mid, low(v - hi))
+    if values not in ("zeros",):
+        assert np.abs(mid).max() > 0
+    assert np.all(np.abs(mid) <= np.abs(hi) * 2.0 ** -7)
+    assert np.all(np.abs(lo) <= np.abs(hi) * 2.0 ** -15)
+
+
+def test_the_products_of_each_precision():
+    """``precision`` keeps its two meanings: ``HIGHEST`` the six products a
+    six-pass float32 contraction makes, the smallest first, ``DEFAULT`` one
+    pass; anything else is ``jnp.dot``'s. The parts lie end to end in
+    whole sublane tiles, the contraction comes up to whole MXU tiles: at
+    ``d`` 784, 37 tiles where six contractions of 784 take 42."""
+    P = jax.lax.Precision
+    six = knn_search._products(P.HIGHEST)
+    assert sorted(six) == [(0, 0), (0, 1), (0, 2), (1, 0), (1, 1), (2, 0)]
+    assert [a + b for a, b in six] == sorted(a + b for a, b in six)[::-1]
+    assert knn_search._products(P.DEFAULT) == ((0, 0),)
+    assert knn_search._products(P.HIGH) is None
+    assert knn_search._stacked_width(784, six) == (784, 37 * 128)
+    assert knn_search._stacked_width(784, ((0, 0),)) == (784, 7 * 128)
+    assert knn_search._stacked_width(100, six) == (112, 6 * 128)
+    assert knn_search._stacked_width(16, six) == (16, 128)
+    assert knn_search._stacked_width(5, six) == (16, 128)
+
+
+# d: the cell's (49 sublane tiles, 6.125 lane tiles), under one lane tile
+# and not whole sublane tiles (100), one lane tile and a remainder not
+# whole sublane tiles (130), one sublane tile (16). Rows that do not fill
+# the last train block, queries that do not fill their blocks, two query
+# blocks a chunk and two chunks over several train blocks.
+WIDTHS = [(784, 300, 128), (100, 700, 256), (130, 450, 128), (16, 1000, 384)]
+
+
+@pytest.mark.parametrize("dim,rows,tile", WIDTHS)
+@pytest.mark.parametrize("maker", [_levels, _small_integers])
+def test_the_kernels_own_product_at_any_width(rng, maker, dim, rows, tile):
+    """The kernel's distances at ``HIGHEST`` against float64 within
+    float32's tolerance at the norms there are, its rows the reference's;
+    on integer rows (every product exact, a third of the rows planted
+    again further down: ties across blocks) bit for bit and to the lower
+    row, in every query block of every chunk."""
+    train, queries = maker(rng, rows, dim), maker(rng, 70, dim)
+    if maker is _small_integers:
+        train[2 * rows // 3:] = train[:rows - 2 * rows // 3]
+    d2, got = _fused(queries, train, 5, tile)
+    want, want_d2 = reference.k_nearest(queries, train, 5)
+    if maker is _small_integers:
+        np.testing.assert_array_equal(got, want[:, :5])
+        np.testing.assert_array_equal(d2, want_d2[:, :5])
+        return
+    # 8 units in the last place of (|q| + |x|)^2, the benchmark's tolerance
+    tol = 8 * 2.0 ** -24 * (2 * np.sqrt(dim / 3)) ** 2
+    np.testing.assert_allclose(d2, want_d2[:, :5], atol=tol, rtol=0)
+    stable = ~reference.unstable(want_d2, 5, tol)
+    assert stable.mean() > 0.8
+    np.testing.assert_array_equal(np.sort(got[stable], axis=1),
+                                  np.sort(want[stable, :5], axis=1))
+
+
+@pytest.mark.parametrize("chunk,chunk_blocks", [(24, 1), (24, 3), (16, 2), (72, 10)])
+def test_every_query_block_sees_every_train_block_in_order(rng, chunk, chunk_blocks):
+    """The loop's order (PR 35): train blocks outermost, the query blocks
+    of a chunk inside. However the 70 queries are cut (three blocks in
+    three chunks; in one chunk; five blocks in three chunks, the last one
+    padding; one block), each block meets all six train blocks in
+    ascending order: of equal rows planted in EVERY train block the lower
+    comes first, and the answers are the tiled search's."""
+    train = _small_integers(rng, 128, 12)
+    train = np.concatenate([train] * 6)[:700]          # six blocks, the last of 60
+    queries = _small_integers(rng, 70, 12)
+    d2, got = _fused(queries, train, 5, 128, chunk=chunk, chunk_blocks=chunk_blocks)
+    tiled_d2, tiled = _tiled(queries, train, 5, 256)
+    np.testing.assert_array_equal(got, tiled)
+    np.testing.assert_array_equal(d2, tiled_d2)
+    # a row's copies in the later blocks tie with it: the lowest comes first
+    assert (got[:, 0] < 128).all() and (np.diff(d2, axis=1) == 0).mean() > 0.5
+    assert (np.diff(got, axis=1)[np.diff(d2, axis=1) == 0] > 0).all()
+    want, _ = reference.k_nearest(queries, train, 5)
+    np.testing.assert_array_equal(got, want[:, :5])
+
+
+def test_another_precision_is_jnp_dots_own(rng):
+    """``Precision.HIGH`` is neither of the two: the kernel hands it to
+    ``jnp.dot`` on the float32 blocks, as every precision went before PR
+    35 (a CPU computes it exactly)."""
+    train, queries = _levels(rng, 300, 20), _levels(rng, 40, 20)
+    d2, got = _fused(queries, train, 5, 128, precision=jax.lax.Precision.HIGH)
+    want, want_d2 = reference.k_nearest(queries, train, 5)
+    _assert_same_neighbours(got, want, want_d2, 5)
+    np.testing.assert_allclose(d2, want_d2[:, :5], atol=TOL)
+
+
+def test_train_blocks_fit_fast_memory():
+    """The train block comes down from ``TRAIN_BLOCK`` where the blocks
+    counted would not fit the kernel's fast memory: not at the cell's
+    shape (six blocks of 1,672 queries, 784-wide rows, 37 tiles), at the
+    widest rows the kernel takes; never under a vreg's lanes; and what is
+    counted at the chosen block is within the limit."""
+    most = knn_search.TRAIN_BLOCK
+    six = knn_search._products(jax.lax.Precision.HIGHEST)
+    cell = knn_search.train_block_rows(784, 37 * 128, 1672, 6 * 1672, most)
+    assert cell == most == 2048
+    wide = knn_search.train_block_rows(
+        *knn_search._stacked_width(1023, six), 1672, 6 * 1672, most)
+    assert 1024 <= wide < most and wide % knn_search.SPLIT_LANES == 0
+    assert knn_search.train_block_rows(112, 6 * 128, 24, 48, most) == most
+    assert knn_search.train_block_rows(1024, 48 * 128, 10 ** 5, 10 ** 5, most) == 128
+
+
 def test_where_the_fused_kernel_applies():
     """What ``nearest`` can see decides: float32 operands, ``k`` within one
     vreg of running best, rows that fit fast memory and that the chip
@@ -237,7 +379,7 @@ def test_where_the_fused_kernel_applies():
             (f32(10, 256), f32(99, 256), 5, "dim=256")]:
         assert why in knn_search.unsupported_reason(queries, train, k)
     assert not knn._ranks_in_the_product(f32(10, 784), f32(99, 784), 5)
-    assert knn_search.query_block_rows(10_000) == 1000
+    assert knn_search.query_block_rows(10_000) == 1672      # six blocks
     assert knn_search.query_block_rows(5) == 8
     assert knn_search.query_block_rows(70, 24) == 24
 
@@ -362,48 +504,65 @@ def test_second_transform_uploads_no_model_and_compiles_nothing(rng, monkeypatch
     assert group.snapshot()["counters"]["model_uploads"] == second["model_uploads"] + 1
 
 
-def test_transform_counts_the_rows_the_kernel_searched(rng, monkeypatch):
+@pytest.mark.parametrize("precision,split", [("HIGHEST", 52), ("DEFAULT", 0)])
+def test_transform_counts_the_rows_the_kernel_searched(rng, monkeypatch, precision, split):
     """``KnnModel.transform`` as a TPU runs it, the kernel interpreted: the
     predictions are the reference's vote, and ``knn.fused_query_rows``
-    moves with ``knn.query_rows`` (``knn.fused_search_share`` reads 1.0)."""
+    moves with ``knn.query_rows`` (``knn.fused_search_share`` reads 1.0);
+    ``knn.split_product_query_rows`` moves with them where the kernel
+    made the float32 product from its own parts, and stands still at the
+    one pass (``knn.split_product_share`` 1.0 and 0.0)."""
     from flinkml_tpu.kernels import _gate
 
     train, queries = _levels(rng, 430, 13), _levels(rng, 52, 13)   # shapes of
     labels = rng.integers(0, 3, 430).astype(np.float64)   # this test alone
     table, asked = Table({"features": train, "label": labels}), Table({"features": queries})
     monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(knn, "PRODUCT_PRECISION", getattr(jax.lax.Precision, precision))
     monkeypatch.setattr(knn_search, "fused_nearest", functools.partial(
         knn_search.fused_nearest, interpret=True, query_block=16, train_block=128))
     before = metrics.group("knn").snapshot()["counters"]
     got = Knn().set_k(5).fit(table).transform(asked)[0]["prediction"]
     after = metrics.group("knn").snapshot()["counters"]
-    assert after["fused_query_rows"] - before["fused_query_rows"] == 52
-    assert after["query_rows"] - before["query_rows"] == 52
-    assert after["train_tiles"] == before["train_tiles"]
+    moved = lambda name: after[name] - before.get(name, 0)
+    assert moved("fused_query_rows") == moved("query_rows") == 52
+    assert moved("split_product_query_rows") == split
+    assert moved("train_tiles") == 0
     rows, d2 = reference.k_nearest(queries, train, 5)
     stable = ~reference.unstable(d2, 5, TOL)
     assert stable.mean() > 0.9
+    # (a CPU computes the one pass exactly too: the vote holds at both)
     np.testing.assert_array_equal(got[stable], reference.vote(labels, rows, 5)[stable])
 
 
-def test_the_fused_share_metric_and_its_entry():
-    """``knn.fused_search_share`` as ``BENCHMARK.json`` has it: within the
-    file's limits of form, listing cells that exist, read by
-    ``counter_ratio`` as 1.0 where every row went through the kernel, 0.0
-    where none did, nothing where the program has no such count."""
+SHARES = {  # metric: (layer, the counter it divides by ``knn.query_rows``)
+    "knn.fused_search_share": ("KNN search", "knn.fused_query_rows"),
+    "knn.split_product_share": ("Kernels", "knn.split_product_query_rows"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SHARES))
+def test_the_share_metrics_and_their_entries(name):
+    """``knn.fused_search_share`` (PR 31) and ``knn.split_product_share``
+    (PR 35) as ``BENCHMARK.json`` has them: within the file's limits of
+    form, listing cells that exist, read by ``counter_ratio`` as 1.0 where
+    every row went that way, 0.0 where none did, nothing where the
+    program has no such count."""
     import json
     import os
     import re
 
     from benchmark.readers import counter_ratio
 
+    layer, counter = SHARES[name]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = next(m for m in bench["per_layer"] if m["name"] == "knn.fused_search_share")
+    at = [m["name"] for m in bench["per_layer"]].index(name)
+    entry = bench["per_layer"][at]
     assert entry == {
-        "name": "knn.fused_search_share", "unit": "rows/row", "better": "higher",
-        "source": "program_counter", "layer": "KNN search",
+        "name": name, "unit": "rows/row", "better": "higher",
+        "source": "program_counter", "layer": layer,
         "moves": "transform_rows_per_s", "workloads": ["knn-mnist8m.transform"]}
     assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", entry["name"])
     assert re.fullmatch(r"[A-Za-z0-9_/%.\-]{1,16}", entry["unit"])
@@ -413,16 +572,17 @@ def test_the_fused_share_metric_and_its_entry():
     assert set(entry["workloads"]) <= cells
     rate = next(m for m in bench["end_to_end"] if m["name"] == entry["moves"])
     assert set(entry["workloads"]) <= set(rate["workloads"])
-    assert entry["layer"] in {m["layer"] for m in bench["per_layer"][:-1]}
+    assert entry["layer"] in {m["layer"] for m in bench["per_layer"][:at]}
     assert [m["name"] for m in bench["per_layer"]].count(entry["name"]) == 1
     assert len(json.dumps(bench)) < 64 * 1024
     with open(os.path.join(root, "benchmark", "metrics", entry["name"] + ".json")) as f:
         how = json.load(f)
     assert how["reader"] == "counter_ratio" and set(how) == {"what", "reader", "params"}
+    assert how["params"] == {"num": counter, "den": "knn.query_rows"}
     obs = lambda counters: {"counters": counters, "setup_counters": {}, "units": {"calls": 7}}
     read = lambda counters: counter_ratio.read(how["params"], obs(counters))
-    assert read({"knn.fused_query_rows": 70_000.0, "knn.query_rows": 70_000.0}) == 1.0
-    assert read({"knn.fused_query_rows": 0.0, "knn.query_rows": 70_000.0}) == 0.0
+    assert read({counter: 70_000.0, "knn.query_rows": 70_000.0}) == 1.0
+    assert read({counter: 0.0, "knn.query_rows": 70_000.0}) == 0.0
     assert read({"knn.query_rows": 70_000.0}) is None          # the parent's program
 
 
