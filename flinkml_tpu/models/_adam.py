@@ -11,7 +11,12 @@ unambiguous (no reliance on psum-transpose rules).
 Convergence: stop when ``|loss_{t-1} - loss_t| <= tol`` or at
 ``max_iter`` steps. Minibatch indices come from a per-step
 ``fold_in``; the key is replicated, so every device samples the same
-local row positions of its own (distinct) shard.
+local row positions of its own (distinct) shard: ``local_bs`` rows drawn
+WITH replacement every step. The dense factorization machines, the MLP
+and ``survival`` batch so. The factorization machines' fit of a
+``CsrColumn`` (``models/_fm_sparse.py``) shares :func:`adam_update` and
+not the draw: its step ``t`` reads window ``t mod ceil(rows / batch)`` of
+the rows placed in a seeded order, as ``_linear_sgd``'s fits do.
 """
 
 from __future__ import annotations
@@ -50,18 +55,28 @@ def _make_minibatch_step(local_loss, axis: str, local_bs: int,
             grads = tuple(grads[: n_params - frozen_tail]) + tuple(
                 jnp.zeros_like(g) for g in grads[n_params - frozen_tail:]
             )
-        t = (step + 1).astype(jnp.float32)
-        b1, b2, eps = 0.9, 0.999, 1e-8
-        m = jax.tree.map(lambda a, g: b1 * a + (1 - b1) * g, m, grads)
-        v = jax.tree.map(lambda a, g: b2 * a + (1 - b2) * g * g, v, grads)
-        params = jax.tree.map(
-            lambda p, mm, vv: p - lr * (mm / (1 - b1 ** t))
-            / (jnp.sqrt(vv / (1 - b2 ** t)) + eps),
-            params, m, v,
-        )
+        params, m, v = adam_update(params, m, v, grads, step, lr)
         return params, m, v, loss
 
     return step_fn
+
+
+def adam_update(params, m, v, grads, step, lr):
+    """Adam's update of a pytree of parameters from the gradients of
+    GLOBAL 0-based step ``step``: ``(params, m, v)``. Rates 0.9 and
+    0.999, epsilon 1e-8 outside the root, both moments bias-corrected.
+    The one statement of it: the dense trainers' step above and the
+    sparse factorization machine's (``models/_fm_sparse.py``) call it."""
+    t = (step + 1).astype(jnp.float32)
+    b1, b2, eps = 0.9, 0.999, 1e-8
+    m = jax.tree.map(lambda a, g: b1 * a + (1 - b1) * g, m, grads)
+    v = jax.tree.map(lambda a, g: b2 * a + (1 - b2) * g * g, v, grads)
+    params = jax.tree.map(
+        lambda p, mm, vv: p - lr * (mm / (1 - b1 ** t))
+        / (jnp.sqrt(vv / (1 - b2 ** t)) + eps),
+        params, m, v,
+    )
+    return params, m, v
 
 
 @functools.lru_cache(maxsize=32)

@@ -1,0 +1,376 @@
+"""The factorization machines' fit of a ``table.CsrColumn``: a
+second-order FM (Rendle, ICDM 2010) trained over a sparse table that
+stays on the mesh, Adam's whole run one device program.
+
+The dense fit (``models/fm.py``) needs a ``[rows, dim]`` matrix, which a
+hashed click log at ``dim`` 1e6 cannot be. Here the cells are all there
+is. For a row with cells ``(i_s, x_s)``, the parameters ONE table ``P
+[dim, 1 + k]`` of a column's weight and its ``k`` factors::
+
+    S_f = sum_s x_s V[i_s, f]
+    y^  = w0 + sum_s x_s w[i_s] + 1/2 sum_f (S_f**2 - sum_s x_s**2 V[i_s, f]**2)
+
+and with ``mult = d loss / d y^`` (logistic or squared, times the row's
+weight)::
+
+    dw[i_s]    += mult * x_s
+    dV[i_s, f] += mult * (x_s S_f - x_s**2 V[i_s, f])
+    dw0        += mult
+
+``_fm_margin``'s equations, over cells. L2 on ``w`` and ``V`` as
+``fm._fm_logistic_loss_builder`` states it, Adam as ``_adam.adam_update``
+does, dense moments over the whole table.
+
+- **Ingest**: ``_data.sparse_fit_columns``; float32 whatever
+  ``jax_enable_x64`` says; no ``SparseVector`` is built. Rows of one
+  width are the ELL block as held (``ops.sparse.one_width_block``), rows
+  that keep to fields are laid one field a slot (``align_ragged_rows``),
+  any other table is one block padded to its widest row.
+- **Lookup and update**: under the slot plan (``ops.sparse.
+  slot_block_plan``) a blocked slot's parameter rows come from its block
+  by a one-hot product (``block_lookup`` with a payload axis: the rows
+  ``P[idx]`` bit for bit at :data:`LOOKUP_PRECISION`) and its gradient
+  goes back the same way (``block_accumulate``); the step walks the
+  slots a few at a time (``block_groups``) so that no product's operand
+  is long. The other
+  slots take their rows with ``jnp.take`` and share one ``segment_sum``
+  over ``[cells, 1 + k]``: correct, and element-serial on a TPU.
+- **Batches**: step ``t`` reads window ``t mod ceil(rows / batch)`` of
+  the rows placed in the order ``default_rng(seed).permutation(rows)``
+  (``_linear_sgd._window``), not ``_adam``'s draw with replacement: a
+  draw is a gather of whole rows every step.
+- **Residency**: the placed cells, labels, weights and the plan are kept
+  WITH the ``Table`` (:meth:`Table.device_resident`), placed once a table
+  and seed through :meth:`DeviceMesh.stage_rows`; a second fit uploads
+  nothing of the table and is bit-equal at equal hyper-parameters.
+- **One program**, ``fm_adam_loop``: ``max_iter``, rate, ``reg`` and
+  ``tol`` are runtime operands, so a sweep over them compiles once.
+
+Spans and counters: ``docs/development/observability.md`` ("Spans",
+``fm.*``; the group ``fm``).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
+from jax.sharding import PartitionSpec as P
+
+from flinkml_tpu.models._adam import adam_update
+from flinkml_tpu.models._data import check_binary_labels, sparse_fit_columns
+from flinkml_tpu.models._linear_sgd import _placed, _slot_major, _window
+from flinkml_tpu.ops import sparse
+from flinkml_tpu.ops.sparse import LANES
+from flinkml_tpu.parallel import DeviceMesh
+from flinkml_tpu.parallel.mesh import gather_pool
+from flinkml_tpu.utils.metrics import metrics
+from flinkml_tpu.utils.profiling import named_program, span
+
+#: The precision of the lookup's and the accumulation's products: the
+#: looked-up rows are the gathered ones bit for bit, the accumulated
+#: gradient float32's own sum (one bfloat16 pass, ``DEFAULT``, rounds
+#: every parameter read to 8 bits: the benchmark's control).
+LOOKUP_PRECISION = jax.lax.Precision.HIGHEST
+
+class _Placed(NamedTuple):
+    """A table's rows as the mesh holds them, in the seeded order, and
+    what one look at its cells found."""
+
+    indices: jax.Array            # [p * n_local, width] int32
+    values: jax.Array             # [p * n_local, width] float32
+    labels: jax.Array             # [p * n_local] float32
+    weights: jax.Array            # [p * n_local] float32, 0 at the padding
+    starts: jax.Array             # [width] int32 rows of 128; [1] under no plan
+    slot_plan: Tuple              # a block length or None per slot; () none
+    rows: int
+    cells: float
+    blocked_cells: float
+
+
+def _ell_block(indptr, indices, values, dim: int):
+    """The table's cells as ONE ELL block and its plan: ``(block,
+    slot_plan, starts, blocked_cells)`` (``ops.sparse.planned_block``).
+    The plan is read with a step of one row (no one-hot bounds a block's
+    length: the step walks its slots), so it is the table's whatever the
+    batch. A table with no plan (ragged rows that keep to no fields) is
+    one block padded to its widest row (index 0, value 0), the caller's
+    rows in order."""
+    with gather_pool() as pool:
+        block, slot_plan, starts, blocked_cells = sparse.planned_block(
+            indptr, indices, values, dim, np.float32, 1, pool)
+    if block is None:
+        (block,), _ = sparse.pack_ell_buckets(
+            indptr, indices, values, dim, max_buckets=1, dtype=np.float32)
+    return block, slot_plan, starts, blocked_cells
+
+
+def _place_table(indptr, indices, values, dim: int, y, w, mesh: DeviceMesh,
+                 seed: int) -> _Placed:
+    """Every row on the mesh, once: the plan (``hostdata.sparse_pack``),
+    then the cells, labels and weights through :meth:`DeviceMesh.
+    stage_rows` in the seeded order (``fm.table_to_device``), padded with
+    zero rows of weight 0 to the mesh."""
+    with span("hostdata.sparse_pack"):
+        block, slot_plan, starts, blocked_cells = _ell_block(
+            indptr, indices, values, dim)
+    n = block["indices"].shape[0]
+    with span("fm.table_to_device") as phase:
+        with span("hostdata.shuffle"), span("hostdata.permute"):
+            order = np.random.default_rng(seed).permutation(n)
+        columns = [(block["indices"], order, np.int32),
+                   (block["values"], order, np.float32),
+                   (np.asarray(y), order, np.float32)]
+        if w is not None:
+            columns.append((np.asarray(w), order, np.float32))
+        arrays = _placed(mesh.stage_rows(columns))
+        if w is None:
+            arrays += (mesh.shard_ones(n, np.float32),)
+        sent = float(sum(a.nbytes for a in arrays[:len(columns)]))
+        phase.add(bytes=sent)
+    group = metrics.group("fm")
+    group.counter("table_uploads")
+    group.counter("table_h2d_bytes", sent)
+    return _Placed(*arrays,
+                   mesh.replicate(np.zeros(1, np.int32) if starts is None else starts),
+                   slot_plan, n, float(np.asarray(indptr)[-1]), blocked_cells)
+
+
+def _lane_rows(table):
+    """``table [1 + k, dim / 128, 128]`` held to the layout its shape
+    states, the 128 columns of a row along the lanes: left to itself the
+    compiler lays Adam's state as some product wants it, once with the
+    ``1 + k`` floats along the lanes (padded to 128: 7.5 times the bytes
+    in every pass of Adam; PERF.md section 5, PR 36)."""
+    return with_layout_constraint(table, Layout(major_to_minor=(0, 1, 2)))
+
+
+def make_step(logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
+              precision=LOOKUP_PRECISION):
+    """ONE Adam step of the sparse factorization machine on a device's
+    shard: ``step(params, m, v, t, idx, val, y, wt, starts, lr, reg) ->
+    (params, m, v, loss)``, ``params = (w0 [1], table [1 + k, dim_pad /
+    128, 128])``, ``t`` the global 0-based step (the window and Adam's
+    bias correction), ``starts`` the blocks' first rows of 128 columns
+    (any array under an empty plan). The table lies with its COLUMNS in
+    rows of 128 lanes, a float of the payload a plane (a TPU pads a
+    ``[dim, 17]`` array's rows to 128 lanes, and gave ``[17, dim]`` the
+    same layout: 7.5 times the bytes in every pass of Adam); a block is
+    whole rows cut out of it and turned. The module docstring has the
+    equations."""
+
+    def step(params, m, v, t, idx, val, y, wt, starts, lr, reg):
+        w0, table = params
+        width = table.shape[0]
+        ib, vb = _window(idx, t, local_bs), _window(val, t, local_bs)
+        yb, wb = _window(y, t, local_bs), _window(wt, t, local_bs)
+        general = [j for j in range(ib.shape[1])
+                   if j >= len(slot_plan) or slot_plan[j] is None]
+        # What a cell adds to its row's sums is x_s P[i_s]: kept, slot
+        # major, for the gradient.
+        sums = jnp.zeros((local_bs, width), table.dtype)
+        squares = jnp.zeros((local_bs,), table.dtype)
+        walked = []
+        for length, slots in sparse.block_groups(slot_plan, local_bs, width):
+            first = [starts[j] for j in slots]
+            blocks = jnp.stack([
+                jax.lax.dynamic_slice_in_dim(table, at, length // LANES, axis=1)
+                .reshape(width, length).T for at in first])
+            local = _slot_major(ib, slots) - LANES * jnp.stack(first)[:, None]
+            xs = _slot_major(vb, slots)
+            with jax.named_scope(f"lookup_{length}x{len(slots)}"):
+                xp = xs[..., None] * sparse.block_lookup(blocks, local, precision)
+            sums += jnp.sum(xp, axis=0)
+            squares += jnp.sum(jnp.square(xp[..., 1:]), axis=(0, 2))
+            walked.append((length, first, local, xs, xp))
+        if general:
+            ig, xg = _slot_major(ib, general).T, _slot_major(vb, general).T
+            gp = xg[..., None] * jnp.moveaxis(
+                jnp.take(table.reshape(width, -1), ig, axis=1), 0, -1)
+            sums += jnp.sum(gp, axis=1)
+            squares += jnp.sum(jnp.square(gp[..., 1:]), axis=(1, 2))
+        factor_sums = sums[:, 1:]
+        margin = w0[0] + sums[:, 0] + 0.5 * (
+            jnp.sum(jnp.square(factor_sums), axis=1) - squares)
+        if logistic:
+            per_row = jnp.logaddexp(0.0, margin) - yb * margin
+            mult = (jax.nn.sigmoid(margin) - yb) * wb
+        else:
+            err = margin - yb
+            per_row, mult = 0.5 * err * err, err * wb
+        # d y^ / d P[i_s] = x_s (1, S_f - x_s V[i_s, f]).
+        base = jnp.concatenate(
+            [jnp.ones((local_bs, 1), table.dtype), factor_sums], axis=1)
+        factors_only = jnp.arange(width) > 0
+
+        def cell_grads(xs, xp, rows_first: bool):
+            """``xs [.., ..]`` and ``xp [.., .., 1 + k]`` over (rows, slots)
+            or (slots, rows)."""
+            of_row = (lambda a: a[:, None]) if rows_first else (lambda a: a[None])
+            return (of_row(mult) * xs)[..., None] * (
+                of_row(base) - jnp.where(factors_only, xp, 0))
+
+        if general:
+            grad = jax.ops.segment_sum(
+                cell_grads(xg, gp, True).reshape(-1, width), ig.reshape(-1),
+                num_segments=table.shape[1] * LANES).T.reshape(table.shape)
+        else:
+            grad = jnp.zeros_like(table)
+        for length, first, local, xs, xp in walked:
+            with jax.named_scope(f"accumulate_{length}x{len(first)}"):
+                back = sparse.block_accumulate(
+                    local, cell_grads(xs, xp, False), length, precision)
+            # One slot after another: blocks may overlap, and add.
+            for at, slot_grad in zip(first, back):
+                grad = jax.lax.dynamic_update_slice_in_dim(
+                    grad,
+                    jax.lax.dynamic_slice_in_dim(grad, at, length // LANES, axis=1)
+                    + slot_grad.T.reshape(width, -1, LANES), at, axis=1)
+        wsum = jax.lax.psum(jnp.sum(wb), axis)
+        total_w = jnp.maximum(wsum, 1e-12)
+        # L2 as fm._fm_*_loss_builder states it: reg * (|w|^2 + |V|^2)
+        # times the batch's weight, inside the sum that total_w divides.
+        l2 = reg * wsum / total_w
+        loss = (jax.lax.psum(jnp.sum(per_row * wb), axis) / total_w
+                + l2 * jnp.sum(jnp.square(table)))
+        grads = (jax.lax.psum(jnp.sum(mult), axis)[None] / total_w,
+                 _lane_rows(jax.lax.psum(grad, axis)) / total_w + 2.0 * l2 * table)
+        params, m, v = adam_update(params, m, v, grads, t, lr)
+        (w0, table), (m0, m1), (v0, v1) = params, m, v
+        return ((w0, _lane_rows(table)), (m0, _lane_rows(m1)),
+                (v0, _lane_rows(v1)), loss)
+
+    return step
+
+
+@functools.lru_cache(maxsize=32)
+def _trainer(mesh, logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
+             precision=LOOKUP_PRECISION):
+    """Adam's whole run as one program, ``fm_adam_loop``: ``(w0, table
+    [1 + k, dim_pad / 128, 128], idx, val, y, wt, starts, lr, reg, max_iter, tol) ->
+    (w0, table, steps, loss)``. It stops after ``max_iter`` steps, or when two
+    successive losses lie within ``tol`` of each other as ``_adam``'s
+    loop does; at ``tol`` 0 it runs ``max_iter`` steps exactly unless the
+    loss is NaN (two losses equal to the bit do not stop it)."""
+    step = make_step(logistic, local_bs, axis, slot_plan, precision)
+
+    def per_device(w0, table, idx, val, y, wt, starts, lr, reg, max_iter, tol):
+        params = (w0, table)
+        zeros = jax.tree.map(jnp.zeros_like, params)
+
+        def cond(state):
+            t, _, _, _, prev, cur = state
+            moving = jnp.where(tol > 0, jnp.abs(prev - cur) > tol,
+                               ~jnp.isnan(cur))
+            return (t < max_iter) & moving
+
+        def body(state):
+            t, params, m, v, _, last = state
+            params, m, v, loss = step(params, m, v, t, idx, val, y, wt,
+                                      starts, lr, reg)
+            return t + 1, params, m, v, last, loss
+
+        inf = jnp.asarray(jnp.inf, table.dtype)
+        t, params, _, _, _, loss = jax.lax.while_loop(
+            cond, body, (jnp.asarray(0, jnp.int32), params, zeros, zeros,
+                         inf, -inf))
+        return params[0], params[1], t, loss
+
+    return jax.jit(jax.shard_map(
+        named_program("fm_adam_loop", per_device), mesh=mesh,
+        in_specs=(P(), P()) + (P(axis),) * 4 + (P(),) * 5,
+        out_specs=(P(), P(), P(), P()),
+    ))
+
+
+def padded_dim(dim: int) -> int:
+    """``dim`` rounded up to whole rows of 128 columns: the parameter
+    table's columns, those past ``dim`` zero for good (no cell names them,
+    and Adam leaves a zero with a zero gradient where it is)."""
+    return -(-dim // LANES) * LANES
+
+
+def fit_csr(est, table, logistic: bool, precision=LOOKUP_PRECISION):
+    """``FMClassifier.fit`` / ``FMRegressor.fit`` where the features
+    column is a ``CsrColumn``: ``(w0 [1], w [dim], V [dim, k])`` as
+    float32 host arrays. The caller's span ``fit`` holds all of it.
+    ``precision`` is the benchmark's control's alone."""
+    features_col = est.get(est.FEATURES_COL)
+    label_col, weight_col = est.get(est.LABEL_COL), est.get(est.WEIGHT_COL)
+    indptr, indices, values, dim, labels, w = sparse_fit_columns(
+        table, features_col, label_col, weight_col)
+    if indptr.size - 1 == 0:
+        raise ValueError("training table is empty")
+    if logistic:
+        check_binary_labels(labels, type(est).__name__)
+    mesh = est.mesh or DeviceMesh()
+    seed = est.get_seed()
+    placed = table.device_resident(
+        ("fm_rows_on_mesh", features_col, label_col, weight_col,
+         mesh.mesh, "float32", seed),
+        lambda: _place_table(indptr, indices, values, dim, labels.values,
+                             w, mesh, seed))
+    p = mesh.axis_size()
+    n_local = placed.indices.shape[0] // p
+    local_bs = min(max(1, -(-est.get(est.GLOBAL_BATCH_SIZE) // p)), n_local)
+    with span("fm.init"):
+        w0, w_start, v_start, reg = est._params0(dim)
+        start = jnp.pad(
+            jnp.concatenate([w_start[None, :], v_start.T], axis=0),
+            ((0, 0), (0, padded_dim(dim) - dim))).reshape(
+                v_start.shape[1] + 1, -1, LANES)
+        w0, start = mesh.replicate((w0, start))
+    trainer = _trainer(mesh.mesh, logistic, local_bs, DeviceMesh.DATA_AXIS,
+                       placed.slot_plan, precision)
+    with span("fm.loop"):
+        with span("fm.dispatch"):
+            out = trainer(
+                w0, start, placed.indices, placed.values, placed.labels,
+                placed.weights, placed.starts,
+                np.float32(est.get(est.LEARNING_RATE)), np.asarray(reg)[0],
+                np.int32(est.get(est.MAX_ITER)),
+                np.float32(est.get(est.TOL)))
+        # The caller reads the parameters next: waiting here costs
+        # nothing and gives the loop a span its device time lies in.
+        jax.block_until_ready(out)
+    with span("fm.readback"):
+        w0, steps = np.asarray(out[0]), int(out[2])
+        learned = np.asarray(out[1]).reshape(out[1].shape[0], -1)
+        # As the model holds them: a column's factors side by side.
+        weights, factors = learned[0, :dim], np.ascontiguousarray(learned[1:, :dim].T)
+    group = metrics.group("fm")
+    group.counter("fits")
+    group.counter("steps", float(steps))
+    group.counter("rows", float(placed.rows))
+    group.counter("cells", placed.cells)
+    group.counter("blocked_cells", placed.blocked_cells)
+    return w0, weights, factors
+
+
+def csr_margin(csr, w0: float, w: np.ndarray, v: np.ndarray,
+               chunk_rows: int = 1 << 16) -> np.ndarray:
+    """The model's margin for every row of a ``CsrColumn``, from its
+    arrays (no ``SparseVector`` is built): NumPy at the parameters' own
+    precision, a chunk of rows at a time."""
+    if csr.dim != w.shape[0]:
+        raise ValueError(
+            f"sparse features have dim {csr.dim}, model expects {w.shape[0]}")
+    indptr = np.asarray(csr.indptr, np.int64)
+    n = indptr.size - 1
+    out = np.full(n, w0, np.result_type(w.dtype, np.float32))
+    for lo in range(0, n, chunk_rows):
+        hi = min(lo + chunk_rows, n)
+        cells = slice(indptr[lo], indptr[hi])
+        idx, x = csr.indices[cells], csr.values[cells].astype(out.dtype)
+        row = np.repeat(np.arange(hi - lo), np.diff(indptr[lo:hi + 1]))
+        xv = x[:, None] * v[idx]
+        per_row = x * w[idx] - 0.5 * np.sum(xv * xv, axis=1)
+        out[lo:hi] += np.bincount(row, weights=per_row, minlength=hi - lo)
+        for f in range(v.shape[1]):
+            s_f = np.bincount(row, weights=xv[:, f], minlength=hi - lo)
+            out[lo:hi] += 0.5 * s_f * s_f
+    return out

@@ -44,14 +44,12 @@ from flinkml_tpu.linalg import check_csr_structure as _check_csr_structure
 from flinkml_tpu.ops.losses import margin_terms as _margin_grad
 from flinkml_tpu.ops.sparse import (
     LANES,
-    align_ragged_rows,
     block_accumulate,
     block_groups,
     block_lookup,
     ell_matvec,
-    one_width_block,
     pack_ell_buckets,
-    slot_block_plan,
+    planned_block,
 )
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.parallel.mesh import gather_pool
@@ -724,35 +722,26 @@ def prepare_sparse_buckets(
         # filled, and the span is the look at ``indptr`` and the plan.
         # (The block products move float32 unrounded, and no other
         # width: any other training dtype has no plan.)
-        planned = np.dtype(dtype) == np.float32
-        slot_plan, starts, aligned = (), None, None
-        block = one_width_block(indptr, indices, values, dtype)
         with gather_pool() as pool:
-            if block is None and planned:
-                block = aligned = align_ragged_rows(
-                    indptr, indices, values, dtype, pool)
-            if block is not None and planned:
-                # One block whose slot j holds every row's j-th cell (or
-                # its j-th field's).
-                slot_plan, starts = slot_block_plan(
-                    block["indices"], dim,
-                    math.ceil(global_batch_size / p_size), pool)
-        if block is None or (aligned is not None and not slot_plan):
+            block, slot_plan, starts, blocked_cells = planned_block(
+                indptr, indices, values, dim, dtype,
+                math.ceil(global_batch_size / p_size), pool,
+                plan=np.dtype(dtype) == np.float32)
+        if block is None:
             # Ragged rows that keep to no fields (or to fields too wide
             # to block): padded buckets, as before there were plans.
-            aligned = None
             buckets, row_ids = pack_ell_buckets(
                 indptr, indices, values, dim, max_buckets=max_buckets,
                 dtype=dtype)
         else:
+            # One block whose slot j holds every row's j-th cell (or
+            # its j-th field's).
             buckets, row_ids = [block], [None]
     counts = metrics.group("hostdata.sparse")
     counts.counter("cells", float(indptr[-1]))
-    blocked = [j for j, length in enumerate(slot_plan) if length is not None]
-    counts.counter("blocked_slots", float(len(blocked)))
-    counts.counter("blocked_cells", float(
-        len(blocked) * n if aligned is None
-        else aligned["slot_cells"][blocked].sum()))
+    counts.counter("blocked_slots",
+                   float(sum(length is not None for length in slot_plan)))
+    counts.counter("blocked_cells", blocked_cells)
     counts.counter("padded_cells",
                    float(sum(b["indices"].size for b in buckets)))
     counts.counter("buckets", float(len(buckets)))

@@ -9,6 +9,11 @@ exists. Training rides the shared whole-run Adam device trainer
 (``_adam.make_adam_trainer``): one program, psum'd minibatch steps over
 the data-sharded mesh. L2 regularization applies to w and V (not the
 intercept), scaled per-minibatch like the loss.
+
+A features column that is a ``table.CsrColumn`` (hashed click logs at
+``dim`` 1e6, which no ``[rows, dim]`` matrix can hold) takes the sparse
+fit of ``models/_fm_sparse.py`` instead: the same equations over cells,
+the same Adam, batches that are windows of a seeded row order.
 """
 
 from __future__ import annotations
@@ -37,6 +42,7 @@ from flinkml_tpu.common_params import (
     HasWeightCol,
 )
 from flinkml_tpu.models._adam import make_adam_trainer
+from flinkml_tpu.models._fm_sparse import LOOKUP_PRECISION  # noqa: F401 — the sparse fit's, public here
 from flinkml_tpu.models._data import (
     check_binary_labels,
     features_matrix,
@@ -45,6 +51,7 @@ from flinkml_tpu.models._data import (
 from flinkml_tpu.params import IntParam, ParamValidators
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.profiling import span
 
 
 class _FMParams(
@@ -217,8 +224,22 @@ def _fm_sharded_trainer(mesh, row_entry, n_shards: int, emu_bs: int,
     ))
 
 
+def start_factors(dim: int, factor_size: int, seed: int) -> jax.Array:
+    """The factors every fit starts from: ``0.01 * N(0, 1)`` ``[dim,
+    factor_size]`` float32 from the seed (``w0`` and ``w`` start at 0)."""
+    return jax.random.normal(
+        jax.random.PRNGKey(seed), (dim, factor_size), jnp.float32) * 0.01
+
+
 class _FMBase(StreamingEstimatorMixin, _FMParams, Estimator):
-    """``fit`` also accepts an iterable of batch Tables or a sealed
+    """``fit`` of a :class:`Table` whose features column is a
+    ``table.CsrColumn`` is the sparse fit (``models/_fm_sparse.py``): the
+    cells stay on the mesh WITH the table, batches are windows of a
+    seeded row order, the whole run is the program ``fm_adam_loop``. A
+    dense or object column takes the dense fit below, whose batches are
+    ``_adam``'s draws with replacement.
+
+    ``fit`` also accepts an iterable of batch Tables or a sealed
     :class:`~flinkml_tpu.iteration.datacache.DataCache` — the
     out-of-core path (the shared streamed-Adam runner,
     :func:`flinkml_tpu.models._adam.run_streamed_adam`; reference replay
@@ -241,15 +262,11 @@ class _FMBase(StreamingEstimatorMixin, _FMParams, Estimator):
 
     def _params0(self, d: int):
         """Initial flat params tuple (bias, w, V, frozen reg tail) — the
-        single source for the in-RAM and streamed paths."""
-        k = self.get(self.FACTOR_SIZE)
-        v0 = jax.random.normal(
-            jax.random.PRNGKey(self.get_seed()), (d, k), jnp.float32
-        ) * 0.01
+        single source for the in-RAM, streamed and sparse paths."""
         return (
             jnp.zeros(1, jnp.float32),
             jnp.zeros(d, jnp.float32),
-            v0,
+            start_factors(d, self.get(self.FACTOR_SIZE), self.get_seed()),
             jnp.asarray([self.get(self.REG)], jnp.float32),
         )
 
@@ -387,11 +404,27 @@ class _FMBase(StreamingEstimatorMixin, _FMParams, Estimator):
             np.asarray(w0), np.asarray(w_sh)[:d], np.asarray(v_sh)[:d],
         ))
 
+    def _fit_csr(self, table: Table):
+        """A ``CsrColumn`` of features: the sparse fit
+        (``models/_fm_sparse.py``), which never builds ``[rows, dim]``."""
+        from flinkml_tpu.models import _fm_sparse
+
+        if self.sharding_plan is not None:
+            raise ValueError(
+                f"{type(self).__name__} fits a CsrColumn with its parameter "
+                "table replicated; the sharding_plan fit shards a DENSE "
+                "features matrix's columns. Drop the plan."
+            )
+        with span("fit"):
+            return self._make_model(_fm_sparse.fit_csr(self, table, self._LOGISTIC))
+
     def fit(self, *inputs):
         (table,) = inputs
         if not isinstance(table, Table):
             return self._fit_stream(table)
         self._reject_in_ram_checkpointing()
+        if table.csr_column(self.get(self.FEATURES_COL)) is not None:
+            return self._fit_csr(table)
         x, y, w = labeled_data(
             table, self.get(self.FEATURES_COL), self.get(self.LABEL_COL),
             self.get(self.WEIGHT_COL),
@@ -458,8 +491,13 @@ class _FMModelBase(_FMParams, Model):
     def _margin(self, table: Table) -> np.ndarray:
         from flinkml_tpu.models._data import sparse_features
 
+        csr = table.csr_column(self.get(self.FEATURES_COL))
+        if csr is not None and len(csr):
+            from flinkml_tpu.models._fm_sparse import csr_margin
+
+            # From the column's arrays: no row object is built.
+            return csr_margin(csr, self._w0, self._w, self._v)
         if sparse_features(table, self.get(self.FEATURES_COL)) is not None:
-            # Row objects: a CsrColumn's are built here, on demand.
             return self._margin_sparse(table.column(self.get(self.FEATURES_COL)))
         x = features_matrix(table, self.get(self.FEATURES_COL))
         xv = x @ self._v

@@ -39,17 +39,7 @@ def ell_matvec(indices, values, w) -> jax.Array:
 LANES = 128
 
 
-def _block_one_hots(local, k: int, dtype):
-    """``local = 128 * hi + lo`` as the one-hot of ``hi`` over a block's
-    ``k`` rows of 128 (an operand of the MXU; None where ``k`` is 1) and
-    the mask of lane ``lo`` (a select on the VPU)."""
-    lanes = jax.nn.one_hot(local % LANES, LANES, dtype=jnp.bool_)
-    if k == 1:
-        return None, lanes
-    return jax.nn.one_hot(local // LANES, k, dtype=dtype), lanes
-
-
-def block_lookup(blocks, local) -> jax.Array:
+def block_lookup(blocks, local, precision=jax.lax.Precision.HIGHEST) -> jax.Array:
     """``blocks[s, local[s, b]]`` for ``blocks [S, R]`` float32, ``R`` a
     multiple of 128, and ``local [S, B]`` int32: a gather written as a
     two-level one-hot product, ``onehot(hi) [B, R/128] @ block [R/128,
@@ -60,37 +50,113 @@ def block_lookup(blocks, local) -> jax.Array:
     bit (any lower precision rounds it to bfloat16). A block of 128
     columns needs no product. An index outside ``[0, R)`` reads 0 or some
     element of its block, so such a cell must carry the value 0 (the zero
-    rows a shard is padded with do)."""
+    rows a shard is padded with do).
+
+    ``blocks [S, R, k]`` are blocks of ROWS, ``k`` floats a column (a
+    factorization machine's weight and factors), and the result is ``[S,
+    B, k]``, the rows ``blocks[s, local[s, b], :]`` bit for bit: the same
+    two levels with :func:`lookup_columns` columns a product row, so
+    that neither the one-hot nor the product's result is long.
+    ``precision`` is for a control alone (one bfloat16 pass rounds every
+    looked-up float)."""
+    if blocks.ndim == 3:
+        return _payload_lookup(blocks, local, precision)
     s, r = blocks.shape
     k = r // LANES
-    rows_of, lanes = _block_one_hots(local, k, blocks.dtype)
+    rows_of, lanes = _one_hots(local, r, LANES, blocks.dtype)
     if rows_of is None:
         rows = blocks[:, None, :]
     else:
         rows = jnp.einsum(
             "sbk,skl->sbl", rows_of, blocks.reshape(s, k, LANES),
-            precision=jax.lax.Precision.HIGHEST,
+            precision=precision,
             preferred_element_type=blocks.dtype)
     return jnp.sum(jnp.where(lanes, rows, 0), axis=-1)
 
 
-def block_accumulate(local, contrib, length: int) -> jax.Array:
+def block_accumulate(local, contrib, length: int,
+                     precision=jax.lax.Precision.HIGHEST) -> jax.Array:
     """``zeros([S, length]).at[s, local[s, b]].add(contrib[s, b])``, the
     transpose of :func:`block_lookup`: ``onehot(hi)ᵀ [R/128, B] @
     (onehot(lo) · contrib) [B, 128]``, the products exact, accumulated in
     float32, the same bits every time. A cell whose index lies outside
-    ``[0, length)`` must contribute 0."""
+    ``[0, length)`` must contribute 0. ``contrib [S, B, k]`` (a row of
+    ``k`` floats a cell) gives ``[S, length, k]``."""
+    if contrib.ndim == 3:
+        return _payload_accumulate(local, contrib, length, precision)
     s, _ = local.shape
     k = length // LANES
-    rows_of, lanes = _block_one_hots(local, k, contrib.dtype)
+    rows_of, lanes = _one_hots(local, length, LANES, contrib.dtype)
     spread = jnp.where(lanes, contrib[..., None], 0)
     if rows_of is None:
         return jnp.sum(spread, axis=1)
     out = jnp.einsum(
         "sbk,sbl->skl", rows_of, spread,
-        precision=jax.lax.Precision.HIGHEST,
+        precision=precision,
         preferred_element_type=contrib.dtype)
     return out.reshape(s, length)
+
+
+def lookup_columns(length: int) -> int:
+    """The columns ``c`` one product row of a payload LOOKUP holds, for a
+    block of ``length`` columns: the one-hot is ``length / c`` long and
+    the product's row ``c`` columns of the payload (a power of two up to
+    128, so it divides every block length). The MXU's work is ``length x
+    payload`` a cell whatever ``c`` is; what ``c`` moves is the one-hot
+    the vector unit makes, the row it then picks one column from, and
+    the schedule the compiler finds. ``length / 64`` up to 16, and 128
+    (rows of 128 lanes, as the one-float lookup) from 8,192 columns up:
+    read off whole steps on a v5e at 65,536 rows and a payload of 17
+    (PERF.md section 5, PR 36: 26.8 ms a step; 29.1 at 32 for long
+    blocks, 33.2 at 64, 36.1 at 8)."""
+    return LANES if length >= 8192 else max(2, min(16, length // 64))
+
+
+def accumulate_columns(length: int) -> int:
+    """:func:`lookup_columns` for a payload ACCUMULATION, whose product
+    contracts the batch: ``length / 64`` up to 8, and 128 from 6,144
+    columns up. Never 16: the compiler's schedule for a ``[.., 16, 17]``
+    operand costs a long block 5 to 7 times what 8 or 32 do (101 ms a
+    step; PR 36, as above), and 32 for long blocks costs 51 ms beside a
+    lookup at 64 or 128 where it costs 29 beside one at 32."""
+    return LANES if length >= 6144 else max(2, min(8, length // 64))
+
+
+def _one_hots(local, length: int, c: int, dtype):
+    """``local = c * hi + lo`` as the one-hot of ``hi`` over a block's
+    ``length / c`` product rows (an operand of the MXU; None where that
+    is one row) and the mask of column ``lo`` of a row (a select on the
+    VPU). The one-float products have rows of ``c`` = 128 lanes."""
+    cols = jax.nn.one_hot(local % c, c, dtype=jnp.bool_)
+    if length == c:
+        return None, cols
+    return jax.nn.one_hot(local // c, length // c, dtype=dtype), cols
+
+
+def _payload_lookup(blocks, local, precision):
+    s, r, k = blocks.shape
+    c = lookup_columns(r)
+    rows_of, cols = _one_hots(local, r, c, blocks.dtype)
+    if rows_of is None:
+        rows = blocks[:, None]
+    else:
+        rows = jnp.einsum(
+            "sba,sack->sbck", rows_of, blocks.reshape(s, r // c, c, k),
+            precision=precision, preferred_element_type=blocks.dtype)
+    return jnp.sum(jnp.where(cols[..., None], rows, 0), axis=2)
+
+
+def _payload_accumulate(local, contrib, length, precision):
+    s, _, k = contrib.shape
+    c = accumulate_columns(length)
+    rows_of, cols = _one_hots(local, length, c, contrib.dtype)
+    spread = jnp.where(cols[..., None], contrib[:, :, None, :], 0)
+    if rows_of is None:
+        return jnp.sum(spread, axis=1)
+    out = jnp.einsum(
+        "sba,sbck->sack", rows_of, spread,
+        precision=precision, preferred_element_type=contrib.dtype)
+    return out.reshape(s, length, k)
 
 
 class BatchedCSR:
@@ -647,18 +713,24 @@ def slot_block_plan(indices: np.ndarray, dim: int, step_rows: int, pool):
     return tuple(plan), np.asarray(starts, np.int32)
 
 
-def block_groups(slot_plan: tuple, step_rows: int):
+def block_groups(slot_plan: tuple, step_rows: int, payload: int = 0):
     """The blocked slots of a plan, those of one block length together
     (one product serves them) as far as :data:`_BLOCK_ONE_HOT_ELEMENTS`
-    allows over ``step_rows`` rows: ``[(length, slots)]``."""
+    allows over ``step_rows`` rows: ``[(length, slots)]``. What is
+    bounded is a slot's longest operand a row: the one-hot of the
+    one-float products, or, with a ``payload`` of floats a column, the
+    longer of a payload product's one-hot and its row of columns, of the
+    lookup or of the accumulation."""
     by_length: dict = {}
     for slot, length in enumerate(slot_plan):
         if length is not None:
             by_length.setdefault(length, []).append(slot)
     groups = []
     for length, slots in sorted(by_length.items()):
-        most = max(1, _BLOCK_ONE_HOT_ELEMENTS
-                   // (step_rows * (length // LANES)))
+        longest = length // LANES if not payload else max(
+            max(length // c, c * payload)
+            for c in (lookup_columns(length), accumulate_columns(length)))
+        most = max(1, _BLOCK_ONE_HOT_ELEMENTS // (step_rows * longest))
         groups += [(length, slots[i:i + most])
                    for i in range(0, len(slots), most)]
     return groups
@@ -738,6 +810,34 @@ def align_ragged_rows(indptr, indices, values, dtype, pool):
         return None
     return {"indices": out_i, "values": out_v,
             "slot_cells": np.sum(filled, axis=0)}
+
+
+def planned_block(indptr, indices, values, dim: int, dtype, step_rows: int,
+                  pool, plan: bool = True):
+    """A table's cells as ONE ELL block with its slot plan, where it has
+    one: ``(block, slot_plan, starts, blocked_cells)``. Rows of one width
+    are the block as held (:func:`one_width_block`); ragged rows that
+    keep to fields are laid one field a slot (:func:`align_ragged_rows`);
+    either is planned (:func:`slot_block_plan` over ``step_rows`` rows a
+    step). ``block`` is None where the table is neither, or where rows
+    had to be aligned and no slot came out blocked: the caller pads
+    buckets (:func:`pack_ell_buckets`) under the empty plan. ``plan``
+    False (a training dtype the block products do not move unrounded)
+    aligns and plans nothing. ``blocked_cells`` counts the cells in
+    blocked slots (an aligned table's missing cells left out)."""
+    n = np.asarray(indptr).size - 1
+    slot_plan, starts, aligned = (), None, None
+    block = one_width_block(indptr, indices, values, dtype)
+    if block is None and plan:
+        block = aligned = align_ragged_rows(indptr, indices, values, dtype, pool)
+    if block is not None and plan:
+        slot_plan, starts = slot_block_plan(block["indices"], dim, step_rows, pool)
+    if aligned is not None and not slot_plan:
+        return None, (), None, 0.0
+    blocked = [j for j, length in enumerate(slot_plan) if length is not None]
+    return block, slot_plan, starts, float(
+        len(blocked) * n if aligned is None
+        else aligned["slot_cells"][blocked].sum())
 
 
 # Chunk width of the two-level running sum in chunked_run_totals. Within-

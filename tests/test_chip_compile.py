@@ -147,3 +147,64 @@ def test_kmeans_whole_loop_at_the_cells_size(topo, no_compile_cache):
     memory = compiled.memory_analysis()
     assert 0.39 * 16e9 < memory.argument_size_in_bytes < 0.41 * 16e9
     assert memory.temp_size_in_bytes < 0.05e9
+
+
+#: ``fm-criteo.fit``'s slot plan: what ``ops.sparse.slot_block_plan`` reads
+#: off ``datagen_criteo``'s rows under ``benchmark/configs/fm-criteo.json``
+#: (field ``f`` on ``min(cardinality, 25,641)`` columns from ``25,641 f``),
+#: up the ladder of block lengths: 269,696 block columns over 39 slots.
+FM_CRITEO_PLAN = (
+    128, 128, 256, 256, 128, 256, 256, 128, 256, 256, 128, 256, 256, 2048, 1024,
+    26624, 26624, 512, 128, 13312, 1024, 128, 26624, 6144, 26624, 4096, 128,
+    15360, 26624, 128, 6144, 3072, 128, 26624, 256, 128, 26624, 256, 26624)
+
+
+@pytest.mark.parametrize("precision", ["HIGHEST", "DEFAULT"])
+def test_fm_adam_loop_at_the_cells_size(topo, no_compile_cache, precision):
+    """``fm-criteo.fit``'s one program: Adam's whole run over 16,777,216 x
+    39 resident cells, ``dim`` 1,000,000, 16 factors, batch 65,536, under
+    the cell's slot plan, on a one-chip mesh (the ``psum`` included), in
+    32-bit mode; at the program's precision and at the one bfloat16 pass
+    of the benchmark's control. XLA's lowering, no kernel. Beside the
+    resident table the program holds its parameter table, two moments and
+    the gradient as ``[17, 7813, 128]`` arrays (68 MB each: laid ``[dim,
+    17]`` or ``[17, dim]`` a v5e pads the 17 to 128 lanes, 512 MB each)
+    and the walk's operands, under a gigabyte in all."""
+    import numpy as np
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flinkml_tpu.models import _fm_sparse
+
+    rows, width, dim, k, batch = 16_777_216, 39, 1_000_000, 16, 65_536
+    assert len(FM_CRITEO_PLAN) == width and sum(FM_CRITEO_PLAN) == 269_696
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    # As a v5e holds them (PR 30): an ELL table lies with its ROWS along
+    # the lanes.
+    rows_minor = Format(Layout(major_to_minor=(1, 0)), by_rows)
+
+    def on(shape, dtype, sharding=whole):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    f32, i32 = jnp.float32, jnp.int32
+    assert _fm_sparse.LOOKUP_PRECISION == jax.lax.Precision.HIGHEST
+    with jax.enable_x64(False):
+        compiled = _fm_sparse._trainer(
+            mesh, True, batch, "data", FM_CRITEO_PLAN,
+            getattr(jax.lax.Precision, precision)).trace(
+            on((1,), f32), on((k + 1, _fm_sparse.padded_dim(dim) // 128, 128), f32),
+            on((rows, width), i32, rows_minor), on((rows, width), f32, rows_minor),
+            on((rows,), f32, by_rows), on((rows,), f32, by_rows),
+            on((width,), i32), on((), f32), on((), f32), on((), i32),
+            on((), f32)).lower().compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text          # XLA's lowering, no kernel
+    # every product of the walk at the precision asked for
+    assert (text.count("operand_precision={highest,highest}") > 0) == (
+        precision == "HIGHEST")
+    memory = compiled.memory_analysis()
+    # the cells, labels and weights: 5.37 GB, a third of the chip
+    assert 0.33 * 16e9 < memory.argument_size_in_bytes < 0.36 * 16e9
+    assert memory.temp_size_in_bytes < 1.0e9
+    assert memory.output_size_in_bytes < 0.1e9    # 17 x 7813 x 128 floats

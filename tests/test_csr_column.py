@@ -363,16 +363,29 @@ def test_the_sparse_fit_has_its_spans_and_counters(uniform):
 @pytest.mark.parametrize("kind", ["fm", "gbt"])
 def test_the_other_sparse_consumers_take_the_column(kind):
     """GBT's hashed route takes the arrays (``hashed_feature_matrix``);
-    FM's margin packs row objects and asks ``column()`` for them."""
+    FM's margin takes them too since PR 36 (``_fm_sparse.csr_margin``:
+    the same sums in another order, so float64 rounding apart from the
+    row objects' path, and no row object built)."""
     from flinkml_tpu.models.fm import FMClassifier
     from flinkml_tpu.models.gbt import GBTClassifier
 
     csr, obj = _tables(False, rows=201)
     est = FMClassifier().set_max_iter(3) if kind == "fm" else GBTClassifier()
     model = est.fit(obj)
-    (got,), (want,) = model.transform(csr), model.transform(obj)
+    built = metrics.group("table").snapshot()["counters"].get(
+        "csr_rows_materialized", 0.0)
+    (got,) = model.transform(csr)
+    if kind == "fm":
+        assert metrics.group("table").snapshot()["counters"].get(
+            "csr_rows_materialized", 0.0) == built
+    (want,) = model.transform(obj)
     for name in want.column_names:
-        if name != "features":
+        if name == "features":
+            continue
+        if kind == "fm" and name == "rawPrediction":
+            np.testing.assert_allclose(got.column(name), want.column(name),
+                                       rtol=1e-12, atol=1e-15)
+        else:
             np.testing.assert_array_equal(got.column(name), want.column(name))
     if kind == "gbt":  # its fit is deterministic: the column trains the same trees
         (again,) = est.fit(csr).transform(obj)

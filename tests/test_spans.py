@@ -695,6 +695,17 @@ def _lowered_programs():
             _struct((64,), f32, rows), _struct((3, 5), f32, rep),
             _struct((), i32, rep)).as_text()
 
+    def fm_adam_loop():
+        from flinkml_tpu.models import _fm_sparse
+
+        rep, rows = shardings()
+        return _fm_sparse._trainer(m(), True, 8, "data", (128, None)).lower(
+            _struct((1,), f32, rep), _struct((4, 3, 128), f32, rep),
+            _struct((128, 2), i32, rows), _struct((128, 2), f32, rows),
+            _struct((128,), f32, rows), _struct((128,), f32, rows),
+            _struct((2,), i32, rep), _struct((), f32, rep), _struct((), f32, rep),
+            _struct((), i32, rep), _struct((), f32, rep)).as_text()
+
     def knn_vote():
         return knn._knn_vote.lower(
             _struct((16, 5), f32), _struct((64, 5), f32), _struct((64,), f32),
@@ -732,6 +743,7 @@ def _lowered_programs():
         "stage_ones": lambda: mesh_mod._ones_below(m(), "data").lower(
             _struct((), i32), 64, np.dtype(np.float32)).as_text(),
         "kmeans_lloyd": kmeans_lloyd,
+        "fm_adam_loop": fm_adam_loop,
         "knn_vote": knn_vote,
         "rows_sq": lambda: blas.squared_norms.lower(_struct((64, 5), f32)).as_text(),
         "fused_chain": fused_chain,
@@ -739,8 +751,8 @@ def _lowered_programs():
 
 
 PROGRAMS = ("lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop", "stage_write",
-            "stage_zeros", "stage_ones", "kmeans_lloyd", "knn_vote", "rows_sq",
-            "fused_chain")
+            "stage_zeros", "stage_ones", "kmeans_lloyd", "fm_adam_loop", "knn_vote",
+            "rows_sq", "fused_chain")
 
 
 @pytest.mark.parametrize("name", PROGRAMS)
