@@ -110,15 +110,19 @@ def _ell_block(indptr, indices, values, dim: int):
 
 
 def _place_table(indptr, indices, values, dim: int, y, w, mesh: DeviceMesh,
-                 seed: int) -> _Placed:
+                 seed: int, make_room) -> _Placed:
     """Every row on the mesh, once: the plan (``hostdata.sparse_pack``),
     then the cells, labels and weights through :meth:`DeviceMesh.
     stage_rows` in the seeded order (``fm.table_to_device``), padded with
-    zero rows of weight 0 to the mesh."""
+    zero rows of weight 0 to the mesh. ``make_room`` is the table's that
+    will keep them (:meth:`Table.device_resident`), told their bytes
+    once the block's width is known."""
     with span("hostdata.sparse_pack"):
         block, slot_plan, starts, blocked_cells = _ell_block(
             indptr, indices, values, dim)
-    n = block["indices"].shape[0]
+    n, width = block["indices"].shape
+    # int32 cells, float32 values, labels and weights.
+    make_room(n * (8 * width + 8), mesh.mesh.devices.flat)
     with span("fm.table_to_device") as phase:
         with span("hostdata.shuffle"), span("hostdata.permute"):
             order = np.random.default_rng(seed).permutation(n)
@@ -312,8 +316,9 @@ def fit_csr(est, table, logistic: bool, precision=LOOKUP_PRECISION):
     placed = table.device_resident(
         ("fm_rows_on_mesh", features_col, label_col, weight_col,
          mesh.mesh, "float32", seed),
-        lambda: _place_table(indptr, indices, values, dim, labels.values,
-                             w, mesh, seed))
+        lambda make_room: _place_table(
+            indptr, indices, values, dim, labels.values, w, mesh, seed,
+            make_room))
     p = mesh.axis_size()
     n_local = placed.indices.shape[0] // p
     local_bs = min(max(1, -(-est.get(est.GLOBAL_BATCH_SIZE) // p)), n_local)

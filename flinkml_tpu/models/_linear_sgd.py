@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -144,6 +144,73 @@ def _placed(rounds) -> Tuple:
     """A placement run to its last round: the arrays, whole."""
     *_, (arrays, _) = rounds
     return arrays
+
+
+class _Kept(NamedTuple):
+    """A fit's placement run to its last round, as its table keeps it."""
+
+    arrays: Tuple             # what the loop was handed
+    reaches: Tuple[int, ...]  # local rows each bucket is complete to
+    plan: Tuple               # sparse: (local_bss, slot_plan, local rows a bucket)
+
+
+def table_placements(table, features_col: str, label_col: str,
+                     weight_col: Optional[str]):
+    """What the trainers take as ``kept``: ``kept(tag, *rest)`` is the
+    table's :class:`~flinkml_tpu.table.ResidentSlot` for a placement of
+    these columns, the trainer adding what else its arrays depend on
+    (the mesh, the placed dtype, the seed; the sparse fit its batch and
+    bucket count). Rate, ``reg``, ``tol`` and ``max_iter`` are the loop's
+    operands and key nothing."""
+
+    def kept(tag: str, *rest):
+        return table.resident(
+            (tag, features_col, label_col, weight_col) + rest)
+
+    return kept
+
+
+def _find_or_keep(slot, place, windows, nbytes: int, mesh: DeviceMesh,
+                  plan: Tuple = ()):
+    """``place`` as a table that keeps placements answers it (``slot``
+    None, a caller with arrays and no table: ``place`` itself).
+    ``windows`` is ``(local rows, local batch)`` a bucket, ``nbytes``
+    what the placement puts on the mesh.
+
+    A hit: the table holds a placement under the slot's key complete as
+    far as steps ``[first, last)`` read (:func:`_reach_rows`), and it is
+    the ONE round, ready to ``last``: nothing is permuted, gathered or
+    sent, and :func:`_run_chunked` makes one dispatch. Anything else is a
+    miss, the fit as it is without a table: the rounds of ``place``,
+    after the set of kept placements has made room for them (an entry
+    that fell short goes first), kept once the last round has been
+    handed out and asked past, so a fit that raises before then keeps
+    nothing. ``metrics.group("hostdata")`` counts ``placement_hits`` and
+    ``placement_misses``."""
+    if slot is None:
+        return place
+
+    def found_or_placed(first: int, last: int):
+        need = tuple(_reach_rows(rows, bs, first, last) for rows, bs in windows)
+        kept = slot.find()
+        hit = kept is not None and all(
+            n <= have for n, have in zip(need, kept.reaches))
+        counts = metrics.group("hostdata")
+        counts.counter("placement_hits", float(hit))
+        counts.counter("placement_misses", float(not hit))
+        if hit:
+            return iter([(kept.arrays, last)])
+        slot.make_room(nbytes, mesh.mesh.devices.flat)
+        # Started here, as without a table: the order is computed before
+        # the loop's span opens, the rounds inside it.
+        return keep_whole(place(first, last), need)
+
+    def keep_whole(rounds, need):
+        for arrays, ready in rounds:
+            yield arrays, ready
+        slot.keep(_Kept(arrays, need, plan))
+
+    return found_or_placed
 
 
 def make_dense_step(loss: str, local_bs: int, axis: str):
@@ -530,6 +597,27 @@ def _place_shuffled(x, y, w, mesh: DeviceMesh, seed: int, dtype,
             for arrays, complete in mesh.stage_rows(columns, reach_rows))
 
 
+def _dense_placement(x, y, w, mesh: DeviceMesh, seed: int, dtype,
+                     n_local: int, local_bs: int, kept):
+    """What :func:`_run_chunked` follows for a dense table (the binomial
+    and the softmax fits place the same three arrays): the rounds of
+    :func:`_place_shuffled` as far as the steps reach, found again or
+    kept with the table where ``kept`` names one (:func:`_find_or_keep`)."""
+    place = _follow_windows(
+        functools.partial(_place_shuffled, x, y, w, mesh, seed, dtype),
+        n_local, local_bs)
+    if kept is None:
+        return place
+    dt = _placed_dtype(x, dtype)
+    rows = mesh.axis_size() * n_local
+    # ``dtype`` None places every column at its own width.
+    return _find_or_keep(
+        kept("linear_rows_on_mesh", mesh.mesh,
+             "own" if dtype is None else dt.name, seed), place,
+        [(n_local, local_bs)],
+        rows * (int(np.prod(x.shape[1:])) + 2) * dt.itemsize, mesh)
+
+
 def _placed_dtype(x, dtype):
     """The dtype the device holds ``x`` at: ``dtype`` (None: ``x``'s
     own) as ``device_put`` narrows it where x64 is off."""
@@ -571,6 +659,7 @@ def train_linear_model(
     listeners=(),
     sharding_plan=None,
     precision=None,
+    kept=None,
 ) -> np.ndarray:
     """Dense distributed training; returns the coefficient on host.
 
@@ -604,6 +693,11 @@ def train_linear_model(
     the step's jaxpr is validated against the policy BEFORE any compile
     by the FML6xx precision-flow pass — see
     ``docs/development/precision.md``.
+
+    ``kept`` (:func:`table_placements`; None for a caller with arrays
+    and no table) is where the replicated fit keeps its placement WITH
+    its table and finds it again (:func:`_find_or_keep`); the plan and
+    precision paths keep nothing.
     """
     if loss not in _LOSS_KEYS:
         raise ValueError(f"loss must be one of {_LOSS_KEYS}, got {loss!r}")
@@ -644,9 +738,7 @@ def train_linear_model(
     n_local = -(-n // p_size)
     local_bs = align_local_bs(global_batch_size, p_size, n_local)
     trainer = _dense_trainer(mesh.mesh, loss, local_bs, DeviceMesh.DATA_AXIS)
-    place = _follow_windows(
-        functools.partial(_place_shuffled, x, y, w, mesh, seed, dtype),
-        n_local, local_bs)
+    place = _dense_placement(x, y, w, mesh, seed, dtype, n_local, local_bs, kept)
     return _run_chunked(
         trainer, place, x.shape[1], _placed_dtype(x, dtype),
         learning_rate, reg * (1.0 - elastic_net), reg * elastic_net,
@@ -660,7 +752,7 @@ def train_linear_model(
 def prepare_sparse_buckets(
     indptr, indices, values, dim: int, y, w, mesh: DeviceMesh,
     global_batch_size: int, max_buckets: int = 4, dtype=np.float32,
-    seed: Optional[int] = None,
+    seed: Optional[int] = None, kept=None,
 ) -> Tuple[Tuple, Tuple[int, ...], Tuple]:
     """Pack CSR data for the bucketed trainer, and say how it is
     shuffled, padded and sharded onto the mesh.
@@ -711,7 +803,49 @@ def prepare_sparse_buckets(
     and a weight column ``w`` go in the same rounds, cast to ``dtype`` in
     the gather; ``w`` None is unit weights, made on the device
     (:meth:`DeviceMesh.shard_ones`), as in :func:`_place_shuffled`.
+
+    ``kept`` (:func:`table_placements`; None for a caller with arrays
+    and no table): the placement is kept WITH the table once its last
+    round has landed, with ``local_bss``, the plan and each bucket's
+    reach, under a key of the mesh, ``dtype``, ``seed``,
+    ``global_batch_size`` and ``max_buckets`` (the buckets and their
+    windows follow from both). A table that holds one answers from it:
+    no bucket is chosen, no plan read, and ``place`` on steps it covers
+    is one round of the kept arrays (:func:`_find_or_keep`); steps past
+    its reach pack and place as a table without one does.
     """
+    p_size = mesh.axis_size()
+    slot = found = None
+    if kept is not None:
+        slot = kept("linear_cells_on_mesh", mesh.mesh, np.dtype(dtype).name,
+                    seed, int(global_batch_size), int(max_buckets))
+        found = slot.find()
+    if found is not None:
+        local_bss, slot_plan, rows = found.plan
+
+        def place(first: int, last: int):
+            # The kept placement falls short of these steps.
+            return _pack_sparse_buckets(
+                indptr, indices, values, dim, y, w, mesh, global_batch_size,
+                max_buckets, dtype, seed)[0](first, last)
+
+        nbytes = sum(a.nbytes for a in found.arrays)
+    else:
+        place, local_bss, slot_plan, rows, nbytes = _pack_sparse_buckets(
+            indptr, indices, values, dim, y, w, mesh, global_batch_size,
+            max_buckets, dtype, seed)
+    place = _find_or_keep(slot, place, list(zip(rows, local_bss)), nbytes,
+                          mesh, (local_bss, slot_plan, rows))
+    return place, local_bss, slot_plan
+
+
+def _pack_sparse_buckets(indptr, indices, values, dim: int, y, w,
+                         mesh: DeviceMesh, global_batch_size: int,
+                         max_buckets: int, dtype, seed: Optional[int]):
+    """:func:`prepare_sparse_buckets` for a table with no kept placement:
+    the bucket choice, the plan and the placement it documents, as
+    ``(place, local_bss, slot_plan, local rows a bucket, bytes the
+    placement puts on the mesh)``."""
     indptr = np.asarray(indptr, dtype=np.int64)
     n = indptr.size - 1
     y = np.asarray(y)
@@ -799,7 +933,12 @@ def prepare_sparse_buckets(
 
         return rounds()
 
-    return place, local_bss, slot_plan
+    rows = tuple(-(-b["indices"].shape[0] // p_size) for b in buckets)
+    width = np.dtype(dtype).itemsize
+    # A bucket's cells (int32 indices, values) and its labels and weights.
+    nbytes = sum(p_size * r * (b["indices"].shape[1] * (4 + width) + 2 * width)
+                 for r, b in zip(rows, buckets))
+    return place, local_bss, slot_plan, rows, nbytes
 
 
 def train_linear_model_sparse_csr(
@@ -824,6 +963,7 @@ def train_linear_model_sparse_csr(
     checkpoint_interval: int = 0,
     resume: bool = False,
     listeners=(),
+    kept=None,
 ) -> np.ndarray:
     """Skew-proof sparse training from host CSR arrays.
 
@@ -833,7 +973,8 @@ def train_linear_model_sparse_csr(
     the data, not with the worst row. Each step takes a proportional
     window from every bucket (stratified batch); with batch ≥ n this is
     exactly the full-dataset gradient. ``y`` and ``w`` as in
-    :func:`prepare_sparse_buckets` (``w`` None: unit weights).
+    :func:`prepare_sparse_buckets` (``w`` None: unit weights; ``kept``:
+    where the placement stays with its table).
     """
     if loss not in _LOSS_KEYS:
         raise ValueError(f"loss must be one of {_LOSS_KEYS}, got {loss!r}")
@@ -842,7 +983,7 @@ def train_linear_model_sparse_csr(
         raise ValueError("training table is empty")
     place, local_bss, slot_plan = prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
-        max_buckets=max_buckets, dtype=dtype, seed=seed,
+        max_buckets=max_buckets, dtype=dtype, seed=seed, kept=kept,
     )
     trainer = _sparse_trainer_bucketed(
         mesh.mesh, loss, tuple(local_bss), DeviceMesh.DATA_AXIS, int(dim),
@@ -918,12 +1059,14 @@ def train_softmax_model(
     checkpoint_interval: int = 0,
     resume: bool = False,
     listeners=(),
+    kept=None,
 ) -> np.ndarray:
     """Multinomial logistic regression: returns coefficient ``[k, d]``.
 
     Same distributed machinery as :func:`train_linear_model` (windowed
-    batches, psum, proximal elastic-net, chunked checkpointing); the loss
-    is weighted softmax cross-entropy over integer labels ``0..k-1``.
+    batches, psum, proximal elastic-net, chunked checkpointing, the
+    placement ``kept`` with the table); the loss is weighted softmax
+    cross-entropy over integer labels ``0..k-1``.
     """
     n = x.shape[0]
     if n == 0:
@@ -936,9 +1079,7 @@ def train_softmax_model(
     )
     # Labels and weights at the features' width, as the step expects.
     dtype = dtype if dtype is not None else x.dtype
-    place = _follow_windows(
-        functools.partial(_place_shuffled, x, y, w, mesh, seed, dtype),
-        n_local, local_bs)
+    place = _dense_placement(x, y, w, mesh, seed, dtype, n_local, local_bs, kept)
     return _run_chunked(
         trainer, place, (int(num_classes), x.shape[1]),
         _placed_dtype(x, dtype),
@@ -1376,9 +1517,11 @@ def train_linear_model_from_table(
     sparse trainer keeps its replicated ``[dim]`` model and refuses a
     plan loudly. ``precision`` (the FML6xx-gated mixed-precision
     policy) rides the same dense-only route and is refused just as
-    loudly on the sparse branch."""
+    loudly on the sparse branch. The replicated fits keep their
+    placement with ``table`` (:func:`table_placements`)."""
     from flinkml_tpu.models._data import sparse_features, sparse_fit_columns
 
+    kept = table_placements(table, features_col, label_col, weight_col)
     if sparse_features(table, features_col) is not None:
         if sharding_plan is not None:
             raise ValueError(
@@ -1398,7 +1541,7 @@ def train_linear_model_from_table(
         if label_check is not None:
             label_check(labels)
         return train_linear_model_sparse_csr(
-            indptr, indices, values, dim, labels.values, w, **hyper
+            indptr, indices, values, dim, labels.values, w, kept=kept, **hyper
         )
     x, labels, w, dtype = dense_table_data(
         table, features_col, label_col, weight_col,
@@ -1410,7 +1553,7 @@ def train_linear_model_from_table(
         label_check(labels)
     hyper.setdefault("dtype", dtype)
     return train_linear_model(x, labels.values, w, sharding_plan=sharding_plan,
-                              precision=precision, **hyper)
+                              precision=precision, kept=kept, **hyper)
 
 
 # ---------------------------------------------------------------------------

@@ -379,9 +379,17 @@ def _rows_on_mesh(table: Table, features_col: str, x: np.ndarray,
     of the column, the mesh and the dtype): the table holds the only
     reference to the device copy (6.35 GB for 2,025,000 x 784 float32
     rows, with 16 MB of norms and mask), a later fit on the same table
-    uploads nothing, and dropping the table frees it."""
+    uploads nothing, and dropping the table frees it (as does a later
+    placement that needs its room: the next fit then places again)."""
     key = ("rows_on_mesh", features_col, mesh.mesh, x.dtype.name)
-    return table.device_resident(key, lambda: _place_rows(x, mesh))
+
+    def place(make_room):
+        # at the width the device holds them (float64 only under x64)
+        make_room(x.size * jax.dtypes.canonicalize_dtype(x.dtype).itemsize,
+                  mesh.mesh.devices.flat)
+        return _place_rows(x, mesh)
+
+    return table.device_resident(key, place)
 
 
 def _lloyd(placed: _Placed, start: np.ndarray, mesh: DeviceMesh, k: int,

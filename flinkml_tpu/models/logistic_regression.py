@@ -121,6 +121,11 @@ class LogisticRegression(StreamingEstimatorMixin, _LogisticRegressionParams, Est
             reg=self.get(_LogisticRegressionParams.REG),
             tol=self.get(_LogisticRegressionParams.TOL),
             seed=self.get_seed(),
+            # The fit's placement stays with the table, for its next fit.
+            kept=_linear_sgd.table_placements(
+                table, features_col,
+                self.get(_LogisticRegressionParams.LABEL_COL),
+                self.get(_LogisticRegressionParams.WEIGHT_COL)),
             **self._checkpoint_kwargs(),
         )
 
@@ -507,6 +512,7 @@ def train_logistic_regression(
     listeners=(),
     sharding_plan=None,
     precision=None,
+    kept=None,
 ) -> np.ndarray:
     """The distributed SGD loop; returns the fitted coefficient on host.
 
@@ -529,6 +535,9 @@ def train_logistic_regression(
         ``flinkml_tpu.iteration.iterate`` — per-epoch listener callbacks
         and checkpointing at epoch granularity, at the cost of one dispatch
         per epoch. Termination always honors ``max_iter``/``tol``.
+
+    ``kept`` (``_linear_sgd.table_placements``) is the device mode's:
+    where the fit keeps its placement with its table.
     """
     if mode not in ("device", "host"):
         raise ValueError(f"mode must be 'device' or 'host', got {mode!r}")
@@ -558,7 +567,7 @@ def train_logistic_regression(
             checkpoint_manager=checkpoint_manager,
             checkpoint_interval=checkpoint_interval,
             resume=resume, listeners=listeners,
-            sharding_plan=sharding_plan, precision=precision,
+            sharding_plan=sharding_plan, precision=precision, kept=kept,
         )
 
     # host mode: per-epoch dispatch with listener/checkpoint support.

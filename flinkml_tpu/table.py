@@ -28,6 +28,10 @@ representation.
 
 from __future__ import annotations
 
+import collections
+import functools
+import threading
+import weakref
 from typing import Any, Dict, Iterable, Iterator, List, Mapping, Optional
 
 import numpy as np
@@ -409,6 +413,137 @@ def _span(name: str):
     return span(name)
 
 
+def _free_bytes(device) -> Optional[int]:
+    """What ``device`` reports free (``bytes_limit`` less
+    ``bytes_in_use``); None where the backend reports no memory (CPU)."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+class _ResidentSet:
+    """Every placement kept with a table (:meth:`Table.device_resident`),
+    of every table in the process, as ONE set in least-recently-used
+    order: the device's memory is one, whoever's table filled it. An
+    entry is the table (weakly: a dropped table's entries go with it),
+    its key, the bytes of its device arrays and the devices they lie on;
+    the arrays themselves stay with the table alone.
+
+    Nothing bounds the set but the device: before a new placement starts,
+    :meth:`make_room` releases entries from the cold end until the new
+    one's bytes fit what every device it goes to reports free. Releasing
+    drops the table's reference: a fit that still runs on those arrays
+    holds its own, and they are freed when it returns.
+    ``metrics.group("hostdata")`` has ``placement_evictions`` and the
+    gauge ``placement_kept_bytes``."""
+
+    def __init__(self):
+        self._lock = threading.RLock()  # a weakref's callback may re-enter
+        self._entries: "collections.OrderedDict" = collections.OrderedDict()
+
+    @functools.cached_property
+    def _counts(self):
+        # Resolved at the first placement, not at import (the data plane
+        # does not depend on the metrics' runtime) and not in a dropped
+        # table's callback, which may run as the interpreter shuts down.
+        from flinkml_tpu.utils.metrics import metrics
+
+        return metrics.group("hostdata")
+
+    def _report(self) -> None:
+        self._counts.gauge(
+            "placement_kept_bytes",
+            float(sum(size for _, size, _ in self._entries.values())))
+
+    def _forget(self, ident) -> None:
+        with self._lock:
+            if self._entries.pop(ident, None) is not None:
+                self._report()
+
+    def touch(self, table: "Table", key) -> None:
+        with self._lock:
+            if (id(table), key) in self._entries:
+                self._entries.move_to_end((id(table), key))
+
+    def add(self, table: "Table", key, value) -> None:
+        import jax
+
+        arrays = [a for a in jax.tree_util.tree_leaves(value)
+                  if _is_device_array(a)]
+        ident = (id(table), key)
+        where = frozenset(d for a in arrays for d in a.devices())
+        with self._lock:
+            self._entries[ident] = (
+                weakref.ref(table, lambda _: self._forget(ident)),
+                sum(a.nbytes for a in arrays), where)
+            self._entries.move_to_end(ident)
+            self._report()
+
+    def make_room(self, table: "Table", key, nbytes: int, devices) -> None:
+        """Before ``nbytes`` are placed evenly over ``devices`` for
+        ``table`` under ``key``: what the table still holds under that
+        key is stale (its fit found it short) and goes first; then other
+        entries with arrays on those devices, least recently used first,
+        until every device has the room. A backend that reports no
+        memory releases nothing."""
+        devices = list(devices)
+        free = {d: _free_bytes(d) for d in devices}
+        need = -(-int(nbytes) // max(1, len(devices)))
+        counts = self._counts
+        counts.counter("placement_evictions", 0.0)
+        with self._lock:
+            mine = (id(table), key)
+            if mine in self._entries:
+                self._release(mine, free)
+            for ident in list(self._entries):
+                if None in free.values() or all(
+                        f >= need for f in free.values()):
+                    break
+                entry = self._entries.get(ident)  # gone, if its table went
+                if entry is not None and entry[2] & free.keys():
+                    self._release(ident, free)
+                    counts.counter("placement_evictions")
+            self._report()
+
+    def _release(self, ident, free) -> None:
+        """Drop the table's reference to the entry ``ident``, and credit
+        its bytes to the devices of ``free`` it lay on."""
+        ref, size, where = self._entries.pop(ident)
+        holder = ref()
+        if holder is not None:
+            holder._device_cache.pop(ident[1], None)
+        for d in where & free.keys():
+            if free[d] is not None:
+                free[d] += size // len(where)
+
+
+_RESIDENT = _ResidentSet()
+
+
+class ResidentSlot:
+    """One table's kept placement under one key: :meth:`find` it (None
+    where the table holds none), :meth:`make_room` for it before its
+    first array is made, :meth:`keep` it once it is whole."""
+
+    def __init__(self, table: "Table", key: tuple):
+        self._table, self._key = table, key
+
+    def find(self):
+        value = self._table._device_cache.get(self._key)
+        if value is not None:
+            _RESIDENT.touch(self._table, self._key)
+        return value
+
+    def make_room(self, nbytes: int, devices) -> None:
+        _RESIDENT.make_room(self._table, self._key, nbytes, devices)
+
+    def keep(self, value):
+        self._table._device_cache[self._key] = value
+        _RESIDENT.add(self._table, self._key, value)
+        return value
+
+
 class Table:
     """Immutable named-column container backed by host numpy arrays and/or
     device-resident ``jax.Array`` columns.
@@ -614,18 +749,30 @@ class Table:
         return self._device_cache[key]
 
     def device_resident(self, key: tuple, place):
-        """What ``place()`` put on a device for this table, kept WITH the
-        table under ``key`` (a tuple that names the column, the mesh and
-        the dtype, and so cannot meet :meth:`device_column`'s keys): an
-        estimator that places a column its own way (``KMeans.fit``: the
-        rows over a mesh, their norms and mask beside them) finds it
-        again at its next fit on this table and uploads nothing. The
-        table holds the only reference: tables are immutable, so the
-        copy cannot go stale, and it is freed when the table is dropped
-        (every relational op returns a NEW table, without it)."""
-        if key not in self._device_cache:
-            self._device_cache[key] = place()
-        return self._device_cache[key]
+        """What ``place(make_room)`` put on a device for this table, kept
+        WITH the table under ``key`` (a tuple that names the column, the
+        mesh and the dtype, and so cannot meet :meth:`device_column`'s
+        keys): an estimator that places a column its own way
+        (``KMeans.fit``: the rows over a mesh, their norms and mask
+        beside them) finds it again at its next fit on this table and
+        uploads nothing. ``place`` calls ``make_room(nbytes, devices)``
+        once it knows its size, before it makes its first array
+        (:class:`ResidentSlot`). The table holds the only reference:
+        tables are immutable, so the copy cannot go stale, and it is
+        freed when the table is dropped (every relational op returns a
+        NEW table, without it) or when a later placement needs its room
+        (:class:`_ResidentSet`)."""
+        slot = self.resident(key)
+        value = slot.find()
+        if value is None:
+            value = slot.keep(place(slot.make_room))
+        return value
+
+    def resident(self, key: tuple) -> "ResidentSlot":
+        """The kept placement of this table under ``key``, to find, make
+        room for and keep in steps of the caller's own (a linear fit
+        keeps its placement only once its last round has landed)."""
+        return ResidentSlot(self, key)
 
     # -- relational ops ----------------------------------------------------
     # Zero-copy on device-backed columns: buffers are rebound, never fetched.

@@ -156,6 +156,12 @@ class CrossValidator(_TuningParams, Estimator):
     row splits. ``fit`` returns a :class:`CrossValidatorModel` whose
     ``avg_metrics`` align with the param maps and whose ``best_model``
     is refit on the full input.
+
+    Every (map, fold) fit builds its own train ``Table``, so a fit finds
+    nothing an earlier one kept with its table (a linear fit's seeded
+    placement): ``k`` fold tables kept alive across the maps would hold
+    ``k`` placements on the device at once.
+    :class:`TrainValidationSplit` has one train table and does keep it.
     """
 
     NUM_FOLDS = IntParam(
@@ -257,10 +263,13 @@ class TrainValidationSplit(_TuningParams, Estimator):
         larger = self.get(self.LARGER_BETTER)
         metric_name = self.get(self.METRIC_NAME)
         metrics = []
+        # ONE train table for every map: what a fit keeps with its table
+        # (a linear fit's seeded placement) the next map's fit finds.
+        train, val = table.take(train_idx), table.take(val_idx)
         for param_map in self.estimator_param_maps:
             _apply(param_map)
-            model = self.estimator.fit(table.take(train_idx))
-            (scored,) = model.transform(table.take(val_idx))
+            model = self.estimator.fit(train)
+            (scored,) = model.transform(val)
             metrics.append(_metric_from(self.evaluator, scored, metric_name))
         best = int(np.argmax(metrics) if larger else np.argmin(metrics))
         _apply(self.estimator_param_maps[best])

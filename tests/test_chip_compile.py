@@ -208,3 +208,52 @@ def test_fm_adam_loop_at_the_cells_size(topo, no_compile_cache, precision):
     assert 0.33 * 16e9 < memory.argument_size_in_bytes < 0.36 * 16e9
     assert memory.temp_size_in_bytes < 1.0e9
     assert memory.output_size_in_bytes < 0.1e9    # 17 x 7813 x 128 floats
+
+
+def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(topo, no_compile_cache):
+    """``lr-a9a.fit``'s one program, ``lr_dense_loop``, at the cell's size
+    (9,437,184 x 123 float32 rows, batch 262,144, on a one-chip mesh). A
+    fit that places its table enters it a chunk at a time, each chunk
+    from the carry the chunk before returned, to the step the landed
+    rows allow; a fit that finds its placement kept with its ``Table``
+    (PR 37) enters it once, from a carry replicated on the mesh, to
+    ``max_iter``. Both are the same avals on the same shardings, so both
+    lower to the one program set-up's fit compiled: the carry goes in as
+    it comes out, and where the loop ends is an operand. The data is not
+    donated: the table keeps the arrays the loop read."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flinkml_tpu.models import _linear_sgd
+
+    rows, dim, batch, max_iter = 9_437_184, 123, 262_144, 72
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+
+    def on(shape, dtype, sharding=whole):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    f32 = jnp.float32
+    trainer = _linear_sgd._dense_trainer(mesh, "logistic", batch, "data")
+    carry = (on((dim,), f32), on((), jnp.int32), on((), f32))
+    data = (on((rows, dim), f32, by_rows), on((rows,), f32, by_rows),
+            on((rows,), f32, by_rows))
+    hy = (on((), f32),) * 4
+
+    def entered(to_step):
+        # as _run_chunked hands it over: a host int32, not a device array
+        return trainer.trace(*carry, *data, *hy, np.int32(to_step)).lower()
+
+    with jax.enable_x64(False):
+        chunk, hit = entered(5), entered(max_iter)
+        assert chunk.as_text() == hit.as_text()
+        compiled = hit.compile()
+    text = compiled.as_text()
+    assert "lr_dense_loop" in text and "tpu_custom_call" not in text
+    (args, _), out = compiled.input_shardings, compiled.output_shardings
+    assert all(a.is_equivalent_to(o, c.ndim)
+               for a, o, c in zip(args[:3], out, carry))
+    memory = compiled.memory_analysis()
+    # the rows (123 columns padded to the 128 lanes), labels and weights
+    assert 0.28 * 16e9 < memory.argument_size_in_bytes < 0.31 * 16e9
+    assert memory.alias_size_in_bytes == 0      # nothing donated

@@ -183,7 +183,9 @@ def test_fit_produces_each_fit_span_once(monkeypatch, stage_bytes, weight_col):
     assert _calls(d) == {**FIT_SPANS, "hostdata.shuffle": 1 + rounds,
                          "hostdata.stage_wait": rounds,
                          "mesh.shard_batch": rounds}
-    assert made == ({"unit_weights_on_device": 1} if weight_col is None else {})
+    # the table's first fit: its placement is a miss, and is kept (PR 37)
+    assert made == {"placement_misses": 1, **(
+        {"unit_weights_on_device": 1} if weight_col is None else {})}
     # What was placed: the columns in lockstep, round by round (the last
     # round steps back over rows already sent), padded to the mesh, at
     # the width the device holds (float32 where x64 is off).
@@ -232,7 +234,7 @@ def test_the_loop_holds_the_rounds_spans_and_the_rest_are_siblings(
     if stage_bytes is not None:
         monkeypatch.setattr(mesh, "_STAGE_BYTES", stage_bytes)
     table = _lr_table()
-    _fit(table)  # compiled before the profile
+    _fit(_lr_table())  # compiled before the profile, on a table of its own
     (fit_start, fit_end, name), *phases = _profiled_spans(
         tmp_path, lambda: _fit(table))
     assert name == "fit" and {n for _, _, n in phases} == set(FIT_SPANS) - {"fit"}
@@ -327,19 +329,20 @@ def test_the_unit_weights_metric_reads_one_a_fit():
                            "hostdata.unit_weights_on_device_per_fit.json")) as f:
         spec = json.load(f)
     assert spec["reader"] == "counter_ratio"
-    table = _lr_table()
 
-    def read(fits, weight_col):
+    def read(fits, weight_col, table=None):
         with _delta("hostdata") as made:
             for _ in range(fits):
-                _fit(table, weight_col)
+                _fit(table or _lr_table(), weight_col)
         counters = {f"hostdata.{k}": v for k, v in made.items()}
         return counter_ratio.read(
             spec["params"], {"counters": counters, "setup_counters": {},
                              "units": {"fits": fits}})
 
-    assert read(3, None) == 1.0
+    assert read(3, None) == 1.0  # a table of its own a fit
     assert read(2, "w") is None  # as on the parent: no count, no metric
+    # one table: its first fit made the weights, the next two found them
+    assert read(3, None, _lr_table()) == 1 / 3
 
 
 def _chain(dim=5, seed=1):
@@ -615,10 +618,10 @@ def test_a_whole_fits_tree_adds_up_to_the_fit(monkeypatch, kind, stage_bytes):
 
     if stage_bytes is not None:
         monkeypatch.setattr(mesh, "_STAGE_BYTES", stage_bytes)
-    table = _lr_table() if kind == "dense" else _sparse_table()
-    _fit(table)  # compiled first
+    make = _lr_table if kind == "dense" else _sparse_table
+    _fit(make())  # compiled first, on a table of its own
     with _delta() as d:
-        _fit(table)
+        _fit(make())
     names = {k[:-len(".calls")] for k in d if k.endswith(".calls")}
     assert names >= set(FIT_SPANS) and (kind == "dense") == (
         "hostdata.sparse_pack" not in names)
@@ -635,6 +638,37 @@ def test_a_whole_fits_tree_adds_up_to_the_fit(monkeypatch, kind, stage_bytes):
     assert 0 < d["trainer.loop.self_seconds"] <= (
         d["trainer.loop.seconds"] - d["hostdata.stage_wait.seconds"]
         - d["mesh.shard_batch.seconds"])
+
+
+@pytest.mark.parametrize("kind", ["dense", "sparse"])
+def test_a_fit_that_finds_its_placement_opens_no_placement_span(kind):
+    """The second fit of a table (PR 37): no permutation, no pack, no
+    round; the ingest, the loop's one dispatch and the read-back are the
+    fit, and the tree still adds up. ``hostdata`` counts the hit, and its
+    gauge holds what the table keeps."""
+    table = _lr_table() if kind == "dense" else _sparse_table()
+    kept = metrics.group("hostdata").snapshot()["gauges"].get(
+        "placement_kept_bytes", 0.0)
+    with _delta("hostdata") as first:
+        _fit(table)
+    assert first["placement_misses"] == 1 and "placement_hits" not in first
+    (entry,) = [v for k, v in table._device_cache.items() if isinstance(k, tuple)]
+    gauges = metrics.group("hostdata").snapshot()["gauges"]
+    assert gauges["placement_kept_bytes"] == kept + sum(
+        a.nbytes for a in entry.arrays)
+    with _delta() as d, _delta("hostdata") as made, \
+            _delta("hostdata.stage") as staged:
+        _fit(table)
+    assert _calls(d) == {"fit": 1, "hostdata.ingest": 1, "trainer.loop": 1,
+                         "trainer.readback": 1}
+    assert made == {"placement_hits": 1} and staged == {}
+    assert _self_sum(d) == pytest.approx(d["fit.seconds"], rel=1e-9)
+    assert d["trainer.loop.self_seconds"] == d["trainer.loop.seconds"]
+    del entry, table
+    import gc
+    gc.collect()
+    assert metrics.group("hostdata").snapshot()["gauges"][
+        "placement_kept_bytes"] == kept
 
 
 def _struct(shape, dtype, sharding=None):
