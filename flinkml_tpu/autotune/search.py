@@ -154,31 +154,29 @@ def measure_gbt_histogram(quick: bool = False) -> Dict[str, float]:
 
 
 def measure_als_reduction(quick: bool = False) -> Dict[str, float]:
-    """ALS rating visits/s per reduction layout through the product
-    ``ALS.fit`` (the ``FLINKML_TPU_ALS_REDUCTION`` A/B)."""
-    from flinkml_tpu.models.als import ALS
-    from flinkml_tpu.table import Table
+    """Rating visits/s per reduction layout of the STREAMED ALS
+    formulation, through ``models.als.coo_fit`` (the
+    ``FLINKML_TPU_ALS_REDUCTION`` A/B). It decides that formulation's
+    reduction only: ``ALS.fit(Table)`` solves by target block and reads
+    no gate."""
+    from flinkml_tpu.models.als import coo_fit
 
     users_n, items_n, nnz, rank, iters = (
         (1_024, 1_024, 1 << 14, 8, 2) if quick
         else (4_096, 4_096, 1 << 18, 16, 4)
     )
     rng = np.random.default_rng(0)
-    table = Table({
-        "user": rng.integers(0, users_n, size=nnz).astype(np.int32),
-        "item": rng.integers(0, items_n, size=nnz).astype(np.int32),
-        "rating": rng.uniform(1, 5, size=nnz).astype(np.float32),
-    })
+    coo = (rng.integers(0, users_n, size=nnz).astype(np.int32),
+           rng.integers(0, items_n, size=nnz).astype(np.int32),
+           rng.uniform(1, 5, size=nnz).astype(np.float32), users_n, items_n)
     out: Dict[str, float] = {}
     for layout in ("segment", "cumsum"):
         with _env("FLINKML_TPU_ALS_REDUCTION", layout):
-            ALS().set_rank(rank).set_max_iter(1).set_seed(0).fit(table)
+            coo_fit(*coo, rank=rank, max_iter=1, reg=0.1)
 
             def rate() -> float:
                 t0 = time.perf_counter()
-                ALS().set_rank(rank).set_max_iter(iters).set_seed(0).fit(
-                    table
-                )
+                coo_fit(*coo, rank=rank, max_iter=iters, reg=0.1)
                 return nnz * 2 * iters / (time.perf_counter() - t0)
 
             out[layout] = _timed_rate(rate)

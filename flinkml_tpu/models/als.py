@@ -2,8 +2,12 @@
 implicit feedback).
 
 Beyond the reference snapshot but a flagship member of the wider Flink ML
-family (recommendation). The TPU-native formulation avoids the
-reference-style per-user sequential solves entirely:
+family (recommendation). ``ALS.fit(Table)`` keeps the ratings on the
+mesh with the table and solves a half-step by target block
+(``models/_als_blocked.py``: no ``[targets, k, k]`` and no ``[ratings,
+k, k]`` array). The STREAMED fit (an iterable of batch tables, a
+``DataCache``) is the formulation below, which avoids the
+reference-style per-user sequential solves:
 
   - Each half-step builds every user's normal equations AT ONCE from the
     ratings COO: gather the fixed side's factors (``y = Y[item_idx]``),
@@ -40,6 +44,8 @@ from jax.sharding import PartitionSpec as P
 
 from flinkml_tpu import kernels
 from flinkml_tpu.api import Estimator, Model
+from flinkml_tpu.models import _als_blocked
+from flinkml_tpu.models._als_blocked import GRAM_PRECISION, start_factors  # noqa: F401
 from flinkml_tpu.models._streaming import StreamingEstimatorMixin
 from flinkml_tpu.common_params import HasMaxIter, HasPredictionCol, HasSeed
 from flinkml_tpu.params import (
@@ -51,6 +57,7 @@ from flinkml_tpu.params import (
 )
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.profiling import span
 
 
 class _ALSParams(HasMaxIter, HasPredictionCol, HasSeed):
@@ -72,7 +79,9 @@ class _ALSParams(HasMaxIter, HasPredictionCol, HasSeed):
 
 
 def _als_layout() -> str:
-    """Measured-default gate for the normal-equation reduction.
+    """Measured-default gate for the STREAMED fit's normal-equation
+    reduction (``ALS.fit(Table)`` reads none of it: it forms no
+    per-rating outer product, ``models/_als_blocked.py``).
 
     ``segment`` (default): per-chunk ``segment_sum`` of the ``[rows, k,
     k]`` outer products — XLA's sort-based lowering drags the 4 KB
@@ -83,8 +92,9 @@ def _als_layout() -> str:
     time and each chunk reduces at precomputed run boundaries with
     :func:`~flinkml_tpu.ops.sparse.chunked_run_totals` — streaming
     passes plus a runs-sized sorted scatter. ``FLINKML_TPU_ALS_REDUCTION``
-    selects; the device A/B decides the default. The streamed fit always
-    uses ``segment`` (its chunks come from cache replay, unsorted)."""
+    selects; the device A/B decides the default. ``_fit_stream`` itself
+    always uses ``segment`` (its chunks come from cache replay,
+    unsorted); ``cumsum`` is :func:`_half_step`'s with run tables."""
     layout = os.environ.get("FLINKML_TPU_ALS_REDUCTION")
     if layout is None:
         # Measured default for this mesh (autotune tuning table), else
@@ -311,6 +321,53 @@ def _half_step(
     return _solve_factors(a, b, gram, jnp.asarray(reg, jnp.float32), cnt)
 
 
+def coo_fit(u_idx, i_idx, ratings, n_users: int, n_items: int, rank: int,
+            max_iter: int, reg: float, implicit: bool = False,
+            alpha: float = 1.0, seed: int = 0, mesh: Optional[DeviceMesh] = None,
+            chunk: int = 1 << 16):
+    """``(user_factors, item_factors)`` by the STREAMED fit's formulation
+    over a COO held in RAM: ``max_iter`` pairs of :func:`_half_step`
+    under :func:`_als_layout`'s reduction. No estimator runs it
+    (``ALS.fit(Table)`` is ``models/_als_blocked.py``); it is what the
+    reduction's A/B (``tools/als_reduction_probe.py``, the autotune knob
+    ``als_reduction``) and the tests of the gated pair measure."""
+    mesh = mesh or DeviceMesh()
+    ratings = np.asarray(ratings, np.float32)
+    chunk = min(chunk, max(256, -(-len(ratings) // mesh.axis_size())))
+    chunk_g = mesh.axis_size() * chunk
+    rng = np.random.default_rng(seed)
+    item_f = jnp.asarray(
+        rng.normal(scale=1.0 / np.sqrt(rank), size=(n_items, rank))
+        .astype(np.float32))
+    user_tabs = item_tabs = None
+    if _als_layout() == "cumsum":
+        # Sort each side by target ONCE (the assignment is static across
+        # iterations); padding ids (n_targets) sort last by construction,
+        # so _pad_coo keeps the order.
+        ou = np.argsort(u_idx, kind="stable")
+        oi = np.argsort(i_idx, kind="stable")
+        by_user = _pad_coo(u_idx[ou], i_idx[ou], ratings[ou], n_users, chunk_g)
+        by_item = _pad_coo(i_idx[oi], u_idx[oi], ratings[oi], n_items, chunk_g)
+
+        def place_tabs(tabs):
+            # The iteration-invariant tables, placed once, a sharded
+            # pair a chunk.
+            return [(mesh.shard_batch(e), mesh.shard_batch(c))
+                    for e, c in zip(*tabs)]
+
+        user_tabs = place_tabs(als_run_tables(by_user[0], mesh.axis_size(), chunk))
+        item_tabs = place_tabs(als_run_tables(by_item[0], mesh.axis_size(), chunk))
+    else:
+        by_user = _pad_coo(u_idx, i_idx, ratings, n_users, chunk_g)
+        by_item = _pad_coo(i_idx, u_idx, ratings, n_items, chunk_g)
+    for _ in range(max_iter):
+        user_f = _half_step(mesh, *by_user, item_f, n_users, reg, implicit,
+                            alpha, chunk, run_tables=user_tabs)
+        item_f = _half_step(mesh, *by_item, user_f, n_items, reg, implicit,
+                            alpha, chunk, run_tables=item_tabs)
+    return np.asarray(user_f), np.asarray(item_f)
+
+
 class ALS(StreamingEstimatorMixin, _ALSParams, Estimator):
     """Alternating least squares over (user, item, rating) tables.
 
@@ -331,8 +388,8 @@ class ALS(StreamingEstimatorMixin, _ALSParams, Estimator):
     streamed fit; ``resume=True`` restores and continues bit-exactly.
     """
 
-    # Per-device rows handed to one normal-equation dispatch; bounds the
-    # nnz×k² intermediate to chunk×k² per device.
+    # Per-device rows the STREAMED fit hands to one normal-equation
+    # dispatch; bounds its nnz×k² intermediate to chunk×k² per device.
     CHUNK = 1 << 16
 
     #: The knob is ACCEPTED at construction so the fit-time refusal can
@@ -342,26 +399,26 @@ class ALS(StreamingEstimatorMixin, _ALSParams, Estimator):
     _SHARDING_PLAN_AWARE = True
 
     def _refuse_sharded_fit(self) -> None:
-        """ALS's wall is NOT factor storage — it is the half-step's
-        normal-equation buffers: every user half-step materializes
-        ``A [n_users, k, k]`` / ``b [n_users, k]`` before the batched
-        Cholesky, a vocab-sized working set that row-sharding the
-        factor tables alone cannot cap (the sparse lookup/exchange
-        primitive moves factor ROWS; it has nothing to say about A/b).
-        Refuse loudly — the honest wiring — and point at what DOES
-        exist: :meth:`ALSModel.factor_tables` serves fitted factors
-        sharded, and the streamed fit bounds the COO (not A/b)."""
+        """An embedding-sharded plan shards factor STORAGE; neither fit
+        stores its factors that way while it trains. The table fit
+        (``models/_als_blocked.py``) deals the TARGETS over the devices
+        and keeps the fixed side replicated on each (every device reads
+        any row of it); the streamed fit scatters into vocab-sized
+        ``A [n, k, k]`` / ``b [n, k]`` buffers. Refuse loudly — the
+        honest wiring — and point at what DOES exist:
+        :meth:`ALSModel.factor_tables` serves fitted factors sharded."""
         if self.sharding_plan is not None:
             raise ValueError(
-                "ALS.fit does not thread a sharding_plan: the per-half-"
-                "step normal-equation buffers (A [n, k, k] / b [n, k]) "
-                "are vocab-sized regardless of how the factor tables "
-                "shard, so an embedding-sharded plan would not cap the "
-                "working set it promises to cap. Partition the id space "
-                "upstream (or shrink rank) to fit the half-step; fitted "
-                "factors CAN be served sharded — see "
-                "ALSModel.factor_tables and docs/development/"
-                "embeddings.md."
+                "ALS.fit does not thread a sharding_plan: a half-step "
+                "reads ANY row of the fixed side's factors, so the table "
+                "fit keeps both factor tables replicated on every device "
+                "(it deals the targets, not the rows, over the mesh), and "
+                "the streamed fit's normal-equation buffers (A [n, k, k] "
+                "/ b [n, k]) are vocab-sized however the factors shard. "
+                "An embedding-sharded plan would not cap the working set "
+                "it promises to cap. Fitted factors CAN be served "
+                "sharded — see ALSModel.factor_tables and "
+                "docs/development/embeddings.md."
             )
 
     def fit(self, *inputs) -> "ALSModel":
@@ -370,80 +427,11 @@ class ALS(StreamingEstimatorMixin, _ALSParams, Estimator):
         if not isinstance(table, Table):
             return self._fit_stream(table)
         self._reject_in_ram_checkpointing()
-        users_raw = np.asarray(table.column(self.get(self.USER_COL)))
-        items_raw = np.asarray(table.column(self.get(self.ITEM_COL)))
-        ratings = np.asarray(
-            table.column(self.get(self.RATING_COL)), dtype=np.float32
-        )
-        implicit = self.get(self.IMPLICIT_PREFS)
-        if implicit and (ratings < 0).any():
-            raise ValueError("implicitPrefs requires non-negative ratings")
-        user_ids, u_idx = np.unique(users_raw, return_inverse=True)
-        item_ids, i_idx = np.unique(items_raw, return_inverse=True)
-        n_users, n_items = len(user_ids), len(item_ids)
-        rank = self.get(self.RANK)
-        reg = self.get(self.REG_PARAM)
-        alpha = self.get(self.ALPHA)
-        mesh = self.mesh or DeviceMesh()
-        chunk = min(
-            self.CHUNK,
-            max(256, -(-len(ratings) // mesh.axis_size())),
-        )
-
-        rng = np.random.default_rng(self.get_seed())
-        # Signed Gaussian init at scale 1/sqrt(rank); the first half-step
-        # solves user factors from these, so no user init is needed
-        # (maxIter is validated > 0).
-        item_f = jnp.asarray(
-            rng.normal(scale=1.0 / np.sqrt(rank), size=(n_items, rank))
-            .astype(np.float32)
-        )
-
-        chunk_g = mesh.axis_size() * chunk
-        user_tabs = item_tabs = None
-        if _als_layout() == "cumsum":
-            # Sort each side by target ONCE (the assignment is static
-            # across iterations); padding ids (n_targets) sort last by
-            # construction, so _pad_coo keeps the order.
-            ou = np.argsort(u_idx, kind="stable")
-            oi = np.argsort(i_idx, kind="stable")
-            by_user = _pad_coo(
-                u_idx[ou], i_idx[ou], ratings[ou], n_users, chunk_g
-            )
-            by_item = _pad_coo(
-                i_idx[oi], u_idx[oi], ratings[oi], n_items, chunk_g
-            )
-            p = mesh.axis_size()
-
-            def place_tabs(tabs):
-                # Device-place the iteration-invariant tables ONCE, as
-                # per-chunk sharded pairs.
-                ends, cols = tabs
-                return [
-                    (mesh.shard_batch(e), mesh.shard_batch(c))
-                    for e, c in zip(ends, cols)
-                ]
-
-            user_tabs = place_tabs(als_run_tables(by_user[0], p, chunk))
-            item_tabs = place_tabs(als_run_tables(by_item[0], p, chunk))
-        else:
-            by_user = _pad_coo(u_idx, i_idx, ratings, n_users, chunk_g)
-            by_item = _pad_coo(i_idx, u_idx, ratings, n_items, chunk_g)
-        for _ in range(self.get(self.MAX_ITER)):
-            user_f = _half_step(
-                mesh, *by_user, item_f, n_users, reg, implicit, alpha,
-                chunk, run_tables=user_tabs,
-            )
-            item_f = _half_step(
-                mesh, *by_item, user_f, n_items, reg, implicit, alpha,
-                chunk, run_tables=item_tabs,
-            )
-        model = ALSModel()
-        model.copy_params_from(self)
-        model._set_factors(
-            user_ids, np.asarray(user_f), item_ids, np.asarray(item_f)
-        )
-        return model
+        with span("fit"):
+            model = ALSModel()
+            model.copy_params_from(self)
+            model._set_factors(*_als_blocked.fit_table(self, table))
+            return model
 
     def _fit_stream(self, source) -> "ALSModel":
         """Out-of-core ALS (see class docstring): one caching pass
@@ -757,19 +745,31 @@ class ALSModel(_ALSParams, Model):
         self._item_factors: Optional[np.ndarray] = None
 
     def _set_factors(self, user_ids, user_factors, item_ids, item_factors):
+        """The factors as handed over: a fit's are the float32 the chip
+        returned, and are widened when first asked for."""
         self._user_ids = np.asarray(user_ids)
         self._item_ids = np.asarray(item_ids)
-        self._user_factors = np.asarray(user_factors, np.float64)
-        self._item_factors = np.asarray(item_factors, np.float64)
+        self._user_factors = np.asarray(user_factors)
+        self._item_factors = np.asarray(item_factors)
+
+    def factors(self) -> Tuple[np.ndarray, np.ndarray]:
+        """``(user [users, rank], item [items, rank])`` as the model holds
+        them (a fit's float32, nothing copied)."""
+        self._require()
+        return self._user_factors, self._item_factors
 
     @property
     def user_factors(self) -> np.ndarray:
         self._require()
+        if self._user_factors.dtype != np.float64:
+            self._user_factors = self._user_factors.astype(np.float64)
         return self._user_factors
 
     @property
     def item_factors(self) -> np.ndarray:
         self._require()
+        if self._item_factors.dtype != np.float64:
+            self._item_factors = self._item_factors.astype(np.float64)
         return self._item_factors
 
     def set_model_data(self, *inputs: Table) -> "ALSModel":
@@ -783,8 +783,8 @@ class ALSModel(_ALSParams, Model):
     def get_model_data(self) -> List[Table]:
         self._require()
         return [
-            Table({"id": self._user_ids, "factors": self._user_factors}),
-            Table({"id": self._item_ids, "factors": self._item_factors}),
+            Table({"id": self._user_ids, "factors": self.user_factors}),
+            Table({"id": self._item_ids, "factors": self.item_factors}),
         ]
 
     def _require(self) -> None:
@@ -809,7 +809,7 @@ class ALSModel(_ALSParams, Model):
         u_pos, u_ok = self._positions(users, self._user_ids)
         i_pos, i_ok = self._positions(items, self._item_ids)
         pred = np.einsum(
-            "nk,nk->n", self._user_factors[u_pos], self._item_factors[i_pos]
+            "nk,nk->n", self.user_factors[u_pos], self.item_factors[i_pos]
         )
         pred = np.where(u_ok & i_ok, pred, np.nan)
         return (table.with_column(self.get(self.PREDICTION_COL), pred),)
@@ -856,9 +856,9 @@ class ALSModel(_ALSParams, Model):
         self._require()
         self._save_with_arrays(path, {
             "userIds": self._user_ids,
-            "userFactors": self._user_factors,
+            "userFactors": self.user_factors,
             "itemIds": self._item_ids,
-            "itemFactors": self._item_factors,
+            "itemFactors": self.item_factors,
         })
 
     @classmethod

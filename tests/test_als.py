@@ -1,6 +1,7 @@
 """ALS: explicit reconstruction, implicit ranking, regularization
 semantics, cold start, persistence, recommendations."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -136,12 +137,15 @@ def test_save_load_and_model_data_roundtrip(tmp_path):
 
 
 def test_chunked_path_matches_single_chunk():
+    """The STREAMED fit's ``CHUNK`` (``ALS.fit(Table)`` has none since PR
+    38: its chunks follow the device's free memory)."""
     users, items, r, _ = _low_rank_ratings(seed=5)
-    t = Table({"user": users, "item": items, "rating": r})
-    big = _als(iters=3).fit(t)
+    batch = {"user": users, "item": items, "rating": r}
+    big = _als(iters=3).fit(iter([Table(batch)]))
     small_chunk = _als(iters=3)
     small_chunk.CHUNK = 64  # force many chunks
-    small = small_chunk.fit(t)
+    small = small_chunk.fit(iter([Table(batch)]))
+    assert np.abs(small.user_factors - big.user_factors).max() > 0  # another order of sums
     np.testing.assert_allclose(
         small.user_factors, big.user_factors, rtol=2e-4, atol=2e-5
     )
@@ -172,52 +176,66 @@ def test_reg_zero_underdetermined_user_stays_finite():
 def test_cumsum_reduction_matches_segment(monkeypatch):
     """FLINKML_TPU_ALS_REDUCTION=cumsum (target-sorted COO + chunked run
     totals) must produce the same factors as the segment_sum reduction,
-    explicit and implicit modes (allclose — summation order differs)."""
-    from flinkml_tpu.models.als import ALS
+    explicit and implicit modes (allclose — summation order differs).
+    Through ``coo_fit``, the streamed formulation over a COO in RAM:
+    ``ALS.fit(Table)`` forms no per-rating outer product since PR 38 and
+    reads no gate."""
+    from flinkml_tpu.models.als import coo_fit
 
     rng = np.random.default_rng(7)
     nnz = 3000
-    t = Table({
-        "user": rng.integers(0, 64, size=nnz).astype(np.int32),
-        "item": rng.integers(0, 50, size=nnz).astype(np.int32),
-        "rating": rng.uniform(1, 5, size=nnz).astype(np.float32),
-    })
+    u = rng.integers(0, 64, size=nnz).astype(np.int32)
+    i = rng.integers(0, 50, size=nnz).astype(np.int32)
+    r = rng.uniform(1, 5, size=nnz).astype(np.float32)
 
     for implicit in (False, True):
         def fit(layout):
             monkeypatch.setenv("FLINKML_TPU_ALS_REDUCTION", layout)
-            est = ALS().set_rank(6).set_max_iter(4).set_seed(0)
-            if implicit:
-                est = est.set_implicit_prefs(True)
-            return est.fit(t)
+            return coo_fit(u, i, r, 64, 50, rank=6, max_iter=4, reg=0.1,
+                           implicit=implicit, seed=0)
 
-        m_seg = fit("segment")
-        m_cum = fit("cumsum")
-        np.testing.assert_allclose(
-            m_cum._user_factors, m_seg._user_factors, rtol=5e-4, atol=5e-5
-        )
-        np.testing.assert_allclose(
-            m_cum._item_factors, m_seg._item_factors, rtol=5e-4, atol=5e-5
-        )
+        seg_u, seg_i = fit("segment")
+        cum_u, cum_i = fit("cumsum")
+        np.testing.assert_allclose(cum_u, seg_u, rtol=5e-4, atol=5e-5)
+        np.testing.assert_allclose(cum_i, seg_i, rtol=5e-4, atol=5e-5)
+        # and both are the table fit's mathematics: one half-step from the
+        # same start solves the same systems
+        assert np.isfinite(seg_u).all() and np.abs(seg_u).max() > 0
 
 
 def test_cumsum_reduction_empty_and_tiny_tables(monkeypatch):
     """The cumsum layout must match segment on degenerate inputs: an
     empty run-table path (zero chunks) and a single-rating table."""
-    from flinkml_tpu.models.als import ALS, als_run_tables
+    from flinkml_tpu.models.als import als_run_tables, coo_fit
 
     empty_e, empty_c = als_run_tables(np.zeros(0, np.int32), 2, 8)
     assert empty_e.shape[0] == 0 and empty_c.shape[0] == 0
 
-    t = Table({
-        "user": np.asarray([3], np.int32),
-        "item": np.asarray([1], np.int32),
-        "rating": np.asarray([4.0], np.float32),
-    })
+    one = (np.asarray([0], np.int32), np.asarray([0], np.int32),
+           np.asarray([4.0], np.float32), 1, 1)
     monkeypatch.setenv("FLINKML_TPU_ALS_REDUCTION", "cumsum")
-    m_cum = ALS().set_rank(3).set_max_iter(2).set_seed(0).fit(t)
+    cum_u, _ = coo_fit(*one, rank=3, max_iter=2, reg=0.1, seed=0)
     monkeypatch.setenv("FLINKML_TPU_ALS_REDUCTION", "segment")
-    m_seg = ALS().set_rank(3).set_max_iter(2).set_seed(0).fit(t)
-    np.testing.assert_allclose(
-        m_cum._user_factors, m_seg._user_factors, rtol=1e-5
-    )
+    seg_u, _ = coo_fit(*one, rank=3, max_iter=2, reg=0.1, seed=0)
+    np.testing.assert_allclose(cum_u, seg_u, rtol=1e-5)
+
+
+def test_the_streamed_formulation_and_the_table_fit_solve_the_same_systems():
+    """One iteration from the same start item factors: ``coo_fit``'s
+    scatter of outer products and the table fit's blocks agree."""
+    from flinkml_tpu.models import _als_blocked
+    from flinkml_tpu.models.als import _half_step, _pad_coo
+    from flinkml_tpu.parallel import DeviceMesh
+
+    users, items, r, _ = _low_rank_ratings(seed=8)
+    model = _als(iters=1).fit(Table({"user": users, "item": items, "rating": r}))
+    user_ids, u = np.unique(users, return_inverse=True)
+    item_ids, i = np.unique(items, return_inverse=True)
+    mesh = DeviceMesh()
+    chunk = 64
+    start = jnp.asarray(_als_blocked.start_factors(0, item_ids.size, 6))
+    by_user = _pad_coo(u.astype(np.int32), i.astype(np.int32),
+                       r.astype(np.float32), user_ids.size, mesh.axis_size() * chunk)
+    user_f = _half_step(mesh, *by_user, start, user_ids.size, 0.01, False, 1.0, chunk)
+    np.testing.assert_allclose(np.asarray(user_f), model.factors()[0],
+                               rtol=2e-3, atol=2e-4)

@@ -257,3 +257,73 @@ def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(topo, no_compile
     # the rows (123 columns padded to the 128 lanes), labels and weights
     assert 0.28 * 16e9 < memory.argument_size_in_bytes < 0.31 * 16e9
     assert memory.alias_size_in_bytes == 0      # nothing donated
+
+
+def _yahoomusic_degrees(side: str):
+    """Ratings a target at ``als-yahoomusic``'s counts, from the laws of
+    ``benchmark/datagen_ratings.py`` (the users' exactly; the items' the
+    expectation of the draw, rounded)."""
+    import numpy as np
+
+    from benchmark import datagen_ratings as gen
+
+    users, items, ratings = 1_000_990, 624_961, 252_800_275
+    if side == "user":
+        return gen.user_degrees(users, ratings), items
+    law = (np.arange(items) + gen.ITEM_SHIFT) ** -gen.ITEM_SKEW
+    expected = ratings * (gen.ITEM_FLAT / items + (1 - gen.ITEM_FLAT) * law / law.sum())
+    return np.rint(expected).astype(np.int64), users
+
+
+@pytest.mark.parametrize("side", ["user", "item"])
+def test_als_half_step_at_the_cells_size(topo, no_compile_cache, side):
+    """``als-yahoomusic.fit``'s program of one side: a half-step over
+    252,800,275 ratings at rank 100 under the side's plan (chunks of
+    262,144 slots), the lane solver's Mosaic kernel included, on a
+    one-chip mesh (the ``all_gather`` included), in 32-bit mode. The
+    compiled program's own memory analysis holds that no ``[targets, k,
+    k]`` (40 GB of users) and no ``[ratings, k, k]`` exists: beside the
+    side's slots (2.5 GB) and the fixed side's factors it holds the
+    solved rows twice (padded to 128 lanes for the next half-step, and as
+    the model keeps them) and a chunk's scratch."""
+    import os
+    import sys
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from flinkml_tpu.models import _als_blocked
+
+    degrees, fixed_rows = _yahoomusic_degrees(side)
+    rank = 100
+    plan = _als_blocked.plan_side(degrees, 1, _als_blocked._CHUNK_SLOTS)
+    assert 1.1 * degrees.sum() < plan.slots_local < 1.3 * degrees.sum()
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+
+    def on(shape, dtype, sharding=whole):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    f32, i32 = jnp.float32, jnp.int32
+    assert _als_blocked.GRAM_PRECISION == jax.lax.Precision.HIGHEST
+    with jax.enable_x64(False):
+        traced = _als_blocked._program(
+            mesh, plan.plan, rank, False, _als_blocked.GRAM_PRECISION, True).trace(
+            on((plan.slots_local,), i32, by_rows), on((plan.slots_local,), f32, by_rows),
+            on((plan.rows_local,), f32, by_rows),
+            on((plan.owner.shape[1],), i32, by_rows), on((degrees.size,), i32),
+            on((fixed_rows + 1, 128), f32), on((), f32), on((), f32))
+        assert not re.search(r"\b[fis]64\b", str(traced.jaxpr))   # Mosaic lowers none
+        compiled = traced.lower().compile()
+    text = compiled.as_text()
+    buckets = len(plan.plan[0]) + bool(plan.plan[1][1])
+    # every bucket's product at the precision the configuration states
+    assert text.count("operand_precision={highest,highest}") >= buckets
+    memory = compiled.memory_analysis()
+    slots_and_fixed = 8 * plan.slots_local + 512 * (fixed_rows + 1)
+    assert slots_and_fixed < memory.argument_size_in_bytes < slots_and_fixed + 0.1e9
+    # [targets + 1, 128] and [targets, 100] float32
+    assert memory.output_size_in_bytes < 1.0e9
+    assert memory.temp_size_in_bytes < 2.0e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9

@@ -671,6 +671,48 @@ def test_a_fit_that_finds_its_placement_opens_no_placement_span(kind):
         "placement_kept_bytes"] == kept
 
 
+def _ratings_table(seed=0, users=40, items=30, nnz=1500):
+    rng = np.random.default_rng(seed)
+    return Table({"user": rng.integers(0, users, nnz).astype(np.int32),
+                  "item": rng.integers(0, items, nnz).astype(np.int32),
+                  "rating": rng.uniform(0, 5, nnz).astype(np.float32)})
+
+
+def test_an_als_fits_spans_are_siblings_in_order_and_a_second_fit_opens_fewer(tmp_path):
+    """``ALS.fit(Table)`` (PR 38): ``als.ingest``, ``als.table_to_device``
+    (holding the staging rounds), ``als.init``, ``als.loop`` (holding
+    ``als.dispatch``) and ``als.readback`` one after another inside
+    ``fit``; a second fit of the table ingests and places nothing, and
+    the tree still adds up."""
+    from flinkml_tpu.models import ALS
+
+    fit = lambda t: ALS().set_rank(4).set_max_iter(2).fit(t)
+    fit(_ratings_table(seed=1))  # compiled before the profile, on a table of its own
+    table = _ratings_table()
+    (fit_start, fit_end, name), *phases = _profiled_spans(tmp_path, lambda: fit(table))
+    assert name == "fit"
+    als = [ph for ph in phases if ph[2].startswith("als.")]
+    assert [n for _, _, n in als] == ["als.ingest", "als.table_to_device", "als.init",
+                                      "als.loop", "als.dispatch", "als.readback"]
+    spans = {n: (a, b) for a, b, n in als}
+    loop, dispatch = spans["als.loop"], spans["als.dispatch"]
+    assert loop[0] <= dispatch[0] <= dispatch[1] <= loop[1]
+    end = fit_start
+    for start, stop, n in als:
+        if n != "als.dispatch":
+            assert end <= start <= stop <= fit_end, n
+            end = stop
+    # the staging rounds of both orders lie inside the upload
+    up = spans["als.table_to_device"]
+    rounds = [ph for ph in phases if not ph[2].startswith("als.")]
+    assert rounds and all(up[0] <= a and b <= up[1] for a, b, _ in rounds)
+    with _delta() as d:
+        fit(table)
+    assert set(_calls(d)) == {"fit", "als.init", "als.loop", "als.dispatch",
+                              "als.readback"}
+    assert _self_sum(d) == pytest.approx(d["fit.seconds"], rel=1e-9)
+
+
 def _struct(shape, dtype, sharding=None):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -740,6 +782,21 @@ def _lowered_programs():
             _struct((2,), i32, rep), _struct((), f32, rep), _struct((), f32, rep),
             _struct((), i32, rep), _struct((), f32, rep)).as_text()
 
+    def als_half_step():
+        from flinkml_tpu.models import _als_blocked
+
+        rep, rows = shardings()
+        p = len(jax.devices())
+        plan = _als_blocked.plan_side(np.array([3, 1, 40, 9] * p), p, 16)
+        return _als_blocked._program(
+            m(), plan.plan, 4, False, _als_blocked.GRAM_PRECISION, False).lower(
+            _struct((p * plan.slots_local,), i32, rows),
+            _struct((p * plan.slots_local,), f32, rows),
+            _struct((p * plan.rows_local,), f32, rows),
+            _struct((p * plan.owner.shape[1],), i32, rows),
+            _struct((4 * p,), i32, rep), _struct((31, 128), f32, rep),
+            _struct((), f32, rep), _struct((), f32, rep)).as_text()
+
     def knn_vote():
         return knn._knn_vote.lower(
             _struct((16, 5), f32), _struct((64, 5), f32), _struct((64,), f32),
@@ -778,6 +835,7 @@ def _lowered_programs():
             _struct((), i32), 64, np.dtype(np.float32)).as_text(),
         "kmeans_lloyd": kmeans_lloyd,
         "fm_adam_loop": fm_adam_loop,
+        "als_half_step": als_half_step,
         "knn_vote": knn_vote,
         "rows_sq": lambda: blas.squared_norms.lower(_struct((64, 5), f32)).as_text(),
         "fused_chain": fused_chain,
@@ -786,7 +844,7 @@ def _lowered_programs():
 
 PROGRAMS = ("lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop", "stage_write",
             "stage_zeros", "stage_ones", "kmeans_lloyd", "fm_adam_loop", "knn_vote",
-            "rows_sq", "fused_chain")
+            "rows_sq", "fused_chain", "als_half_step")
 
 
 @pytest.mark.parametrize("name", PROGRAMS)
