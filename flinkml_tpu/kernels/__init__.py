@@ -29,7 +29,12 @@ fallback for table-chosen backends).
 
 Outside the gate, :mod:`flinkml_tpu.kernels.knn_search` is the KNN
 search's product and ranking in one kernel; ``models.knn.nearest`` takes
-it wherever it applies (a TPU, float32 rows, ``k`` ≤ 128).
+it wherever it applies (a TPU, float32 rows, ``k`` ≤ 128);
+:mod:`flinkml_tpu.kernels.spd_solve` is ALS's solve, a system a lane;
+:mod:`flinkml_tpu.kernels.sparse_blocks` is the blocked sparse step's
+lookup and accumulation in fast memory, which
+``models._linear_sgd.make_sparse_step_bucketed`` takes wherever they
+apply (a TPU, float32 coefficients, a slot plan, a batch in whole tiles).
 
 See ``docs/development/kernels.md`` for the supported-shape tables,
 the equivalence-test recipe, and the device re-tune runbook.

@@ -260,6 +260,22 @@ def _slot_major(block, slots):
     return jnp.stack([block[:, j] for j in slots])
 
 
+def _blocks_in_fast_memory(dtype, local_bs: int, slot_plan: Tuple) -> bool:
+    """Whether a plan's blocked slots are looked up and accumulated by
+    :mod:`flinkml_tpu.kernels.sparse_blocks` (a slot's one-hot product
+    made, selected from and dropped in fast memory) and not by
+    ``ops.sparse.block_lookup`` / ``block_accumulate`` through XLA: on a
+    TPU (elsewhere Mosaic's kernels would run interpreted), float32
+    coefficients, a device's batch in whole tiles, blocks that fast
+    memory holds. Read off what the fit is handed; nothing sets it."""
+    from flinkml_tpu.kernels import _gate, sparse_blocks
+
+    groups = [(length, len(slots))
+              for length, slots in block_groups(slot_plan, local_bs)]
+    return (bool(groups) and not _gate.interpret_mode()
+            and sparse_blocks.unsupported_reason(dtype, local_bs, groups) is None)
+
+
 def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
                               axis: str, dim: int,
                               segsum_backend: str = "xla",
@@ -291,8 +307,15 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
     the same way (``block_accumulate``) and added to those rows; the
     other slots gather and share the one segment-sum, as every slot does
     under an empty plan, whose program is the one this function built
-    before there were plans. Blocks may overlap: the gradient adds."""
+    before there were plans. Blocks may overlap: the gradient adds.
+
+    Where :func:`_blocks_in_fast_memory` says so the two block products
+    are ``kernels.sparse_blocks``' two kernels, every group's slots in
+    one call each side of ``_margin_grad``: the same looked-up floats,
+    the gradient's float32 sums in the kernel's fixed order. The row
+    gather before and the row scatter-add after are the same."""
     from flinkml_tpu import kernels
+    from flinkml_tpu.kernels import sparse_blocks
 
     if any(slot_plan) and len(local_bss) != 1:
         raise ValueError("a slot plan is a one-bucket table's")
@@ -313,28 +336,45 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
             vb = _window(vall, epoch, local_bs)
             yb = _window(yl, epoch, local_bs)
             wb = _window(wl, epoch, local_bs)
-            # Each group's cells, slot-major, indexed from their blocks'
-            # first rows; what is left of ib, vb are the general slots.
+            fused = _blocks_in_fast_memory(coef.dtype, local_bs, slot_plan)
+            # Each group's block rows and, for XLA's products, its cells,
+            # slot-major, indexed from their blocks' first rows; what is
+            # left of ib, vb are the general slots.
             cells = []
             for length, slots in groups:
                 first = jnp.stack([slot_starts[j] for j in slots])
                 rows = first[:, None] + jnp.arange(length // LANES)
-                cells.append((rows,
-                              _slot_major(ib, slots) - LANES * first[:, None],
-                              _slot_major(vb, slots)))
+                cells.append((rows, None, None) if fused else (
+                    rows, _slot_major(ib, slots) - LANES * first[:, None],
+                    _slot_major(vb, slots)))
+            if fused:
+                # The kernels walk the window's cells as they are, a slot
+                # a row: which rows, group by group, and the starts say.
+                walk = ([(length, len(slots)) for length, slots in groups],
+                        [j for _, slots in groups for j in slots])
+                whole = (ib.T, vb.T, slot_starts)
             if groups:
                 ib, vb = _slot_major(ib, general).T, _slot_major(vb, general).T
                 tiled = _lane_rows(coef)
             dot = ell_matvec(ib, vb, coef)
-            for rows, local, vals in cells:
-                looked = block_lookup(
-                    tiled[rows].reshape(rows.shape[0], -1), local)
-                dot = dot + jnp.sum(vals * looked, axis=0)
+            if fused:
+                dot = dot + sparse_blocks.lookup_dot(
+                    *walk, [tiled[rows] for rows, _, _ in cells], *whole)
+            else:
+                for rows, local, vals in cells:
+                    looked = block_lookup(
+                        tiled[rows].reshape(rows.shape[0], -1), local)
+                    dot = dot + jnp.sum(vals * looked, axis=0)
             mult, per_ex = _margin_grad(loss, dot, yb, wb)
-            block_grads += [
-                (rows, block_accumulate(
-                    local, vals * mult[None, :], LANES * rows.shape[1]))
-                for rows, local, vals in cells]
+            if fused:
+                block_grads += zip(
+                    (rows for rows, _, _ in cells),
+                    sparse_blocks.accumulate(*walk, *whole, mult))
+            else:
+                block_grads += [
+                    (rows, block_accumulate(
+                        local, vals * mult[None, :], LANES * rows.shape[1]))
+                    for rows, local, vals in cells]
             contribs.append((vb * mult[:, None]).reshape(-1))
             flat_idx.append(ib.reshape(-1))
             loss_l = loss_l + jnp.sum(per_ex.astype(acc))
@@ -981,6 +1021,12 @@ def train_linear_model_sparse_csr(
     n = np.asarray(indptr).size - 1
     if n == 0:
         raise ValueError("training table is empty")
+    if np.dtype(dtype) == np.float32:
+        # A plan's step may hold the block kernels (a TPU's): what
+        # tracing them imports loads beside the pack and the permutation.
+        from flinkml_tpu.kernels import sparse_blocks
+
+        sparse_blocks.import_beside_host_work()
     place, local_bss, slot_plan = prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
         max_buckets=max_buckets, dtype=dtype, seed=seed, kept=kept,
@@ -989,7 +1035,7 @@ def train_linear_model_sparse_csr(
         mesh.mesh, loss, tuple(local_bss), DeviceMesh.DATA_AXIS, int(dim),
         _segsum_backend(), slot_plan,
     )
-    return _run_chunked(
+    coef = _run_chunked(
         trainer, place, int(dim), jnp.dtype(dtype),
         learning_rate, reg * (1.0 - elastic_net), reg * elastic_net,
         tol, max_iter, mesh,
@@ -997,6 +1043,11 @@ def train_linear_model_sparse_csr(
         checkpoint_interval=checkpoint_interval,
         resume=resume, listeners=listeners,
     )
+    # Counted at the loop, whose dispatches carry the kernels or do not:
+    # a fit that finds its placement kept packs nothing and still counts.
+    metrics.group("trainer").counter("fused_block_fits", float(
+        _blocks_in_fast_memory(dtype, local_bss[0], slot_plan)))
+    return coef
 
 
 def make_softmax_step(num_classes: int, local_bs: int, axis: str):

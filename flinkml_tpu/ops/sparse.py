@@ -14,6 +14,21 @@ a time on a TPU (7 ns a cell). A slot whose columns sit in a block of its
 own (:func:`slot_block_plan`) is served by :func:`block_lookup` and
 :func:`block_accumulate` instead: two-level one-hot products on the MXU,
 exact in float32.
+
+On a TPU the linear trainers' blocked step runs those two products as
+:mod:`flinkml_tpu.kernels.sparse_blocks`' two Mosaic kernels (PR 39):
+through XLA a slot's product ``[batch, 128]`` goes to HBM and comes back
+for one float of each 128 to be kept; the kernels make it, select from
+it and drop it in fast memory, a tile of up to 4,096 batch rows at a
+time, the blocks' bfloat16 parts (6 bytes a block column) resident for
+the whole call. Where they apply is read off the step
+(``models._linear_sgd._blocks_in_fast_memory``: a TPU, float32, a
+device's batch in whole tiles of 128, at most two million block
+columns). :func:`block_lookup` and :func:`block_accumulate` are their
+reference (``tests/test_sparse_blocks.py`` holds both to the same
+gather and scatter-add) and what every other backend, dtype and batch
+runs; the payload forms (a factorization machine's rows) are XLA's
+everywhere.
 """
 
 from __future__ import annotations

@@ -210,6 +210,108 @@ def test_fm_adam_loop_at_the_cells_size(topo, no_compile_cache, precision):
     assert memory.output_size_in_bytes < 0.1e9    # 17 x 7813 x 128 floats
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
+        topo, no_compile_cache, monkeypatch, chips):
+    """``lr-criteo.fit``'s one program, ``lr_sparse_loop``: 160 steps of
+    65,536 rows over 16,777,216 x 39 resident cells, ``dim`` 1,000,000,
+    under the cell's slot plan (``fm-criteo.fit``'s: the same rows), on a
+    one-chip mesh as the cell runs it and on the host's four chips (a
+    quarter of the rows and of the batch each, the gradient's ``psum``
+    after the kernels). On a TPU the 39 blocked slots' products are
+    ``kernels.sparse_blocks``' two kernels, compiled here by Mosaic (not
+    interpreted): one ``[65,536, 128]`` product of a slot in HBM is 33.5
+    MB, and the program's temporaries stay under what five of them would
+    take. Compiled UNDER x64, as the suite runs: the kernels
+    are traced in 32-bit mode whatever the flag says, and the traced
+    program is read for 64-bit blocks first (Mosaic aborts on one)."""
+    import numpy as np
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.models import _linear_sgd
+
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    rows, width, dim, batch = 16_777_216, 39, 1_000_000, 65_536
+    assert _linear_sgd._blocks_in_fast_memory(jnp.float32, batch // chips,
+                                              FM_CRITEO_PLAN)
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    # As a v5e holds them (PR 30): an ELL table lies with its ROWS along
+    # the lanes.
+    rows_minor = Format(Layout(major_to_minor=(1, 0)), by_rows)
+
+    def on(shape, dtype, sharding=whole):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    f32, i32 = jnp.float32, jnp.int32
+    _linear_sgd._sparse_trainer_bucketed.cache_clear()   # keyed by no backend
+    try:
+        with jax.enable_x64(True):
+            traced = _linear_sgd._sparse_trainer_bucketed(
+                mesh, "logistic", (batch // chips,), "data", dim, "xla",
+                FM_CRITEO_PLAN).trace(
+                on((dim,), f32), on((), i32), on((), f32),
+                on((rows, width), i32, rows_minor), on((rows, width), f32, rows_minor),
+                on((rows,), f32, by_rows), on((rows,), f32, by_rows),
+                on((chips * width,), i32, by_rows),
+                on((), f32), on((), f32), on((), f32), on((), f32), np.int32(160))
+            kernels = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)]
+            assert len(kernels) == 2                    # the lookup, the accumulation
+            wide = [str(v.aval) for eqn in kernels
+                    for v in eqn.params["jaxpr"].invars + eqn.params["jaxpr"].outvars
+                    if re.search(r"[fiu]64", str(v.aval))]
+            assert wide == []
+            compiled = traced.lower().compile()
+    finally:
+        _linear_sgd._sparse_trainer_bucketed.cache_clear()
+    text = compiled.as_text()
+    assert "lr_sparse_loop" in text and text.count("tpu_custom_call") == 2
+    memory = compiled.memory_analysis()
+    # the cells, labels and weights: 5.37 GB, a third of one chip
+    assert 0.33 * 16e9 < chips * memory.argument_size_in_bytes < 0.36 * 16e9
+    assert memory.temp_size_in_bytes < 5 * batch * 128 * 4
+
+
+def test_sparse_block_kernels_take_criteo_laid_out_field_by_field(
+        one_chip, no_compile_cache):
+    """The two kernels alone at another ladder of block lengths: Criteo
+    field by field (PR 29: five fields on blocks of up to 194,560
+    columns, 1,520 rows of 128), whose parts stay in fast memory while a
+    long block's product is cut in tiles of 512 batch rows."""
+    from flinkml_tpu.kernels import sparse_blocks
+
+    groups, width, batch = [(8_192, 32), (59_392, 2), (194_560, 5)], 39, 65_536
+    assert sparse_blocks.unsupported_reason(jnp.float32, batch, groups) is None
+    assert sparse_blocks.tile_rows(batch, sparse_blocks.walk(groups)) == 512
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blocks = [on((slots, length // 128, 128), jnp.float32) for length, slots in groups]
+    cells = (on((width, batch), jnp.int32), on((width, batch), jnp.float32),
+             on((width,), jnp.int32))
+    with jax.enable_x64(True):
+        lookup = jax.jit(lambda b, c, v, at: sparse_blocks.lookup_dot(
+            groups, range(width), b, c, v, at, interpret=False)).lower(
+                blocks, *cells).compile()
+        accumulate = jax.jit(lambda c, v, at, m: sparse_blocks.accumulate(
+            groups, range(width), c, v, at, m, interpret=False)).lower(
+                *cells, on((batch,), jnp.float32)).compile()
+    assert lookup.as_text().count("tpu_custom_call") == 1
+    assert accumulate.as_text().count("tpu_custom_call") == 1
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` of ``jaxpr``, inner programs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
 def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(topo, no_compile_cache):
     """``lr-a9a.fit``'s one program, ``lr_dense_loop``, at the cell's size
     (9,437,184 x 123 float32 rows, batch 262,144, on a one-chip mesh). A

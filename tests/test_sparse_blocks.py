@@ -18,7 +18,11 @@ of their own are looked up and accumulated by two-level one-hot products
   field a slot and planned the same way; hashed or text-like ragged
   rows keep their padded buckets;
 - the sparse trainer under the empty plan lowers to the text of the
-  step as it was before plans existed (a copy of it, kept here).
+  step as it was before plans existed (a copy of it, kept here);
+- the same lookup, accumulation and step through
+  ``kernels.sparse_blocks`` (PR 39: a TPU's two Mosaic kernels, here
+  interpreted, the cases marked ``kernel``), held to the same gather,
+  scatter-add and general step.
 """
 
 from concurrent.futures import ThreadPoolExecutor
@@ -30,16 +34,22 @@ import pytest
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from flinkml_tpu.kernels import sparse_blocks
 from flinkml_tpu.models import _linear_sgd
 from flinkml_tpu.ops import sparse
 from flinkml_tpu.utils.metrics import metrics
 
-#: Block lengths either side of 128 rows of 128 lanes: below it the lane
-#: index is contracted on the MXU, from it on the row index.
-LENGTHS = [128, 256, 1024, 3072, 128 * 128, 128 * 136]
+#: Block lengths either side of 128 rows of 128 lanes (below it the lane
+#: index is contracted on the MXU, from it on the row index), and the
+#: ladder's rungs the kernel treats differently: up to 4,096 columns one
+#: stacked pass (8 to 128 columns a product row), 42 rows of 128 the
+#: most three stacked parts of whole rows would be, 13,312 and 26,624
+#: ``lr-criteo``'s long slots.
+LENGTHS = [128, 256, 1024, 3072, 5376, 13312, 128 * 128, 128 * 136, 26624]
+PATHS = ["xla", "kernel"]
 
 
-def _cells(length, slots=3, rows=700, seed=0):
+def _cells(length, slots=3, rows=768, seed=0):
     rng = np.random.default_rng(seed)
     blocks = rng.standard_normal((slots, length)).astype(np.float32)
     blocks[0, :4] = [0.0, 1e-30, -3.5e20, np.float32(1) + np.float32(2) ** -23]
@@ -48,22 +58,55 @@ def _cells(length, slots=3, rows=700, seed=0):
     return blocks, local, rng.standard_normal((slots, rows)).astype(np.float32)
 
 
+def _lookup(path, blocks, local):
+    """``blocks[s, local[s, b]]`` by XLA's products, or by the kernel
+    (interpreted): a slot at a time with the value 1, so that its share
+    of the margin IS the looked-up float."""
+    if path == "xla":
+        return np.asarray(jax.jit(sparse.block_lookup)(blocks, local))
+    length = blocks.shape[1]
+    one = jnp.ones((1, local.shape[1]), jnp.float32)
+    none = jnp.zeros(1, jnp.int32)
+    return np.stack([np.asarray(sparse_blocks.lookup_dot(
+        [(length, 1)], [0], [jnp.asarray(blocks[s:s + 1]).reshape(1, -1, 128)],
+        jnp.asarray(local[s:s + 1]), one, none, interpret=True))
+        for s in range(blocks.shape[0])])
+
+
+def _accumulate(path, local, contrib, length):
+    """``zeros([S, length]).at[s, local[s, b]].add(contrib[s, b])``; the
+    kernel takes the contributions as values times a multiplier a row."""
+    if path == "xla":
+        return np.asarray(jax.jit(sparse.block_accumulate, static_argnums=2)(
+            local, contrib, length))
+    mult = np.random.default_rng(9).uniform(0.5, 2.0, local.shape[1]).astype(
+        np.float32)
+    slots = local.shape[0]
+    (sums,) = sparse_blocks.accumulate(
+        [(length, slots)], range(slots), jnp.asarray(local),
+        jnp.asarray(contrib / mult), jnp.zeros(slots, jnp.int32),
+        jnp.asarray(mult), interpret=True)
+    return np.asarray(sums).reshape(local.shape[0], length)
+
+
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("length", LENGTHS)
-def test_block_lookup_is_the_gather_bit_for_bit(length):
+def test_block_lookup_is_the_gather_bit_for_bit(length, path):
     blocks, local, _ = _cells(length)
-    got = np.asarray(jax.jit(sparse.block_lookup)(blocks, local))
+    got = _lookup(path, blocks, local)
     want = np.take_along_axis(blocks, local, axis=1)
     assert got.dtype == np.float32
     assert got.tobytes() == want.tobytes()
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("length", [256, 128 * 136])
-def test_a_bfloat16_lookup_fails_the_bit_for_bit_test(length):
+def test_a_bfloat16_lookup_fails_the_bit_for_bit_test(length, path):
     """The control: the same lookup in a table rounded to bfloat16, which
     is what any precision under ``HIGHEST`` makes of it on the MXU."""
     blocks, local, _ = _cells(length)
-    low = jnp.asarray(blocks).astype(jnp.bfloat16).astype(jnp.float32)
-    got = np.asarray(jax.jit(sparse.block_lookup)(low, local))
+    low = np.asarray(jnp.asarray(blocks).astype(jnp.bfloat16).astype(jnp.float32))
+    got = _lookup(path, low, local)
     want = np.take_along_axis(blocks, local, axis=1)
     assert np.mean(got != want) > 0.9
     np.testing.assert_allclose(got, want, rtol=2.0 ** -8)
@@ -81,11 +124,64 @@ def test_the_products_ask_for_the_highest_precision():
         assert "precision = [HIGHEST, HIGHEST]" in text
 
 
+@pytest.mark.parametrize("length", [256, 4096, 26624])
+def test_the_kernels_products_are_bfloat16_parts_that_sum_to_the_float(length):
+    """What stands in for ``HIGHEST`` in the kernels: every operand of
+    every product is bfloat16 (one pass each on the MXU), the blocks'
+    three parts sum back to the float32 bit for bit in either order, and
+    the products accumulate in float32."""
+    blocks, local, vals = _cells(length)
+    (group,) = sparse_blocks.walk([(length, 3)])
+    r = length // group.c
+    operand = np.asarray(sparse_blocks.block_parts(
+        [jnp.asarray(blocks).reshape(3, -1, 128)], group).astype(jnp.float32))
+    if group.narrow:
+        parts = [operand[:, :, p * group.rows:p * group.rows + r]
+                 for p in range(3)]
+        assert not operand[:, :, 3 * group.rows:].any()
+        want = blocks.reshape(3, r, group.c).transpose(0, 2, 1)
+    else:
+        parts = [operand[:, p * group.rows:p * group.rows + r]
+                 for p in range(3)]
+        want = blocks.reshape(3, r, 128)
+    # Summed as they lie along the contraction, from either end (the
+    # first and the last alone, added first, need not be a float32).
+    hi, mid, lo = parts
+    assert ((hi + mid) + lo).tobytes() == want.tobytes()
+    assert (hi + (mid + lo)).tobytes() == want.tobytes()
+    args = (jnp.asarray(local), jnp.asarray(vals), jnp.zeros(3, jnp.int32))
+    programs = (
+        jax.make_jaxpr(lambda l, v, at: sparse_blocks.lookup_dot(
+            [(length, 3)], range(3), [jnp.asarray(blocks).reshape(3, -1, 128)],
+            l, v, at, interpret=True))(*args),
+        jax.make_jaxpr(lambda l, v, at: sparse_blocks.accumulate(
+            [(length, 3)], range(3), l, v, at, v[0], interpret=True))(*args))
+    for program in programs:
+        dots = [eqn for call in _pallas_calls(program.jaxpr)
+                for eqn in _flat(call.params["jaxpr"])
+                if eqn.primitive.name == "dot_general"]
+        assert dots
+        for eqn in dots:
+            assert [str(v.aval.dtype) for v in eqn.invars] == ["bfloat16"] * 2
+            assert eqn.params["preferred_element_type"] == jnp.float32
+
+
+def _flat(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _flat(sub)
+
+
+def _pallas_calls(jaxpr):
+    return [eqn for eqn in _flat(jaxpr) if eqn.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("length", LENGTHS)
-def test_block_accumulate_is_the_scatter_add_and_repeats(length):
-    _, local, contrib = _cells(length, rows=5000)
-    accumulate = jax.jit(sparse.block_accumulate, static_argnums=2)
-    got = np.asarray(accumulate(local, contrib, length))
+def test_block_accumulate_is_the_scatter_add_and_repeats(length, path):
+    _, local, contrib = _cells(length, rows=5120)
+    got = _accumulate(path, local, contrib, length)
     assert got.shape == (local.shape[0], length) and got.dtype == np.float32
     exact = np.zeros(got.shape)
     for s in range(local.shape[0]):
@@ -93,26 +189,109 @@ def test_block_accumulate_is_the_scatter_add_and_repeats(length):
     scatter = np.stack([
         np.asarray(jnp.zeros(length, jnp.float32).at[local[s]].add(contrib[s]))
         for s in range(local.shape[0])])
-    # Float32 rounding: as far from float64 as the scatter-add is.
+    # Float32 rounding: as far from float64 as the scatter-add is (the
+    # kernel's contributions are a float32 product first: one more ulp).
     assert np.abs(got - exact).max() < 4 * max(
         np.abs(scatter - exact).max(), 2.0 ** -22)
-    assert got.tobytes() == np.asarray(
-        accumulate(local, contrib, length)).tobytes()
+    assert got.tobytes() == _accumulate(path, local, contrib, length).tobytes()
 
 
-def test_cells_outside_the_block_with_value_zero_change_nothing():
+@pytest.mark.parametrize("path", PATHS)
+def test_cells_outside_the_block_with_value_zero_change_nothing(path):
     """The zero rows a shard is padded with: index 0 in every slot."""
-    blocks, local, contrib = _cells(1024)
+    blocks, local, contrib = _cells(1024, rows=700 if path == "xla" else 768)
     local[:, 10:20] = -7000
     contrib[:, 10:20] = 0.0
-    looked = np.asarray(sparse.block_lookup(blocks, local))
-    assert np.isfinite(looked).all()
+    assert np.isfinite(_lookup(path, blocks, local)).all()
     kept = np.ones(local.shape[1], bool)
     kept[10:20] = False
+    if path == "kernel":      # whole tiles: the same cells inside the block
+        kept, local_in = slice(None), local.copy()
+        local_in[:, 10:20] = 5
+    else:
+        local_in = local
     np.testing.assert_array_equal(
-        np.asarray(sparse.block_accumulate(local, contrib, 1024)),
-        np.asarray(sparse.block_accumulate(
-            local[:, kept], contrib[:, kept], 1024)))
+        _accumulate(path, local, contrib, 1024),
+        _accumulate(path, local_in[:, kept], contrib[:, kept], 1024))
+
+
+@pytest.mark.parametrize("tile", [128, 256, 512])
+def test_the_kernels_walk_groups_of_every_kind_whatever_the_tile(tile, monkeypatch):
+    """Several lengths a call (a body each, the slots of one a loop), at
+    each tile a batch can be cut in: the margin's share and the sums
+    against NumPy float64."""
+    groups = [(128, 3), (256, 2), (2048, 1), (6144, 2), (26624, 1)]
+    batch = 3 * tile if tile < 512 else 1024
+    monkeypatch.setattr(sparse_blocks, "TILE", 512)
+    assert sparse_blocks.tile_rows(batch, sparse_blocks.walk(groups)) == tile
+    rng = np.random.default_rng(tile)
+    blocks = [rng.standard_normal((n, length // 128, 128)).astype(np.float32)
+              for length, n in groups]
+    # The walked slots' rows among the step's cells, not in their order,
+    # rows 2 and 5 another kind's (never read); every block starts at a
+    # row of its own.
+    where = [7, 0, 9, 3, 10, 1, 4, 8, 6]
+    starts = rng.integers(0, 50, 11).astype(np.int32)
+    lengths = [length for length, n in groups for _ in range(n)]
+    local = np.full((11, batch), 1 << 30, np.int32)
+    for row, length in zip(where, lengths):
+        local[row] = rng.integers(0, length, batch)
+    cells = local + 128 * starts[:, None]
+    vals = rng.standard_normal(local.shape).astype(np.float32)
+    mult = rng.standard_normal(batch).astype(np.float32)
+    flat = [b[i].reshape(-1) for b in blocks for i in range(b.shape[0])]
+    want = sum(vals[row].astype(np.float64) * block[local[row]]
+               for row, block in zip(where, flat))
+    operands = (jnp.asarray(cells), jnp.asarray(vals), jnp.asarray(starts))
+    got = np.asarray(sparse_blocks.lookup_dot(
+        groups, where, [jnp.asarray(b) for b in blocks], *operands,
+        interpret=True))
+    np.testing.assert_allclose(got, want, rtol=0, atol=4e-6)
+    sums = sparse_blocks.accumulate(
+        groups, where, *operands, jnp.asarray(mult), interpret=True)
+    rows = iter(where)
+    for (length, n), out in zip(groups, sums):
+        assert out.shape == (n, length // 128, 128)
+        for i in range(n):
+            row = next(rows)
+            exact = np.zeros(length)
+            np.add.at(exact, local[row], vals[row].astype(np.float64) * mult)
+            np.testing.assert_allclose(
+                np.asarray(out[i]).reshape(-1), exact, rtol=0, atol=2e-5)
+
+
+def test_where_the_kernels_apply_is_read_off_the_step(monkeypatch):
+    from flinkml_tpu.kernels import _gate
+
+    criteo = [(128, 11), (256, 10), (4096, 1), (26624, 8)]
+    long = [(194_560, 5), (59_392, 2), (8_192, 32)]     # Criteo field by field
+    reason = sparse_blocks.unsupported_reason
+    assert sparse_blocks.tile_rows(65_536, sparse_blocks.walk(criteo)) == sparse_blocks.TILE
+    # a long block's product is cut in shorter tiles; a batch has to be tiles
+    assert sparse_blocks.tile_rows(65_536, sparse_blocks.walk(long)) == 512
+    assert sparse_blocks.tile_rows(384, sparse_blocks.walk(criteo)) == 128
+    assert sparse_blocks.tile_rows(100, sparse_blocks.walk(criteo)) is None
+    assert reason(jnp.float32, 65_536, criteo) is None
+    assert reason(jnp.float32, 65_536, long) is None
+    assert "bfloat16" in reason(jnp.bfloat16, 65_536, criteo)
+    assert "whole tiles" in reason(jnp.float32, 1000, criteo)
+    assert "fast memory" in reason(jnp.float32, 65_536, [(194_560, 39)])
+    # here, on a CPU, Mosaic's kernels would be interpreted: XLA's products
+    plan = (128, 256, None, 26624)
+    assert not _linear_sgd._blocks_in_fast_memory(jnp.float32, 65_536, plan)
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)     # a TPU
+    assert _linear_sgd._blocks_in_fast_memory(jnp.float32, 65_536, plan)
+    assert not _linear_sgd._blocks_in_fast_memory(jnp.float32, 1000, plan)
+    assert not _linear_sgd._blocks_in_fast_memory(jnp.float64, 65_536, plan)
+    assert not _linear_sgd._blocks_in_fast_memory(jnp.float32, 65_536, ())
+    assert not _linear_sgd._blocks_in_fast_memory(jnp.float32, 65_536, (None,) * 4)
+    # neighbours of one shape are one group: four loops for eleven lengths
+    assert [(g.members, g.first, g.c, g.rows, g.narrow) for g in sparse_blocks.walk(
+        [(128, 11), (256, 10), (512, 1), (4096, 2), (5120, 1), (15360, 1), (26624, 8)])
+    ] == [(((128, 11), (256, 10)), 0, 8, 32, True),
+          (((512, 1), (4096, 2)), 21, 128, 32, True),
+          (((5120, 1), (15360, 1)), 24, 128, 128, False),
+          (((26624, 8),), 26, 128, 208, False)]
 
 
 # -- the step -----------------------------------------------------------------
@@ -124,6 +303,36 @@ DIM, WIDTH, BS = 5000, 6, 16
 MIXED_PLAN = (128, 128, 256, None, 1024, None)
 MIXED_STARTS = np.asarray([0, 0, 0, 0, 32, 0], np.int32)
 TOP = 32 * 128
+
+
+def test_pallas_is_imported_beside_the_host_work_on_a_tpu_alone(monkeypatch):
+    """A sparse fit asks for Pallas early only where its step will trace
+    the kernels and the import is still to do."""
+    import sys
+    import threading
+
+    from flinkml_tpu.kernels import _gate
+
+    started = []
+
+    class Recorded:
+        def __init__(self, **kw):
+            started.append(kw)
+
+        def start(self):
+            pass
+
+    monkeypatch.setattr(threading, "Thread", Recorded)
+    sparse_blocks.import_beside_host_work()                 # a CPU
+    assert started == []
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setitem(sys.modules, "jax.experimental.pallas", object())
+    sparse_blocks.import_beside_host_work()                 # imported already
+    assert started == []
+    monkeypatch.delitem(sys.modules, "jax.experimental.pallas")
+    sparse_blocks.import_beside_host_work()
+    (thread,) = started
+    assert thread["args"] == ("jax.experimental.pallas",) and thread["daemon"]
 
 
 def _step_rows(rows, seed=1):
@@ -139,9 +348,10 @@ def _step_rows(rows, seed=1):
     return idx, val, y, w
 
 
-def _run_step(mesh, loss, plan, data, coef, epoch, starts=None):
+def _run_step(mesh, loss, plan, data, coef, epoch, starts=None, bs=BS,
+              check_vma=True):
     step = _linear_sgd.make_sparse_step_bucketed(
-        loss, (BS,), "data", DIM, "xla", plan)
+        loss, (bs,), "data", DIM, "xla", plan)
     if plan:
         data += (mesh.shard_batch(np.tile(starts, mesh.axis_size())),)
     f = jax.jit(jax.shard_map(
@@ -149,32 +359,67 @@ def _run_step(mesh, loss, plan, data, coef, epoch, starts=None):
             c, e, *placed, jnp.float32(0.3), jnp.float32(0.01),
             jnp.float32(0.001)),
         mesh=mesh.mesh, in_specs=(P(), P()) + (P("data"),) * len(data),
-        out_specs=(P(), P())))
+        out_specs=(P(), P()), check_vma=check_vma))
     new_coef, loss_value = f(coef, jnp.asarray(epoch, jnp.int32), *data)
     return np.asarray(new_coef), float(loss_value)
 
 
+@pytest.fixture(params=PATHS)
+def blocked(request, monkeypatch):
+    """How the blocked step is run: ``(rows a device a step, keywords of
+    :func:`_run_step`)``. ``xla``: the products of ``ops.sparse``, as a
+    CPU runs the step. ``kernel``: the step as a TPU traces it, the two
+    kernels of ``kernels.sparse_blocks`` interpreted (whole tiles of 128
+    rows a device; an interpreted kernel's values carry no mesh axes, so
+    the ``shard_map`` does not check them)."""
+    if request.param == "xla":
+        return BS, {}
+    monkeypatch.setattr(
+        _linear_sgd, "_blocks_in_fast_memory",
+        lambda dtype, bs, plan: any(plan) and dtype == jnp.float32)
+    return 128, {"bs": 128, "check_vma": False}
+
+
+def _step_uses_the_kernels(plan, bs):
+    step = _linear_sgd.make_sparse_step_bucketed(
+        "logistic", (bs,), "data", DIM, "xla", plan)
+    f32 = jnp.float32
+    args = [jax.ShapeDtypeStruct((DIM,), f32), jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((3 * bs, WIDTH), jnp.int32),
+            jax.ShapeDtypeStruct((3 * bs, WIDTH), f32),
+            jax.ShapeDtypeStruct((3 * bs,), f32), jax.ShapeDtypeStruct((3 * bs,), f32)]
+    if any(plan):                                   # the blocks' starts
+        args.append(jax.ShapeDtypeStruct((WIDTH,), jnp.int32))
+    args += [jax.ShapeDtypeStruct((), f32)] * 3
+    program = jax.make_jaxpr(
+        lambda *a: step(*a), axis_env=[("data", 1)])(*args)
+    return len(_pallas_calls(program.jaxpr))
+
+
 @pytest.mark.parametrize("loss", ["logistic", "hinge", "squared"])
-def test_the_blocked_step_agrees_with_the_general_step(mesh, loss):
+def test_the_blocked_step_agrees_with_the_general_step(mesh, loss, blocked):
+    bs, how = blocked
     p = mesh.axis_size()
     data = tuple(jax.device_put(a, NamedSharding(mesh.mesh, P("data")))
-                 for a in _step_rows(p * 3 * BS))
+                 for a in _step_rows(p * 3 * bs))
     coef = jnp.asarray(
         np.random.default_rng(2).standard_normal(DIM).astype(np.float32))
+    assert _step_uses_the_kernels(MIXED_PLAN, bs) == (2 if how else 0)
     for epoch in (0, 2):
-        want, want_loss = _run_step(mesh, loss, (), data, coef, epoch)
+        want, want_loss = _run_step(mesh, loss, (), data, coef, epoch, bs=bs)
         got, got_loss = _run_step(
-            mesh, loss, MIXED_PLAN, data, coef, epoch, MIXED_STARTS)
+            mesh, loss, MIXED_PLAN, data, coef, epoch, MIXED_STARTS, **how)
         assert np.abs(want - np.asarray(coef)).max() > 1e-3
         np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
         assert got_loss == pytest.approx(want_loss, rel=1e-6)
 
 
-def test_blocks_that_start_elsewhere_run_the_same_step(mesh):
+def test_blocks_that_start_elsewhere_run_the_same_step(mesh, blocked):
     """The starts are an operand: the cells moved by three rows of 128,
     and the coefficients with them, give the same step three rows on."""
+    bs, how = blocked
     p = mesh.axis_size()
-    idx, val, y, w = _step_rows(p * 3 * BS)
+    idx, val, y, w = _step_rows(p * 3 * bs)
     low, top = TOP - 384, DIM - 384                # where slot 4's block goes
     for slot in (3, 5):                            # the gathered cells stay
         idx[:, slot] = 640 + idx[:, slot] % (low - 640)
@@ -187,20 +432,134 @@ def test_blocks_that_start_elsewhere_run_the_same_step(mesh):
         jax.device_put(a, NamedSharding(mesh.mesh, P("data"))) for a in arrays)
     want, want_loss = _run_step(
         mesh, "logistic", MIXED_PLAN, place(idx, val, y, w),
-        jnp.asarray(coef), 1, MIXED_STARTS)
+        jnp.asarray(coef), 1, MIXED_STARTS, **how)
     got, got_loss = _run_step(
         mesh, "logistic", MIXED_PLAN, place(idx + 128 * shift, val, y, w),
-        jnp.asarray(moved), 1, MIXED_STARTS + shift)
+        jnp.asarray(moved), 1, MIXED_STARTS + shift, **how)
     assert got_loss == want_loss
     np.testing.assert_array_equal(got[384:640], want[:256])
     np.testing.assert_array_equal(got[low:top], want[TOP:])
     np.testing.assert_array_equal(got[640:low], want[640:low])
 
 
+def test_a_step_the_kernels_do_not_take_is_the_step_as_it_was(monkeypatch):
+    """Another dtype, a batch that is not whole tiles, the empty plan, a
+    CPU: no kernel in the step, whatever else holds."""
+    from flinkml_tpu.kernels import _gate
+
+    assert _step_uses_the_kernels(MIXED_PLAN, 128) == 0          # a CPU
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)  # a TPU
+    assert _step_uses_the_kernels(MIXED_PLAN, 128) == 2
+    assert _step_uses_the_kernels(MIXED_PLAN, 100) == 0
+    assert _step_uses_the_kernels((), 128) == 0
+    assert _step_uses_the_kernels((None,) * WIDTH, 128) == 0
+
+
 def test_a_plan_with_several_buckets_is_refused():
     with pytest.raises(ValueError, match="one-bucket"):
         _linear_sgd.make_sparse_step_bucketed(
             "logistic", (8, 8), "data", DIM, "xla", MIXED_PLAN)
+
+
+def _products_through_xla(monkeypatch):
+    """The two kernels' places in the step taken by ``ops.sparse``'
+    products on the same operands (an interpreted kernel cannot run
+    inside the trainer's ``shard_map``, which checks its values' mesh
+    axes), and the step told it is on a TPU: what is run is how the
+    trainer walks its plan for the kernels and takes their sums back."""
+    def local_of(at, where, cells, starts, slots):
+        rows = jnp.asarray(list(where[at:at + slots]))
+        return cells[rows] - 128 * starts[rows][:, None], rows
+
+    def lookup_dot(groups, where, blocks, cells, vals, starts):
+        dot, at = 0.0, 0
+        for (length, slots), block in zip(groups, blocks):
+            local, rows = local_of(at, where, cells, starts, slots)
+            dot = dot + jnp.sum(vals[rows] * sparse.block_lookup(
+                block.reshape(slots, length), local), axis=0)
+            at += slots
+        return dot
+
+    def accumulate(groups, where, cells, vals, starts, mult):
+        sums, at = [], 0
+        for length, slots in groups:
+            local, rows = local_of(at, where, cells, starts, slots)
+            sums.append(sparse.block_accumulate(
+                local, vals[rows] * mult[None, :], length).reshape(slots, -1, 128))
+            at += slots
+        return sums
+
+    monkeypatch.setattr(sparse_blocks, "lookup_dot", lookup_dot)
+    monkeypatch.setattr(sparse_blocks, "accumulate", accumulate)
+    monkeypatch.setattr(
+        _linear_sgd, "_blocks_in_fast_memory",
+        lambda dtype, bs, plan: any(plan) and dtype == jnp.float32)
+
+
+def _fit_counting(fit):
+    counters = metrics.group("trainer")
+    before = counters.snapshot()["counters"].get("fused_block_fits")
+    coef = fit()
+    return coef, counters.snapshot()["counters"]["fused_block_fits"] - (before or 0.0)
+
+
+def test_a_fit_counts_whether_its_loop_carried_the_kernels(mesh, monkeypatch):
+    """``trainer.fused_block_fits``: counted at the loop, one a fit, 0.0
+    where XLA's products ran (here, a CPU); and the fit whose step walks
+    its plan as it does for the kernels is the fit."""
+    indices = _field_rows(14, rows=2048)
+    rows, width = indices.shape
+    values = np.random.default_rng(14).standard_normal(indices.shape).astype(np.float32)
+    y = (np.random.default_rng(15).random(rows) < 0.4).astype(np.float32)
+
+    def fit():
+        _linear_sgd._sparse_trainer_bucketed.cache_clear()
+        return _linear_sgd.train_linear_model_sparse_csr(
+            np.arange(rows + 1, dtype=np.int64) * width, indices.reshape(-1),
+            values.reshape(-1), PLAN_DIM, y, None, loss="logistic", mesh=mesh,
+            max_iter=4, learning_rate=0.5, global_batch_size=1024, reg=0.0,
+            elastic_net=0.0, tol=0.0, seed=3)
+
+    plain, counted = _fit_counting(fit)
+    assert counted == 0.0
+    _products_through_xla(monkeypatch)
+    walked, counted = _fit_counting(fit)
+    assert counted == 1.0
+    _linear_sgd._sparse_trainer_bucketed.cache_clear()
+    np.testing.assert_allclose(walked, plain, rtol=0, atol=1e-6)
+    assert np.abs(plain).max() > 1e-2
+
+
+def test_the_fused_share_reads_the_count_over_the_fits():
+    """``benchmark/metrics/trainer.sparse_fused_block_share.json``
+    through the benchmark's ``counter_ratio`` over a window's counters
+    as ``benchmark/run.py`` flattens them, and its entry in
+    ``BENCHMARK.json``."""
+    import json
+    import os
+
+    from benchmark.readers import counter_ratio
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = "trainer.sparse_fused_block_share"
+    with open(os.path.join(root, "benchmark", "metrics", f"{name}.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "counter_ratio"
+    assert spec["params"] == {"num": "trainer.fused_block_fits", "den": "fits"}
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": "fits/fit", "better": "higher",
+        "source": "program_counter", "layer": "Kernels",
+        "moves": "fit_samples_per_s",
+        "workloads": ["lr-criteo.fit", "lr-criteo.fit-cold"]}
+    obs = {"setup_counters": {}, "units": {"fits": 17}}
+    for fused, share in ((17.0, 1.0), (0.0, 0.0)):
+        counters = {"trainer.fused_block_fits": fused, "trainer.steps": 2720.0}
+        assert counter_ratio.read(spec["params"], {**obs, "counters": counters}) == share
+    # a program without the count (the parent): no metric, no error
+    assert counter_ratio.read(
+        spec["params"], {**obs, "counters": {"trainer.steps": 2720.0}}) is None
 
 
 # -- the planner --------------------------------------------------------------
