@@ -139,6 +139,64 @@ def interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
+#: What tracing a Mosaic kernel imports: Pallas, and the module its TPU
+#: lowering pulls in at the first ``pallas_call`` lowered (private: left
+#: to that moment where this version of JAX has none of the name).
+_TRACING_IMPORTS = ("jax.experimental.pallas", "jax.experimental.pallas.tpu",
+                    "jax._src.pallas.mosaic.pallas_call_registration")
+
+
+def _import_keeping_bytecode(names=_TRACING_IMPORTS) -> None:
+    """Import ``names``, their bytecode kept beside the programs this
+    process keeps: where JAX's persistent compilation cache is on (the
+    entry point's or ``JAX_COMPILATION_CACHE_DIR``'s directory), the
+    interpreter reads and writes the modules' compiled code under its
+    ``pycache/`` while the imports run (``sys.pycache_prefix``, put back
+    after). On a host whose site-packages keep no bytecode the import of
+    Pallas is 1.09 s of ``compile()`` in 1.15 (PERF.md section 5), each
+    process anew, where the program it traces comes from the cache; a
+    process with no cache directory imports as it always did."""
+    import importlib
+    import os
+    import sys
+
+    from flinkml_tpu.utils import jax_cache
+
+    kept = jax_cache.in_use()
+    before = sys.pycache_prefix, sys.dont_write_bytecode
+    if kept:
+        sys.pycache_prefix = os.path.join(kept, "pycache")
+        sys.dont_write_bytecode = False
+    try:
+        for name in names:
+            try:
+                importlib.import_module(name)
+            except ImportError:
+                if not name.startswith("jax._src."):
+                    raise
+    finally:
+        sys.pycache_prefix, sys.dont_write_bytecode = before
+
+
+def import_beside_host_work() -> None:
+    """Start importing Pallas on a thread of its own, on a TPU, where it
+    is not imported yet: what a fit whose step may hold a Mosaic kernel
+    (``sparse_blocks``, ``dense_step``) calls where it starts. A fit's
+    first dispatch traces the kernels, and before it comes host work
+    that is NumPy's (the plan's pass, the seeded permutation and gather:
+    0.8 s at ``lr-criteo``'s 16.8 M rows, 0.7 s at ``lr-a9a``'s 9.4 M);
+    the import otherwise stands in the fit between its placement's first
+    round and its first step (:func:`_import_keeping_bytecode` has what
+    it costs). The import's own lock makes the tracing thread wait for
+    what is left of it."""
+    import sys
+    import threading
+
+    if interpret_mode() or "jax.experimental.pallas" in sys.modules:
+        return
+    threading.Thread(target=_import_keeping_bytecode, daemon=True).start()
+
+
 def out_struct(shape, dtype, *operands):
     """The ``out_shape`` entry for a ``pallas_call`` whose output varies
     over the same manual mesh axes as ``operands``: inside

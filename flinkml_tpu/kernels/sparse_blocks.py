@@ -123,28 +123,6 @@ def walk(groups: Sequence[Tuple[int, int]]) -> Tuple[Group, ...]:
     return tuple(out)
 
 
-def import_beside_host_work() -> None:
-    """Start importing Pallas on a thread of its own, on a TPU, where it
-    is not imported yet. A fit's first dispatch traces the kernels, and
-    before it comes host work that is NumPy's (the plan's pass, the
-    seeded permutation: 0.8 s at ``lr-criteo``'s 16.8 M rows); an
-    interpreter that keeps no bytecode compiles Pallas' hundred modules
-    from source, 1.3 s on the chip's host, which otherwise stands in
-    the fit between its placement's first round and its first step
-    (PERF.md section 5). The import's own lock makes the tracing thread
-    wait for what is left of it."""
-    import importlib
-    import sys
-    import threading
-
-    from flinkml_tpu.kernels import _gate
-
-    if _gate.interpret_mode() or "jax.experimental.pallas" in sys.modules:
-        return
-    threading.Thread(target=importlib.import_module, daemon=True,
-                     args=("jax.experimental.pallas",)).start()
-
-
 def tile_rows(batch: int, groups: Sequence[Group]) -> Optional[int]:
     """Batch rows a grid step: the most, of :data:`TILE` halved down to
     128, that divide the batch and keep what a step makes of the longest

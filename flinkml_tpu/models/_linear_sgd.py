@@ -87,11 +87,17 @@ def align_local_bs(global_batch_size: int, p_size: int, n_local: int) -> int:
     return min(max(1, math.ceil(global_batch_size / p_size)), n_local)
 
 
+def _window_start(n_rows: int, epoch, local_bs: int):
+    """Row the window of step ``epoch`` starts at, before any clamping:
+    window ``epoch mod ceil(n_rows / local_bs)``."""
+    n_windows = max(-(-n_rows // local_bs), 1)
+    return (jnp.asarray(epoch, jnp.int32) % n_windows) * local_bs
+
+
 def _window(arr, epoch, local_bs):
     """Contiguous rotating window with ceil coverage (tail included via
     dynamic_slice clamping)."""
-    n_windows = max(-(-arr.shape[0] // local_bs), 1)
-    start = (jnp.asarray(epoch, jnp.int32) % n_windows) * local_bs
+    start = _window_start(arr.shape[0], epoch, local_bs)
     zero = jnp.zeros((), dtype=start.dtype)
     if arr.ndim == 1:
         return jax.lax.dynamic_slice(arr, (start,), (local_bs,))
@@ -213,23 +219,52 @@ def _find_or_keep(slot, place, windows, nbytes: int, mesh: DeviceMesh,
     return found_or_placed
 
 
-def make_dense_step(loss: str, local_bs: int, axis: str):
-    """Per-device epoch: window → margin grad on MXU → psum → prox update.
+def _rows_in_fast_memory(xl, coef, local_bs: int) -> bool:
+    """Whether a dense step's window is read ONCE, by
+    :mod:`flinkml_tpu.kernels.dense_step` (a tile of rows makes its
+    margins, multipliers and gradient while it is in fast memory), and
+    not twice, by XLA's forward and back products: on a TPU, float32
+    rows and coefficients, a shard in whole windows of whole tiles, a
+    width the vector unit's products are for. Read off what the step is
+    handed; nothing sets it."""
+    from flinkml_tpu.kernels import dense_step
 
-    A hand-fused Pallas version of this step lost to this plain
-    lowering and was removed (not re-measured on the current chip) —
-    XLA's forward + back-product pair is the product path."""
+    return (coef.dtype == xl.dtype and dense_step.unsupported_reason(
+        xl.dtype, xl.shape[0], local_bs, xl.shape[1]) is None)
+
+
+def make_dense_step(loss: str, local_bs: int, axis: str):
+    """Per-device epoch: window → margins, multipliers, gradient → psum
+    → prox update.
+
+    Where :func:`_rows_in_fast_memory` says so the window's three sums
+    are ``kernels.dense_step.margin_grad``'s, its rows read from the
+    shard in place and once; everywhere else XLA's forward and back
+    products, each a pass over a ``dynamic_slice`` of the shard. On a
+    v5e at ``lr-a9a.fit``'s 262,144 x 123 float32 XLA's pair is two
+    ``multiply_reduce`` fusions at 0.182 and 0.180 ms a step, nine
+    tenths of the HBM rate each (ledger, PR 39); the kernel's one read
+    is 0.199 ms a step, one read of the window at 80 % of the HBM's
+    rate (chip runs, PR 40; PERF.md section 5). The hand-fused
+    Pallas step this file once had streamed the rows through the MXU
+    and lost to XLA's pair; this one loads them as its weights."""
+    from flinkml_tpu.kernels import dense_step
 
     def step(coef, epoch, xl, yl, wl, learning_rate, reg_l2, reg_l1):
-        xb = _window(xl, epoch, local_bs)
-        yb = _window(yl, epoch, local_bs)
-        wb = _window(wl, epoch, local_bs)
-        acc = _acc_dt(xb.dtype)
-        dot = xb @ coef
-        mult, per_ex = _margin_grad(loss, dot, yb, wb)
-        grad_l = xb.T @ mult
-        loss_l = jnp.sum(per_ex.astype(acc))
-        wsum_l = jnp.sum(wb.astype(acc))
+        acc = _acc_dt(xl.dtype)
+        if _rows_in_fast_memory(xl, coef, local_bs):
+            grad_l, loss_l, wsum_l = dense_step.margin_grad(
+                loss, xl, yl, wl, coef,
+                _window_start(xl.shape[0], epoch, local_bs), local_bs)
+        else:
+            xb = _window(xl, epoch, local_bs)
+            yb = _window(yl, epoch, local_bs)
+            wb = _window(wl, epoch, local_bs)
+            dot = xb @ coef
+            mult, per_ex = _margin_grad(loss, dot, yb, wb)
+            grad_l = xb.T @ mult
+            loss_l = jnp.sum(per_ex.astype(acc))
+            wsum_l = jnp.sum(wb.astype(acc))
         grad = jax.lax.psum(grad_l, axis)
         loss_sum = jax.lax.psum(loss_l, axis)
         wsum = jax.lax.psum(wsum_l, axis)
@@ -777,16 +812,30 @@ def train_linear_model(
     p_size = mesh.axis_size()
     n_local = -(-n // p_size)
     local_bs = align_local_bs(global_batch_size, p_size, n_local)
+    from flinkml_tpu.kernels import _gate, dense_step
+
+    dt = _placed_dtype(x, dtype)
+    # What the step will read off its operands (:func:`_rows_in_fast_
+    # memory`), known here already: what tracing the kernel imports
+    # loads beside the permutation, the gather and the upload.
+    fused = dense_step.unsupported_reason(
+        dt, n_local, local_bs, x.shape[1]) is None
+    if fused:
+        _gate.import_beside_host_work()
     trainer = _dense_trainer(mesh.mesh, loss, local_bs, DeviceMesh.DATA_AXIS)
     place = _dense_placement(x, y, w, mesh, seed, dtype, n_local, local_bs, kept)
-    return _run_chunked(
-        trainer, place, x.shape[1], _placed_dtype(x, dtype),
+    coef = _run_chunked(
+        trainer, place, x.shape[1], dt,
         learning_rate, reg * (1.0 - elastic_net), reg * elastic_net,
         tol, max_iter, mesh,
         checkpoint_manager=checkpoint_manager,
         checkpoint_interval=checkpoint_interval,
         resume=resume, listeners=listeners,
     )
+    # Counted at the loop, as ``fused_block_fits`` is: a fit that finds
+    # its placement kept runs the same dispatches.
+    metrics.group("trainer").counter("fused_dense_fits", float(fused))
+    return coef
 
 
 def prepare_sparse_buckets(
@@ -1024,9 +1073,9 @@ def train_linear_model_sparse_csr(
     if np.dtype(dtype) == np.float32:
         # A plan's step may hold the block kernels (a TPU's): what
         # tracing them imports loads beside the pack and the permutation.
-        from flinkml_tpu.kernels import sparse_blocks
+        from flinkml_tpu.kernels import _gate
 
-        sparse_blocks.import_beside_host_work()
+        _gate.import_beside_host_work()
     place, local_bss, slot_plan = prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
         max_buckets=max_buckets, dtype=dtype, seed=seed, kept=kept,

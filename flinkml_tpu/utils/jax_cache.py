@@ -31,6 +31,15 @@ def aot_dir() -> str:
     return os.path.join(cache_dir(), "aot")
 
 
+def in_use():
+    """The directory this process's JAX keeps compiled programs in
+    (:func:`enable`'s, or the variable's), None where the persistent
+    cache is off: for what else is kept beside the programs."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir
+
+
 def enable() -> str:
     """Turn the persistent cache on for this process; return its
     directory. Every compile is cached (the default floor of one second

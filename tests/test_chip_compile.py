@@ -303,6 +303,69 @@ def test_sparse_block_kernels_take_criteo_laid_out_field_by_field(
     assert accumulate.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("loss,rows,dim,batch,tile", [
+    ("logistic", 9_437_184, 123, 262_144, 4096),      # lr-a9a.fit's
+    ("hinge", 9_437_184, 123, 262_144, 4096),
+    ("squared", 9_437_184, 123, 262_144, 4096),
+    ("logistic", 1_048_576, 1020, 8_192, 2048),       # several rows of lanes
+    ("logistic", 262_144, 2048, 65_536, 1024),        # the widest it takes
+])
+def test_dense_step_kernel_at_the_cells_size(one_chip, no_compile_cache,
+                                             monkeypatch, loss, rows, dim,
+                                             batch, tile):
+    """``kernels.dense_step.margin_grad`` alone, compiled by Mosaic for
+    a described v5e: ``lr-a9a.fit``'s window (262,144 x 123 of 9,437,184
+    resident rows, tiles of 4,096, every loss: one body each) and the
+    widths either side of it that ``unsupported_reason`` lets through.
+    UNDER x64: the kernel is traced in 32-bit mode whatever the flag
+    says (one float64 block and Mosaic aborts the process, so the traced
+    program is read first). The table is an operand as it lies: the
+    compiled program holds no second array."""
+    from flinkml_tpu.kernels import _gate, dense_step
+
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    assert dense_step.unsupported_reason(jnp.float32, rows, batch, dim) is None
+    assert dense_step.tile_rows(batch, dim) == tile
+
+    def on(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.enable_x64(True):
+        traced = jax.jit(lambda x, y, w, c, at: dense_step.margin_grad(
+            loss, x, y, w, c, at, batch, interpret=False)).trace(
+                on((rows, dim)), on((rows,)), on((rows,)), on((dim,)),
+                on((), jnp.int32))
+        (kernel,) = _pallas_calls(traced.jaxpr.jaxpr)
+        inner = kernel.params["jaxpr"]
+        assert [str(v.aval) for v in inner.invars + inner.outvars
+                if re.search(r"[fiu]64", str(v.aval))] == []
+        compiled = traced.lower().compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * dim * 128 * 4
+
+
+def test_which_tables_lie_with_their_features_along_the_lanes(one_chip,
+                                                              no_compile_cache):
+    """``dense_step.features_along_lanes``, the one thing the kernel's
+    selection cannot read off its operands, against what the chip's
+    compiler does: a float32 ``[n, dim]`` table is row-major where that
+    pads it no more than the other way (the last row of lanes filled to
+    within eight), and lies with its ROWS along the lanes elsewhere
+    (``knn-mnist8m``'s 784 columns, PR 30): a kernel handed such a table
+    by its rows would have the whole of it re-laid first."""
+    from flinkml_tpu.kernels import dense_step
+
+    widths = [1, 8, 9, 100, 120, 121, 123, 127, 128, 129, 136, 249, 250, 256,
+              257, 500, 505, 784, 1000, 1017, 1024, 1535, 2000, 2041, 2048]
+    for rows in (65_536, 9_437_184):
+        for dim in widths if rows < 1e6 else (123,):
+            table = jax.ShapeDtypeStruct((rows, dim), jnp.float32, sharding=one_chip)
+            compiled = jax.jit(lambda x: jnp.sum(x, axis=0)).lower(table).compile()
+            ((held,), _) = compiled.input_formats
+            assert (held.layout.major_to_minor == (0, 1)) == (
+                dense_step.features_along_lanes(dim)), (rows, dim, held)
+
+
 def _pallas_calls(jaxpr):
     """Every ``pallas_call`` of ``jaxpr``, inner programs included."""
     for eqn in jaxpr.eqns:
@@ -312,9 +375,16 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(sub)
 
 
-def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(topo, no_compile_cache):
+@pytest.mark.parametrize("step", ["kernel", "xla"])
+def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(
+        topo, no_compile_cache, monkeypatch, step):
     """``lr-a9a.fit``'s one program, ``lr_dense_loop``, at the cell's size
-    (9,437,184 x 123 float32 rows, batch 262,144, on a one-chip mesh). A
+    (9,437,184 x 123 float32 rows, batch 262,144, on a one-chip mesh),
+    with the step as a TPU traces it (``kernel``: ``kernels.dense_step``'s
+    one Mosaic kernel, compiled here and not interpreted, the rows read
+    in place: no slice and no copy of them, the labels' reshape a
+    bitcast, under x64 as the suite runs, traced in 32-bit mode all the
+    same) and as every other backend does (``xla``: two products). A
     fit that places its table enters it a chunk at a time, each chunk
     from the carry the chunk before returned, to the step the landed
     rows allow; a fit that finds its placement kept with its ``Table``
@@ -326,9 +396,12 @@ def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(topo, no_compile
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from flinkml_tpu.kernels import _gate
     from flinkml_tpu.models import _linear_sgd
 
     rows, dim, batch, max_iter = 9_437_184, 123, 262_144, 72
+    if step == "kernel":
+        monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
     mesh = Mesh(np.array(topo.devices[:1]), ("data",))
     by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
 
@@ -336,6 +409,7 @@ def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(topo, no_compile
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     f32 = jnp.float32
+    _linear_sgd._dense_trainer.cache_clear()             # keyed by no backend
     trainer = _linear_sgd._dense_trainer(mesh, "logistic", batch, "data")
     carry = (on((dim,), f32), on((), jnp.int32), on((), f32))
     data = (on((rows, dim), f32, by_rows), on((rows,), f32, by_rows),
@@ -344,14 +418,30 @@ def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(topo, no_compile
 
     def entered(to_step):
         # as _run_chunked hands it over: a host int32, not a device array
-        return trainer.trace(*carry, *data, *hy, np.int32(to_step)).lower()
+        traced = trainer.trace(*carry, *data, *hy, np.int32(to_step))
+        kernels = list(_pallas_calls(traced.jaxpr.jaxpr))
+        assert len(kernels) == (step == "kernel")
+        # one 64-bit block inside a kernel and Mosaic ABORTS the process
+        assert [str(v.aval) for eqn in kernels
+                for v in eqn.params["jaxpr"].invars + eqn.params["jaxpr"].outvars
+                if re.search(r"[fiu]64", str(v.aval))] == []
+        return traced.lower()
 
-    with jax.enable_x64(False):
-        chunk, hit = entered(5), entered(max_iter)
-        assert chunk.as_text() == hit.as_text()
-        compiled = hit.compile()
+    try:
+        with jax.enable_x64(step == "kernel"):
+            chunk, hit = entered(5), entered(max_iter)
+            assert chunk.as_text() == hit.as_text()
+            compiled = hit.compile()
+    finally:
+        _linear_sgd._dense_trainer.cache_clear()
     text = compiled.as_text()
-    assert "lr_dense_loop" in text and "tpu_custom_call" not in text
+    assert "lr_dense_loop" in text
+    assert text.count("tpu_custom_call") == (step == "kernel")
+    if step == "kernel":
+        # the rows go to the kernel as the table holds them: no copy, no
+        # slice, no product of XLA's over them
+        assert not re.search(r"f32\[\d+,123\]\S* (copy|dynamic-slice|fusion)\(", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6
     (args, _), out = compiled.input_shardings, compiled.output_shardings
     assert all(a.is_equivalent_to(o, c.ndim)
                for a, o, c in zip(args[:3], out, carry))

@@ -306,8 +306,8 @@ TOP = 32 * 128
 
 
 def test_pallas_is_imported_beside_the_host_work_on_a_tpu_alone(monkeypatch):
-    """A sparse fit asks for Pallas early only where its step will trace
-    the kernels and the import is still to do."""
+    """A fit asks for Pallas early only where its step will trace a
+    kernel and the import is still to do."""
     import sys
     import threading
 
@@ -323,16 +323,53 @@ def test_pallas_is_imported_beside_the_host_work_on_a_tpu_alone(monkeypatch):
             pass
 
     monkeypatch.setattr(threading, "Thread", Recorded)
-    sparse_blocks.import_beside_host_work()                 # a CPU
+    _gate.import_beside_host_work()                 # a CPU
     assert started == []
     monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
     monkeypatch.setitem(sys.modules, "jax.experimental.pallas", object())
-    sparse_blocks.import_beside_host_work()                 # imported already
+    _gate.import_beside_host_work()                 # imported already
     assert started == []
     monkeypatch.delitem(sys.modules, "jax.experimental.pallas")
-    sparse_blocks.import_beside_host_work()
+    _gate.import_beside_host_work()
     (thread,) = started
-    assert thread["args"] == ("jax.experimental.pallas",) and thread["daemon"]
+    assert thread["target"] is _gate._import_keeping_bytecode and thread["daemon"]
+
+
+def test_the_early_import_keeps_its_bytecode_beside_the_compile_cache(
+        tmp_path, monkeypatch):
+    """Where the process keeps compiled programs, the modules a kernel's
+    trace imports are compiled once a cache directory and read back by
+    the processes after it (``sys.pycache_prefix`` while the import
+    runs, put back whatever happens); with no cache directory the import
+    is the interpreter's own."""
+    import sys
+
+    from flinkml_tpu.kernels import _gate
+
+    (tmp_path / "src").mkdir()
+    monkeypatch.syspath_prepend(str(tmp_path / "src"))
+    before = sys.pycache_prefix, sys.dont_write_bytecode
+
+    def compiled(name):
+        (tmp_path / "src" / f"{name}.py").write_text("VALUE = 41 + 1\n")
+        _gate._import_keeping_bytecode((name,))
+        assert sys.modules.pop(name).VALUE == 42
+        assert (sys.pycache_prefix, sys.dont_write_bytecode) == before
+        return [p.name for p in (tmp_path / "cache").rglob(f"{name}.*.pyc")]
+
+    from flinkml_tpu.utils import jax_cache
+
+    monkeypatch.setattr(jax_cache, "in_use", lambda: str(tmp_path / "cache"))
+    monkeypatch.setattr(sys, "dont_write_bytecode", True)   # as the chip's host
+    before = sys.pycache_prefix, True
+    assert len(compiled("kept_beside_the_programs")) == 1
+    monkeypatch.setattr(jax_cache, "in_use", lambda: None)
+    assert compiled("kept_nowhere") == []
+    # a private module this JAX does not have is left to the lowering
+    _gate._import_keeping_bytecode(("jax._src.pallas.no_such_module",))
+    with pytest.raises(ImportError):
+        _gate._import_keeping_bytecode(("no_such_public_module",))
+    assert (sys.pycache_prefix, sys.dont_write_bytecode) == before
 
 
 def _step_rows(rows, seed=1):
