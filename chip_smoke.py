@@ -20,9 +20,10 @@ the LAST stdout line of a passing run is
 child process that imports JAX (a chip belongs to one process).
 
 Sizes: dense LR at BASELINE.json config 1's width (d = 123) with 2 GB
-resident; the Criteo-profile sparse LR (dim 1e6, 39 nnz/row); the
-bench's five-stage transform chain over 1M float32 rows; a replica pool
-with one replica per chip. ``--rehearse`` keeps every width and cuts
+resident; a hashed sparse LR (dim 1e6, 39 nnz/row); the benchmark's
+five-stage transform chain (``benchmark/drivers/chain_model.py``) over
+1M float32 rows, held to ``benchmark/reference``; a replica pool with
+one replica per chip. ``--rehearse`` keeps every width and cuts
 rows/steps so tier-1 can run the same code on the CPU mesh.
 """
 
@@ -39,6 +40,11 @@ import threading
 import time
 
 import numpy as np
+
+from benchmark import datagen
+from benchmark.drivers import chain_model
+from benchmark.reference import chain as reference_chain
+from benchmark.reference.linear import log_loss
 
 FULL = dict(
     dense_n=4_194_304, dense_d=123, dense_gbs=262_144, dense_steps=32,
@@ -69,11 +75,6 @@ REHEARSAL = dict(
 def _check(cond: bool, what: str) -> None:
     if not cond:
         raise AssertionError(what)
-
-
-def _log_loss(margins, y) -> float:
-    """Mean logistic loss at float64 (LogisticGradient's form)."""
-    return float(np.mean(np.logaddexp(0.0, -margins * (2.0 * y - 1.0))))
 
 
 class _DeviceWatch:
@@ -164,7 +165,7 @@ def phase_train_dense(ctx) -> dict:
            "coefficients not finite")
     sample = slice(0, min(n, 65_536))
     margins = x[sample].astype(np.float64) @ coef
-    loss = _log_loss(margins, y[sample])
+    loss = log_loss(margins, y[sample])
     acc = float(np.mean((margins >= 0) == (y[sample] > 0)))
     _check(loss < math.log(2.0), f"log-loss {loss} not below ln 2")
     _check(acc > 0.9, f"accuracy {acc} on the planted labels <= 0.9")
@@ -173,18 +174,24 @@ def phase_train_dense(ctx) -> dict:
             "log_loss": loss, "accuracy": acc, **spread}
 
 
-def _criteo_table(n, dim, nnz, seed):
-    """``bench.make_criteo_csr`` as a Table with a SparseVector column
-    (rows that drew one column twice merge the two values, which is what
-    the CSR margins that planted the labels did)."""
-    import bench
+def _criteo_table(n, dim, nnz, seed, n_active=256):
+    """Rows of ``nnz`` cells hashed uniformly over ``dim`` (no slot of
+    theirs is blocked: the sparse trainers' GENERAL step, which no cell
+    of the benchmark runs), labels planted by ``n_active`` coefficients,
+    as a Table with a SparseVector column (rows that drew one column
+    twice merge the two values, which is what the margins that planted
+    the labels did). ``benchmark.datagen_criteo``'s rows keep to their
+    fields' strata and carry logistic noise: neither fits this check."""
     from flinkml_tpu.linalg import SparseVector
     from flinkml_tpu.table import Table
 
-    _, indices, values, y, _ = bench.make_criteo_csr(
-        n, dim=dim, nnz=nnz, seed=seed)
-    idx = indices.reshape(n, nnz).astype(np.int64)
-    val = values.reshape(n, nnz).astype(np.float64)
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, dim, size=(n, nnz)).astype(np.int64)
+    val = rng.normal(size=(n, nnz)).astype(np.float32).astype(np.float64)
+    beta = np.zeros(dim, np.float32)
+    beta[rng.choice(dim, size=n_active, replace=False)] = rng.normal(
+        size=n_active)
+    y = ((val * beta[idx]).sum(axis=1) > 0).astype(np.float32)
     col = np.empty(n, object)
     for i in range(n):
         u, inv = np.unique(idx[i], return_inverse=True)
@@ -213,7 +220,7 @@ def phase_train_sparse(ctx) -> dict:
     _check(coef.shape == (dim,) and np.isfinite(coef).all(),
            "coefficients not finite")
     margins = (val * coef[idx]).sum(axis=1)
-    loss = _log_loss(margins, y)
+    loss = log_loss(margins, y)
     acc = float(np.mean((margins >= 0) == (y > 0)))
     _check(loss < math.log(2.0), f"log-loss {loss} not below ln 2")
     _check(acc > 0.9, f"accuracy {acc} on the planted labels <= 0.9")
@@ -221,44 +228,6 @@ def phase_train_sparse(ctx) -> dict:
     return {"rows": n, "dim": dim, "nnz_per_row": nnz,
             "steps": z["sparse_steps"], "log_loss": loss, "accuracy": acc,
             **spread}
-
-
-def _chain_reference(model, x64: np.ndarray):
-    """The five-stage chain in plain NumPy float64, built from each
-    fitted stage's model data (``get_model_data`` and params) — nothing
-    of ``pipeline_fusion`` or the stages' own transforms."""
-    std_m, mm_m, ma_m, rb_m, lr_m = model.stages
-    (t,) = std_m.get_model_data()
-    mean, std = (np.asarray(t.column(c), np.float64)[0]
-                 for c in ("mean", "std"))
-    out = x64
-    if std_m.get(std_m.WITH_MEAN):
-        out = out - mean
-    if std_m.get(std_m.WITH_STD):
-        out = out / np.where(std > 0, std, 1.0)
-    (t,) = mm_m.get_model_data()
-    dmin, dmax = (np.asarray(t.column(c), np.float64)[0]
-                  for c in ("dataMin", "dataMax"))
-    span = dmax - dmin
-    unit = np.where(span > 0, (out - dmin) / np.where(span > 0, span, 1.0),
-                    0.5)
-    lo, hi = mm_m.get(mm_m.MIN), mm_m.get(mm_m.MAX)
-    out = unit * (hi - lo) + lo
-    (t,) = ma_m.get_model_data()
-    ma = np.asarray(t.column("maxAbs"), np.float64)[0]
-    out = out / np.where(ma > 0, ma, 1.0)
-    (t,) = rb_m.get_model_data()
-    med, rng_ = (np.asarray(t.column(c), np.float64)[0]
-                 for c in ("median", "range"))
-    if rb_m.get(rb_m.WITH_CENTERING):
-        out = out - med
-    if rb_m.get(rb_m.WITH_SCALING):
-        out = out / np.where(rng_ > 0, rng_, 1.0)
-    (t,) = lr_m.get_model_data()
-    coef = np.asarray(t.column("coefficient"), np.float64)[0]
-    dot = out @ coef
-    p = 1.0 / (1.0 + np.exp(-dot))
-    return dot, (dot >= 0).astype(np.float64), np.stack([1.0 - p, p], -1)
 
 
 def _assert_chain_close(what, dot, ref_pred, ref_raw, pred, raw,
@@ -283,24 +252,22 @@ def _assert_chain_close(what, dot, ref_pred, ref_raw, pred, raw,
 
 
 def phase_transform(ctx) -> dict:
-    import jax
-
-    import bench
     from flinkml_tpu.table import Table
     from flinkml_tpu.utils.metrics import metrics
 
     z = ctx.sizes
-    model, x = bench._five_stage_model(
-        z["chain_n"], z["chain_d"], seed=ctx.seed, dtype=np.float32)
+    x = datagen.normal_matrix(
+        ctx.seed, datagen.TAG_FEATURES, z["chain_n"], z["chain_d"])
     _check(x.dtype == np.float32, "chain features are not float32")
-    # The chain's own two-step LR fit leaves every margin near +40:
-    # probabilities saturate at 1 and a comparison of them is blind.
-    # Plant a zero-sum coefficient (the scaled features share an
-    # offset), so margins straddle 0 and every stage's error shows.
+    # A coefficient that does not sum to zero leaves every margin far to
+    # one side (the scaled features share an offset): probabilities
+    # saturate and a comparison of them is blind. Plant a zero-sum one,
+    # so margins straddle 0 and every stage's error shows.
     g = np.random.default_rng([ctx.seed, 2]).standard_normal(z["chain_d"])
     g -= g.mean()
-    model.stages[-1].set_model_data(
-        Table({"coefficient": (2.0 * g / np.linalg.norm(g))[None, :]}))
+    md = dict(datagen.chain_model_data(ctx.seed, z["chain_d"]),
+              coefficient=2.0 * g / np.linalg.norm(g))
+    model = chain_model.build(md)
     table = Table({"features": x})
     fusion = metrics.group("pipeline.fusion")
 
@@ -320,8 +287,7 @@ def phase_transform(ctx) -> dict:
            f"unexpected output shapes {pred.shape} {raw.shape}")
     rows = np.random.default_rng([ctx.seed, 3]).choice(
         z["chain_n"], size=z["chain_sample"], replace=False)
-    dot, ref_pred, ref_raw = _chain_reference(
-        model, x[rows].astype(np.float64))
+    dot, ref_pred, ref_raw = reference_chain.chain(md, x[rows])
     close = _assert_chain_close("transform", dot, ref_pred, ref_raw,
                                 pred[rows], raw[rows], ctx.raw_tol)
     ctx.chain = dict(model=model, x=x, pred=pred, raw=raw)
@@ -509,8 +475,8 @@ def phase_ingest(ctx) -> dict:
 
 
 def phase_kernels(ctx) -> dict:
-    """Each Pallas site at the shape phases 2-4 hand it: it compiles
-    (``interpret=False``) and agrees with the XLA lowering, or its
+    """Each of three Pallas kernels at the shape phases 2-4 hand it: it
+    compiles (``interpret=False``) and agrees with the XLA lowering, or its
     ``unsupported_reason`` names why not and an explicit request raises
     :class:`KernelUnsupportedError` with that reason."""
     import jax
@@ -537,7 +503,8 @@ def phase_kernels(ctx) -> dict:
     sites = {}
 
     def run_site(site, reason, explicit, diff_vs_xla):
-        """``explicit()`` is the dispatcher under ``backend="pallas"``;
+        """``explicit()`` asks for the kernel by name (the dispatcher
+        under ``backend="pallas"``, or the kernel's own entry point);
         ``diff_vs_xla()`` runs the kernel and returns its max abs
         difference to the XLA lowering."""
         if reason is None:
@@ -600,7 +567,8 @@ def phase_kernels(ctx) -> dict:
         return np.max(np.abs(np.asarray(pv) - np.asarray(rv)))
 
     run_site("topk", k_topk.unsupported_reason(xq, z["topk_k"], interpret),
-             lambda: kernels.top_k(xq, z["topk_k"], backend="pallas"),
+             lambda: k_topk.pallas_top_k(xq, z["topk_k"],
+                                         interpret=interpret),
              topk_diff)
 
     # fused chain (phases 3-4): the five-stage model at a serving bucket,
@@ -712,7 +680,7 @@ def phase_multichip(ctx) -> dict:
     _check(coef.shape == (d,) and np.isfinite(coef).all(),
            "FSDP coefficients not finite")
     margins = x.astype(np.float64) @ coef.astype(np.float64)
-    loss = _log_loss(margins, y)
+    loss = log_loss(margins, y)
     _check(loss < math.log(2.0), f"FSDP log-loss {loss} not below ln 2")
     # Parameters and the momentum slot are both [d] float32: sharded
     # over every device, not replicated.

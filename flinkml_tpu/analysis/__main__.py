@@ -119,9 +119,9 @@ _FIXTURE_PASSES = (
 
 
 def _pass_retrace_selfcheck(report: Report) -> None:
-    """Drive the bench's ``pipeline_fused`` chain (4 scalers + a
-    LogisticRegressionModel, the 5-stage all-kernel spine ``bench.py``
-    measures) across varying batch sizes within one row bucket (and one
+    """Drive a five-stage chain (4 scalers + a LogisticRegressionModel,
+    every stage a fusible kernel: the shape of the benchmark's
+    ``chain-a9a``) across varying batch sizes within one row bucket (and one
     boundary crossing) under a zero-budget guard — the runtime half of
     the bucket-policy contract, checked device-free."""
     import numpy as np

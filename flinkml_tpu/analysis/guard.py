@@ -47,13 +47,16 @@ class GuardViolation(AssertionError):
 
 
 def _chain_identity(key: Tuple) -> Tuple:
-    """A fused-cache key minus its row bucket (index 4 of the layout
+    """A fused program's key minus its row bucket (index 4 of the layout
     ``(chain fp, ext specs, const specs, out names, bucket, policy,
     kernel backend)``): the identity under which a compile at a NEW
     bucket is policy-allowed. The precision policy AND the kernel
     backend STAY in the identity — flipping either compiles a genuinely
-    different program."""
-    return key[:4] + key[5:]
+    different program. What a fused-CACHE key carries after those seven
+    elements (the placement, once a compile-cache store is active: any
+    process that has started a ReplicaPool) is not the program's:
+    ``on_compile`` reports the program key alone."""
+    return key[:4] + key[5:7]
 
 
 def _counters(group: str) -> Dict[str, float]:

@@ -42,10 +42,10 @@ Rules:
     footprint (:class:`~flinkml_tpu.sharding.plan.NoFeasiblePlanError`
     rendered as a finding).
 
-The estimate is **measured, not guessed**: ``bench.py``'s ``memory_cpu``
-stage pins it against XLA's own ``Compiled.memory_analysis()``
-(temp + argument + output bytes) on the fused 5-stage chain and the
-plan-sharded SGD step, and CI trips outside a 0.5x-2.0x band.
+The estimate is **measured, not guessed**: ``tests/test_analysis_memory.py``
+pins it against XLA's own ``Compiled.memory_analysis()`` (temp +
+argument + output bytes) on the fused 5-stage chain's arithmetic and the
+plan-sharded SGD step, and fails outside a 0.5x-2.0x band.
 
 Inputs come from live functions pre-compile (:func:`check_memory_fn`,
 :func:`estimate_fn_memory`) or ``*.memory.json`` fixtures

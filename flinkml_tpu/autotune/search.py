@@ -1,7 +1,7 @@
 """Knob searches: measure every candidate through the PRODUCT path.
 
-Each ``measure_*`` function runs a compact version of the bench
-harness's corresponding stage — same trainers, same gates, smaller
+Each ``measure_*`` function runs a compact scenario through the
+product's own path — same trainers, same gates, small
 shapes — and returns ``{candidate: measured_value}`` in the knob's unit
 (throughput; higher is better). :func:`settle` converts measurements
 into a committed default under the **decisive-win hysteresis rule**: the
@@ -49,7 +49,6 @@ STATIC_DEFAULTS: Dict[str, Any] = {
     "serving_window_ms": 2.0,
     "kernel_backend_fused_chain": "xla",
     "kernel_backend_segment_sum": "xla",
-    "kernel_backend_topk": "xla",
     "embedding_exchange": "ring",
     "serving_scale_up_backlog": 0.5,
     "int8_min_const_elems": 16,
@@ -570,34 +569,6 @@ def measure_kernel_backend_segment_sum(quick: bool = False
     return out
 
 
-def measure_kernel_backend_topk(quick: bool = False) -> Dict[str, float]:
-    """KNN-shaped queries/s per top-k backend (``[nq, n]`` distance
-    matrix, k of the bench's neighbor-query size)."""
-    import jax
-    import jax.numpy as jnp
-
-    from flinkml_tpu import kernels
-
-    nq, n, k = (256, 2_048, 8) if quick else (1_024, 8_192, 16)
-    reps = 5 if quick else 20
-    rng = np.random.default_rng(0)
-    d2 = jnp.asarray(rng.normal(size=(nq, n)).astype(np.float32))
-    out: Dict[str, float] = {}
-    for backend in ("xla", "pallas"):
-        fn = jax.jit(functools.partial(kernels.top_k, k=k, backend=backend))
-        np.asarray(fn(-d2)[1])  # compile + warmup
-
-        def rate() -> float:
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                _, idx = fn(-d2)
-            np.asarray(idx)
-            return nq * reps / (time.perf_counter() - t0)
-
-        out[backend] = _timed_rate(rate)
-    return out
-
-
 def measure_embedding_exchange(quick: bool = False) -> Dict[str, float]:
     """Lookup+update rows/s per embedding-exchange candidate on a
     mid-size sharded table (one scatter-exchange + one lookup per
@@ -688,7 +659,6 @@ MEASURERS: Dict[str, Callable[[bool], Dict[str, float]]] = {
     "serving_window_ms": measure_serving_window_ms,
     "kernel_backend_fused_chain": measure_kernel_backend_fused_chain,
     "kernel_backend_segment_sum": measure_kernel_backend_segment_sum,
-    "kernel_backend_topk": measure_kernel_backend_topk,
     "embedding_exchange": measure_embedding_exchange,
     "serving_scale_up_backlog": measure_serving_scale_up_backlog,
     "int8_min_const_elems": measure_int8_min_const_elems,

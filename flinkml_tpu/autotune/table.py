@@ -20,7 +20,7 @@ Format (``tuning_table.json``, committed next to this module)::
 The mesh key is the measurement's validity domain: a winner measured on
 an 8-virtual-device CPU mesh says nothing about a v5p pod, so lookups
 only ever see their own mesh's entry (a chip re-tune lands as a new
-entry — ``bench.py``'s ``autotune`` stage; none is committed yet).
+entry; none is committed yet).
 
 ``candidates`` is committed alongside the winner on purpose: a reader
 can see HOW decisive the win was, and the search's hysteresis rule
@@ -68,11 +68,10 @@ KNOWN_KNOBS: Dict[str, str] = {
     "serving_window_ms": "rows_per_sec",
     # The kernel-backend family (flinkml_tpu.kernels): xla vs pallas
     # per gated site. Committed CPU entries measure the INTERPRETER
-    # (auditable, not competitive); the device re-tune (bench stage
-    # `pallas`) is what can flip these.
+    # (auditable, not competitive); a device re-tune is what can flip
+    # these (docs/development/kernels.md).
     "kernel_backend_fused_chain": "rows_per_sec",
     "kernel_backend_segment_sum": "cells_per_sec",
-    "kernel_backend_topk": "queries_per_sec",
     # The sharded-embedding exchange (flinkml_tpu.embeddings): ring vs
     # all_to_all row routing, with dense_psum (replicated table, dense
     # gradient psum) as the below-threshold candidate — the knob that

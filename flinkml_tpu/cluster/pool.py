@@ -172,8 +172,8 @@ class ClusterPool(ReplicaPool):
     def respawn_dead(self) -> List[Replica]:
         """Replace every retired (dead-worker) replica with a freshly
         spawned one: prune the corpses, spawn warm successors. The
-        recovery idiom the cluster smoke exercises — a respawned worker
-        rejoins with ZERO new XLA compiles because its warmup
+        recovery idiom ``tests/test_cluster.py`` exercises — a respawned
+        worker rejoins with ZERO new XLA compiles because its warmup
         retarget-loads the shared artifacts its predecessor persisted."""
         pruned = self.prune_retired()
         replaced = [self.add_replica() for _ in pruned]

@@ -62,8 +62,7 @@ STRATEGIES = ("ring", "all_to_all", "dense_psum")
 ENV_VAR = "FLINKML_TPU_EMBEDDING_EXCHANGE"
 
 #: Vocab-size override for the dense-psum threshold (lowest vocab that
-#: SHARDS). ``FLINKML_W2V_SHARD_VOCAB`` is honored as a back-compat
-#: alias (it predates this subsystem; 0 forces sharding — the test hook).
+#: SHARDS; 0 forces sharding — the test hook).
 ENV_DENSE_VOCAB_VAR = "FLINKML_TPU_EMBEDDING_DENSE_VOCAB"
 
 #: Below this vocab size a dense [vocab, dim] gradient psum per step
@@ -75,11 +74,8 @@ DENSE_VOCAB_DEFAULT = 1 << 18
 def dense_vocab_threshold() -> int:
     """The vocab size at or below which tables stay replicated and
     gradients ride a dense psum (the ``dense_psum`` placement)."""
-    for var in (ENV_DENSE_VOCAB_VAR, "FLINKML_W2V_SHARD_VOCAB"):
-        raw = os.environ.get(var)
-        if raw is not None:
-            return int(raw)
-    return DENSE_VOCAB_DEFAULT
+    raw = os.environ.get(ENV_DENSE_VOCAB_VAR)
+    return DENSE_VOCAB_DEFAULT if raw is None else int(raw)
 
 
 def exchange_strategy() -> str:
@@ -106,9 +102,8 @@ def exchange_strategy() -> str:
                 f"{ENV_VAR}=dense_psum: dense_psum is the replicated "
                 "PLACEMENT, not a sharded exchange algorithm — to force "
                 f"the dense path, raise the vocab threshold instead "
-                f"({ENV_DENSE_VOCAB_VAR}, or the FLINKML_W2V_SHARD_VOCAB "
-                "alias); on an already-sharded table pick 'ring' or "
-                "'all_to_all'"
+                f"({ENV_DENSE_VOCAB_VAR}); on an already-sharded table "
+                "pick 'ring' or 'all_to_all'"
             )
         return raw
     from flinkml_tpu.autotune import tuned_default

@@ -398,8 +398,8 @@ class EmbeddingTable:
                                 strategy: str = "ring") -> int:
         """Analytic per-step exchange traffic for a ``batch``-id
         gather + scatter round (all shards, both directions) — linear
-        in ``batch``, INDEPENDENT of vocab; the bench stage emits this
-        next to the measured rate so the traffic contract is auditable."""
+        in ``batch``, INDEPENDENT of vocab (``tests/test_embeddings.py``
+        holds it), so the traffic contract is auditable."""
         if not self.sharded or strategy == "dense_psum":
             # The dense placement's psum moves the whole table.
             return 2 * self.padded_vocab * self.dim * self.dtype.itemsize

@@ -53,7 +53,7 @@ site                      where it fires
                           mid-traffic (every batch on that replica
                           raises from then on — the pool must retire it
                           and respread traffic; the chaos contract of
-                          the ``serving scaleout`` CI stage)
+                          ``tests/test_serving_pool.py``)
 ``cluster.worker``        inside a :mod:`flinkml_tpu.cluster` worker
                           process: before every predict dispatch of the
                           worker harness (context: ``worker``,
@@ -92,8 +92,7 @@ nothing is allocated, no callable is invoked, so production paths pay
 nothing. All triggers are counter/epoch based: a plan replays
 identically run after run, which is what lets tests assert bit-exact
 recovery (kill at epoch k, corrupt the newest snapshot, resume, compare
-against the uninterrupted run — see ``tests/test_online_resume.py`` and
-the chaos stage in ``tools/ci.sh``).
+against the uninterrupted run — see ``tests/test_online_resume.py``).
 """
 
 from __future__ import annotations

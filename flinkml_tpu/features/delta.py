@@ -158,8 +158,8 @@ class ModelDelta(Model):
         }
 
     def payload_bytes(self) -> int:
-        """Published payload size (the number the bench's delta-vs-full
-        byte ratio is computed from)."""
+        """Published payload size (the number the publisher's
+        delta-vs-full byte ratio is computed from)."""
         return int(sum(a.nbytes for a in self._arrays.values()))
 
     def get_model_data(self):

@@ -16,7 +16,7 @@ what the pool's ``serving.<pool>.freshness`` gauge subtracts from the
 trainer's live watermark. No wall clocks.
 
 Byte accounting rides the ``features.publisher`` metrics group
-(``delta_bytes`` / ``full_bytes`` / ``delta_ratio``) so the bench's
+(``delta_bytes`` / ``full_bytes`` / ``delta_ratio``) so a test of the
 delta-vs-snapshot ratio and a production dashboard read the same
 numbers.
 """

@@ -1,43 +1,47 @@
-"""Hand-written Pallas kernels for the hot inner loops (ROADMAP item 2).
+"""Hand-written Pallas (Mosaic) kernels.
 
-PAPER.md's blueprint is a "JAX/XLA/pjit/**Pallas** design"; this package
-is the Pallas half: a gated second backend for the three loops where the
-executor's speed was hostage to XLA codegen (the bf16 fused-chain CPU
-ratio of 0.24–0.49 in PR 10 is the motivating number):
+**Five kernels run, ungated.** Each is chosen where it applies by an
+``unsupported_reason`` its caller reads (the backend, the dtype, the
+shapes: no environment variable, no knob), and every other backend runs
+the XLA lowering of the same result:
 
-- ``fused_chain`` — the fused 5-stage transform chain as ONE row-tiled
-  Pallas kernel per bucket, validity mask applied in-kernel
-  (:mod:`flinkml_tpu.kernels.chain`);
-- ``segment_sum`` — the padded-ELL sparse gradient scatter-accumulate
-  with an ``indices_are_sorted`` run-flush specialization and a
-  multi-block cell grid (:mod:`flinkml_tpu.kernels.segsum`);
-- ``topk`` — the bucketed top-k behind KNN voting and LSH candidate
-  ranking as k masked row-max passes (:mod:`flinkml_tpu.kernels.topk`).
+- :mod:`~flinkml_tpu.kernels.knn_search` — the KNN search's product and
+  ranking in one kernel, the running ``k`` best in fast memory
+  (``models.knn.nearest``: a TPU, float32 rows, ``k`` ≤ 128; the whole
+  device time of ``knn-mnist8m.transform``);
+- :mod:`~flinkml_tpu.kernels.sparse_blocks` — the blocked sparse step's
+  lookup and accumulation, a slot's product never in HBM
+  (``models._linear_sgd.make_sparse_step_bucketed``: a TPU, float32
+  coefficients, a slot plan, a batch in whole tiles; ``lr-criteo.fit``);
+- :mod:`~flinkml_tpu.kernels.dense_step` — the dense linear step, its
+  window read once (``models._linear_sgd.make_dense_step``;
+  ``lr-a9a.fit``);
+- :mod:`~flinkml_tpu.kernels.spd_solve` — ALS's normal equations, a
+  system a lane (``models._als_blocked``; ``als-yahoomusic.fit``);
+- :mod:`~flinkml_tpu.kernels.topk` — exact top-k as ``k`` masked passes
+  over a tile, what a TPU's tiled KNN fallback ranks a tile with
+  (``models.knn._tile_top_k``).
 
-Everything rides the established gate idiom
-(:mod:`flinkml_tpu.kernels._gate`): env-gated
-(``FLINKML_TPU_KERNELS=pallas|xla`` or per-site pairs), measured
-defaults from the autotune table's ``kernel_backend_<site>`` knobs
-(XLA stays the default until a >1.10x committed win), lru-keyed (the
-backend joins the fused executor's program/AOT cache identity, the
-trainer factories' lru keys, and jit static args — a flip re-keys, it
-never aliases), pinned-numerics equivalence (``interpret=True`` CPU
-parity tests in ``tests/test_kernels.py``; bitwise at f32, policy
-tolerance under bf16), and loud refusal on unsupported dtypes/shapes
-(:class:`KernelUnsupportedError` on explicit requests, warn-once XLA
-fallback for table-chosen backends).
+:mod:`~flinkml_tpu.kernels._split` holds the two ways they make a
+float32 from bfloat16 parts. They share ``_gate``'s helpers
+(``interpret_mode``, ``out_struct``, ``import_beside_host_work``).
 
-Outside the gate, :mod:`flinkml_tpu.kernels.knn_search` is the KNN
-search's product and ranking in one kernel; ``models.knn.nearest`` takes
-it wherever it applies (a TPU, float32 rows, ``k`` ≤ 128);
-:mod:`flinkml_tpu.kernels.spd_solve` is ALS's solve, a system a lane;
-:mod:`flinkml_tpu.kernels.sparse_blocks` is the blocked sparse step's
-lookup and accumulation in fast memory, which
-``models._linear_sgd.make_sparse_step_bucketed`` takes wherever they
-apply (a TPU, float32 coefficients, a slot plan, a batch in whole tiles).
+**The gate is what is left of PR 12**: ``FLINKML_TPU_KERNELS`` (and the
+autotune table's ``kernel_backend_<site>`` knobs) choosing between XLA
+and Pallas for two sites that no benchmark cell runs and that default to
+XLA (:mod:`flinkml_tpu.kernels._gate`): ``fused_chain``
+(:mod:`~flinkml_tpu.kernels.chain`: the fused transform chain as one
+row-tiled kernel; refuses the float64 constants a fitted chain carries)
+and ``segment_sum`` (:mod:`~flinkml_tpu.kernels.segsum`: the padded-ELL
+scatter-accumulate; refuses the sparse trainers' ``[1e6, 1]`` output).
+The resolved backend joins the fused executor's program and AOT cache
+identity, the trainer factories' lru keys and jit static args; an
+explicit request for Pallas on unsupported operands raises
+:class:`KernelUnsupportedError`, a table-chosen one warns once and runs
+XLA. ROADMAP D3 has what deleting it takes.
 
-See ``docs/development/kernels.md`` for the supported-shape tables,
-the equivalence-test recipe, and the device re-tune runbook.
+See ``docs/development/kernels.md`` for the supported-shape tables and
+the equivalence-test recipe.
 """
 
 from flinkml_tpu.kernels._gate import (  # noqa: F401
@@ -58,13 +62,7 @@ from flinkml_tpu.kernels.segsum import (  # noqa: F401
 from flinkml_tpu.kernels.segsum import (  # noqa: F401
     factory_backend as segsum_backend,
 )
-from flinkml_tpu.kernels.topk import (  # noqa: F401
-    pallas_top_k,
-    top_k,
-)
-from flinkml_tpu.kernels.topk import (  # noqa: F401
-    factory_backend as topk_backend,
-)
+from flinkml_tpu.kernels.topk import pallas_top_k  # noqa: F401
 
 __all__ = [
     "BACKENDS",
@@ -80,6 +78,4 @@ __all__ = [
     "segment_sum",
     "segsum_backend",
     "pallas_top_k",
-    "top_k",
-    "topk_backend",
 ]

@@ -1,21 +1,20 @@
 """The kernel-backend gate — a measured default for the choice between
 XLA's lowering and the hand-written Pallas kernels.
 
-Three *sites* exist, one per hot inner loop:
+Two *sites* exist, one per gated inner loop:
 
 - ``fused_chain``  — the fused pipeline executor's per-bucket chain
   program (:mod:`flinkml_tpu.kernels.chain`),
 - ``segment_sum``  — the padded-ELL sparse gradient scatter-accumulate
   shared by the linear SGD trainers, ``BatchedCSR.rmatvec``, and the
-  Word2Vec embedding accumulator (:mod:`flinkml_tpu.kernels.segsum`),
-- ``topk``         — the bucketed top-k behind KNN voting and LSH
-  candidate ranking (:mod:`flinkml_tpu.kernels.topk`).
+  Word2Vec embedding accumulator (:mod:`flinkml_tpu.kernels.segsum`).
 
 Lookup precedence per site:
 ``FLINKML_TPU_KERNELS`` env var > the mesh-keyed autotune table's
 ``kernel_backend_<site>`` knob > the static default ``"xla"``. The env
 var takes either one backend for every site (``pallas``/``xla``) or a
-per-site list (``fused_chain=pallas,topk=xla``); anything else raises.
+per-site list (``fused_chain=pallas,segment_sum=xla``); anything else
+raises.
 
 Refusal contract: a Pallas backend selected EXPLICITLY (env var or a
 ``backend=`` argument) refuses unsupported dtypes/shapes LOUDLY with
@@ -41,8 +40,8 @@ from flinkml_tpu.utils.logging import get_logger
 
 _log = get_logger("kernels")
 
-#: The three gated sites (one per hot inner loop — module docstring).
-SITES = ("fused_chain", "segment_sum", "topk")
+#: The two gated sites (module docstring).
+SITES = ("fused_chain", "segment_sum")
 
 #: Known backends. ``xla`` is the static default everywhere; ``pallas``
 #: must win a measured A/B (the autotune ``kernel_backend_*`` knobs) or

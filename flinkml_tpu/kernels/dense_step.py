@@ -26,8 +26,9 @@ read 0.51 ms a step on a v5e, a lane sum and a lane broadcast a vreg
 0.27, XLA's two passes 0.38 (PERF.md section 5). The MXU takes a
 ``[128 rows, 128 features]`` block as its WEIGHTS either way up for the
 price of loading it. So a chunk of 128 rows is split once into its
-three bfloat16 parts (:func:`_parts`: disjoint bit fields, their sum the
-float32 bit for bit) and is the weights of both products: forward, the
+three bfloat16 parts (:func:`flinkml_tpu.kernels._split.disjoint_parts`:
+disjoint bit fields, their sum the float32 bit for bit) and is the
+weights of both products: forward, the
 coefficients' three parts ``[16, dim]`` against the chunk's transpose
 (``[16, 128]``: nine exact products a feature, summed in float32; the
 three rows added are the chunk's margins, a row a lane); backward, the
@@ -54,6 +55,8 @@ from __future__ import annotations
 
 import functools
 from typing import Optional
+
+from flinkml_tpu.kernels._split import disjoint_parts
 
 #: Lanes of a vreg: the rows of a chunk, the MXU's block of weights.
 LANES = 128
@@ -150,28 +153,6 @@ def chunks(dim: int, tile: int = TILE) -> int:
     return n
 
 
-def _parts(v):
-    """Three float32s whose sum is the float32 ``v`` bit for bit, each
-    exact in bfloat16: ``v``'s top sixteen bits (sign, exponent, seven
-    of mantissa), the top sixteen of what is left, and the rest. Disjoint
-    bit fields of one significand, so a sum of any of them in any order
-    is exact too (rounded parts are not: ``hi + lo`` can need a 25th
-    bit). Integer masks and exact subtractions: nothing for a compiler's
-    excess precision to keep (PERF.md section 6, PR 35)."""
-    import jax
-    import jax.numpy as jnp
-
-    def top(a):
-        bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
-        return jax.lax.bitcast_convert_type(
-            bits & jnp.uint32(0xFFFF0000), jnp.float32)
-
-    hi = top(v)
-    rest = v - hi
-    mid = top(rest)
-    return hi, mid, rest - mid
-
-
 def _streamed(parts, rows: int):
     """``parts`` (three ``[..., 1, n]`` float32 rows) as the MXU's
     streamed operand: ``[..., rows, n]`` bfloat16, the parts its first
@@ -219,7 +200,7 @@ def _body(first_ref, x_ref, y_ref, w_ref, coef_ref, grad_ref, loss_ref,
             x = jnp.where(lane < dim, x, 0.0)
         # Each chunk's three parts, the MXU's weights for both products.
         weights = [p.astype(jnp.bfloat16).reshape(n_chunks, LANES, padded)
-                   for p in _parts(x)]
+                   for p in disjoint_parts(x)]
         # Forward: a chunk's margins, a row a lane.
         coefs = jnp.broadcast_to(coef, (n_chunks,) + coef.shape)
         dot = jnp.sum(sum(jax.lax.dot_general(
@@ -231,7 +212,8 @@ def _body(first_ref, x_ref, y_ref, w_ref, coef_ref, grad_ref, loss_ref,
         loss_ref[...] += jnp.sum(per_ex, axis=0, keepdims=True)
         wsum_ref[...] += jnp.sum(w, axis=0, keepdims=True)
         # Backward: the chunks' shares of the gradient.
-        streamed = _streamed([p[:, None, :] for p in _parts(mult)], STREAMED)
+        streamed = _streamed(
+            [p[:, None, :] for p in disjoint_parts(mult)], STREAMED)
         grad_ref[...] += jnp.sum(sum(jax.lax.dot_general(
             streamed, part, as_it_lies, preferred_element_type=jnp.float32)
             for part in weights), axis=0)
@@ -267,7 +249,7 @@ def margin_grad(loss: str, xl, yl, wl, coef, start, local_bs: int, *,
         first = (jnp.asarray(start, jnp.int32) // tile).reshape(1)
         # The coefficients as the MXU streams them: their parts a row
         # each, zeros past the last feature. Made once a step.
-        coef_parts = _streamed(_parts(jnp.pad(
+        coef_parts = _streamed(disjoint_parts(jnp.pad(
             coef.astype(jnp.float32), (0, padded - dim))[None, :]), STREAMED)
         # The labels as the chip holds a vector: 128 rows a row.
         y2 = yl.reshape(n_local // LANES, LANES)

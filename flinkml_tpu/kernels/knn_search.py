@@ -13,8 +13,9 @@ fast memory all the while. A step forms its ``[bq, bt]`` block ``‖q‖² −
 query block's running ``k`` best.
 
 *The product* is the kernel's own (PR 35). A float32 ``v`` is exactly
-three bfloat16 parts, ``hi + mid + lo`` (:func:`_bf16_parts`), and the
-float32 product ``q·x`` at ``Precision.HIGHEST`` is the six bfloat16
+three bfloat16 parts, ``hi + mid + lo``
+(:func:`flinkml_tpu.kernels._split.rounded_parts`), and the float32
+product ``q·x`` at ``Precision.HIGHEST`` is the six bfloat16
 products a six-pass contraction makes (``q_lo·x_hi``, ``q_mid·x_mid``,
 ``q_hi·x_lo``, ``q_mid·x_hi``, ``q_hi·x_mid``, ``q_hi·x_hi``: each exact
 in float32, summed in float32; the three it drops are under 2⁻²⁴ of the
@@ -60,6 +61,8 @@ from __future__ import annotations
 
 import functools
 from typing import Optional, Tuple
+
+from flinkml_tpu.kernels._split import rounded_parts
 
 #: Sublanes of a float32 vreg: the rows a pass ranks together.
 GROUP = 8
@@ -167,35 +170,8 @@ def _entrants(low, best_d, k: int):
     return jnp.max(jnp.where(low < best_d[:, k - 1:k], 1, 0))
 
 
-def _bf16_parts(v, *, in_kernel: bool):
-    """``(hi, mid, lo)``, bfloat16, of a float32 ``v``: ``hi`` is ``v``
-    rounded, ``mid`` what is left of it rounded, ``lo`` what is left then;
-    three times 8 bits of mantissa hold float32's 24, so in float32
-    ``hi + mid + lo`` is ``v`` again, bit for bit.
-
-    Outside a kernel the roundings are ``lax.reduce_precision``: inside
-    one fusion XLA keeps a bfloat16 value it has just made at float32
-    ("excess precision"), so ``rest - float32(bfloat16(rest))`` came out
-    0 on a v5e and ``lo`` with it (PERF.md §6, PR 35). Mosaic lowers the
-    casts alone, and keeps them."""
-    import jax
-    import jax.numpy as jnp
-
-    def rounded(a):
-        """``a`` at bfloat16's precision, as bfloat16 and as float32."""
-        if in_kernel:
-            low = a.astype(jnp.bfloat16)
-            return low, low.astype(jnp.float32)
-        a = jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
-        return a.astype(jnp.bfloat16), a
-
-    hi, hi_of = rounded(v)
-    mid, mid_of = rounded(v - hi_of)
-    return hi, mid, ((v - hi_of) - mid_of).astype(jnp.bfloat16)
-
-
 def _products(precision):
-    """The (query part, train part) pairs of :func:`_bf16_parts` whose
+    """The (query part, train part) pairs of ``rounded_parts`` whose
     bfloat16 products, each exact in float32 and summed in float32, are
     the product at ``precision``; the small terms first. ``HIGHEST`` is
     the six XLA's and Mosaic's ``fp32`` contraction make (the three it
@@ -288,7 +264,7 @@ def _search_body(q_ref, qsq_ref, xt_ref, xsq_ref, best_d_ref, best_r_ref,
                     if part != dim:   # the block's rows past the array's: anything
                         row = at + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
                         x = jnp.where(row < dim, x, 0.0)
-                    x_parts = _bf16_parts(x, in_kernel=True)
+                    x_parts = rounded_parts(x, in_kernel=True)
                     for s, (_, of_x) in enumerate(products):
                         parts_ref[pl.ds(s * part + at, PART_ROWS), lanes] = x_parts[of_x]
                 return 0
@@ -402,7 +378,7 @@ def fused_nearest(queries, train_x, train_sq, k: int, *, precision,
         if products is not None:
             # The queries' parts side by side, each product's over its
             # train part's rows: the six products are ONE contraction.
-            q_parts = _bf16_parts(jnp.pad(q, ((0, 0), (0, x_rows - dim))),
+            q_parts = rounded_parts(jnp.pad(q, ((0, 0), (0, x_rows - dim))),
                                   in_kernel=False)
             q = jnp.concatenate([q_parts[of_q] for of_q, _ in products], axis=1)
             q = jnp.pad(q, ((0, 0), (0, width - q.shape[1])))

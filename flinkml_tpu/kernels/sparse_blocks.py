@@ -51,6 +51,8 @@ from __future__ import annotations
 import functools
 from typing import NamedTuple, Optional, Sequence, Tuple
 
+from flinkml_tpu.kernels._split import rounded_parts
+
 #: Lanes of a vreg, and the columns of a block's row as the trainer
 #: holds it (``[length / 128, 128]``).
 LANES = 128
@@ -168,8 +170,6 @@ def block_parts(blocks, group: Group):
     a value it has just rounded at float32; PERF.md section 6, PR 35)."""
     import jax.numpy as jnp
 
-    from flinkml_tpu.kernels.knn_search import _bf16_parts
-
     padded = []
     for (length, slots), member in zip(group.members, blocks):
         r = length // group.c
@@ -180,7 +180,7 @@ def block_parts(blocks, group: Group):
         else:
             pad = ((0, 0), (0, group.rows - r), (0, 0))
         padded.append(jnp.pad(member, pad))
-    parts = _bf16_parts(jnp.concatenate(padded), in_kernel=False)
+    parts = rounded_parts(jnp.concatenate(padded), in_kernel=False)
     if not group.narrow:
         return jnp.concatenate(parts, axis=1)
     stacked = jnp.concatenate(parts, axis=2)
@@ -280,8 +280,6 @@ def _accumulate_body(where_ref, starts_ref, cells_ref, vals_ref, mult_ref,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    from flinkml_tpu.kernels.knn_search import _bf16_parts
-
     @pl.when(pl.program_id(0) == 0)
     def _():
         for out_ref in out_refs:
@@ -305,7 +303,8 @@ def _accumulate_body(where_ref, starts_ref, cells_ref, vals_ref, mult_ref,
                 [jnp.where(rows_of, jnp.broadcast_to(
                     part.astype(jnp.float32), (rows, tile)), 0.0)
                  .astype(jnp.bfloat16)
-                 for part in _bf16_parts(vals * mult, in_kernel=True)], axis=0)
+                 for part in rounded_parts(vals * mult, in_kernel=True)],
+                axis=0)
             three = jax.lax.dot_general(
                 spread, _as_operand(_one_hot(lo, group.c)),
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)

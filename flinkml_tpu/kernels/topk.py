@@ -11,14 +11,12 @@ elements and ``argmax`` takes the FIRST maximum, so values AND indices
 are bit-identical to ``lax.top_k`` (both break ties toward the lower
 index).
 
-The KNN search calls :func:`pallas_top_k` itself, for the tiles of its
-distance matrix: on a v5e it is that search's fastest exact top-k (a
-call of 10,000 queries against 2,025,000 rows: 1.589 s with it, 1.791
-with ``lax.top_k`` over the same tiles; PERF.md §5, PR 30). LSH goes
-through :func:`top_k` and the gate (:mod:`flinkml_tpu.kernels._gate`,
-site ``topk``), which keeps XLA the default there; a caller that does
-threads the resolved backend into its own compile key, so a gate flip
-re-keys the program instead of silently reusing the old one.
+The KNN search's tiled path calls :func:`pallas_top_k` for the tiles of
+its distance matrix (``models.knn._tile_top_k``), behind no gate: on a
+v5e it is that search's fastest exact top-k (a call of 10,000 queries
+against 2,025,000 rows: 1.589 s with it, 1.791 with ``lax.top_k`` over
+the same tiles; PERF.md §5, PR 30). Operands it cannot rank are refused
+by name (:class:`~flinkml_tpu.kernels._gate.KernelUnsupportedError`).
 """
 
 from __future__ import annotations
@@ -99,6 +97,10 @@ def pallas_top_k(x, k: int, *, interpret: Optional[bool] = None) -> Tuple:
 
     if interpret is None:
         interpret = _gate.interpret_mode()
+    reason = unsupported_reason(x, k, interpret)
+    if reason is not None:
+        raise _gate.KernelUnsupportedError(
+            f"kernels.topk: the kernel cannot rank these operands: {reason}")
     squeeze = x.ndim == 1
     x2 = x[None, :] if squeeze else x
     rows, n = x2.shape
@@ -134,31 +136,3 @@ def pallas_top_k(x, k: int, *, interpret: Optional[bool] = None) -> Tuple:
     if squeeze:
         vals, idxs = vals[0], idxs[0]
     return vals, idxs
-
-
-def top_k(x, k: int, *, backend: Optional[str] = None) -> Tuple:
-    """The gated dispatcher: ``jax.lax.top_k`` under ``"xla"``, the
-    masked-pass kernel under ``"pallas"``. ``backend=None`` resolves the
-    gate (env > autotune table > xla); a passed backend is an explicit
-    request and refuses unsupported operands loudly."""
-    import jax
-    import jax.numpy as jnp
-
-    from flinkml_tpu.kernels import _gate
-
-    x = jnp.asarray(x)
-    interpret = _gate.interpret_mode()
-    chosen = _gate.resolve_checked(
-        "topk", unsupported_reason(x, k, interpret), backend,
-    )
-    if chosen == "pallas":
-        return pallas_top_k(x, k, interpret=interpret)
-    return jax.lax.top_k(x, k)
-
-
-def factory_backend() -> str:
-    """The resolved topk backend for callers that bake it into a jit
-    static argument (the lru-key idiom — see the gate module)."""
-    from flinkml_tpu.kernels import _gate
-
-    return _gate.backend_for("topk")

@@ -849,8 +849,8 @@ def prepare_sparse_buckets(
     Returns ``(place, local_bss, slot_plan)``: the placement
     :func:`_run_chunked` follows, each bucket's per-device window size
     (proportional share of ``global_batch_size``, ≥ 1), and the plan the
-    step follows. The single source of the batching policy — the bench
-    measures exactly what the product trains with.
+    step follows. The single source of the batching policy — the
+    benchmark measures exactly what the product trains with.
 
     ``place(first, last)`` starts the placement for steps ``[first,
     last)`` and gives its rounds, ``(data_args, epoch the loop can run

@@ -188,7 +188,7 @@ def gbt_hist_tables(b_pad: np.ndarray, p_size: int, n_bins: int):
 def sharded_hist_args(b_pad: np.ndarray, mesh, n_bins: int,
                       hist_layout: str) -> tuple:
     """The extra sharded builder args for ``hist_layout`` — ONE
-    definition shared by the product fit path, the bench GBT stage, and
+    definition shared by the product fit path and
     ``tools/gbt_hist_probe.py``, so every consumer passes the builder
     the identical table layout. Empty for ``segment``."""
     if hist_layout != "cumsum":

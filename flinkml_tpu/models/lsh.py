@@ -160,15 +160,10 @@ class MinHashLSHModel(HasInputCol, HasOutputCol, HasSeed, Model):
         else:
             import jax
 
-            from flinkml_tpu import kernels
-
             # x64 keeps the ranking in float64, matching the host
             # distances exactly (no f32 rounding could reorder ties).
             with jax.enable_x64(True):
-                _, order = kernels.top_k(
-                    jax.numpy.asarray(-dists), k_eff,
-                    backend=kernels.topk_backend(),
-                )
+                _, order = jax.lax.top_k(jax.numpy.asarray(-dists), k_eff)
             order = np.asarray(order, dtype=np.int64)
         picked = candidates[order]
         return dataset.take(picked).with_column(dist_col, dists[order])

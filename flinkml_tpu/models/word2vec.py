@@ -388,8 +388,8 @@ def _shard_vocab_threshold() -> int:
     vocab-sharded exchange trainer on a multi-device mesh (the dense
     trainer's per-step [vocab, dim] gradient psum stops scaling there).
     Now the embedding subsystem's ONE dense-psum threshold
-    (:func:`flinkml_tpu.embeddings.dense_vocab_threshold`), which
-    honors ``FLINKML_W2V_SHARD_VOCAB`` as a back-compat alias (0 forces
+    (:func:`flinkml_tpu.embeddings.dense_vocab_threshold`;
+    ``FLINKML_TPU_EMBEDDING_DENSE_VOCAB`` overrides it, 0 forces
     sharding — the test hook)."""
     from flinkml_tpu.embeddings import dense_vocab_threshold
 
@@ -671,7 +671,7 @@ class Word2Vec(StreamingEstimatorMixin, _Word2VecParams, Estimator):
                     "single-process mesh (both switch to the "
                     "vocab-sharded ring trainer above this threshold), "
                     "raise minCount to prune the vocabulary, or override "
-                    "via FLINKML_W2V_SHARD_VOCAB."
+                    "via FLINKML_TPU_EMBEDDING_DENSE_VOCAB."
                 )
 
             # -- pass B: replay doc cache into the pair cache --------------

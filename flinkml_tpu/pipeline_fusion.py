@@ -134,7 +134,7 @@ _POLICY = threading.local()
 
 def enabled() -> bool:
     """Fusion master switch: the ``FLINKML_TPU_DISABLE_FUSION=1`` env var or
-    :func:`set_enabled` (used by the bench's unfused baseline) turns the
+    :func:`set_enabled` (a test's per-stage baseline) turns the
     fused executor off, restoring pure per-stage execution."""
     return _ENABLED[0] and os.environ.get("FLINKML_TPU_DISABLE_FUSION") != "1"
 

@@ -24,9 +24,10 @@ keeps the failure) and written as a deterministic
 (:func:`flinkml_tpu.faults.plan_to_json`) that
 :func:`flinkml_tpu.faults.plan_from_json` replays exactly.
 
-CI runs ``tools/ci.sh``'s *chaos soak* stage: a fixed-seed soak of ≥ 25
-schedules inside a wall-clock budget, plus a shrink demonstration on a
-seeded failing schedule. Run it by hand::
+Tier-1 runs a fixed-seed soak of 25 schedules
+(``tests/test_recovery.py::test_chaos_soak_small_budget_green``) and a
+shrink demonstration on a seeded failing schedule
+(``::test_shrink_minimizes_to_the_poison``). Run it by hand::
 
     JAX_PLATFORMS=cpu python -m flinkml_tpu.recovery.fuzz \
         --seed 7 --budget 25 --repro-dir /tmp/repros

@@ -113,8 +113,8 @@ class ServingConfig:
     ``batching`` selects the queue policy: ``"continuous"`` (default —
     requests split at bucket boundaries, Orca-style; see
     :class:`~flinkml_tpu.serving.batcher.ContinuousBatcher`) or
-    ``"fifo"`` (PR 3's whole-request packing, kept for A/B comparison —
-    the ``serving_scaleout`` bench stage measures both).
+    ``"fifo"`` (PR 3's whole-request packing, kept for an A/B that no
+    cell of the benchmark has run: ROADMAP D2).
 
     ``device`` pins every dispatch (warmup included) to one
     ``jax.Device`` via ``jax.default_device`` — how a

@@ -4,9 +4,9 @@ Rule: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it
 and this module sets no directory; where it is not, the cache lives at
 ``<checkout>/.jax_cache`` (git-ignored, resolved from this package's own
 path — the directory is part of the cache key, so it must not move
-between runs). Entry points (``tests/conftest.py``, ``bench.py``,
-``chip_smoke.py``, the probes) call :func:`enable` before their first
-compile; library code never does.
+between runs). Entry points (``tests/conftest.py``,
+``benchmark/drivers/program.py``, ``chip_smoke.py``, the probes) call
+:func:`enable` before their first compile; library code never does.
 """
 
 from __future__ import annotations

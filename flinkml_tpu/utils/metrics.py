@@ -272,7 +272,7 @@ class EpochMetricsListener(IterationListener):
 
     Attach to :func:`flinkml_tpu.iteration.iterate` via ``listeners=[...]``.
     ``samples_per_epoch`` (if given) feeds a ``samples`` meter and a final
-    ``samples_per_sec`` gauge — the bench's headline metric.
+    ``samples_per_sec`` gauge.
     """
 
     def __init__(

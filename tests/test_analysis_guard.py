@@ -66,7 +66,22 @@ def test_new_chain_compile_inside_guard_violates():
         pm.transform(t)
 
 
-def test_new_bucket_compile_is_policy_allowed():
+@pytest.fixture(params=["none", "memory"])
+def compile_store(request):
+    """Both states a process can be in: no compile-cache store, and the
+    memory-only store every ``ReplicaPool`` leaves active (the fused
+    cache's keys then carry the placement too: under the driver's six
+    workers this file follows files that started pools)."""
+    from flinkml_tpu import compile_cache
+
+    compile_cache.reset()
+    if request.param == "memory":
+        compile_cache.ensure_store()
+    yield
+    compile_cache.reset()
+
+
+def test_new_bucket_compile_is_policy_allowed(compile_store):
     t = _data(n=200)
     pm = _two_stage_chain(t)
     pm.transform(t.slice(0, 60))  # warm the 64 bucket

@@ -381,20 +381,78 @@ def test_cli_runs_the_memory_pass_and_dir_walk_finds_fixtures(capsys):
 
 
 # ---------------------------------------------------------------------------
-# calibration vs XLA's own memory_analysis (CPU twin of the bench stage)
+# calibration vs XLA's own memory_analysis
 # ---------------------------------------------------------------------------
 
-def test_estimate_calibrated_against_xla_memory_analysis():
+def _toy_twin():
     def f(x):
         h = jnp.tanh(x @ x.T)
         return (h * h).sum()
 
     x = np.zeros((256, 256), np.float32)
-    compiled = jax.jit(f).lower(x).compile()
+    return jax.jit(f).lower(x).compile(), estimate_fn_memory(f, x)
+
+
+def _fused_chain_twin(n=8192, d=32):
+    """The five-stage scoring chain's arithmetic (four scalers and the
+    logistic head) as one program on one device."""
+    def chain(x, mean, std, dmin, dmax, maxabs, median, rng_, coef):
+        h = (x - mean) / std
+        h = (h - dmin) / (dmax - dmin)
+        h = h / maxabs
+        h = (h - median) / rng_
+        return jax.nn.sigmoid(h @ coef)
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    row = np.ones((1, d), np.float32)
+    coef = rng.normal(size=(d,)).astype(np.float32)
+    args = (x, row, row, 0 * row, row, row, 0 * row, row, coef)
+    return (jax.jit(chain).lower(*args).compile(),
+            estimate_fn_memory(chain, *args))
+
+
+def _sharded_sgd_step_twin(state_dim=65536, bs=256):
+    """The plan-sharded SGD step the product trains with, its state
+    donated, FSDP over the eight-device mesh."""
+    from flinkml_tpu.parallel import DeviceMesh
+    from flinkml_tpu.sharding.apply import (
+        batch_sharding, init_linear_state, linear_step_fn, state_shardings,
+    )
+
+    mesh = DeviceMesh.for_plan(FSDP)
+    step = linear_step_fn(
+        loss="logistic", optimizer="sgd", dtype_name="float32",
+        learning_rate=0.1, momentum=0.9, reg_l2=0.0, reg_l1=0.0,
+    )
+    state = init_linear_state(state_dim, "sgd", np.float32)
+    rng = np.random.default_rng(0)
+    args = (state, rng.normal(size=(bs, state_dim)).astype(np.float32),
+            (rng.random(bs) > 0.5).astype(np.float32),
+            np.ones(bs, np.float32))
+    b_shard = batch_sharding(FSDP, mesh)
+    compiled = jax.jit(
+        step,
+        in_shardings=(state_shardings(FSDP, mesh, state),
+                      b_shard, b_shard, b_shard),
+        donate_argnums=(0,),
+    ).lower(*args).compile()
+    return compiled, estimate_fn_memory(
+        step, *args, plan=FSDP, mesh=dict(mesh.mesh.shape),
+        param_argnums=(0,), donate_argnums=(0,),
+    )
+
+
+@pytest.mark.parametrize(
+    "twin", [_toy_twin, _fused_chain_twin, _sharded_sgd_step_twin])
+def test_estimate_calibrated_against_xla_memory_analysis(twin):
+    """The static estimate stays inside 0.5x-2.0x of what XLA reports for
+    the compiled program (temporaries, arguments and outputs): on a toy,
+    and on the two programs the band was set on."""
+    compiled, est = twin()
     ma = compiled.memory_analysis()
     actual = (int(ma.temp_size_in_bytes) + int(ma.argument_size_in_bytes)
               + int(ma.output_size_in_bytes))
-    est = estimate_fn_memory(f, x)
     assert 0.5 * actual <= est.peak_bytes <= 2.0 * actual, (
         f"estimate {est.peak_bytes} vs XLA {actual}"
     )

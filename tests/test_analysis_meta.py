@@ -37,6 +37,17 @@ def test_every_rule_has_a_docs_catalog_row():
     )
 
 
+def test_cli_rules_catalog_is_the_docs_table(capsys):
+    """What ``python -m flinkml_tpu.analysis --rules`` prints and the
+    docs table agree row for row."""
+    from flinkml_tpu.analysis.__main__ import main
+
+    assert main(["--rules"]) == 0
+    printed = set(re.findall(r"^(FML\d{3})\b", capsys.readouterr().out,
+                             re.MULTILINE))
+    assert printed == _documented_rules()
+
+
 def test_every_rule_has_a_fixture_or_a_flagging_test():
     fixture_names = " ".join(os.listdir(FIXTURES)).lower()
     test_sources = ""

@@ -146,12 +146,12 @@ def test_closed_loop_load_triple_recovers_p99_without_operator():
     platform CPU "devices" share one XLA executor pool and the Python
     dispatchers share the GIL, so IN-PROCESS replicas cannot add real
     capacity (closed-loop p50 scales with 1/throughput — Little's law);
-    the true p99-recovery number is the queued DEVICE bench stage's,
-    where each replica owns a chip (the PR 8 precedent). The remedy
+    the true p99-recovery number needs each replica to own a chip and
+    is not measured (no cell of the benchmark serves). The remedy
     for the single-process ceiling itself is the multi-process worker
     pool (``flinkml_tpu.cluster.ClusterPool`` — each replica a real
     process with its own GIL and executor pool; see
-    ``tests/test_cluster.py`` and ci's ``cluster smoke`` stage), which
+    ``tests/test_cluster.py``), which
     this scenario deliberately does NOT use so the tripwire keeps
     watching the in-process path. The 2x bound is NOT vacuous: the
     unbounded per-(rows,bucket) pad-compile bug this PR fixed in

@@ -254,9 +254,8 @@ def test_committed_table_has_measured_values_for_this_mesh():
 
 
 def test_quick_search_smoke(tmp_path):
-    """The search harness itself, smoke-size, on two cheap knobs — the
-    full run is `python -m flinkml_tpu.autotune --commit` (and bench's
-    autotune stage on-device)."""
+    """The search harness itself, smoke-size, on one cheap knob — the
+    full run is `python -m flinkml_tpu.autotune --commit`."""
     from flinkml_tpu.autotune.search import apply_results, search_knobs
 
     results = search_knobs(["infer_plan_order"], quick=True)

@@ -98,8 +98,8 @@ def test_knn_search_at_the_cells_size(one_chip, no_compile_cache, monkeypatch,
 
 @pytest.mark.parametrize("x64", [False, True])
 def test_top_k_kernel_compiles_whatever_x64_says(one_chip, no_compile_cache, x64):
-    """``kernels.topk.pallas_top_k`` by itself (LSH's route to it, through
-    the gate): its ``pallas_call`` holds no 64-bit value under x64 (Mosaic
+    """``kernels.topk.pallas_top_k`` by itself (as the KNN search's tiled
+    path calls it): its ``pallas_call`` holds no 64-bit value under x64 (Mosaic
     refuses an int64 block index and aborts on a float64 block), so it
     compiles in both modes."""
     from flinkml_tpu.kernels import topk

@@ -7,30 +7,44 @@ otherwise)."""
 import json
 import os
 import re
+import shutil
 import subprocess
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)  # chip_smoke and bench live at the root
+sys.path.insert(0, REPO)  # chip_smoke lives at the root
 SMOKE = os.path.join(REPO, "chip_smoke.py")
 
 
-def _run(args, tmp_path, **env):
+def _run(args, tmp_path, smoke=SMOKE, **env):
     """Run from a foreign cwd (the script must find its own checkout)."""
     return subprocess.run(
-        [sys.executable, SMOKE, *args], cwd=str(tmp_path),
+        [sys.executable, smoke, *args], cwd=str(tmp_path),
         capture_output=True, text=True, timeout=600,
         env={**os.environ, **env},
     )
+
+
+def _checkout_of_its_own(tmp_path):
+    """A checkout whose ``.jax_cache`` no one else writes: the script
+    copied, what it imports linked. ``jax_cache.cache_dir()`` resolves
+    the default from the package's path as imported, so this checkout's
+    default is ``<here>/.jax_cache``, not the one the suite's other
+    workers fill while this test runs."""
+    here = tmp_path / "checkout"
+    here.mkdir()
+    shutil.copy(SMOKE, here / "chip_smoke.py")
+    for name in ("flinkml_tpu", "benchmark", "__graft_entry__.py"):
+        os.symlink(os.path.join(REPO, name), here / name)
+    return here
 
 
 def test_rehearsal_runs_every_phase(tmp_path):
     import chip_smoke
 
     cache = tmp_path / "jaxcache"
-    marker_dir = os.path.join(REPO, ".jax_cache")
-    before = set(os.listdir(marker_dir)) if os.path.isdir(marker_dir) else None
-    proc = _run(["--rehearse"], tmp_path,
+    here = _checkout_of_its_own(tmp_path)
+    proc = _run(["--rehearse"], tmp_path, smoke=str(here / "chip_smoke.py"),
                 JAX_COMPILATION_CACHE_DIR=str(cache))
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = [json.loads(l) for l in proc.stdout.splitlines()]
@@ -50,10 +64,10 @@ def test_rehearsal_runs_every_phase(tmp_path):
     assert by["serve"]["replica_device_ids"] == list(range(8))
     assert by["serve"]["compile_cache"]["retarget_loads"] >= 7
     assert by["kernels"]["interpret"] is True
-    # The variable was set: the cache went there, nothing into .jax_cache.
+    # The variable was set: the cache went there, and the checkout's own
+    # default directory was never made.
     assert any(f.endswith("-cache") for f in os.listdir(cache))
-    after = set(os.listdir(marker_dir)) if os.path.isdir(marker_dir) else None
-    assert after == before
+    assert not (here / ".jax_cache").exists()
 
 
 def test_default_invocation_refuses_a_non_tpu_backend(tmp_path):

@@ -506,6 +506,45 @@ def test_raise_at_read_seam_fires_mid_stream():
     )
 
 
+def test_shuffled_csv_glob_killed_at_read_resumes_exact_sequence(tmp_path):
+    """A CSV glob of unequal parts, mapped and SHUFFLED: the source dies
+    mid-stream at the data.read seam, and the iterator's cursor resumes
+    to the exact batches the uninterrupted run delivers."""
+    rng = np.random.default_rng(0)
+    d = 4
+    for part in range(4):
+        rows = rng.normal(size=(96 + 32 * part, d))
+        header = ",".join(f"f{j}" for j in range(d))
+        body = "\n".join(",".join(f"{v:.17g}" for v in r) for r in rows)
+        (tmp_path / f"part-{part}.csv").write_text(f"{header}\n{body}\n")
+
+    def make_ds():
+        return (
+            Dataset.from_csv(str(tmp_path / "part-*.csv"), batch_size=48)
+            .map(lambda t: Table({"features": np.stack(
+                [t.column(f"f{j}") for j in range(d)], 1)}))
+            .shuffle(3, seed=11)
+        )
+
+    def features(batches):
+        return [np.asarray(b.column("features")) for b in batches]
+
+    golden = features(make_ds())
+    it = make_ds().iterate()
+    got = []
+    with faults.armed(faults.FaultPlan(faults.RaiseAtRead(at_read=7))):
+        with pytest.raises(faults.FaultInjected):
+            for b in it:
+                got.append(np.asarray(b.column("features")))
+    assert 0 < len(got) < len(golden)
+    cursor = it.cursor()
+    it.close()
+    got += features(make_ds().iterate(cursor))
+    assert len(got) == len(golden)
+    for g, h in zip(golden, got):
+        np.testing.assert_array_equal(g, h)
+
+
 def test_dataset_feeds_streamed_estimator():
     """A Dataset drops in anywhere an iterable of batch Tables is
     accepted — here a streamed (out-of-core) KMeans fit."""

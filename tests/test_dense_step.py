@@ -160,7 +160,7 @@ def test_a_floats_three_parts_sum_to_it_in_any_order(order):
     v = rng.standard_normal(8192) * np.exp(rng.uniform(-60.0, 60.0, 8192))
     v = np.concatenate([v, [0.0, -0.0, 1.0, -1.0, 3.0e38, 1.0 + 2.0 ** -23]])
     v = v.astype(np.float32)
-    parts = [np.asarray(p) for p in jax.jit(dense_step._parts)(v)]
+    parts = [np.asarray(p) for p in jax.jit(dense_step.disjoint_parts)(v)]
     for part in parts:
         assert part.dtype == np.float32
         np.testing.assert_array_equal(

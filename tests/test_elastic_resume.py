@@ -206,6 +206,9 @@ def test_kill_world4_resume_world2_and_world8_bit_exact(tmp_path, name):
         )
         np.testing.assert_array_equal(extract(recovered), extract(golden))
         assert recovered.model_version == golden.model_version == B
+        # Resumed from the kill's own snapshot: the cursor of world 4.
+        cursor = m.last_restored_extra["data_cursor"]
+        assert cursor["num_shards"] == 4 and cursor["emitted"] == KILL_EPOCH
 
 
 def test_kill_world4_resume_world2_shuffled_dataset_fed(tmp_path):

@@ -105,9 +105,9 @@ def test_exchange_strategy_resolution():
         os.environ["FLINKML_TPU_EMBEDDING_EXCHANGE"] = "dense_psum"
         with pytest.raises(ValueError, match="vocab threshold"):
             resolve_exchange(over, 8)
-        # the back-compat W2V threshold alias still works
+        # a threshold of 0 shards every vocabulary
         os.environ.pop("FLINKML_TPU_EMBEDDING_EXCHANGE")
-        os.environ["FLINKML_W2V_SHARD_VOCAB"] = "0"
+        os.environ["FLINKML_TPU_EMBEDDING_DENSE_VOCAB"] = "0"
         assert resolve_exchange(10, 8) in ("ring", "all_to_all")
     finally:
         os.environ.clear()
@@ -149,6 +149,25 @@ def test_footprint_model_agrees_with_padded_placement():
         EmbeddingTable("edge", vocab, dim,
                        mesh=DeviceMesh.for_plan(EMBEDDING),
                        hbm_budget_bytes=padded - 1, optimizer_slots=1)
+
+
+@pytest.mark.parametrize("strategy", ["ring", "all_to_all"])
+def test_exchange_traffic_follows_the_batch_not_the_vocabulary(strategy):
+    """A step's exchange traffic, reckoned from shapes: a row and its id
+    for every id of the batch, over every shard, both ways. Twice the
+    batch is twice the bytes, ten times the vocabulary the same bytes,
+    and the dense placement's psum of the whole table is what it is
+    compared with."""
+    dim, batch = 16, 256
+    _, small = _table(vocab=1_000, dim=dim)
+    _, large = _table(vocab=10_000, dim=dim)
+    per_step = small.exchange_bytes_per_step(batch, strategy)
+    assert per_step == 2 * small.n_shards * batch * (dim * 4 + 4)
+    assert large.exchange_bytes_per_step(batch, strategy) == per_step
+    assert small.exchange_bytes_per_step(2 * batch, strategy) == 2 * per_step
+    dense = large.exchange_bytes_per_step(batch, "dense_psum")
+    assert dense == 2 * large.padded_vocab * dim * 4
+    assert per_step < dense
 
 
 def test_unknown_strategy_refused_in_exchange():
@@ -322,7 +341,7 @@ def test_w2v_sharded_strategies_match_dense(monkeypatch, strategy):
             .set_max_iter(2).set_min_count(1).set_seed(0).fit(t)
 
     dense = fit()
-    monkeypatch.setenv("FLINKML_W2V_SHARD_VOCAB", "0")
+    monkeypatch.setenv("FLINKML_TPU_EMBEDDING_DENSE_VOCAB", "0")
     monkeypatch.setenv("FLINKML_TPU_EMBEDDING_EXCHANGE", strategy)
     sharded = fit()
     np.testing.assert_array_equal(sharded.vocabulary, dense.vocabulary)
@@ -338,7 +357,7 @@ def test_w2v_ring_and_a2a_gathers_agree_bitwise(monkeypatch):
 
     docs = _w2v_corpus(seed=5)
     t = Table({"doc": np.asarray(docs, dtype=object)})
-    monkeypatch.setenv("FLINKML_W2V_SHARD_VOCAB", "0")
+    monkeypatch.setenv("FLINKML_TPU_EMBEDDING_DENSE_VOCAB", "0")
 
     out = {}
     for strategy in ("ring", "all_to_all"):

@@ -495,8 +495,9 @@ def test_publisher_compacts_at_max_depth_and_prices_bytes(tmp_path):
     assert snap["counters"]["compactions"] == 1
     assert snap["counters"]["full_publishes"] == 2
     assert snap["counters"]["delta_publishes"] == 3
-    # Deltas must be (much) smaller than the full state they stand for.
-    assert 0.0 < snap["gauges"]["delta_ratio"] < 1.0
+    # Deltas must be (much) smaller than the full state they stand for:
+    # eight hot keys of 1,024 buckets, under half by a wide margin.
+    assert 0.0 < snap["gauges"]["delta_ratio"] < 0.5
     # The compacted version resolves directly (no chain walk).
     _, model = reg.get(4)
     assert isinstance(model, HashedFMModel)

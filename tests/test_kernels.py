@@ -15,6 +15,7 @@ from flinkml_tpu.autotune import TuningTable, mesh_key
 from flinkml_tpu.autotune.table import ENV_DISABLE_VAR, ENV_TABLE_VAR
 from flinkml_tpu.kernels import ENV_VAR, KernelUnsupportedError
 from flinkml_tpu.kernels import chain as kchain
+from flinkml_tpu.kernels import topk
 from flinkml_tpu.table import Table
 
 
@@ -160,7 +161,7 @@ def test_top_k_parity(dtype):
     x = x.at[0, 9].set(x[0, 3])   # tie inside one row
     x = x.at[5, :].set(x[5, 0])   # fully tied row
     rv, ri = jax.lax.top_k(x, 6)
-    pv, pi = kernels.top_k(x, 6, backend="pallas")
+    pv, pi = topk.pallas_top_k(x, 6)
     assert np.asarray(rv).tobytes() == np.asarray(pv).tobytes()
     assert np.asarray(ri).tobytes() == np.asarray(pi).tobytes()
 
@@ -176,7 +177,7 @@ def test_top_k_neg_inf_rows_parity():
         [1.0, -np.inf, 2.0],
     ], dtype=jnp.float32)
     rv, ri = jax.lax.top_k(x, 3)
-    pv, pi = kernels.top_k(x, 3, backend="pallas")
+    pv, pi = topk.pallas_top_k(x, 3)
     assert np.asarray(rv).tobytes() == np.asarray(pv).tobytes()
     assert np.asarray(ri).tobytes() == np.asarray(pi).tobytes()
 
@@ -185,14 +186,17 @@ def test_top_k_1d_parity():
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(size=41).astype(np.float32))
     rv, ri = jax.lax.top_k(x, 7)
-    pv, pi = kernels.top_k(x, 7, backend="pallas")
+    pv, pi = topk.pallas_top_k(x, 7)
     assert np.asarray(rv).tobytes() == np.asarray(pv).tobytes()
     assert np.asarray(ri).tobytes() == np.asarray(pi).tobytes()
 
 
 def test_knn_backends_agree(fusion_cache, monkeypatch):
-    """KNN predictions are backend-invariant (the vote consumes only
-    the top-k indices, which are bitwise-equal)."""
+    """KNN predictions are the same whichever way a tile is ranked,
+    ``lax.top_k`` (every backend but a TPU) or the kernel (a TPU;
+    interpreted here): the vote consumes only the top-k indices, which
+    are bitwise-equal."""
+    from flinkml_tpu.models import knn
     from flinkml_tpu.models.knn import Knn
 
     rng = np.random.default_rng(6)
@@ -203,16 +207,27 @@ def test_knn_backends_agree(fusion_cache, monkeypatch):
         .set(Knn.LABEL_COL, "label").set(Knn.K, 5).fit(t)
     q = Table({"features": rng.normal(size=(30, 4))})
     (ref,) = model.transform(q)
-    monkeypatch.setenv(ENV_VAR, "topk=pallas")
-    (got,) = model.transform(q)
-    assert np.array_equal(np.asarray(ref.column("prediction")),
+    ranked_by_kernel = []
+
+    def by_kernel(d2, k):
+        neg, at = topk.pallas_top_k(-d2, k, interpret=True)
+        ranked_by_kernel.append(d2.shape)
+        return -neg, at
+
+    monkeypatch.setattr(knn, "_tile_top_k", by_kernel)
+    # Another query count: a new trace, which sees the patched ranking.
+    q2 = Table({"features": np.asarray(q.column("features"))[:29]})
+    (got,) = model.transform(q2)
+    assert ranked_by_kernel
+    assert np.array_equal(np.asarray(ref.column("prediction"))[:29],
                           np.asarray(got.column("prediction")))
 
 
 def test_lsh_ranking_pinned_order(monkeypatch):
     """The satellite fix (lsh.py host argsort → device top_k): ranking
     order equals the stable host argsort EXACTLY — ascending distance,
-    ties toward the lower candidate index — on both backends."""
+    ties toward the lower candidate index — through the model
+    (``lax.top_k``) and with the kernel ranking the same distances."""
     from flinkml_tpu.models.lsh import MinHashLSH
 
     rng = np.random.default_rng(7)
@@ -238,20 +253,20 @@ def test_lsh_ranking_pinned_order(monkeypatch):
             _jaccard_distance(rows[i], key_idx) for i in cand
         ])
         order = np.argsort(dists, kind="stable")[:k]
-        return cand[order], dists[order]
+        return cand[order], dists[order], dists, order
 
     for k in (3, 7, 1000):   # 1000 > candidate count: clamp path
-        want_rows, want_dists = golden(x[0], k)
-        for env in (None, "topk=pallas"):
-            if env is None:
-                monkeypatch.delenv(ENV_VAR, raising=False)
-            else:
-                monkeypatch.setenv(ENV_VAR, env)
-            got = model.approx_nearest_neighbors(t, x[0], k)
-            assert np.array_equal(np.asarray(got.column("distCol")),
-                                  want_dists), (k, env)
-            assert np.array_equal(np.asarray(got.column("f")),
-                                  x[want_rows]), (k, env)
+        want_rows, want_dists, dists, want_order = golden(x[0], k)
+        got = model.approx_nearest_neighbors(t, x[0], k)
+        assert np.array_equal(np.asarray(got.column("distCol")),
+                              want_dists), k
+        assert np.array_equal(np.asarray(got.column("f")),
+                              x[want_rows]), k
+        # The kernel over the same float64 distances: the same order.
+        with jax.enable_x64(True):
+            _, by_kernel = topk.pallas_top_k(
+                jnp.asarray(-dists), min(k, dists.size))
+        assert np.array_equal(np.asarray(by_kernel), want_order), k
         # duplicate distances must actually occur for the tie pin to
         # mean anything
     assert len(np.unique(golden(x[0], 1000)[1])) < \
@@ -314,13 +329,13 @@ def test_pallas_compile_counter(fusion_cache, monkeypatch):
 
 def test_top_k_refuses_integer_dtype():
     with pytest.raises(KernelUnsupportedError, match="not floating"):
-        kernels.top_k(jnp.arange(10), 3, backend="pallas")
+        topk.pallas_top_k(jnp.arange(10), 3)
 
 
 def test_top_k_refuses_bad_k():
     x = jnp.ones((4, 8), jnp.float32)
     with pytest.raises(KernelUnsupportedError, match="outside"):
-        kernels.top_k(x, 9, backend="pallas")
+        topk.pallas_top_k(x, 9)
 
 
 def test_segment_sum_refuses_integer_values():
@@ -375,13 +390,13 @@ def test_chain_refuses_weak_typed_constant():
 def test_env_var_validation(monkeypatch):
     monkeypatch.setenv(ENV_VAR, "bogus")
     with pytest.raises(ValueError, match="FLINKML_TPU_KERNELS"):
-        kernels.backend_for("topk")
-    monkeypatch.setenv(ENV_VAR, "topk=metal")
+        kernels.backend_for("segment_sum")
+    monkeypatch.setenv(ENV_VAR, "segment_sum=metal")
     with pytest.raises(ValueError, match="bad pair"):
-        kernels.backend_for("topk")
+        kernels.backend_for("segment_sum")
     monkeypatch.setenv(ENV_VAR, "notasite=pallas")
     with pytest.raises(ValueError, match="bad pair"):
-        kernels.backend_for("topk")
+        kernels.backend_for("segment_sum")
 
 
 def test_threaded_table_choice_keeps_fallback_semantics(
@@ -392,22 +407,22 @@ def test_threaded_table_choice_keeps_fallback_semantics(
     through that way must keep warn-and-fallback on unsupported
     operands — only a backend DISAGREEING with the gate is an explicit
     per-call request that refuses loudly."""
-    tuned_kernels({"kernel_backend_topk": "pallas"})
+    tuned_kernels({"kernel_backend_segment_sum": "pallas"})
     # Simulate a compiled (non-interpret) target: float64 unsupported.
     monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")
-    x = jnp.asarray(np.random.default_rng(8).normal(size=(4, 16)))
+    x = jnp.asarray(np.random.default_rng(8).normal(size=16))
+    ids = jnp.asarray(np.arange(16) % 4, jnp.int32)
     assert x.dtype == jnp.float64
-    threaded = kernels.topk_backend()
+    threaded = kernels.segsum_backend()
     assert threaded == "pallas"
     # table choice threaded through: degrades to the XLA result.
-    rv, ri = kernels.top_k(x, 3, backend=threaded)
-    ev, ei = jax.lax.top_k(x, 3)
-    assert np.asarray(rv).tobytes() == np.asarray(ev).tobytes()
-    assert np.asarray(ri).tobytes() == np.asarray(ei).tobytes()
+    got = kernels.segment_sum(x, ids, 4, backend=threaded)
+    want = jax.ops.segment_sum(x, ids, num_segments=4)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
     # the same operands under a genuinely explicit request refuse.
-    monkeypatch.setenv(ENV_VAR, "topk=xla")   # gate now says xla ...
+    monkeypatch.setenv(ENV_VAR, "segment_sum=xla")   # gate now says xla ...
     with pytest.raises(KernelUnsupportedError):
-        kernels.top_k(x, 3, backend="pallas")  # ... arg disagrees
+        kernels.segment_sum(x, ids, 4, backend="pallas")  # ... arg disagrees
 
 
 def test_table_chosen_backend_falls_back_warn_once(tuned_kernels):
@@ -437,14 +452,14 @@ def test_gate_defaults_off():
 def test_gate_precedence_env_over_table_over_default(
     tuned_kernels, monkeypatch
 ):
-    tuned_kernels({"kernel_backend_topk": "pallas"})
+    tuned_kernels({"kernel_backend_segment_sum": "pallas"})
     # table layer supplies the default ...
-    assert kernels.backend_for("topk") == "pallas"
+    assert kernels.backend_for("segment_sum") == "pallas"
     # ... other sites keep the static default ...
-    assert kernels.backend_for("segment_sum") == "xla"
+    assert kernels.backend_for("fused_chain") == "xla"
     # ... the env var beats the table ...
-    monkeypatch.setenv(ENV_VAR, "topk=xla")
-    assert kernels.backend_for("topk") == "xla"
+    monkeypatch.setenv(ENV_VAR, "segment_sum=xla")
+    assert kernels.backend_for("segment_sum") == "xla"
     # ... a global env value covers every site ...
     monkeypatch.setenv(ENV_VAR, "pallas")
     for site in kernels.SITES:
@@ -452,17 +467,17 @@ def test_gate_precedence_env_over_table_over_default(
     # ... and FLINKML_TPU_AUTOTUNE=0 turns the table layer off.
     monkeypatch.delenv(ENV_VAR)
     monkeypatch.setenv(ENV_DISABLE_VAR, "0")
-    assert kernels.backend_for("topk") == "xla"
+    assert kernels.backend_for("segment_sum") == "xla"
 
 
 def test_factory_backends_follow_gate(monkeypatch):
     from flinkml_tpu.models._linear_sgd import _segsum_backend
 
     assert _segsum_backend() == "xla"
-    assert kernels.topk_backend() == "xla"
+    assert kernels.segsum_backend() == "xla"
     monkeypatch.setenv(ENV_VAR, "pallas")
     assert _segsum_backend() == "pallas"
-    assert kernels.topk_backend() == "pallas"
+    assert kernels.segsum_backend() == "pallas"
 
 
 # -- compile cache: backend is key material ----------------------------------

@@ -247,7 +247,7 @@ def test_three_bfloat16_parts_are_the_float32(rng, values, in_kernel):
     before: nothing of a value is lost that a one-pass product would
     keep."""
     v = PARTS_OF[values](rng)
-    split = functools.partial(knn_search._bf16_parts, in_kernel=in_kernel)
+    split = functools.partial(knn_search.rounded_parts, in_kernel=in_kernel)
     hi, mid, lo = (np.asarray(p.astype(jnp.float32))
                    for p in jax.jit(split)(jnp.asarray(v)))
     np.testing.assert_array_equal((hi + mid) + lo, v)

@@ -291,14 +291,25 @@ def test_pool_parity_and_balance():
         pool.stop()
 
 
-def test_pool_replica_kill_mid_traffic_loses_nothing():
+@pytest.mark.parametrize("source", ["model", "registry"])
+def test_pool_replica_kill_mid_traffic_loses_nothing(source, tmp_path):
     """Chaos contract: kill 1 of 4 replicas via the serving.replica seam
     while clients run. Zero client errors (requests on the dead replica
-    are retried on healthy ones), correct parity and version tags, the
-    replica is retired, the pool keeps serving."""
+    are retried on healthy ones), correct parity and version tags (a
+    pool that follows a registry tags every response, the retried ones
+    too, with the published version), the replica is retired, the pool
+    keeps serving."""
     x, y = _data()
     pm = _two_stage_chain(x, y)
-    pool = _pool(pm, x, name="chaos_pool").start()
+    if source == "registry":
+        reg = ModelRegistry(str(tmp_path / "reg"))
+        reg.publish(pm)
+        pool = _pool(reg, x, name="chaos_pool_reg").start()
+        pool.follow_registry()
+        version = 1
+    else:
+        pool = _pool(pm, x, name="chaos_pool").start()
+        version = None
     errors = []
     served = [0]
     stop = threading.Event()
@@ -311,6 +322,7 @@ def test_pool_replica_kill_mid_traffic_loses_nothing():
                 lo = int(rng.integers(0, x.shape[0] - rows))
                 sl = x[lo:lo + rows]
                 resp = pool.predict({"features": sl})
+                assert resp.version == version, resp.version
                 (ref,) = pm.transform(Table({"features": sl}))
                 np.testing.assert_array_equal(
                     np.asarray(ref.column("prediction")),

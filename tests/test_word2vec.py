@@ -112,7 +112,7 @@ def test_sharded_trainer_matches_dense(monkeypatch):
     ring's masked partial adds)."""
     docs, animals, tools = _topic_corpus()
     dense_model, _ = _fit(docs)
-    monkeypatch.setenv("FLINKML_W2V_SHARD_VOCAB", "0")
+    monkeypatch.setenv("FLINKML_TPU_EMBEDDING_DENSE_VOCAB", "0")
     sharded_model, _ = _fit(docs)
     dv = dense_model._vectors
     sv = sharded_model._vectors
@@ -175,7 +175,7 @@ def test_streamed_fit_shards_above_vocab_threshold(monkeypatch):
         )
 
     dense_model = fit()
-    monkeypatch.setenv("FLINKML_W2V_SHARD_VOCAB", "0")
+    monkeypatch.setenv("FLINKML_TPU_EMBEDDING_DENSE_VOCAB", "0")
     sharded_model = fit()
     np.testing.assert_allclose(
         sharded_model._vectors, dense_model._vectors, rtol=2e-3, atol=2e-4
