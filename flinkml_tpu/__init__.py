@@ -36,7 +36,7 @@ from flinkml_tpu.api import (
     Model,
     Estimator,
 )
-from flinkml_tpu.table import CsrColumn, Table
+from flinkml_tpu.table import CsrColumn, Table, TokenColumn
 from flinkml_tpu.pipeline import Pipeline, PipelineModel
 from flinkml_tpu.graph import GraphBuilder, Graph, GraphModel, TableId
 from flinkml_tpu.tuning import (
@@ -68,6 +68,7 @@ __all__ = [
     "Estimator",
     "Table",
     "CsrColumn",
+    "TokenColumn",
     "Pipeline",
     "PipelineModel",
     "GraphBuilder",

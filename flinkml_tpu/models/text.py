@@ -52,8 +52,9 @@ def _token_column(table: Table, col: str) -> np.ndarray:
     values = table.column(col)
     if values.dtype != object:
         raise ValueError(
-            f"Column {col!r} must be a token-list column (object dtype), "
-            f"got {values.dtype} — run a Tokenizer first"
+            f"Column {col!r} must be a token-list column (object dtype, or a "
+            f"table.TokenColumn: the fast input of Word2Vec.fit), got "
+            f"{values.dtype} — run a Tokenizer first"
         )
     return values
 

@@ -1,6 +1,14 @@
 """Word2Vec — skip-gram with negative sampling (the Spark/Flink family
 member), TPU-native.
 
+``fit(Table)`` is :mod:`flinkml_tpu.models._w2v_table`: the token column
+(a :class:`~flinkml_tpu.table.TokenColumn`, or an object column of token
+lists encoded to one) ingested once a table and kept on the chip, the
+pairs and the negatives drawn on the device, a step the update of the
+batch's rows — one program, ``w2v_sgns_loop``, on one device; on more,
+:func:`_sgns_trainer_sharded` over the same draw. What follows is the
+STREAMED fit (an iterable of batch Tables).
+
 Host prep (strings never touch the device): frequency vocabulary with
 ``minCount`` pruning, (center, context) pair generation over
 ``windowSize``, and a unigram^0.75 negative-sampling pool materialized
@@ -48,7 +56,7 @@ from flinkml_tpu.common_params import (
     HasSeed,
 )
 from flinkml_tpu.models.text import _token_column
-from flinkml_tpu.params import IntParam, ParamValidators
+from flinkml_tpu.params import FloatParam, IntParam, ParamValidators
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
 
@@ -76,20 +84,17 @@ class _Word2VecParams(HasInputCol, HasOutputCol, HasMaxIter,
         "batchSize", "Global pairs per SGNS step.", 1024,
         ParamValidators.gt(0),
     )
-
-
-def _build_pairs(docs, vocab_index: Dict[str, int], window: int,
-                 rng: np.random.Generator):
-    centers, contexts = [], []
-    for toks in docs:
-        ids = [vocab_index[t] for t in map(str, toks) if t in vocab_index]
-        for i, c in enumerate(ids):
-            w = int(rng.integers(1, window + 1))   # word2vec's window jitter
-            for j in range(max(0, i - w), min(len(ids), i + w + 1)):
-                if j != i:
-                    centers.append(c)
-                    contexts.append(ids[j])
-    return (np.asarray(centers, np.int32), np.asarray(contexts, np.int32))
+    MAX_STEPS = IntParam(
+        "maxSteps", "SGNS steps of a fit(Table); 0: maxIter epochs' steps "
+        "(an epoch is the corpus's expected pairs / batchSize).", 0,
+        ParamValidators.gt_eq(0),
+    )
+    SUBSAMPLE = FloatParam(
+        "subsample", "word2vec.c's threshold t of fit(Table): an occurrence "
+        "of a word of frequency f survives with probability "
+        "min(1, sqrt(t / f) + t / f); 0: every occurrence.", 0.0,
+        ParamValidators.gt_eq(0.0),
+    )
 
 
 def _agree_token_counts(tokens, counts, mesh) -> "Dict[str, int]":
@@ -176,20 +181,45 @@ def _kernels_segsum_backend() -> str:
     return kernels.segsum_backend()
 
 
-def _sgns_pair_grads(vc, uc, un, wb):
+#: The precision of the scores' products: float32-accurate on every
+#: backend (a TPU's compiler turns these skinny products into float32
+#: multiplies and sums of its own accord; where one goes to a matrix unit
+#: it may not take one bfloat16 pass).
+SCORE_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _sgns_pair_grads(vc, uc, un, wb, score_dtype=None):
     """SGNS pair gradients from the gathered embedding rows — the ONE
-    definition of the loss math, shared by the dense and vocab-sharded
-    trainers (their numerics-parity contract,
-    ``tests/test_word2vec.py::test_sharded_trainer_matches_dense``,
-    depends on it). Returns ``(grad_vc, grad_uc, grad_un)``."""
-    pos_score = jnp.sum(vc * uc, axis=1)
-    neg_score = jnp.einsum("bd,bnd->bn", vc, un)
+    definition of the loss math, shared by the table fit, the dense and
+    the vocab-sharded trainers (their numerics-parity contract,
+    ``tests/test_word2vec.py::test_sharded_trainer_matches_one_device``,
+    depends on it). Returns ``(grad_vc, grad_uc, grad_un)``.
+    ``score_dtype`` is the benchmark's control's alone: ``bfloat16``
+    rounds every product's operands as one bfloat16 pass would."""
+    def operand(x):
+        if score_dtype is None:
+            return x
+        info = jnp.finfo(score_dtype)
+        return jax.lax.reduce_precision(x, info.nexp, info.nmant)
+
+    vs, us, ns = operand(vc), operand(uc), operand(un)
+    pos_score = jnp.sum(vs * us, axis=1)
+    neg_score = jnp.einsum("bd,bnd->bn", vs, ns, precision=SCORE_PRECISION)
     g_pos = (jax.nn.sigmoid(pos_score) - 1.0) * wb   # [bs]
     g_neg = jax.nn.sigmoid(neg_score) * wb[:, None]  # [bs, neg]
-    grad_vc = g_pos[:, None] * uc + jnp.einsum("bn,bnd->bd", g_neg, un)
+    grad_vc = g_pos[:, None] * uc + jnp.einsum(
+        "bn,bnd->bd", operand(g_neg), ns, precision=SCORE_PRECISION)
     grad_uc = g_pos[:, None] * vc
     grad_un = g_neg[..., None] * vc[:, None, :]
     return grad_vc, grad_uc, grad_un
+
+
+def start_vectors(seed: int, vocab: int, dim: int) -> jax.Array:
+    """A table fit's start word vectors, made on the device from the seed
+    alone: uniform in ``[-0.5, 0.5) / dim``, float32 ``[vocab, dim]`` (the
+    context vectors start at 0)."""
+    return (jax.random.uniform(jax.random.PRNGKey(seed), (vocab, dim),
+                               jnp.float32) - 0.5) / dim
 
 
 @functools.lru_cache(maxsize=8)
@@ -295,7 +325,8 @@ def _sgns_trainer(mesh, axis: str, local_bs: int, n_neg: int,
 @functools.lru_cache(maxsize=8)
 def _sgns_trainer_sharded(mesh, axis: str, local_bs: int, n_neg: int,
                           shard_rows: int, strategy: str = "ring",
-                          segsum_backend: str = "xla"):
+                          segsum_backend: str = "xla", corpus=None,
+                          score_dtype=None):
     """Vocab-sharded SGNS trainer: the scale path above the embedding
     dense-psum threshold (VERDICT r4 weak #6 — the dense trainer psums
     a full ``[vocab, dim]`` gradient every step, quadratically painful
@@ -325,32 +356,30 @@ def _sgns_trainer_sharded(mesh, axis: str, local_bs: int, n_neg: int,
     match the dense trainer up to f32 summation order; the strategies
     match each other bitwise on the gather and up to summation order on
     the scatter (both pinned in ``tests/test_word2vec.py`` /
-    ``tests/test_embeddings.py``)."""
+    ``tests/test_embeddings.py``).
+
+    The streamed fit hands it a chunk of the pair list, ``(centers,
+    contexts, weights, pool, v, u, lr, steps, key)``. A table fit names
+    its ``corpus`` (a :class:`_w2v_table.Draw`) and calls ``(v, u, tokens,
+    keep, pool, seed, lr, steps)``: the corpus is replicated, every
+    device makes the step's whole draw (:func:`_w2v_table.draw`) and takes
+    its ``local_bs`` slots of it, so the step is the one-device program's
+    on the same pairs."""
     from flinkml_tpu.embeddings import exchange
 
     p = dict(mesh.shape)[axis]
 
-    def local(centers, contexts, wl, pool, v_shard, u_shard, lr, n_steps,
-              key):
-        n_local = centers.shape[0]
-
+    def loop(sample, v_shard, u_shard, lr, n_steps):
         def body(state):
             step, v, u = state
-            k = jax.random.fold_in(key, step)
-            k1, k2 = jax.random.split(k)
-            idx = jax.random.randint(k1, (local_bs,), 0, n_local)
-            c = centers[idx]
-            ctx = contexts[idx]
-            wb = wl[idx]
-            neg = pool[jax.random.randint(
-                k2, (local_bs, n_neg), 0, pool.shape[0]
-            )]
+            c, ctx, wb, neg = sample(step)
             vc, uc, un = exchange.gather(
                 ((v, c), (u, ctx), (u, neg)),
                 axes=axis, n_shards=p, shard_rows=shard_rows,
                 strategy=strategy,
             )
-            grad_vc, grad_uc, grad_un = _sgns_pair_grads(vc, uc, un, wb)
+            grad_vc, grad_uc, grad_un = _sgns_pair_grads(
+                vc, uc, un, wb, score_dtype=score_dtype)
             tw = jnp.maximum(jax.lax.psum(jnp.sum(wb), axis), 1e-12)
             scale = lr / tw
             v, u = exchange.scatter_add(
@@ -373,6 +402,41 @@ def _sgns_trainer_sharded(mesh, axis: str, local_bs: int, n_neg: int,
         )
         return v, u
 
+    def local(centers, contexts, wl, pool, v_shard, u_shard, lr, n_steps,
+              key):
+        n_local = centers.shape[0]
+
+        def sample(step):
+            k = jax.random.fold_in(key, step)
+            k1, k2 = jax.random.split(k)
+            idx = jax.random.randint(k1, (local_bs,), 0, n_local)
+            neg = pool[jax.random.randint(
+                k2, (local_bs, n_neg), 0, pool.shape[0]
+            )]
+            return centers[idx], contexts[idx], wl[idx], neg
+
+        return loop(sample, v_shard, u_shard, lr, n_steps)
+
+    def local_corpus(v_shard, u_shard, tokens, keep, pool, seed, lr, n_steps):
+        from flinkml_tpu.models import _w2v_table
+
+        def sample(step):
+            c, ctx, neg, found = _w2v_table.draw(
+                corpus, tokens, keep, pool, seed, step.astype(jnp.uint32))
+            mine = jax.lax.axis_index(axis) * local_bs
+            c, ctx, neg = (jax.lax.dynamic_slice_in_dim(x, mine, local_bs)
+                           for x in (c, ctx, neg))
+            wb = jnp.full(local_bs, found > 0, v_shard.dtype)
+            return c, ctx, wb, neg
+
+        return loop(sample, v_shard, u_shard, lr, n_steps)
+
+    if corpus is not None:
+        return jax.jit(jax.shard_map(
+            local_corpus, mesh=mesh,
+            in_specs=(P(axis), P(axis)) + (P(),) * 6,
+            out_specs=(P(axis), P(axis)),
+        ))
     return jax.jit(
         jax.shard_map(
             local, mesh=mesh,
@@ -428,91 +492,14 @@ class Word2Vec(StreamingEstimatorMixin, _Word2VecParams, Estimator):
         if not isinstance(table, Table):
             return self._fit_stream(table)
         self._reject_in_ram_checkpointing()
-        docs = _token_column(table, self.get(self.INPUT_COL))
-        min_count = self.get(self.MIN_COUNT)
-        counts: Dict[str, int] = {}
-        for toks in docs:
-            for t in toks:
-                t = str(t)
-                counts[t] = counts.get(t, 0) + 1
-        vocab = [t for t, c in counts.items() if c >= min_count]
-        vocab.sort(key=lambda t: (-counts[t], t))
-        if not vocab:
-            raise ValueError(
-                f"no token reaches minCount={min_count}; vocabulary is empty"
-            )
-        vocab_index = {t: i for i, t in enumerate(vocab)}
-        rng = np.random.default_rng(self.get_seed())
-        centers, contexts = _build_pairs(
-            docs, vocab_index, self.get(self.WINDOW_SIZE), rng
-        )
-        if centers.size == 0:
-            raise ValueError("no (center, context) pairs; documents too short")
-        # unigram^0.75 negative pool.
-        freq = np.asarray([counts[t] for t in vocab], np.float64) ** 0.75
-        pool = rng.choice(
-            len(vocab), size=_NEG_POOL, p=freq / freq.sum()
-        ).astype(np.int32)
+        from flinkml_tpu.models import _w2v_table
+        from flinkml_tpu.utils.profiling import span
 
-        dim = self.get(self.VECTOR_SIZE)
-        mesh = self.mesh or DeviceMesh()
-        p = mesh.axis_size()
-        # Shuffle, then pad by REPEATING real pairs: a zero-filled pad
-        # would be a genuine (0, 0) positive pair self-training the most
-        # frequent word; cycling real pairs only mildly over-weights a
-        # few of them.
-        perm = rng.permutation(len(centers))
-        centers, contexts = centers[perm], contexts[perm]
-        pad = (-len(centers)) % p
-        centers_p = np.concatenate([centers, centers[:pad]])
-        contexts_p = np.concatenate([contexts, contexts[:pad]])
-
-        local_bs = max(1, self.get(self.BATCH_SIZE) // p)
-        n_pairs = len(centers)
-        steps_per_epoch = max(1, n_pairs // self.get(self.BATCH_SIZE))
-        n_steps = steps_per_epoch * self.get(self.MAX_ITER)
-
-        v0 = (rng.random((len(vocab), dim)) - 0.5).astype(np.float32) / dim
-        u0 = np.zeros((len(vocab), dim), np.float32)
-        if p > 1 and len(vocab) > _shard_vocab_threshold():
-            # Scale path: both embedding tables shard over the mesh; the
-            # per-step ring traffic is batch-sized, never vocab-sized.
-            shard_rows = -(-len(vocab) // p)
-            row_pad = shard_rows * p - len(vocab)
-            v0p = np.concatenate([v0, np.zeros((row_pad, dim), np.float32)])
-            u0p = np.concatenate([u0, np.zeros((row_pad, dim), np.float32)])
-            trainer = _sgns_trainer_sharded(
-                mesh.mesh, DeviceMesh.DATA_AXIS, local_bs,
-                self.get(self.NUM_NEGATIVES), shard_rows,
-                _exchange_strategy(), _kernels_segsum_backend(),
-            )
-            v, _u = trainer(
-                mesh.shard_batch(centers_p), mesh.shard_batch(contexts_p),
-                mesh.shard_batch(np.ones(len(centers_p), np.float32)),
-                jnp.asarray(pool), mesh.shard_batch(v0p),
-                mesh.shard_batch(u0p),
-                jnp.asarray(self.get(self.LEARNING_RATE), jnp.float32),
-                jnp.asarray(n_steps, jnp.int32),
-                jax.random.PRNGKey(self.get_seed()),
-            )
-            v = np.asarray(v)[: len(vocab)]
-        else:
-            trainer = _sgns_trainer(
-                mesh.mesh, DeviceMesh.DATA_AXIS, local_bs,
-                self.get(self.NUM_NEGATIVES), _w2v_accum(),
-                _kernels_segsum_backend(),
-            )
-            v, _u = trainer(
-                mesh.shard_batch(centers_p), mesh.shard_batch(contexts_p),
-                mesh.shard_batch(np.ones(len(centers_p), np.float32)),
-                jnp.asarray(pool), jnp.asarray(v0), jnp.asarray(u0),
-                jnp.asarray(self.get(self.LEARNING_RATE), jnp.float32),
-                jnp.asarray(n_steps, jnp.int32),
-                jax.random.PRNGKey(self.get_seed()),
-            )
-        model = Word2VecModel()
-        model.copy_params_from(self)
-        model._set(np.asarray(vocab, dtype=str), np.asarray(v, np.float64))
+        with span("fit"):
+            vocab, vectors = _w2v_table.fit_table(self, table)
+            model = Word2VecModel()
+            model.copy_params_from(self)
+            model._set(vocab, vectors)
         return model
 
     # Pair-chunk row tile: bounds the set of padded chunk shapes (and so
@@ -881,31 +868,43 @@ class Word2Vec(StreamingEstimatorMixin, _Word2VecParams, Estimator):
         model = Word2VecModel()
         model.copy_params_from(self)
         model._set(
-            np.asarray(vocab, dtype=str),
-            np.asarray(v, np.float64)[: len(vocab)],
+            np.asarray(vocab, dtype=str), np.asarray(v)[: len(vocab)],
         )
         return model
 
 
 class Word2VecModel(_Word2VecParams, Model):
+    """The fitted vectors as the chip returned them (float32, ``[vocab,
+    dim]``: :meth:`word_vectors`); :attr:`vectors` widens them to float64
+    when asked, once. The word index is built at its first use."""
+
     def __init__(self):
         super().__init__()
         self._vocab: Optional[np.ndarray] = None
         self._vectors: Optional[np.ndarray] = None
-        self._index: Dict[str, int] = {}
 
     def _set(self, vocab: np.ndarray, vectors: np.ndarray) -> None:
         self._vocab = vocab
         self._vectors = vectors
-        self._index = {str(t): i for i, t in enumerate(vocab)}
+        for cached in ("_index", "vectors"):
+            self.__dict__.pop(cached, None)
+
+    @functools.cached_property
+    def _index(self) -> Dict[str, int]:
+        return {str(t): i for i, t in enumerate(self._vocab)}
 
     @property
     def vocabulary(self) -> np.ndarray:
         self._require()
         return self._vocab
 
-    @property
+    @functools.cached_property
     def vectors(self) -> np.ndarray:
+        self._require()
+        return np.asarray(self._vectors, np.float64)
+
+    def word_vectors(self) -> np.ndarray:
+        """The vectors as held (a fit's: the chip's float32), no copy."""
         self._require()
         return self._vectors
 
@@ -913,7 +912,7 @@ class Word2VecModel(_Word2VecParams, Model):
         (table,) = inputs
         self._set(
             np.asarray(table.column("word"), dtype=str),
-            np.asarray(table.column("vector"), np.float64),
+            np.asarray(table.column("vector")),
         )
         return self
 
@@ -936,7 +935,7 @@ class Word2VecModel(_Word2VecParams, Model):
         for i, toks in enumerate(docs):
             ids = [self._index[t] for t in map(str, toks) if t in self._index]
             if ids:
-                out[i] = self._vectors[ids].mean(axis=0)
+                out[i] = self._vectors[ids].mean(axis=0, dtype=np.float64)
         return (table.with_column(self.get(self.OUTPUT_COL), out),)
 
     def find_synonyms(self, word: str, k: int) -> Tuple[np.ndarray, np.ndarray]:

@@ -231,6 +231,19 @@ class SortedSparseColumn(PaddedDeviceColumn):
         return out
 
 
+def _ragged_take(indptr: np.ndarray, rows):
+    """Rows ``rows`` (anything that indexes an array of row numbers) of a
+    ragged column laid by ``indptr``, in that order: ``(the result's indptr,
+    the position in the old cells of each of its cells)``."""
+    rows = np.arange(indptr.shape[0] - 1)[rows].reshape(-1)
+    counts = indptr[rows + 1] - indptr[rows]
+    out = np.zeros(rows.shape[0] + 1, np.int64)
+    np.cumsum(counts, out=out[1:])
+    # Cell j of the result is cell (its row's first cell + its slot).
+    return out, (np.repeat(indptr[rows] - out[:-1], counts)
+                 + np.arange(int(out[-1]), dtype=np.int64))
+
+
 class CsrColumn:
     """A host SPARSE column that *is* CSR: row ``r`` holds the cells
     ``indices[indptr[r]:indptr[r + 1]]`` / ``values[...]`` of a
@@ -349,13 +362,7 @@ class CsrColumn:
         """The rows ``rows`` (anything that indexes an array of row
         numbers: integers, negative ones, a boolean mask), in that
         order."""
-        rows = np.arange(len(self))[rows].reshape(-1)
-        counts = self.indptr[rows + 1] - self.indptr[rows]
-        indptr = np.zeros(rows.shape[0] + 1, np.int64)
-        np.cumsum(counts, out=indptr[1:])
-        # Cell j of the result is cell (its row's first cell + its slot).
-        src = (np.repeat(self.indptr[rows] - indptr[:-1], counts)
-               + np.arange(int(indptr[-1]), dtype=np.int64))
+        indptr, src = _ragged_take(self.indptr, rows)
         return CsrColumn._trusted(
             indptr, self.indices[src], self.values[src], self.dim)
 
@@ -389,9 +396,134 @@ class CsrColumn:
         _materialization_metrics().counter("csr_rows_materialized", float(n))
         return out
 
+    to_rows = to_vectors     # what Table.column asks of an array column
+
     def __repr__(self) -> str:  # pragma: no cover
         return (f"CsrColumn({len(self)} rows, dim {self.dim}, "
                 f"{self.indices.shape[0]} cells, {self.values.dtype})")
+
+class TokenColumn:
+    """A host column of token LISTS that *is* two arrays: row ``r`` holds
+    the tokens ``vocabulary[ids[indptr[r]:indptr[r + 1]]]``, in order, a
+    token as often as it comes — a tokenised corpus as an encoder writes
+    it in bulk, carried by a :class:`Table` without one Python list a row
+    (a :class:`CsrColumn` cannot carry a sentence: its indices ascend
+    strictly).
+
+    ``indptr`` is int64, ``ids`` int32 in ``[0, len(vocabulary))``,
+    ``vocabulary`` a 1-D array of the distinct tokens (strings, or
+    anything ``str`` names); arrays that already fit are kept by
+    reference. Validated ONCE here, vectorised. ``Word2Vec.fit`` takes
+    the arrays as they are; a row-wise consumer asks
+    :meth:`Table.column`, which builds the object array of token lists on
+    demand (:meth:`to_lists`, counted in ``table.token_rows_materialized``).
+    """
+
+    __slots__ = ("indptr", "ids", "vocabulary")
+
+    def __init__(self, indptr, ids, vocabulary):
+        indptr = np.asarray(indptr, dtype=np.int64)
+        vocabulary = np.asarray(vocabulary)
+        if indptr.ndim != 1 or np.ndim(ids) != 1 or vocabulary.ndim != 1:
+            raise ValueError("indptr, ids and vocabulary must be 1-D")
+        if indptr.size == 0 or indptr[0] != 0 or np.any(np.diff(indptr) < 0):
+            raise ValueError("indptr must start at 0 and never fall")
+        n = np.shape(ids)[0]
+        if int(indptr[-1]) != n:
+            raise ValueError(
+                f"indptr ends at {int(indptr[-1])}, the column holds {n} tokens")
+        # Range on the caller's integers: a cast to int32 must not wrap
+        # an id that is out of range anyway.
+        if n and (np.min(ids) < 0 or np.max(ids) >= vocabulary.shape[0]):
+            raise ValueError(
+                f"token ids must lie in [0, {vocabulary.shape[0]}), the "
+                "vocabulary's positions")
+        self._set(indptr, np.asarray(ids, dtype=np.int32), vocabulary)
+        _materialization_metrics().counter("token_rows_materialized", 0.0)
+
+    def _set(self, indptr, ids, vocabulary) -> "TokenColumn":
+        self.indptr, self.ids, self.vocabulary = indptr, ids, vocabulary
+        return self
+
+    @classmethod
+    def _trusted(cls, indptr, ids, vocabulary) -> "TokenColumn":
+        """Rows of a validated column: no second look."""
+        return object.__new__(cls)._set(indptr, ids, vocabulary)
+
+    @classmethod
+    def from_lists(cls, docs) -> "TokenColumn":
+        """The column holding a sequence of token lists (or arrays), the
+        tokens as ``str`` names them: ONE ``np.unique`` over all of them,
+        so the vocabulary is sorted."""
+        rows = [np.asarray(d, dtype=str).reshape(-1) for d in docs]
+        indptr = np.zeros(len(rows) + 1, np.int64)
+        np.cumsum([r.shape[0] for r in rows], out=indptr[1:])
+        flat = np.concatenate(rows) if rows else np.empty(0, str)
+        vocabulary, ids = np.unique(flat, return_inverse=True)
+        return cls._trusted(indptr, ids.astype(np.int32).reshape(-1), vocabulary)
+
+    # What a Table asks of any column.
+    @property
+    def shape(self):
+        return (self.indptr.shape[0] - 1,)
+
+    ndim = 1
+    dtype = np.dtype(object)
+
+    def __len__(self) -> int:
+        return self.indptr.shape[0] - 1
+
+    def __getitem__(self, rows) -> "TokenColumn":
+        """``column[a:b]`` and ``column[row numbers or mask]``, as an array
+        column answers them; a single row is :meth:`Table.column`'s to
+        build."""
+        if isinstance(rows, slice) and rows.step in (None, 1):
+            start, stop, _ = rows.indices(len(self))
+            stop = max(start, stop)
+            lo, hi = int(self.indptr[start]), int(self.indptr[stop])
+            return TokenColumn._trusted(
+                self.indptr[start:stop + 1] - lo, self.ids[lo:hi],
+                self.vocabulary)
+        if np.ndim(rows) == 0 and not isinstance(rows, slice):
+            raise TypeError(
+                "a TokenColumn is indexed by a slice, row numbers or a mask; "
+                "Table.column(name)[i] gives row i as a list of tokens")
+        indptr, src = _ragged_take(self.indptr, rows)
+        return TokenColumn._trusted(indptr, self.ids[src], self.vocabulary)
+
+    def concat(self, other: "TokenColumn") -> "TokenColumn":
+        if (not isinstance(other, TokenColumn)
+                or not np.array_equal(other.vocabulary, self.vocabulary)):
+            raise ValueError(
+                "a TokenColumn concatenates with a TokenColumn of its own "
+                "vocabulary")
+        return TokenColumn._trusted(
+            np.concatenate([self.indptr, other.indptr[1:] + self.indptr[-1]]),
+            np.concatenate([self.ids, other.ids]), self.vocabulary)
+
+    def to_lists(self) -> np.ndarray:
+        """The object array of token lists (``str`` tokens), for row-wise
+        consumers; counted in ``table.token_rows_materialized``."""
+        n = len(self)
+        words = self.vocabulary.astype(str)[self.ids].tolist()
+        bounds = self.indptr.tolist()
+        out = np.empty(n, dtype=object)
+        for r in range(n):
+            out[r] = words[bounds[r]:bounds[r + 1]]
+        _materialization_metrics().counter("token_rows_materialized", float(n))
+        return out
+
+    to_rows = to_lists       # what Table.column asks of an array column
+
+    def __repr__(self) -> str:  # pragma: no cover
+        return (f"TokenColumn({len(self)} rows, {self.ids.shape[0]} tokens, "
+                f"vocabulary {self.vocabulary.shape[0]})")
+
+
+#: The columns that are arrays of their own and no ``np.ndarray``: carried
+#: by reference, row-indexed on their arrays, their object rows built by
+#: :meth:`Table.column` alone.
+_ARRAY_COLUMNS = (CsrColumn, TokenColumn)
 
 
 def _is_device_backed(x: Any) -> bool:
@@ -556,6 +688,9 @@ class Table:
       - a :class:`CsrColumn` (a sparse column as CSR arrays): carried by
         reference, row-indexed on its arrays; :meth:`column` builds its
         ``SparseVector`` rows on demand, :meth:`csr_column` hands it over.
+      - a :class:`TokenColumn` (token lists as two arrays and their
+        vocabulary): carried the same way; :meth:`column` builds the
+        lists on demand, :meth:`token_column` hands it over.
     """
 
     def __init__(self, columns: Mapping[str, Any]):
@@ -564,7 +699,7 @@ class Table:
         conv: Dict[str, Any] = {}
         n_rows: Optional[int] = None
         for name, col in columns.items():
-            if isinstance(col, (np.ndarray, CsrColumn)) or _is_device_backed(col):
+            if isinstance(col, (np.ndarray,) + _ARRAY_COLUMNS) or _is_device_backed(col):
                 arr = col
             else:
                 arr = _to_array(col)
@@ -632,9 +767,9 @@ class Table:
         transfer, cached); until this call they cost no host bandwidth.
         """
         col = self._raw_column(name)
-        if isinstance(col, CsrColumn):
+        if isinstance(col, _ARRAY_COLUMNS):
             if name not in self._host_cache:
-                self._host_cache[name] = col.to_vectors()
+                self._host_cache[name] = col.to_rows()
             return self._host_cache[name]
         if not _is_device_backed(col):
             return col
@@ -658,11 +793,17 @@ class Table:
         col = self._raw_column(name)
         return col if isinstance(col, CsrColumn) else None
 
+    def token_column(self, name: str) -> Optional[TokenColumn]:
+        """The column's :class:`TokenColumn` if it is one, else None; no
+        row is built."""
+        col = self._raw_column(name)
+        return col if isinstance(col, TokenColumn) else None
+
     def _host_rows(self, name: str):
         """What the row-indexed ops index: a CsrColumn as it is, any
         other column as :meth:`column` gives it."""
         col = self._raw_column(name)
-        return col if isinstance(col, CsrColumn) else self.column(name)
+        return col if isinstance(col, _ARRAY_COLUMNS) else self.column(name)
 
     def device_column(self, name: str):
         """The column as a device-resident ``jax.Array`` — no host copy for
@@ -781,7 +922,7 @@ class Table:
 
     def with_column(self, name: str, values: Any) -> "Table":
         cols = dict(self._columns)
-        if isinstance(values, (np.ndarray, CsrColumn)) or _is_device_backed(values):
+        if isinstance(values, (np.ndarray,) + _ARRAY_COLUMNS) or _is_device_backed(values):
             cols[name] = values
         else:
             cols[name] = _to_array(values)
@@ -806,8 +947,8 @@ class Table:
             raise ValueError("concat requires identical column sets")
 
         def join(n):
-            a, b = self.csr_column(n), other.csr_column(n)
-            if a is not None and b is not None:
+            a, b = self._raw_column(n), other._raw_column(n)
+            if isinstance(a, _ARRAY_COLUMNS) and type(a) is type(b):
                 return a.concat(b)
             return np.concatenate([self.column(n), other.column(n)])
 

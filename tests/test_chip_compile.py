@@ -519,3 +519,52 @@ def test_als_half_step_at_the_cells_size(topo, no_compile_cache, side):
     assert memory.output_size_in_bytes < 1.0e9
     assert memory.temp_size_in_bytes < 2.0e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+
+
+@pytest.mark.parametrize("scores", ["float32", "bfloat16"])
+def test_w2v_whole_fit_at_the_cells_size_holds_no_vocabulary_sized_temporary(
+        one_chip, no_compile_cache, scores):
+    """``w2v-1bw.fit``'s one program, ``w2v_sgns_loop``: 256 steps of 16,384
+    pairs over the 805,306,368-token corpus and two ``[1,115,011, 384]``
+    tables, as the program and as the benchmark's control rounds the
+    products' operands. The tables are donated and updated row by row: beside
+    its arguments the program holds the draw's frames and the batch's rows
+    (0.38 GB), never a ``[vocab, dim]`` array (1.7 GB; the dense trainer's
+    two gradients a step were 2.7 GB), and the whole stays inside a v5e's
+    16 GB. Every gather and scatter stays XLA's own: a gather of slices the
+    compiler cannot fetch as rows is expanded into a ``while`` of its own
+    (65,536 turns a step, read off this program as first written)."""
+    from flinkml_tpu.models import _w2v_table
+
+    vocab, dim, tokens = 1_115_011, 300, 805_306_368
+    # 70.27 % of the tokens survive at the cell's corpus: its candidates
+    d = _w2v_table.Draw(16_384, 5, 5, _w2v_table.candidates_a_step(
+        16_384, tokens, int(0.7027 * 65536 * tokens)), tokens, 100_000_000)
+    assert d.candidates == 35_072
+    rows = -(-(tokens + 2 * d.span) // 128) + 1
+    lanes = _w2v_table.padded_dim(dim)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.enable_x64(True):    # as a user with ``jax_enable_x64`` on calls it
+        traced = _w2v_table._program(
+            d, None if scores == "float32" else jnp.bfloat16).trace(
+            on_chip((vocab, lanes), jnp.float32), on_chip((vocab, lanes), jnp.float32),
+            on_chip((rows, 128), jnp.int32), on_chip((rows, 128), jnp.uint16),
+            on_chip((100_000_000,), jnp.int32), on_chip((), jnp.uint32),
+            on_chip((), jnp.float32), on_chip((), jnp.int32))
+        # (a Python number is a weak 64-bit SCALAR under x64 until it meets
+        # its array; an ARRAY of 64-bit values would be emulated on the chip)
+        wide = [line.strip() for line in str(traced.jaxpr).splitlines()
+                if re.search(r"\b[fiu]64\[\d", line)]
+        assert wide == []
+        compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"= \([^\n]*\) while\(", text)) == 1
+    memory = compiled.memory_analysis()
+    table = vocab * lanes * 4
+    assert memory.temp_size_in_bytes < 0.3 * table
+    assert memory.alias_size_in_bytes >= 2 * table       # both updated in place
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 0.5 * 16e9 < held < 0.65 * 16e9

@@ -713,6 +713,52 @@ def test_an_als_fits_spans_are_siblings_in_order_and_a_second_fit_opens_fewer(tm
     assert _self_sum(d) == pytest.approx(d["fit.seconds"], rel=1e-9)
 
 
+def _token_table(seed=0, words=300, tokens=20_000):
+    from flinkml_tpu.table import TokenColumn
+
+    rng = np.random.default_rng(seed)
+    ends = np.cumsum(rng.integers(2, 30, tokens // 10))
+    indptr = np.concatenate([[0], ends[ends < tokens], [tokens]])
+    ids = (rng.random(tokens) ** 3 * words).astype(np.int32)
+    return Table({"tok": TokenColumn(indptr, ids, np.arange(words).astype(str))})
+
+
+def test_a_word2vec_fits_spans_are_siblings_in_order_and_a_second_fit_opens_fewer(tmp_path):
+    """``Word2Vec.fit(Table)`` (PR 44): ``w2v.ingest``,
+    ``w2v.table_to_device``, ``w2v.init``, ``w2v.loop`` (holding
+    ``w2v.dispatch``) and ``w2v.readback`` one after another inside
+    ``fit``; a second fit of the table ingests and places nothing, and the
+    tree still adds up."""
+    from flinkml_tpu.models import Word2Vec
+
+    one = DeviceMesh(devices=jax.devices()[:1])
+    fit = lambda t: (Word2Vec(mesh=one).set_input_col("tok").set_vector_size(8)
+                     .set_min_count(1).set_batch_size(128).set_max_steps(3).fit(t))
+    fit(_token_table(seed=1))  # compiled before the profile, on a table of its own
+    table = _token_table()
+    (fit_start, fit_end, name), *phases = _profiled_spans(tmp_path, lambda: fit(table))
+    assert name == "fit"
+    w2v = [ph for ph in phases if ph[2].startswith("w2v.")]
+    assert [n for _, _, n in w2v] == ["w2v.ingest", "w2v.table_to_device", "w2v.init",
+                                      "w2v.loop", "w2v.dispatch", "w2v.readback"]
+    spans = {n: (a, b) for a, b, n in w2v}
+    loop, dispatch = spans["w2v.loop"], spans["w2v.dispatch"]
+    assert loop[0] <= dispatch[0] <= dispatch[1] <= loop[1]
+    end = fit_start
+    for start, stop, n in w2v:
+        if n != "w2v.dispatch":
+            assert end <= start <= stop <= fit_end, n
+            end = stop
+    with _delta() as d, _delta("w2v") as counted:
+        fit(table)
+    assert set(_calls(d)) == {"fit", "w2v.init", "w2v.loop", "w2v.dispatch",
+                              "w2v.readback"}
+    assert _self_sum(d) == pytest.approx(d["fit.seconds"], rel=1e-9)
+    assert counted == {"fits": 1, "steps": 3, "pairs": 3 * 128,
+                       "row_fetches": 3 * 128 * 7, "row_updates": 3 * 128 * 7,
+                       "tokens": 20_000}
+
+
 def _struct(shape, dtype, sharding=None):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -797,6 +843,16 @@ def _lowered_programs():
             _struct((4 * p,), i32, rep), _struct((31, 128), f32, rep),
             _struct((), f32, rep), _struct((), f32, rep)).as_text()
 
+    def w2v_sgns_loop():
+        from flinkml_tpu.models import _w2v_table
+
+        u32, u16 = jnp.uint32, jnp.uint16
+        d = _w2v_table.Draw(64, 2, 3, 128, 1000, 4096)
+        return _w2v_table._program(d).lower(
+            _struct((50, 128), f32), _struct((50, 128), f32),
+            _struct((10, 128), i32), _struct((10, 128), u16), _struct((4096,), i32),
+            _struct((), u32), _struct((), f32), _struct((), i32)).as_text()
+
     def knn_vote():
         return knn._knn_vote.lower(
             _struct((16, 5), f32), _struct((64, 5), f32), _struct((64,), f32),
@@ -836,6 +892,7 @@ def _lowered_programs():
         "kmeans_lloyd": kmeans_lloyd,
         "fm_adam_loop": fm_adam_loop,
         "als_half_step": als_half_step,
+        "w2v_sgns_loop": w2v_sgns_loop,
         "knn_vote": knn_vote,
         "rows_sq": lambda: blas.squared_norms.lower(_struct((64, 5), f32)).as_text(),
         "fused_chain": fused_chain,
@@ -844,7 +901,7 @@ def _lowered_programs():
 
 PROGRAMS = ("lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop", "stage_write",
             "stage_zeros", "stage_ones", "kmeans_lloyd", "fm_adam_loop", "knn_vote",
-            "rows_sq", "fused_chain", "als_half_step")
+            "rows_sq", "fused_chain", "als_half_step", "w2v_sgns_loop")
 
 
 @pytest.mark.parametrize("name", PROGRAMS)
@@ -863,7 +920,7 @@ def test_the_docs_programs_table_lists_exactly_the_named_programs():
         doc = f.read()
     table = doc[doc.index("### Programs"):]
     table = table[:table.index("\n## ")] if "\n## " in table else table
-    listed = set(re.findall(r"^\| `([a-z_]+)` \|", table, flags=re.M))
+    listed = set(re.findall(r"^\| `([a-z0-9_]+)` \|", table, flags=re.M))
     assert listed == set(PROGRAMS)
     named = set()
     for dirpath, _, files in os.walk(os.path.join(root, "flinkml_tpu")):
@@ -871,6 +928,6 @@ def test_the_docs_programs_table_lists_exactly_the_named_programs():
             if name.endswith(".py"):
                 with open(os.path.join(dirpath, name)) as f:
                     named |= set(re.findall(
-                        r"named_program[,(]\s*\"([a-z_]+)\"", f.read()))
+                        r"named_program[,(]\s*\"([a-z0-9_]+)\"", f.read()))
     # _whole_loop's three callers hand their names down to its one call
     assert named | {"lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop"} == set(PROGRAMS)

@@ -20,18 +20,18 @@ def _topic_corpus(n_docs=600, seed=0):
     return docs, animals, tools
 
 
-def _fit(docs, **kw):
+def _fit(docs, mesh=None, stream=False, **kw):
     t = Table({"text": np.asarray(docs)})
     (tok,) = Tokenizer().set_input_col("text").set_output_col("tok").transform(t)
     w2v = (
-        Word2Vec().set_input_col("tok").set_output_col("vec")
+        Word2Vec(mesh=mesh).set_input_col("tok").set_output_col("vec")
         .set_vector_size(16).set_window_size(3).set_min_count(2)
         .set_max_iter(10).set_learning_rate(2.0).set_batch_size(512)
         .set_seed(0)
     )
     for name, v in kw.items():
         getattr(w2v, f"set_{name}")(v)
-    return w2v.fit(tok), tok
+    return w2v.fit(iter([tok]) if stream else tok), tok
 
 
 def _cos(a, b):
@@ -104,17 +104,20 @@ def test_persistence_and_determinism(tmp_path):
     np.testing.assert_array_equal(model2.vectors, model.vectors)
 
 
-def test_sharded_trainer_matches_dense(monkeypatch):
-    """Above the vocab threshold the in-RAM fit switches to the
-    vocab-sharded ring trainer; forcing the threshold to 0 must
-    reproduce the dense trainer's vectors on the same seed (identical
-    sampling sequence; f32 summation order differs only through the
-    ring's masked partial adds)."""
+def test_sharded_trainer_matches_one_device():
+    """On more than one device the table fit runs the vocab-sharded ring
+    trainer over the same draw as the one-device program
+    (``w2v_sgns_loop``): the same pairs and negatives every step, so the
+    same vectors (f32 summation order differs only through the ring's
+    masked partial adds)."""
+    import jax
+
+    from flinkml_tpu.parallel import DeviceMesh
+
     docs, animals, tools = _topic_corpus()
-    dense_model, _ = _fit(docs)
-    monkeypatch.setenv("FLINKML_TPU_EMBEDDING_DENSE_VOCAB", "0")
+    one_model, _ = _fit(docs, mesh=DeviceMesh(devices=jax.devices()[:1]))
     sharded_model, _ = _fit(docs)
-    dv = dense_model._vectors
+    dv = one_model._vectors
     sv = sharded_model._vectors
     np.testing.assert_allclose(sv, dv, rtol=2e-3, atol=2e-4)
     # And the sharded embedding still carries the topic structure.
@@ -133,9 +136,11 @@ def test_onehot_accum_matches_scatter(monkeypatch):
     structure. Pinned so a measured device winner can flip the default
     without a numerics question."""
     docs, animals, tools = _topic_corpus(seed=4)
-    scatter_model, _ = _fit(docs)
+    # The STREAMED fit's dense trainer reads the gate (a table fit updates
+    # rows and makes no [vocab, dim] gradient to accumulate).
+    scatter_model, _ = _fit(docs, stream=True)
     monkeypatch.setenv("FLINKML_TPU_W2V_ACCUM", "onehot")
-    onehot_model, _ = _fit(docs)
+    onehot_model, _ = _fit(docs, stream=True)
     np.testing.assert_array_equal(
         onehot_model.vocabulary, scatter_model.vocabulary
     )
