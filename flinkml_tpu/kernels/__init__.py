@@ -1,6 +1,6 @@
 """Hand-written Pallas (Mosaic) kernels.
 
-**Five kernels run, ungated.** Each is chosen where it applies by an
+**Six kernels run, ungated.** Each is chosen where it applies by an
 ``unsupported_reason`` its caller reads (the backend, the dtype, the
 shapes: no environment variable, no knob), and every other backend runs
 the XLA lowering of the same result:
@@ -16,6 +16,11 @@ the XLA lowering of the same result:
 - :mod:`~flinkml_tpu.kernels.dense_step` — the dense linear step, its
   window read once (``models._linear_sgd.make_dense_step``;
   ``lr-a9a.fit``);
+- :mod:`~flinkml_tpu.kernels.row_update` — a table's rows updated in
+  sorted order, each distinct group of eight read, added to and written
+  once by DMA, many in flight (``models._w2v_table._program``: a TPU,
+  float32 tables in whole groups of rows and whole rows of lanes, one
+  device; ``w2v-1bw.fit``);
 - :mod:`~flinkml_tpu.kernels.spd_solve` — ALS's normal equations, a
   system a lane (``models._als_blocked``; ``als-yahoomusic.fit``);
 - :mod:`~flinkml_tpu.kernels.topk` — exact top-k as ``k`` masked passes

@@ -180,8 +180,9 @@ def _import_keeping_bytecode(names=_TRACING_IMPORTS) -> None:
 def import_beside_host_work() -> None:
     """Start importing Pallas on a thread of its own, on a TPU, where it
     is not imported yet: what a fit whose step may hold a Mosaic kernel
-    (``sparse_blocks``, ``dense_step``) calls where it starts. A fit's
-    first dispatch traces the kernels, and before it comes host work
+    (``sparse_blocks``, ``dense_step``, ``row_update``) calls where it
+    starts. A fit's first dispatch traces the kernels, and before it comes
+    host work
     that is NumPy's (the plan's pass, the seeded permutation and gather:
     0.8 s at ``lr-criteo``'s 16.8 M rows, 0.7 s at ``lr-a9a``'s 9.4 M);
     the import otherwise stands in the fit between its placement's first

@@ -28,7 +28,14 @@ a few rows of two tables and never a ``[vocab, dim]`` array.
   :func:`word2vec._sgns_pair_grads` on them, the gradients times ``-
   learningRate / batch`` added to their rows in place (rows that collide
   are summed): the module's mean-of-batch step, every gradient taken at
-  the step's start.
+  the step's start, so a step's updates commute. On a TPU they go in
+  sorted order (:mod:`flinkml_tpu.kernels.row_update`: a table's (id,
+  contribution) entries sorted by id, stably, and every distinct group of
+  eight rows read, added to and written ONCE, many groups in flight; the
+  output table's contexts and negatives are one list); every other
+  backend keeps XLA's three scatter-adds, a read-modify-write a named
+  row one after another. The same float32 sums either way, in another
+  order.
 - **One program**, ``w2v_sgns_loop``: seed, rate and step count are
   operands. The tables are held ``[vocab, dim rounded up to 128]``: as
   ``[vocab, 300]`` a v5e lays the WORDS along the lanes and re-lays both
@@ -204,10 +211,13 @@ def padded_dim(dim: int) -> int:
 
 
 @functools.lru_cache(maxsize=16)
-def _program(d: Draw, score_dtype=None):
-    """The whole fit on one device, ``w2v_sgns_loop``: ``(v, u [vocab,
+def _program(d: Draw, score_dtype=None, sorted_updates: bool = False):
+    """The whole fit on one device, ``w2v_sgns_loop``: ``(v, u [rows,
     padded_dim], tokens, keep, pool, seed, rate, steps) -> (v, u)``. ``v``
-    and ``u`` are donated: the rows are updated where they lie."""
+    and ``u`` are donated: the rows are updated where they lie, by XLA's
+    scatter-adds or, ``sorted_updates``, by ``kernels.row_update`` (the
+    same sums in the sorted list's order; :func:`fit_table` says where)."""
+    from flinkml_tpu.kernels import row_update
     from flinkml_tpu.models.word2vec import _sgns_pair_grads
 
     def w2v_sgns_loop(v, u, tokens, keep, pool, seed, rate, steps):
@@ -219,6 +229,17 @@ def _program(d: Draw, score_dtype=None):
             grad_vc, grad_uc, grad_un = _sgns_pair_grads(
                 v[c], u[ctx], u[neg], ones, score_dtype=score_dtype)
             scale = -jnp.where(found > 0, rate, 0.0) / d.batch
+            if sorted_updates:
+                # The output rows' entries as ONE list, an ordinal a run
+                # of the batch (a negative's ordinal leads: no [batch, 5,
+                # lanes] array padded to eight sublanes and re-laid).
+                ids = jnp.concatenate([ctx[None], neg.T]).reshape(-1)
+                rows = jnp.concatenate([
+                    (scale * grad_uc)[None],
+                    jnp.moveaxis(scale * grad_un, 1, 0)])
+                return (row_update.add_rows(v, c, scale * grad_vc),
+                        row_update.add_rows(
+                            u, ids, rows.reshape(-1, rows.shape[-1])))
             v = v.at[c].add(scale * grad_vc)
             u = u.at[ctx].add(scale * grad_uc)
             u = u.at[neg.reshape(-1)].add(
@@ -401,11 +422,16 @@ def fit_table(est, table, score_dtype=None):
     """``Word2Vec.fit(Table)``: ``(vocabulary [vocab] str, vectors [vocab,
     dim] float32)`` as the chip returned them. The caller's span ``fit``
     holds all of it. ``score_dtype`` is the benchmark's control's alone."""
+    from flinkml_tpu.kernels import _gate, row_update
     from flinkml_tpu.models import word2vec
 
     name = est.get(est.INPUT_COL)
     mesh = est.mesh or DeviceMesh()
     p = mesh.axis_size()
+    if p == 1:
+        # A TPU's step holds the sorted update's kernel: what tracing it
+        # imports loads beside the ingest.
+        _gate.import_beside_host_work()
     window, min_count = est.get(est.WINDOW_SIZE), est.get(est.MIN_COUNT)
     subsample = float(est.get(est.SUBSAMPLE))
     placed = table.device_resident(
@@ -422,12 +448,18 @@ def fit_table(est, table, score_dtype=None):
         max(1, placed.pairs_an_epoch // batch) * est.get(est.MAX_ITER))
     seed = np.uint32(est.get_seed() & 0xFFFFFFFF)
     rate = np.float32(est.get(est.LEARNING_RATE))
+    # Where the rows are updated in sorted order, a group of eight at a
+    # time, the tables end on a whole group.
+    whole_groups = -(-vocab // row_update.GROUP) * row_update.GROUP
+    sorted_updates = row_update.unsupported_reason(
+        jnp.float32, whole_groups, padded_dim(dim), p) is None
     with span("w2v.init"):
         if p == 1:
+            rows = whole_groups if sorted_updates else vocab
             with jax.default_device(mesh.mesh.devices.flat[0]):
-                v, u = _start_tables(est.get_seed(), vocab, dim, vocab,
+                v, u = _start_tables(est.get_seed(), vocab, dim, rows,
                                      padded_dim(dim))
-            run = _program(d, score_dtype)
+            run = _program(d, score_dtype, sorted_updates)
         else:
             shard_rows = -(-vocab // p)
             v, u = map(mesh.shard_batch, _start_tables(
@@ -448,6 +480,7 @@ def fit_table(est, table, score_dtype=None):
     counters = metrics.group("w2v")
     counters.counter("fits")
     counters.counter("steps", float(steps))
+    counters.counter("sorted_update_steps", float(steps) if sorted_updates else 0.0)
     counters.counter("pairs", float(steps) * batch)
     counters.counter("row_fetches", float(steps) * batch * (2 + negatives))
     counters.counter("row_updates", float(steps) * batch * (2 + negatives))

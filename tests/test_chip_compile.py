@@ -521,22 +521,57 @@ def test_als_half_step_at_the_cells_size(topo, no_compile_cache, side):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
 
 
-@pytest.mark.parametrize("scores", ["float32", "bfloat16"])
+@pytest.mark.parametrize("entries", [300, 1536, 16_384, 98_304])
+def test_the_sorted_row_update_at_the_cells_table(one_chip, no_compile_cache, entries):
+    """``kernels.row_update.add_rows_sorted`` as Mosaic compiles it (not
+    interpreted) on ``w2v-1bw``'s ``[1,115,016, 384]`` table: a group of
+    eight rows is a DMA's slice, the table is aliased through and nothing
+    else is held; at the cell's two lists and at lists shorter than a
+    tile (the chip tiles a vector of int32 by 1,024: an ids block of
+    another length is refused)."""
+    from flinkml_tpu.kernels import row_update
+
+    rows, lanes = 1_115_016, 384
+    update = jax.jit(
+        lambda t, i, r: row_update.add_rows_sorted(t, i, r, interpret=False),
+        donate_argnums=0)
+    with jax.enable_x64(True):
+        compiled = update.trace(
+            jax.ShapeDtypeStruct((rows, lanes), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((entries,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((entries, lanes), jnp.float32, sharding=one_chip),
+        ).lower(lowering_platforms=("tpu",)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == rows * lanes * 4
+    assert memory.temp_size_in_bytes < 8 * 2048 * lanes      # a short list's padding
+
+
+@pytest.mark.parametrize("scores,updates", [
+    ("float32", "sorted"), ("bfloat16", "sorted"), ("float32", "scattered")])
 def test_w2v_whole_fit_at_the_cells_size_holds_no_vocabulary_sized_temporary(
-        one_chip, no_compile_cache, scores):
+        one_chip, no_compile_cache, monkeypatch, scores, updates):
     """``w2v-1bw.fit``'s one program, ``w2v_sgns_loop``: 256 steps of 16,384
-    pairs over the 805,306,368-token corpus and two ``[1,115,011, 384]``
-    tables, as the program and as the benchmark's control rounds the
-    products' operands. The tables are donated and updated row by row: beside
-    its arguments the program holds the draw's frames and the batch's rows
-    (0.38 GB), never a ``[vocab, dim]`` array (1.7 GB; the dense trainer's
-    two gradients a step were 2.7 GB), and the whole stays inside a v5e's
-    16 GB. Every gather and scatter stays XLA's own: a gather of slices the
-    compiler cannot fetch as rows is expanded into a ``while`` of its own
-    (65,536 turns a step, read off this program as first written)."""
+    pairs over the 805,306,368-token corpus and two ``[1,115,016, 384]``
+    tables (1,115,011 words in whole groups of eight rows), as the program
+    and as the benchmark's control rounds the products' operands. The tables
+    are donated and updated where they lie: beside its arguments the program
+    holds the draw's frames and the batch's rows (0.38 GB; 0.41 with the
+    contributions in sorted order), never a ``[vocab, dim]`` array (1.7 GB;
+    the dense trainer's two gradients a step were 2.7 GB), and the whole
+    stays inside a v5e's 16 GB. ``sorted``, what a TPU runs: Mosaic accepts
+    ``kernels.row_update`` twice a step (``v``'s centres, ``u``'s contexts
+    and negatives as one list) at these shapes, aliased through. Every
+    gather stays XLA's own, and ``scattered`` (every other backend's step,
+    compiled for the chip) the three scatter-adds too: a gather of slices
+    the compiler cannot fetch as rows is expanded into a ``while`` of its
+    own (65,536 turns a step, read off this program as first written)."""
+    from flinkml_tpu.kernels import _gate, row_update
     from flinkml_tpu.models import _w2v_table
 
-    vocab, dim, tokens = 1_115_011, 300, 805_306_368
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    vocab, dim, tokens = 1_115_016, 300, 805_306_368
+    assert row_update.unsupported_reason(jnp.float32, vocab, 384) is None
     # 70.27 % of the tokens survive at the cell's corpus: its candidates
     d = _w2v_table.Draw(16_384, 5, 5, _w2v_table.candidates_a_step(
         16_384, tokens, int(0.7027 * 65536 * tokens)), tokens, 100_000_000)
@@ -549,7 +584,8 @@ def test_w2v_whole_fit_at_the_cells_size_holds_no_vocabulary_sized_temporary(
 
     with jax.enable_x64(True):    # as a user with ``jax_enable_x64`` on calls it
         traced = _w2v_table._program(
-            d, None if scores == "float32" else jnp.bfloat16).trace(
+            d, None if scores == "float32" else jnp.bfloat16,
+            updates == "sorted").trace(
             on_chip((vocab, lanes), jnp.float32), on_chip((vocab, lanes), jnp.float32),
             on_chip((rows, 128), jnp.int32), on_chip((rows, 128), jnp.uint16),
             on_chip((100_000_000,), jnp.int32), on_chip((), jnp.uint32),
@@ -562,6 +598,7 @@ def test_w2v_whole_fit_at_the_cells_size_holds_no_vocabulary_sized_temporary(
         compiled = traced.lower().compile()
     text = compiled.as_text()
     assert len(re.findall(r"= \([^\n]*\) while\(", text)) == 1
+    assert text.count("tpu_custom_call") == (2 if updates == "sorted" else 0)
     memory = compiled.memory_analysis()
     table = vocab * lanes * 4
     assert memory.temp_size_in_bytes < 0.3 * table

@@ -221,6 +221,43 @@ def test_negatives_follow_count_to_the_three_quarters_over_a_large_vocabulary():
     assert drawn.max() > vocab // 2                     # the tail is reached
 
 
+@pytest.mark.parametrize("rate", [0.025, 0.0125])
+def test_a_fit_through_the_sorted_update_is_the_plain_fit_and_the_references(
+        rate, monkeypatch):
+    """The step a TPU runs: the rows' entries sorted by id and added a
+    group of eight at a time by ``kernels.row_update`` (interpreted here),
+    tables padded to whole groups, near the rehearsal's size (3,997 words,
+    the cell's batch of 16,384) against the fit as a CPU runs it and
+    against the float64 replay; ``w2v.sorted_update_steps`` says which
+    ran. The same sums in another order (the plain step adds a pair's
+    context, then its negatives a pair a run; the sorted list holds the
+    contexts, then the negatives an ordinal a run): float32's rounding
+    apart, nothing more."""
+    from flinkml_tpu.kernels import row_update
+
+    vocab, batch, steps = 3997, 16_384, 3
+    indptr, ids = _corpus(seed=7, vocab=vocab, tokens=400_000)
+    count = lambda: metrics.group("w2v").snapshot()["counters"].get(
+        "sorted_update_steps", 0.0)
+    before = count()
+    est = lambda: _estimator(batch=batch, steps=steps, rate=rate)
+    plain = est().fit(_table(indptr, ids, vocab)).word_vectors()
+    assert count() == before
+    monkeypatch.setattr(row_update, "unsupported_reason",
+                        lambda dtype, rows, lanes, devices=1:
+                        None if devices == 1 else "the exchange's")
+    model = est().fit(_table(indptr, ids, vocab))
+    assert count() - before == steps
+    got = model.word_vectors()
+    c, start, want, _ = _replay(indptr, ids, rate, batch=batch, steps=steps,
+                                vocab=vocab)
+    assert c.order.size % row_update.GROUP          # the tables were padded
+    assert got.shape == plain.shape == (c.order.size, DIM)
+    assert _gap(got, want, start) < TOL
+    assert _gap(got, plain.astype(np.float64), start) < TOL
+    assert not np.array_equal(plain, start)
+
+
 @pytest.mark.parametrize("p", [4, 8])
 def test_the_sharded_trainer_ties_to_the_one_device_step(p):
     """Tables row-sharded, the exchange: the same draw, every device its
