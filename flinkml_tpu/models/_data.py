@@ -84,9 +84,18 @@ class LabelFacts:
 
     The fits' label checks answer from these. The distinct values
     themselves (:meth:`distinct`: the one sort of the column) are asked
-    for only where the labels are not all 0 or 1."""
+    for only where the labels are not all 0 or 1.
 
-    __slots__ = ("values", "binary", "lo", "hi", "integral", "_distinct")
+    A table-level fit takes its table's (:meth:`of`): made at the first
+    fit of a ``Table`` and a label column, kept with the table as its
+    placement is, and found by every later fit, whose label checks all
+    still run and answer from it. A ``Table`` is immutable by its own
+    contract: a caller who writes into a column's array in place after a
+    fit already trains on a stale placement, and gets stale facts by the
+    same act."""
+
+    __slots__ = ("values", "binary", "lo", "hi", "integral", "_distinct",
+                 "__weakref__")
 
     def __init__(self, column):
         y = np.asarray(column).reshape(-1)
@@ -109,6 +118,25 @@ class LabelFacts:
             if not exact and self.integral:
                 self.integral = bool(np.all(part == np.rint(part)))
 
+    @classmethod
+    def of(cls, table: Table, label_col: str) -> "LabelFacts":
+        """The facts of ``table``'s label column, kept with the table
+        (:meth:`Table.host_kept`). ``metrics.group("hostdata")`` counts
+        ``label_facts_kept`` (found) and ``label_facts_made`` (the pass
+        was run), beside ``placement_hits`` and ``placement_misses``."""
+        kept = True
+
+        def make():
+            nonlocal kept
+            kept = False
+            return cls(table.column(label_col))
+
+        facts = table.host_kept(("label_facts", label_col), make)
+        counts = metrics.group("hostdata")
+        counts.counter("label_facts_kept", float(kept))
+        counts.counter("label_facts_made", float(not kept))
+        return facts
+
     def distinct(self) -> np.ndarray:
         """The sorted distinct labels (``np.unique`` over the column,
         floating as every fit has printed them; a fit that asks counts
@@ -124,10 +152,10 @@ class LabelFacts:
 
 def _label_and_weight_columns(table: Table, label_col: str,
                               weight_col: Optional[str], rows: int):
-    """``(LabelFacts, weights)`` as the table holds them; ``weights`` is
-    None where there is no weight column (the trainer makes unit weights
-    on the device)."""
-    labels = LabelFacts(table.column(label_col))
+    """``(LabelFacts, weights)`` as the table holds them (the facts kept
+    with it: :meth:`LabelFacts.of`); ``weights`` is None where there is
+    no weight column (the trainer makes unit weights on the device)."""
+    labels = LabelFacts.of(table, label_col)
     _check_rows(label_col, labels.values, rows)
     if weight_col is None:
         return labels, None
@@ -142,8 +170,10 @@ def fit_columns(table: Table, features_col: str, label_col: str,
 
     Every column is taken as the table has it (a contiguous floating
     features column is not copied); ``labels`` is the column's
-    :class:`LabelFacts`, read once here; ``w`` is None without a weight
-    column. The whole of it is the fit's ``hostdata.ingest`` span."""
+    :class:`LabelFacts`, read once a table (:meth:`LabelFacts.of`: a
+    later fit of the table looks them up); ``w`` is None without a
+    weight column. The whole of it is the fit's ``hostdata.ingest``
+    span."""
     with span("hostdata.ingest"):
         x = features_matrix(table, features_col, dtype=None)
         labels, w = _label_and_weight_columns(

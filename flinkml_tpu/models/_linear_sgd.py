@@ -584,21 +584,21 @@ def _run_chunked(
     from flinkml_tpu.iteration.checkpoint import begin_resume
 
     resume_epoch = begin_resume(checkpoint_manager, resume, mesh.mesh.size)
-    coef = jnp.zeros(dim, dtype=dt)
+    # The start carry and the hyper-parameters are host values of the
+    # loop's own dtypes: the carry reaches the mesh in ONE ``device_put``,
+    # the scalars as operands of the dispatches the fit makes anyway, and
+    # no device operation of their own comes before the loop.
+    coef = np.zeros(dim, dtype=dt)
     epoch = 0
     cur_loss = float("inf")
     if resume_epoch is not None:
         coef_h, epoch, cur_loss = _restore_carry(
             checkpoint_manager, dim, dt, mesh
         )
-        coef = jnp.asarray(coef_h, dt)
+        coef = np.asarray(coef_h, dtype=dt)
 
-    hy = (
-        jnp.asarray(learning_rate, dt),
-        jnp.asarray(reg_l2, dt),
-        jnp.asarray(reg_l1, dt),
-        jnp.asarray(tol, dt),
-    )
+    hy = tuple(np.asarray(v, dtype=dt)
+               for v in (learning_rate, reg_l2, reg_l1, tol))
     first = epoch
     rounds = place(first, max_iter)
     at_host = checkpoint_manager is not None or bool(listeners)
@@ -609,9 +609,11 @@ def _run_chunked(
         rounds = ((data_args, min(end, max_iter))
                   for end in range(first + chunk, max_iter + chunk, chunk))
     # On the mesh as the trainer returns it, so that a chunk entered from
-    # the chunk before is the program the first one compiled.
-    carry = mesh.replicate(
-        (coef, jnp.asarray(epoch, jnp.int32), jnp.asarray(cur_loss, dt)))
+    # the chunk before is the program the first one compiled (a host
+    # operand and a returned carry are two signatures to ``jax.jit``).
+    carry = jax.device_put(
+        (coef, np.asarray(epoch, np.int32), np.asarray(cur_loss, dt)),
+        mesh.replicated_sharding())
     # Steps [first, before) went out ahead of the last dispatch.
     before = sent = first
     with span("trainer.loop"):

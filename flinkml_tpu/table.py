@@ -720,6 +720,8 @@ class Table:
         # column uploaded to device) is converted at most once per Table.
         self._host_cache: Dict[str, np.ndarray] = {}
         self._device_cache: Dict[str, Any] = {}
+        # What a fit read off a host column (:meth:`host_kept`).
+        self._host_kept: Dict[tuple, Any] = {}
 
     # -- construction ------------------------------------------------------
     @staticmethod
@@ -914,6 +916,18 @@ class Table:
         room for and keep in steps of the caller's own (a linear fit
         keeps its placement only once its last round has landed)."""
         return ResidentSlot(self, key)
+
+    def host_kept(self, key: tuple, make):
+        """What ``make()`` read off this table's host columns, kept WITH
+        the table under ``key`` and found again by every later call: the
+        host's side of :meth:`device_resident` (a label column's
+        ``models._data.LabelFacts``: a view of the column and a few
+        scalars). Tables are immutable, so it cannot go stale. It holds
+        no device bytes: it is no entry of :class:`_ResidentSet`, is
+        never let go for room, and goes when the table does."""
+        if key not in self._host_kept:
+            self._host_kept[key] = make()
+        return self._host_kept[key]
 
     # -- relational ops ----------------------------------------------------
     # Zero-copy on device-backed columns: buffers are rebound, never fetched.

@@ -183,8 +183,9 @@ def test_fit_produces_each_fit_span_once(monkeypatch, stage_bytes, weight_col):
     assert _calls(d) == {**FIT_SPANS, "hostdata.shuffle": 1 + rounds,
                          "hostdata.stage_wait": rounds,
                          "mesh.shard_batch": rounds}
-    # the table's first fit: its placement is a miss, and is kept (PR 37)
-    assert made == {"placement_misses": 1, **(
+    # the table's first fit: its placement is a miss, and is kept (PR 37);
+    # so are the label facts its ingest made (PR 46)
+    assert made == {"placement_misses": 1, "label_facts_made": 1, **(
         {"unit_weights_on_device": 1} if weight_col is None else {})}
     # What was placed: the columns in lockstep, round by round (the last
     # round steps back over rows already sent), padded to the mesh, at
@@ -652,6 +653,7 @@ def test_a_fit_that_finds_its_placement_opens_no_placement_span(kind):
     with _delta("hostdata") as first:
         _fit(table)
     assert first["placement_misses"] == 1 and "placement_hits" not in first
+    assert first["label_facts_made"] == 1 and "label_facts_kept" not in first
     (entry,) = [v for k, v in table._device_cache.items() if isinstance(k, tuple)]
     gauges = metrics.group("hostdata").snapshot()["gauges"]
     assert gauges["placement_kept_bytes"] == kept + sum(
@@ -661,7 +663,8 @@ def test_a_fit_that_finds_its_placement_opens_no_placement_span(kind):
         _fit(table)
     assert _calls(d) == {"fit": 1, "hostdata.ingest": 1, "trainer.loop": 1,
                          "trainer.readback": 1}
-    assert made == {"placement_hits": 1} and staged == {}
+    # the ingest span brackets a lookup: the table's facts (PR 46)
+    assert made == {"placement_hits": 1, "label_facts_kept": 1} and staged == {}
     assert _self_sum(d) == pytest.approx(d["fit.seconds"], rel=1e-9)
     assert d["trainer.loop.self_seconds"] == d["trainer.loop.seconds"]
     del entry, table
