@@ -1,6 +1,6 @@
 """Hand-written Pallas (Mosaic) kernels.
 
-**Six kernels run, ungated.** Each is chosen where it applies by an
+**Seven kernels run, ungated.** Each is chosen where it applies by an
 ``unsupported_reason`` its caller reads (the backend, the dtype, the
 shapes: no environment variable, no knob), and every other backend runs
 the XLA lowering of the same result:
@@ -21,6 +21,11 @@ the XLA lowering of the same result:
   once by DMA, many in flight (``models._w2v_table._program``: a TPU,
   float32 tables in whole groups of rows and whole rows of lanes, one
   device; ``w2v-1bw.fit``);
+- :mod:`~flinkml_tpu.kernels.gbt_hist` — a tree level's (node, feature,
+  bin) sums of gradients and hessians as one-hot products, a feature's
+  one-hot never outside fast memory (``models._gbt_table._program``: a
+  TPU, float32 statistics, uint8 bins, a device's rows in whole tiles;
+  ``gbt-airline.fit``);
 - :mod:`~flinkml_tpu.kernels.spd_solve` — ALS's normal equations, a
   system a lane (``models._als_blocked``; ``als-yahoomusic.fit``);
 - :mod:`~flinkml_tpu.kernels.topk` — exact top-k as ``k`` masked passes

@@ -3,14 +3,23 @@
 A major model family beyond the reference snapshot, designed TPU-first
 rather than translated from CPU tree libraries:
 
-  - **Quantile binning** (host, once): each feature → int32 bin ids in
-    ``[0, maxBins)`` via per-feature quantile edges — the LightGBM/
-    HistGradientBoosting layout. Raw thresholds are recovered from the
-    edges so inference needs no binning.
+  - **Quantile binning** (host, once a ``Table``): each feature → bin ids
+    in ``[0, maxBins)`` via per-feature quantile edges — the LightGBM/
+    HistGradientBoostingClassifier layout. On a dense column
+    (:mod:`~flinkml_tpu.models._gbt_table`) the edges are taken over a
+    seeded sample of rows (:func:`bin_edges`), the bins are one byte a
+    cell, features-major, and the binned table stays on the chip with
+    its ``Table``. Raw thresholds are recovered from the edges so
+    inference needs no binning.
   - **Level-wise growth with static shapes**: every tree is a complete
     binary tree of depth ``maxDepth`` (heap layout). Each level computes
-    ALL (node, feature, bin) gradient/hessian histograms as ONE
-    ``segment_sum`` over ``n·d`` keys, cumulative-sums over bins, and
+    ALL (node, feature, bin) gradient/hessian histograms at once — on a
+    dense column as one-hot products over tiles of rows
+    (:mod:`~flinkml_tpu.kernels.gbt_hist` on a TPU); for hashed sparse
+    input (hundreds to thousands of bundled features, made on the host a
+    fit) as ONE ``segment_sum`` over ``n·d`` keys
+    (:func:`_forest_builder`, with the ``FLINKML_TPU_GBT_HISTOGRAM``
+    gate) — cumulative-sums over bins, and
     picks every node's best split with one argmax — no per-node
     recursion, no data-dependent shapes, XLA-friendly end to end.
   - **Whole-boosting-run on device**: trees are built inside a single
@@ -51,9 +60,16 @@ from flinkml_tpu.models._data import (
     labeled_data,
     sparse_features,
 )
+from flinkml_tpu.models._gbt_table import (  # noqa: F401
+    best_splits,
+    bin_edges,
+    bin_features,
+    quantile_bin_edges,
+)
 from flinkml_tpu.params import FloatParam, IntParam, ParamValidators
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.profiling import span
 
 
 class _GBTParams(
@@ -97,32 +113,7 @@ class _GBTParams(
     )
 
 
-# -- binning ------------------------------------------------------------------
-
-def quantile_bin_edges(x: np.ndarray, max_bins: int) -> np.ndarray:
-    """Per-feature interior quantile edges, padded with +inf to a fixed
-    ``[d, max_bins - 1]`` (duplicate quantiles collapse, so features with
-    few distinct values just use fewer real edges)."""
-    n, d = x.shape
-    qs = np.linspace(0, 1, max_bins + 1)[1:-1]
-    edges = np.full((d, max_bins - 1), np.inf)
-    for j in range(d):
-        e = np.unique(np.quantile(x[:, j], qs))
-        e = e[np.isfinite(e)]
-        edges[j, : len(e)] = e
-    return edges
-
-
-def bin_features(x: np.ndarray, edges: np.ndarray) -> np.ndarray:
-    """bin = #{edges < x} per feature; ``bin <= b  ⟺  x <= edges[b]``."""
-    n, d = x.shape
-    out = np.empty((n, d), dtype=np.int32)
-    for j in range(d):
-        out[:, j] = np.searchsorted(edges[j], x[:, j], side="left")
-    return out
-
-
-# -- device forest builder ----------------------------------------------------
+# -- device forest builder (hashed sparse input's) ----------------------------------------------------
 
 
 def _hist_layout() -> str:
@@ -279,35 +270,9 @@ def _forest_builder(mesh, axis: str, n_feat: int, n_bins: int, depth: int,
                         jnp.repeat(h, n_feat), ids, num_segments=seg), axis)
                     hg = hg.reshape(n_leaves, n_feat, n_bins)
                     hh = hh.reshape(n_leaves, n_feat, n_bins)
-                gl = jnp.cumsum(hg, axis=2)
-                hl = jnp.cumsum(hh, axis=2)
-                gt = gl[:, :, -1:]
-                ht = hl[:, :, -1:]
-                gr = gt - gl
-                hr = ht - hl
-                gain = (
-                    gl * gl / (hl + lam) + gr * gr / (hr + lam)
-                    - gt * gt / (ht + lam)
-                )
-                # Splits with an empty side are not real splits — and with
-                # lam == 0 their 0/0 gain would be NaN, which argmax treats
-                # as the maximum (silently training a useless forest).
-                gain = jnp.where((hl > 0) & (hr > 0), gain, 0.0)
-                # The last bin's "split" sends everything left: force its
-                # gain to 0 so argmax prefers real splits.
-                gain = gain.at[:, :, -1].set(0.0)
-                # Per-tree feature subset (bagging): -inf, NOT a zero
-                # multiply — zeroed gains would still beat negative
-                # in-subset gains (possible under regLambda) and leak
-                # excluded features into the forest.
-                gain = jnp.where(
-                    fmask[None, :, None] > 0, gain, -jnp.inf
-                )
-                flat_gain = gain.reshape(n_leaves, n_feat * n_bins)
-                best = jnp.argmax(flat_gain, axis=1)
-                best_gain = jnp.maximum(jnp.max(flat_gain, axis=1), 0.0)
-                bf = (best // n_bins).astype(jnp.int32)     # [n_leaves]
-                bb = (best % n_bins).astype(jnp.int32)
+                # The module's one split finding (cumulative sums, gains,
+                # empty sides and the last bin at 0, the subset's -inf).
+                bf, bb, best_gain, *_ = best_splits(hg, hh, lam, fmask)
                 start = (1 << level) - 1
                 idx = start + jnp.arange(1 << level)
                 feat_arr = feat_arr.at[idx].set(bf[: 1 << level])
@@ -373,6 +338,16 @@ def _forest_builder(mesh, axis: str, n_feat: int, n_bins: int, depth: int,
             out_specs=(P(), P(), P(), P()),
         )
     )
+
+
+def _thresholds(edges: np.ndarray, feats: np.ndarray,
+                bins: np.ndarray) -> np.ndarray:
+    """Raw thresholds: split "bin <= b" ⟺ "x <= edges[f, b]" (the last
+    bin has threshold +inf: everything goes left)."""
+    edges_inf = np.concatenate(
+        [edges, np.full((edges.shape[0], 1), np.inf)], axis=1
+    )
+    return edges_inf[feats, np.minimum(bins, edges_inf.shape[1] - 1)]
 
 
 def _walk_forest_per_tree(x: np.ndarray, feats, thrs, leaves,
@@ -471,27 +446,39 @@ class _GBTBase(StreamingEstimatorMixin, _GBTParams, Estimator):
         )
         return x, y, w, n_hash
 
+    def _holdout_rows(self, n: int):
+        """``(held, train)``: the rows of ``validationFraction``'s holdout
+        and the rest, a seeded permutation's two ends; None where there
+        is no holdout."""
+        vf = self.get(self.VALIDATION_FRACTION)
+        if vf <= 0:
+            return None
+        if not self._BOOSTING:
+            raise ValueError(
+                "validationFraction applies to boosted estimators only "
+                "(bagged forests don't overfit with more trees)"
+            )
+        perm = np.random.default_rng(self.get_seed()).permutation(n)
+        n_hold = max(1, int(round(vf * n)))
+        if n_hold >= n:
+            raise ValueError("validationFraction leaves no training rows")
+        return perm[:n_hold], perm[n_hold:]
+
     def _fit_forest(self, table: Table):
+        """Hashed sparse input's fit: the bundled columns made, binned
+        (int32, row-major) and placed a fit, :func:`_forest_builder`'s
+        histograms. (A dense column also runs, as the tests' second
+        opinion of :meth:`_fit_table`.)"""
         x, y, w, hash_features = self._labeled_maybe_hashed(table)
         if self._LOGISTIC:
             # Validate on the FULL label column, before any holdout split
             # (an invalid label permuted into the holdout would silently
             # corrupt the early-stopping loss instead of raising).
             check_binary_labels(y, type(self).__name__)
-        vf = self.get(self.VALIDATION_FRACTION)
         holdout = None
-        if vf > 0:
-            if not self._BOOSTING:
-                raise ValueError(
-                    "validationFraction applies to boosted estimators only "
-                    "(bagged forests don't overfit with more trees)"
-                )
-            rng = np.random.default_rng(self.get_seed())
-            perm = rng.permutation(x.shape[0])
-            n_hold = max(1, int(round(vf * x.shape[0])))
-            if n_hold >= x.shape[0]:
-                raise ValueError("validationFraction leaves no training rows")
-            hold_idx, train_idx = perm[:n_hold], perm[n_hold:]
+        rows = self._holdout_rows(x.shape[0])
+        if rows is not None:
+            hold_idx, train_idx = rows
             holdout = (x[hold_idx], y[hold_idx], w[hold_idx])
             x, y, w = x[train_idx], y[train_idx], w[train_idx]
         if self._LOGISTIC:
@@ -532,12 +519,7 @@ class _GBTBase(StreamingEstimatorMixin, _GBTParams, Estimator):
         )
         feats = np.asarray(feats)
         bins = np.asarray(bins)
-        # Raw thresholds: split "bin <= b" ⟺ "x <= edges[f, b]" (the last
-        # bin has threshold +inf: everything goes left).
-        edges_inf = np.concatenate(
-            [edges, np.full((edges.shape[0], 1), np.inf)], axis=1
-        )
-        thrs = edges_inf[feats, np.minimum(bins, edges_inf.shape[1] - 1)]
+        thrs = _thresholds(edges, feats, bins)
         gains = np.asarray(gains)
         leaves = np.asarray(leaves)
         if holdout is not None:
@@ -546,6 +528,27 @@ class _GBTBase(StreamingEstimatorMixin, _GBTParams, Estimator):
             )
         return (feats, thrs, gains, leaves, base, depth, x.shape[1],
                 hash_features)
+
+    def _fit_table(self, table: Table):
+        """The fit on a dense features column (:mod:`~flinkml_tpu.models.
+        _gbt_table`): the binned table kept with ``table``, the whole
+        forest one program; raw thresholds from the edges. A holdout's
+        rows stay in the table at weight 0 (and out of the edges)."""
+        from flinkml_tpu.models import _gbt_table
+
+        rows = self._holdout_rows(table.num_rows)
+        held = None if rows is None else np.sort(rows[0])
+        feats, bins, gains, leaves, base, edges = _gbt_table.fit_table(
+            self, table, held=held)
+        thrs = _thresholds(edges, feats, bins)
+        depth = self.get(self.MAX_DEPTH)
+        if held is not None:
+            x, y, w = labeled_data(
+                table.take(held), self.get(self.FEATURES_COL),
+                self.get(self.LABEL_COL), self.get(self.WEIGHT_COL))
+            feats, thrs, gains, leaves = self._truncate_to_best_prefix(
+                (x, y, w), feats, thrs, gains, leaves, base, depth)
+        return (feats, thrs, gains, leaves, base, depth, edges.shape[0], 0)
 
     def _truncate_to_best_prefix(self, holdout, feats, thrs, gains, leaves,
                                  base, depth):
@@ -639,10 +642,7 @@ class _GBTBase(StreamingEstimatorMixin, _GBTParams, Estimator):
             reservoir_capacity=self.stream_reservoir_capacity,
             **self._checkpoint_kwargs(),
         )
-        edges_inf = np.concatenate(
-            [edges, np.full((edges.shape[0], 1), np.inf)], axis=1
-        )
-        thrs = edges_inf[feats, np.minimum(bins, edges_inf.shape[1] - 1)]
+        thrs = _thresholds(edges, feats, bins)
         hash_features = (
             0 if isinstance(source, DataCache) else (hash_seen[0] or 0)
         )
@@ -658,7 +658,11 @@ class _GBTBase(StreamingEstimatorMixin, _GBTParams, Estimator):
                 "the in-RAM fit builds the whole forest in one device "
                 "program"
             )
-            forest = self._fit_forest(table)
+            with span("fit"):
+                if sparse_features(table, self.get(self.FEATURES_COL)) is not None:
+                    forest = self._fit_forest(table)
+                else:
+                    forest = self._fit_table(table)
         else:
             forest = self._fit_stream_forest(table)
         (feats, thrs, gains, leaves, base, depth, n_features,

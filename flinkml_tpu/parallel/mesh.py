@@ -307,16 +307,18 @@ class DeviceMesh:
                 counts.counter("rows_sent", float(p * min(chunk, reach - r * chunk)))
                 yield placed, offset + chunk
 
-    def shard_ones(self, n: int, dtype) -> jax.Array:
+    def shard_ones(self, n: int, dtype, total: Optional[int] = None) -> jax.Array:
         """``shard_batch(pad_to_multiple(np.ones(n, dtype), p)[0])``, made
         on the device: 1 at the positions below ``n``, 0 at the padding.
         The unit weights of a table with no weight column, which on the
         host were a vector built, permuted into itself and uploaded.
+        ``total`` (a multiple of ``p``) is the length where the caller
+        pads further than to ``p`` (a tree fit's whole tiles a device).
         Multi-process it is one SPMD program every process calls."""
         p = self.axis_size(self.DATA_AXIS)
         dt = np.dtype(jax.dtypes.canonicalize_dtype(dtype))
         ones = _ones_below(self.mesh, self.DATA_AXIS)
-        return ones(np.int32(n), p * -(-n // p), dt)
+        return ones(np.int32(n), p * -(-n // p) if total is None else total, dt)
 
     def replicate(self, tree):
         """Replicate a pytree of arrays onto every device (broadcast-model)."""

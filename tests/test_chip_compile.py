@@ -605,3 +605,92 @@ def test_w2v_whole_fit_at_the_cells_size_holds_no_vocabulary_sized_temporary(
     assert memory.alias_size_in_bytes >= 2 * table       # both updated in place
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 0.5 * 16e9 < held < 0.65 * 16e9
+
+
+@pytest.mark.parametrize("features,nodes", [(13, 1), (13, 32), (13, 128), (100, 16)])
+def test_gbt_level_kernel_at_the_cells_size(one_chip, no_compile_cache, features, nodes):
+    """``kernels.gbt_hist`` by itself over ``gbt-airline``'s 115,343,360 x
+    13 one-byte bins: a level of 1 node, of 32 (depth 6's last: 192 columns,
+    two MXU tiles) and of 128 (depth 8's last, the most it takes at 13
+    features), and the widest level it takes of a table of 100 features
+    (``vmem_bytes`` against its limit: Mosaic has to agree). It takes the
+    uint8 block (the array's own count of rows), the 32-bit copy in scratch
+    whose rows a ``fori_loop`` over the features reads by a dynamic sublane,
+    and the product that contracts the lanes of both operands; beside its
+    arguments the program holds the sums and their re-ordered copy."""
+    from flinkml_tpu.kernels import gbt_hist
+
+    rows = 115_343_360 if features == 13 else 1 << 22
+    assert gbt_hist.tile_rows(rows) == gbt_hist.TILE
+    assert gbt_hist.vmem_bytes(features, nodes, gbt_hist.TILE) <= gbt_hist.VMEM_LIMIT_BYTES
+    if (features, nodes) in ((13, 128), (100, 16)):     # the widest it takes
+        assert gbt_hist.vmem_bytes(features, 2 * nodes, gbt_hist.TILE) > (
+            gbt_hist.VMEM_LIMIT_BYTES)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.enable_x64(True):
+        traced = jax.jit(lambda b, g, h, n: gbt_hist.level_histograms(
+            b, g, h, n, nodes, interpret=False)).trace(
+            on_chip((features, rows), jnp.uint8), on_chip((rows,), jnp.float32),
+            on_chip((rows,), jnp.float32), on_chip((rows,), jnp.int32))
+        assert not re.search(r"\b[fiu]64\[", str(traced.jaxpr))
+        compiled = traced.lower().compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64e6
+    if features == 13:
+        # the bins lie 13 rows in 16: 1.85 GB, and three rows of 0.46 GB
+        assert 16 * rows + 12 * rows <= memory.argument_size_in_bytes < 17 * rows + 12 * rows
+
+
+@pytest.mark.parametrize("histograms", ["kernel", "xla"])
+def test_gbt_forest_at_the_cells_size_holds_no_rows_by_features_array_wider_than_a_byte(
+        topo, no_compile_cache, monkeypatch, histograms):
+    """``gbt-airline.fit``'s one program, ``gbt_forest``: two trees of depth
+    6 over 115,343,360 x 13 bins, on a one-chip mesh (the ``psum``
+    included), with the Mosaic product a level (what a TPU runs: six
+    kernels, the two trees one ``scan``) and with XLA's chunked product
+    (every other backend's, compiled for the chip). Neither makes an array
+    of ``rows x features`` entries wider than a byte (the parent's three
+    were 6 GB each a level), nor one of ``rows x bins``; beside the table,
+    the labels and the weights the program holds rows of 0.46 GB
+    (prediction, gradients, hessians, node, the draw's), inside a v5e's 16
+    GB."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.models import _gbt_table
+    from flinkml_tpu.parallel import DeviceMesh
+
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    rows, features = 115_343_360, 13
+    mesh = Mesh(np.array(topo.devices[:1]), (DeviceMesh.DATA_AXIS,))
+
+    def on_mesh(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    with jax.enable_x64(True):    # as a user with ``jax_enable_x64`` on calls it
+        traced = _gbt_table._program(
+            mesh, DeviceMesh.DATA_AXIS, features, 256, 6, 2, True, True, 0, False,
+            (histograms == "kernel",) * 6).trace(
+            on_mesh((features, rows), jnp.uint8, None, DeviceMesh.DATA_AXIS),
+            on_mesh((rows,), jnp.float32, DeviceMesh.DATA_AXIS),
+            on_mesh((rows,), jnp.float32, DeviceMesh.DATA_AXIS),
+            *[on_mesh((), jnp.float32)] * 4, on_mesh((2,), jnp.uint32))
+        compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (6 if histograms == "kernel" else 0)
+    itemsize = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2}
+    wide = set()
+    for dtype, dims in re.findall(r"\b(pred|[suf]\d+|bf16)\[([\d,]+)\]", text):
+        entries = np.prod([int(n) for n in dims.split(",")], dtype=np.float64)
+        if entries >= rows * features and itemsize.get(dtype, 4) > 1:
+            wide.add(f"{dtype}[{dims}]")
+    assert wide == set()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 0.25 * 16e9 < held < 0.75 * 16e9

@@ -762,6 +762,46 @@ def test_a_word2vec_fits_spans_are_siblings_in_order_and_a_second_fit_opens_fewe
                        "tokens": 20_000}
 
 
+def _binary_table(seed=0, rows=1_500, features=5):
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(rows, features)).astype(np.float32)
+    return Table({"features": x, "label": (x[:, 0] * x[:, 1] > 0).astype(np.float32)})
+
+
+def test_a_tree_fits_spans_are_siblings_in_order_and_a_second_fit_opens_fewer(tmp_path):
+    """``GBTClassifier.fit(Table)`` on a dense column (PR 47):
+    ``gbt.ingest``, ``gbt.table_to_device``, ``gbt.loop`` (holding
+    ``gbt.dispatch``) and ``gbt.readback`` one after another inside
+    ``fit``; a second fit of the table bins and places nothing, and the
+    tree still adds up."""
+    from flinkml_tpu.models import GBTClassifier
+
+    one = DeviceMesh(devices=jax.devices()[:1])
+    fit = lambda t: (GBTClassifier(mesh=one).set_num_trees(2).set_max_depth(3)
+                     .set_max_bins(16).fit(t))
+    fit(_binary_table(seed=1))  # compiled before the profile, on a table of its own
+    table = _binary_table()
+    (fit_start, fit_end, name), *phases = _profiled_spans(tmp_path, lambda: fit(table))
+    assert name == "fit"
+    gbt = [ph for ph in phases if ph[2].startswith("gbt.")]
+    assert [n for _, _, n in gbt] == ["gbt.ingest", "gbt.table_to_device", "gbt.loop",
+                                      "gbt.dispatch", "gbt.readback"]
+    spans = {n: (a, b) for a, b, n in gbt}
+    loop, dispatch = spans["gbt.loop"], spans["gbt.dispatch"]
+    assert loop[0] <= dispatch[0] <= dispatch[1] <= loop[1]
+    end = fit_start
+    for start, stop, n in gbt:
+        if n != "gbt.dispatch":
+            assert end <= start <= stop <= fit_end, n
+            end = stop
+    with _delta() as d, _delta("gbt") as counted:
+        fit(table)
+    assert set(_calls(d)) == {"fit", "gbt.loop", "gbt.dispatch", "gbt.readback"}
+    assert _self_sum(d) == pytest.approx(d["fit.seconds"], rel=1e-9)
+    assert counted == {"fits": 1, "trees": 2, "levels": 6, "rows": 1_500,
+                       "hist_cells": 6 * 1_536 * 5}     # product_levels: a CPU's 0
+
+
 def _struct(shape, dtype, sharding=None):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -856,6 +896,18 @@ def _lowered_programs():
             _struct((10, 128), i32), _struct((10, 128), u16), _struct((4096,), i32),
             _struct((), u32), _struct((), f32), _struct((), i32)).as_text()
 
+    def gbt_forest():
+        from flinkml_tpu.models import _gbt_table
+
+        rep, rows = shardings()
+        p = len(jax.devices())
+        across = jax.sharding.NamedSharding(m(), jax.sharding.PartitionSpec(None, "data"))
+        return _gbt_table._program(
+            m(), "data", 5, 16, 3, 2, True, True, 0, 3, (False,) * 3).lower(
+            _struct((5, 128 * p), jnp.uint8, across), _struct((128 * p,), f32, rows),
+            _struct((128 * p,), f32, rows), *[_struct((), f32, rep)] * 4,
+            _struct((2,), jnp.uint32, rep)).as_text()
+
     def knn_vote():
         return knn._knn_vote.lower(
             _struct((16, 5), f32), _struct((64, 5), f32), _struct((64,), f32),
@@ -896,6 +948,7 @@ def _lowered_programs():
         "fm_adam_loop": fm_adam_loop,
         "als_half_step": als_half_step,
         "w2v_sgns_loop": w2v_sgns_loop,
+        "gbt_forest": gbt_forest,
         "knn_vote": knn_vote,
         "rows_sq": lambda: blas.squared_norms.lower(_struct((64, 5), f32)).as_text(),
         "fused_chain": fused_chain,
@@ -904,7 +957,7 @@ def _lowered_programs():
 
 PROGRAMS = ("lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop", "stage_write",
             "stage_zeros", "stage_ones", "kmeans_lloyd", "fm_adam_loop", "knn_vote",
-            "rows_sq", "fused_chain", "als_half_step", "w2v_sgns_loop")
+            "rows_sq", "fused_chain", "als_half_step", "w2v_sgns_loop", "gbt_forest")
 
 
 @pytest.mark.parametrize("name", PROGRAMS)
