@@ -23,7 +23,9 @@ the XLA lowering of the same result:
   device; ``w2v-1bw.fit``);
 - :mod:`~flinkml_tpu.kernels.gbt_hist` — a tree level's (node, feature,
   bin) sums of gradients and hessians as one-hot products, a feature's
-  one-hot never outside fast memory (``models._gbt_table._program``: a
+  one-hot never outside fast memory, the bin's low bit folded into the
+  product's columns where that is fewer MXU passes (1 to 8 nodes and 32:
+  ``gbt_hist.fold``) (``models._gbt_table._program``: a
   TPU, float32 statistics, uint8 bins, a device's rows in whole tiles;
   ``gbt-airline.fit``);
 - :mod:`~flinkml_tpu.kernels.spd_solve` — ALS's normal equations, a

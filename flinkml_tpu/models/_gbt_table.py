@@ -476,6 +476,9 @@ def fit_table(est, table, *, held: Optional[np.ndarray] = None,
     counters.counter("trees", float(num_trees))
     counters.counter("levels", levels)
     counters.counter("product_levels", float(num_trees * sum(product_levels)))
+    counters.counter("folded_levels", float(num_trees * sum(
+        taken and gbt_hist.fold(1 << level)
+        for level, taken in enumerate(product_levels))))
     counters.counter("rows", float(placed.rows))
     counters.counter("hist_cells", levels * placed.bins.shape[1] * n_feat)
     return feats, bins, gains, leaves, base, placed.edges

@@ -607,13 +607,16 @@ def test_w2v_whole_fit_at_the_cells_size_holds_no_vocabulary_sized_temporary(
     assert 0.5 * 16e9 < held < 0.65 * 16e9
 
 
-@pytest.mark.parametrize("features,nodes", [(13, 1), (13, 32), (13, 128), (100, 16)])
+@pytest.mark.parametrize("features,nodes", [(13, 1), (13, 8), (13, 32), (13, 128), (100, 16)])
 def test_gbt_level_kernel_at_the_cells_size(one_chip, no_compile_cache, features, nodes):
     """``kernels.gbt_hist`` by itself over ``gbt-airline``'s 115,343,360 x
-    13 one-byte bins: a level of 1 node, of 32 (depth 6's last: 192 columns,
-    two MXU tiles) and of 128 (depth 8's last, the most it takes at 13
-    features), and the widest level it takes of a table of 100 features
-    (``vmem_bytes`` against its limit: Mosaic has to agree). It takes the
+    13 one-byte bins: a level of 1 node and of 8 (FOLDED, PR 48: a one-hot
+    of 128 rows against 96 columns of one MXU tile, a feature's own, the
+    packed bfloat16 rows masked as 32-bit words), of 32 (depth 6's last,
+    folded: 384 columns, three MXU tiles) and of 128 (depth 8's last, the
+    most it takes at 13 features, not folded), and the widest level it takes
+    of a table of 100 features (16 nodes, not folded; ``vmem_bytes`` against
+    its limit: Mosaic has to agree). It takes the
     uint8 block (the array's own count of rows), the 32-bit copy in scratch
     whose rows a ``fori_loop`` over the features reads by a dynamic sublane,
     and the product that contracts the lanes of both operands; beside its
@@ -621,6 +624,7 @@ def test_gbt_level_kernel_at_the_cells_size(one_chip, no_compile_cache, features
     from flinkml_tpu.kernels import gbt_hist
 
     rows = 115_343_360 if features == 13 else 1 << 22
+    assert gbt_hist.fold(nodes) == (nodes in (1, 8, 32))
     assert gbt_hist.tile_rows(rows) == gbt_hist.TILE
     assert gbt_hist.vmem_bytes(features, nodes, gbt_hist.TILE) <= gbt_hist.VMEM_LIMIT_BYTES
     if (features, nodes) in ((13, 128), (100, 16)):     # the widest it takes
@@ -636,6 +640,9 @@ def test_gbt_level_kernel_at_the_cells_size(one_chip, no_compile_cache, features
             on_chip((features, rows), jnp.uint8), on_chip((rows,), jnp.float32),
             on_chip((rows,), jnp.float32), on_chip((rows,), jnp.int32))
         assert not re.search(r"\b[fiu]64\[", str(traced.jaxpr))
+        sums = "f32[%d,%d,%d]" % (features, gbt_hist.one_hot_rows(nodes),
+                                  gbt_hist.columns(nodes))
+        assert sums in str(traced.jaxpr)             # the layout the rule names
         compiled = traced.lower().compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     memory = compiled.memory_analysis()

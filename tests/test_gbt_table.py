@@ -158,6 +158,14 @@ def _level(seed, n, nodes, d=13):
     return bins, g, h, node
 
 
+def _parts_added(sums, nodes):
+    """``[hg, hh]`` of ``[features, 256, used]`` sums (``gbt_hist.
+    unfolded``'s): the three parts' columns of each statistic added."""
+    group = max(nodes, 8)
+    return [sum(np.asarray(sums[:, :, (s * 3 + p) * group:(s * 3 + p) * group + nodes])
+                for p in range(3)).transpose(2, 0, 1) for s in range(2)]
+
+
 def _segment_sums(bins, g, h, node, nodes):
     d, n = bins.shape
     ids = ((node[None, :] * d + np.arange(d)[:, None]) * 256 + bins).reshape(-1)
@@ -227,7 +235,15 @@ def test_the_kernel_says_why_it_does_not_take_a_level():
         assert "nodes" in reason(f32, u8, 1_000, 4_096, 1)
     assert gbt_hist.vmem_bytes(13, 128, 4_096) < gbt_hist.VMEM_LIMIT_BYTES
     assert gbt_hist.tile_rows(115_343_360) == gbt_hist.TILE
-    assert gbt_hist.columns(1) == 128 and gbt_hist.columns(32) == 256
+    # a folded level's sums are no larger: 128 x 2 used for 256 x used
+    assert gbt_hist.columns(1) == 128 and gbt_hist.columns(32) == 384
+    assert gbt_hist.vmem_bytes(13, 1, 4_096) < gbt_hist.vmem_bytes(13, 16, 4_096)
+    with pytest.MonkeyPatch.context() as patch:
+        folded = [gbt_hist.vmem_bytes(13, n, 4_096) for n in (1, 8, 32)]
+        patch.setattr(gbt_hist, "fold", lambda nodes: False)
+        assert gbt_hist.columns(1) == 128 and gbt_hist.columns(32) == 256
+        plain = [gbt_hist.vmem_bytes(13, n, 4_096) for n in (1, 8, 32)]
+    assert all(a < b for a, b in zip(folded, plain))
     assert _gbt_table.padded_rows(6_000, 4) == 4 * 1_536
     assert _gbt_table.padded_rows(115_343_360, 1) == 115_343_360
 
@@ -420,16 +436,118 @@ def test_the_kernels_runs_of_tiles_add_up_to_the_level():
     nodes, n = 4, 128 * 300
     assert 2 * gbt_hist.RUN_TILES < 300 < 3 * gbt_hist.RUN_TILES
     bins, g, h, node = _level(9, n, nodes, d=2)
-    sums = gbt_hist.level_sums(*map(jnp.asarray, (bins, g, h, node)), nodes, tile=128,
-                               interpret=True)
-    group = 8
-    for s, stat in enumerate((g, h)):
+    sums = gbt_hist.unfolded(gbt_hist.level_sums(
+        *map(jnp.asarray, (bins, g, h, node)), nodes, tile=128, interpret=True), nodes)
+    for ours, stat in zip(_parts_added(sums, nodes), (g, h)):
         want = np.zeros((nodes, 2, 256))
         for f in range(2):
             np.add.at(want, (node, f, bins[f]), stat.astype(np.float64))
-        ours = sum(np.asarray(sums[:, :, (s * 3 + p) * group:(s * 3 + p) * group + nodes])
-                   for p in range(3)).transpose(2, 0, 1)
         assert np.abs(ours - want).max() <= 1e-6 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("nodes,folds,hot,width", [
+    (1, True, 128, 128), (2, True, 128, 128), (4, True, 128, 128), (8, True, 128, 128),
+    (16, False, 256, 128), (32, True, 128, 384), (64, False, 256, 384),
+    (128, False, 256, 768), (256, False, 256, 1_536)])
+def test_the_fold_is_chosen_from_the_node_count_alone(nodes, folds, hot, width):
+    """A one-hot of 128 rows against twice the used columns where that is
+    fewer MXU passes than 256 rows against them once (PR 48): at 1 to 8
+    nodes (48 used columns) and at 32 (192: three tiles for four); the
+    level's 16 nodes and ``maxDepth`` 8's 64 and 128 keep the product
+    they had."""
+    used = 6 * max(nodes, 8)
+    assert gbt_hist.used_columns(nodes) == used
+    assert gbt_hist.fold(nodes) is folds
+    assert folds == (128 * -(-2 * used // 128) < 256 * -(-used // 128))
+    assert (gbt_hist.one_hot_rows(nodes), gbt_hist.columns(nodes)) == (hot, width)
+    assert gbt_hist.vmem_bytes(13, nodes, 4_096) == (
+        3 * 13 * hot * width * 4 + 4_096 * (hot + width) * 6 + 13 * 4_096 * 6)
+
+
+@pytest.mark.parametrize("tiles", ["one run", "300 tiles of 128"])
+@pytest.mark.parametrize("nodes", [1, 2, 4, 8, 16, 32, 128])
+def test_a_folded_levels_sums_are_the_unfolded_kernels_to_the_bit(nodes, tiles):
+    """The fold moves a cell's sum to another cell of the MXU's output and
+    adds the same products in the same order: interpreted, the level's sums
+    un-folded equal the kernel's with the rule patched to "never" bit for
+    bit (this CPU's product does not order a contraction's additions by the
+    operands' shapes), over one run of tiles and over three, and the
+    histograms XLA's product and the segment sums as before."""
+    rows, tile, d = (2_048, None, 13) if tiles == "one run" else (128 * 300, 128, 3)
+    bins, g, h, node = _level(40 + nodes, rows, nodes, d=d)
+    operands = tuple(map(jnp.asarray, (bins, g, h, node)))
+    sums = gbt_hist.level_sums(*operands, nodes, tile=tile, interpret=True)
+    assert sums.shape == (d, gbt_hist.one_hot_rows(nodes), gbt_hist.columns(nodes))
+    ours = np.asarray(gbt_hist.unfolded(sums, nodes))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(gbt_hist, "fold", lambda nodes: False)
+        plain = gbt_hist.level_sums(*operands, nodes, tile=tile, interpret=True)
+        assert plain.shape == (d, 256, -(-6 * max(nodes, 8) // 128) * 128)
+        plain = np.asarray(gbt_hist.unfolded(plain, nodes))
+    assert ours.shape == plain.shape == (d, 256, 6 * max(nodes, 8))
+    assert np.array_equal(ours, plain)                                  # to the bit
+    if tile is None:
+        with pytest.MonkeyPatch.context() as patch:
+            got = gbt_hist.level_histograms(*operands, nodes, interpret=True)
+            patch.setattr(gbt_hist, "fold", lambda nodes: False)
+            want = gbt_hist.level_histograms(*operands, nodes, interpret=True)
+        assert all(np.array_equal(a, b) for a, b in zip(got, want))
+        others = (_gbt_table.xla_level_histograms(*operands, nodes),
+                  _segment_sums(bins, g, h, node, nodes))
+    else:
+        got = _parts_added(ours, nodes)
+        others = (_segment_sums(bins, g, h, node, nodes),)
+    for other in others:
+        for a, b, stat in zip(got, other, (g, h)):
+            assert a.shape == (nodes, d, 256)
+            assert (np.abs(np.asarray(a) - np.asarray(b)).max()
+                    <= 2e-6 * np.abs(stat).sum() / nodes ** 0.5)
+
+
+#: What the parent commit (0231a88, before PR 48) returned for
+#: ``fit_table(_estimator(), _airline()[2])``: the splits' features and
+#: bins weighted by their place, the gains' sum, the leaves against a
+#: seeded probe, the base score.
+PARENT_FOREST = (40_801, 590_093, "0x1.21a8fd2a00000p+10", "0x1.c309fadaf2f5cp+3",
+                 "-0x1.0bfcb03eac582p-3")
+
+
+def _digest(fit):
+    feats, cuts, gains, leaves, base, _ = fit
+    place = np.arange(1, feats.size + 1).reshape(feats.shape)
+    probe = np.random.default_rng(5).normal(size=leaves.shape)
+    return (int((feats * place).sum()), int((cuts * place).sum()),
+            float(gains.astype(np.float64).sum()).hex(),
+            float((leaves.astype(np.float64) * probe).sum()).hex(), float(base).hex())
+
+
+def test_a_cpus_forest_is_the_parents_to_the_bit_and_folded_levels_are_counted(monkeypatch):
+    """A CPU keeps XLA's product at every level: the forest is the parent
+    commit's bit for bit and no level counts as the kernel's or as folded.
+    Where the kernel takes every level (here: said to, XLA's product
+    standing in its place, because an interpreted kernel's values carry no
+    mesh axes inside the trainer's ``shard_map``) a tree of depth 6 counts
+    six product levels and the five folded ones: all but the 16 nodes'."""
+    _, _, table = _airline()
+    counters = lambda: dict(metrics.group("gbt").snapshot()["counters"])
+    before = counters()
+    assert _digest(_gbt_table.fit_table(_estimator(), table)) == PARENT_FOREST
+    after = counters()
+    assert after["levels"] - before.get("levels", 0) == 12
+    assert after.get("product_levels", 0) == before.get("product_levels", 0)
+    assert after.get("folded_levels", 0) == before.get("folded_levels", 0)
+    monkeypatch.setattr(gbt_hist, "unsupported_reason", lambda *a, **k: None)
+    monkeypatch.setattr(gbt_hist, "level_histograms", _gbt_table.xla_level_histograms)
+    try:
+        assert _digest(_gbt_table.fit_table(_estimator(), table)) == PARENT_FOREST
+        shallow = _estimator(trees=3, depth=4)
+        _gbt_table.fit_table(shallow, table)
+    finally:
+        _gbt_table._program.cache_clear()     # programs traced around the stand-in
+    last = counters()
+    assert last["levels"] - after["levels"] == 12 + 12
+    assert last["product_levels"] - after.get("product_levels", 0) == 12 + 12
+    assert last["folded_levels"] - after.get("folded_levels", 0) == 2 * 5 + 3 * 4
 
 
 # -- what the cell's ``correct`` sees -----------------------------------------
