@@ -254,11 +254,11 @@ def add_rows_sorted(table, ids_sorted, rows_sorted, *,
         )(ids, rows, table)
 
 
-def add_rows(table, ids, rows):
-    """``table.at[ids].add(rows)`` for ids in any order, through
-    :func:`add_rows_sorted`: a stable sort of (id, position), so entries
-    of one row keep their order and the same entries give the same sums
-    bit for bit, and the contributions fetched in that order."""
+def sorted_entries(ids, rows):
+    """``(ids, rows)`` in rising order of id, by a stable sort of (id,
+    position): entries of one row keep their order, so the same entries
+    give the same sums bit for bit, and the contributions are fetched in
+    that order."""
     import jax
     import jax.numpy as jnp
 
@@ -266,5 +266,10 @@ def add_rows(table, ids, rows):
         position = jax.lax.iota(jnp.int32, ids.shape[0])
         ids, position = jax.lax.sort((ids.astype(jnp.int32), position),
                                      num_keys=1, is_stable=True)
-        return add_rows_sorted(table, ids, rows[position])
+        return ids, rows[position]
 
+
+def add_rows(table, ids, rows):
+    """``table.at[ids].add(rows)`` for ids in any order:
+    :func:`add_rows_sorted` on their :func:`sorted_entries`."""
+    return add_rows_sorted(table, *sorted_entries(ids, rows))

@@ -59,12 +59,16 @@ from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.parallel.mesh import _GATHER_THREADS, gather_pool
 from flinkml_tpu.table import _free_bytes
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import named_program, span
+from flinkml_tpu.utils.profiling import named_program, phase, span
 
 #: The precision of the Gram and right-hand-side products: float32's own
 #: sums (six bfloat16 products on a TPU). One bfloat16 pass (``DEFAULT``)
 #: rounds every factor read to 8 bits: the benchmark's control.
 GRAM_PRECISION = jax.lax.Precision.HIGHEST
+#: The half-step's phases (``profiling.phase``): a chunk's or a piece's
+#: rows fetched, its Gram products, its solves. The pieces' sums, the
+#: ``all_gather`` and the id order are in none.
+PHASES = ("als.fetch", "als.gram", "als.solve")
 
 #: A factor row's lanes: the rank padded, so that a fetched row is whole
 #: vregs and lane ``rank`` is free for a rating's right-hand-side weight.
@@ -392,22 +396,24 @@ def make_half_step(plan: Tuple, rank: int, implicit: bool, axis: str,
     def systems(ids, r, fixed, alpha):
         """``[c, rank, width]``: ``A`` and ``b`` of the ``c`` targets
         whose slots ``ids``, ``r`` ``[c, L]`` are, unregularised."""
-        y = fixed.at[ids].get(mode="promise_in_bounds")   # [c, L, 128]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, LANES), 2)
-        if implicit:
-            a_w = alpha * r
-            right = jnp.where(lane == rank, (1.0 + a_w)[..., None], a_w[..., None] * y)
-        else:
-            right = jnp.where(lane == rank, r[..., None], y)
-        with jax.named_scope("gram"):
+        with phase("als.fetch"):
+            y = fixed.at[ids].get(mode="promise_in_bounds")   # [c, L, 128]
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, LANES), 2)
+            if implicit:
+                a_w = alpha * r
+                right = jnp.where(lane == rank, (1.0 + a_w)[..., None], a_w[..., None] * y)
+            else:
+                right = jnp.where(lane == rank, r[..., None], y)
+        with phase("als.gram"):
             g = jnp.einsum("clk,clm->ckm", y, right, precision=precision,
                            preferred_element_type=f32)
-        return g[:, :rank, :width]
+            return g[:, :rank, :width]
 
     def solved(g, counts, shared, reg):
-        lam = jnp.maximum(reg * jnp.maximum(counts, 1.0), _LAM_FLOOR)
-        eye = jnp.eye(rank, width, dtype=f32)
-        return _solve(g + shared + lam[:, None, None] * eye, rank, on_lanes)
+        with phase("als.solve"):
+            lam = jnp.maximum(reg * jnp.maximum(counts, 1.0), _LAM_FLOOR)
+            eye = jnp.eye(rank, width, dtype=f32)
+            return _solve(g + shared + lam[:, None, None] * eye, rank, on_lanes)
 
     def half_step(idx, val, counts, owner, where, fixed, reg, alpha):
         if implicit:
@@ -421,8 +427,9 @@ def make_half_step(plan: Tuple, rank: int, implicit: bool, axis: str,
 
             def one_chunk(i, slot=slot, row=row, length=length, chunk=chunk):
                 at = slot + i * (chunk * length)
-                ids = jax.lax.dynamic_slice(idx, (at,), (chunk * length,))
-                r = jax.lax.dynamic_slice(val, (at,), (chunk * length,))
+                with phase("als.fetch"):
+                    ids = jax.lax.dynamic_slice(idx, (at,), (chunk * length,))
+                    r = jax.lax.dynamic_slice(val, (at,), (chunk * length,))
                 g = systems(ids.reshape(chunk, length), r.reshape(chunk, length),
                             fixed, alpha)
                 n = jax.lax.dynamic_slice(counts, (row + i * chunk,), (chunk,))
@@ -436,8 +443,9 @@ def make_half_step(plan: Tuple, rank: int, implicit: bool, axis: str,
 
             def one_piece(i, slot=slot):
                 at = slot + i * piece
-                ids = jax.lax.dynamic_slice(idx, (at,), (piece,))
-                r = jax.lax.dynamic_slice(val, (at,), (piece,))
+                with phase("als.fetch"):
+                    ids = jax.lax.dynamic_slice(idx, (at,), (piece,))
+                    r = jax.lax.dynamic_slice(val, (at,), (piece,))
                 return systems(ids[None], r[None], fixed, alpha)[0]
 
             parts = jax.lax.map(one_piece, jnp.arange(pieces, dtype=jnp.int32))
@@ -461,7 +469,7 @@ def _program(mesh, plan: Tuple, rank: int, implicit: bool, precision, on_lanes: 
     axis = DeviceMesh.DATA_AXIS
     fn = make_half_step(plan, rank, implicit, axis, precision, on_lanes)
     return jax.jit(jax.shard_map(
-        named_program("als_half_step", fn), mesh=mesh,
+        named_program("als_half_step", fn, phases=PHASES), mesh=mesh,
         in_specs=(P(axis),) * 4 + (P(),) * 4, out_specs=(P(), P()),
         # The outputs are made of the all-gathered rows, the same on every
         # device: what the replication check cannot see of an all_gather.

@@ -69,13 +69,15 @@ from flinkml_tpu.ops.sparse import LANES
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.parallel.mesh import gather_pool
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import named_program, span
+from flinkml_tpu.utils.profiling import named_program, phase, span
 
 #: The precision of the lookup's and the accumulation's products: the
 #: looked-up rows are the gathered ones bit for bit, the accumulated
 #: gradient float32's own sum (one bfloat16 pass, ``DEFAULT``, rounds
 #: every parameter read to 8 bits: the benchmark's control).
 LOOKUP_PRECISION = jax.lax.Precision.HIGHEST
+#: The step's phases (``profiling.phase``).
+PHASES = ("fm.lookup", "fm.interaction", "fm.accumulate", "fm.adam")
 
 class _Placed(NamedTuple):
     """A table's rows as the mesh holds them, in the seeded order, and
@@ -170,46 +172,47 @@ def make_step(logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
     def step(params, m, v, t, idx, val, y, wt, starts, lr, reg):
         w0, table = params
         width = table.shape[0]
-        ib, vb = _window(idx, t, local_bs), _window(val, t, local_bs)
-        yb, wb = _window(y, t, local_bs), _window(wt, t, local_bs)
-        general = [j for j in range(ib.shape[1])
-                   if j >= len(slot_plan) or slot_plan[j] is None]
-        # What a cell adds to its row's sums is x_s P[i_s]: kept, slot
-        # major, for the gradient.
-        sums = jnp.zeros((local_bs, width), table.dtype)
-        squares = jnp.zeros((local_bs,), table.dtype)
-        walked = []
-        for length, slots in sparse.block_groups(slot_plan, local_bs, width):
-            first = [starts[j] for j in slots]
-            blocks = jnp.stack([
-                jax.lax.dynamic_slice_in_dim(table, at, length // LANES, axis=1)
-                .reshape(width, length).T for at in first])
-            local = _slot_major(ib, slots) - LANES * jnp.stack(first)[:, None]
-            xs = _slot_major(vb, slots)
-            with jax.named_scope(f"lookup_{length}x{len(slots)}"):
+        with phase("fm.lookup"):
+            ib, vb = _window(idx, t, local_bs), _window(val, t, local_bs)
+            yb, wb = _window(y, t, local_bs), _window(wt, t, local_bs)
+            general = [j for j in range(ib.shape[1])
+                       if j >= len(slot_plan) or slot_plan[j] is None]
+            # What a cell adds to its row's sums is x_s P[i_s]: kept, slot
+            # major, for the gradient.
+            sums = jnp.zeros((local_bs, width), table.dtype)
+            squares = jnp.zeros((local_bs,), table.dtype)
+            walked = []
+            for length, slots in sparse.block_groups(slot_plan, local_bs, width):
+                first = [starts[j] for j in slots]
+                blocks = jnp.stack([
+                    jax.lax.dynamic_slice_in_dim(table, at, length // LANES, axis=1)
+                    .reshape(width, length).T for at in first])
+                local = _slot_major(ib, slots) - LANES * jnp.stack(first)[:, None]
+                xs = _slot_major(vb, slots)
                 xp = xs[..., None] * sparse.block_lookup(blocks, local, precision)
-            sums += jnp.sum(xp, axis=0)
-            squares += jnp.sum(jnp.square(xp[..., 1:]), axis=(0, 2))
-            walked.append((length, first, local, xs, xp))
-        if general:
-            ig, xg = _slot_major(ib, general).T, _slot_major(vb, general).T
-            gp = xg[..., None] * jnp.moveaxis(
-                jnp.take(table.reshape(width, -1), ig, axis=1), 0, -1)
-            sums += jnp.sum(gp, axis=1)
-            squares += jnp.sum(jnp.square(gp[..., 1:]), axis=(1, 2))
-        factor_sums = sums[:, 1:]
-        margin = w0[0] + sums[:, 0] + 0.5 * (
-            jnp.sum(jnp.square(factor_sums), axis=1) - squares)
-        if logistic:
-            per_row = jnp.logaddexp(0.0, margin) - yb * margin
-            mult = (jax.nn.sigmoid(margin) - yb) * wb
-        else:
-            err = margin - yb
-            per_row, mult = 0.5 * err * err, err * wb
-        # d y^ / d P[i_s] = x_s (1, S_f - x_s V[i_s, f]).
-        base = jnp.concatenate(
-            [jnp.ones((local_bs, 1), table.dtype), factor_sums], axis=1)
-        factors_only = jnp.arange(width) > 0
+                sums += jnp.sum(xp, axis=0)
+                squares += jnp.sum(jnp.square(xp[..., 1:]), axis=(0, 2))
+                walked.append((length, first, local, xs, xp))
+            if general:
+                ig, xg = _slot_major(ib, general).T, _slot_major(vb, general).T
+                gp = xg[..., None] * jnp.moveaxis(
+                    jnp.take(table.reshape(width, -1), ig, axis=1), 0, -1)
+                sums += jnp.sum(gp, axis=1)
+                squares += jnp.sum(jnp.square(gp[..., 1:]), axis=(1, 2))
+        with phase("fm.interaction"):
+            factor_sums = sums[:, 1:]
+            margin = w0[0] + sums[:, 0] + 0.5 * (
+                jnp.sum(jnp.square(factor_sums), axis=1) - squares)
+            if logistic:
+                per_row = jnp.logaddexp(0.0, margin) - yb * margin
+                mult = (jax.nn.sigmoid(margin) - yb) * wb
+            else:
+                err = margin - yb
+                per_row, mult = 0.5 * err * err, err * wb
+            # d y^ / d P[i_s] = x_s (1, S_f - x_s V[i_s, f]).
+            base = jnp.concatenate(
+                [jnp.ones((local_bs, 1), table.dtype), factor_sums], axis=1)
+            factors_only = jnp.arange(width) > 0
 
         def cell_grads(xs, xp, rows_first: bool):
             """``xs [.., ..]`` and ``xp [.., .., 1 + k]`` over (rows, slots)
@@ -218,35 +221,36 @@ def make_step(logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
             return (of_row(mult) * xs)[..., None] * (
                 of_row(base) - jnp.where(factors_only, xp, 0))
 
-        if general:
-            grad = jax.ops.segment_sum(
-                cell_grads(xg, gp, True).reshape(-1, width), ig.reshape(-1),
-                num_segments=table.shape[1] * LANES).T.reshape(table.shape)
-        else:
-            grad = jnp.zeros_like(table)
-        for length, first, local, xs, xp in walked:
-            with jax.named_scope(f"accumulate_{length}x{len(first)}"):
+        with phase("fm.accumulate"):
+            if general:
+                grad = jax.ops.segment_sum(
+                    cell_grads(xg, gp, True).reshape(-1, width), ig.reshape(-1),
+                    num_segments=table.shape[1] * LANES).T.reshape(table.shape)
+            else:
+                grad = jnp.zeros_like(table)
+            for length, first, local, xs, xp in walked:
                 back = sparse.block_accumulate(
                     local, cell_grads(xs, xp, False), length, precision)
-            # One slot after another: blocks may overlap, and add.
-            for at, slot_grad in zip(first, back):
-                grad = jax.lax.dynamic_update_slice_in_dim(
-                    grad,
-                    jax.lax.dynamic_slice_in_dim(grad, at, length // LANES, axis=1)
-                    + slot_grad.T.reshape(width, -1, LANES), at, axis=1)
-        wsum = jax.lax.psum(jnp.sum(wb), axis)
-        total_w = jnp.maximum(wsum, 1e-12)
-        # L2 as fm._fm_*_loss_builder states it: reg * (|w|^2 + |V|^2)
-        # times the batch's weight, inside the sum that total_w divides.
-        l2 = reg * wsum / total_w
-        loss = (jax.lax.psum(jnp.sum(per_row * wb), axis) / total_w
-                + l2 * jnp.sum(jnp.square(table)))
-        grads = (jax.lax.psum(jnp.sum(mult), axis)[None] / total_w,
-                 _lane_rows(jax.lax.psum(grad, axis)) / total_w + 2.0 * l2 * table)
-        params, m, v = adam_update(params, m, v, grads, t, lr)
-        (w0, table), (m0, m1), (v0, v1) = params, m, v
-        return ((w0, _lane_rows(table)), (m0, _lane_rows(m1)),
-                (v0, _lane_rows(v1)), loss)
+                # One slot after another: blocks may overlap, and add.
+                for at, slot_grad in zip(first, back):
+                    grad = jax.lax.dynamic_update_slice_in_dim(
+                        grad,
+                        jax.lax.dynamic_slice_in_dim(grad, at, length // LANES, axis=1)
+                        + slot_grad.T.reshape(width, -1, LANES), at, axis=1)
+        with phase("fm.adam"):
+            wsum = jax.lax.psum(jnp.sum(wb), axis)
+            total_w = jnp.maximum(wsum, 1e-12)
+            # L2 as fm._fm_*_loss_builder states it: reg * (|w|^2 + |V|^2)
+            # times the batch's weight, inside the sum that total_w divides.
+            l2 = reg * wsum / total_w
+            loss = (jax.lax.psum(jnp.sum(per_row * wb), axis) / total_w
+                    + l2 * jnp.sum(jnp.square(table)))
+            grads = (jax.lax.psum(jnp.sum(mult), axis)[None] / total_w,
+                     _lane_rows(jax.lax.psum(grad, axis)) / total_w + 2.0 * l2 * table)
+            params, m, v = adam_update(params, m, v, grads, t, lr)
+            (w0, table), (m0, m1), (v0, v1) = params, m, v
+            return ((w0, _lane_rows(table)), (m0, _lane_rows(m1)),
+                    (v0, _lane_rows(v1)), loss)
 
     return step
 
@@ -285,7 +289,7 @@ def _trainer(mesh, logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
         return params[0], params[1], t, loss
 
     return jax.jit(jax.shard_map(
-        named_program("fm_adam_loop", per_device), mesh=mesh,
+        named_program("fm_adam_loop", per_device, phases=PHASES), mesh=mesh,
         in_specs=(P(), P()) + (P(axis),) * 4 + (P(),) * 5,
         out_specs=(P(), P(), P(), P()),
     ))

@@ -63,13 +63,17 @@ from flinkml_tpu.models._data import (
 )
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import named_program, span
+from flinkml_tpu.utils.profiling import named_program, phase, span
 
 #: Rows the bin edges are taken over (a seeded uniform sample of the
 #: table's; all of them where it has no more).
 BIN_SAMPLE_ROWS = 1 << 20
 #: Rows a task of the binning's pool of threads takes, and the threads.
 _BIN_CHUNK_ROWS, _BIN_THREADS = 1 << 18, 12
+#: The fit's phases (``profiling.phase``): a tree's gradients with the
+#: prediction's update, and a level's histograms, split finding and rows'
+#: re-assignment. The ``psum`` of a level's histograms is in none.
+PHASES = ("gbt.gradients", "gbt.histograms", "gbt.splits", "gbt.route")
 #: Nodes of a level up to which a row's split is looked up by selects
 #: (a chain of them, fused with what reads it); a deeper level gathers.
 _SELECT_NODES = 64
@@ -347,19 +351,22 @@ def _program(mesh, axis: str, n_feat: int, n_bins: int, depth: int,
                 nodes = 1 << level
                 histograms = (gbt_hist.level_histograms if product_levels[level]
                               else xla_level_histograms)
-                hg, hh = histograms(bins, g, h, node, nodes)
+                with phase("gbt.histograms"):
+                    hg, hh = histograms(bins, g, h, node, nodes)
                 hg = jax.lax.psum(hg, axis)[:, :, :n_bins]
                 hh = jax.lax.psum(hh, axis)[:, :, :n_bins]
-                bf, bb, gain, lg, lh, rg, rh = best_splits(hg, hh, lam, fmask)
+                with phase("gbt.splits"):
+                    bf, bb, gain, lg, lh, rg, rh = best_splits(hg, hh, lam, fmask)
                 feats.append(bf)
                 cuts.append(bb)
                 gains.append(gain)
                 if boosting or level + 1 < depth:
-                    of_row, cut = _of_node(bf, node, nodes), _of_node(bb, node, nodes)
-                    mine = jnp.zeros(n_local, jnp.int32)
-                    for f in range(n_feat):
-                        mine = jnp.where(of_row == f, bins[f].astype(jnp.int32), mine)
-                    node = node * 2 + (mine > cut)
+                    with phase("gbt.route"):
+                        of_row, cut = _of_node(bf, node, nodes), _of_node(bb, node, nodes)
+                        mine = jnp.zeros(n_local, jnp.int32)
+                        for f in range(n_feat):
+                            mine = jnp.where(of_row == f, bins[f].astype(jnp.int32), mine)
+                        node = node * 2 + (mine > cut)
             # The leaves' sums are the last level's: the chosen split's
             # two sides.
             leaf_g = jnp.stack([lg, rg], axis=1).reshape(-1)
@@ -372,20 +379,22 @@ def _program(mesh, axis: str, n_feat: int, n_bins: int, depth: int,
                     leaf.astype(jnp.float32), node)
 
         def tree_step(pred, tree_key):
-            g, h = grad_hess(pred, y, w)
-            k_rows, k_feats = jax.random.split(tree_key)
-            if boosting:
-                # Every row where subsample is 1: the draw is under 1.
-                g, h = jax.lax.cond(
-                    subsample < 1.0,
-                    lambda: tuple(jnp.where(
-                        jax.random.uniform(k_rows, (n_local,)) < subsample, s, 0.0)
-                        for s in (g, h)),
-                    lambda: (g, h))
-            else:
-                # Poisson bootstrap: the with-replacement resample.
-                count = jax.random.poisson(k_rows, subsample, (n_local,)).astype(g.dtype)
-                g, h = g * count, h * count
+            with phase("gbt.gradients"):
+                g, h = grad_hess(pred, y, w)
+                k_rows, k_feats = jax.random.split(tree_key)
+                if boosting:
+                    # Every row where subsample is 1: the draw is under 1.
+                    g, h = jax.lax.cond(
+                        subsample < 1.0,
+                        lambda: tuple(jnp.where(
+                            jax.random.uniform(k_rows, (n_local,)) < subsample, s, 0.0)
+                            for s in (g, h)),
+                        lambda: (g, h))
+                else:
+                    # Poisson bootstrap: the with-replacement resample.
+                    count = jax.random.poisson(
+                        k_rows, subsample, (n_local,)).astype(g.dtype)
+                    g, h = g * count, h * count
             if feat_subset:
                 perm = jax.random.permutation(k_feats, n_feat)
                 fmask = jnp.zeros(n_feat, jnp.float32).at[perm[:feat_subset]].set(1.0)
@@ -393,7 +402,9 @@ def _program(mesh, axis: str, n_feat: int, n_bins: int, depth: int,
                 fmask = jnp.ones(n_feat, jnp.float32)
             feat_arr, bin_arr, gain_arr, leaf, node = build_tree(g, h, fmask)
             if boosting:
-                pred = (pred + lr * _of_node(leaf, node, n_leaves)).astype(jnp.float32)
+                with phase("gbt.gradients"):
+                    pred = (pred + lr * _of_node(leaf, node, n_leaves)
+                            ).astype(jnp.float32)
             return pred, (feat_arr, bin_arr, gain_arr, leaf)
 
         keys = jax.random.split(key, num_trees)
@@ -403,7 +414,7 @@ def _program(mesh, axis: str, n_feat: int, n_bins: int, depth: int,
         return trees
 
     return jax.jit(jax.shard_map(
-        named_program("gbt_forest", gbt_forest), mesh=mesh,
+        named_program("gbt_forest", gbt_forest, phases=PHASES), mesh=mesh,
         in_specs=(P(None, axis), P(axis), P(axis), P(), P(), P(), P(), P()),
         out_specs=(P(), P(), P(), P())))
 

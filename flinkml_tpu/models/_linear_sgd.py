@@ -54,7 +54,7 @@ from flinkml_tpu.ops.sparse import (
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.parallel.mesh import gather_pool
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import named_program, span
+from flinkml_tpu.utils.profiling import named_program, phase, span
 
 _LOSS_KEYS = ("logistic", "hinge", "squared")
 
@@ -280,6 +280,14 @@ def make_dense_step(loss: str, local_bs: int, axis: str):
     return step
 
 
+#: The sparse step's phases (``profiling.phase``): the coefficients of a
+#: window's cells looked up, and their gradient accumulated, each by the
+#: block products (kernel or XLA) with the gather or segment-sum of the
+#: slots no block takes. The margin's parts, the L2 term and the update
+#: are in none.
+SPARSE_PHASES = ("lr.sparse_lookup", "lr.sparse_accumulate")
+
+
 def _lane_rows(x):
     """``x [dim]`` as rows of 128 lanes, zeros past its end: what a
     block, which starts at a row, is cut out of."""
@@ -372,57 +380,61 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
             yb = _window(yl, epoch, local_bs)
             wb = _window(wl, epoch, local_bs)
             fused = _blocks_in_fast_memory(coef.dtype, local_bs, slot_plan)
-            # Each group's block rows and, for XLA's products, its cells,
-            # slot-major, indexed from their blocks' first rows; what is
-            # left of ib, vb are the general slots.
-            cells = []
-            for length, slots in groups:
-                first = jnp.stack([slot_starts[j] for j in slots])
-                rows = first[:, None] + jnp.arange(length // LANES)
-                cells.append((rows, None, None) if fused else (
-                    rows, _slot_major(ib, slots) - LANES * first[:, None],
-                    _slot_major(vb, slots)))
-            if fused:
-                # The kernels walk the window's cells as they are, a slot
-                # a row: which rows, group by group, and the starts say.
-                walk = ([(length, len(slots)) for length, slots in groups],
-                        [j for _, slots in groups for j in slots])
-                whole = (ib.T, vb.T, slot_starts)
-            if groups:
-                ib, vb = _slot_major(ib, general).T, _slot_major(vb, general).T
-                tiled = _lane_rows(coef)
-            dot = ell_matvec(ib, vb, coef)
-            if fused:
-                dot = dot + sparse_blocks.lookup_dot(
-                    *walk, [tiled[rows] for rows, _, _ in cells], *whole)
-            else:
-                for rows, local, vals in cells:
-                    looked = block_lookup(
-                        tiled[rows].reshape(rows.shape[0], -1), local)
-                    dot = dot + jnp.sum(vals * looked, axis=0)
+            with phase("lr.sparse_lookup"):
+                # Each group's block rows and, for XLA's products, its
+                # cells, slot-major, indexed from their blocks' first
+                # rows; what is left of ib, vb are the general slots.
+                cells = []
+                for length, slots in groups:
+                    first = jnp.stack([slot_starts[j] for j in slots])
+                    rows = first[:, None] + jnp.arange(length // LANES)
+                    cells.append((rows, None, None) if fused else (
+                        rows, _slot_major(ib, slots) - LANES * first[:, None],
+                        _slot_major(vb, slots)))
+                if fused:
+                    # The kernels walk the window's cells as they are, a
+                    # slot a row: which rows, group by group, and the
+                    # starts say.
+                    walk = ([(length, len(slots)) for length, slots in groups],
+                            [j for _, slots in groups for j in slots])
+                    whole = (ib.T, vb.T, slot_starts)
+                if groups:
+                    ib, vb = _slot_major(ib, general).T, _slot_major(vb, general).T
+                    tiled = _lane_rows(coef)
+                dot = ell_matvec(ib, vb, coef)
+                if fused:
+                    dot = dot + sparse_blocks.lookup_dot(
+                        *walk, [tiled[rows] for rows, _, _ in cells], *whole)
+                else:
+                    for rows, local, vals in cells:
+                        looked = block_lookup(
+                            tiled[rows].reshape(rows.shape[0], -1), local)
+                        dot = dot + jnp.sum(vals * looked, axis=0)
             mult, per_ex = _margin_grad(loss, dot, yb, wb)
-            if fused:
-                block_grads += zip(
-                    (rows for rows, _, _ in cells),
-                    sparse_blocks.accumulate(*walk, *whole, mult))
-            else:
-                block_grads += [
-                    (rows, block_accumulate(
-                        local, vals * mult[None, :], LANES * rows.shape[1]))
-                    for rows, local, vals in cells]
-            contribs.append((vb * mult[:, None]).reshape(-1))
-            flat_idx.append(ib.reshape(-1))
+            with phase("lr.sparse_accumulate"):
+                if fused:
+                    block_grads += zip(
+                        (rows for rows, _, _ in cells),
+                        sparse_blocks.accumulate(*walk, *whole, mult))
+                else:
+                    block_grads += [
+                        (rows, block_accumulate(
+                            local, vals * mult[None, :], LANES * rows.shape[1]))
+                        for rows, local, vals in cells]
+                contribs.append((vb * mult[:, None]).reshape(-1))
+                flat_idx.append(ib.reshape(-1))
             loss_l = loss_l + jnp.sum(per_ex.astype(acc))
             wsum_l = wsum_l + jnp.sum(wb.astype(acc))
-        grad_local = kernels.segment_sum(
-            jnp.concatenate(contribs), jnp.concatenate(flat_idx),
-            dim, backend=segsum_backend,
-        )
-        if block_grads:
-            tiled = _lane_rows(grad_local)
-            for rows, sums in block_grads:
-                tiled = tiled.at[rows].add(sums.reshape(rows.shape + (LANES,)))
-            grad_local = tiled.reshape(-1)[:dim]
+        with phase("lr.sparse_accumulate"):
+            grad_local = kernels.segment_sum(
+                jnp.concatenate(contribs), jnp.concatenate(flat_idx),
+                dim, backend=segsum_backend,
+            )
+            if block_grads:
+                tiled = _lane_rows(grad_local)
+                for rows, sums in block_grads:
+                    tiled = tiled.at[rows].add(sums.reshape(rows.shape + (LANES,)))
+                grad_local = tiled.reshape(-1)[:dim]
         grad = jax.lax.psum(grad_local, axis)
         loss_sum = jax.lax.psum(loss_l, axis)
         wsum = jax.lax.psum(wsum_l, axis)
@@ -438,12 +450,14 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
     return step
 
 
-def _whole_loop(mesh, step, n_sharded: int, axis: str, name: str):
+def _whole_loop(mesh, step, n_sharded: int, axis: str, name: str,
+                phases: Tuple[str, ...] = ()):
     """Carry-style whole-loop trainer around one per-device ``step``: runs
     epochs from ``epoch`` up to ``epoch_end`` (or until ``loss <= tol``)
     entirely on device and returns the full carry ``(coef, epoch, loss)``.
     The data args are ``n_sharded`` arrays sharded along ``axis``; the
-    program's ``name`` is what a profile calls each dispatch of it.
+    program's ``name`` is what a profile calls each dispatch of it, and
+    ``phases`` are those ``step`` opens (``profiling.named_program``).
 
     Because the carry and ``epoch_end`` are runtime values, the SAME
     compiled executable serves both the one-dispatch fit (epoch_end =
@@ -473,7 +487,7 @@ def _whole_loop(mesh, step, n_sharded: int, axis: str, name: str):
 
     return jax.jit(
         jax.shard_map(
-            named_program(name, per_device),
+            named_program(name, per_device, phases),
             mesh=mesh,
             in_specs=(P(), P(), P()) + (P(axis),) * n_sharded + (P(),) * 5,
             out_specs=(P(), P(), P()),
@@ -506,7 +520,7 @@ def _sparse_trainer_bucketed(mesh, loss: str, local_bss: Tuple[int, ...],
     step = make_sparse_step_bucketed(loss, local_bss, axis, dim,
                                      segsum_backend, slot_plan)
     return _whole_loop(mesh, step, 4 * len(local_bss) + bool(slot_plan), axis,
-                       "lr_sparse_loop")
+                       "lr_sparse_loop", SPARSE_PHASES)
 
 
 def _restore_carry(checkpoint_manager, dim: int, dtype, mesh=None):

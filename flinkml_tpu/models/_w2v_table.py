@@ -61,7 +61,7 @@ from flinkml_tpu.ops.sparse import LANES
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.parallel.mesh import gather_pool
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import named_program, span
+from flinkml_tpu.utils.profiling import named_program, phase, span
 
 #: Top bit of a corpus entry: the token is its sentence's first. Padding
 #: is word 0 so marked.
@@ -79,6 +79,9 @@ CANDIDATE_MARGIN = (3, 2)
 #: Pieces a long host pass is cut in for the fit's pool of threads.
 _PASS_CHUNKS = 16
 _GOLDEN = 0x9E3779B9
+#: The step's phases (``profiling.phase``); the scatter-adds sort nothing.
+PHASES = ("w2v.draw", "w2v.fetch", "w2v.grads", "w2v.sort", "w2v.update")
+PHASES_UNSORTED = tuple(p for p in PHASES if p != "w2v.sort")
 # What each stream of bits draws (the configuration's file lists them).
 (S_POSITION_HI, S_POSITION_LO, S_SIDE_ORDINAL, S_KEEP, S_NEGATIVE_HI,
  S_NEGATIVE_LO) = range(6)
@@ -223,33 +226,47 @@ def _program(d: Draw, score_dtype=None, sorted_updates: bool = False):
     def w2v_sgns_loop(v, u, tokens, keep, pool, seed, rate, steps):
         def step(t, tables):
             v, u = tables
-            c, ctx, neg, found = draw(d, tokens, keep, pool, seed,
-                                      t.astype(jnp.uint32))
+            with phase("w2v.draw"):
+                c, ctx, neg, found = draw(d, tokens, keep, pool, seed,
+                                          t.astype(jnp.uint32))
             ones = jnp.ones(d.batch, v.dtype)
-            grad_vc, grad_uc, grad_un = _sgns_pair_grads(
-                v[c], u[ctx], u[neg], ones, score_dtype=score_dtype)
-            scale = -jnp.where(found > 0, rate, 0.0) / d.batch
+            with phase("w2v.fetch"):
+                vc, uc, un = v[c], u[ctx], u[neg]
+            with phase("w2v.grads"):
+                grad_vc, grad_uc, grad_un = _sgns_pair_grads(
+                    vc, uc, un, ones, score_dtype=score_dtype)
+                scale = -jnp.where(found > 0, rate, 0.0) / d.batch
             if sorted_updates:
-                # The output rows' entries as ONE list, an ordinal a run
-                # of the batch (a negative's ordinal leads: no [batch, 5,
-                # lanes] array padded to eight sublanes and re-laid).
-                ids = jnp.concatenate([ctx[None], neg.T]).reshape(-1)
-                rows = jnp.concatenate([
-                    (scale * grad_uc)[None],
-                    jnp.moveaxis(scale * grad_un, 1, 0)])
-                return (row_update.add_rows(v, c, scale * grad_vc),
-                        row_update.add_rows(
-                            u, ids, rows.reshape(-1, rows.shape[-1])))
-            v = v.at[c].add(scale * grad_vc)
-            u = u.at[ctx].add(scale * grad_uc)
-            u = u.at[neg.reshape(-1)].add(
-                (scale * grad_un).reshape(-1, grad_un.shape[-1]))
+                with phase("w2v.sort"):
+                    # The output rows' entries as ONE list, an ordinal a
+                    # run of the batch (a negative's ordinal leads: no
+                    # [batch, 5, lanes] array padded to eight sublanes and
+                    # re-laid).
+                    ids = jnp.concatenate([ctx[None], neg.T]).reshape(-1)
+                    rows = jnp.concatenate([
+                        (scale * grad_uc)[None],
+                        jnp.moveaxis(scale * grad_un, 1, 0)])
+                    of_v = row_update.sorted_entries(c, scale * grad_vc)
+                with phase("w2v.update"):
+                    v = row_update.add_rows_sorted(v, *of_v)
+                with phase("w2v.sort"):
+                    of_u = row_update.sorted_entries(
+                        ids, rows.reshape(-1, rows.shape[-1]))
+                with phase("w2v.update"):
+                    return v, row_update.add_rows_sorted(u, *of_u)
+            with phase("w2v.update"):
+                v = v.at[c].add(scale * grad_vc)
+                u = u.at[ctx].add(scale * grad_uc)
+                u = u.at[neg.reshape(-1)].add(
+                    (scale * grad_un).reshape(-1, grad_un.shape[-1]))
             return v, u
 
         return jax.lax.fori_loop(jnp.int32(0), steps, step, (v, u))
 
-    return jax.jit(named_program("w2v_sgns_loop", w2v_sgns_loop),
-                   donate_argnums=(0, 1))
+    return jax.jit(
+        named_program("w2v_sgns_loop", w2v_sgns_loop,
+                      phases=PHASES if sorted_updates else PHASES_UNSORTED),
+        donate_argnums=(0, 1))
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))

@@ -58,7 +58,7 @@ from flinkml_tpu.ops.distance import DistanceMeasure
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
 from flinkml_tpu.utils.metrics import metrics
-from flinkml_tpu.utils.profiling import named_program, span
+from flinkml_tpu.utils.profiling import named_program, phase, span
 
 #: The precision of a round's two products, a static argument of both
 #: trainers: float32 accuracy, the distance expansion's own
@@ -66,6 +66,9 @@ from flinkml_tpu.utils.profiling import named_program, span
 #: (one bfloat16 pass) to show that the benchmark's check tells the two
 #: apart.
 PRODUCT_PRECISION = blas.DISTANCE_PRECISION
+#: A round's phases (``profiling.phase``): the distances with their
+#: argmin; the per-cluster sums with the update. The ``psum`` is in none.
+PHASES = ("kmeans.assign", "kmeans.sums")
 
 
 class _KMeansParams(
@@ -292,23 +295,25 @@ def _share_sums(xl, sq, wl, centroids, k: int, precision):
     XLA fuses the argmin into the distances' product and the one-hot
     into the sums': nothing of ``[rows, k]`` reaches HBM, and a round
     reads the rows twice (PERF.md §5, PR 32)."""
-    d2 = blas.squared_distances(xl, centroids, precision=precision, xs_sq=sq)
-    assign = jnp.argmin(d2, axis=-1)
-    # Per-cluster sums via one-hot matmul (k is small; a matmul beats
-    # scatter on TPU). The one-hot side is exact in any precision; the
-    # rows are not, in one bfloat16 pass.
-    onehot = jax.nn.one_hot(assign, k, dtype=xl.dtype) * wl[:, None]
-    counts = jnp.sum(onehot, axis=0)
-    # The rows are summed as deviations from a pivot near them (the mean
-    # of the centroids; XLA fuses the subtraction into the product's
-    # operand): a float32 sum of 400,000 pixels loses a part in 5,000 to
-    # its own accumulator, and the deviations' partial sums are a few
-    # times smaller. Read on a v5e at 2,025,000 x 784: the centroids' gap
-    # to float64 Lloyd 1.1e-4 -> 2.1-2.9e-5 for 1.5 % of a round
-    # (PERF.md §5, PR 32).
-    pivot = jnp.mean(centroids, axis=0)
-    sums = jnp.matmul(onehot.T, xl - pivot, precision=precision)
-    return sums + counts[:, None] * pivot, counts
+    with phase("kmeans.assign"):
+        d2 = blas.squared_distances(xl, centroids, precision=precision, xs_sq=sq)
+        assign = jnp.argmin(d2, axis=-1)
+    with phase("kmeans.sums"):
+        # Per-cluster sums via one-hot matmul (k is small; a matmul beats
+        # scatter on TPU). The one-hot side is exact in any precision; the
+        # rows are not, in one bfloat16 pass.
+        onehot = jax.nn.one_hot(assign, k, dtype=xl.dtype) * wl[:, None]
+        counts = jnp.sum(onehot, axis=0)
+        # The rows are summed as deviations from a pivot near them (the
+        # mean of the centroids; XLA fuses the subtraction into the
+        # product's operand): a float32 sum of 400,000 pixels loses a part
+        # in 5,000 to its own accumulator, and the deviations' partial
+        # sums are a few times smaller. Read on a v5e at 2,025,000 x 784:
+        # the centroids' gap to float64 Lloyd 1.1e-4 -> 2.1-2.9e-5 for
+        # 1.5 % of a round (PERF.md §5, PR 32).
+        pivot = jnp.mean(centroids, axis=0)
+        sums = jnp.matmul(onehot.T, xl - pivot, precision=precision)
+        return sums + counts[:, None] * pivot, counts
 
 
 def _moved(sums, counts, centroids):
@@ -333,14 +338,15 @@ def _kmeans_trainer(mesh, k: int, axis: str, precision=PRODUCT_PRECISION):
     def per_device(xl, sq, wl, init_centroids, max_iter):
         def body(_, centroids):
             sums, counts = _share_sums(xl, sq, wl, centroids, k, precision)
-            return _moved(jax.lax.psum(sums, axis), jax.lax.psum(counts, axis),
-                          centroids)
+            sums, counts = jax.lax.psum(sums, axis), jax.lax.psum(counts, axis)
+            with phase("kmeans.sums"):
+                return _moved(sums, counts, centroids)
 
         return jax.lax.fori_loop(0, max_iter, body, init_centroids)
 
     return jax.jit(
         jax.shard_map(
-            named_program("kmeans_lloyd", per_device),
+            named_program("kmeans_lloyd", per_device, phases=PHASES),
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(), P()),
             out_specs=P(),

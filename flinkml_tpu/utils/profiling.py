@@ -7,8 +7,10 @@ records a span: a host phase that is both a ``jax.profiler``
 chip's ``XLA Ops`` rows) and a few counters in ``metrics.group("span")``
 (so it is read with no profiler at all). :func:`named_program` gives a
 jitted program the name a profile's ``XLA Modules`` row shows for each of
-its runs on the chip. :func:`trace` captures a profile
-and degrades gracefully: if the profiler cannot start (e.g. unsupported
+its runs on the chip, and :func:`phase` is the ONLY way the program names
+a part of such a program: the device-side counterpart of a span, read off
+the device trace as that part's own device time. :func:`trace` captures a
+profile and degrades gracefully: if the profiler cannot start (e.g. unsupported
 on the backend) it becomes a no-op rather than failing the training job.
 """
 
@@ -17,7 +19,8 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Callable, Iterator
+import zlib
+from typing import Callable, Iterator, Sequence
 
 import jax
 
@@ -27,9 +30,13 @@ from flinkml_tpu.utils.metrics import metrics
 SPAN_PREFIX = "flinkml:"
 #: The metric group the spans count into.
 SPAN_GROUP = "span"
+#: Prefix of a phase's component in an operation's ``op_name`` path
+#: (``jit(w2v_sgns_loop)/while/body/flinkml.w2v.draw/sort``): a reader
+#: finds the phase without knowing the names.
+PHASE_PREFIX = "flinkml."
 
 # The spans open on this thread, outermost first: a span's parent is the
-# one under it.
+# one under it. ``.phase`` is the phase open on this thread, if any.
 _OPEN = threading.local()
 
 try:
@@ -46,16 +53,56 @@ except Exception:  # noqa: BLE001 — any jaxlib without the flag
         return False
 
 
-def named_program(name: str, fn: Callable) -> Callable:
+def named_program(name: str, fn: Callable, phases: Sequence[str] = ()) -> Callable:
     """``fn`` under the name ``jax.jit`` takes its module's from
     (``jit_<name>``: the event of each of its runs on a profile's ``XLA
     Modules`` row, and the first line of its lowered text). Called on the
     function a program is built from, before ``shard_map`` or ``jit``
     wraps it; ``fn`` itself is renamed, so hand it a function built for
     that one program. The name is part of the compile cache's key.
-    ``docs/development/observability.md`` ("Programs") lists the names."""
+
+    ``phases`` declares the :func:`phase` names ``fn`` opens. The cache
+    keys a program with its debug information stripped, a phase's name
+    with it, so the declaration is made part of the module's name
+    (``jit_<name>.<digits>``, a checksum of the names; a profile's reader
+    takes ``.<digits>`` off again): an executable compiled before a phase
+    existed or was renamed is then another key and is never loaded for
+    this program. ``docs/development/observability.md`` ("Programs",
+    "Phases") lists the names."""
+    if phases:
+        name = f"{name}.{zlib.crc32(' '.join(phases).encode())}"
     fn.__name__ = fn.__qualname__ = name
     return fn
+
+
+@contextlib.contextmanager
+def phase(name: str) -> Iterator[None]:
+    """One part of a compiled program: ``with phase("w2v.draw"):`` around
+    the lines that compute it, inside the function a
+    :func:`named_program` is built from (which declares it). Every
+    operation traced inside carries ``flinkml.<name>`` in its ``op_name``
+    path, loop bodies and kernels included, and a traced run's profile
+    gives the device time of the phase's operations
+    (``benchmark/readers/trace_phase_device_time``).
+
+    It is ``jax.named_scope`` and nothing else: no counter, no operation,
+    no synchronisation, and it runs only while JAX traces the function,
+    once a compile. Phases do not nest (``RuntimeError`` at trace time):
+    an operation is in one phase or none, and a program's phases add up
+    with its unphased time to its device time.
+
+    A new phase comes with the metric that reads it
+    (``docs/development/observability.md``)."""
+    inside = getattr(_OPEN, "phase", None)
+    if inside is not None:
+        raise RuntimeError(
+            f"phase {name!r} opened inside phase {inside!r}: phases do not nest")
+    _OPEN.phase = name
+    try:
+        with jax.named_scope(PHASE_PREFIX + name):
+            yield
+    finally:
+        _OPEN.phase = None
 
 
 @contextlib.contextmanager

@@ -18,6 +18,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 
+def _phases(text: str) -> set:
+    """The ``profiling.phase`` names in the ``op_name`` paths of a
+    compiled program's instructions."""
+    from .test_phases import _PHASE_IN_PATH
+
+    return set(_PHASE_IN_PATH.findall(text))
+
+
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
@@ -144,6 +152,7 @@ def test_kmeans_whole_loop_at_the_cells_size(topo, no_compile_cache):
     text = compiled.as_text()
     assert text.count("operand_precision={highest,highest}") == 2
     assert "tpu_custom_call" not in text          # XLA's lowering, no kernel
+    assert _phases(text) == set(kmeans.PHASES)
     memory = compiled.memory_analysis()
     assert 0.39 * 16e9 < memory.argument_size_in_bytes < 0.41 * 16e9
     assert memory.temp_size_in_bytes < 0.05e9
@@ -203,6 +212,7 @@ def test_fm_adam_loop_at_the_cells_size(topo, no_compile_cache, precision):
     # every product of the walk at the precision asked for
     assert (text.count("operand_precision={highest,highest}") > 0) == (
         precision == "HIGHEST")
+    assert _phases(text) == set(_fm_sparse.PHASES)
     memory = compiled.memory_analysis()
     # the cells, labels and weights: 5.37 GB, a third of the chip
     assert 0.33 * 16e9 < memory.argument_size_in_bytes < 0.36 * 16e9
@@ -268,6 +278,7 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
         _linear_sgd._sparse_trainer_bucketed.cache_clear()
     text = compiled.as_text()
     assert "lr_sparse_loop" in text and text.count("tpu_custom_call") == 2
+    assert _phases(text) == set(_linear_sgd.SPARSE_PHASES)
     memory = compiled.memory_analysis()
     # the cells, labels and weights: 5.37 GB, a third of one chip
     assert 0.33 * 16e9 < chips * memory.argument_size_in_bytes < 0.36 * 16e9
@@ -512,6 +523,7 @@ def test_als_half_step_at_the_cells_size(topo, no_compile_cache, side):
     buckets = len(plan.plan[0]) + bool(plan.plan[1][1])
     # every bucket's product at the precision the configuration states
     assert text.count("operand_precision={highest,highest}") >= buckets
+    assert _phases(text) == set(_als_blocked.PHASES)
     memory = compiled.memory_analysis()
     slots_and_fixed = 8 * plan.slots_local + 512 * (fixed_rows + 1)
     assert slots_and_fixed < memory.argument_size_in_bytes < slots_and_fixed + 0.1e9
@@ -599,6 +611,8 @@ def test_w2v_whole_fit_at_the_cells_size_holds_no_vocabulary_sized_temporary(
     text = compiled.as_text()
     assert len(re.findall(r"= \([^\n]*\) while\(", text)) == 1
     assert text.count("tpu_custom_call") == (2 if updates == "sorted" else 0)
+    assert _phases(text) == set(
+        _w2v_table.PHASES if updates == "sorted" else _w2v_table.PHASES_UNSORTED)
     memory = compiled.memory_analysis()
     table = vocab * lanes * 4
     assert memory.temp_size_in_bytes < 0.3 * table
@@ -691,6 +705,7 @@ def test_gbt_forest_at_the_cells_size_holds_no_rows_by_features_array_wider_than
         compiled = traced.lower().compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == (6 if histograms == "kernel" else 0)
+    assert _phases(text) == set(_gbt_table.PHASES)
     itemsize = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2}
     wide = set()
     for dtype, dims in re.findall(r"\b(pred|[suf]\d+|bf16)\[([\d,]+)\]", text):

@@ -967,7 +967,8 @@ def test_the_trainer_under_the_empty_plan_lowers_to_the_text_before_plans(
     now = _linear_sgd._sparse_trainer_bucketed(m, loss, sizes, "data", 300, "xla")
     before = _linear_sgd._whole_loop(
         m, _step_before_plans(loss, sizes, "data", 300), 4 * len(widths), "data",
-        "lr_sparse_loop")
+        # under the module's name of today: the phases it declares are in it
+        "lr_sparse_loop", _linear_sgd.SPARSE_PHASES)
     text = now.lower(*args).as_text()
     assert text == before.lower(*args).as_text()
     assert "gather" in text and "dynamic_slice" not in text.replace(
