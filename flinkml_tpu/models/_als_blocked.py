@@ -26,9 +26,12 @@ million users of rank 100. Here neither array exists:
   what :func:`chunk_slots` read off the device's free memory). A target
   with more ratings than a chunk holds is cut in pieces of a whole chunk
   each, whose partial sums are added before the solve.
-- **A chunk** (:func:`_chunk_systems`): the slots' fixed-side rows ``Y
-  [c, L, 128]`` fetched (rank padded to 128 lanes, lane ``k`` free), ONE
-  batched product ``Y' [a Y | b]`` at :data:`GRAM_PRECISION` giving ``A``
+- **A chunk** (``systems``): the slots' fixed-side rows ``Y [c, L,
+  128]`` fetched (rank padded to 128 lanes, lane ``k`` free; XLA's gather,
+  or, where the fixed side's heaviest rows cover enough of the slots,
+  ``kernels.row_fetch``: those rows read out of fast memory and the gather
+  kept for the cold slots alone, :func:`_lay_fetch`), ONE batched product
+  ``Y' [a Y | b]`` at :data:`GRAM_PRECISION` giving ``A``
   and the right-hand side together as ``[c, k, k + 1]``, regularised,
   solved (:func:`_solve`: on a TPU ``kernels.spd_solve``, a system a
   lane; elsewhere XLA's Cholesky), ``[c, k]`` written.
@@ -47,6 +50,7 @@ group ``als``).
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -54,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from flinkml_tpu.kernels import _gate, spd_solve
+from flinkml_tpu.kernels import _gate, row_fetch, spd_solve
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.parallel.mesh import _GATHER_THREADS, gather_pool
 from flinkml_tpu.table import _free_bytes
@@ -88,6 +92,7 @@ class _Side(NamedTuple):
     """One order of the ratings as a device's loops read it."""
 
     idx: jax.Array        # [p * slots_local] int32: fixed-side rows, the zero row at padding
+                          # (with ``fetch``: row_fetch's local indices)
     val: jax.Array        # [p * slots_local] float32 ratings, 0 at padding
     counts: jax.Array     # [p * rows_local] float32 ratings a local target
     owner: jax.Array      # [p * pieces] int32: a piece's local cut target
@@ -95,6 +100,18 @@ class _Side(NamedTuple):
     plan: Tuple           # ((length, chunk, chunks), ...), (pieces, cut targets), slots a piece
     targets: int
     slots: int            # all devices', padding included
+    # Where kernels.row_fetch fetches the rows (:func:`_lay_fetch`): the
+    # devices' cold ids, where a turn's begin and the tiles' starts, the hot
+    # rows' ids; its static plan (hot rows, a tile's DMA); and the slots that
+    # name a hot row.
+    fetch: Tuple = ()
+    fetch_plan: Optional[Tuple] = None
+    hot_slots: int = 0
+
+    @property
+    def operands(self) -> Tuple:
+        """What the side's program takes before the fixed side."""
+        return (*self[:5], *self.fetch)
 
 
 class _Placed(NamedTuple):
@@ -307,15 +324,112 @@ def _slot_order(order: _Order, plan: _Plan, p: int, sentinel: int) -> np.ndarray
     return out
 
 
+def _loops(plan: Tuple):
+    """``(slots a turn, turns)`` of a device's loops in slot order: every
+    bucket's chunks, then the cut targets' pieces."""
+    buckets, (pieces, _), piece = plan
+    return ([(length * chunk, chunks) for length, chunk, chunks in buckets]
+            + ([(piece, pieces)] if pieces else []))
+
+
+#: Slots a thread lays at a time in :func:`_lay_fetch`.
+_LAY_SLOTS = 1 << 21
+
+
+def hot_rows_of(degrees: np.ndarray, slots: int):
+    """The fixed side's hot rows where ``kernels.row_fetch`` fetches a
+    side's ``slots`` slots, None where XLA's gather does
+    (``row_fetch.unsupported_reason``: the backend, and the share of the
+    slots that name a hot row): ``(ids [hot] int32, cold slots)``, the
+    rows with the most ratings and, last, the zero row (which every
+    padding slot names; it fills a short table's list), and the slots
+    that name any other row. ``degrees`` are the ratings of the fixed
+    side's rows, the zero row's not among them."""
+    rows = degrees.size
+    hot = row_fetch.hot_rows(rows + 1)
+    heavy = (np.arange(rows) if rows < hot
+             else np.argpartition(degrees, rows - (hot - 1))[rows - (hot - 1):])
+    ids = np.full(hot, rows, np.int32)
+    ids[:heavy.size] = heavy
+    cold = int(degrees.sum()) - int(degrees[heavy].sum())
+    if row_fetch.unsupported_reason(np.float32, LANES, 1.0 - cold / max(1, slots)):
+        return None
+    return ids, cold
+
+
+def _lay_fetch(other: np.ndarray, slots: np.ndarray, plan: Tuple, hot_ids: np.ndarray,
+               p: int):
+    """The devices' slots as ``kernels.row_fetch`` reads them, a turn of a
+    loop a call, from ``other`` (the fixed side's column, the zero row's
+    behind it) and ``slots`` (:func:`_slot_order`): ``(loc [p *
+    slots_local], cold [p * cold ids a device], cold_at [p * (turns a
+    device + 1)], starts [p * tiles a device], (hot rows, a tile's
+    DMA))``: a turn's cold ids are ``cold[cold_at[turn]:cold_at[turn +
+    1]]`` of its device's, whole blocks. No shape but the cold ids'
+    total (in steps of a sixteenth or so of itself) and the DMA's rows (a
+    power of two) follows the table's ids, so a program compiled for one
+    table serves another of the same plan. The gather pool's threads take
+    :data:`_LAY_SLOTS` slots at a time: gathered and laid while in cache."""
+    loops = _loops(plan)
+    rank = row_fetch.ranks(hot_ids, int(other[-1]) + 1)
+    loc = np.empty(slots.size, np.int32)
+    jobs = []                               # (device, first slot, turns, slots a turn)
+    for device in range(p):
+        at = device * (slots.size // p)
+        for n, turns in loops:
+            each = max(1, _LAY_SLOTS // n)
+            jobs += [(device, at + lo * n, min(each, turns - lo), n)
+                     for lo in range(0, turns, each)]
+            at += turns * n
+
+    def lay(job):
+        _, at, turns, n = job
+        ids = other.take(slots[at:at + turns * n], mode="clip").reshape(turns, n)
+        return row_fetch.localize(ids, rank, hot_ids.size,
+                                  loc[at:at + turns * n].reshape(turns, n))
+
+    with gather_pool() as pool:
+        laid = list(pool.map(lay, jobs))
+    by_device = [[local for job, local in zip(jobs, laid) if job[0] == device]
+                 for device in range(p)]
+    lengths = [np.concatenate([local.lengths for local in mine]) for mine in by_device]
+    most = max(row_fetch.BLOCK, max(int(own.sum()) for own in lengths))
+    step = max(row_fetch.BLOCK, 1 << (most.bit_length() - 4))   # a sixteenth or so
+    held = -(-most // step) * step
+    cold = np.zeros((p, held), np.int32)
+    for device, mine in enumerate(by_device):
+        listed = np.concatenate([local.cold for local in mine])
+        cold[device, :listed.size] = listed
+    cold_at = np.stack([np.concatenate([[0], np.cumsum(own)]) for own in lengths])
+    starts = np.concatenate([local.starts.reshape(-1) for local in laid])
+    run = max(local.run for local in laid)
+    cap = max(row_fetch.GROUP, 1 << (run - 1).bit_length())
+    return (loc, cold.reshape(-1), cold_at.astype(np.int32).reshape(-1), starts,
+            (hot_ids.size, cap))
+
+
 def _place_side(order: _Order, other: np.ndarray, ratings: np.ndarray,
-                plan: _Plan, mesh: DeviceMesh):
+                plan: _Plan, mesh: DeviceMesh, hot: Optional[Tuple] = None):
     """One order on the mesh: ``other`` (the fixed side's positions, the
     zero row's behind them) and ``ratings`` (a 0 behind them) gathered
-    into the slots and sent through :meth:`DeviceMesh.stage_rows`."""
+    into the slots and sent through :meth:`DeviceMesh.stage_rows`. With
+    ``hot`` (:func:`hot_rows_of`) the slots hold ``kernels.row_fetch``'s
+    local indices, and the cold slots' ids lie beside them."""
     p = mesh.axis_size()
     slots = _slot_order(order, plan, p, other.size - 1)
-    *_, ((idx, val), _) = mesh.stage_rows(
-        [(other, slots, np.int32), (ratings, slots, np.float32)])
+    fetch, fetch_plan, hot_slots = (), None, 0
+    if hot is not None:
+        hot_ids, cold_slots = hot
+        loc, cold, cold_at, starts, fetch_plan = _lay_fetch(
+            other, slots, plan.plan, hot_ids, p)
+        *_, ((val,), _) = mesh.stage_rows([(ratings, slots, np.float32)])
+        idx = mesh.shard_batch(loc)
+        fetch = (mesh.shard_batch(cold), mesh.shard_batch(cold_at),
+                 mesh.shard_batch(starts), mesh.replicate(hot_ids))
+        hot_slots = slots.size - cold_slots
+    else:
+        *_, ((idx, val), _) = mesh.stage_rows(
+            [(other, slots, np.int32), (ratings, slots, np.float32)])
     degrees = np.diff(order.indptr)
     held = degrees > 0
     counts = np.zeros((p, plan.rows_local), np.float32)
@@ -323,7 +437,8 @@ def _place_side(order: _Order, other: np.ndarray, ratings: np.ndarray,
     where = (plan.device * plan.rows_local + plan.row).astype(np.int32)
     return _Side(idx, val, mesh.shard_batch(counts.reshape(-1)),
                  mesh.shard_batch(plan.owner.reshape(-1)), mesh.replicate(where),
-                 plan.plan, degrees.size, p * plan.slots_local)
+                 plan.plan, degrees.size, p * plan.slots_local,
+                 fetch, fetch_plan, hot_slots)
 
 
 def _with_tail(column: np.ndarray, tail, dtype) -> np.ndarray:
@@ -347,16 +462,25 @@ def place(users, items, ratings, mesh: DeviceMesh, make_room) -> _Placed:
         # The chunk is read off the room both orders will leave: 8 bytes
         # a slot, a third more slots than ratings at the most.
         piece = chunk_slots(devices, int(2 * 8 * 4 / 3 * u.size / p))
-        plans = (plan_side(np.diff(by_user.indptr), p, piece),
-                 plan_side(np.diff(by_item.indptr), p, piece))
+        degrees = np.diff(by_user.indptr), np.diff(by_item.indptr)
+        plans = plan_side(degrees[0], p, piece), plan_side(degrees[1], p, piece)
+        # A side's fixed side is the other side's targets.
+        hot = (hot_rows_of(degrees[1], p * plans[0].slots_local),
+               hot_rows_of(degrees[0], p * plans[1].slots_local))
         u, i = _with_tail(u, user_ids.size, np.int32), _with_tail(i, item_ids.size, np.int32)
         r = _with_tail(np.asarray(ratings), 0, np.float32)
     nbytes = sum(p * (8 * plan.slots_local + 4 * plan.rows_local + 4 * plan.owner.shape[1])
                  + 4 * plan.row.size for plan in plans)
-    make_room(nbytes, devices)
-    with span("als.table_to_device") as phase:
-        sides = (_place_side(by_user, i, r, plans[0], mesh),
-                 _place_side(by_item, u, r, plans[1], mesh))
+    # Under row_fetch the cold slots' ids come on top: four bytes each, and
+    # a quarter more for the padding to whole groups, blocks and steps.
+    make_room(nbytes + sum(5 * side[1] for side in hot if side is not None), devices)
+    with span("als.table_to_device") as phase, ThreadPoolExecutor(2) as both:
+        # The two orders side by side: a side's passes are NumPy's on the
+        # gather pool's threads and leave cores idle between them.
+        sides = tuple(both.map(
+            lambda side: _place_side(*side[:3], side[3], mesh, side[4]),
+            ((by_user, i, r, plans[0], hot[0]), (by_item, u, r, plans[1], hot[1]))))
+        nbytes += sum(x.nbytes for side in sides for x in side.fetch)
         phase.add(bytes=nbytes)
     counters = metrics.group("als")
     counters.counter("table_uploads")
@@ -382,22 +506,25 @@ def _solve(aug, rank: int, on_lanes: bool):
 
 
 def make_half_step(plan: Tuple, rank: int, implicit: bool, axis: str,
-                   precision=GRAM_PRECISION, on_lanes: bool = False):
+                   precision=GRAM_PRECISION, on_lanes: bool = False,
+                   fetch_plan: Optional[Tuple] = None):
     """One device's half-step under ``plan`` (:func:`plan_side`):
     ``(idx, val, counts, owner, where, fixed [n + 1, 128], reg, alpha) ->
     (table [targets + 1, 128], rows [targets, rank])``, the solved
     factors of ALL targets (every device's, gathered), as the next
     half-step's fixed side (lanes padded, the zero row last) and as the
-    model holds them."""
+    model holds them. Under a ``fetch_plan`` (:func:`_lay_fetch`) ``idx``
+    holds ``kernels.row_fetch``'s local indices and ``cold, cold_at,
+    starts, hot_ids`` come before ``fixed``."""
     buckets, (pieces, cut), piece = plan
     width = spd_solve.augmented_width(rank)
     f32 = jnp.float32
 
-    def systems(ids, r, fixed, alpha):
+    def systems(y, r, alpha):
         """``[c, rank, width]``: ``A`` and ``b`` of the ``c`` targets
-        whose slots ``ids``, ``r`` ``[c, L]`` are, unregularised."""
+        whose slots' fixed-side rows ``y [c, L, 128]`` and ratings ``r
+        [c, L]`` are, unregularised."""
         with phase("als.fetch"):
-            y = fixed.at[ids].get(mode="promise_in_bounds")   # [c, L, 128]
             lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, LANES), 2)
             if implicit:
                 a_w = alpha * r
@@ -415,40 +542,67 @@ def make_half_step(plan: Tuple, rank: int, implicit: bool, axis: str,
             eye = jnp.eye(rank, width, dtype=f32)
             return _solve(g + shared + lam[:, None, None] * eye, rank, on_lanes)
 
-    def half_step(idx, val, counts, owner, where, fixed, reg, alpha):
+    def half_step(idx, val, counts, owner, where, *rest):
+        *fetch, fixed, reg, alpha = rest
         if implicit:
             shared = jnp.einsum("nk,nm->km", fixed, fixed,
                                 precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=f32)[:rank, :width]
         else:
             shared = jnp.zeros((rank, width), f32)
-        out, slot, row = [], 0, 0
+        if fetch_plan is not None:
+            cold, cold_at, starts, hot_ids = fetch
+            _, cap = fetch_plan
+            with phase("als.fetch"):
+                hot = fixed.at[hot_ids].get(mode="promise_in_bounds")
+
+        def fetched(rows, i, slot, n, turn, tile_at):
+            """Turn ``i`` of a loop of ``n`` slots a turn from ``slot``: their
+            fixed-side rows ``[n, 128]`` and ratings ``[n]``. Under a fetch
+            plan ``rows`` is the loop's buffer of cold rows, handed on."""
+            with phase("als.fetch"):
+                ids = jax.lax.dynamic_slice(idx, (slot + i * n,), (n,))
+                r = jax.lax.dynamic_slice(val, (slot + i * n,), (n,))
+                if fetch_plan is None:
+                    return rows, fixed.at[ids].get(mode="promise_in_bounds"), r
+                tiles = row_fetch.tiles_of(n)
+                first, end = cold_at[turn + i], cold_at[turn + i + 1]
+                rows = row_fetch.fetch_cold(
+                    fixed, cold, first, (end - first) // row_fetch.BLOCK, rows)
+                return rows, row_fetch.fetch(
+                    ids, jax.lax.dynamic_slice(starts, (tile_at + i * tiles,), (tiles,)),
+                    hot, rows, cap=cap), r
+
+        def turns_of(n, turns, one_turn):
+            """``one_turn(rows, i) -> (rows, out)`` over a loop's turns."""
+            rows = (() if fetch_plan is None
+                    else jnp.zeros((row_fetch.cold_rows(n, cap), LANES), f32))
+            return jax.lax.scan(one_turn, rows, jnp.arange(turns, dtype=jnp.int32))[1]
+
+        out, slot, row, turn, tile_at = [], 0, 0, 0, 0
         for length, chunk, chunks in buckets:
 
-            def one_chunk(i, slot=slot, row=row, length=length, chunk=chunk):
-                at = slot + i * (chunk * length)
-                with phase("als.fetch"):
-                    ids = jax.lax.dynamic_slice(idx, (at,), (chunk * length,))
-                    r = jax.lax.dynamic_slice(val, (at,), (chunk * length,))
-                g = systems(ids.reshape(chunk, length), r.reshape(chunk, length),
-                            fixed, alpha)
+            def one_chunk(rows, i, at=(slot, chunk * length, turn, tile_at),
+                          row=row, length=length, chunk=chunk):
+                rows, y, r = fetched(rows, i, *at)
+                g = systems(y.reshape(chunk, length, LANES), r.reshape(chunk, length),
+                            alpha)
                 n = jax.lax.dynamic_slice(counts, (row + i * chunk,), (chunk,))
-                return solved(g, n, shared, reg)
+                return rows, solved(g, n, shared, reg)
 
-            out.append(jax.lax.map(one_chunk, jnp.arange(chunks, dtype=jnp.int32))
+            out.append(turns_of(chunk * length, chunks, one_chunk)
                        .reshape(chunks * chunk, rank))
             slot += chunks * chunk * length
             row += chunks * chunk
+            turn += chunks
+            tile_at += chunks * row_fetch.tiles_of(chunk * length)
         if cut:
 
-            def one_piece(i, slot=slot):
-                at = slot + i * piece
-                with phase("als.fetch"):
-                    ids = jax.lax.dynamic_slice(idx, (at,), (piece,))
-                    r = jax.lax.dynamic_slice(val, (at,), (piece,))
-                return systems(ids[None], r[None], fixed, alpha)[0]
+            def one_piece(rows, i, at=(slot, piece, turn, tile_at)):
+                rows, y, r = fetched(rows, i, *at)
+                return rows, systems(y[None], r[None], alpha)[0]
 
-            parts = jax.lax.map(one_piece, jnp.arange(pieces, dtype=jnp.int32))
+            parts = turns_of(piece, pieces, one_piece)
             # The padding's pieces name the target ``cut``: dropped.
             whole = jax.ops.segment_sum(parts, owner[:pieces], num_segments=cut)
             out.append(solved(whole, jax.lax.dynamic_slice(counts, (row,), (cut,)),
@@ -464,13 +618,15 @@ def make_half_step(plan: Tuple, rank: int, implicit: bool, axis: str,
 
 
 @functools.lru_cache(maxsize=32)
-def _program(mesh, plan: Tuple, rank: int, implicit: bool, precision, on_lanes: bool):
+def _program(mesh, plan: Tuple, rank: int, implicit: bool, precision, on_lanes: bool,
+             fetch_plan: Optional[Tuple] = None):
     """The program ``als_half_step`` of one side's plan on ``mesh``."""
     axis = DeviceMesh.DATA_AXIS
-    fn = make_half_step(plan, rank, implicit, axis, precision, on_lanes)
+    fn = make_half_step(plan, rank, implicit, axis, precision, on_lanes, fetch_plan)
+    fetch_specs = () if fetch_plan is None else (P(axis), P(axis), P(axis), P())
     return jax.jit(jax.shard_map(
         named_program("als_half_step", fn, phases=PHASES), mesh=mesh,
-        in_specs=(P(axis),) * 4 + (P(),) * 4, out_specs=(P(), P()),
+        in_specs=(P(axis),) * 4 + (P(),) + fetch_specs + (P(),) * 3, out_specs=(P(), P()),
         # The outputs are made of the all-gathered rows, the same on every
         # device: what the replication check cannot see of an all_gather.
         check_vma=False))
@@ -509,8 +665,9 @@ def fit_table(est, table, precision=GRAM_PRECISION):
         reg, alpha = np.float32(est.get(est.REG_PARAM)), np.float32(est.get(est.ALPHA))
         users_from, items_from = (
             functools.partial(
-                _program(mesh.mesh, side.plan, rank, implicit, precision, on_lanes),
-                *side[:5]) for side in (placed.by_user, placed.by_item))
+                _program(mesh.mesh, side.plan, rank, implicit, precision, on_lanes,
+                         side.fetch_plan),
+                *side.operands) for side in (placed.by_user, placed.by_item))
         with span("als.loop"):
             with span("als.dispatch"):
                 for _ in range(max_iter):
@@ -528,6 +685,8 @@ def fit_table(est, table, precision=GRAM_PRECISION):
     slots = max_iter * (placed.by_user.slots + placed.by_item.slots)
     counters.counter("rating_slots", float(slots))
     counters.counter("padding_slots", float(slots - 2 * max_iter * placed.ratings))
+    counters.counter("hot_slots",
+                     float(max_iter * (placed.by_user.hot_slots + placed.by_item.hot_slots)))
     counters.counter("targets",
                      float(max_iter * (placed.by_user.targets + placed.by_item.targets)))
     return placed.user_ids, user_rows, placed.item_ids, item_rows

@@ -14,7 +14,10 @@ which imports nothing of the program), on seeded data at small sizes:
   go when a device reports no room; a fit equals its repeat to the bit;
 - the ingest: vocabularies equal to ``np.unique``'s, both orders stable;
 - the lane solver (``kernels/spd_solve``, interpreted) against NumPy's
-  float64 solve.
+  float64 solve;
+- the rows fetched by ``kernels/row_fetch`` (interpreted) where the hot
+  rows cover enough of the slots: half-steps and whole fits equal to the
+  gather's to the bit, and where the choice falls.
 """
 
 import gc
@@ -30,7 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.reference import als as reference  # noqa: E402
 from flinkml_tpu import table as table_mod  # noqa: E402
-from flinkml_tpu.kernels import spd_solve  # noqa: E402
+from flinkml_tpu.kernels import _gate, row_fetch, spd_solve  # noqa: E402
 from flinkml_tpu.models import ALS, ALSModel, _als_blocked  # noqa: E402
 from flinkml_tpu.parallel import DeviceMesh  # noqa: E402
 from flinkml_tpu.table import Table  # noqa: E402
@@ -151,10 +154,11 @@ def test_pairs_that_come_twice_count_twice(small_chunks):
 
 # -- the half-step on hand-made degrees -------------------------------------------
 
-def _half_step(degrees, piece, rank=6, p=1, implicit=False, seed=0):
+def _half_step(degrees, piece, rank=6, p=1, implicit=False, seed=0, hot_ids=None):
     """One half-step of targets with ``degrees`` ratings each over 50
-    fixed rows, through :func:`plan_side` and the program; and the
-    float64 answer."""
+    fixed rows, through :func:`plan_side` and the program (with
+    ``hot_ids``: its rows fetched by ``kernels.row_fetch``, interpreted);
+    and the float64 answer."""
     rng = np.random.default_rng(seed)
     degrees = np.asarray(degrees, np.int64)
     indptr = np.concatenate([[0], np.cumsum(degrees)])
@@ -168,12 +172,14 @@ def _half_step(degrees, piece, rank=6, p=1, implicit=False, seed=0):
         side = _als_blocked._place_side(
             _als_blocked._Order(indptr, None),
             np.append(other, fixed_rows).astype(np.int32),
-            np.append(r, 0).astype(np.float32), plan, mesh)
+            np.append(r, 0).astype(np.float32), plan, mesh,
+            None if hot_ids is None else (hot_ids, int((~np.isin(other, hot_ids)).sum())))
         table = mesh.replicate(jnp.pad(jnp.asarray(fixed),
                                        ((0, 1), (0, _als_blocked.LANES - rank))))
         program = _als_blocked._program(
-            mesh.mesh, side.plan, rank, implicit, _als_blocked.GRAM_PRECISION, False)
-        padded, rows = program(*side[:5], table, np.float32(0.3), np.float32(0.5))
+            mesh.mesh, side.plan, rank, implicit, _als_blocked.GRAM_PRECISION, False,
+            side.fetch_plan)
+        padded, rows = program(*side.operands, table, np.float32(0.3), np.float32(0.5))
     want = reference.solve_targets(
         [(other[lo:hi], r[lo:hi]) for lo, hi in zip(indptr[:-1], indptr[1:])],
         fixed, 0.3, implicit, 0.5)
@@ -196,6 +202,111 @@ def test_a_half_step_solves_every_target_over_all_its_ratings(degrees, piece, p)
     assert not padded[-1].any() and not padded[:, rows.shape[1]:].any()
     np.testing.assert_array_equal(padded[:-1, :rows.shape[1]], rows)
     assert side.slots >= sum(degrees)
+
+
+HALF_STEPS = [
+    ([0, 1, 2, 0, 7], 64),                    # no rating, one rating
+    ([5, 300, 9, 64, 65, 1000, 3], 64),       # more than a chunk holds: cut in pieces
+    ([64] * 9 + [1] * 20, 64),                # whole chunks and a short last one
+    (list(range(1, 40)), 16),                 # every length of the ladder's foot
+    ([3000, 2500, 40, 7], 4096),              # chunks and pieces of several tiles
+]
+
+
+@pytest.mark.parametrize("hot", ["seven rows", "every row", "the zero row alone"])
+@pytest.mark.parametrize("degrees,piece", HALF_STEPS, ids=[
+    "none-and-one", "cut-in-pieces", "short-last-chunk", "ladder", "several-tiles"])
+@pytest.mark.parametrize("p", [1, 4])
+def test_a_half_step_through_the_fetch_kernel_is_the_gathers_to_the_bit(
+        degrees, piece, p, hot):
+    """The rows are copied, so the same products and the same solves: the
+    padding's slots name the zero row (the hot rows' last), a turn's cold
+    slots its own list of ids, whatever the hot rows are."""
+    hot_ids = {"seven rows": np.r_[3, 11, 12, 20, 31, 40, 49, 50],
+               "every row": np.r_[0:50, [50] * 14],
+               "the zero row alone": np.full(8, 50)}[hot].astype(np.int32)
+    _, gathered, want, plain = _half_step(degrees, piece, p=p)
+    _, fetched, _, side = _half_step(degrees, piece, p=p, hot_ids=hot_ids)
+    np.testing.assert_array_equal(fetched, gathered)
+    assert plain.fetch_plan is None and plain.hot_slots == 0 and not plain.fetch
+    hot_rows, cap = side.fetch_plan
+    assert hot_rows == hot_ids.size and cap >= 8 and cap & (cap - 1) == 0
+    cold, cold_at, starts, _ = side.fetch
+    turns = sum(turns for _, turns in _als_blocked._loops(side.plan))
+    assert cold_at.shape == (p * (turns + 1),) and cold.shape[0] % p == 0
+    assert starts.shape == (p * sum(row_fetch.tiles_of(n) * turns
+                                    for n, turns in _als_blocked._loops(side.plan)),)
+    assert side.slots - sum(degrees) <= side.hot_slots <= side.slots
+    if hot == "every row":
+        assert side.hot_slots == side.slots
+    if hot == "the zero row alone":
+        assert side.hot_slots == side.slots - sum(degrees)
+
+
+@pytest.fixture
+def fetch_kernel_taken(monkeypatch):
+    """The choice a TPU makes for a skewed table, here: the kernel runs
+    interpreted."""
+    monkeypatch.setattr(row_fetch, "unsupported_reason", lambda *a: None)
+
+
+@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+@pytest.mark.parametrize("p", [1, 8])
+def test_a_fit_through_the_fetch_kernel_is_the_gathers_fit_to_the_bit(
+        p, implicit, small_chunks, monkeypatch):
+    u, i, r = _ratings(seed=21)
+    before = _als_counters()
+    gathered = _als(8, implicit=implicit, mesh=_mesh(p)).fit(_table(u, i, r))
+    between = _als_counters()
+    monkeypatch.setattr(row_fetch, "unsupported_reason", lambda *a: None)
+    fetched = _als(8, implicit=implicit, mesh=_mesh(p)).fit(_table(u, i, r))
+    after = _als_counters()
+    for a, b in zip(gathered.factors(), fetched.factors()):
+        np.testing.assert_array_equal(a, b)
+    assert _gap(fetched, _want(u, i, r, 8, 3, 0.1, 1, implicit)) < TOL
+    # the gather serves no slot from fast memory; here every row is hot
+    assert between.get("hot_slots", 0) == before.get("hot_slots", 0)
+    assert (after["hot_slots"] - between.get("hot_slots", 0)
+            == after["rating_slots"] - between["rating_slots"])
+    # the cold slots' ids and the tiles' starts are part of what was sent
+    assert (after["table_h2d_bytes"] - between["table_h2d_bytes"]
+            > between["table_h2d_bytes"] - before.get("table_h2d_bytes", 0))
+
+
+def test_eight_devices_through_the_fetch_kernel_are_the_one_device_fit(
+        small_chunks, fetch_kernel_taken):
+    u, i, r = _ratings(seed=6)
+    one = _als(8).fit(_table(u, i, r))
+    eight = _als(8, mesh=_mesh(8)).fit(_table(u, i, r))
+    for a, b in zip(one.factors(), eight.factors()):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * np.abs(a).max())
+
+
+@pytest.mark.parametrize("case,taken", [
+    ("a CPU", False), ("skewed degrees", True), ("flat degrees", False),
+    ("a short table", True)])
+def test_the_hot_rows_follow_the_backend_and_the_degrees(monkeypatch, case, taken):
+    """``hot_rows_of``: the heaviest rows and the zero row where they
+    cover enough of the slots on a TPU, None everywhere else."""
+    monkeypatch.delenv(_gate.ENV_INTERPRET_VAR, raising=False)
+    if case != "a CPU":
+        monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    rng = np.random.default_rng(3)
+    rows = 40 if case == "a short table" else 400_000
+    degrees = (np.full(rows, 12) if case == "flat degrees"
+               else rng.permutation((3e6 / (np.arange(rows) + 10.0) ** 1.2).astype(np.int64)))
+    slots = int(degrees.sum() * 1.2)
+    hot = _als_blocked.hot_rows_of(degrees, slots)
+    if not taken:
+        assert hot is None
+        return
+    hot, cold_slots = hot
+    assert cold_slots == degrees.sum() - degrees[hot[hot < rows]].sum()
+    assert hot.dtype == np.int32 and hot.size == row_fetch.hot_rows(rows + 1)
+    assert hot[-1] == rows                         # the zero row
+    held = hot[hot < rows]
+    assert np.unique(held).size == held.size == min(rows, hot.size - 1)
+    assert degrees[held].min() >= np.sort(degrees)[::-1][held.size - 1]
 
 
 def test_a_plan_holds_every_rating_once_and_no_chunk_passes_a_piece():

@@ -478,17 +478,24 @@ def _yahoomusic_degrees(side: str):
     return np.rint(expected).astype(np.int64), users
 
 
+@pytest.mark.parametrize("fetch", ["gather", "row_fetch"])
 @pytest.mark.parametrize("side", ["user", "item"])
-def test_als_half_step_at_the_cells_size(topo, no_compile_cache, side):
+def test_als_half_step_at_the_cells_size(topo, no_compile_cache, monkeypatch, side, fetch):
     """``als-yahoomusic.fit``'s program of one side: a half-step over
     252,800,275 ratings at rank 100 under the side's plan (chunks of
     262,144 slots), the lane solver's Mosaic kernel included, on a
-    one-chip mesh (the ``all_gather`` included), in 32-bit mode. The
-    compiled program's own memory analysis holds that no ``[targets, k,
-    k]`` (40 GB of users) and no ``[ratings, k, k]`` exists: beside the
-    side's slots (2.5 GB) and the fixed side's factors it holds the
-    solved rows twice (padded to 128 lanes for the next half-step, and as
-    the model keeps them) and a chunk's scratch."""
+    one-chip mesh (the ``all_gather`` included), in 32-bit mode; with
+    every slot's row through XLA's gather, and as the cell runs it since
+    PR 50: ``kernels.row_fetch`` a chunk (65,536 hot rows in fast memory
+    under its own limit, whatever XLA fuses around the call) and the
+    gather over a chunk's cold ids alone, a block of 4,096 at a time into
+    the loop's one buffer (here three slots in ten; a tile's DMA 1,024
+    rows). The compiled program's own memory
+    analysis holds that no ``[targets, k, k]`` (40 GB of users) and no
+    ``[ratings, k, k]`` exists: beside the side's slots (2.5 GB) and the
+    fixed side's factors it holds the solved rows twice (padded to 128
+    lanes for the next half-step, and as the model keeps them) and a
+    chunk's scratch."""
     import os
     import sys
 
@@ -496,8 +503,10 @@ def test_als_half_step_at_the_cells_size(topo, no_compile_cache, side):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from flinkml_tpu.kernels import _gate, row_fetch
     from flinkml_tpu.models import _als_blocked
 
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
     degrees, fixed_rows = _yahoomusic_degrees(side)
     rank = 100
     plan = _als_blocked.plan_side(degrees, 1, _als_blocked._CHUNK_SLOTS)
@@ -509,28 +518,73 @@ def test_als_half_step_at_the_cells_size(topo, no_compile_cache, side):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     f32, i32 = jnp.float32, jnp.int32
+    loops = _als_blocked._loops(plan.plan)
+    fetch_plan, fetch_args = None, ()
+    if fetch == "row_fetch":
+        fetch_plan = (row_fetch.HOT_ROWS, 1024)
+        fetch_args = (
+            on((3 * plan.slots_local // 10 // row_fetch.BLOCK * row_fetch.BLOCK,),
+               i32, by_rows),
+            on((sum(turns for _, turns in loops) + 1,), i32, by_rows),
+            on((sum(turns * row_fetch.tiles_of(n) for n, turns in loops),), i32, by_rows),
+            on((row_fetch.HOT_ROWS,), i32))
     assert _als_blocked.GRAM_PRECISION == jax.lax.Precision.HIGHEST
     with jax.enable_x64(False):
         traced = _als_blocked._program(
-            mesh, plan.plan, rank, False, _als_blocked.GRAM_PRECISION, True).trace(
+            mesh, plan.plan, rank, False, _als_blocked.GRAM_PRECISION, True,
+            fetch_plan).trace(
             on((plan.slots_local,), i32, by_rows), on((plan.slots_local,), f32, by_rows),
             on((plan.rows_local,), f32, by_rows),
             on((plan.owner.shape[1],), i32, by_rows), on((degrees.size,), i32),
+            *fetch_args,
             on((fixed_rows + 1, 128), f32), on((), f32), on((), f32))
         assert not re.search(r"\b[fis]64\b", str(traced.jaxpr))   # Mosaic lowers none
         compiled = traced.lower().compile()
     text = compiled.as_text()
-    buckets = len(plan.plan[0]) + bool(plan.plan[1][1])
     # every bucket's product at the precision the configuration states
-    assert text.count("operand_precision={highest,highest}") >= buckets
+    assert text.count("operand_precision={highest,highest}") >= len(loops)
     assert _phases(text) == set(_als_blocked.PHASES)
+    # the solver a loop, and the fetch kernel a loop where it is taken
+    assert text.count("tpu_custom_call") == len(loops) * (1 + (fetch == "row_fetch"))
     memory = compiled.memory_analysis()
-    slots_and_fixed = 8 * plan.slots_local + 512 * (fixed_rows + 1)
+    slots_and_fixed = (8 * plan.slots_local + 512 * (fixed_rows + 1)
+                       + sum(4 * a.shape[0] for a in fetch_args))
     assert slots_and_fixed < memory.argument_size_in_bytes < slots_and_fixed + 0.1e9
     # [targets + 1, 128] and [targets, 100] float32
     assert memory.output_size_in_bytes < 1.0e9
     assert memory.temp_size_in_bytes < 2.0e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+
+
+@pytest.mark.parametrize("slots", [262_144, 261_888, 24])
+def test_the_row_fetch_kernel_at_the_cells_size(one_chip, no_compile_cache, slots):
+    """``kernels.row_fetch.fetch`` as Mosaic compiles it (not
+    interpreted) at ``als-yahoomusic``'s shapes: 65,536 hot rows of 128
+    lanes in ONE buffer of fast memory with two tiles of cold rows behind
+    them (34 MiB, inside the kernel's own limit and a v5e's 128 MiB), a
+    tile of 2,048 slots, runs of up to 1,024 cold rows read by one DMA; a
+    chunk of whole tiles, one that ends inside its last tile (a bucket of
+    1,023 targets of 256 slots), and one shorter than a tile."""
+    from flinkml_tpu.kernels import row_fetch
+
+    cap = 1024 if slots > row_fetch.TILE else 8
+    tiles = row_fetch.tiles_of(slots)
+    fetch = jax.jit(lambda loc, starts, hot, cold: row_fetch.fetch(
+        loc, starts, hot, cold, cap=cap, interpret=False))
+    with jax.enable_x64(True):
+        compiled = fetch.trace(
+            jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((tiles,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((row_fetch.HOT_ROWS, 128), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((row_fetch.cold_rows(slots, cap), 128), jnp.float32,
+                                 sharding=one_chip),
+        ).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f'"size":"{row_fetch.VMEM_LIMIT_BYTES}"' in text
+    assert (row_fetch.HOT_ROWS + 2 * row_fetch.TILE) * 512 < row_fetch.VMEM_LIMIT_BYTES
+    # nothing but the padding of the slots' local indices to whole tiles
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * row_fetch.TILE + 4096
 
 
 @pytest.mark.parametrize("entries", [300, 1536, 16_384, 98_304])
