@@ -125,7 +125,8 @@ class StreamingEstimatorMixin:
             raise ValueError(
                 f"{type(self).__name__} does not support precision yet "
                 "(policy-aware estimators: the linear family's dense "
-                "paths — LogisticRegression, LinearSVC, LinearRegression)"
+                "paths — LogisticRegression, LinearSVC, LinearRegression — "
+                "and the perceptrons' table fit)"
             )
         from flinkml_tpu.precision import resolve_policy
 

@@ -4,9 +4,21 @@
 The natural fit for this framework's design stance: the WHOLE training
 run is one device program — a ``lax.while_loop`` of Adam steps (with
 tol-based early stopping) over a data-sharded mesh, gradients
-``psum``-combined per step, every layer a batched MXU matmul. (The upstream operator trains with L-BFGS on the
-JVM; Adam-on-device is the TPU-idiomatic equivalent and is documented
+``psum``-combined per step. (The upstream operator trains with L-BFGS on
+the JVM; Adam-on-device is the TPU-idiomatic equivalent and is documented
 as such rather than imitated.)
+
+``fit(Table)`` is ``models/_mlp_table.py``: the table's rows placed once
+and kept with it, step ``t`` a window of them, every layer a product
+forward and two back, under ``precision="mixed"`` each with bfloat16
+operands and a float32 sum. What a chip makes of it, read at
+784-2500-2000-1500-1000-500-10 and a batch of 16,384 on a v5e (PR 52;
+PERF.md section 5, ``mlp-mnist8m.fit``): a ``mixed`` step is 8.18 ms on
+the device, 2.71 forward and 5.33 back, Adam's update inside the weight
+gradients' product fusions: 69 % of the 5.64 ms its 1.112 TFLOP take at
+the bfloat16 peak; with no policy (six bfloat16 passes a float32
+product) a step is 38 ms. ``fit`` of an iterable is the streamed fit
+below, whose batches are ``_adam``'s draw.
 
 Architecture: ``layers = [d_in, h_1, ..., h_k, n_classes]``, tanh hidden
 activations (the upstream convention), softmax output, cross-entropy
@@ -22,8 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from flinkml_tpu.api import Estimator, Model
+from flinkml_tpu.models import _mlp_table
 from flinkml_tpu.models._streaming import StreamingEstimatorMixin
-from flinkml_tpu.models._adam import make_adam_trainer
 from flinkml_tpu.common_params import (
     HasFeaturesCol,
     HasGlobalBatchSize,
@@ -37,8 +49,9 @@ from flinkml_tpu.common_params import (
 )
 from flinkml_tpu.models._data import features_matrix, labeled_data
 from flinkml_tpu.params import IntArrayParam, ParamValidators
-from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
+from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.profiling import span
 
 
 class _MLPParams(
@@ -55,19 +68,6 @@ class _MLPParams(
 class _MLPClassifierParams(_MLPParams, HasRawPredictionCol):
     """Only the classifier emits a rawPrediction column; the regressor
     must not carry the dead param."""
-
-
-def _init_params(layers: List[int], key) -> List:
-    params = []
-    for i in range(len(layers) - 1):
-        key, sub = jax.random.split(key)
-        scale = jnp.sqrt(2.0 / layers[i])
-        params.append((
-            jax.random.normal(sub, (layers[i], layers[i + 1]),
-                              jnp.float32) * scale,
-            jnp.zeros(layers[i + 1], jnp.float32),
-        ))
-    return params
 
 
 def _forward(params, x):
@@ -117,9 +117,18 @@ class _MLPBase(StreamingEstimatorMixin, _MLPParams, Estimator):
 
     _MODEL_CLS = None
     _LOSS_BUILDER = None
-
+    #: The table fit's loss: cross-entropy over class ids, or the square.
+    _CLASSIFY = True
+    #: ``precision=`` declares the products of the table fit
+    #: (``models/_mlp_table.py``).
+    _PRECISION_AWARE = True
 
     def _prepare_labels(self, y: np.ndarray, layers) -> np.ndarray:
+        raise NotImplementedError
+
+    def _check_labels(self, labels, layers) -> None:
+        """The table fit's label checks, from the column's kept
+        :class:`~flinkml_tpu.models._data.LabelFacts`."""
         raise NotImplementedError
 
     def _check_layers(self):
@@ -133,41 +142,12 @@ class _MLPBase(StreamingEstimatorMixin, _MLPParams, Estimator):
         if not isinstance(table, Table):
             return self._fit_stream(table)
         self._reject_in_ram_checkpointing()
-        layers = self._check_layers()
-        x, y, w = labeled_data(
-            table, self.get(self.FEATURES_COL), self.get(self.LABEL_COL)
-        )
-        if x.shape[1] != layers[0]:
-            raise ValueError(
-                f"layers[0]={layers[0]} != feature dim {x.shape[1]}"
-            )
-        y_dev = self._prepare_labels(y, layers)
-        mesh = self.mesh or DeviceMesh()
-        p = mesh.axis_size()
-        x_pad, n_valid = pad_to_multiple(x.astype(np.float32), p)
-        y_pad, _ = pad_to_multiple(y_dev, p)
-        w_pad = np.zeros(x_pad.shape[0], np.float32)
-        w_pad[:n_valid] = w[:n_valid].astype(np.float32)
-        local_bs = max(1, self.get(self.GLOBAL_BATCH_SIZE) // p)
-        trainer = make_adam_trainer(
-            mesh.mesh, DeviceMesh.DATA_AXIS, local_bs,
-            type(self)._LOSS_BUILDER, 2 * (len(layers) - 1),
-        )
-        key = jax.random.PRNGKey(self.get_seed())
-        init = _init_params(list(layers), key)
-        flat0 = tuple(t for wb in init for t in wb)
-        f32 = lambda v: jnp.asarray(v, jnp.float32)
-        flat, _steps, _loss = trainer(
-            mesh.shard_batch(x_pad), mesh.shard_batch(y_pad),
-            mesh.shard_batch(w_pad), flat0,
-            f32(self.get(self.LEARNING_RATE)),
-            jnp.asarray(self.get(self.MAX_ITER), jnp.int32),
-            f32(self.get(self.TOL)),
-            jax.random.fold_in(key, 123),
-        )
+        with span("fit"):
+            params, losses = _mlp_table.fit_table(self, table, self._CLASSIFY)
         model = self._MODEL_CLS()
         model.copy_params_from(self)
-        model._weights = [np.asarray(t, np.float64) for t in flat]
+        model._weights = list(params)
+        model.loss_history = losses
         return model
 
     def _fit_stream(self, source):
@@ -177,6 +157,12 @@ class _MLPBase(StreamingEstimatorMixin, _MLPParams, Estimator):
         continuous run, snapshotted at epoch boundaries."""
         from flinkml_tpu.models._adam import run_streamed_adam
 
+        if self.precision is not None:
+            raise ValueError(
+                "precision declares the products of the table fit; the "
+                "streamed fit runs at the backend's default. Drop the policy "
+                "or fit a Table."
+            )
         layers = self._check_layers()
         features_col = self.get(self.FEATURES_COL)
         label_col = self.get(self.LABEL_COL)
@@ -199,10 +185,8 @@ class _MLPBase(StreamingEstimatorMixin, _MLPParams, Estimator):
                 raise ValueError(
                     f"layers[0]={layers[0]} != feature dim {d}"
                 )
-            init = _init_params(
-                list(layers), jax.random.PRNGKey(self.get_seed())
-            )
-            return tuple(t for wb in init for t in wb)
+            return _mlp_table.init_params(
+                layers, jax.random.PRNGKey(self.get_seed()))
 
         flat = run_streamed_adam(
             source,
@@ -239,6 +223,14 @@ class MLPClassifier(_MLPClassifierParams, _MLPBase):
             )
         return yi.astype(np.int32)
 
+    def _check_labels(self, labels, layers) -> None:
+        n_classes = layers[-1]
+        if not labels.integral or labels.lo < 0 or labels.hi >= n_classes:
+            raise ValueError(
+                f"labels must be class ids in [0, {n_classes}), got "
+                f"[{labels.lo}, {labels.hi}]"
+            )
+
 
 class _MLPModelBase(_MLPParams, Model):
     """Weight storage, forward pass, and persistence shared by the
@@ -247,6 +239,9 @@ class _MLPModelBase(_MLPParams, Model):
     def __init__(self):
         super().__init__()
         self._weights: Optional[List[np.ndarray]] = None
+        #: The loss of every step of the table fit that made the model
+        #: (float32 ``[steps]``); None for any other model.
+        self.loss_history: Optional[np.ndarray] = None
 
     def set_model_data(self, *inputs: Table) -> "MLPClassifierModel":
         (table,) = inputs
@@ -269,6 +264,8 @@ class _MLPModelBase(_MLPParams, Model):
             raise ValueError("Model data is not set; fit or set_model_data first")
 
     def _logits(self, table: Table) -> np.ndarray:
+        # ``x`` is float64 and every product widens to it: the table fit's
+        # float32 arrays are multiplied as their float64 values.
         x = features_matrix(table, self.get(self.FEATURES_COL))
         n_layers = len(self._weights) // 2
         h = x
@@ -278,10 +275,11 @@ class _MLPModelBase(_MLPParams, Model):
 
     def save(self, path: str) -> None:
         self._require()
+        arrays = {f"arr{i}": a for i, a in enumerate(self._weights)}
+        if self.loss_history is not None:
+            arrays["lossHistory"] = self.loss_history
         self._save_with_arrays(
-            path,
-            {f"arr{i}": a for i, a in enumerate(self._weights)},
-            extra={"numArrays": len(self._weights)},
+            path, arrays, extra={"numArrays": len(self._weights)},
         )
 
     @classmethod
@@ -289,6 +287,7 @@ class _MLPModelBase(_MLPParams, Model):
         model, arrays, meta = cls._load_with_arrays(path)
         n = int(meta["numArrays"])
         model._weights = [arrays[f"arr{i}"] for i in range(n)]
+        model.loss_history = arrays.get("lossHistory")
         return model
 
 
@@ -313,12 +312,17 @@ class MLPRegressor(_MLPBase):
     tanh hidden activations, linear output, squared loss — the same
     whole-run Adam device trainer as the classifier."""
 
+    _CLASSIFY = False
+
     def _prepare_labels(self, y: np.ndarray, layers) -> np.ndarray:
+        self._check_labels(None, layers)
+        return y.astype(np.float32)
+
+    def _check_labels(self, labels, layers) -> None:
         if layers[-1] != 1:
             raise ValueError(
                 "layers must be [inputDim, hidden..., 1] for regression"
             )
-        return y.astype(np.float32)
 
 
 class MLPRegressorModel(_MLPModelBase):

@@ -770,3 +770,61 @@ def test_gbt_forest_at_the_cells_size_holds_no_rows_by_features_array_wider_than
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 0.25 * 16e9 < held < 0.75 * 16e9
+
+
+@pytest.mark.parametrize("precision", ["mixed", None])
+def test_mlp_fit_at_the_cells_size(topo, no_compile_cache, precision):
+    """``mlp-mnist8m.fit``'s one program, ``mlp_fit``: 124 steps of
+    784-2500-2000-1500-1000-500-10 over windows of 16,384 of 2,025,000 x
+    784 float32 rows on a one-chip mesh (the ``psum`` included), under
+    ``mixed`` (the cell's: every product bfloat16 operands into a float32
+    sum) and with no policy (float32 at ``HIGHEST``: the benchmark's better
+    side). The rows lie as a v5e holds them, rows along the lanes, and the
+    first product and its gradient read the window so: no copy of the
+    table and no turned window. Under ``mixed`` the compiler casts the
+    WHOLE table to bfloat16 ahead of the loop (3.18 GB of temporaries, a
+    step then reads a 25.7 MB window; an ``optimization_barrier`` on the
+    window does not hold it back, PR 52); both fit a v5e's 16 GB beside
+    the table."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from flinkml_tpu.models import _mlp_table
+    from flinkml_tpu.parallel import DeviceMesh
+    from flinkml_tpu.precision import resolve_policy
+
+    layers, rows, batch, steps = (784, 2500, 2000, 1500, 1000, 500, 10), 2_025_000, 16_384, 124
+    mesh = Mesh(np.array(topo.devices[:1]), (DeviceMesh.DATA_AXIS,))
+
+    def on_mesh(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    f32 = jnp.float32
+    params = tuple(s for a, b in zip(layers, layers[1:])
+                   for s in (on_mesh((a, b), f32), on_mesh((b,), f32)))
+    _mlp_table._trainer.cache_clear()                    # keyed by no backend
+    try:
+        trainer = _mlp_table._trainer(mesh, layers, True, batch, DeviceMesh.DATA_AXIS,
+                                      steps, resolve_policy(precision))
+        with jax.enable_x64(True):    # as the suite runs; the operands are float32
+            compiled = trainer.trace(
+                params, on_mesh((rows, 784), f32, DeviceMesh.DATA_AXIS),
+                on_mesh((rows,), jnp.int32, DeviceMesh.DATA_AXIS),
+                on_mesh((rows,), f32, DeviceMesh.DATA_AXIS),
+                np.float32(1e-3), np.float32(0.0)).lower().compile()
+    finally:
+        _mlp_table._trainer.cache_clear()
+    text = compiled.as_text()
+    assert "mlp_fit" in text
+    assert _phases(text) == set(_mlp_table.PHASES)
+    table = compiled.input_formats[0][1]
+    assert table.layout.major_to_minor == (1, 0)          # rows along the lanes
+    assert not re.search(r"f32\[2025000,784\]\S* (copy|transpose)\(", text)
+    assert not re.search(r"\[784,16384\]", text)          # no window turned
+    assert ("bf16[2025000,784]" in text) == (precision == "mixed")
+    assert "f64[" not in text
+    memory = compiled.memory_analysis()
+    assert 0.39 * 16e9 < memory.argument_size_in_bytes < 0.41 * 16e9
+    assert memory.temp_size_in_bytes < (4.2e9 if precision == "mixed" else 1.2e9)
+    assert memory.alias_size_in_bytes == 0      # the table keeps what the loop read

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 
 from flinkml_tpu.models import (
-    _als_blocked, _fm_sparse, _gbt_table, _linear_sgd, _w2v_table, kmeans)
+    _als_blocked, _fm_sparse, _gbt_table, _linear_sgd, _mlp_table, _w2v_table, kmeans)
 from flinkml_tpu.utils import jax_cache, profiling
 
 from .test_spans import _lowered_programs, _struct
@@ -82,6 +82,8 @@ CASES = {
                                _linear_sgd, _linear_sgd._sparse_trainer_bucketed),
     "kmeans_lloyd": (lambda: _lowered_programs()["kmeans_lloyd"](),
                      kmeans.PHASES, kmeans, kmeans._kmeans_trainer),
+    "mlp_fit": (lambda: _lowered_programs()["mlp_fit"](),
+                _mlp_table.PHASES, _mlp_table, _mlp_table._trainer),
 }
 
 _PHASE_IN_PATH = re.compile(
@@ -225,7 +227,8 @@ def _declared():
     return {
         "w2v_sgns_loop": _w2v_table.PHASES, "fm_adam_loop": _fm_sparse.PHASES,
         "als_half_step": _als_blocked.PHASES, "gbt_forest": _gbt_table.PHASES,
-        "lr_sparse_loop": _linear_sgd.SPARSE_PHASES, "kmeans_lloyd": kmeans.PHASES}
+        "lr_sparse_loop": _linear_sgd.SPARSE_PHASES, "kmeans_lloyd": kmeans.PHASES,
+        "mlp_fit": _mlp_table.PHASES}
 
 
 def test_the_docs_phases_table_lists_every_phase_with_the_metric_that_reads_it():

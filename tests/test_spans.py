@@ -910,6 +910,23 @@ def _lowered_programs():
             _struct((128 * p,), f32, rows), *[_struct((), f32, rep)] * 4,
             _struct((2,), jnp.uint32, rep))
 
+    def mlp_programs():
+        from flinkml_tpu.models import _mlp_table
+        from flinkml_tpu.precision import MIXED
+
+        rep, rows = shardings()
+        layers = (5, 7, 3)
+        params = tuple(_struct(s, f32, rep) for s in ((5, 7), (7,), (7, 3), (3,)))
+        data = (_struct((64, 5), f32, rows), _struct((64,), i32, rows),
+                _struct((64,), f32, rows))
+        return {
+            "mlp_fit": lambda: _mlp_table._trainer(
+                m(), layers, True, 4, "data", 6, MIXED).lower(
+                    params, *data, _struct((), f32, rep), _struct((), f32, rep)),
+            "mlp_start": lambda: _mlp_table._start_program(layers).lower(
+                jax.random.PRNGKey(0)),
+        }
+
     def knn_vote():
         return knn._knn_vote.lower(
             _struct((16, 5), f32), _struct((64, 5), f32), _struct((64,), f32),
@@ -954,12 +971,14 @@ def _lowered_programs():
         "knn_vote": knn_vote,
         "rows_sq": lambda: blas.squared_norms.lower(_struct((64, 5), f32)),
         "fused_chain": fused_chain,
+        **mlp_programs(),
     }
 
 
 PROGRAMS = ("lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop", "stage_write",
             "stage_zeros", "stage_ones", "kmeans_lloyd", "fm_adam_loop", "knn_vote",
-            "rows_sq", "fused_chain", "als_half_step", "w2v_sgns_loop", "gbt_forest")
+            "rows_sq", "fused_chain", "als_half_step", "w2v_sgns_loop", "gbt_forest",
+            "mlp_fit", "mlp_start")
 
 
 @pytest.mark.parametrize("name", PROGRAMS)
