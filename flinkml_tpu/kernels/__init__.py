@@ -1,6 +1,6 @@
 """Hand-written Pallas (Mosaic) kernels.
 
-**Seven kernels run, ungated.** Each is chosen where it applies by an
+**Eight kernels run, ungated.** Each is chosen where it applies by an
 ``unsupported_reason`` its caller reads (the backend, the dtype, the
 shapes: no environment variable, no knob), and every other backend runs
 the XLA lowering of the same result:
@@ -13,6 +13,12 @@ the XLA lowering of the same result:
   lookup and accumulation, a slot's product never in HBM
   (``models._linear_sgd.make_sparse_step_bucketed``: a TPU, float32
   coefficients, a slot plan, a batch in whole tiles; ``lr-criteo.fit``);
+- :mod:`~flinkml_tpu.kernels.payload_blocks` — the same walk with a
+  payload axis: a factorization machine's rows looked up in their blocks
+  and their gradient accumulated, a block walked in chunks of 16 rows of
+  128 columns (``models._fm_sparse.make_step``: a TPU, float32
+  parameters, a batch in whole tiles, blocks whose parts fast memory
+  holds; ``fm-criteo.fit``);
 - :mod:`~flinkml_tpu.kernels.dense_step` — the dense linear step, its
   window read once (``models._linear_sgd.make_dense_step``;
   ``lr-a9a.fit``);

@@ -32,7 +32,13 @@ does, dense moments over the whole table.
   ``P[idx]`` bit for bit at :data:`LOOKUP_PRECISION`) and its gradient
   goes back the same way (``block_accumulate``); the step walks the
   slots a few at a time (``block_groups``) so that no product's operand
-  is long. The other
+  is long. On a TPU both are ``kernels.payload_blocks``' Mosaic kernels
+  (PR 53; :func:`_walk_in_fast_memory` reads where: float32, a device's
+  batch in whole tiles of 128, blocks whose parts fast memory holds): a
+  tile of the batch meets a slot's block in fast memory, the looked-up
+  rows stay the table's bit for bit, the cells' gradients are made in
+  the kernel and summed in float32 in one fixed order; XLA's walk is
+  their reference and every other backend's path. The other
   slots take their rows with ``jnp.take`` and share one ``segment_sum``
   over ``[cells, 1 + k]``: correct, and element-serial on a TPU.
 - **Batches**: step ``t`` reads window ``t mod ceil(rows / batch)`` of
@@ -61,6 +67,7 @@ import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
+from flinkml_tpu.kernels import _gate
 from flinkml_tpu.models._adam import adam_update
 from flinkml_tpu.models._data import check_binary_labels, sparse_fit_columns
 from flinkml_tpu.models._linear_sgd import _placed, _slot_major, _window
@@ -155,6 +162,87 @@ def _lane_rows(table):
     return with_layout_constraint(table, Layout(major_to_minor=(0, 1, 2)))
 
 
+def _walk_in_fast_memory(dtype, local_bs: int, slot_plan: Tuple, payload: int,
+                         precision) -> bool:
+    """Whether a plan's blocked slots are looked up and accumulated by
+    :mod:`flinkml_tpu.kernels.payload_blocks` (a slot's products made,
+    selected from and dropped in fast memory) and not by
+    ``ops.sparse.block_lookup`` / ``block_accumulate`` through XLA: on a
+    TPU (elsewhere Mosaic's kernels would run interpreted), float32
+    parameters at the program's own precision (the kernels have no
+    one-pass mode: a control keeps XLA's walk), a device's batch in whole
+    tiles, blocks whose parts fast memory holds (a long block's, and a
+    tile of all the short ones at once). Read off what the fit is handed;
+    nothing sets it."""
+    from flinkml_tpu.kernels import payload_blocks
+
+    return (precision == LOOKUP_PRECISION and not _gate.interpret_mode()
+            and payload_blocks.unsupported_reason(
+                dtype, local_bs, _walk(slot_plan)[0], payload,
+                len(slot_plan)) is None)
+
+
+def _walk(slot_plan: Tuple):
+    """The plan's blocked slots as the kernels walk them, the shortest
+    blocks first: ``(lengths, where)``, each one's block length and which
+    row of a step's cells it is."""
+    where = sorted((j for j, length in enumerate(slot_plan) if length is not None),
+                   key=lambda j: (slot_plan[j], j))
+    return [slot_plan[j] for j in where], where
+
+
+def xla_lookup(table, ib, vb, starts, slot_plan: Tuple, precision):
+    """The blocked slots' share of a step's lookup through XLA's
+    products, a few slots at a time (``ops.sparse.block_groups``):
+    ``(sums [1 + k, rows], squares [rows], walked)`` for the window's
+    cells ``ib``, ``vb [rows, width]``, the sums laid as
+    ``kernels.payload_blocks.lookup`` hands its own (the step has one
+    form of what follows); ``walked`` keeps a group's ``(length, first,
+    local, xs, xp)`` for :func:`xla_accumulate`. A block is whole rows
+    cut out of ``table`` and turned."""
+    width = table.shape[0]
+    local_bs = ib.shape[0]
+    sums = jnp.zeros((local_bs, width), table.dtype)
+    squares = jnp.zeros((local_bs,), table.dtype)
+    walked = []
+    for length, slots in sparse.block_groups(slot_plan, local_bs, width):
+        first = [starts[j] for j in slots]
+        blocks = jnp.stack([
+            jax.lax.dynamic_slice_in_dim(table, at, length // LANES, axis=1)
+            .reshape(width, length).T for at in first])
+        local = _slot_major(ib, slots) - LANES * jnp.stack(first)[:, None]
+        xs = _slot_major(vb, slots)
+        xp = xs[..., None] * sparse.block_lookup(blocks, local, precision)
+        sums += jnp.sum(xp, axis=0)
+        squares += jnp.sum(jnp.square(xp[..., 1:]), axis=(0, 2))
+        walked.append((length, first, local, xs, xp))
+    return sums.T, squares, walked
+
+
+def xla_accumulate(grad, walked, cell_grads, precision):
+    """:func:`xla_lookup`'s transpose: every walked group's cells'
+    gradients (``cell_grads(xs, xp)`` over ``[slots, rows]``) summed on
+    their blocks' columns and added into ``grad [1 + k, dim_pad / 128,
+    128]``."""
+    width = grad.shape[0]
+    for length, first, local, xs, xp in walked:
+        back = sparse.block_accumulate(
+            local, cell_grads(xs, xp), length, precision)
+        # One slot after another: blocks may overlap, and add.
+        for at, slot_grad in zip(first, back):
+            grad = _add_rows(grad, slot_grad.T.reshape(width, -1, LANES), at)
+    return grad
+
+
+def _add_rows(grad, slot_grad, at):
+    """``grad`` with ``slot_grad [1 + k, rows, 128]`` added from row
+    ``at`` of 128 columns on."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        grad,
+        jax.lax.dynamic_slice_in_dim(grad, at, slot_grad.shape[1], axis=1)
+        + slot_grad, at, axis=1)
+
+
 def make_step(logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
               precision=LOOKUP_PRECISION):
     """ONE Adam step of the sparse factorization machine on a device's
@@ -165,13 +253,24 @@ def make_step(logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
     (any array under an empty plan). The table lies with its COLUMNS in
     rows of 128 lanes, a float of the payload a plane (a TPU pads a
     ``[dim, 17]`` array's rows to 128 lanes, and gave ``[17, dim]`` the
-    same layout: 7.5 times the bytes in every pass of Adam); a block is
-    whole rows cut out of it and turned. The module docstring has the
-    equations."""
+    same layout: 7.5 times the bytes in every pass of Adam); XLA's walk
+    cuts a block out of it as whole rows and turns it. The module
+    docstring has the equations.
+
+    Where :func:`_walk_in_fast_memory` says so the blocked slots' lookup
+    and accumulation are ``kernels.payload_blocks``' two kernels: the
+    same looked-up floats, the gradient's float32 sums in the kernel's
+    fixed order. That chooses who makes ``sums`` and ``grad`` and nothing
+    else: the rows' sums, the interaction and ``base`` lie ``[1 + k,
+    rows]`` (the batch along the lanes, as the kernels take them) on
+    either path."""
+    from flinkml_tpu.kernels import payload_blocks
 
     def step(params, m, v, t, idx, val, y, wt, starts, lr, reg):
         w0, table = params
         width = table.shape[0]
+        fused = _walk_in_fast_memory(table.dtype, local_bs, slot_plan, width,
+                                     precision)
         with phase("fm.lookup"):
             ib, vb = _window(idx, t, local_bs), _window(val, t, local_bs)
             yb, wb = _window(y, t, local_bs), _window(wt, t, local_bs)
@@ -179,30 +278,25 @@ def make_step(logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
                        if j >= len(slot_plan) or slot_plan[j] is None]
             # What a cell adds to its row's sums is x_s P[i_s]: kept, slot
             # major, for the gradient.
-            sums = jnp.zeros((local_bs, width), table.dtype)
-            squares = jnp.zeros((local_bs,), table.dtype)
-            walked = []
-            for length, slots in sparse.block_groups(slot_plan, local_bs, width):
-                first = [starts[j] for j in slots]
-                blocks = jnp.stack([
-                    jax.lax.dynamic_slice_in_dim(table, at, length // LANES, axis=1)
-                    .reshape(width, length).T for at in first])
-                local = _slot_major(ib, slots) - LANES * jnp.stack(first)[:, None]
-                xs = _slot_major(vb, slots)
-                xp = xs[..., None] * sparse.block_lookup(blocks, local, precision)
-                sums += jnp.sum(xp, axis=0)
-                squares += jnp.sum(jnp.square(xp[..., 1:]), axis=(0, 2))
-                walked.append((length, first, local, xs, xp))
+            if fused:
+                # The kernels walk the window's cells as they are, a slot
+                # a row: which rows, length by length, and the starts say.
+                walk = _walk(slot_plan)
+                whole = (ib.T, vb.T, starts)
+                xps, sums, squares = payload_blocks.lookup(*walk, table, *whole)
+            else:
+                sums, squares, walked = xla_lookup(
+                    table, ib, vb, starts, slot_plan, precision)
             if general:
-                ig, xg = _slot_major(ib, general).T, _slot_major(vb, general).T
+                ig, xg = _slot_major(ib, general), _slot_major(vb, general)
                 gp = xg[..., None] * jnp.moveaxis(
                     jnp.take(table.reshape(width, -1), ig, axis=1), 0, -1)
-                sums += jnp.sum(gp, axis=1)
-                squares += jnp.sum(jnp.square(gp[..., 1:]), axis=(1, 2))
+                sums += jnp.sum(gp, axis=0).T
+                squares += jnp.sum(jnp.square(gp[..., 1:]), axis=(0, 2))
         with phase("fm.interaction"):
-            factor_sums = sums[:, 1:]
-            margin = w0[0] + sums[:, 0] + 0.5 * (
-                jnp.sum(jnp.square(factor_sums), axis=1) - squares)
+            linear, factor_sums = sums[0], sums[1:]
+            margin = w0[0] + linear + 0.5 * (
+                jnp.sum(jnp.square(factor_sums), axis=0) - squares)
             if logistic:
                 per_row = jnp.logaddexp(0.0, margin) - yb * margin
                 mult = (jax.nn.sigmoid(margin) - yb) * wb
@@ -210,33 +304,30 @@ def make_step(logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
                 err = margin - yb
                 per_row, mult = 0.5 * err * err, err * wb
             # d y^ / d P[i_s] = x_s (1, S_f - x_s V[i_s, f]).
-            base = jnp.concatenate(
-                [jnp.ones((local_bs, 1), table.dtype), factor_sums], axis=1)
+            base = jnp.concatenate([jnp.ones_like(linear)[None], factor_sums])
             factors_only = jnp.arange(width) > 0
 
-        def cell_grads(xs, xp, rows_first: bool):
-            """``xs [.., ..]`` and ``xp [.., .., 1 + k]`` over (rows, slots)
-            or (slots, rows)."""
-            of_row = (lambda a: a[:, None]) if rows_first else (lambda a: a[None])
-            return (of_row(mult) * xs)[..., None] * (
-                of_row(base) - jnp.where(factors_only, xp, 0))
+        def cell_grads(xs, xp):
+            """``xs [slots, rows]`` and ``xp [slots, rows, 1 + k]``."""
+            return (mult * xs)[..., None] * (
+                base.T - jnp.where(factors_only, xp, 0))
 
         with phase("fm.accumulate"):
             if general:
+                # Summed a row's cells after another's, as they always were.
                 grad = jax.ops.segment_sum(
-                    cell_grads(xg, gp, True).reshape(-1, width), ig.reshape(-1),
+                    jnp.swapaxes(cell_grads(xg, gp), 0, 1).reshape(-1, width),
+                    ig.T.reshape(-1),
                     num_segments=table.shape[1] * LANES).T.reshape(table.shape)
             else:
                 grad = jnp.zeros_like(table)
-            for length, first, local, xs, xp in walked:
-                back = sparse.block_accumulate(
-                    local, cell_grads(xs, xp, False), length, precision)
+            if fused:
                 # One slot after another: blocks may overlap, and add.
-                for at, slot_grad in zip(first, back):
-                    grad = jax.lax.dynamic_update_slice_in_dim(
-                        grad,
-                        jax.lax.dynamic_slice_in_dim(grad, at, length // LANES, axis=1)
-                        + slot_grad.T.reshape(width, -1, LANES), at, axis=1)
+                for j, slot_grad in zip(walk[1], payload_blocks.accumulate(
+                        *walk, *whole, mult, base, xps)):
+                    grad = _add_rows(grad, slot_grad, starts[j])
+            else:
+                grad = xla_accumulate(grad, walked, cell_grads, precision)
         with phase("fm.adam"):
             wsum = jax.lax.psum(jnp.sum(wb), axis)
             total_w = jnp.maximum(wsum, 1e-12)
@@ -317,6 +408,9 @@ def fit_csr(est, table, logistic: bool, precision=LOOKUP_PRECISION):
         check_binary_labels(labels, type(est).__name__)
     mesh = est.mesh or DeviceMesh()
     seed = est.get_seed()
+    # The step may hold the payload kernels (a TPU's): what tracing them
+    # imports loads beside the plan's pass and the permutation.
+    _gate.import_beside_host_work()
     placed = table.device_resident(
         ("fm_rows_on_mesh", features_col, label_col, weight_col,
          mesh.mesh, "float32", seed),
@@ -357,6 +451,10 @@ def fit_csr(est, table, logistic: bool, precision=LOOKUP_PRECISION):
     group.counter("rows", float(placed.rows))
     group.counter("cells", placed.cells)
     group.counter("blocked_cells", placed.blocked_cells)
+    # Counted at the loop, as ``trainer.fused_block_fits`` is: 1.0 a fit
+    # whose blocked slots ran in ``kernels.payload_blocks``.
+    group.counter("fused_block_fits", float(_walk_in_fast_memory(
+        start.dtype, local_bs, placed.slot_plan, start.shape[0], precision)))
     return w0, weights, factors
 
 

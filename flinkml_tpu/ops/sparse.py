@@ -27,8 +27,13 @@ device's batch in whole tiles of 128, at most two million block
 columns). :func:`block_lookup` and :func:`block_accumulate` are their
 reference (``tests/test_sparse_blocks.py`` holds both to the same
 gather and scatter-add) and what every other backend, dtype and batch
-runs; the payload forms (a factorization machine's rows) are XLA's
-everywhere.
+runs. Their payload forms (a factorization machine's rows) stand the same
+way to :mod:`flinkml_tpu.kernels.payload_blocks` since PR 53: on a TPU the
+factorization machines' step looks its blocked slots' rows up and
+accumulates their gradient in that module's kernels
+(``models._fm_sparse._walk_in_fast_memory`` says where), and
+:func:`block_lookup` / :func:`block_accumulate` with a payload axis are
+the reference (``tests/test_payload_blocks.py``) and every other path.
 """
 
 from __future__ import annotations
@@ -73,7 +78,9 @@ def block_lookup(blocks, local, precision=jax.lax.Precision.HIGHEST) -> jax.Arra
     two levels with :func:`lookup_columns` columns a product row, so
     that neither the one-hot nor the product's result is long.
     ``precision`` is for a control alone (one bfloat16 pass rounds every
-    looked-up float)."""
+    looked-up float). Since PR 53 the payload form is the reference of
+    ``kernels.payload_blocks.lookup`` and what every backend but a TPU
+    (and, there, a control or a step the kernels do not take) runs."""
     if blocks.ndim == 3:
         return _payload_lookup(blocks, local, precision)
     s, r = blocks.shape
@@ -123,7 +130,9 @@ def lookup_columns(length: int) -> int:
     (rows of 128 lanes, as the one-float lookup) from 8,192 columns up:
     read off whole steps on a v5e at 65,536 rows and a payload of 17
     (PERF.md section 5, PR 36: 26.8 ms a step; 29.1 at 32 for long
-    blocks, 33.2 at 64, 36.1 at 8)."""
+    blocks, 33.2 at 64, 36.1 at 8). The reference's and the non-TPU
+    path's alone since PR 53: ``kernels.payload_blocks`` walks a block in
+    chunks of 16 rows of 128 columns whatever its length."""
     return LANES if length >= 8192 else max(2, min(16, length // 64))
 
 
@@ -133,7 +142,8 @@ def accumulate_columns(length: int) -> int:
     columns up. Never 16: the compiler's schedule for a ``[.., 16, 17]``
     operand costs a long block 5 to 7 times what 8 or 32 do (101 ms a
     step; PR 36, as above), and 32 for long blocks costs 51 ms beside a
-    lookup at 64 or 128 where it costs 29 beside one at 32."""
+    lookup at 64 or 128 where it costs 29 beside one at 32. As
+    :func:`lookup_columns`: XLA's walk alone reads it since PR 53."""
     return LANES if length >= 6144 else max(2, min(8, length // 64))
 
 

@@ -285,6 +285,102 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
     assert memory.temp_size_in_bytes < 5 * batch * 128 * 4
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_fm_adam_loop_at_the_cells_size_holds_the_payload_kernels(
+        topo, no_compile_cache, monkeypatch, chips):
+    """``fm-criteo.fit``'s one program as a TPU traces it (PR 53): the 39
+    blocked slots' rows looked up and their gradient accumulated by
+    ``kernels.payload_blocks``' kernels, two a direction (the 21 slots of
+    up to 256 columns in one call, the 18 longer ones walked in chunks in
+    the other), compiled here by Mosaic (not interpreted), on a one-chip
+    mesh as the cell runs it and on the host's four chips (a quarter of
+    the rows and of the batch each, the gradient's ``psum`` after the
+    kernels). The looked-up rows ``xp [39, 17, batch]`` are the one long
+    operand a step keeps: the program's temporaries stay under 0.6 GB
+    where XLA's walk keeps a gigabyte. Traced under x64, as the suite
+    runs: the kernels are traced in 32-bit mode whatever the flag says."""
+    import numpy as np
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.models import _fm_sparse
+
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    rows, width, dim, k, batch = 16_777_216, 39, 1_000_000, 16, 65_536
+    assert _fm_sparse._walk_in_fast_memory(
+        jnp.float32, batch // chips, FM_CRITEO_PLAN, k + 1,
+        _fm_sparse.LOOKUP_PRECISION)
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    rows_minor = Format(Layout(major_to_minor=(1, 0)), by_rows)
+
+    def on(shape, dtype, sharding=whole):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    f32, i32 = jnp.float32, jnp.int32
+    _fm_sparse._trainer.cache_clear()
+    try:
+        with jax.enable_x64(True):
+            traced = _fm_sparse._trainer(
+                mesh, True, batch // chips, "data", FM_CRITEO_PLAN).trace(
+                on((1,), f32), on((k + 1, _fm_sparse.padded_dim(dim) // 128, 128), f32),
+                on((rows, width), i32, rows_minor), on((rows, width), f32, rows_minor),
+                on((rows,), f32, by_rows), on((rows,), f32, by_rows),
+                on((width,), i32), on((), f32), on((), f32), on((), i32),
+                on((), f32))
+            kernels = list(_pallas_calls(traced.jaxpr.jaxpr))
+            assert len(kernels) == 4
+            wide = [str(v.aval) for eqn in kernels
+                    for v in eqn.params["jaxpr"].invars + eqn.params["jaxpr"].outvars
+                    if re.search(r"[fiu]64", str(v.aval))]
+            assert wide == []
+            compiled = traced.lower().compile()
+    finally:
+        _fm_sparse._trainer.cache_clear()
+    text = compiled.as_text()
+    assert "fm_adam_loop" in text and text.count("tpu_custom_call") == 4
+    assert "operand_precision={highest,highest}" not in text   # no product of XLA's
+    assert _phases(text) == set(_fm_sparse.PHASES)
+    memory = compiled.memory_analysis()
+    # the cells, labels and weights, 5.37 GB over the chips, and a table each
+    assert 0.33 * 16e9 < chips * memory.argument_size_in_bytes < 0.37 * 16e9
+    assert memory.temp_size_in_bytes < 0.6e9
+
+
+@pytest.mark.parametrize("slots,tile", [(36, 4096), (76, 1024), (129, 128)])
+def test_payload_kernels_take_many_short_slots_at_a_smaller_tile(
+        one_chip, no_compile_cache, slots, tile):
+    """The short kernels alone where a table has many narrow fields: a
+    grid step holds a tile of EVERY short slot, so Mosaic refused 76
+    slots of 256 columns x 17 floats at a tile of 4,096 rows (64.25 MiB
+    of its 64; PR 53's review). ``short_tile_rows`` halves the tile
+    before that: the most slots it leaves each tile compile here, and
+    one slot more than 129 falls back to XLA's walk."""
+    from flinkml_tpu.kernels import payload_blocks
+
+    payload, batch, lengths = 17, 65_536, [256] * slots
+    assert payload_blocks.unsupported_reason(jnp.float32, batch, lengths, payload) is None
+    assert payload_blocks.short_tile_rows(batch, payload, slots, 256, slots) == tile
+    assert payload_blocks.short_tile_rows(batch, payload, 130, 256, 130) is None
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cells = (on((slots, batch), jnp.int32), on((slots, batch), jnp.float32),
+             on((slots,), jnp.int32))
+    with jax.enable_x64(True):
+        lookup = jax.jit(lambda table, c, v, at: payload_blocks.lookup(
+            lengths, range(slots), table, c, v, at, interpret=False)).lower(
+                on((payload, 7813, 128), jnp.float32), *cells).compile()
+        accumulate = jax.jit(lambda c, v, at, m, base, xp: payload_blocks.accumulate(
+            lengths, range(slots), c, v, at, m, base, [xp], interpret=False)).lower(
+                *cells, on((batch,), jnp.float32), on((payload, batch), jnp.float32),
+                on((slots, payload, batch), jnp.float32)).compile()
+    assert lookup.as_text().count("tpu_custom_call") == 1
+    assert accumulate.as_text().count("tpu_custom_call") == 1
+
+
 def test_sparse_block_kernels_take_criteo_laid_out_field_by_field(
         one_chip, no_compile_cache):
     """The two kernels alone at another ladder of block lengths: Criteo

@@ -20,7 +20,12 @@ order):
 - the span tree's self seconds add up to ``fit``'s; four data shards
   joined by the real ``psum`` equal one;
 - ``lr-criteo``'s blocked step lowers to the text it had before the
-  payload axis.
+  payload axis;
+- the same fits with the blocked slots in ``kernels.payload_blocks``' two
+  kernels (PR 53: a TPU's, here interpreted), one device and four;
+  overlapping blocks; and what the kernels do not take (a batch that is
+  not whole tiles, a block too long for fast memory, the control's one
+  pass) is the fit as it was, to the bit.
 """
 
 import contextlib
@@ -549,6 +554,150 @@ def test_overlapping_blocks_add_and_agree_with_no_plan():
                     idx, val, y, wt, starts)
 
     planned = run((128, 128, 256, None), np.asarray([0, 0, 0, 0], np.int32))
+    general = run((), np.zeros(1, np.int32))
+    for a, b in zip(jax.tree.leaves(planned[1]), jax.tree.leaves(general[1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=2e-8)
+    assert float(planned[3]) == pytest.approx(float(general[3]), rel=1e-6)
+
+
+# -- the walk in fast memory (PR 53) ------------------------------------------
+
+@pytest.fixture
+def through_the_kernels(monkeypatch):
+    """The fit as a TPU traces it, ``kernels.payload_blocks``' kernels
+    interpreted: the gate answers as on a TPU wherever the kernels take
+    the step (whole tiles of 128 rows a device; an interpreted kernel's
+    values carry no mesh axes, so the ``shard_map`` does not check
+    them)."""
+    import functools
+
+    from flinkml_tpu.kernels import payload_blocks
+
+    def as_on_a_tpu(dtype, local_bs, slot_plan, payload, precision):
+        return (precision == _fm_sparse.LOOKUP_PRECISION
+                and payload_blocks.unsupported_reason(
+                    dtype, local_bs, _fm_sparse._walk(slot_plan)[0], payload) is None)
+
+    monkeypatch.setattr(_fm_sparse, "_walk_in_fast_memory", as_on_a_tpu)
+    monkeypatch.setattr(jax, "shard_map",
+                        functools.partial(jax.shard_map, check_vma=False))
+    _fm_sparse._trainer.cache_clear()
+    yield
+    _fm_sparse._trainer.cache_clear()
+
+
+@pytest.mark.parametrize("case,cls,shards", [
+    ("one width", FMClassifier, 1), ("field-blocked ragged", FMClassifier, 1),
+    ("one width", FMRegressor, 1), ("one width", FMClassifier, 4)])
+def test_a_fit_through_the_kernels_is_float64_adam_too(
+        case, cls, shards, through_the_kernels):
+    """The blocked slots looked up and accumulated by the two kernels
+    (rows of one width: all five slots; the ragged table's too, its
+    padding cells value 0), one device and four joined by the real
+    ``psum``: within the tolerance XLA's walk is held to, and counted."""
+    rng = np.random.default_rng(11)
+    rows_of = CASES[case](rng)
+    logistic = cls is FMClassifier
+    y = _labels(rng, 1500, logistic)
+    batch = 128 * shards * 2
+    mesh = DeviceMesh(devices=jax.devices()[:shards])
+    with _delta("fm") as counts:
+        model = _estimator(cls, mesh=mesh, batch=batch).fit(_table(rows_of, y))
+    assert counts["fused_block_fits"] == 1 and counts["fits"] == 1
+    assert counts["blocked_cells"] == counts["cells"]
+    want = _want(rows_of, y, logistic=logistic, shards=shards, batch=batch)
+    assert _gap(model, want) <= TOL
+
+
+@pytest.mark.parametrize("case", ["batch of 100", "too long", "one pass"])
+def test_a_fit_the_kernels_do_not_take_is_the_fit_as_it_was(case, monkeypatch):
+    """On a TPU (the gate told so; a kernel traced here would fail to
+    lower): a device's batch that is not whole tiles, a block whose parts
+    fast memory would not hold, the control's one bfloat16 pass each keep
+    XLA's walk, give the model a CPU's fit gives to the bit, and count
+    ``fused_block_fits`` 0."""
+    from flinkml_tpu.kernels import _gate, payload_blocks
+
+    rng = np.random.default_rng(11)
+    rows_of = CASES["one width"](rng)
+    y = _labels(rng, 1500)
+    batch = 100 if case == "batch of 100" else BATCH
+    precision = (jax.lax.Precision.DEFAULT if case == "one pass"
+                 else _fm_sparse.LOOKUP_PRECISION)
+
+    def fit():
+        _fm_sparse._trainer.cache_clear()
+        est = _estimator(batch=batch)
+        with _delta("fm") as counts:
+            w0, w, v = _fm_sparse.fit_csr(est, _table(rows_of, y), True, precision)
+        return (w0, w, v), counts
+
+    plain, _ = fit()
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)     # a TPU
+    if case == "too long":
+        monkeypatch.setattr(payload_blocks, "_RESIDENT_BYTES", 1 << 16)
+    else:
+        assert _fm_sparse._walk_in_fast_memory(
+            jnp.float32, BATCH, (384,) * 5, K + 1, _fm_sparse.LOOKUP_PRECISION)
+    try:
+        as_it_was, counts = fit()
+    finally:
+        _fm_sparse._trainer.cache_clear()
+    assert counts.get("fused_block_fits", 0.0) == 0 and counts["fits"] == 1
+    for a, b in zip(plain, as_it_was):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def test_where_the_walk_runs_in_fast_memory_is_read_off_the_fit(monkeypatch):
+    from flinkml_tpu.kernels import _gate
+
+    plan = (128, 256, None, 26624)
+    high, low = _fm_sparse.LOOKUP_PRECISION, jax.lax.Precision.DEFAULT
+    taken = _fm_sparse._walk_in_fast_memory
+    # here, on a CPU, Mosaic's kernels would be interpreted: XLA's products
+    assert not taken(jnp.float32, 65_536, plan, 17, high)
+    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)     # a TPU
+    assert taken(jnp.float32, 65_536, plan, 17, high)
+    assert taken(jnp.float32, 128, plan, 7, high)
+    assert not taken(jnp.float64, 65_536, plan, 17, high)
+    assert not taken(jnp.float32, 100, plan, 17, high)
+    assert not taken(jnp.float32, 65_536, plan, 17, low)    # the control
+    assert not taken(jnp.float32, 65_536, (), 17, high)
+    assert not taken(jnp.float32, 65_536, (None,) * 4, 17, high)
+    assert not taken(jnp.float32, 65_536, (194_560, 128), 17, high)
+    # the walk: the shortest blocks first, each length's slots in turn
+    assert _fm_sparse._walk(plan) == ([128, 256, 26624], [0, 1, 3])
+    assert _fm_sparse._walk((256, 128, None, 256)) == ([128, 256, 256], [1, 0, 3])
+
+
+def test_overlapping_blocks_add_through_the_kernels_too(through_the_kernels):
+    """:func:`test_overlapping_blocks_add_and_agree_with_no_plan`'s step,
+    the blocked slots in the kernels: two short blocks on one row of 128
+    columns, a third over both, a long one over all three, a general
+    slot beside them."""
+    rng = np.random.default_rng(13)
+    rows, dim = 512, 5000
+    idx = np.stack([rng.integers(0, 100, rows), rng.integers(20, 128, rows),
+                    rng.integers(90, 250, rows), rng.integers(0, 2500, rows),
+                    rng.integers(0, dim, rows)], axis=1).astype(np.int32)
+    val = rng.standard_normal((rows, 5)).astype(np.float32)
+    y, wt = _labels(rng, rows), np.ones(rows, np.float32)
+    table = (0.1 * rng.standard_normal((K + 1, 5120))).astype(np.float32)
+    table[:, dim:] = 0
+    params = (jnp.asarray([0.1], jnp.float32), jnp.asarray(table.reshape(K + 1, 40, 128)))
+    zeros = jax.tree.map(jnp.zeros_like, params)
+    mesh, spec = _one_device(), jax.sharding.PartitionSpec()
+
+    def run(plan, starts):
+        step = _fm_sparse.make_step(True, rows, "data", plan)
+        with jax.enable_x64(False):
+            return jax.jit(jax.shard_map(
+                lambda *a: step(params, zeros, zeros, jnp.int32(0), *a,
+                                jnp.float32(0.01), jnp.float32(1e-3)),
+                mesh=mesh.mesh, in_specs=(spec,) * 5, out_specs=spec))(
+                    idx, val, y, wt, starts)
+
+    planned = run((128, 128, 256, 2560, None), np.zeros(5, np.int32))
     general = run((), np.zeros(1, np.int32))
     for a, b in zip(jax.tree.leaves(planned[1]), jax.tree.leaves(general[1])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=2e-8)
