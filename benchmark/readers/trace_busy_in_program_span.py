@@ -1,11 +1,11 @@
 """Device busy milliseconds while the PROGRAM was inside its own span
 ``params["span"]`` (a ``flinkml:`` span, not the benchmark's ``bench:``
 unit), per unit of ``params["unit"]`` done in the traced slice, meaned
-over the chips: ``trainer.sparse_step_ms`` is the chip's busy time inside
-``trainer.loop`` over the steps, so the staging writes and zero fills
-that the fit's upload runs on the chip before the loop are not in it
-(``trainer.step_ms`` divides everything inside ``bench:fit``). An
-operation that straddles the span's edge counts for the part inside.
+over the chips: ``knn.search_device_ms_per_call`` is the chip's busy time
+inside ``knn.search`` over the calls and ``kmeans.round_device_ms`` the
+same inside ``kmeans.loop`` over the rounds, so what the chip runs for
+the unit outside the span (an upload's writes, a read-back) is not in
+it. An operation that straddles the span's edge counts for the part inside.
 None where the program has no such span (a rehearsal, an older program).
 """
 

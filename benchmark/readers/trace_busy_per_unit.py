@@ -1,6 +1,6 @@
 """Device busy milliseconds inside the spans named ``params["span"]``,
 per unit of ``params["unit"]`` done in the traced slice (``steps``,
-``calls``): ``trainer.step_ms``, ``fusion.device_ms_per_call``."""
+``calls``): ``fusion.device_ms_per_call``."""
 
 
 def busy_seconds_per_unit(params, obs):

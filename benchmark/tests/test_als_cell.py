@@ -30,8 +30,7 @@ with open(os.path.join(BENCH, "workloads", f"{CELL_NAME}.json")) as f:
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     BENCHMARK = json.load(f)
 
-COUNTED = ["compile.cache_misses.setup", "als.padded_slot_share",
-           "als.table_h2d_bytes_per_fit"]
+COUNTED = ["compile.cache_misses.setup", "als.padded_slot_share"]
 #: Read off the PROFILED fit (one fit fills a traced window on the chip).
 OF_THE_TRACED_FIT = ["als.dispatch_s_per_fit", "als.readback_s_per_fit",
                      "api.fit_own_traced_s_per_fit"]
@@ -161,9 +160,11 @@ def test_a_rehearsal_of_the_cell(trace):
     assert len(checks) == 7 and all(c["ok"] for c in checks)
     gaps = [c["value"] for c in checks[:2]]
     assert all(0 < g < CELL["rehearse"]["limits"]["factor_gap"] for g in gaps)
+    # held by the check, not by a per-layer metric (PR 54): a miss is not correct
+    assert [(c["value"], c["limit"]) for c in checks
+            if "bytes uploaded inside the window" in c["what"]] == [(0.0, 0)]
     if trace:
         assert set(COUNTED + OF_THE_TRACED_FIT) <= set(line["metrics"])
-        assert line["metrics"]["als.table_h2d_bytes_per_fit"]["value"] == 0.0
         assert 0.0 < line["metrics"]["als.padded_slot_share"]["value"] < 0.5
     else:
         assert set(line["metrics"]) == {"fit_samples_per_s", "setup_s"}

@@ -30,8 +30,8 @@ with open(os.path.join(BENCH, "workloads", f"{CELL_NAME}.json")) as f:
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     BENCHMARK = json.load(f)
 
-COUNTED = ["compile.cache_misses.setup", "gbt.table_h2d_bytes_per_fit",
-           "gbt.product_level_share", "hostdata.label_facts_kept_share"]
+COUNTED = ["compile.cache_misses.setup", "gbt.product_level_share",
+           "hostdata.label_facts_kept_share"]
 TRACED = ["gbt.level_device_ms", "gbt_level_roofline", "device.idle_share.fit",
           "device.idle_outside_spans.fit"]
 SPANS = ["gbt.dispatch_s_per_fit", "gbt.readback_s_per_fit",
@@ -205,9 +205,11 @@ def test_a_rehearsal_of_the_cell(trace):
     assert 0 < checks[3]["value"] < CELL["limits"]["leaf_gap"]
     assert 0 < checks[4]["value"] < CELL["limits"]["gain_gap"]
     assert checks[5]["value"] <= CELL["limits"]["split_regret"]
+    # held by the check, not by a per-layer metric (PR 54): a miss is not correct
+    assert [(c["value"], c["limit"]) for c in checks
+            if "bytes uploaded inside the window" in c["what"]] == [(0.0, 0)]
     if trace:
         assert set(COUNTED + SPANS) <= set(line["metrics"])
-        assert line["metrics"]["gbt.table_h2d_bytes_per_fit"]["value"] == 0.0
         assert line["metrics"]["gbt.product_level_share"]["value"] == 0.0
         assert line["metrics"]["hostdata.label_facts_kept_share"]["value"] == 1.0
     else:

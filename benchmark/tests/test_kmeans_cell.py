@@ -26,9 +26,8 @@ with open(os.path.join(BENCH, "workloads", f"{CELL_NAME}.json")) as f:
 with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
     BENCHMARK = json.load(f)
 
-COUNTED = ["compile.cache_misses.setup", "kmeans.table_h2d_bytes_per_fit",
-           "kmeans.init_s_per_fit", "kmeans.dispatch_s_per_fit",
-           "kmeans.readback_s_per_fit", "api.kmeans_fit_self_s_per_fit"]
+COUNTED = ["compile.cache_misses.setup", "kmeans.init_s_per_fit",
+           "kmeans.dispatch_s_per_fit", "kmeans.readback_s_per_fit"]
 TRACED = ["kmeans.round_device_ms", "kmeans_lloyd_roofline",
           "device.idle_share.fit", "device.idle_outside_spans.fit"]
 
@@ -123,7 +122,9 @@ def test_the_entries_keep_to_the_form_of_benchmark_json():
     mine = ([c for c in BENCHMARK["configs"] if c["name"] == "kmeans-mnist8m"]
             + [w for w in BENCHMARK["workloads"] if w["config"] == "kmeans-mnist8m"]
             + [m for m in BENCHMARK["per_layer"] if "kmeans" in m["name"]])
-    assert len(mine) == 9
+    # the configuration, the cell and PR 32's entries that stay; later PRs
+    # appended their own (PR 49's two phases)
+    assert len(mine) >= 2 + len([n for n in COUNTED + TRACED if "kmeans" in n])
     for entry in mine:
         assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", entry["name"]), entry["name"]
         for key in {"why", "source", "layer"} & set(entry):
@@ -157,13 +158,15 @@ def test_a_rehearsal_of_the_cell(trace, capsys):
     units = next(c for c in lines if c.get("phase") == "window")["units"]
     assert units["rounds"] == 20 * units["fits"]
     assert units["samples"] == 20_000 * units["rounds"]
+    # held by the check, not by a per-layer metric (PR 54): a miss is not correct
+    assert [(c["value"], c["limit"]) for c in checks
+            if "bytes uploaded inside the window" in c["what"]] == [(0.0, 0)]
     metrics = line["metrics"]
     if not trace:
         assert set(metrics) == {"fit_samples_per_s", "setup_s"}
         return
     assert set(metrics) >= set(COUNTED)        # a rehearsal has no device number
     assert not set(metrics) & set(TRACED)
-    assert metrics["kmeans.table_h2d_bytes_per_fit"]["value"] == 0.0
     assert metrics["kmeans.dispatch_s_per_fit"]["value"] > 0.0
 
 

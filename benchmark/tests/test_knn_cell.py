@@ -128,9 +128,9 @@ def test_the_configuration_is_the_issues():
         bench = json.load(f)
     listed = {m["name"] for m in bench["per_layer"]
               if "knn-mnist8m.transform" in m.get("workloads", [])}
-    assert listed == set(COUNTED) | set(TRACED)
+    assert listed >= set(COUNTED) | set(TRACED)
     rate = next(m for m in bench["end_to_end"] if m["name"] == "transform_rows_per_s")
-    assert rate["workloads"] == ["chain-a9a.transform", "knn-mnist8m.transform"]
+    assert "knn-mnist8m.transform" in rate["workloads"]
 
 
 def test_the_entries_keep_to_the_form_of_benchmark_json():
@@ -143,7 +143,10 @@ def test_the_entries_keep_to_the_form_of_benchmark_json():
     mine = ([c for c in bench["configs"] if c["name"] == "knn-mnist8m"]
             + [w for w in bench["workloads"] if w["config"] == "knn-mnist8m"]
             + [m for m in bench["per_layer"] if m["name"].startswith("knn")])
-    assert len(mine) == 7
+    # the configuration, the cell and PR 30's entries that stay (later PRs
+    # added their own; PR 54 retired knn.model_h2d_bytes_per_call, which
+    # the cell's check holds at 0)
+    assert len(mine) >= 2 + len([n for n in COUNTED + TRACED if n.startswith("knn")])
     for entry in mine:
         assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", entry["name"]), entry["name"]
         for key in {"why", "source", "layer"} & set(entry):
@@ -155,8 +158,8 @@ def test_the_entries_keep_to_the_form_of_benchmark_json():
     assert len(CELL["why"]) <= 200
 
 
-COUNTED = ["compile.cache_misses.setup", "knn.model_h2d_bytes_per_call",
-           "knn.dispatch_s_per_call", "knn.readback_s_per_call"]
+COUNTED = ["compile.cache_misses.setup", "knn.dispatch_s_per_call",
+           "knn.readback_s_per_call"]
 TRACED = ["knn.search_device_ms_per_call", "knn_search_roofline",
           "device.idle_share.transform", "device.idle_outside_spans.transform"]
 
@@ -181,8 +184,8 @@ def test_a_rehearsal_of_the_cell(trace, capsys):
     if not trace:
         assert set(metrics) == {"transform_rows_per_s", "setup_s"}
         return
-    assert set(metrics) == set(COUNTED)        # a rehearsal has no device number
-    assert metrics["knn.model_h2d_bytes_per_call"]["value"] == 0.0
+    # a rehearsal has no device number
+    assert set(COUNTED) <= set(metrics) and not set(TRACED) & set(metrics)
     assert metrics["knn.dispatch_s_per_call"]["value"] > 0.0
 
 

@@ -54,7 +54,8 @@ def test_the_cold_cell_is_the_warm_cells_but_for_its_driver():
         k: v for k, v in warm.items() if k not in told}
     assert (cold["driver"], warm["driver"]) == ("fit_sparse_cold", "fit_sparse")
     entries = {w["name"]: w for w in BENCHMARK["workloads"]}
-    assert BENCHMARK["workloads"][-1] is entries[COLD]  # appended, not inserted
+    names = list(entries)
+    assert names.index(COLD) > names.index(WARM)  # appended after it, not inserted
     assert entries[COLD] == {**entries[WARM], "name": COLD, "traffic": "fit-cold",
                              "why": cold["why"]}
     assert len(cold["why"]) <= 200 and "\n" not in cold["why"]
@@ -74,11 +75,14 @@ def test_the_benchmark_lists_the_cold_cell_wherever_it_lists_the_warm_one():
               if "workloads" in m]
     with_warm = [m["name"] for m in listed if WARM in m["workloads"]]
     with_cold = [m["name"] for m in listed if COLD in m["workloads"]]
-    assert with_cold == with_warm and "fit_samples_per_s" in with_cold
+    # the cold cell alone feeds the host data path (PR 54), so it lists more
+    assert set(with_warm) <= set(with_cold) and "fit_samples_per_s" in with_warm
+    assert {"hostdata.shuffle_s_per_fit", "hostdata.sparse_pack_s_per_fit",
+            "hostdata.upload_bytes_per_s"} <= set(with_cold) - set(with_warm)
     for m in listed:
-        if COLD in m["workloads"]:
-            assert m["workloads"][-1] == COLD  # appended: nothing else moved
-    share = BENCHMARK["per_layer"][-1]
+        if WARM in m["workloads"]:  # appended after it: nothing else moved
+            assert m["workloads"].index(COLD) > m["workloads"].index(WARM)
+    (share,) = [m for m in BENCHMARK["per_layer"] if m["name"] == HIT_SHARE]
     assert share == {
         "name": HIT_SHARE, "unit": "fits/fit", "better": "higher",
         "source": "program_counter", "layer": "Host data",
@@ -108,7 +112,12 @@ def test_every_timed_fit_of_a_kept_cell_is_a_hit(cell, capsys):
     assert misses == 1  # set-up's fit
     assert hits == line["attempted"] >= 1
     assert line["metrics"][HIT_SHARE]["value"] == 1.0
-    assert line["metrics"]["hostdata.shuffle_s_per_fit"]["value"] == 0.0
+    # a hit follows no placement, and the line has none of the host data path's
+    assert not {"hostdata.shuffle_s_per_fit", "hostdata.upload_s_per_fit",
+                "hostdata.stage_wait_s_per_fit", "hostdata.sparse_pack_s_per_fit",
+                "hostdata.unit_weights_on_device_per_fit"} & set(line["metrics"])
+    # what it keeps of the placement is set-up's: the window moved no count
+    assert 0.0 < line["metrics"]["hostdata.staged_row_share"]["value"] <= 1.0
 
 
 def test_the_share_is_absent_where_the_program_counts_no_placement():
@@ -121,3 +130,27 @@ def test_the_share_is_absent_where_the_program_counts_no_placement():
     assert counter_ratio.read(
         params, {"counters": {"hostdata.placement_hits": 0.0},
                  "units": {"fits": 3.0}}) == 0.0
+
+
+def test_a_ratio_of_counters_the_window_did_not_move_is_set_ups():
+    """A window of hits stages nothing: ``hostdata.staged_row_share`` is
+    then the ratio of the placement set-up made. A count over the
+    window's units, or a ratio whose window moved, never looks there."""
+    from benchmark.readers import counter_ratio
+
+    staged = _read(BENCH, "metrics", "hostdata.staged_row_share.json")["params"]
+    setup = {"hostdata.stage.rows_sent": 500.0, "hostdata.stage.rows": 800.0,
+             "hostdata.placement_hits": 2.0}
+    still = {"hostdata.stage.rows_sent": 0.0, "hostdata.stage.rows": 0.0}
+    for window in ({}, still):
+        obs = {"counters": window, "setup_counters": setup, "units": {"fits": 3.0}}
+        assert counter_ratio.read(staged, obs) == 0.625
+    moved = {"hostdata.stage.rows_sent": 30.0, "hostdata.stage.rows": 40.0}
+    assert counter_ratio.read(staged, {"counters": moved, "setup_counters": setup,
+                                       "units": {"fits": 3.0}}) == 0.75
+    assert counter_ratio.read(staged, {"counters": still, "setup_counters": {},
+                                       "units": {}}) is None
+    assert counter_ratio.read(staged, {"counters": still, "units": {}}) is None
+    hits = _read(BENCH, "metrics", f"{HIT_SHARE}.json")["params"]
+    assert counter_ratio.read(hits, {"counters": {}, "setup_counters": setup,
+                                     "units": {"fits": 3.0}}) is None

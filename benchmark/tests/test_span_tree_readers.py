@@ -4,7 +4,7 @@
 ``roofline_of_program``, ``trace_idle_in_innermost_span``): on counters
 and a two-chip trace made by hand, where every number can be counted;
 every new metric file through ``run.load_spec``; and through a traced
-rehearsal of each of the five cells, which has the counters and no chip."""
+rehearsal of each of six cells, which has the counters and no chip."""
 
 import json
 import os
@@ -23,37 +23,45 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 MS = 1e6  # ns
 
 LR = ["lr-a9a.fit", "lr-criteo.fit"]
-FITS = LR + ["kmeans-mnist8m.fit"]
+COLD = ["lr-criteo.fit-cold"]
+FITS = LR + COLD + ["kmeans-mnist8m.fit"]
 TRANSFORMS = ["chain-a9a.transform", "knn-mnist8m.transform"]
 #: The cells whose traced run holds fits no profiler saw: not
-#: ``lr-criteo.fit``, whose one profiled fit fills the window (PERF.md §6).
+#: ``lr-criteo.fit-cold``, whose one profiled fit can fill the window
+#: (PERF.md §7).
 DENSE, PLAIN_FITS = ["lr-a9a.fit"], ["lr-a9a.fit", "kmeans-mnist8m.fit"]
-#: metric -> the cells that list it (ISSUE 34's table, less that).
+#: metric -> cells that list it, in the order PR 34 appended them. A
+#: later issue may list a metric in further cells and append entries
+#: after or between these; the host data path's metrics read in the cold
+#: cell alone since PR 54 (every timed fit of the two kept cells finds
+#: its placement kept, PR 37), and PR 54 retired the four the span tree
+#: made for ``lr-a9a.fit``'s plain fits.
 NEW = {
     "api.fit_own_s_per_fit": PLAIN_FITS,
-    "hostdata.permute_s_per_fit": DENSE,
-    "hostdata.gather_s_per_fit": DENSE,
-    "hostdata.stage_wait_plain_s_per_fit": DENSE,
-    "trainer.loop_plain_wall_s_per_fit": DENSE,
     "trainer.loop_own_s_per_fit": DENSE,
     "tracing.traced_unit_excess_s.fit": PLAIN_FITS,
     "tracing.traced_unit_excess_s.transform": TRANSFORMS,
-    "trainer.loop_device_ms_per_step": LR,
-    "dense_lr_loop_roofline": ["lr-a9a.fit"],
-    "sparse_lr_loop_roofline": ["lr-criteo.fit"],
-    "hostdata.stage_device_ms_per_fit": LR,
-    "device.idle_in_permute_s_per_fit": LR,
-    "device.idle_in_gather_s_per_fit": LR,
-    "device.idle_in_stage_wait_s_per_fit": LR,
-    "device.idle_in_loop_own_s_per_fit": LR,
-    "api.fit_own_traced_s_per_fit": LR,
-    "hostdata.permute_traced_s_per_fit": LR,
-    "hostdata.gather_traced_s_per_fit": LR,
-    "trainer.loop_own_traced_s_per_fit": LR,
+    "trainer.loop_device_ms_per_step": LR + COLD,
+    "dense_lr_loop_roofline": DENSE,
+    "sparse_lr_loop_roofline": ["lr-criteo.fit"] + COLD,
+    "hostdata.stage_device_ms_per_fit": COLD,
+    "device.idle_in_permute_s_per_fit": COLD,
+    "device.idle_in_gather_s_per_fit": COLD,
+    "device.idle_in_stage_wait_s_per_fit": COLD,
+    "device.idle_in_loop_own_s_per_fit": LR + COLD,
+    "api.fit_own_traced_s_per_fit": LR + COLD,
+    "hostdata.permute_traced_s_per_fit": COLD,
+    "hostdata.gather_traced_s_per_fit": COLD,
+    "trainer.loop_own_traced_s_per_fit": LR + COLD,
 }
 #: Of them, read from the counters alone: a rehearsal prints them.
 COUNTED = [m for m in NEW if m.split(".")[0] in ("api", "hostdata", "trainer", "tracing")
            and "device_ms" not in m]
+
+
+def _bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
 
 
 def _by_hand():
@@ -277,26 +285,29 @@ def test_a_parent_without_the_span_tree_reads_nothing(traced):
 
 @pytest.mark.parametrize("metric", sorted(NEW))
 def test_a_new_metric_loads_for_exactly_the_cells_it_lists(metric):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    bench = _bench()
     (entry,) = [m for m in bench["per_layer"] if m["name"] == metric]
-    assert entry["workloads"] == NEW[metric]
+    assert set(NEW[metric]) <= set(entry["workloads"])
     for cell in (w["name"] for w in bench["workloads"]):
         loaded = [m for m in run.load_spec(ROOT, cell)["per_layer"]
                   if m["name"] == metric]
-        assert len(loaded) == (cell in NEW[metric])
+        assert len(loaded) == (cell in entry["workloads"])
         for m in loaded:
             assert m["reader"] and m["what"] and m["moves"] in {
                 e["name"] for e in run.load_spec(ROOT, cell)["end_to_end"]}
 
 
 def test_the_new_entries_are_appended_and_keep_to_the_form():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    """Appended, never inserted: among themselves in the order they
+    came, after every layer they name was there. Later issues append
+    after them (and a ``benchmark`` issue may retire one), so no position
+    is counted from the end."""
+    bench = _bench()
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW):] == list(NEW)
-    layers = {m["layer"] for m in bench["per_layer"][:-len(NEW)]}
-    for m in bench["per_layer"][-len(NEW):]:
+    at = [names.index(n) for n in NEW]
+    assert at == sorted(at)
+    layers = {m["layer"] for m in bench["per_layer"][:at[0]]}
+    for m in (bench["per_layer"][i] for i in at):
         assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
         assert len(m["name"]) <= 64 and m["layer"] in layers
         assert m["source"] in ("program_span", "device_trace")
@@ -306,24 +317,26 @@ def test_the_new_entries_are_appended_and_keep_to_the_form():
 
 @pytest.mark.parametrize("cell", FITS + TRANSFORMS)
 def test_a_traced_rehearsal_of_every_cell_still_prints_its_line(cell, capsys):
-    """New metrics read from the counters are there (a rehearsal
-    profiles its first units as a chip run does), those read from the
-    chip's profile are absent, and none raises."""
+    """The metrics read from the counters are there in the cells that
+    list them (a rehearsal profiles its first units as a chip run does),
+    those read from the chip's profile are absent, and none raises."""
     assert run.main(["--workload", cell, "--seed", "2147493104", "--seconds", "1",
                      "--trace", "1", "--rehearse"]) == 0
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
-    got = set(line["metrics"])
-    counted = {m for m in COUNTED if cell in NEW[m]}
-    assert got & set(NEW) == counted
+    listed = {m["name"] for m in run.load_spec(ROOT, cell)["per_layer"]}
+    counted = set(COUNTED) & listed
+    assert {m for m in COUNTED if cell in NEW[m]} <= counted
+    assert set(line["metrics"]) & set(NEW) == counted
+    value = {m: line["metrics"][m]["value"] for m in counted}
+    assert all(v >= 0 for m, v in value.items() if "tracing" not in m)
     if cell in PLAIN_FITS:
-        assert line["metrics"]["api.fit_own_s_per_fit"]["value"] > 0
-    if cell in LR:
-        value = {m: line["metrics"][m]["value"] for m in counted}
-        assert all(v >= 0 for m, v in value.items() if "tracing" not in m)
+        assert value["api.fit_own_s_per_fit"] > 0
+    if cell in COLD:
         # the profiled fit's permutation and gathers are its hostdata.shuffle
         assert (value["hostdata.permute_traced_s_per_fit"]
                 + value["hostdata.gather_traced_s_per_fit"]) > 0
-    if cell in DENSE:
-        assert (value["trainer.loop_own_s_per_fit"]
-                <= value["trainer.loop_plain_wall_s_per_fit"])
+    if cell in LR:
+        # a hit: the loop is the fit, and nothing the host data path reads is there
+        assert 0 < value["trainer.loop_own_traced_s_per_fit"]
+        assert not {m for m in line["metrics"] if m.startswith("hostdata.permute")}

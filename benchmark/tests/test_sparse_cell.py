@@ -176,14 +176,15 @@ def test_roofline_in_program_span(monkeypatch):
     assert roofline_in_program_span.read({**params, "span": "absent"}, obs) is None
 
 
-NEW_COUNTED = ["hostdata.ingest_s_per_fit", "hostdata.shuffle_s_per_fit",
-               "hostdata.stage_wait_s_per_fit", "hostdata.upload_s_per_fit",
-               "hostdata.upload_bytes_per_s", "trainer.loop_wall_s_per_fit",
-               "trainer.readback_s_per_fit", "hostdata.sparse_pack_s_per_fit",
-               "hostdata.csr_materialized_rows", "api.sparse_fit_self_s_per_fit"]
-NEW_TRACED = ["trainer.sparse_step_ms", "sparse_lr_step_roofline",
-              "device.idle_share.fit", "device.idle_outside_spans.fit",
-              "device.idle_in_upload_s_per_fit"]
+#: What PR 26 listed for the cell and the cell still feeds: every timed
+#: fit finds its placement kept (PR 37), so the host data path's metrics
+#: list ``lr-criteo.fit-cold`` alone (PR 54; ``test_lr_cold_cell.py``).
+NEW_COUNTED = ["hostdata.ingest_s_per_fit", "trainer.readback_s_per_fit",
+               "hostdata.csr_materialized_rows"]
+NEW_TRACED = ["device.idle_share.fit", "device.idle_outside_spans.fit"]
+COLD_ONLY = ["hostdata.shuffle_s_per_fit", "hostdata.stage_wait_s_per_fit",
+             "hostdata.upload_s_per_fit", "hostdata.upload_bytes_per_s",
+             "hostdata.sparse_pack_s_per_fit"]
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -204,12 +205,14 @@ def test_a_rehearsal_of_the_cell(trace, capsys):
     if not trace:
         assert set(metrics) == {"fit_samples_per_s", "setup_s"}
         return
-    assert set(metrics) == set(NEW_COUNTED)    # a rehearsal has no device number
+    # a rehearsal has no device number, and a hit none of the host data path's
+    assert set(NEW_COUNTED) <= set(metrics)
+    assert not (set(NEW_TRACED) | set(COLD_ONLY)) & set(metrics)
     assert metrics["hostdata.csr_materialized_rows"]["value"] == 0.0
-    assert metrics["hostdata.sparse_pack_s_per_fit"]["value"] > 0.0
-    assert "compile.cache_misses.setup" not in metrics
-    phases = sum(metrics[n]["value"] for n in NEW_COUNTED if n.endswith("_s_per_fit"))
-    assert 0 <= metrics["api.sparse_fit_self_s_per_fit"]["value"] < phases
+    assert metrics["compile.cache_misses.setup"]["value"] >= 0.0
+    # the profiled fit: the loop is most of it, the rest is the fit's own
+    assert metrics["trainer.loop_own_traced_s_per_fit"]["value"] > 0.0
+    assert metrics["api.fit_own_traced_s_per_fit"]["value"] >= 0.0
 
 
 def test_the_cells_metrics_are_the_issues():
@@ -217,7 +220,8 @@ def test_the_cells_metrics_are_the_issues():
         bench = json.load(f)
     listed = {m["name"] for m in bench["per_layer"]
               if "lr-criteo.fit" in m.get("workloads", [])}
-    assert listed == set(NEW_COUNTED) | set(NEW_TRACED)
+    assert set(NEW_COUNTED) | set(NEW_TRACED) <= listed
+    assert not set(COLD_ONLY) & listed
     assert all("workloads" in m for m in bench["per_layer"])
     misses = next(m for m in bench["per_layer"] if m["name"] == "compile.cache_misses.setup")
-    assert misses["workloads"] == ["chain-a9a.transform", "lr-a9a.fit"]
+    assert {"chain-a9a.transform", "lr-a9a.fit", "lr-criteo.fit"} <= set(misses["workloads"])

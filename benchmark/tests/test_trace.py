@@ -9,8 +9,8 @@ import os
 import pytest
 
 from benchmark import trace
-from benchmark.readers import (counter_ratio, roofline, span_lead_share,
-                               trace_busy_per_unit, trace_idle_share)
+from benchmark.readers import (counter_ratio, roofline, trace_busy_per_unit,
+                               trace_idle_share)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 MS = 1e6  # ns
@@ -115,8 +115,10 @@ def test_recorded_transform_trace_gives_what_the_run_printed():
     # by hand: 4194304 * (123 * 4 + 12) B / 819e9 B/s = 2.5811 ms of 29.064 ms
     assert share == pytest.approx(100 * 4194304 * 504 / 819e9 / 0.029064021)
     assert share == pytest.approx(8.880774459412049)
-    lead = span_lead_share.read({"span": "transform-call"}, obs)
-    assert 79.0 < lead < 82.0
+    # the reducer's lead (its reader went with PR 54): four fifths of a call
+    lead = sum(s["lead_s"] for s in r["spans"]) / sum(
+        s["end_s"] - s["start_s"] for s in r["spans"])
+    assert 0.79 < lead < 0.82
     top = r["idle_gaps"][0]
     assert top[0] == "transform-call after compare_convert_fusion"
 
@@ -139,7 +141,6 @@ def test_readers_return_nothing_when_there_is_nothing_to_read():
            "cell": {}, "config": {}, "peaks": {}}
     assert trace_idle_share.read({}, obs) is None
     assert trace_busy_per_unit.read({"span": "fit", "unit": "steps"}, obs) is None
-    assert span_lead_share.read({"span": "fit"}, obs) is None
     assert counter_ratio.read({"num": "g.absent", "den": "rows"}, obs) is None
     assert counter_ratio.read({"num": "g.bytes", "den": "rows"}, obs) == 4.0
     assert counter_ratio.read({"num": "jax.cache_misses", "when": "setup"}, obs) == 0.0

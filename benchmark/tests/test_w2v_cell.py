@@ -30,7 +30,7 @@ with open(os.path.join(BENCH, "workloads", f"{CELL_NAME}.json")) as f:
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     BENCHMARK = json.load(f)
 
-COUNTED = ["compile.cache_misses.setup", "w2v.table_h2d_bytes_per_fit"]
+COUNTED = ["compile.cache_misses.setup", "w2v.sorted_update_share"]
 TRACED = ["w2v.step_device_ms", "w2v_step_roofline", "device.idle_share.fit",
           "device.idle_outside_spans.fit"]
 SPANS = ["w2v.init_s_per_fit", "w2v.dispatch_s_per_fit", "w2v.readback_s_per_fit"]
@@ -159,7 +159,7 @@ def test_the_configuration_and_the_entries():
     assert all(m["layer"] == "Word2Vec trainer" for m in BENCHMARK["per_layer"]
                if m["name"].startswith("w2v"))
     rate = next(m for m in BENCHMARK["end_to_end"] if m["name"] == "fit_samples_per_s")
-    assert rate["workloads"][-1] == CELL_NAME
+    assert CELL_NAME in rate["workloads"]      # not "the last": the next cell's goes after
     for name in mine:
         assert os.path.exists(os.path.join(BENCH, "metrics", f"{name}.json")), name
 
@@ -183,9 +183,12 @@ def test_a_rehearsal_of_the_cell(trace):
     checks = [c for c in lines if c.get("phase") == "check"]
     assert len(checks) == 8 and all(c["ok"] for c in checks)   # no vocabulary row
     assert 0 < checks[0]["value"] < min(CELL["limits"]["vector_gap"].values())
+    # held by the check, not by a per-layer metric (PR 54): a miss is not correct
+    assert [(c["value"], c["limit"]) for c in checks
+            if "bytes uploaded inside the window" in c["what"]] == [(0.0, 0)]
     if trace:
         assert set(COUNTED) <= set(line["metrics"])
-        assert line["metrics"]["w2v.table_h2d_bytes_per_fit"]["value"] == 0.0
+        assert 0.0 <= line["metrics"]["w2v.sorted_update_share"]["value"] <= 1.0
     else:
         assert set(line["metrics"]) == {"fit_samples_per_s", "setup_s"}
 

@@ -256,13 +256,19 @@ def test_a_cells_new_entries_resolve_to_files_and_read_none_without_a_trace(cell
         assert m["params"]["unit"] == own["params"]["unit"]
 
 
-def test_the_new_entries_are_the_last_of_their_list_and_other_cells_have_none():
+def test_the_new_entries_were_appended_in_their_order_and_list_their_cells():
+    """ISSUE 49's twenty-one, in the order it appended them (later
+    issues append their own after or between, so none is counted from
+    the end), each listing every cell whose program has its phase."""
+    own = list(dict.fromkeys(f"{p}_device_ms" for c in CELLS for p in CELLS[c]))
+    own.append("device.unphased_share.fit")
+    assert len(own) == 21
     names = [m["name"] for m in BENCHMARK["per_layer"]]
-    new = [m for m in BENCHMARK["per_layer"]
-           if os.path.exists(os.path.join(ROOT, "benchmark", "metrics", f"{m['name']}.json"))
-           and json.load(open(os.path.join(
-               ROOT, "benchmark", "metrics", f"{m['name']}.json")))["reader"]
-           == "trace_phase_device_time"]
-    assert len(new) == 21 and [m["name"] for m in new] == names[-21:]
-    listed = {w for m in new for w in m["workloads"]}
-    assert listed == set(CELLS)
+    at = [names.index(n) for n in own]
+    assert at == sorted(at)
+    for n, i in zip(own, at):
+        entry = BENCHMARK["per_layer"][i]
+        with open(os.path.join(ROOT, "benchmark", "metrics", f"{n}.json")) as f:
+            assert json.load(f)["reader"] == "trace_phase_device_time"
+        cells = {c for c in CELLS if n[:-len("_device_ms")] in CELLS[c]} or set(CELLS)
+        assert cells <= set(entry["workloads"]), n
