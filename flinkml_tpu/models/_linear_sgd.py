@@ -283,9 +283,11 @@ def make_dense_step(loss: str, local_bs: int, axis: str):
 #: The sparse step's phases (``profiling.phase``): the coefficients of a
 #: window's cells looked up, and their gradient accumulated, each by the
 #: block products (kernel or XLA) with the gather or segment-sum of the
-#: slots no block takes. The margin's parts, the L2 term and the update
-#: are in none.
-SPARSE_PHASES = ("lr.sparse_lookup", "lr.sparse_accumulate")
+#: slots no block takes; and the all-reduce of the local gradient and
+#: the two local sums over the data axis (on a mesh of one the compiler
+#: drops it, and the phase holds no operation). The margin's parts, the
+#: L2 term and the update are in none.
+SPARSE_PHASES = ("lr.sparse_lookup", "lr.sparse_accumulate", "lr.psum")
 
 
 def _lane_rows(x):
@@ -435,9 +437,10 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
                 for rows, sums in block_grads:
                     tiled = tiled.at[rows].add(sums.reshape(rows.shape + (LANES,)))
                 grad_local = tiled.reshape(-1)[:dim]
-        grad = jax.lax.psum(grad_local, axis)
-        loss_sum = jax.lax.psum(loss_l, axis)
-        wsum = jax.lax.psum(wsum_l, axis)
+        with phase("lr.psum"):
+            grad = jax.lax.psum(grad_local, axis)
+            loss_sum = jax.lax.psum(loss_l, axis)
+            wsum = jax.lax.psum(wsum_l, axis)
         grad = grad + 2.0 * reg_l2 * coef
         loss_sum = loss_sum + reg_l2 * jnp.sum(jnp.square(coef.astype(acc)))
         step_size = learning_rate.astype(acc) / wsum
@@ -591,9 +594,12 @@ def _run_chunked(
       (epoch granularity requires the host loop in ``iterate``; the
       device loop surfaces only chunk boundaries to the host).
 
-    ``metrics.group("trainer")`` counts ``steps`` (run) and
+    ``metrics.group("trainer")`` counts ``steps`` (run),
     ``pipelined_steps`` (of them, dispatched before the placement's last
-    round was sent).
+    round was sent), ``mesh_devices`` (the data axis's size, added once a
+    fit: over the fits, the workers of a fit) and ``psum_bytes`` (what a
+    device hands a step's all-reduce, the gradient and the two sums, over
+    the steps run; 0 on a mesh of one, which reduces nothing).
     """
     from flinkml_tpu.iteration.checkpoint import begin_resume
 
@@ -652,6 +658,11 @@ def _run_chunked(
     counts.counter("steps", float(epoch - first))
     counts.counter("pipelined_steps",
                    0.0 if at_host else float(min(epoch, before) - first))
+    workers = mesh.axis_size()
+    counts.counter("mesh_devices", float(workers))
+    counts.counter("psum_bytes", 0.0 if workers == 1 else float(
+        (epoch - first)
+        * (coef.size * coef.itemsize + 2 * jnp.dtype(_acc_dt(dt)).itemsize)))
     with span("trainer.readback"):
         result = np.asarray(carry[0])
         if checkpoint_manager is not None:

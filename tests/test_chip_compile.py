@@ -220,15 +220,21 @@ def test_fm_adam_loop_at_the_cells_size(topo, no_compile_cache, precision):
     assert memory.output_size_in_bytes < 0.1e9    # 17 x 7813 x 128 floats
 
 
-@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("chips, rows, steps", [
+    (1, 16_777_216, 160), (4, 16_777_216, 160), (4, 4 * 11_460_155, 700)],
+    ids=["lr-criteo.fit", "lr-criteo.fit-4chips", "lr-criteo-dp4.fit"])
 def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
-        topo, no_compile_cache, monkeypatch, chips):
+        topo, no_compile_cache, monkeypatch, chips, rows, steps):
     """``lr-criteo.fit``'s one program, ``lr_sparse_loop``: 160 steps of
     65,536 rows over 16,777,216 x 39 resident cells, ``dim`` 1,000,000,
     under the cell's slot plan (``fm-criteo.fit``'s: the same rows), on a
     one-chip mesh as the cell runs it and on the host's four chips (a
     quarter of the rows and of the batch each, the gradient's ``psum``
-    after the kernels). On a TPU the 39 blocked slots' products are
+    after the kernels); and ``lr-criteo-dp4.fit``'s (PR 55): the whole
+    file's 45,840,617 rows, padded to 11,460,155 a chip (a shard that is
+    no whole number of its 16,384-row windows), 700 steps, the ``psum``
+    under its own phase, ``lr.psum``, which a mesh of one compiles to
+    nothing. On a TPU the 39 blocked slots' products are
     ``kernels.sparse_blocks``' two kernels, compiled here by Mosaic (not
     interpreted): one ``[65,536, 128]`` product of a slot in HBM is 33.5
     MB, and the program's temporaries stay under what five of them would
@@ -243,7 +249,7 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
     from flinkml_tpu.models import _linear_sgd
 
     monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
-    rows, width, dim, batch = 16_777_216, 39, 1_000_000, 65_536
+    width, dim, batch = 39, 1_000_000, 65_536
     assert _linear_sgd._blocks_in_fast_memory(jnp.float32, batch // chips,
                                               FM_CRITEO_PLAN)
     mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
@@ -266,7 +272,7 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
                 on((rows, width), i32, rows_minor), on((rows, width), f32, rows_minor),
                 on((rows,), f32, by_rows), on((rows,), f32, by_rows),
                 on((chips * width,), i32, by_rows),
-                on((), f32), on((), f32), on((), f32), on((), f32), np.int32(160))
+                on((), f32), on((), f32), on((), f32), on((), f32), np.int32(steps))
             kernels = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)]
             assert len(kernels) == 2                    # the lookup, the accumulation
             wide = [str(v.aval) for eqn in kernels
@@ -278,10 +284,16 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
         _linear_sgd._sparse_trainer_bucketed.cache_clear()
     text = compiled.as_text()
     assert "lr_sparse_loop" in text and text.count("tpu_custom_call") == 2
-    assert _phases(text) == set(_linear_sgd.SPARSE_PHASES)
+    # the collective's phase holds an operation where there is a collective
+    assert _phases(text) == set(_linear_sgd.SPARSE_PHASES) - (
+        {"lr.psum"} if chips == 1 else set())
+    assert ("all-reduce" in text) == (chips > 1)
     memory = compiled.memory_analysis()
-    # the cells, labels and weights: 5.37 GB, a third of one chip
-    assert 0.33 * 16e9 < chips * memory.argument_size_in_bytes < 0.36 * 16e9
+    # the cells, labels and weights: 328 B a row as the chip tiles them
+    # (a row's 39 slots on 40 sublanes): 5.5 GB, a third of one chip; the
+    # whole file 3.76 GB a chip, 23.5 %
+    assert (0.99 * 328 * rows < chips * memory.argument_size_in_bytes
+            < 1.01 * 328 * rows + 64e6)
     assert memory.temp_size_in_bytes < 5 * batch * 128 * 4
 
 

@@ -501,11 +501,12 @@ def test_the_metric_reads_its_two_counters(monkeypatch, name):
     assert spec["params"] == {"num": num, "den": den}
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         (entry,) = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    # The cold cell is where a fit stages rows and pipelines steps; which
+    # other cells list the entry is the benchmark's to decide.
+    assert "lr-criteo.fit-cold" in entry.pop("workloads")
     assert entry == {"name": name, "unit": unit, "better": better,
                      "source": "program_counter", "layer": layer,
-                     "moves": "fit_samples_per_s",
-                     "workloads": ["lr-a9a.fit", "lr-criteo.fit",
-                                   "lr-criteo.fit-cold"]}
+                     "moves": "fit_samples_per_s"}
     monkeypatch.setattr(mesh_mod, "_STAGE_BYTES", TINY_STAGE)
     with _Delta() as counted:
         _fit(_table("dense"), 1, max_iter=5, batch=100)
