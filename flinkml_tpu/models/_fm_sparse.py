@@ -51,6 +51,12 @@ does, dense moments over the whole table.
   nothing of the table and is bit-equal at equal hyper-parameters.
 - **One program**, ``fm_adam_loop``: ``max_iter``, rate, ``reg`` and
   ``tol`` are runtime operands, so a sweep over them compiles once.
+- **Hand-over**: a second, small program (``fm_handover``) turns the
+  learned table to the model's ``w`` and ``V [dim, k]`` on the device
+  (in rows of 128 lanes, the padding last), one ``jax.device_get``
+  brings them, ``w0`` and the step count to the host, and the model
+  holds views of those float32 buffers: no pass over the parameters is
+  made on the host (PR 56).
 
 Spans and counters: ``docs/development/observability.md`` ("Spans",
 ``fm.*``; the group ``fm``).
@@ -386,6 +392,26 @@ def _trainer(mesh, logistic: bool, local_bs: int, axis: str, slot_plan: Tuple,
     ))
 
 
+def _model_layout(table):
+    """The learned ``table [1 + k, dim_pad / 128, 128]`` as the model
+    holds it, in rows of 128 lanes: ``(w [dim_pad / 128, 128], V [dim_pad
+    * k / 128, 128])``, ``V`` a column's ``k`` factors side by side. Read
+    flat, each is the model's array and then the padding past ``dim``, so
+    the host's cut and ``reshape(dim, k)`` are views. Not ``[dim, k]``
+    itself: a TPU pads the ``k`` to 128 lanes and hands the host a layout
+    it has to turn again; and not flat: a one-axis array of 64 MB crosses
+    the link three times slower than the same bytes in rows (0.072 s for
+    0.025: PERF.md section 5, PR 56)."""
+    factors = table[1:].reshape(table.shape[0] - 1, -1)
+    return table[0], factors.T.reshape(-1, LANES)
+
+
+#: Dispatched straight after ``fm_adam_loop``, before the wait: the turn
+#: the host made of the read-back (a strided pass over ``dim * k`` floats)
+#: is the device's, outside the loop and outside every phase.
+_handover = jax.jit(named_program("fm_handover", _model_layout))
+
+
 def padded_dim(dim: int) -> int:
     """``dim`` rounded up to whole rows of 128 columns: the parameter
     table's columns, those past ``dim`` zero for good (no cell names them,
@@ -396,8 +422,10 @@ def padded_dim(dim: int) -> int:
 def fit_csr(est, table, logistic: bool, precision=LOOKUP_PRECISION):
     """``FMClassifier.fit`` / ``FMRegressor.fit`` where the features
     column is a ``CsrColumn``: ``(w0 [1], w [dim], V [dim, k])`` as
-    float32 host arrays. The caller's span ``fit`` holds all of it.
-    ``precision`` is the benchmark's control's alone."""
+    float32 host arrays, C-contiguous and read-only: views of the buffers
+    one ``jax.device_get`` returned, laid as the model holds them by the
+    device (:func:`_model_layout`), with no pass over them on the host. The caller's span ``fit`` holds all of
+    it. ``precision`` is the benchmark's control's alone."""
     features_col = est.get(est.FEATURES_COL)
     label_col, weight_col = est.get(est.LABEL_COL), est.get(est.WEIGHT_COL)
     indptr, indices, values, dim, labels, w = sparse_fit_columns(
@@ -431,20 +459,22 @@ def fit_csr(est, table, logistic: bool, precision=LOOKUP_PRECISION):
                        placed.slot_plan, precision)
     with span("fm.loop"):
         with span("fm.dispatch"):
-            out = trainer(
+            w0, learned, steps, _ = trainer(
                 w0, start, placed.indices, placed.values, placed.labels,
                 placed.weights, placed.starts,
                 np.float32(est.get(est.LEARNING_RATE)), np.asarray(reg)[0],
                 np.int32(est.get(est.MAX_ITER)),
                 np.float32(est.get(est.TOL)))
+        out = (w0, *_handover(learned), steps)
         # The caller reads the parameters next: waiting here costs
         # nothing and gives the loop a span its device time lies in.
         jax.block_until_ready(out)
     with span("fm.readback"):
-        w0, steps = np.asarray(out[0]), int(out[2])
-        learned = np.asarray(out[1]).reshape(out[1].shape[0], -1)
-        # As the model holds them: a column's factors side by side.
-        weights, factors = learned[0, :dim], np.ascontiguousarray(learned[1:, :dim].T)
+        w0, weights, factors, steps = jax.device_get(out)
+        # Views: the padding past ``dim`` cut, a column's factors a row.
+        k = learned.shape[0] - 1
+        weights = weights.reshape(-1)[:dim]
+        factors = factors.reshape(-1)[:dim * k].reshape(dim, k)
     group = metrics.group("fm")
     group.counter("fits")
     group.counter("steps", float(steps))
@@ -461,18 +491,21 @@ def fit_csr(est, table, logistic: bool, precision=LOOKUP_PRECISION):
 def csr_margin(csr, w0: float, w: np.ndarray, v: np.ndarray,
                chunk_rows: int = 1 << 16) -> np.ndarray:
     """The model's margin for every row of a ``CsrColumn``, from its
-    arrays (no ``SparseVector`` is built): NumPy at the parameters' own
-    precision, a chunk of rows at a time."""
+    arrays (no ``SparseVector`` is built): NumPy at float64, the
+    parameters widened as gathered (a fit's are float32: the product of
+    a float64 value and a gathered float32 parameter is the product with
+    its float64 value), a chunk of rows at a time, so ``w`` and ``v`` are
+    never widened whole."""
     if csr.dim != w.shape[0]:
         raise ValueError(
             f"sparse features have dim {csr.dim}, model expects {w.shape[0]}")
     indptr = np.asarray(csr.indptr, np.int64)
     n = indptr.size - 1
-    out = np.full(n, w0, np.result_type(w.dtype, np.float32))
+    out = np.full(n, w0, np.float64)
     for lo in range(0, n, chunk_rows):
         hi = min(lo + chunk_rows, n)
         cells = slice(indptr[lo], indptr[hi])
-        idx, x = csr.indices[cells], csr.values[cells].astype(out.dtype)
+        idx, x = csr.indices[cells], csr.values[cells].astype(np.float64)
         row = np.repeat(np.arange(hi - lo), np.diff(indptr[lo:hi + 1]))
         xv = x[:, None] * v[idx]
         per_row = x * w[idx] - 0.5 * np.sum(xv * xv, axis=1)

@@ -51,6 +51,7 @@ from flinkml_tpu.models._data import (
 from flinkml_tpu.params import IntParam, ParamValidators
 from flinkml_tpu.parallel import DeviceMesh, pad_to_multiple
 from flinkml_tpu.table import Table
+from flinkml_tpu.utils.metrics import metrics
 from flinkml_tpu.utils.profiling import span
 
 
@@ -271,11 +272,13 @@ class _FMBase(StreamingEstimatorMixin, _FMParams, Estimator):
         )
 
     def _make_model(self, params):
+        """The model of a fit's ``(w0 [1], w, V)``: it holds the float32
+        arrays the fit read back as they are (no float64 copy: a float32
+        widened is the same number, and every margin widens what it
+        gathers)."""
         model = (FMClassifierModel if self._LOGISTIC else FMRegressorModel)()
         model.copy_params_from(self)
-        model._set(np.asarray(params[0], np.float64)[0],
-                   np.asarray(params[1], np.float64),
-                   np.asarray(params[2], np.float64))
+        model._set(np.asarray(params[0])[0], params[1], params[2])
         return model
 
     def _fit_stream(self, source):
@@ -416,7 +419,15 @@ class _FMBase(StreamingEstimatorMixin, _FMParams, Estimator):
                 "features matrix's columns. Drop the plan."
             )
         with span("fit"):
-            return self._make_model(_fm_sparse.fit_csr(self, table, self._LOGISTIC))
+            params = _fm_sparse.fit_csr(self, table, self._LOGISTIC)
+            model = self._make_model(params)
+            # Read off the arrays, not stated: 1.0 a fit whose model holds
+            # the read-back's own float32 buffers.
+            metrics.group("fm").counter("handover_view_fits", float(all(
+                held.dtype == np.float32 and held.flags.c_contiguous
+                and np.shares_memory(held, read)
+                for held, read in ((model._w, params[1]), (model._v, params[2])))))
+            return model
 
     def fit(self, *inputs):
         (table,) = inputs
@@ -457,7 +468,23 @@ class _FMBase(StreamingEstimatorMixin, _FMParams, Estimator):
         return self._make_model(params)
 
 
+def _floating(a) -> np.ndarray:
+    """``a`` as the model holds it: a floating array as it is, anything
+    else as float64."""
+    a = np.asarray(a)
+    return a if a.dtype.kind == "f" else a.astype(np.float64)
+
+
 class _FMModelBase(_FMParams, Model):
+    """``w0``, ``w [dim]`` and ``V [dim, k]`` in whatever floating dtype
+    they were handed: a fit's are the float32 arrays the device returned
+    (``get_model_data`` hands out views of them), ``set_model_data`` and
+    ``load`` keep the table's or the file's (a model saved as float64
+    loads as float64). Every margin of ``transform`` is float64
+    arithmetic whatever they are, the parameters widened as they are
+    gathered: a float32 model scores as its float64 copy does, to the
+    bit."""
+
     def __init__(self):
         super().__init__()
         self._w0: Optional[float] = None
@@ -465,14 +492,14 @@ class _FMModelBase(_FMParams, Model):
         self._v: Optional[np.ndarray] = None
 
     def _set(self, w0, w, v):
-        self._w0, self._w, self._v = float(w0), np.asarray(w), np.asarray(v)
+        self._w0, self._w, self._v = float(w0), _floating(w), _floating(v)
 
     def set_model_data(self, *inputs: Table):
         (table,) = inputs
         self._set(
-            float(np.asarray(table.column("w0"))[0]),
-            np.asarray(table.column("w"), np.float64)[0],
-            np.asarray(table.column("v"), np.float64)[0],
+            np.asarray(table.column("w0"))[0],
+            np.asarray(table.column("w"))[0],
+            np.asarray(table.column("v"))[0],
         )
         return self
 
@@ -499,9 +526,11 @@ class _FMModelBase(_FMParams, Model):
             return csr_margin(csr, self._w0, self._w, self._v)
         if sparse_features(table, self.get(self.FEATURES_COL)) is not None:
             return self._margin_sparse(table.column(self.get(self.FEATURES_COL)))
+        # float64 rows: the products promote the parameters, and the
+        # squares are float64's own (a float32 square rounds).
         x = features_matrix(table, self.get(self.FEATURES_COL))
         xv = x @ self._v
-        x2v2 = (x * x) @ (self._v * self._v)
+        x2v2 = (x * x) @ np.multiply(self._v, self._v, dtype=np.float64)
         return self._w0 + x @ self._w + 0.5 * (xv * xv - x2v2).sum(axis=1)
 
     def _margin_sparse(self, vecs) -> np.ndarray:
@@ -512,8 +541,9 @@ class _FMModelBase(_FMParams, Model):
         the pairwise term gathers factor rows (``v[indices]`` is
         O(nnz·k)) and contracts with two einsums. ELL padding (index 0
         / value 0) is exact: value 0 zeroes both the gather product and
-        the squared term. Runs under x64 so the float64 model
-        parameters keep full precision, matching the dense path."""
+        the squared term. Runs under x64 and widens the gathered
+        parameters (never the table), so the margin is float64
+        arithmetic, matching the dense path."""
         import jax
 
         from flinkml_tpu.ops.sparse import BatchedCSR, ell_matvec
@@ -528,7 +558,7 @@ class _FMModelBase(_FMParams, Model):
             return np.full(vb.shape[0], self._w0)
         with jax.enable_x64(True):
             linear = np.asarray(ell_matvec(ib, vb, self._w))
-        gathered = self._v[ib]                       # [n, s, k]
+        gathered = self._v[ib].astype(np.float64, copy=False)   # [n, s, k]
         xv = np.einsum("ns,nsk->nk", vb, gathered)
         x2v2 = np.einsum("ns,nsk->nk", vb * vb, gathered * gathered)
         return self._w0 + linear + 0.5 * (xv * xv - x2v2).sum(axis=1)

@@ -220,6 +220,31 @@ def test_fm_adam_loop_at_the_cells_size(topo, no_compile_cache, precision):
     assert memory.output_size_in_bytes < 0.1e9    # 17 x 7813 x 128 floats
 
 
+def test_fm_handover_at_the_cells_size(one_chip, no_compile_cache):
+    """``fm-criteo.fit``'s hand-over (PR 56): the learned ``[17, 7813,
+    128]`` table turned to ``w [7813, 128]`` and ``V [125008, 128]`` (a
+    column's 16 factors side by side) on the device. Both results lie in
+    rows of 128 lanes, the host's own order, so the read-back is a plain
+    copy; handed out as ``[dim, 16]`` the compiler relabels the ``[16,
+    dim]`` it had (layout ``{0,1}``) and leaves the turn to the host
+    again. The turn passes through ``[dim, 16]`` lane-padded eightfold:
+    half a gigabyte of temporaries, once a fit, beside a table of 5.4 GB
+    (and under the loop's own peak: ``memory_peak_bytes`` did not move)."""
+    from flinkml_tpu.models import _fm_sparse
+
+    dim, k = 1_000_000, 16
+    table = jax.ShapeDtypeStruct(
+        (k + 1, _fm_sparse.padded_dim(dim) // 128, 128), jnp.float32, sharding=one_chip)
+    compiled = _fm_sparse._handover.lower(table).compile()
+    text = compiled.as_text()
+    assert "fm_handover" in text.splitlines()[0]
+    assert ("(f32[7813,128]{1,0:T(8,128)}, f32[125008,128]{1,0:T(8,128)}) tuple("
+            in text)
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < 0.07e9        # 17 x 1,000,064 floats
+    assert memory.temp_size_in_bytes < 0.6e9
+
+
 @pytest.mark.parametrize("chips, rows, steps", [
     (1, 16_777_216, 160), (4, 16_777_216, 160), (4, 4 * 11_460_155, 700)],
     ids=["lr-criteo.fit", "lr-criteo.fit-4chips", "lr-criteo-dp4.fit"])

@@ -25,7 +25,12 @@ order):
   kernels (PR 53: a TPU's, here interpreted), one device and four;
   overlapping blocks; and what the kernels do not take (a batch that is
   not whole tiles, a block too long for fast memory, the control's one
-  pass) is the fit as it was, to the bit.
+  pass) is the fit as it was, to the bit;
+- the hand-over (PR 56): the model holds the float32 buffers the fit's one
+  ``jax.device_get`` returned, laid ``w [dim]`` and ``V [dim, k]`` by the
+  device to the bit of the host's turn of the raw table; every margin
+  path scores a float32 model as its float64 copy, to the bit; ``save``,
+  ``load`` and ``set_model_data`` keep the floating dtype they meet.
 """
 
 import contextlib
@@ -702,6 +707,141 @@ def test_overlapping_blocks_add_through_the_kernels_too(through_the_kernels):
     for a, b in zip(jax.tree.leaves(planned[1]), jax.tree.leaves(general[1])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=2e-8)
     assert float(planned[3]) == pytest.approx(float(general[3]), rel=1e-6)
+
+
+# -- the hand-over (PR 56) ----------------------------------------------------
+
+def test_the_model_holds_the_buffers_the_read_back_returned(monkeypatch):
+    """No pass over the parameters on the host: ``w`` and ``V`` are the
+    arrays ONE ``jax.device_get`` returned (views: the padding cut, a
+    column's factors a row), float32 and C-contiguous, ``get_model_data`` hands out views
+    of them, and the fit counts itself."""
+    rng = np.random.default_rng(17)
+    rows_of = _field_rows(rng, 600)
+    dim = rows_of[3]
+    read = []
+    device_get = jax.device_get
+
+    def spy(tree):
+        read.append(device_get(tree))
+        return read[-1]
+
+    monkeypatch.setattr(jax, "device_get", spy)
+    with _delta("fm") as counts:
+        model = _estimator().fit(_table(rows_of, _labels(rng, 600)))
+    monkeypatch.undo()
+    assert counts["handover_view_fits"] == 1 and counts["fits"] == 1
+    (w0, w, v, steps), = read                        # one read-back a fit
+    assert w0.shape == (1,) and int(steps) == STEPS
+    # in rows of 128 lanes, the padding past dim (1,500 of 1,536) last
+    assert w.shape == (12, 128) and v.shape == (12 * K, 128)
+    assert model._w0 == float(w0[0]) and isinstance(model._w0, float)
+    assert np.shares_memory(model._w, w) and np.shares_memory(model._v, v)
+    for held, shape in ((model._w, (dim,)), (model._v, (dim, K))):
+        assert held.dtype == np.float32 and held.shape == shape
+        assert held.flags.c_contiguous
+    data = model.get_model_data()[0]
+    assert np.shares_memory(data.column("w"), model._w)
+    assert np.shares_memory(data.column("v"), model._v)
+    assert data.column("w").dtype == data.column("v").dtype == np.float32
+    assert data.column("w").shape == (1, dim) and data.column("v").shape == (1, dim, K)
+
+
+def test_a_model_that_copies_its_parameters_is_not_counted(monkeypatch):
+    """The counter reads the arrays, not a flag: a model that widened
+    what it was handed (the parent's) counts 0."""
+    rng = np.random.default_rng(17)
+    table = _table(_field_rows(rng, 600), _labels(rng, 600))
+    monkeypatch.setattr(fm, "_floating", lambda a: np.asarray(a, np.float64))
+    with _delta("fm") as counts:
+        model = _estimator().fit(table)
+    assert model._v.dtype == np.float64
+    assert counts["fits"] == 1 and "handover_view_fits" not in counts
+
+
+def test_the_devices_turn_is_the_hosts_turn_of_the_raw_table(monkeypatch):
+    """``fit_csr``'s ``(w0, w, V)`` against the parent's layout of the
+    table the loop returned (``table[0, :dim]``, ``table[1:, :dim].T``),
+    to the bit, at a ``dim`` (1,500) that is no multiple of 128."""
+    rng = np.random.default_rng(18)
+    rows_of = _field_rows(rng, 600)
+    dim = rows_of[3]
+    assert dim % 128 and _fm_sparse.padded_dim(dim) == 1536
+    raw = []
+    handover = _fm_sparse._handover
+
+    def spy(table):
+        raw.append(np.asarray(table))
+        return handover(table)
+
+    monkeypatch.setattr(_fm_sparse, "_handover", spy)
+    w0, w, v = _fm_sparse.fit_csr(_estimator(), _table(rows_of, _labels(rng, 600)), True)
+    (table,) = raw
+    assert table.shape == (K + 1, 12, 128) and table.dtype == np.float32
+    planes = table.reshape(K + 1, -1)
+    assert not planes[:, dim:].any() and planes[1:, :dim].any()
+    assert w.tobytes() == planes[0, :dim].tobytes()
+    assert v.tobytes() == np.ascontiguousarray(planes[1:, :dim].T).tobytes()
+    assert (w0.shape, w.shape, v.shape) == ((1,), (dim,), (dim, K))
+    assert w0.dtype == w.dtype == v.dtype == np.float32
+
+
+def _model_of(cls, w0, w, v):
+    model = cls().set_features_col("features")
+    return model.set_model_data(Table({"w0": np.asarray([w0]), "w": w[None], "v": v[None]}))
+
+
+@pytest.mark.parametrize("cls", [fm.FMClassifierModel, fm.FMRegressorModel])
+@pytest.mark.parametrize("path", ["CsrColumn", "SparseVector column", "dense matrix"])
+def test_a_float32_model_scores_as_its_float64_copy_to_the_bit(path, cls):
+    """Every margin is float64 arithmetic, the parameters widened as they
+    are gathered: the model a fit makes (float32) against a clone handed
+    ``astype(np.float64)`` copies (what the parent's model held)."""
+    rng = np.random.default_rng(19)
+    rows_of = _hashed_rows(rng, 300, dim=400)
+    dim, idx, val = rows_of[3:]
+    w = rng.standard_normal(dim).astype(np.float32)
+    v = (0.3 * rng.standard_normal((dim, K))).astype(np.float32)
+    narrow = _model_of(cls, 0.25, w, v)
+    wide = _model_of(cls, 0.25, w.astype(np.float64), v.astype(np.float64))
+    assert narrow._v.dtype == np.float32 and wide._v.dtype == np.float64
+    table = _table(rows_of, np.zeros(300, np.float32))
+    if path == "SparseVector column":
+        table = Table({"features": table.column("features")})
+        assert table.column("features").dtype == object
+    elif path == "dense matrix":
+        table = Table({"features": reference.densified(idx, val, dim)})
+    for column in ("prediction",) + (("rawPrediction",) if "Classifier" in cls.__name__ else ()):
+        got = np.asarray(narrow.transform(table)[0].column(column))
+        want = np.asarray(wide.transform(table)[0].column(column))
+        assert got.dtype == np.float64 and got.tobytes() == want.tobytes()
+
+
+def test_save_load_and_set_model_data_keep_the_floating_dtype(tmp_path):
+    rng = np.random.default_rng(20)
+    rows_of = _field_rows(rng, 300)
+    table = _table(rows_of, _labels(rng, 300))
+    model = _estimator(steps=3).fit(table)
+    model.save(str(tmp_path / "narrow"))
+    loaded = fm.FMClassifierModel.load(str(tmp_path / "narrow"))
+    assert loaded._w.dtype == loaded._v.dtype == np.float32
+    assert loaded._v.tobytes() == model._v.tobytes() and loaded._w0 == model._w0
+    want = np.asarray(model.transform(table)[0].column("rawPrediction"))
+    assert np.asarray(loaded.transform(table)[0].column("rawPrediction")
+                      ).tobytes() == want.tobytes()
+    # what an earlier tree saved (float64) loads and scores as float64
+    wide = _model_of(fm.FMClassifierModel, model._w0, model._w.astype(np.float64),
+                     model._v.astype(np.float64))
+    assert wide._w.dtype == wide._v.dtype == np.float64
+    wide.save(str(tmp_path / "wide"))
+    loaded = fm.FMClassifierModel.load(str(tmp_path / "wide"))
+    assert loaded._w.dtype == loaded._v.dtype == np.float64
+    assert np.asarray(loaded.transform(table)[0].column("rawPrediction")
+                      ).tobytes() == want.tobytes()
+    # a table of whole numbers is held as float64
+    whole = _model_of(fm.FMClassifierModel, 1, np.arange(rows_of[3]),
+                      np.ones((rows_of[3], K), np.int32))
+    assert whole._w.dtype == whole._v.dtype == np.float64 and whole._w0 == 1.0
 
 
 # -- the API's edges ----------------------------------------------------------

@@ -873,6 +873,12 @@ def _lowered_programs():
             _struct((2,), i32, rep), _struct((), f32, rep), _struct((), f32, rep),
             _struct((), i32, rep), _struct((), f32, rep))
 
+    def fm_handover():
+        from flinkml_tpu.models import _fm_sparse
+
+        rep, _ = shardings()
+        return _fm_sparse._handover.lower(_struct((4, 3, 128), f32, rep))
+
     def als_half_step():
         from flinkml_tpu.models import _als_blocked
 
@@ -965,6 +971,7 @@ def _lowered_programs():
             _struct((), i32), 64, np.dtype(np.float32)),
         "kmeans_lloyd": kmeans_lloyd,
         "fm_adam_loop": fm_adam_loop,
+        "fm_handover": fm_handover,
         "als_half_step": als_half_step,
         "w2v_sgns_loop": w2v_sgns_loop,
         "gbt_forest": gbt_forest,
@@ -976,7 +983,7 @@ def _lowered_programs():
 
 
 PROGRAMS = ("lr_dense_loop", "lr_sparse_loop", "lr_softmax_loop", "stage_write",
-            "stage_zeros", "stage_ones", "kmeans_lloyd", "fm_adam_loop", "knn_vote",
+            "stage_zeros", "stage_ones", "kmeans_lloyd", "fm_adam_loop", "fm_handover", "knn_vote",
             "rows_sq", "fused_chain", "als_half_step", "w2v_sgns_loop", "gbt_forest",
             "mlp_fit", "mlp_start")
 
