@@ -2,7 +2,7 @@
 """chip_smoke.py — does the system still start on the chip?
 
 Drives fit, transform, serving, the streamed (checkpointed) fit, native
-ingest, the Pallas kernels and — on more than one chip — plan-sharded
+ingest, a Pallas kernel and — on more than one chip — plan-sharded
 training once each, through the entry points a user calls, in ONE
 process, and checks every phase's result by the repo's own means. It is
 a bring-up check, not a benchmark: the ``wall_s`` it prints are not
@@ -475,19 +475,14 @@ def phase_ingest(ctx) -> dict:
 
 
 def phase_kernels(ctx) -> dict:
-    """Each of three Pallas kernels at the shape phases 2-4 hand it: it
-    compiles (``interpret=False``) and agrees with the XLA lowering, or its
-    ``unsupported_reason`` names why not and an explicit request raises
-    :class:`KernelUnsupportedError` with that reason."""
+    """The top-k kernel at the shape the tiled KNN fallback ranks a tile
+    with: it compiles (``interpret=False`` on the chip) and its values and
+    indices are ``lax.top_k``'s."""
     import jax
     import jax.numpy as jnp
 
-    from flinkml_tpu import kernels, pipeline_fusion
-    from flinkml_tpu.kernels import KernelUnsupportedError
-    from flinkml_tpu.kernels import chain as k_chain
-    from flinkml_tpu.kernels import segsum as k_segsum
+    from flinkml_tpu import kernels
     from flinkml_tpu.kernels import topk as k_topk
-    from flinkml_tpu.table import Table
 
     z = ctx.sizes
     interpret = kernels.interpret_mode()
@@ -499,162 +494,18 @@ def phase_kernels(ctx) -> dict:
                "kernels would run interpreted on the TPU backend "
                f"({kernels.ENV_INTERPRET_VAR} is set?)")
     rng = np.random.default_rng([ctx.seed, 7])
-    ran = "interpreted" if interpret else "compiled"
-    sites = {}
-
-    def run_site(site, reason, explicit, diff_vs_xla):
-        """``explicit()`` asks for the kernel by name (the dispatcher
-        under ``backend="pallas"``, or the kernel's own entry point);
-        ``diff_vs_xla()`` runs the kernel and returns its max abs
-        difference to the XLA lowering."""
-        if reason is None:
-            sites[site] = {"status": ran,
-                           "max_abs_diff_vs_xla": float(diff_vs_xla())}
-            return
-        try:
-            explicit()
-        except KernelUnsupportedError as e:
-            _check(reason in str(e),
-                   f"{site}: refusal does not name its reason: {e}")
-            sites[site] = {"status": "refused", "refusal": reason}
-        else:
-            raise AssertionError(
-                f"{site}: unsupported_reason says {reason!r} but an "
-                "explicit pallas request ran")
-
-    # sparse trainer, one device's step (phase 2): the gradient scatter
-    # into [dim].
-    rows = z["sparse_gbs"] // len(jax.devices())
-    width, dim = 64, z["sparse_dim"]  # 39 nnz pads to the 64-wide ELL
-    ib = jnp.asarray(rng.integers(0, dim, (rows, width)), jnp.int32)
-    vb = jnp.asarray(rng.standard_normal((rows, width), dtype=np.float32))
-    contrib, ids = vb.reshape(-1), ib.reshape(-1)
-
-    def segsum_diff(values, seg_ids, nseg):
-        got = jax.jit(lambda v, i: k_segsum.pallas_segment_sum(
-            v, i, nseg, interpret=interpret))(values, seg_ids)
-        ref = jax.jit(lambda v, i: jax.ops.segment_sum(
-            v, i, num_segments=nseg))(values, seg_ids)
-        return np.max(np.abs(np.asarray(got) - np.asarray(ref)))
-
-    run_site("segment_sum",
-             k_segsum.unsupported_reason(contrib, ids, dim, interpret),
-             lambda: kernels.segment_sum(contrib, ids, dim,
-                                         backend="pallas"),
-             lambda: segsum_diff(contrib, ids, dim))
-    # ... and at the largest [num_segments, 16] row payload the compiled
-    # path admits (the embedding-exchange shape), where it must run.
-    nseg = k_segsum.MAX_COMPILED_CELLS // 128
-    cells = 4 * k_segsum.BLOCK_CELLS + 1_000
-    if ctx.rehearse:
-        nseg, cells = 512, k_segsum.BLOCK_CELLS + 100
-    payload = jnp.asarray(rng.standard_normal((cells, 16), dtype=np.float32))
-    pids = jnp.asarray(rng.integers(0, nseg, cells), jnp.int32)
-    run_site("segment_sum_row_payload",
-             k_segsum.unsupported_reason(payload, pids, nseg, interpret),
-             lambda: kernels.segment_sum(payload, pids, nseg,
-                                         backend="pallas"),
-             lambda: segsum_diff(payload, pids, nseg))
-
     xq = jnp.asarray(rng.standard_normal(z["topk_shape"], dtype=np.float32))
-
-    def topk_diff():
-        pv, pi = jax.jit(lambda q: k_topk.pallas_top_k(
-            q, z["topk_k"], interpret=interpret))(xq)
-        rv, ri = jax.jit(lambda q: jax.lax.top_k(q, z["topk_k"]))(xq)
-        _check(bool(np.array_equal(np.asarray(pi), np.asarray(ri))),
-               "topk indices differ from lax.top_k")
-        return np.max(np.abs(np.asarray(pv) - np.asarray(rv)))
-
-    run_site("topk", k_topk.unsupported_reason(xq, z["topk_k"], interpret),
-             lambda: k_topk.pallas_top_k(xq, z["topk_k"],
-                                         interpret=interpret),
-             topk_diff)
-
-    # fused chain (phases 3-4): the five-stage model at a serving bucket,
-    # through the executor's own gate.
-    model, x = ctx.chain["model"], ctx.chain["x"]
-    batch = Table({"features": x[:1_024]})
-
-    def chain_outputs(backend):
-        os.environ[kernels.ENV_VAR] = f"fused_chain={backend}"
-        try:
-            pipeline_fusion.reset_cache()
-            (out,) = model.transform(batch)
-            return (np.asarray(out.column("prediction")),
-                    np.asarray(out.column("rawPrediction")))
-        finally:
-            del os.environ[kernels.ENV_VAR]
-            pipeline_fusion.reset_cache()
-
-    ks, _ = pipeline_fusion.collect_run(batch, model.stages, 0)
-    with jax.enable_x64(True):
-        # As the executor uploads them: model data keeps its float64.
-        consts64 = tuple(
-            tuple(jnp.asarray(k.constants[c]) for c in sorted(k.constants))
-            for k in ks)
-    names = (("features",), ("prediction", "rawPrediction"))
-
-    def chain_reason(consts):
-        with jax.enable_x64(True):
-            return k_chain.unsupported_reason(
-                ks, *names, 1_024, None, (jnp.asarray(x[:1_024]),), consts,
-                interpret)
-
-    def chain_diff():
-        xp, xr = chain_outputs("xla")
-        pp, pr = chain_outputs("pallas")
-        # Both run float32; predictions may only differ where the XLA
-        # probability is within float32 rounding of 0.5.
-        near = np.abs(xr[:, 1] - 0.5) <= 1e-4
-        _check(bool(np.array_equal(pp[~near], xp[~near])),
-               "fused_chain predictions differ from the XLA chain")
-        return np.max(np.abs(pr.astype(np.float64) - xr))
-
-    run_site("fused_chain", chain_reason(consts64),
-             lambda: chain_outputs("pallas"), chain_diff)
-    # ... and the kernel itself on the same chain with float32 model
-    # constants (the fitted models store float64, which the compiled
-    # path refuses; the stages cast them to float32 anyway).
-    consts32 = tuple(tuple(c.astype(jnp.float32) for c in cv)
-                     for cv in consts64)
-
-    def chain_f32_diff():
-        xs = (jnp.asarray(x[:1_024]),)
-        got = jax.jit(k_chain.pallas_chain_fn(ks, *names, 1_024, None))(
-            xs, consts32, np.int32(1_000))
-        ref = jax.jit(pipeline_fusion._chain_fn(ks, *names, 1_024, None))(
-            xs, consts32, np.int32(1_000))
-        gp, rp = (np.asarray(o["prediction"])[:1_000] for o in (got, ref))
-        gr, rr = (np.asarray(o["rawPrediction"])[:1_000] for o in (got, ref))
-        near = np.abs(rr[:, 1] - 0.5) <= 1e-4
-        _check(bool(np.array_equal(gp[~near], rp[~near])),
-               "fused_chain (f32 constants) predictions differ from XLA")
-        return np.max(np.abs(gr.astype(np.float64) - rr))
-
-    def refused():
-        raise AssertionError("the float32-constant chain must be supported")
-
-    run_site("fused_chain_f32_constants", chain_reason(consts32), refused,
-             chain_f32_diff)
-    for site, rec in sites.items():
-        if rec["status"] != "refused":
-            _check(rec["max_abs_diff_vs_xla"] <= 1e-4,
-                   f"{site}: pallas differs from XLA by "
-                   f"{rec['max_abs_diff_vs_xla']}")
-    # Two counts of the three kernels: compiled at the shape phases 2-4
-    # hand the site (what the product can select), and compiled at some
-    # operands the kernel admits (what Mosaic can build at all).
-    elsewhere = {"fused_chain": "fused_chain_f32_constants",
-                 "topk": "topk", "segment_sum": "segment_sum_row_payload"}
-
-    def compiled(site):
-        return sites[site]["status"] == "compiled"
-
-    return {"interpret": bool(interpret), "sites": sites,
-            "compiled_at_product_shape": sum(map(compiled, elsewhere)),
-            "compiled_at_some_admitted_shape": sum(
-                compiled(s) or compiled(e) for s, e in elsewhere.items())}
+    reason = k_topk.unsupported_reason(xq, z["topk_k"], interpret)
+    _check(reason is None, f"topk refuses the KNN fallback's tile: {reason}")
+    pv, pi = jax.jit(lambda q: k_topk.pallas_top_k(
+        q, z["topk_k"], interpret=interpret))(xq)
+    rv, ri = jax.jit(lambda q: jax.lax.top_k(q, z["topk_k"]))(xq)
+    _check(bool(np.array_equal(np.asarray(pi), np.asarray(ri))),
+           "topk indices differ from lax.top_k")
+    _check(bool(np.array_equal(np.asarray(pv), np.asarray(rv))),
+           "topk values differ from lax.top_k")
+    return {"interpret": bool(interpret),
+            "topk": "interpreted" if interpret else "compiled"}
 
 
 def phase_multichip(ctx) -> dict:

@@ -48,15 +48,15 @@ class GuardViolation(AssertionError):
 
 def _chain_identity(key: Tuple) -> Tuple:
     """A fused program's key minus its row bucket (index 4 of the layout
-    ``(chain fp, ext specs, const specs, out names, bucket, policy,
-    kernel backend)``): the identity under which a compile at a NEW
-    bucket is policy-allowed. The precision policy AND the kernel
-    backend STAY in the identity — flipping either compiles a genuinely
-    different program. What a fused-CACHE key carries after those seven
-    elements (the placement, once a compile-cache store is active: any
-    process that has started a ReplicaPool) is not the program's:
-    ``on_compile`` reports the program key alone."""
-    return key[:4] + key[5:7]
+    ``(chain fp, ext specs, const specs, out names, bucket, policy)``):
+    the identity under which a compile at a NEW bucket is
+    policy-allowed. The precision policy STAYS in the identity —
+    flipping it compiles a genuinely different program. What a
+    fused-CACHE key carries after those six elements (the placement,
+    once a compile-cache store is active: any process that has started a
+    ReplicaPool) is not the program's: ``on_compile`` reports the
+    program key alone."""
+    return key[:4] + key[5:6]
 
 
 def _counters(group: str) -> Dict[str, float]:
@@ -122,7 +122,7 @@ class TransferRetraceGuard:
 
         # Compile policy. Key layout (pipeline_fusion._run_program):
         # (chain fingerprint, ext specs, const specs, out names, bucket,
-        # precision policy, kernel backend).
+        # precision policy).
         counted = 0
         seen_chains = set(self._known_chains)
         # Fingerprint-churn detection: keyed by everything EXCEPT the
@@ -133,12 +133,11 @@ class TransferRetraceGuard:
         # alternative chains (budgeted via allow_compiles) unflagged.
         by_shape: Dict[Tuple, set] = {}
         for key in self._compiled_keys:
-            chain_fp, ext_specs, consts, outs, bucket, policy, backend = key
+            chain_fp, ext_specs, consts, outs, bucket, policy = key
             by_shape.setdefault(
-                (ext_specs, consts, outs, bucket, policy, backend), set()
+                (ext_specs, consts, outs, bucket, policy), set()
             ).add(chain_fp)
-        for (_ext, _consts, _outs, bucket, _pol, _be), fps in \
-                by_shape.items():
+        for (_ext, _consts, _outs, bucket, _pol), fps in by_shape.items():
             if len(fps) >= 3:
                 findings.append(Finding(
                     "FML403",

@@ -22,7 +22,6 @@ tests); committed numbers should come from a full run
 from __future__ import annotations
 
 import contextlib
-import functools
 import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -47,8 +46,6 @@ STATIC_DEFAULTS: Dict[str, Any] = {
     "infer_plan_order": ["batch_parallel", "fsdp", "fsdp_tp"],
     "serving_max_batch_rows": 1024,
     "serving_window_ms": 2.0,
-    "kernel_backend_fused_chain": "xla",
-    "kernel_backend_segment_sum": "xla",
     "embedding_exchange": "ring",
     "serving_scale_up_backlog": 0.5,
     "int8_min_const_elems": 16,
@@ -491,84 +488,6 @@ def measure_int8_min_const_elems(quick: bool = False) -> Dict[str, float]:
     return out
 
 
-# -- the kernel-backend family (flinkml_tpu.kernels) -------------------------
-#
-# Each site's A/B is driven through the FLINKML_TPU_KERNELS env gate so
-# the search measures exactly the code path a user selecting that
-# backend would run (the layout-knob discipline above). On a CPU mesh
-# the Pallas candidate runs under the interpreter — expect XLA to keep
-# winning there (the committed candidates make that auditable); the
-# device re-tune is the measurement that can flip a default.
-
-
-def measure_kernel_backend_fused_chain(quick: bool = False
-                                       ) -> Dict[str, float]:
-    """Fused 5-stage chain transform rows/s per chain backend (the
-    product ``PipelineModel.transform`` path, both backends through the
-    real fused-executor gate + cache)."""
-    from flinkml_tpu import pipeline_fusion
-    from flinkml_tpu.table import Table
-
-    model, x = _serving_model()
-    rows = min(1_024 if quick else 4_096, x.shape[0])
-    reps = 3 if quick else 10
-    batch = Table({"features": x[:rows], "label": np.zeros(rows)})
-    out: Dict[str, float] = {}
-    for backend in ("xla", "pallas"):
-        with _env("FLINKML_TPU_KERNELS", f"fused_chain={backend}"):
-            pipeline_fusion.reset_cache()
-            (warm,) = model.transform(batch)
-            read = [c for c in warm.column_names
-                    if c not in ("features", "label")]
-            for c in read:
-                warm.column(c)
-
-            def rate() -> float:
-                t0 = time.perf_counter()
-                for _ in range(reps):
-                    (o,) = model.transform(batch)
-                    for c in read:
-                        o.column(c)
-                return rows * reps / (time.perf_counter() - t0)
-
-            out[backend] = _timed_rate(rate)
-    pipeline_fusion.reset_cache()
-    return out
-
-
-def measure_kernel_backend_segment_sum(quick: bool = False
-                                       ) -> Dict[str, float]:
-    """Gradient-scatter cells/s per segment-sum backend at the sparse
-    trainer's per-step shape (flat padded-ELL contributions into a
-    dense [dim] gradient)."""
-    import jax
-    import jax.numpy as jnp
-
-    from flinkml_tpu import kernels
-
-    cells, dim = (1 << 13, 1 << 14) if quick else (1 << 15, 1 << 16)
-    reps = 5 if quick else 20
-    rng = np.random.default_rng(0)
-    ids = jnp.asarray(rng.integers(0, dim, cells), jnp.int32)
-    vals = jnp.asarray(rng.normal(size=cells).astype(np.float32))
-    out: Dict[str, float] = {}
-    for backend in ("xla", "pallas"):
-        fn = jax.jit(functools.partial(
-            kernels.segment_sum, num_segments=dim, backend=backend,
-        ))
-        np.asarray(fn(vals, ids))  # compile + warmup
-
-        def rate() -> float:
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                r = fn(vals, ids)
-            np.asarray(r)
-            return cells * reps / (time.perf_counter() - t0)
-
-        out[backend] = _timed_rate(rate)
-    return out
-
-
 def measure_embedding_exchange(quick: bool = False) -> Dict[str, float]:
     """Lookup+update rows/s per embedding-exchange candidate on a
     mid-size sharded table (one scatter-exchange + one lookup per
@@ -657,8 +576,6 @@ MEASURERS: Dict[str, Callable[[bool], Dict[str, float]]] = {
     "infer_plan_order": measure_infer_plan_order,
     "serving_max_batch_rows": measure_serving_max_batch_rows,
     "serving_window_ms": measure_serving_window_ms,
-    "kernel_backend_fused_chain": measure_kernel_backend_fused_chain,
-    "kernel_backend_segment_sum": measure_kernel_backend_segment_sum,
     "embedding_exchange": measure_embedding_exchange,
     "serving_scale_up_backlog": measure_serving_scale_up_backlog,
     "int8_min_const_elems": measure_int8_min_const_elems,

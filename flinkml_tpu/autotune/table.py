@@ -66,12 +66,6 @@ KNOWN_KNOBS: Dict[str, str] = {
     "infer_plan_order": "samples_per_sec",
     "serving_max_batch_rows": "rows_per_sec",
     "serving_window_ms": "rows_per_sec",
-    # The kernel-backend family (flinkml_tpu.kernels): xla vs pallas
-    # per gated site. Committed CPU entries measure the INTERPRETER
-    # (auditable, not competitive); a device re-tune is what can flip
-    # these (docs/development/kernels.md).
-    "kernel_backend_fused_chain": "rows_per_sec",
-    "kernel_backend_segment_sum": "cells_per_sec",
     # The sharded-embedding exchange (flinkml_tpu.embeddings): ring vs
     # all_to_all row routing, with dense_psum (replicated table, dense
     # gradient psum) as the below-threshold candidate — the knob that

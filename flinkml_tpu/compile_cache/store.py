@@ -7,10 +7,9 @@ An artifact is addressed by TWO fingerprints:
 
 1. The **program key** — whatever hashable identity the compile site
    already uses for its in-memory cache (the fused executor's ``(chain
-   fingerprint, ext specs, const specs, outputs, bucket, policy,
-   kernel backend)`` tuple; the plan step's ``(mesh topology, plan,
-   hypers, policy, shapes)``), rendered canonically by
-   :func:`stable_key_repr` and
+   fingerprint, ext specs, const specs, outputs, bucket, policy)``
+   tuple; the plan step's ``(mesh topology, plan, hypers, policy,
+   shapes)``), rendered canonically by :func:`stable_key_repr` and
    hashed. The keys were built hashable and collision-tested for the
    in-memory caches; this module only adds persistence.
 2. The **environment fingerprint** — jax/jaxlib version, backend

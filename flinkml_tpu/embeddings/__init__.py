@@ -17,9 +17,8 @@ spec (tables sharded ``PS((fsdp, tp), None)``):
 - :mod:`~flinkml_tpu.embeddings.exchange` — the device-side sparse
   lookup (masked gather on the owning shard) and gradient exchange
   (batch-sized row payloads over ``ppermute`` rings or one
-  ``all_to_all``, the scatter riding the PR 12 padded-ELL
-  ``segment_sum`` kernel gate) — never a vocab-sized dense psum, never
-  a host gather. Strategy is the ``embedding_exchange`` autotune knob;
+  ``all_to_all``, the scatter one ``segment_sum`` a table) — never a
+  vocab-sized dense psum, never a host gather. Strategy is the ``embedding_exchange`` autotune knob;
   the ``dense_psum`` placement below the vocab threshold subsumes
   W2V's old static ``_shard_vocab_threshold``.
 - :mod:`~flinkml_tpu.embeddings.serving` — a mesh-bindable lookup model

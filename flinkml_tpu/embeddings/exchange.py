@@ -29,10 +29,9 @@ Three strategies (the ``embedding_exchange`` autotune knob family):
 - ``all_to_all`` — ids ``all_gather`` to every shard (cheap ints), each
   shard produces its masked contribution for the full global id list,
   and ONE ``all_to_all`` routes contributions home (gather) or the
-  gathered rows scatter into the local shard via the PR 12 padded-ELL
-  ``segment_sum`` kernel gate (scatter). Same total traffic as the
-  ring, 2 collectives instead of 2·P hops — the latency bet the device
-  re-tune decides.
+  gathered rows ``segment_sum`` into the local shard (scatter). Same
+  total traffic as the ring, 2 collectives instead of 2·P hops — the
+  latency bet the device re-tune decides.
 - ``dense_psum`` — not an exchange at all: the below-threshold
   placement where the table stays replicated and gradients ride one
   dense ``[vocab, dim]`` psum per step (the classic W2V dense trainer).
@@ -288,15 +287,11 @@ def a2a_gather(pairs: Sequence, *, axes: Axes, n_shards: int,
 
 
 def a2a_scatter_add(tables: Sequence, triples: Sequence, *, axes: Axes,
-                    n_shards: int, shard_rows: int,
-                    segsum_backend: str = "xla"):
+                    n_shards: int, shard_rows: int):
     """The ``all_to_all``-family scatter: every shard ``all_gather``s the
     (ids, rows) payloads and segment-sums the rows IT owns into its
-    shard — the scatter rides the PR 12 padded-ELL ``segment_sum``
-    kernel gate (``segsum_backend`` is lru-key material at every
-    consumer, so a kernel-gate flip re-keys the jitted trainer). Masked
-    (non-owned) rows segment-sum as zeros into local row 0 — the ELL
-    no-op-add convention.
+    shard. Masked (non-owned) rows segment-sum as zeros into local row
+    0 — the ELL no-op-add convention.
 
     All payloads ride ONE id ``all_gather`` + ONE row ``all_gather``
     (equal-dim payloads concatenate; mixed dims fall back to a round
@@ -306,15 +301,12 @@ def a2a_scatter_add(tables: Sequence, triples: Sequence, *, axes: Axes,
     import jax
     import jax.numpy as jnp
 
-    from flinkml_tpu import kernels
-
     dims = sorted({int(rows.shape[-1]) for _, _, rows in triples})
     if len(dims) > 1:
         for triple in triples:
             tables = a2a_scatter_add(
                 tables, (triple,), axes=axes, n_shards=n_shards,
-                shard_rows=shard_rows, segsum_backend=segsum_backend,
-            )
+                shard_rows=shard_rows)
         return tuple(tables)
     dim = dims[0]
     tables = list(tables)
@@ -335,10 +327,10 @@ def a2a_scatter_add(tables: Sequence, triples: Sequence, *, axes: Axes,
         seg_ids = per_src_ids[:, offset:offset + m].reshape(-1)
         seg_rows = per_src_rows[:, offset:offset + m].reshape(-1, dim)
         mask, safe = owned(seg_ids, axes, shard_rows)
-        tables[slot] = tables[slot] + kernels.segment_sum(
+        tables[slot] = tables[slot] + jax.ops.segment_sum(
             jnp.where(mask[:, None], seg_rows, 0.0),
             jnp.where(mask, safe, 0),
-            shard_rows, backend=segsum_backend,
+            num_segments=shard_rows,
         )
         offset += m
     return tuple(tables)
@@ -360,16 +352,14 @@ def gather(pairs: Sequence, *, axes: Axes, n_shards: int, shard_rows: int,
 
 
 def scatter_add(tables: Sequence, triples: Sequence, *, axes: Axes,
-                n_shards: int, shard_rows: int, strategy: str = "ring",
-                segsum_backend: str = "xla"):
+                n_shards: int, shard_rows: int, strategy: str = "ring"):
     """Strategy-dispatched sparse gradient exchange (module docstring)."""
     if strategy == "ring":
         return ring_scatter_add(tables, triples, axes=axes,
                                 n_shards=n_shards, shard_rows=shard_rows)
     if strategy == "all_to_all":
         return a2a_scatter_add(tables, triples, axes=axes,
-                               n_shards=n_shards, shard_rows=shard_rows,
-                               segsum_backend=segsum_backend)
+                               n_shards=n_shards, shard_rows=shard_rows)
     raise ValueError(
         f"unknown sharded exchange strategy {strategy!r} (dense_psum is a "
         f"placement, not an exchange; expected 'ring' or 'all_to_all')"

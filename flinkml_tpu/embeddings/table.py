@@ -94,7 +94,7 @@ def _lookup_program(mesh, row_entry, n_shards: int, shard_rows: int):
 
 @functools.lru_cache(maxsize=64)
 def _scatter_program(mesh, row_entry, n_shards: int, shard_rows: int,
-                     strategy: str, segsum_backend: str):
+                     strategy: str):
     """Jitted sharded scatter-add: the global delta batch arrives split
     over the row axes (each shard routes ITS slice of the batch), so
     per-step traffic is batch-sized regardless of vocab."""
@@ -108,8 +108,7 @@ def _scatter_program(mesh, row_entry, n_shards: int, shard_rows: int,
         (out,) = exchange.scatter_add(
             (table_shard,), ((0, ids, delta),),
             axes=axes_arg, n_shards=n_shards, shard_rows=shard_rows,
-            strategy=strategy, segsum_backend=segsum_backend,
-        )
+            strategy=strategy)
         return out
 
     return jax.jit(jax.shard_map(
@@ -309,8 +308,6 @@ class EmbeddingTable:
             strategy = exchange.resolve_exchange(self.vocab, self.n_shards)
             if strategy == "dense_psum":  # sharded table: exchange anyway
                 strategy = exchange.exchange_strategy()
-        from flinkml_tpu import kernels
-
         pad = (-ids.shape[0]) % self.n_shards
         if pad:
             ids = np.concatenate([ids, np.zeros(pad, np.int32)])
@@ -319,7 +316,7 @@ class EmbeddingTable:
             )
         program = _scatter_program(
             self.mesh.mesh, self.row_entry, self.n_shards, self.shard_rows,
-            strategy, kernels.segsum_backend(),
+            strategy,
         )
         from jax.sharding import NamedSharding, PartitionSpec as P
         import jax

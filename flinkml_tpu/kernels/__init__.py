@@ -1,6 +1,6 @@
 """Hand-written Pallas (Mosaic) kernels.
 
-**Eight kernels run, ungated.** Each is chosen where it applies by an
+**Nine kernels.** Each is chosen where it applies by an
 ``unsupported_reason`` its caller reads (the backend, the dtype, the
 shapes: no environment variable, no knob), and every other backend runs
 the XLA lowering of the same result:
@@ -27,6 +27,11 @@ the XLA lowering of the same result:
   once by DMA, many in flight (``models._w2v_table._program``: a TPU,
   float32 tables in whole groups of rows and whole rows of lanes, one
   device; ``w2v-1bw.fit``);
+- :mod:`~flinkml_tpu.kernels.row_fetch` — a table's rows fetched where
+  most ids name a few hot rows: those read out of fast memory a slot at
+  a time, XLA's gather over the cold slots alone
+  (``models._als_blocked``: a TPU, float32 rows of 128 lanes, hot rows
+  that cover enough of the slots; ``als-yahoomusic.fit``);
 - :mod:`~flinkml_tpu.kernels.gbt_hist` — a tree level's (node, feature,
   bin) sums of gradients and hessians as one-hot products, a feature's
   one-hot never outside fast memory, the bin's low bit folded into the
@@ -41,59 +46,24 @@ the XLA lowering of the same result:
   (``models.knn._tile_top_k``).
 
 :mod:`~flinkml_tpu.kernels._split` holds the two ways they make a
-float32 from bfloat16 parts. They share ``_gate``'s helpers
-(``interpret_mode``, ``out_struct``, ``import_beside_host_work``).
-
-**The gate is what is left of PR 12**: ``FLINKML_TPU_KERNELS`` (and the
-autotune table's ``kernel_backend_<site>`` knobs) choosing between XLA
-and Pallas for two sites that no benchmark cell runs and that default to
-XLA (:mod:`flinkml_tpu.kernels._gate`): ``fused_chain``
-(:mod:`~flinkml_tpu.kernels.chain`: the fused transform chain as one
-row-tiled kernel; refuses the float64 constants a fitted chain carries)
-and ``segment_sum`` (:mod:`~flinkml_tpu.kernels.segsum`: the padded-ELL
-scatter-accumulate; refuses the sparse trainers' ``[1e6, 1]`` output).
-The resolved backend joins the fused executor's program and AOT cache
-identity, the trainer factories' lru keys and jit static args; an
-explicit request for Pallas on unsupported operands raises
-:class:`KernelUnsupportedError`, a table-chosen one warns once and runs
-XLA. ROADMAP D3 has what deleting it takes.
+float32 from bfloat16 parts, :mod:`~flinkml_tpu.kernels._mosaic` what
+they share (``interpret_mode``, ``out_struct``,
+``import_beside_host_work``, :class:`KernelUnsupportedError`).
 
 See ``docs/development/kernels.md`` for the supported-shape tables and
 the equivalence-test recipe.
 """
 
-from flinkml_tpu.kernels._gate import (  # noqa: F401
-    BACKENDS,
+from flinkml_tpu.kernels._mosaic import (  # noqa: F401
     ENV_INTERPRET_VAR,
-    ENV_VAR,
-    KNOB_PREFIX,
-    SITES,
     KernelUnsupportedError,
-    backend_for,
     interpret_mode,
-    resolve_backend,
-)
-from flinkml_tpu.kernels.segsum import (  # noqa: F401
-    pallas_segment_sum,
-    segment_sum,
-)
-from flinkml_tpu.kernels.segsum import (  # noqa: F401
-    factory_backend as segsum_backend,
 )
 from flinkml_tpu.kernels.topk import pallas_top_k  # noqa: F401
 
 __all__ = [
-    "BACKENDS",
     "ENV_INTERPRET_VAR",
-    "ENV_VAR",
-    "KNOB_PREFIX",
-    "SITES",
     "KernelUnsupportedError",
-    "backend_for",
     "interpret_mode",
-    "resolve_backend",
-    "pallas_segment_sum",
-    "segment_sum",
-    "segsum_backend",
     "pallas_top_k",
 ]

@@ -121,9 +121,9 @@ def unsupported_reason(dtype, n_local: int, local_bs: int,
     the backend and what the step is handed, nothing else."""
     import jax.numpy as jnp
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
-    if _gate.interpret_mode():
+    if _mosaic.interpret_mode():
         return "not a TPU: Mosaic's kernel would run interpreted"
     if jnp.dtype(dtype) != jnp.float32:
         return f"features {jnp.dtype(dtype).name}: the sums are float32's"
@@ -239,10 +239,10 @@ def margin_grad(loss: str, xl, yl, wl, coef, start, local_bs: int, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     n_local, dim = xl.shape
     tile, padded = tile_rows(local_bs, dim), _padded(dim)
     with jax.enable_x64(False):
@@ -273,9 +273,9 @@ def margin_grad(loss: str, xl, yl, wl, coef, start, local_bs: int, *,
                 out_specs=[whole(STREAMED, padded), whole(1, LANES),
                            whole(1, LANES)]),
             out_shape=[
-                _gate.out_struct((STREAMED, padded), jnp.float32, *operands),
-                _gate.out_struct((1, LANES), jnp.float32, *operands),
-                _gate.out_struct((1, LANES), jnp.float32, *operands)],
+                _mosaic.out_struct((STREAMED, padded), jnp.float32, *operands),
+                _mosaic.out_struct((1, LANES), jnp.float32, *operands),
+                _mosaic.out_struct((1, LANES), jnp.float32, *operands)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),

@@ -239,10 +239,10 @@ def level_sums(bins, g, h, node, nodes: int, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     features, rows = bins.shape
     tile = tile or tile_rows(rows)
     sums = (features, one_hot_rows(nodes), columns(nodes))
@@ -254,7 +254,7 @@ def level_sums(bins, g, h, node, nodes: int, *,
             in_specs=[pl.BlockSpec((features, tile), lambda t: (0, t)),
                       row_of_lanes, row_of_lanes, row_of_lanes],
             out_specs=pl.BlockSpec(sums, lambda t: (0, 0, 0)),
-            out_shape=_gate.out_struct(sums, jnp.float32, bins, g, h, node),
+            out_shape=_mosaic.out_struct(sums, jnp.float32, bins, g, h, node),
             scratch_shapes=[pltpu.VMEM((features, tile), jnp.int32),
                             pltpu.VMEM(sums, jnp.float32)],
             compiler_params=pltpu.CompilerParams(

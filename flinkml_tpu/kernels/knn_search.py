@@ -349,10 +349,10 @@ def fused_nearest(queries, train_x, train_sq, k: int, *, precision,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     n_queries, dim = queries.shape
     n_train = train_x.shape[0]
     bq = query_block_rows(n_queries, query_block)
@@ -396,8 +396,8 @@ def fused_nearest(queries, train_x, train_sq, k: int, *, precision,
                 pl.BlockSpec((per_chunk * bq, LANES), whole),
             ),
             out_shape=(
-                _gate.out_struct((per_chunk * bq, LANES), jnp.float32, q),
-                _gate.out_struct((per_chunk * bq, LANES), jnp.int32, q),
+                _mosaic.out_struct((per_chunk * bq, LANES), jnp.float32, q),
+                _mosaic.out_struct((per_chunk * bq, LANES), jnp.int32, q),
             ),
             scratch_shapes=[pltpu.VMEM(parts_shape, jnp.bfloat16),
                             pltpu.VMEM((bq, bt), jnp.float32),

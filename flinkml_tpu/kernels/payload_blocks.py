@@ -520,10 +520,10 @@ def _call(body, grid, prefetch, operands, in_specs, out_specs, out_shape,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     return pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -635,7 +635,7 @@ def lookup(lengths: Sequence[int], where: Sequence[int], table, cells, vals,
     import jax
     import jax.numpy as jnp
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     payload = table.shape[0]
     width, batch = cells.shape
@@ -643,7 +643,7 @@ def lookup(lengths: Sequence[int], where: Sequence[int], table, cells, vals,
     xps, sums, squares = [], 0.0, 0.0
 
     def out_shapes(slots, *operands):
-        return [_gate.out_struct(shape, jnp.float32, cells, vals, starts, *operands)
+        return [_mosaic.out_struct(shape, jnp.float32, cells, vals, starts, *operands)
                 for shape in ((slots, payload, batch), (payload, batch),
                               (SUBLANES, batch))]
 
@@ -701,7 +701,7 @@ def accumulate(lengths: Sequence[int], where: Sequence[int], cells, vals,
     import jax.numpy as jnp
     from jax.experimental.pallas import tpu as pltpu
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     payload, batch = base.shape
     width = cells.shape[0]
@@ -722,7 +722,7 @@ def accumulate(lengths: Sequence[int], where: Sequence[int], cells, vals,
                 + [_tiles((1,), tile, False), _tiles((payload,), tile, False),
                    _tiles((len(short), payload), tile, False)],
                 _whole((len(short), columns, 3 * padded)),
-                _gate.out_struct((len(short), columns, 3 * padded), jnp.float32,
+                _mosaic.out_struct((len(short), columns, 3 * padded), jnp.float32,
                                  cells, vals, starts, mult, base, xp),
                 [pltpu.VMEM((3 * padded, tile), jnp.float32)],
                 interpret, "flinkml.fm.accumulate.short")
@@ -746,7 +746,7 @@ def accumulate(lengths: Sequence[int], where: Sequence[int], cells, vals,
                                            _tiles((payload,), tile),
                                            _slot_tiles(payload, tile)],
                 _of_slot(shape),
-                _gate.out_struct((len(long),) + shape, jnp.float32,
+                _mosaic.out_struct((len(long),) + shape, jnp.float32,
                                  cells, vals, starts, mult, base, xp),
                 _long_scratch(payload, tile, False),
                 interpret, "flinkml.fm.accumulate.long"), payload)

@@ -96,9 +96,9 @@ def unsupported_reason(dtype, lanes: int, hot_share: float) -> Optional[str]:
     hot rows cover, nothing else."""
     import jax.numpy as jnp
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
-    if _gate.interpret_mode():
+    if _mosaic.interpret_mode():
         return "not a TPU: Mosaic's kernel would run interpreted"
     if jnp.dtype(dtype) != jnp.float32:
         return f"a {jnp.dtype(dtype).name} table: a row is float32's sublane"
@@ -259,10 +259,10 @@ def fetch(loc, starts, hot, cold, *, cap: int, tile: int = TILE,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     (n,), (hot_n, lanes) = loc.shape, hot.shape
     tiles = tiles_of(n, tile)
     if unroll is None:
@@ -289,7 +289,7 @@ def fetch(loc, starts, hot, cold, *, cap: int, tile: int = TILE,
                     pltpu.VMEM((hot_n + 2 * tile, lanes), jnp.float32),
                     pltpu.SemaphoreType.DMA(()),
                     pltpu.SemaphoreType.DMA((2,))]),
-            out_shape=_gate.out_struct((n, lanes), hot.dtype, loc, hot, cold),
+            out_shape=_mosaic.out_struct((n, lanes), hot.dtype, loc, hot, cold),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),

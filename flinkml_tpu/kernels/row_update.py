@@ -67,9 +67,9 @@ def unsupported_reason(dtype, rows: int, lanes: int,
     the backend and what the update is handed, nothing else."""
     import jax.numpy as jnp
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
-    if _gate.interpret_mode():
+    if _mosaic.interpret_mode():
         return "not a TPU: Mosaic's kernel would run interpreted"
     if devices != 1:
         return (f"{devices} devices: the table is row-sharded and its "
@@ -218,10 +218,10 @@ def add_rows_sorted(table, ids_sorted, rows_sorted, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     entries, lanes = rows_sorted.shape
     if tile is None:
         tile = TILE if entries > TILE // 2 else TILE // 2
@@ -239,7 +239,7 @@ def add_rows_sorted(table, ids_sorted, rows_sorted, *,
                 pl.BlockSpec((tile, lanes), lambda t: (t, 0)),
                 pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            out_shape=_gate.out_struct(table.shape, table.dtype, table),
+            out_shape=_mosaic.out_struct(table.shape, table.dtype, table),
             scratch_shapes=[
                 pltpu.VMEM((RING * CHUNK * GROUP, lanes), jnp.float32),
                 pltpu.SemaphoreType.DMA((RING,)),

@@ -322,10 +322,10 @@ def _call(body, groups, tile: int, where, starts, operands, in_specs,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     return pl.pallas_call(
         functools.partial(body, groups=groups),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -368,7 +368,7 @@ def lookup_dot(groups: Sequence[Tuple[int, int]], where: Sequence[int], blocks,
     import jax
     import jax.numpy as jnp
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     groups = walk(groups)
     width, batch = cells.shape
@@ -381,7 +381,7 @@ def lookup_dot(groups: Sequence[Tuple[int, int]], where: Sequence[int], blocks,
             _lookup_body, groups, tile, where, starts, [cells, vals] + operands,
             [_tiles(width, tile)] * 2 + [_whole(o.shape) for o in operands],
             _tiles(1, tile),
-            _gate.out_struct((1, batch), jnp.float32, cells, vals, starts,
+            _mosaic.out_struct((1, batch), jnp.float32, cells, vals, starts,
                              *operands),
             "parallel", interpret)
     return out[0]
@@ -399,7 +399,7 @@ def accumulate(groups: Sequence[Tuple[int, int]], where: Sequence[int], cells,
     import jax
     import jax.numpy as jnp
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     groups = walk(groups)
     width, batch = cells.shape
@@ -411,7 +411,7 @@ def accumulate(groups: Sequence[Tuple[int, int]], where: Sequence[int], cells,
             [cells, vals, mult[None, :]],
             [_tiles(width, tile)] * 2 + [_tiles(1, tile)],
             [_whole(s) for s in shapes],
-            [_gate.out_struct(s, jnp.float32, cells, vals, starts, mult)
+            [_mosaic.out_struct(s, jnp.float32, cells, vals, starts, mult)
              for s in shapes],
             "arbitrary", interpret)
     out = []

@@ -103,10 +103,10 @@ def solve_lanes(aug, k: int, interpret: Optional[bool] = None):
     positive definite."""
     import jax
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     rows, width, batch = aug.shape
     if rows != k or width != augmented_width(k) or batch % LANES:
         raise ValueError(

@@ -12,11 +12,11 @@ are bit-identical to ``lax.top_k`` (both break ties toward the lower
 index).
 
 The KNN search's tiled path calls :func:`pallas_top_k` for the tiles of
-its distance matrix (``models.knn._tile_top_k``), behind no gate: on a
+its distance matrix (``models.knn._tile_top_k``): on a
 v5e it is that search's fastest exact top-k (a call of 10,000 queries
 against 2,025,000 rows: 1.589 s with it, 1.791 with ``lax.top_k`` over
 the same tiles; PERF.md §5, PR 30). Operands it cannot rank are refused
-by name (:class:`~flinkml_tpu.kernels._gate.KernelUnsupportedError`).
+by name (:class:`~flinkml_tpu.kernels._mosaic.KernelUnsupportedError`).
 """
 
 from __future__ import annotations
@@ -93,13 +93,13 @@ def pallas_top_k(x, k: int, *, interpret: Optional[bool] = None) -> Tuple:
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     if interpret is None:
-        interpret = _gate.interpret_mode()
+        interpret = _mosaic.interpret_mode()
     reason = unsupported_reason(x, k, interpret)
     if reason is not None:
-        raise _gate.KernelUnsupportedError(
+        raise _mosaic.KernelUnsupportedError(
             f"kernels.topk: the kernel cannot rank these operands: {reason}")
     squeeze = x.ndim == 1
     x2 = x[None, :] if squeeze else x
@@ -126,8 +126,8 @@ def pallas_top_k(x, k: int, *, interpret: Optional[bool] = None) -> Tuple:
                 pl.BlockSpec((ROW_TILE, k), lambda i: (i, 0)),
             ),
             out_shape=(
-                _gate.out_struct((x2.shape[0], k), x2.dtype, x2),
-                _gate.out_struct((x2.shape[0], k), jnp.int32, x2),
+                _mosaic.out_struct((x2.shape[0], k), x2.dtype, x2),
+                _mosaic.out_struct((x2.shape[0], k), jnp.int32, x2),
             ),
             interpret=interpret,
         )(x2)

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from flinkml_tpu.kernels import _gate, row_fetch, spd_solve
+from flinkml_tpu.kernels import _mosaic, row_fetch, spd_solve
 from flinkml_tpu.parallel import DeviceMesh
 from flinkml_tpu.parallel.mesh import _GATHER_THREADS, gather_pool
 from flinkml_tpu.table import _free_bytes
@@ -657,7 +657,7 @@ def fit_table(est, table, precision=GRAM_PRECISION):
         lambda make_room: place(*(np.asarray(table.column(c)) for c in cols),
                                 mesh, make_room))
     rank, max_iter = est.get(est.RANK), est.get(est.MAX_ITER)
-    on_lanes = not _gate.interpret_mode()
+    on_lanes = not _mosaic.interpret_mode()
     with jax.enable_x64(False):
         with span("als.init"):
             start = start_factors(est.get_seed(), placed.item_ids.size, rank)

@@ -73,7 +73,7 @@ import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
-from flinkml_tpu.kernels import _gate
+from flinkml_tpu.kernels import _mosaic
 from flinkml_tpu.models._adam import adam_update
 from flinkml_tpu.models._data import check_binary_labels, sparse_fit_columns
 from flinkml_tpu.models._linear_sgd import _placed, _slot_major, _window
@@ -182,7 +182,7 @@ def _walk_in_fast_memory(dtype, local_bs: int, slot_plan: Tuple, payload: int,
     nothing sets it."""
     from flinkml_tpu.kernels import payload_blocks
 
-    return (precision == LOOKUP_PRECISION and not _gate.interpret_mode()
+    return (precision == LOOKUP_PRECISION and not _mosaic.interpret_mode()
             and payload_blocks.unsupported_reason(
                 dtype, local_bs, _walk(slot_plan)[0], payload,
                 len(slot_plan)) is None)
@@ -438,7 +438,7 @@ def fit_csr(est, table, logistic: bool, precision=LOOKUP_PRECISION):
     seed = est.get_seed()
     # The step may hold the payload kernels (a TPU's): what tracing them
     # imports loads beside the plan's pass and the permutation.
-    _gate.import_beside_host_work()
+    _mosaic.import_beside_host_work()
     placed = table.device_resident(
         ("fm_rows_on_mesh", features_col, label_col, weight_col,
          mesh.mesh, "float32", seed),

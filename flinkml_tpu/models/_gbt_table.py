@@ -428,13 +428,13 @@ def fit_table(est, table, *, held: Optional[np.ndarray] = None,
     holds all of it. ``held`` are the rows (ascending) of the estimator's
     holdout, which the fit leaves out; ``one_part`` is the benchmark's
     control's alone."""
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     features_col, label_col = est.get(est.FEATURES_COL), est.get(est.LABEL_COL)
     weight_col = est.get(est.WEIGHT_COL)
     mesh = est.mesh or DeviceMesh()
     p = mesh.axis_size()
-    _gate.import_beside_host_work()
+    _mosaic.import_beside_host_work()
     max_bins, depth, seed = est.get(est.MAX_BINS), est.get(est.MAX_DEPTH), est.get_seed()
     x = features_matrix(table, features_col, dtype=None)
     if x.ndim != 2:

@@ -59,17 +59,6 @@ from flinkml_tpu.utils.profiling import named_program, phase, span
 _LOSS_KEYS = ("logistic", "hinge", "squared")
 
 
-def _segsum_backend() -> str:
-    """The kernel-backend gate for the gradient scatter-accumulate
-    (:mod:`flinkml_tpu.kernels`, site ``segment_sum``): env var >
-    autotune table > ``"xla"``. Resolved at FIT time and threaded
-    through the trainer factories' lru keys, so flipping the gate
-    re-keys the jitted trainer."""
-    from flinkml_tpu import kernels
-
-    return kernels.segsum_backend()
-
-
 def _soft_threshold(x, t):
     return jnp.sign(x) * jnp.maximum(jnp.abs(x) - t, 0.0)
 
@@ -313,17 +302,16 @@ def _blocks_in_fast_memory(dtype, local_bs: int, slot_plan: Tuple) -> bool:
     TPU (elsewhere Mosaic's kernels would run interpreted), float32
     coefficients, a device's batch in whole tiles, blocks that fast
     memory holds. Read off what the fit is handed; nothing sets it."""
-    from flinkml_tpu.kernels import _gate, sparse_blocks
+    from flinkml_tpu.kernels import _mosaic, sparse_blocks
 
     groups = [(length, len(slots))
               for length, slots in block_groups(slot_plan, local_bs)]
-    return (bool(groups) and not _gate.interpret_mode()
+    return (bool(groups) and not _mosaic.interpret_mode()
             and sparse_blocks.unsupported_reason(dtype, local_bs, groups) is None)
 
 
 def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
                               axis: str, dim: int,
-                              segsum_backend: str = "xla",
                               slot_plan: Tuple = ()):
     """nnz-bucketed sparse (padded-ELL) step: gather forward, one fused
     segment-sum gradient over every bucket's cells; under a plan, the
@@ -334,10 +322,6 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
     proportionally to its row count, so every step sees a representative
     nnz mix and every epoch covers every bucket's rows. The data args
     are four sharded arrays a bucket (indices, values, y, w).
-    ``segsum_backend`` selects the scatter-accumulate lowering (XLA or
-    the Pallas kernel, :mod:`flinkml_tpu.kernels`), resolved ONCE at fit
-    time and threaded through the trainer factory's lru key so a gate
-    flip re-keys the jitted step.
 
     ``slot_plan`` is what :func:`prepare_sparse_buckets` read off a
     one-width table's cells (``ops.sparse.slot_block_plan``: a block
@@ -359,7 +343,6 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
     one call each side of ``_margin_grad``: the same looked-up floats,
     the gradient's float32 sums in the kernel's fixed order. The row
     gather before and the row scatter-add after are the same."""
-    from flinkml_tpu import kernels
     from flinkml_tpu.kernels import sparse_blocks
 
     if any(slot_plan) and len(local_bss) != 1:
@@ -428,9 +411,9 @@ def make_sparse_step_bucketed(loss: str, local_bss: Tuple[int, ...],
             loss_l = loss_l + jnp.sum(per_ex.astype(acc))
             wsum_l = wsum_l + jnp.sum(wb.astype(acc))
         with phase("lr.sparse_accumulate"):
-            grad_local = kernels.segment_sum(
+            grad_local = jax.ops.segment_sum(
                 jnp.concatenate(contribs), jnp.concatenate(flat_idx),
-                dim, backend=segsum_backend,
+                num_segments=dim,
             )
             if block_grads:
                 tiled = _lane_rows(grad_local)
@@ -508,20 +491,17 @@ def _dense_trainer(mesh, loss: str, local_bs: int, axis: str):
 @functools.lru_cache(maxsize=128)
 def _sparse_trainer_bucketed(mesh, loss: str, local_bss: Tuple[int, ...],
                              axis: str, dim: int,
-                             segsum_backend: str = "xla",
                              slot_plan: Tuple = ()):
     """The bucketed sparse whole-loop trainer (:func:`_whole_loop`) over
-    four sharded arrays a bucket. ``segsum_backend`` is lru-key
-    material: an XLA-kernel trainer and a Pallas-kernel trainer never
-    alias one jitted program. So is ``slot_plan`` (:func:`make_sparse_
-    step_bucketed`): static, a block length or None per slot, up a short
-    ladder so that a configuration's tables share one; where the blocks
+    four sharded arrays a bucket. ``slot_plan`` (:func:`make_sparse_
+    step_bucketed`) is lru-key material: static, a block length or None
+    per slot, up a short ladder so that a configuration's tables share
+    one; where the blocks
     start is a fifth sharded array (every device's shard the same
     ``[width]`` starts) and keys nothing. A table with no blocked slot
     has the empty plan ``()``, no fifth array, and the program every
     sparse fit had before."""
-    step = make_sparse_step_bucketed(loss, local_bss, axis, dim,
-                                     segsum_backend, slot_plan)
+    step = make_sparse_step_bucketed(loss, local_bss, axis, dim, slot_plan)
     return _whole_loop(mesh, step, 4 * len(local_bss) + bool(slot_plan), axis,
                        "lr_sparse_loop", SPARSE_PHASES)
 
@@ -839,7 +819,7 @@ def train_linear_model(
     p_size = mesh.axis_size()
     n_local = -(-n // p_size)
     local_bs = align_local_bs(global_batch_size, p_size, n_local)
-    from flinkml_tpu.kernels import _gate, dense_step
+    from flinkml_tpu.kernels import _mosaic, dense_step
 
     dt = _placed_dtype(x, dtype)
     # What the step will read off its operands (:func:`_rows_in_fast_
@@ -848,7 +828,7 @@ def train_linear_model(
     fused = dense_step.unsupported_reason(
         dt, n_local, local_bs, x.shape[1]) is None
     if fused:
-        _gate.import_beside_host_work()
+        _mosaic.import_beside_host_work()
     trainer = _dense_trainer(mesh.mesh, loss, local_bs, DeviceMesh.DATA_AXIS)
     place = _dense_placement(x, y, w, mesh, seed, dtype, n_local, local_bs, kept)
     coef = _run_chunked(
@@ -1100,17 +1080,16 @@ def train_linear_model_sparse_csr(
     if np.dtype(dtype) == np.float32:
         # A plan's step may hold the block kernels (a TPU's): what
         # tracing them imports loads beside the pack and the permutation.
-        from flinkml_tpu.kernels import _gate
+        from flinkml_tpu.kernels import _mosaic
 
-        _gate.import_beside_host_work()
+        _mosaic.import_beside_host_work()
     place, local_bss, slot_plan = prepare_sparse_buckets(
         indptr, indices, values, dim, y, w, mesh, global_batch_size,
         max_buckets=max_buckets, dtype=dtype, seed=seed, kept=kept,
     )
     trainer = _sparse_trainer_bucketed(
         mesh.mesh, loss, tuple(local_bss), DeviceMesh.DATA_AXIS, int(dim),
-        _segsum_backend(), slot_plan,
-    )
+        slot_plan)
     coef = _run_chunked(
         trainer, place, int(dim), jnp.dtype(dtype),
         learning_rate, reg * (1.0 - elastic_net), reg * elastic_net,
@@ -1334,8 +1313,7 @@ def _train_linear_sparse_stream_multiprocess(
     p_size = mesh.axis_size()
     row_tile = p_size * 8
     axis = DeviceMesh.DATA_AXIS
-    stepper = _sparse_stream_stepper(mesh.mesh, loss, axis, int(sparse_dim),
-                                     _segsum_backend())
+    stepper = _sparse_stream_stepper(mesh.mesh, loss, axis, int(sparse_dim))
     l2 = reg * (1.0 - elastic_net)
     l1 = reg * elastic_net
 
@@ -1721,14 +1699,11 @@ def _stream_stepper(mesh, loss: str, axis: str):
 
 
 @functools.lru_cache(maxsize=64)
-def _sparse_stream_stepper(mesh, loss: str, axis: str, dim: int,
-                           segsum_backend: str = "xla"):
+def _sparse_stream_stepper(mesh, loss: str, axis: str, dim: int):
     """Sparse sibling of :func:`_stream_stepper`: the batch arrives as a
     sharded padded-ELL block (indices/values), the dense ``[dim]``
     coefficient stays replicated. ELL matvec forward + one
-    ``segment_sum`` gradient scatter. ``segsum_backend`` is lru-key
-    material (kernel gate idiom)."""
-    from flinkml_tpu import kernels
+    ``segment_sum`` gradient scatter."""
 
     def per_device(coef, ib, vb, yb, wb, learning_rate, reg_l2, reg_l1):
         acc = _acc_dt(vb.dtype)
@@ -1736,8 +1711,7 @@ def _sparse_stream_stepper(mesh, loss: str, axis: str, dim: int,
         mult, per_ex = _margin_grad(loss, dot, yb, wb)
         contrib = (vb * mult[:, None]).reshape(-1)
         grad = jax.lax.psum(
-            kernels.segment_sum(contrib, ib.reshape(-1), dim,
-                                backend=segsum_backend),
+            jax.ops.segment_sum(contrib, ib.reshape(-1), num_segments=dim),
             axis,
         ) + 2.0 * reg_l2 * coef
         loss_sum = jax.lax.psum(jnp.sum(per_ex.astype(acc)), axis) + (
@@ -1763,8 +1737,7 @@ def _sparse_stream_stepper(mesh, loss: str, axis: str, dim: int,
 
 
 @functools.lru_cache(maxsize=64)
-def _sorted_column_stepper(loss: str, dim: int,
-                           segsum_backend: str = "xla"):
+def _sorted_column_stepper(loss: str, dim: int):
     """Step factory for :func:`train_linear_model_sorted_stream`: one
     SGD step over a prefetched :class:`~flinkml_tpu.table
     .SortedSparseColumn` batch. Pure ``jax.jit`` — the column's global
@@ -1780,9 +1753,7 @@ def _sorted_column_stepper(loss: str, dim: int,
     thread). Row-bucket padding is neutralized in-jit: the weight
     column is masked by the traced ``n_valid`` row count (weight 0 ⇒
     exact zero contribution to grad/loss/wsum), so batch-size jitter
-    inside a bucket never retraces. ``segsum_backend`` is lru-key
-    material (kernel gate idiom)."""
-    from flinkml_tpu import kernels
+    inside a bucket never retraces."""
 
     def step(coef, ib, vb, perm, seg, yb, wb, n_valid, learning_rate,
              reg_l2, reg_l1):
@@ -1796,9 +1767,9 @@ def _sorted_column_stepper(loss: str, dim: int,
         dot = ell_matvec(ib, vb, coef)
         mult, per_ex = _margin_grad(loss, dot, yb, wb)
         contrib = (vb * mult[:, None]).reshape(-1)
-        scattered = kernels.segment_sum(
-            jnp.take(contrib, perm), seg, dim,
-            indices_are_sorted=True, backend=segsum_backend,
+        scattered = jax.ops.segment_sum(
+            jnp.take(contrib, perm), seg, num_segments=dim,
+            indices_are_sorted=True,
         )
         # Without the barrier XLA folds the L2 term into the scatter's
         # init operand (each coefficient's sum would START from it);
@@ -1901,9 +1872,7 @@ def train_linear_model_sorted_stream(
             )
         if dim is None:
             dim = col.dim
-            stepper = _sorted_column_stepper(
-                loss, dim, _segsum_backend()
-            )
+            stepper = _sorted_column_stepper(loss, dim)
             coef = jnp.zeros(dim, dt)
         elif col.dim != dim:
             raise ValueError(
@@ -2322,8 +2291,7 @@ def train_linear_model_stream(
     row_tile = p_size * 8  # bounds the set of padded shapes → compilations
     axis = DeviceMesh.DATA_AXIS
     stepper = (
-        _sparse_stream_stepper(mesh.mesh, loss, axis, int(sparse_dim),
-                               _segsum_backend())
+        _sparse_stream_stepper(mesh.mesh, loss, axis, int(sparse_dim))
         if sparse_dim is not None
         else _stream_stepper(mesh.mesh, loss, axis)
     )

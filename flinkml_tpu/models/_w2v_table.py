@@ -439,7 +439,7 @@ def fit_table(est, table, score_dtype=None):
     """``Word2Vec.fit(Table)``: ``(vocabulary [vocab] str, vectors [vocab,
     dim] float32)`` as the chip returned them. The caller's span ``fit``
     holds all of it. ``score_dtype`` is the benchmark's control's alone."""
-    from flinkml_tpu.kernels import _gate, row_update
+    from flinkml_tpu.kernels import _mosaic, row_update
     from flinkml_tpu.models import word2vec
 
     name = est.get(est.INPUT_COL)
@@ -448,7 +448,7 @@ def fit_table(est, table, score_dtype=None):
     if p == 1:
         # A TPU's step holds the sorted update's kernel: what tracing it
         # imports loads beside the ingest.
-        _gate.import_beside_host_work()
+        _mosaic.import_beside_host_work()
     window, min_count = est.get(est.WINDOW_SIZE), est.get(est.MIN_COUNT)
     subsample = float(est.get(est.SUBSAMPLE))
     placed = table.device_resident(
@@ -483,8 +483,7 @@ def fit_table(est, table, score_dtype=None):
                 est.get_seed(), vocab, dim, shard_rows * p, dim))
             run = word2vec._sgns_trainer_sharded(
                 mesh.mesh, DeviceMesh.DATA_AXIS, local_bs, negatives, shard_rows,
-                word2vec._exchange_strategy(), word2vec._kernels_segsum_backend(),
-                corpus=d, score_dtype=score_dtype)
+                word2vec._exchange_strategy(), corpus=d, score_dtype=score_dtype)
     with span("w2v.loop"):
         with span("w2v.dispatch"):
             out = run(v, u, placed.tokens, placed.keep, placed.pool, seed, rate,

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from flinkml_tpu import kernels
 from flinkml_tpu.api import Estimator, Model
 from flinkml_tpu.models import _als_blocked
 from flinkml_tpu.models._als_blocked import GRAM_PRECISION, start_factors  # noqa: F401
@@ -135,8 +134,7 @@ def als_run_tables(seg_padded: np.ndarray, p_size: int, chunk: int):
 
 @functools.lru_cache(maxsize=32)
 def _normal_eq_chunk_fn(mesh, axis: str, n_segments: int, implicit: bool,
-                        layout: str = "segment",
-                        segsum_backend: str = "xla"):
+                        layout: str = "segment"):
     """Accumulate one COO chunk into the normal equations.
 
     Chunk inputs are sharded over the data axis; the returned partial
@@ -144,13 +142,8 @@ def _normal_eq_chunk_fn(mesh, axis: str, n_segments: int, implicit: bool,
     entries carry segment id ``n_segments`` and fall into a dummy row.
     ``layout="cumsum"`` takes two extra sharded args (per-device run
     ``ends``/``cols`` from :func:`als_run_tables`) and reduces without
-    the per-chunk sort (see :func:`_als_layout`). The ``segment``
-    layout's three scatters route through the kernel-backend gate
-    (:mod:`flinkml_tpu.kernels`, site ``segment_sum``; ``segsum_backend``
-    is lru-key material) — identical numerics under the default
-    ``"xla"``, multi-block Pallas capable when the gate selects it.
+    the per-chunk sort (see :func:`_als_layout`).
     """
-    from flinkml_tpu import kernels
 
     def weights(r, alpha):
         if implicit:
@@ -165,16 +158,13 @@ def _normal_eq_chunk_fn(mesh, axis: str, n_segments: int, implicit: bool,
         # rating is 0; explicit a_w=1 is harmless in the dummy row).
         k = y.shape[1]
         outer = (y[:, :, None] * y[:, None, :]) * a_w[:, None, None]
-        # Rank-2 operands keep the gated kernel eligible ([cells, k] is
-        # its 2-D contract); reshape back after the scatter.
-        a = kernels.segment_sum(
-            outer.reshape(-1, k * k), seg, n_segments + 1,
-            backend=segsum_backend,
+        a = jax.ops.segment_sum(
+            outer.reshape(-1, k * k), seg, num_segments=n_segments + 1,
         ).reshape(n_segments + 1, k, k)
-        b = kernels.segment_sum(b_w[:, None] * y, seg, n_segments + 1,
-                                backend=segsum_backend)
-        cnt = kernels.segment_sum(jnp.ones_like(r), seg, n_segments + 1,
-                                  backend=segsum_backend)
+        b = jax.ops.segment_sum(b_w[:, None] * y, seg,
+                                num_segments=n_segments + 1)
+        cnt = jax.ops.segment_sum(jnp.ones_like(r), seg,
+                                  num_segments=n_segments + 1)
         return (
             jax.lax.psum(a[:-1], axis),
             jax.lax.psum(b[:-1], axis),
@@ -297,9 +287,7 @@ def _half_step(
     chunk_g = mesh.axis_size() * chunk
     layout = "segment" if run_tables is None else "cumsum"
     fn = _normal_eq_chunk_fn(
-        mesh.mesh, DeviceMesh.DATA_AXIS, n_target, implicit, layout,
-        kernels.segsum_backend(),
-    )
+        mesh.mesh, DeviceMesh.DATA_AXIS, n_target, implicit, layout)
     a = jnp.zeros((n_target, k, k), jnp.float32)
     b = jnp.zeros((n_target, k), jnp.float32)
     cnt = jnp.zeros((n_target,), jnp.float32)
@@ -612,13 +600,9 @@ class ALS(StreamingEstimatorMixin, _ALSParams, Estimator):
 
         chunk_fns = {
             True: _normal_eq_chunk_fn(
-                mesh.mesh, DeviceMesh.DATA_AXIS, n_users, implicit,
-                "segment", kernels.segsum_backend(),
-            ),
+                mesh.mesh, DeviceMesh.DATA_AXIS, n_users, implicit, "segment"),
             False: _normal_eq_chunk_fn(
-                mesh.mesh, DeviceMesh.DATA_AXIS, n_items, implicit,
-                "segment", kernels.segsum_backend(),
-            ),
+                mesh.mesh, DeviceMesh.DATA_AXIS, n_items, implicit, "segment"),
         }
         alpha_j = jnp.asarray(alpha, jnp.float32)
 

@@ -52,7 +52,7 @@ from flinkml_tpu.common_params import (
     HasLabelCol,
     HasPredictionCol,
 )
-from flinkml_tpu.kernels import _gate
+from flinkml_tpu.kernels import _mosaic
 from flinkml_tpu.kernels import knn_search
 from flinkml_tpu.kernels import topk as topk_kernel
 from flinkml_tpu.models._data import features_matrix
@@ -233,7 +233,7 @@ def _tile_top_k(d2, k: int):
     unrolled-pass ceiling a sort is the right tool, and ``lax.top_k`` is
     it; so it is where Mosaic does not compile the kernel (a CPU or a
     GPU would run it interpreted, a Python loop over blocks of 8 rows)."""
-    if k <= topk_kernel.MAX_K and not _gate.interpret_mode():
+    if k <= topk_kernel.MAX_K and not _mosaic.interpret_mode():
         neg, at = topk_kernel.pallas_top_k(-d2, k)
     else:
         neg, at = jax.lax.top_k(-d2, k)
@@ -244,7 +244,7 @@ def _ranks_in_the_product(queries, train_x, k: int) -> bool:
     """Whether :func:`nearest` takes the fused kernel for these operands:
     on a TPU (elsewhere it would run interpreted, a Python loop over
     blocks), and where the kernel takes their type, width and ``k``."""
-    return (not _gate.interpret_mode()
+    return (not _mosaic.interpret_mode()
             and knn_search.unsupported_reason(queries, train_x, k) is None)
 
 

@@ -171,16 +171,6 @@ def _w2v_accum() -> str:
     return layout
 
 
-def _kernels_segsum_backend() -> str:
-    """The kernel-backend gate for the embedding-gradient scatter
-    (:mod:`flinkml_tpu.kernels`, site ``segment_sum``) — resolved at
-    fit time and threaded through the trainer's lru key, mirroring
-    :func:`_w2v_accum`."""
-    from flinkml_tpu import kernels
-
-    return kernels.segsum_backend()
-
-
 #: The precision of the scores' products: float32-accurate on every
 #: backend (a TPU's compiler turns these skinny products into float32
 #: multiplies and sums of its own accord; where one goes to a matrix unit
@@ -224,25 +214,9 @@ def start_vectors(seed: int, vocab: int, dim: int) -> jax.Array:
 
 @functools.lru_cache(maxsize=8)
 def _sgns_trainer(mesh, axis: str, local_bs: int, n_neg: int,
-                  accum: str = "scatter", segsum_backend: str = "xla"):
-    from flinkml_tpu import kernels
-
+                  accum: str = "scatter"):
     def local(centers, contexts, wl, pool, v0, u0, lr, n_steps, key):
         n_local = centers.shape[0]
-
-        def scatter_rows(table_like, ids, rows):
-            """The ``scatter`` accumulation under the kernel-backend
-            gate: ``.at[ids].add`` (XLA) or the Pallas row-payload
-            segment-sum — ``segsum_backend`` is lru-key material, so a
-            gate flip re-keys the jitted trainer."""
-            if segsum_backend == "pallas":
-                return kernels.segment_sum(
-                    rows.reshape(-1, rows.shape[-1]), ids.reshape(-1),
-                    table_like.shape[0], backend="pallas",
-                )
-            return jnp.zeros_like(table_like).at[ids.reshape(-1)].add(
-                rows.reshape(-1, rows.shape[-1])
-            )
 
         def onehot_sum(table_like, ids, rows):
             """``one_hot(ids)^T @ rows`` — the gated scatter-free
@@ -273,15 +247,6 @@ def _sgns_trainer(mesh, axis: str, local_bs: int, n_neg: int,
             if accum == "onehot":
                 dv = onehot_sum(v, c, grad_vc)
                 du = onehot_sum(u, ctx, grad_uc) + onehot_sum(
-                    u, neg, grad_un
-                )
-            elif segsum_backend == "pallas":
-                # Two independent scatters summed (instead of one
-                # chained scatter) — same gradients, f32 order differs
-                # only on ctx/neg id collisions; the kernel parity test
-                # pins each scatter bitwise against its XLA twin.
-                dv = scatter_rows(v, c, grad_vc)
-                du = scatter_rows(u, ctx, grad_uc) + scatter_rows(
                     u, neg, grad_un
                 )
             else:
@@ -325,8 +290,7 @@ def _sgns_trainer(mesh, axis: str, local_bs: int, n_neg: int,
 @functools.lru_cache(maxsize=8)
 def _sgns_trainer_sharded(mesh, axis: str, local_bs: int, n_neg: int,
                           shard_rows: int, strategy: str = "ring",
-                          segsum_backend: str = "xla", corpus=None,
-                          score_dtype=None):
+                          corpus=None, score_dtype=None):
     """Vocab-sharded SGNS trainer: the scale path above the embedding
     dense-psum threshold (VERDICT r4 weak #6 — the dense trainer psums
     a full ``[vocab, dim]`` gradient every step, quadratically painful
@@ -347,9 +311,7 @@ def _sgns_trainer_sharded(mesh, axis: str, local_bs: int, n_neg: int,
       2. local pair math — :func:`_sgns_pair_grads`, shared with the
          dense trainer.
       3. ONE exchange scatter — the scaled gradient rows for both
-         tables route home; the ``all_to_all`` scatter rides the PR 12
-         padded-ELL ``segment_sum`` kernel gate (``segsum_backend`` is
-         lru-key material, like the dense trainer's).
+         tables route home.
 
     Per step, per device: ``2·(2 + n_neg)·global_bs·dim`` floats total
     regardless of strategy — independent of vocab AND of P. Numerics
@@ -390,8 +352,7 @@ def _sgns_trainer_sharded(mesh, axis: str, local_bs: int, n_neg: int,
                     (1, neg, -scale * grad_un),
                 ),
                 axes=axis, n_shards=p, shard_rows=shard_rows,
-                strategy=strategy, segsum_backend=segsum_backend,
-            )
+                strategy=strategy)
             return step + 1, v, u
 
         def cond(state):
@@ -741,14 +702,11 @@ class Word2Vec(StreamingEstimatorMixin, _Word2VecParams, Estimator):
             trainer = _sgns_trainer_sharded(
                 mesh.mesh, DeviceMesh.DATA_AXIS, local_bs,
                 self.get(self.NUM_NEGATIVES), shard_rows,
-                _exchange_strategy(), _kernels_segsum_backend(),
-            )
+                _exchange_strategy())
         else:
             trainer = _sgns_trainer(
                 mesh.mesh, DeviceMesh.DATA_AXIS, local_bs,
-                self.get(self.NUM_NEGATIVES), _w2v_accum(),
-                _kernels_segsum_backend(),
-            )
+                self.get(self.NUM_NEGATIVES), _w2v_accum())
         lr = jnp.asarray(self.get(self.LEARNING_RATE), jnp.float32)
         base_key = jax.random.PRNGKey(self.get_seed())
         tile = p * self._PAIR_TILE

@@ -283,23 +283,17 @@ class BatchedCSR:
         """Row-wise sparse dot against a dense vector: [n]."""
         return ell_matvec(self.indices, self.values, jnp.asarray(w))
 
-    def rmatvec(self, coeffs, backend=None) -> jax.Array:
+    def rmatvec(self, coeffs) -> jax.Array:
         """Transpose product: X^T @ coeffs -> dense [dim].
 
         The sparse-gradient scatter-add (SURVEY.md §7 hard part (a)):
         flattens to one ``segment_sum`` so XLA emits a single HBM
-        scatter. The lowering routes through the kernel-backend gate
-        (:mod:`flinkml_tpu.kernels`, site ``segment_sum``): XLA by
-        default, the Pallas streaming accumulator when the gate — or an
-        explicit ``backend=`` — selects it.
+        scatter.
         """
-        from flinkml_tpu import kernels
-
         coeffs = jnp.asarray(coeffs)
         contrib = (self.values * coeffs[:, None]).reshape(-1)
         flat_idx = self.indices.reshape(-1)
-        return kernels.segment_sum(contrib, flat_idx, self.dim,
-                                   backend=backend)
+        return jax.ops.segment_sum(contrib, flat_idx, num_segments=self.dim)
 
     def slice_rows(self, start: int, stop: int) -> "BatchedCSR":
         return BatchedCSR(

@@ -16,7 +16,7 @@ Compile cache and row bucketing
 Programs are cached under a key of
 
   ``(chain fingerprint, external input col specs, constant specs,
-  requested output columns, bucket, policy, kernel backend)``
+  requested output columns, bucket, policy)``
 
 where the chain fingerprint is the tuple of each kernel's ``fingerprint``,
 input col specs are ``(name, dtype, trailing shape)`` of every column the
@@ -77,24 +77,8 @@ strong wide constant into the compute region) raises
 :class:`~flinkml_tpu.precision.PrecisionValidationError` instead of
 compiling. No active policy (the default) leaves every path untouched.
 
-Kernel backend (the Pallas gate)
---------------------------------
-
-Each program's chain lowers through one of two backends: the plain
-``jax.jit`` XLA path (default), or ONE row-tiled Pallas kernel per
-bucket (:mod:`flinkml_tpu.kernels.chain`), selected by the kernel gate
-(``FLINKML_TPU_KERNELS`` env var > the autotune table's
-``kernel_backend_fused_chain`` knob > ``"xla"``). The backend joins
-BOTH the in-memory program key and the AOT compile-cache identity, so
-a Pallas program can never alias an XLA one, and the FML6xx pre-compile
-validation always runs against the XLA-reference chain (identical math
-— the Pallas body executes the same kernel fns). Unsupported
-dtypes/shapes refuse loudly on an explicit request and fall back with
-one warning on a table-chosen backend
-(:mod:`flinkml_tpu.kernels._gate`).
-
 Instrumentation (``metrics.group("pipeline.fusion")``): ``compiles`` /
-``cache_hits`` / ``pallas_compiles`` counters, ``fused_segments`` / ``fused_stages``,
+``cache_hits`` counters, ``fused_segments`` / ``fused_stages``,
 ``host_to_device_transfers`` / ``host_to_device_bytes``, and
 ``host_transfer_bytes_avoided`` (bytes of intermediate columns that would
 have round-tripped host↔device under per-stage execution). Tests can hook
@@ -534,67 +518,11 @@ def _validate_chain(chain, ext_vals, const_vals, kernels, policy) -> None:
     )
 
 
-def _chain_support_checked(kernels, ext_names, out_names, bucket, policy,
-                           ext_vals, const_vals, backend: str,
-                           explicit: bool) -> str:
-    """The Pallas support check for one chain program: pay the
-    abstract trace only for a resolved ``pallas`` choice, refusing
-    loudly (explicit request) or falling back with one warning
-    (table-chosen). Called on cache MISSES only — a steady-state hit
-    never traces."""
-    if backend != "pallas":
-        return backend
-    import jax
-
-    from flinkml_tpu.kernels import _gate
-    from flinkml_tpu.kernels import chain as _pchain
-
-    if policy is not None and policy.quant is not None:
-        # The Pallas chain body has no dequant path for QuantizedConst
-        # pairs; an int8-tier program lowers through XLA.
-        return _gate.refuse_or_fallback(
-            "fused_chain", explicit,
-            f"quantized ({policy.quant}) model constants are not "
-            "supported by the pallas chain backend",
-        )
-
-    with jax.enable_x64(True):
-        reason = _pchain.unsupported_reason(
-            kernels, ext_names, out_names, bucket, policy,
-            ext_vals, const_vals, _gate.interpret_mode(),
-        )
-    if reason is not None:
-        return _gate.refuse_or_fallback("fused_chain", explicit, reason)
-    return "pallas"
-
-
-def _chain_backend(kernels, ext_names, out_names, bucket, policy,
-                   ext_vals, const_vals) -> str:
-    """Gate resolution + support check in one step (the executor defers
-    the check to cache misses; this combined form serves tests and
-    one-shot callers). The returned name joins the program cache key
-    AND the AOT store identity."""
-    from flinkml_tpu.kernels import _gate
-
-    backend, explicit = _gate.resolve_backend("fused_chain")
-    return _chain_support_checked(
-        kernels, ext_names, out_names, bucket, policy, ext_vals,
-        const_vals, backend, explicit,
-    )
-
-
-def _build_chain(kernels, ext_names, out_names, bucket, policy,
-                 backend: str):
-    """The chain callable for ``backend`` — ``_chain_fn`` under XLA,
-    the row-tiled Pallas kernel otherwise (same cols→cols contract) —
-    under the name a profile calls the program's runs, ``fused_chain``."""
-    if backend == "pallas":
-        from flinkml_tpu.kernels.chain import pallas_chain_fn
-
-        chain = pallas_chain_fn(kernels, ext_names, out_names, bucket, policy)
-    else:
-        chain = _chain_fn(kernels, ext_names, out_names, bucket, policy)
-    return named_program("fused_chain", chain)
+def _build_chain(kernels, ext_names, out_names, bucket, policy):
+    """:func:`_chain_fn`'s callable under the name a profile calls the
+    program's runs, ``fused_chain``."""
+    return named_program(
+        "fused_chain", _chain_fn(kernels, ext_names, out_names, bucket, policy))
 
 
 def _placement_ids(ext_vals) -> Tuple[int, ...]:
@@ -641,52 +569,28 @@ def _run_program(kernels, ext_names, out_names, ext_specs, const_specs,
 
     from flinkml_tpu import compile_cache
 
-    from flinkml_tpu.kernels import _gate
-
     group = metrics.group("pipeline.fusion")
     store = compile_cache.active_store()
-    backend, backend_explicit = _gate.resolve_backend("fused_chain")
-
-    def _key_for(chosen: str):
-        return (
-            tuple(k.fingerprint for k in kernels),
-            tuple(ext_specs),
-            const_specs,
-            tuple(out_names),
-            bucket,
-            policy,
-            chosen,
-        )
-
-    key = _key_for(backend)
+    # What decides the program, and nothing else: the in-memory cache's
+    # key, what ``on_compile`` hooks receive and the AOT store's identity.
+    key = (
+        tuple(k.fingerprint for k in kernels),
+        tuple(ext_specs),
+        const_specs,
+        tuple(out_names),
+        bucket,
+        policy,
+    )
     devsig = _placement_ids(ext_vals) if store is not None else None
     cache_key = key if store is None else key + (devsig,)
     with _LOCK:
         program = _CACHE.get(cache_key)
-    if program is None and backend == "pallas":
-        # Support check on MISSES only — a cached Pallas program was
-        # checked when it was built, so steady-state hits never pay the
-        # abstract trace. A refused chain re-keys to (and may hit) the
-        # XLA program.
-        checked = _chain_support_checked(
-            kernels, ext_names, out_names, bucket, policy,
-            ext_vals, const_vals, backend, backend_explicit,
-        )
-        if checked != backend:
-            backend = checked
-            key = _key_for(backend)
-            cache_key = key if store is None else key + (devsig,)
-            with _LOCK:
-                program = _CACHE.get(cache_key)
     if program is None and policy is not None:
         # Refusal precedes compile AND caching: a failing chain leaves
         # no executable behind (re-entry revalidates — validation is an
         # abstract trace, compile-free and cheap next to a compile).
         # This also gates AOT *loads*: a cached artifact only executes
         # in a process whose policy gate admits the same chain.
-        # Validation ALWAYS walks the XLA-reference chain — the Pallas
-        # backend runs the same kernel fns, and the FML6xx jaxpr walker
-        # must see their math, not an opaque pallas_call.
         with jax.enable_x64(True):
             _validate_chain(
                 _chain_fn(kernels, ext_names, out_names, bucket, policy),
@@ -698,7 +602,7 @@ def _run_program(kernels, ext_names, out_names, ext_specs, const_specs,
             with jax.enable_x64(True):
                 return jax.jit(
                     _build_chain(kernels, ext_names, out_names, bucket,
-                                 policy, backend)
+                                 policy)
                 ).lower(tuple(ext_vals), const_vals, np.int32(n)).compile()
 
         program, outcome = store.get_or_compile(
@@ -715,14 +619,12 @@ def _run_program(kernels, ext_names, out_names, ext_specs, const_specs,
             if program is None:
                 program = jax.jit(
                     _build_chain(kernels, ext_names, out_names, bucket,
-                                 policy, backend)
+                                 policy)
                 )
                 _CACHE[cache_key] = program
                 compiled = True
     if compiled:
         group.counter("compiles")
-        if backend == "pallas":
-            group.counter("pallas_compiles")
         for hook in list(on_compile):
             hook(key)
     else:

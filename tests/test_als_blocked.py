@@ -33,7 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.reference import als as reference  # noqa: E402
 from flinkml_tpu import table as table_mod  # noqa: E402
-from flinkml_tpu.kernels import _gate, row_fetch, spd_solve  # noqa: E402
+from flinkml_tpu.kernels import _mosaic, row_fetch, spd_solve  # noqa: E402
 from flinkml_tpu.models import ALS, ALSModel, _als_blocked  # noqa: E402
 from flinkml_tpu.parallel import DeviceMesh  # noqa: E402
 from flinkml_tpu.table import Table  # noqa: E402
@@ -288,9 +288,9 @@ def test_eight_devices_through_the_fetch_kernel_are_the_one_device_fit(
 def test_the_hot_rows_follow_the_backend_and_the_degrees(monkeypatch, case, taken):
     """``hot_rows_of``: the heaviest rows and the zero row where they
     cover enough of the slots on a TPU, None everywhere else."""
-    monkeypatch.delenv(_gate.ENV_INTERPRET_VAR, raising=False)
+    monkeypatch.delenv(_mosaic.ENV_INTERPRET_VAR, raising=False)
     if case != "a CPU":
-        monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+        monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     rng = np.random.default_rng(3)
     rows = 40 if case == "a short table" else 400_000
     degrees = (np.full(rows, 12) if case == "flat degrees"

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from flinkml_tpu.autotune import (
+    DEFAULT_TABLE_PATH,
     KNOWN_KNOBS,
     TuningTable,
     load_table,
@@ -251,6 +252,22 @@ def test_committed_table_has_measured_values_for_this_mesh():
         {"segment", "cumsum"}
     assert set(table.record(mesh, "w2v_accum")["candidates"]) == \
         {"scatter", "onehot"}
+
+
+def test_the_knob_sets_agree():
+    """One set of knobs in four places: the search's static defaults,
+    its measurers, the table's unit map and the committed ``cpu/cpu/8``
+    entry. Nine of them, none a kernel's backend."""
+    from flinkml_tpu.autotune.search import MEASURERS
+
+    with open(DEFAULT_TABLE_PATH) as f:
+        committed = json.load(f)["entries"]
+    assert list(committed) == ["cpu/cpu/8"]
+    names = set(STATIC_DEFAULTS)
+    assert names == set(MEASURERS) == set(KNOWN_KNOBS) == set(
+        committed["cpu/cpu/8"])
+    assert len(names) == 9
+    assert not [name for name in names if "backend" in name]
 
 
 def test_quick_search_smoke(tmp_path):

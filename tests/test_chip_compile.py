@@ -63,10 +63,10 @@ def test_knn_search_at_the_cells_size(one_chip, no_compile_cache, monkeypatch,
     the benchmark's control."""
     from jax.experimental.layout import Format, Layout
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
     from flinkml_tpu.models import knn
 
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     rows, dim, queries, k = 2_025_000, 784, 10_000, 5
     assert knn._ranks_in_the_product(
         jax.ShapeDtypeStruct((queries, dim), jnp.float32),
@@ -270,10 +270,10 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
     from jax.experimental.layout import Format, Layout
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
     from flinkml_tpu.models import _linear_sgd
 
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     width, dim, batch = 39, 1_000_000, 65_536
     assert _linear_sgd._blocks_in_fast_memory(jnp.float32, batch // chips,
                                               FM_CRITEO_PLAN)
@@ -291,7 +291,7 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
     try:
         with jax.enable_x64(True):
             traced = _linear_sgd._sparse_trainer_bucketed(
-                mesh, "logistic", (batch // chips,), "data", dim, "xla",
+                mesh, "logistic", (batch // chips,), "data", dim,
                 FM_CRITEO_PLAN).trace(
                 on((dim,), f32), on((), i32), on((), f32),
                 on((rows, width), i32, rows_minor), on((rows, width), f32, rows_minor),
@@ -340,10 +340,10 @@ def test_fm_adam_loop_at_the_cells_size_holds_the_payload_kernels(
     from jax.experimental.layout import Format, Layout
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
     from flinkml_tpu.models import _fm_sparse
 
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     rows, width, dim, k, batch = 16_777_216, 39, 1_000_000, 16, 65_536
     assert _fm_sparse._walk_in_fast_memory(
         jnp.float32, batch // chips, FM_CRITEO_PLAN, k + 1,
@@ -465,9 +465,9 @@ def test_dense_step_kernel_at_the_cells_size(one_chip, no_compile_cache,
     says (one float64 block and Mosaic aborts the process, so the traced
     program is read first). The table is an operand as it lies: the
     compiled program holds no second array."""
-    from flinkml_tpu.kernels import _gate, dense_step
+    from flinkml_tpu.kernels import _mosaic, dense_step
 
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     assert dense_step.unsupported_reason(jnp.float32, rows, batch, dim) is None
     assert dense_step.tile_rows(batch, dim) == tile
 
@@ -540,12 +540,12 @@ def test_lr_dense_loop_is_one_program_for_a_chunk_and_for_a_hit(
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
     from flinkml_tpu.models import _linear_sgd
 
     rows, dim, batch, max_iter = 9_437_184, 123, 262_144, 72
     if step == "kernel":
-        monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+        monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     mesh = Mesh(np.array(topo.devices[:1]), ("data",))
     by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
 
@@ -636,10 +636,10 @@ def test_als_half_step_at_the_cells_size(topo, no_compile_cache, monkeypatch, si
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from flinkml_tpu.kernels import _gate, row_fetch
+    from flinkml_tpu.kernels import _mosaic, row_fetch
     from flinkml_tpu.models import _als_blocked
 
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     degrees, fixed_rows = _yahoomusic_degrees(side)
     rank = 100
     plan = _als_blocked.plan_side(degrees, 1, _als_blocked._CHUNK_SLOTS)
@@ -765,10 +765,10 @@ def test_w2v_whole_fit_at_the_cells_size_holds_no_vocabulary_sized_temporary(
     compiled for the chip) the three scatter-adds too: a gather of slices
     the compiler cannot fetch as rows is expanded into a ``while`` of its
     own (65,536 turns a step, read off this program as first written)."""
-    from flinkml_tpu.kernels import _gate, row_update
+    from flinkml_tpu.kernels import _mosaic, row_update
     from flinkml_tpu.models import _w2v_table
 
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     vocab, dim, tokens = 1_115_016, 300, 805_306_368
     assert row_update.unsupported_reason(jnp.float32, vocab, 384) is None
     # 70.27 % of the tokens survive at the cell's corpus: its candidates
@@ -870,11 +870,11 @@ def test_gbt_forest_at_the_cells_size_holds_no_rows_by_features_array_wider_than
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
     from flinkml_tpu.models import _gbt_table
     from flinkml_tpu.parallel import DeviceMesh
 
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     rows, features = 115_343_360, 13
     mesh = Mesh(np.array(topo.devices[:1]), (DeviceMesh.DATA_AXIS,))
 

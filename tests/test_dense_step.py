@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flinkml_tpu.kernels import _gate, dense_step
+from flinkml_tpu.kernels import _mosaic, dense_step
 from flinkml_tpu.models import _linear_sgd
 from flinkml_tpu.ops.losses import margin_terms
 from flinkml_tpu.parallel import DeviceMesh
@@ -197,7 +197,7 @@ def test_where_the_kernel_applies_is_read_off_the_step(case, monkeypatch):
     dtype, n_local, local_bs, dim, why = REFUSALS[case]
     # here, on a CPU, Mosaic's kernel would be interpreted: XLA's products
     assert "not a TPU" in dense_step.unsupported_reason(dtype, n_local, local_bs, dim)
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)     # a TPU
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)     # a TPU
     reason = dense_step.unsupported_reason(dtype, n_local, local_bs, dim)
     assert (reason is None) if why is None else (why in reason)
     xl = jax.ShapeDtypeStruct((n_local, dim), dtype)
@@ -259,7 +259,7 @@ def _step_before(loss, local_bs, axis):
 def test_a_step_the_kernel_does_not_take_is_the_step_as_it_was(case, monkeypatch):
     dtype, n_local, dim = jnp.float32, ROWS, 123
     if case != "a CPU":
-        monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+        monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     if case == "bfloat16 rows":
         dtype = jnp.bfloat16
     elif case == "a ragged shard":
@@ -275,7 +275,7 @@ def test_a_step_the_kernel_does_not_take_is_the_step_as_it_was(case, monkeypatch
 
 
 def test_on_a_tpu_the_step_is_one_kernel_and_no_slice_of_the_rows(monkeypatch):
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     outside = [e.primitive.name for e in _step_program(jnp.float32).jaxpr.eqns]
     assert outside.count("pallas_call") == 1
     # the kernel's own products are of a chunk in fast memory; none of

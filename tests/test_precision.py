@@ -970,24 +970,3 @@ def test_serving_engine_int8_tier_end_to_end(quantize_small_consts):
     finally:
         e32.stop()
         eq8.stop()
-
-
-def test_int8_tier_refuses_explicit_pallas_backend():
-    """An EXPLICIT pallas request composed with the int8 tier refuses
-    loudly (the gate contract) — the Pallas chain body has no dequant
-    path; a table-chosen backend would warn-and-fall-back instead."""
-    from flinkml_tpu.kernels._gate import KernelUnsupportedError
-
-    pm, t = _wide_scaler_lr_pipeline(seed=14)
-    old = os.environ.get("FLINKML_TPU_KERNELS")
-    os.environ["FLINKML_TPU_KERNELS"] = "pallas"
-    try:
-        with pipeline_fusion.precision_scope("int8_inference"):
-            with pytest.raises(KernelUnsupportedError, match="quantized"):
-                (out,) = pm.transform(t)
-                np.asarray(out.column("prediction"))
-    finally:
-        if old is None:
-            os.environ.pop("FLINKML_TPU_KERNELS", None)
-        else:
-            os.environ["FLINKML_TPU_KERNELS"] = old

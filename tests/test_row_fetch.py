@@ -20,7 +20,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from flinkml_tpu.kernels import _gate, row_fetch  # noqa: E402
+from flinkml_tpu.kernels import _mosaic, row_fetch  # noqa: E402
 
 ROWS, LANES, TILE = 600, 128, 1024
 
@@ -153,9 +153,9 @@ def test_the_hot_rows_are_the_power_of_two_that_holds_the_table(rows, hot):
 def test_where_the_kernel_is_taken(monkeypatch, case, dtype, lanes, share, word):
     """The backend, the dtype, the lanes and the hot rows' share of the
     slots: nothing else is read."""
-    monkeypatch.delenv(_gate.ENV_INTERPRET_VAR, raising=False)
+    monkeypatch.delenv(_mosaic.ENV_INTERPRET_VAR, raising=False)
     if case != "a CPU":
-        monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+        monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     reason = row_fetch.unsupported_reason(dtype, lanes, share)
     if word is None:
         assert reason is None

@@ -23,7 +23,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from flinkml_tpu.kernels import _gate, row_update  # noqa: E402
+from flinkml_tpu.kernels import _mosaic, row_update  # noqa: E402
 
 ROWS, LANES = 1000, 384
 
@@ -164,7 +164,7 @@ def test_the_table_is_updated_where_it_lies():
 def test_where_the_kernel_applies_is_read_off_the_table(case, monkeypatch):
     name, why = case
     if name != "a CPU":
-        monkeypatch.setattr(_gate, "interpret_mode", lambda: False)     # a TPU
+        monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)     # a TPU
     dtype = jnp.bfloat16 if name == "bfloat16 tables" else jnp.float32
     rows = 1_115_011 if name == "rows off a multiple of 8" else 1_115_016
     lanes = 300 if name == "300 lanes" else 384

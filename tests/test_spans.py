@@ -395,7 +395,7 @@ def test_spans_live_in_their_own_group():
         _score(model, table)
     known = {"host_to_device_transfers", "host_to_device_bytes", "compiles",
              "cache_hits", "fused_segments", "fused_stages",
-             "host_transfer_bytes_avoided", "aot_loads", "pallas_compiles"}
+             "host_transfer_bytes_avoided", "aot_loads"}
     assert set(fusion) <= known
     assert set(tab) == {"device_to_host_materializations", "device_to_host_bytes"}
     assert not any(k.endswith((".seconds", ".calls")) for k in (*fusion, *tab))
@@ -845,7 +845,7 @@ def _lowered_programs():
         data = [_struct((128, 7), i32, rows), _struct((128, 7), f32, rows),
                 _struct((128,), f32, rows), _struct((128,), f32, rows)]
         trainer = _linear_sgd._sparse_trainer_bucketed(
-            m(), "logistic", (8,), "data", 300, "xla")
+            m(), "logistic", (8,), "data", 300)
         return trainer.lower(*head, *data, *tail)
 
     def stage_write():
@@ -955,7 +955,7 @@ def _lowered_programs():
         kernels, ext, outs, ext_vals, const_vals, bucket, n, policy = seen[0]
         with jax.enable_x64(True):
             return jax.jit(pipeline_fusion._build_chain(
-                kernels, ext, outs, bucket, policy, "xla")).lower(
+                kernels, ext, outs, bucket, policy)).lower(
                     tuple(ext_vals), const_vals, np.int32(n))
 
     return {

@@ -261,7 +261,7 @@ def test_the_kernels_walk_groups_of_every_kind_whatever_the_tile(tile, monkeypat
 
 
 def test_where_the_kernels_apply_is_read_off_the_step(monkeypatch):
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     criteo = [(128, 11), (256, 10), (4096, 1), (26624, 8)]
     long = [(194_560, 5), (59_392, 2), (8_192, 32)]     # Criteo field by field
@@ -279,7 +279,7 @@ def test_where_the_kernels_apply_is_read_off_the_step(monkeypatch):
     # here, on a CPU, Mosaic's kernels would be interpreted: XLA's products
     plan = (128, 256, None, 26624)
     assert not _linear_sgd._blocks_in_fast_memory(jnp.float32, 65_536, plan)
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)     # a TPU
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)     # a TPU
     assert _linear_sgd._blocks_in_fast_memory(jnp.float32, 65_536, plan)
     assert not _linear_sgd._blocks_in_fast_memory(jnp.float32, 1000, plan)
     assert not _linear_sgd._blocks_in_fast_memory(jnp.float64, 65_536, plan)
@@ -311,7 +311,7 @@ def test_pallas_is_imported_beside_the_host_work_on_a_tpu_alone(monkeypatch):
     import sys
     import threading
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     started = []
 
@@ -323,16 +323,16 @@ def test_pallas_is_imported_beside_the_host_work_on_a_tpu_alone(monkeypatch):
             pass
 
     monkeypatch.setattr(threading, "Thread", Recorded)
-    _gate.import_beside_host_work()                 # a CPU
+    _mosaic.import_beside_host_work()                 # a CPU
     assert started == []
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     monkeypatch.setitem(sys.modules, "jax.experimental.pallas", object())
-    _gate.import_beside_host_work()                 # imported already
+    _mosaic.import_beside_host_work()                 # imported already
     assert started == []
     monkeypatch.delitem(sys.modules, "jax.experimental.pallas")
-    _gate.import_beside_host_work()
+    _mosaic.import_beside_host_work()
     (thread,) = started
-    assert thread["target"] is _gate._import_keeping_bytecode and thread["daemon"]
+    assert thread["target"] is _mosaic._import_keeping_bytecode and thread["daemon"]
 
 
 def test_the_early_import_keeps_its_bytecode_beside_the_compile_cache(
@@ -344,7 +344,7 @@ def test_the_early_import_keeps_its_bytecode_beside_the_compile_cache(
     is the interpreter's own."""
     import sys
 
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     (tmp_path / "src").mkdir()
     monkeypatch.syspath_prepend(str(tmp_path / "src"))
@@ -352,7 +352,7 @@ def test_the_early_import_keeps_its_bytecode_beside_the_compile_cache(
 
     def compiled(name):
         (tmp_path / "src" / f"{name}.py").write_text("VALUE = 41 + 1\n")
-        _gate._import_keeping_bytecode((name,))
+        _mosaic._import_keeping_bytecode((name,))
         assert sys.modules.pop(name).VALUE == 42
         assert (sys.pycache_prefix, sys.dont_write_bytecode) == before
         return [p.name for p in (tmp_path / "cache").rglob(f"{name}.*.pyc")]
@@ -366,9 +366,9 @@ def test_the_early_import_keeps_its_bytecode_beside_the_compile_cache(
     monkeypatch.setattr(jax_cache, "in_use", lambda: None)
     assert compiled("kept_nowhere") == []
     # a private module this JAX does not have is left to the lowering
-    _gate._import_keeping_bytecode(("jax._src.pallas.no_such_module",))
+    _mosaic._import_keeping_bytecode(("jax._src.pallas.no_such_module",))
     with pytest.raises(ImportError):
-        _gate._import_keeping_bytecode(("no_such_public_module",))
+        _mosaic._import_keeping_bytecode(("no_such_public_module",))
     assert (sys.pycache_prefix, sys.dont_write_bytecode) == before
 
 
@@ -388,7 +388,7 @@ def _step_rows(rows, seed=1):
 def _run_step(mesh, loss, plan, data, coef, epoch, starts=None, bs=BS,
               check_vma=True):
     step = _linear_sgd.make_sparse_step_bucketed(
-        loss, (bs,), "data", DIM, "xla", plan)
+        loss, (bs,), "data", DIM, plan)
     if plan:
         data += (mesh.shard_batch(np.tile(starts, mesh.axis_size())),)
     f = jax.jit(jax.shard_map(
@@ -419,7 +419,7 @@ def blocked(request, monkeypatch):
 
 def _step_uses_the_kernels(plan, bs):
     step = _linear_sgd.make_sparse_step_bucketed(
-        "logistic", (bs,), "data", DIM, "xla", plan)
+        "logistic", (bs,), "data", DIM, plan)
     f32 = jnp.float32
     args = [jax.ShapeDtypeStruct((DIM,), f32), jax.ShapeDtypeStruct((), jnp.int32),
             jax.ShapeDtypeStruct((3 * bs, WIDTH), jnp.int32),
@@ -482,10 +482,10 @@ def test_blocks_that_start_elsewhere_run_the_same_step(mesh, blocked):
 def test_a_step_the_kernels_do_not_take_is_the_step_as_it_was(monkeypatch):
     """Another dtype, a batch that is not whole tiles, the empty plan, a
     CPU: no kernel in the step, whatever else holds."""
-    from flinkml_tpu.kernels import _gate
+    from flinkml_tpu.kernels import _mosaic
 
     assert _step_uses_the_kernels(MIXED_PLAN, 128) == 0          # a CPU
-    monkeypatch.setattr(_gate, "interpret_mode", lambda: False)  # a TPU
+    monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)  # a TPU
     assert _step_uses_the_kernels(MIXED_PLAN, 128) == 2
     assert _step_uses_the_kernels(MIXED_PLAN, 100) == 0
     assert _step_uses_the_kernels((), 128) == 0
@@ -495,7 +495,7 @@ def test_a_step_the_kernels_do_not_take_is_the_step_as_it_was(monkeypatch):
 def test_a_plan_with_several_buckets_is_refused():
     with pytest.raises(ValueError, match="one-bucket"):
         _linear_sgd.make_sparse_step_bucketed(
-            "logistic", (8, 8), "data", DIM, "xla", MIXED_PLAN)
+            "logistic", (8, 8), "data", DIM, MIXED_PLAN)
 
 
 def _products_through_xla(monkeypatch):
@@ -906,9 +906,8 @@ def test_the_one_hot_encoders_default_output_field_by_field_is_planned(mesh):
 # -- the program no plan reaches ------------------------------------------------
 
 
-def _step_before_plans(loss, local_bss, axis, dim, segsum_backend="xla"):
+def _step_before_plans(loss, local_bss, axis, dim):
     """``make_sparse_step_bucketed`` as it was before plans (cb591e5)."""
-    from flinkml_tpu import kernels
     from flinkml_tpu.models._linear_sgd import (
         _acc_dt, _margin_grad, _soft_threshold, _window, ell_matvec)
 
@@ -930,9 +929,9 @@ def _step_before_plans(loss, local_bss, axis, dim, segsum_backend="xla"):
             flat_idx.append(ib.reshape(-1))
             loss_l = loss_l + jnp.sum(per_ex.astype(acc))
             wsum_l = wsum_l + jnp.sum(wb.astype(acc))
-        grad_local = kernels.segment_sum(
+        grad_local = jax.ops.segment_sum(
             jnp.concatenate(contribs), jnp.concatenate(flat_idx),
-            dim, backend=segsum_backend,
+            num_segments=dim,
         )
         grad = jax.lax.psum(grad_local, axis)
         loss_sum = jax.lax.psum(loss_l, axis)
@@ -966,7 +965,7 @@ def test_the_trainer_under_the_empty_plan_lowers_to_the_text_before_plans(
                  arg((128,), f32, rows), arg((128,), f32, rows)]
     args += [arg((), f32)] * 4 + [arg((), jnp.int32)]
     sizes = (8,) * len(widths)
-    now = _linear_sgd._sparse_trainer_bucketed(m, loss, sizes, "data", 300, "xla")
+    now = _linear_sgd._sparse_trainer_bucketed(m, loss, sizes, "data", 300)
     before = _linear_sgd._whole_loop(
         m, _step_before_plans(loss, sizes, "data", 300), 4 * len(widths), "data",
         # under the module's name of today: the phases it declares are in it
@@ -980,7 +979,7 @@ def test_the_trainer_under_the_empty_plan_lowers_to_the_text_before_plans(
 def test_a_plan_is_part_of_the_trainers_cache_key_and_its_starts_are_not(mesh):
     def trainer(plan):
         return _linear_sgd._sparse_trainer_bucketed(
-            mesh.mesh, "logistic", (BS,), "data", DIM, "xla", plan)
+            mesh.mesh, "logistic", (BS,), "data", DIM, plan)
 
     assert trainer(()) is trainer(())
     assert trainer(MIXED_PLAN) is trainer(MIXED_PLAN)

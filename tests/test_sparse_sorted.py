@@ -1,5 +1,4 @@
-"""Sorted-by-design sparse hot loops (ISSUE 16): the multi-block
-segment-sum grid above the retired one-block input ceiling, the
+"""Sorted-by-design sparse hot loops (ISSUE 16): the
 SortedSparseColumn pack/prefetch format with zero retraces across
 buckets, the sorted-column stream fit's bitwise parity with the CSR
 stream, and the FML404 sorted-scatter provenance gate."""
@@ -10,9 +9,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from flinkml_tpu import kernels
-from flinkml_tpu.kernels import KernelUnsupportedError
-from flinkml_tpu.kernels import segsum as _segsum
 from flinkml_tpu.linalg import SparseVector
 from flinkml_tpu.table import SortedSparseColumn, Table
 
@@ -29,101 +25,6 @@ def _sparse_table(rng, rows, dim, nnz, weight=True):
     if weight:
         cols["w"] = rng.uniform(0.5, 1.5, rows).astype(np.float32)
     return Table(cols)
-
-
-# -- multi-block segment-sum -------------------------------------------------
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("sorted_", [False, True])
-def test_segsum_multiblock_above_old_input_ceiling(dtype, sorted_):
-    """cells just ABOVE the retired one-block input ceiling
-    (MAX_COMPILED_CELLS used to refuse this shape outright): the grid
-    streams ceil(cells / BLOCK_CELLS) blocks and stays bitwise with
-    ``jax.ops.segment_sum`` — the carry between blocks adds in the same
-    left-to-right element order XLA's CPU scatter uses."""
-    rng = np.random.default_rng(0)
-    cells = _segsum.MAX_COMPILED_CELLS + 1000
-    nseg = 1 << 10
-    ids = rng.integers(0, nseg, cells)
-    if sorted_:
-        ids = np.sort(ids)
-    ids = jnp.asarray(ids, jnp.int32)
-    vals = jnp.asarray(rng.normal(size=cells)).astype(dtype)
-    ref = jax.ops.segment_sum(vals, ids, num_segments=nseg,
-                              indices_are_sorted=sorted_)
-    out = kernels.segment_sum(vals, ids, nseg, indices_are_sorted=sorted_,
-                              backend="pallas")
-    assert out.dtype == ref.dtype
-    assert np.asarray(ref).tobytes() == np.asarray(out).tobytes()
-
-
-def test_segsum_multiblock_just_below_old_ceiling_row_payload():
-    """The [cells, k] embedding-exchange shape with cells*k straddling
-    the old ceiling: one flat-size below, one above — both bitwise (the
-    ceiling no longer depends on the INPUT size at all)."""
-    rng = np.random.default_rng(1)
-    k, nseg = 8, 512
-    for cells in (_segsum.MAX_COMPILED_CELLS // k - 16,
-                  _segsum.MAX_COMPILED_CELLS // k + 16):
-        ids = jnp.asarray(rng.integers(0, nseg, cells), jnp.int32)
-        rows = jnp.asarray(rng.normal(size=(cells, k)).astype(np.float32))
-        ref = jax.ops.segment_sum(rows, ids, num_segments=nseg)
-        out = kernels.segment_sum(rows, ids, nseg, backend="pallas")
-        assert np.asarray(ref).tobytes() == np.asarray(out).tobytes()
-
-
-def test_segsum_multiblock_ragged_tail_parity():
-    """cells one past a block boundary — the final grid step is almost
-    entirely zero-padding; padding cells must be exact no-op adds."""
-    rng = np.random.default_rng(2)
-    cells = _segsum.BLOCK_CELLS + 1
-    ids = jnp.asarray(np.sort(rng.integers(0, 100, cells)), jnp.int32)
-    vals = jnp.asarray(rng.normal(size=cells).astype(np.float32))
-    ref = jax.ops.segment_sum(vals, ids, num_segments=100,
-                              indices_are_sorted=True)
-    out = kernels.segment_sum(vals, ids, 100, indices_are_sorted=True,
-                              backend="pallas")
-    assert np.asarray(ref).tobytes() == np.asarray(out).tobytes()
-
-
-def test_segsum_output_ceiling_refusal_names_constant(monkeypatch):
-    """The ONLY remaining compiled-path ceiling is the OUTPUT block
-    ([num_segments, k] padded to 128 lanes per row): an explicit pallas
-    request above it refuses typed, naming MAX_COMPILED_CELLS — through
-    the dispatcher AND the direct kernel entry point."""
-    monkeypatch.setenv(kernels.ENV_INTERPRET_VAR, "0")
-    vals = jnp.ones(8, jnp.float32)
-    ids = jnp.zeros(8, jnp.int32)
-    over = _segsum.MAX_COMPILED_CELLS // 128 + 8
-    with pytest.raises(KernelUnsupportedError, match="MAX_COMPILED_CELLS"):
-        kernels.segment_sum(vals, ids, over, backend="pallas")
-    with pytest.raises(KernelUnsupportedError, match="MAX_COMPILED_CELLS"):
-        _segsum.pallas_segment_sum(vals, ids, over, interpret=False)
-    # ... while the interpreter (no VMEM) accepts any num_segments.
-    assert _segsum.unsupported_reason(vals, ids, over, interpret=True) is None
-
-
-def test_segsum_exchange_shape_above_old_ceiling_accepted_compiled():
-    """The embedding-exchange scatter at production shard sizes: an
-    input block far above the old input ceiling with a modest output
-    block is now COMPILED-path eligible (unsupported_reason is None) —
-    checked abstractly via ShapeDtypeStruct, no 128 MB allocation."""
-    cells, k, shard_rows = 1 << 21, 16, 1 << 14   # cells*k = 8x old cap
-    vals = jax.ShapeDtypeStruct((cells, k), jnp.float32)
-    ids = jax.ShapeDtypeStruct((cells,), jnp.int32)
-    assert cells * k > _segsum.MAX_COMPILED_CELLS
-    assert _segsum.padded_cells(shard_rows, k) == _segsum.MAX_COMPILED_CELLS
-    assert _segsum.unsupported_reason(
-        vals, ids, shard_rows, interpret=False) is None
-    # the output ceiling still applies to the same shape (one more
-    # sublane tile of rows):
-    assert "MAX_COMPILED_CELLS" in _segsum.unsupported_reason(
-        vals, ids, shard_rows + 8, interpret=False)
-    # the sparse trainers' own shape (k = 1, dim 1e6) is refused by name:
-    flat = jax.ShapeDtypeStruct((cells,), jnp.float32)
-    assert "488 MiB" in _segsum.unsupported_reason(
-        flat, ids, 1_000_000, interpret=False)
 
 
 # -- SortedSparseColumn pack + prefetch --------------------------------------

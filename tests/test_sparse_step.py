@@ -14,8 +14,7 @@ eight-device mesh:
   taking its own rotating window a step. **The same replay at bfloat16
   values and coefficient is outside the tolerance**, so the comparison
   can tell the trainer's float32 from the next precision down;
-- the forward margin ``ops.sparse.ell_matvec`` alone;
-- the kernel gate no longer knows the deleted ``spmv`` site.
+- the forward margin ``ops.sparse.ell_matvec`` alone.
 """
 
 import math
@@ -24,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flinkml_tpu import kernels
 from flinkml_tpu.models._linear_sgd import train_linear_model_sparse_csr
 from flinkml_tpu.ops.sparse import choose_ell_widths, ell_matvec
 from tests import reference_sparse_sgd as reference
@@ -133,14 +131,3 @@ def test_ell_matvec_matches_numpy(case):
             * np.asarray(w, np.float64)[ib]).sum(axis=1)
     tol = {"float32": 1e-5, "float64": 1e-13, "bfloat16": 0.25}[dtype]
     np.testing.assert_allclose(np.asarray(out, np.float64), want, atol=tol)
-
-
-def test_the_kernel_gate_has_no_spmv_site(monkeypatch):
-    """The Pallas SpMV went with its site: asking for it is the typo'd
-    gate's ``ValueError``, not a silent default."""
-    assert "spmv" not in kernels.SITES
-    monkeypatch.setenv(kernels.ENV_VAR, "spmv=pallas")
-    with pytest.raises(ValueError, match="bad pair"):
-        kernels.backend_for("segment_sum")
-    with pytest.raises(ValueError, match="unknown kernel site"):
-        kernels.resolve_backend("spmv")
