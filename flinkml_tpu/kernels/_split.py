@@ -1,4 +1,5 @@
-"""A float32 as three bfloat16 parts: the two forms the kernels use.
+"""A float32 as three bfloat16 parts, the two forms the kernels use, and
+as four int8 digits of its bits, the form a lookup can use.
 
 The MXU multiplies bfloat16. A float32 product at full precision is made
 from parts: three times 8 bits of mantissa hold float32's 24, so
@@ -33,6 +34,17 @@ reasons read off the chip (PERF.md section 6, PRs 35, 39, 40):
 Where "bit for bit" ends, for both: a part under 2^-126 is flushed to
 zero (the chip and XLA:CPU alike), so values under 2^-100 come back to
 within 2^-126 and not exactly, and the sum of the parts of -0 is +0.
+
+- :func:`digits` does no arithmetic on the float at all. A lookup
+  SELECTS: its product's other operand is 0/1 and names a column once,
+  so the float's 32 bits can travel as integers, four int8 digits a
+  float, on the MXU at int8's rate (twice bfloat16's), and what the
+  products pick, put together by shifts and adds, is the float's bits
+  whatever they are: -0, an infinity, a NaN's payload, a subnormal. A
+  SUM of floats cannot travel so (``kernels.payload_blocks``' long
+  lookup since PR 53, ``kernels.sparse_blocks``' wide one since PR 58;
+  both accumulations keep their three parts).
+
 ``tests/test_kernels_split.py`` holds all of it.
 """
 
@@ -77,3 +89,29 @@ def disjoint_parts(v):
     rest = v - hi
     mid = top(rest)
     return hi, mid, rest - mid
+
+
+def digits(floats):
+    """A float32's 32 bits as four int8 digits, ``bits = d0 + 256 (d1 +
+    256 (d2 + 256 d3))`` in two's complement, each ``d`` in ``[-128,
+    128)``: the product of each with a 0/1 operand is exact on the MXU at
+    int8's rate, twice bfloat16's, and the sum above of what the products
+    pick is the float's bits again, whatever they are."""
+    import jax
+    import jax.numpy as jnp
+
+    rest = jax.lax.bitcast_convert_type(floats, jnp.int32)
+    out = []
+    for _ in range(4):
+        digit = ((rest + 128) & 255) - 128
+        out.append(digit.astype(jnp.int8))
+        rest = (rest - digit) >> 8
+    return out
+
+
+def joined_digits(four):
+    """:func:`digits`' inverse on four int32 planes, ``four[0]`` to
+    ``four[3]`` (what products of the digit planes with a 0/1 operand
+    picked; a list, or a kernel's ref read plane by plane): the floats'
+    bits, by three shifts and adds that wrap as two's complement does."""
+    return (four[0] + (four[1] << 8)) + ((four[2] << 16) + (four[3] << 24))

@@ -50,9 +50,9 @@ other way round in the accumulation.
 
 *The lookup stays the gather bit for bit.* A 0/1 operand is exact and a
 column is named once, so a product is the table's own value: a long
-block's float comes back as the four digits of its bits (:func:`digits`:
-a selection needs no arithmetic on the float, so its bits can travel as
-integers), a short
+block's float comes back as the four digits of its bits
+(``_split.digits``: a selection needs no arithmetic on the float, so its
+bits can travel as integers), a short
 block's as its three bfloat16 parts, added as they lie, ``(hi + mid) +
 lo``, for ``sparse_blocks``' reasons. *The accumulation is exact in
 float32*: the cell's gradient ``(mult x_s)(base - [0, xp_f])`` is made in
@@ -84,7 +84,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple
 
-from flinkml_tpu.kernels._split import rounded_parts
+from flinkml_tpu.kernels._split import digits, joined_digits, rounded_parts
 from flinkml_tpu.kernels.sparse_blocks import (
     LANES, PACKED, SUBLANES, TILE, _as_operand, _one_hot)
 
@@ -232,7 +232,7 @@ def block_digits(table, lengths: Sequence[int], first):
     128`` rows of every plane of ``table [payload, dim / 128, 128]`` from
     row ``first[i]`` on, zeros after them up to the longest block's whole
     chunks, a chunk's rows as :func:`_chunked` lays them, each float's 32
-    bits in four digits (:func:`digits`). Made once a step by XLA."""
+    bits in four digits (``_split.digits``). Made once a step by XLA."""
     import jax
     import jax.numpy as jnp
 
@@ -243,24 +243,6 @@ def block_digits(table, lengths: Sequence[int], first):
         for length, at in zip(lengths, first)])
     return jnp.stack([_chunked(digit, table.shape[0])
                       for digit in digits(blocks)], axis=2)
-
-
-def digits(floats):
-    """A float32's 32 bits as four int8 digits, ``bits = d0 + 256 (d1 +
-    256 (d2 + 256 d3))`` in two's complement, each ``d`` in ``[-128,
-    128)``: the product of each with a 0/1 operand is exact on the MXU at
-    int8's rate, twice bfloat16's, and the sum above of what the products
-    pick is the float's bits again, whatever they are."""
-    import jax
-    import jax.numpy as jnp
-
-    rest = jax.lax.bitcast_convert_type(floats, jnp.int32)
-    out = []
-    for _ in range(4):
-        digit = ((rest + 128) & 255) - 128
-        out.append(digit.astype(jnp.int8))
-        rest = (rest - digit) >> 8
-    return out
 
 
 def _cells_of(where_ref, starts_ref, cells_ref, vals_ref):
@@ -335,8 +317,7 @@ def _lookup_body(where_ref, starts_ref, chunks_ref, cells_ref, vals_ref,
     def floats(acc_ref):
         """The picked digits put together: the looked-up floats' bits."""
         return jax.lax.bitcast_convert_type(
-            (acc_ref[0] + (acc_ref[1] << 8)) + (
-                (acc_ref[2] << 16) + (acc_ref[3] << 24)), jnp.float32)
+            joined_digits(acc_ref), jnp.float32)
 
     # This tile's lanes of the rows' sums over the slots, which stay for
     # the whole grid: sum_s xp, and sum_s sum_f xp_f ** 2 over the factors

@@ -20,20 +20,31 @@ shuffle), and picking one of ``n`` looked-up rows is the same kind of
 mask and a sum down the sublanes. A block of ``length`` columns is ``r``
 product rows of ``c`` columns (:func:`shape`; ``local = c * hi + lo``).
 
-*The lookup stays the gather bit for bit.* A float32 is exactly three
-bfloat16 parts, a 0/1 operand is exact in bfloat16, the MXU sums in
-float32, and the three parts added as they lie (``(hi + mid) + lo``, or
-from the other end) pass through float32 values only. A *narrow* block
-(``r`` ≤ 32: up to 4,096 columns) has its parts along the contraction
-against the one-hot of ``hi`` repeated: ONE pass of ``[c, 128] @ [128,
-tile]``; up to 256 columns ``c`` is 8, so that ``lo`` picks among 8
-rows and not 128. A *wide* block contracts ``lo`` (128 lanes: a whole
-MXU tile, nothing padded) while the parts' rows stream through, ``[3
-rows, 128] @ [128, tile]``; the three results are added and ``hi``
-picks the row.
+*The lookup stays the gather bit for bit*, in two forms, by what a block's
+:func:`shape` is. A *narrow* block (``r`` ≤ 32: up to 4,096 columns) is
+three bfloat16 parts: a float32 is exactly three of them, a 0/1 operand
+is exact in bfloat16, the MXU sums in float32, and the three parts added
+as they lie (``(hi + mid) + lo``, or from the other end) pass through
+float32 values only. The parts lie along the contraction against the
+one-hot of ``hi`` repeated, so the contraction itself adds them: ONE
+pass of ``[c, 128] @ [128, tile]``; up to 256 columns ``c`` is 8, so
+that ``lo`` picks among 8 rows and not 128. A narrow slot is bound by
+its weight tile's load, not by passes, and place values would not
+survive being summed by the contraction, so it keeps its parts. A *wide*
+block contracts ``lo`` (128 lanes: a whole MXU tile, nothing padded)
+while its rows stream through, and there the passes are the cost: a
+lookup SELECTS and does no arithmetic on the float, so a wide block's
+operand is the four int8 digits of each float's BITS (``_split.digits``,
+``kernels.payload_blocks``' form since PR 53), ``[4 rows, 128] @ [128,
+tile]`` int8 by int8 into int32 at the MXU's int8 rate, twice
+bfloat16's; ``hi`` picks each digit plane's row and shifts and adds put
+the four picked rows together into the float's bits, whatever they are
+(PR 58: a 26,624-column slot 0.059 → 0.041 ms on a v5e, the three
+bfloat16 passes having stood at 87 % of the MXU's own time).
 
-*The accumulation* is the transpose, one form for both: the cells'
-contributions ``vals × mult`` in three bfloat16 parts (so the products
+*The accumulation* is the transpose, one form for both (it ADDS floats,
+and sums cannot travel as digits): the cells' contributions ``vals ×
+mult`` in three bfloat16 parts (so the products
 are exact) on their product rows, ``[3 rows, tile]``, contracted over
 the tile with the 0/1 mask of ``lo``, ``[c, tile]``; summed in float32
 into an output that stays in VMEM over the grid's one axis, which is
@@ -51,7 +62,7 @@ from __future__ import annotations
 import functools
 from typing import NamedTuple, Optional, Sequence, Tuple
 
-from flinkml_tpu.kernels._split import rounded_parts
+from flinkml_tpu.kernels._split import digits, joined_digits, rounded_parts
 
 #: Lanes of a vreg, and the columns of a block's row as the trainer
 #: holds it (``[length / 128, 128]``).
@@ -71,12 +82,13 @@ NARROW_ROWS = 32
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _STEP_BYTES = 24 * 1024 * 1024
 #: Bytes a grid step holds for each product row of a wide block and batch
-#: row: the three parts' product and their sum in float32, the picked
-#: rows, the masks.
-_LIVE_BYTES = 24
-#: Bytes that stay in fast memory for each block column: the lookup's
-#: three bfloat16 parts, or the accumulation's float32 sums, in two
-#: buffers.
+#: row: the lookup's four digit planes in int32, the row's mask and a
+#: plane picked from (the accumulation's three parts' product in float32,
+#: their sum and its masks are 24).
+_LIVE_BYTES = 28
+#: Bytes that stay in fast memory for each block column, in two buffers:
+#: a narrow block's three bfloat16 parts (a wide block's four int8
+#: digits, and either's float32 sums, are 8).
 _RESIDENT_BYTES = 12
 
 
@@ -101,7 +113,9 @@ def shape(length: int) -> Tuple[int, int, bool]:
     / c`` product rows of ``c`` columns in room for ``rows``. Narrow, at
     most :data:`NARROW_ROWS` rows: of 8 columns up to 256 columns, of 128
     up to 4,096. Wide: its rows of 128 as the trainer holds them, in
-    room for 128 or, longer, whole bfloat16 tiles."""
+    room for 128 or, longer, whole bfloat16 tiles (of 16: the
+    accumulation's parts; four digit planes of them are whole int8
+    tiles of 32)."""
     if length <= SUBLANES * NARROW_ROWS:
         return SUBLANES, NARROW_ROWS, True
     if length <= LANES * NARROW_ROWS:
@@ -128,8 +142,9 @@ def walk(groups: Sequence[Tuple[int, int]]) -> Tuple[Group, ...]:
 def tile_rows(batch: int, groups: Sequence[Group]) -> Optional[int]:
     """Batch rows a grid step: the most, of :data:`TILE` halved down to
     128, that divide the batch and keep what a step makes of the longest
-    block (its parts' product, their sum, the picked rows: about
-    :data:`_LIVE_BYTES` a product row and batch row) inside
+    block (its digits' product and a plane picked from, or its parts'
+    product and their sum: about :data:`_LIVE_BYTES` a product row and
+    batch row) inside
     :data:`_STEP_BYTES`; None where none does (the caller keeps XLA's
     products)."""
     rows = max(g.rows for g in groups)
@@ -150,8 +165,8 @@ def unsupported_reason(dtype, batch: int,
         return f"coefficients {dtype}: the parts are a float32's"
     columns = sum(length * slots for length, slots in groups)
     if _RESIDENT_BYTES * columns > _STEP_BYTES:
-        return (f"{columns} block columns: their parts, or their sums, "
-                "would not stay in fast memory")
+        return (f"{columns} block columns: their parts or digits, or their "
+                "sums, would not stay in fast memory")
     if tile_rows(batch, walk(groups)) is None:
         return f"a batch of {batch} rows a device is not whole tiles of {LANES}"
     return None
@@ -159,15 +174,16 @@ def unsupported_reason(dtype, batch: int,
 
 def block_parts(blocks, group: Group):
     """A group's blocks (each member's ``[slots, length / 128, 128]``
-    float32) as the lookup's left operand ``[slots, .., ..]`` bfloat16. A
-    narrow block's is ``[c, 128]``: column ``lo`` of product row ``hi``
+    float32) as the lookup's left operand ``[slots, .., ..]``. A narrow
+    block's is ``[c, 128]`` bfloat16: column ``lo`` of product row ``hi``
     at ``[lo, p * rows + hi]`` for part ``p``, zeros between and after
     (``[c, 3 rows] @ [3 rows, tile]``). A wide block's is its rows of
-    128 as they are, the three parts one under the other at multiples
-    of ``rows``: ``[3 rows, 128]``. Made once a step by XLA, a megabyte
-    in all (the KNN kernel's split: the roundings are
-    ``lax.reduce_precision`` there, because inside one fusion XLA keeps
-    a value it has just rounded at float32; PERF.md section 6, PR 35)."""
+    128 as they are, int8: the four digits of the floats' bits
+    (``_split.digits``) one under the other at multiples of ``rows``,
+    ``[4 rows, 128]``. Made once a step by XLA, under a megabyte in all
+    (the parts' roundings are ``lax.reduce_precision`` there, because
+    inside one fusion XLA keeps a value it has just rounded at float32;
+    PERF.md section 6, PR 35)."""
     import jax.numpy as jnp
 
     padded = []
@@ -180,10 +196,10 @@ def block_parts(blocks, group: Group):
         else:
             pad = ((0, 0), (0, group.rows - r), (0, 0))
         padded.append(jnp.pad(member, pad))
-    parts = rounded_parts(jnp.concatenate(padded), in_kernel=False)
+    padded = jnp.concatenate(padded)
     if not group.narrow:
-        return jnp.concatenate(parts, axis=1)
-    stacked = jnp.concatenate(parts, axis=2)
+        return jnp.concatenate(digits(padded), axis=1)
+    stacked = jnp.concatenate(rounded_parts(padded, in_kernel=False), axis=2)
     return jnp.pad(stacked, ((0, 0), (0, 0), (0, LANES - 3 * group.rows)))
 
 
@@ -237,6 +253,42 @@ def _split(local, group: Group):
     return local >> (group.c.bit_length() - 1), local & (group.c - 1)
 
 
+def _picked_narrow(parts, hi, lo, group: Group):
+    """A narrow block's looked-up floats, ``[8, tile]`` float32 (a lane's
+    one sublane not 0): the parts along the contraction, the one-hot of
+    ``hi`` under each, and ``lo`` picks among the ``c`` rows."""
+    import jax.numpy as jnp
+
+    tile = hi.shape[1]
+    rows_of = _as_operand(_one_hot(hi, group.rows))
+    rest = LANES - 3 * group.rows
+    stacked = [rows_of] * 3 + (
+        [jnp.zeros((rest, tile), jnp.bfloat16)] if rest else [])
+    looked = jnp.dot(parts, jnp.concatenate(stacked, axis=0),
+                     preferred_element_type=jnp.float32)
+    return _down_to_a_vreg(jnp.where(_one_hot(lo, group.c), looked, 0.0))
+
+
+def _picked_wide(four_digits, hi, lo, rows: int):
+    """A wide block's looked-up floats, ``[8, tile]`` float32 (a lane's
+    one sublane not 0): the lane is contracted (128: a whole MXU tile),
+    the digits' rows stream through it at int8's rate, ``hi`` picks each
+    plane's row and the four picked rows are put together, all in
+    integers. Picked BEFORE they are joined: each plane of the product
+    is selected from and summed down as it comes, and no ``[rows, tile]``
+    value but the product is kept (joined first, a 26,624-column slot
+    read 0.054 ms for 0.041 on a v5e; PERF.md section 6, PR 58)."""
+    import jax
+    import jax.numpy as jnp
+
+    lanes_of = jnp.where(_one_hot(lo, LANES), 1, 0).astype(jnp.int8)
+    four = jnp.dot(four_digits, lanes_of, preferred_element_type=jnp.int32)
+    rows_of = _one_hot(hi, rows)
+    return jax.lax.bitcast_convert_type(joined_digits([
+        _down_to_a_vreg(jnp.where(rows_of, four[k * rows:(k + 1) * rows], 0))
+        for k in range(4)]), jnp.float32)
+
+
 def _lookup_body(where_ref, starts_ref, cells_ref, vals_ref, *refs, groups):
     import jax
     import jax.numpy as jnp
@@ -247,28 +299,14 @@ def _lookup_body(where_ref, starts_ref, cells_ref, vals_ref, *refs, groups):
     for group, block_ref in zip(groups, block_refs):
 
         def one_slot(i, acc, group=group, block_ref=block_ref):
-            rows = group.rows
             local, vals = _cells_of(group.first + i, where_ref, starts_ref,
                                     cells_ref, vals_ref)
             hi, lo = _split(local, group)
             if group.narrow:
-                # The parts along the contraction, the one-hot under each.
-                rows_of = _as_operand(_one_hot(hi, rows))
-                rest = LANES - 3 * rows
-                stacked = [rows_of] * 3 + (
-                    [jnp.zeros((rest, tile), jnp.bfloat16)] if rest else [])
-                looked = jnp.dot(block_ref[i], jnp.concatenate(stacked, axis=0),
-                                 preferred_element_type=jnp.float32)
-                picked = jnp.where(_one_hot(lo, group.c), looked, 0.0)
+                picked = _picked_narrow(block_ref[i], hi, lo, group)
             else:
-                # The lane is contracted (128: a whole MXU tile), the
-                # parts' rows stream through it, and the row is picked.
-                three = jnp.dot(block_ref[i], _as_operand(_one_hot(lo, LANES)),
-                                preferred_element_type=jnp.float32)
-                picked = jnp.where(_one_hot(hi, rows),
-                                   _sum_of_parts(three, rows), 0.0)
-            return acc + _down_to_a_vreg(picked) * jnp.broadcast_to(
-                vals, (SUBLANES, tile))
+                picked = _picked_wide(block_ref[i], hi, lo, group.rows)
+            return acc + picked * jnp.broadcast_to(vals, (SUBLANES, tile))
 
         acc = jax.lax.fori_loop(0, group.slots, one_slot, acc)
     out_ref[...] = jnp.sum(acc, axis=0, keepdims=True)

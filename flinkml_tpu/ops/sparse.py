@@ -20,8 +20,12 @@ On a TPU the linear trainers' blocked step runs those two products as
 through XLA a slot's product ``[batch, 128]`` goes to HBM and comes back
 for one float of each 128 to be kept; the kernels make it, select from
 it and drop it in fast memory, a tile of up to 4,096 batch rows at a
-time, the blocks' bfloat16 parts (6 bytes a block column) resident for
-the whole call. Where they apply is read off the step
+time, the blocks resident for the whole call in the form a lookup's
+product takes them: a block of up to 4,096 columns as three bfloat16
+parts (6 bytes a block column), a longer one as the four int8 digits of
+its floats' bits (4 bytes; a lookup selects, so the bits travel as
+integers at the MXU's int8 rate, PR 58), the looked-up float the
+block's own bit for bit either way. Where they apply is read off the step
 (``models._linear_sgd._blocks_in_fast_memory``: a TPU, float32, a
 device's batch in whole tiles of 128, at most two million block
 columns). :func:`block_lookup` and :func:`block_accumulate` are their

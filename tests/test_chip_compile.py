@@ -265,18 +265,33 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
     MB, and the program's temporaries stay under what five of them would
     take. Compiled UNDER x64, as the suite runs: the kernels
     are traced in 32-bit mode whatever the flag says, and the traced
-    program is read for 64-bit blocks first (Mosaic aborts on one)."""
+    program is read for 64-bit blocks first (Mosaic aborts on one).
+    Since PR 58 the lookup's two wide bodies multiply int8 digits of the
+    floats' bits into int32; four int32 planes of a 26,624-column block
+    still leave the tile at 4,096 batch rows at 65,536 and at 16,384 rows
+    a device (at 512 a long slot read 0.59 ms for 0.49), which is
+    asserted here and not assumed."""
     import numpy as np
     from jax.experimental.layout import Format, Layout
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from flinkml_tpu.kernels import _mosaic
+    from flinkml_tpu.kernels import _mosaic, sparse_blocks
     from flinkml_tpu.models import _linear_sgd
+    from flinkml_tpu.ops import sparse
 
     monkeypatch.setattr(_mosaic, "interpret_mode", lambda: False)
     width, dim, batch = 39, 1_000_000, 65_536
     assert _linear_sgd._blocks_in_fast_memory(jnp.float32, batch // chips,
                                               FM_CRITEO_PLAN)
+    groups = sparse_blocks.walk([
+        (length, len(slots)) for length, slots in
+        sparse.block_groups(FM_CRITEO_PLAN, batch // chips)])
+    # four traced bodies for eleven lengths: narrow 8, narrow 128, wide
+    # 128, wide 208
+    assert [(g.c, g.rows, g.narrow, g.slots) for g in groups] == [
+        (8, 32, True, 21), (128, 32, True, 6), (128, 128, False, 4),
+        (128, 208, False, 8)]
+    assert sparse_blocks.tile_rows(batch // chips, groups) == sparse_blocks.TILE == 4096
     mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
     by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     # As a v5e holds them (PR 30): an ELL table lies with its ROWS along
@@ -300,6 +315,9 @@ def test_lr_sparse_loop_at_the_cells_size_holds_the_block_kernels(
                 on((), f32), on((), f32), on((), f32), on((), f32), np.int32(steps))
             kernels = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)]
             assert len(kernels) == 2                    # the lookup, the accumulation
+            # a product a body: the lookup's wide two in int8 digits
+            assert [_product_operands(k) for k in kernels] == [
+                ["bfloat16"] * 2 + ["int8"] * 2, ["bfloat16"] * 4]
             wide = [str(v.aval) for eqn in kernels
                     for v in eqn.params["jaxpr"].invars + eqn.params["jaxpr"].outvars
                     if re.search(r"[fiu]64", str(v.aval))]
@@ -422,8 +440,10 @@ def test_sparse_block_kernels_take_criteo_laid_out_field_by_field(
         one_chip, no_compile_cache):
     """The two kernels alone at another ladder of block lengths: Criteo
     field by field (PR 29: five fields on blocks of up to 194,560
-    columns, 1,520 rows of 128), whose parts stay in fast memory while a
-    long block's product is cut in tiles of 512 batch rows."""
+    columns, 1,520 rows of 128), whose digits (the lookup's wide body,
+    int8 into int32 since PR 58: 6,080 rows of them a block) and sums
+    stay in fast memory while a long block's product is cut in tiles of
+    512 batch rows."""
     from flinkml_tpu.kernels import sparse_blocks
 
     groups, width, batch = [(8_192, 32), (59_392, 2), (194_560, 5)], 39, 65_536
@@ -437,9 +457,13 @@ def test_sparse_block_kernels_take_criteo_laid_out_field_by_field(
     cells = (on((width, batch), jnp.int32), on((width, batch), jnp.float32),
              on((width,), jnp.int32))
     with jax.enable_x64(True):
-        lookup = jax.jit(lambda b, c, v, at: sparse_blocks.lookup_dot(
-            groups, range(width), b, c, v, at, interpret=False)).lower(
-                blocks, *cells).compile()
+        traced = jax.jit(lambda b, c, v, at: sparse_blocks.lookup_dot(
+            groups, range(width), b, c, v, at, interpret=False)).trace(
+                blocks, *cells)
+        # three wide bodies (rooms of 128, 464 and 1,520 rows), no narrow one
+        (kernel,) = _pallas_calls(traced.jaxpr.jaxpr)
+        assert _product_operands(kernel) == ["int8"] * 3
+        lookup = traced.lower().compile()
         accumulate = jax.jit(lambda c, v, at, m: sparse_blocks.accumulate(
             groups, range(width), c, v, at, m, interpret=False)).lower(
                 *cells, on((batch,), jnp.float32)).compile()
@@ -517,6 +541,19 @@ def _pallas_calls(jaxpr):
             yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
             yield from _pallas_calls(sub)
+
+
+def _product_operands(kernel):
+    """The operand dtypes of every product a kernel (a ``pallas_call``)
+    traces, loops' bodies included, sorted."""
+    def dots(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield str(eqn.invars[0].aval.dtype)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from dots(sub)
+
+    return sorted(dots(kernel.params["jaxpr"]))
 
 
 @pytest.mark.parametrize("step", ["kernel", "xla"])

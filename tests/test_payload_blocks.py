@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flinkml_tpu.kernels import payload_blocks
+from flinkml_tpu.kernels import _split, payload_blocks
 
 #: Block lengths: 128 columns and the other short rung; one chunk of 16
 #: rows exactly, less than one, two, three, and 104 rows (``fm-criteo``'s
@@ -231,7 +231,7 @@ def test_the_products_operands_are_exact_and_the_digits_are_the_floats_bits():
     table, cells, vals, starts, where, _ = _step(17, lengths, 7)
     odd = np.asarray([-0.0, 1e-42, np.inf, -np.inf, np.nan, 3.4e38], np.float32)
     table[3, 40, :6] = odd
-    four = [np.asarray(d).astype(np.int64) for d in payload_blocks.digits(
+    four = [np.asarray(d).astype(np.int64) for d in _split.digits(
         jnp.asarray(table))]
     assert all(d.min() >= -128 and d.max() < 128 for d in four)
     bits = (four[0] + (four[1] << 8) + (four[2] << 16) + (four[3] << 24)

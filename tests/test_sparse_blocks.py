@@ -124,46 +124,61 @@ def test_the_products_ask_for_the_highest_precision():
         assert "precision = [HIGHEST, HIGHEST]" in text
 
 
-@pytest.mark.parametrize("length", [256, 4096, 26624])
+@pytest.mark.parametrize("length", [256, 4096, 13312, 26624])
 def test_the_kernels_products_are_bfloat16_parts_that_sum_to_the_float(length):
-    """What stands in for ``HIGHEST`` in the kernels: every operand of
-    every product is bfloat16 (one pass each on the MXU), the blocks'
-    three parts sum back to the float32 bit for bit in either order, and
-    the products accumulate in float32."""
+    """What stands in for ``HIGHEST`` in the kernels. A narrow block's
+    lookup and every accumulation: every operand of every product is
+    bfloat16 (one pass each on the MXU), the blocks' three parts sum back
+    to the float32 bit for bit in either order, and the products
+    accumulate in float32. A wide block's lookup (PR 58; 13,312 columns
+    in room for 128 rows, 26,624 in 208): a lookup selects, so the
+    operand is the four int8 digits of the floats' BITS, the one product
+    int8 by int8 into int32, and the digits put together are the blocks'
+    bits whatever they are; its accumulation adds floats and keeps the
+    parts."""
     blocks, local, vals = _cells(length)
     (group,) = sparse_blocks.walk([(length, 3)])
     r = length // group.c
-    operand = np.asarray(sparse_blocks.block_parts(
-        [jnp.asarray(blocks).reshape(3, -1, 128)], group).astype(jnp.float32))
+    operand = sparse_blocks.block_parts(
+        [jnp.asarray(blocks).reshape(3, -1, 128)], group)
     if group.narrow:
+        assert operand.dtype == jnp.bfloat16
+        operand = np.asarray(operand.astype(jnp.float32))
         parts = [operand[:, :, p * group.rows:p * group.rows + r]
                  for p in range(3)]
         assert not operand[:, :, 3 * group.rows:].any()
         want = blocks.reshape(3, r, group.c).transpose(0, 2, 1)
+        # Summed as they lie along the contraction, from either end (the
+        # first and the last alone, added first, need not be a float32).
+        hi, mid, lo = parts
+        assert ((hi + mid) + lo).tobytes() == want.tobytes()
+        assert (hi + (mid + lo)).tobytes() == want.tobytes()
+        lookup_operands = ["bfloat16", "bfloat16", jnp.float32]
     else:
-        parts = [operand[:, p * group.rows:p * group.rows + r]
-                 for p in range(3)]
-        want = blocks.reshape(3, r, 128)
-    # Summed as they lie along the contraction, from either end (the
-    # first and the last alone, added first, need not be a float32).
-    hi, mid, lo = parts
-    assert ((hi + mid) + lo).tobytes() == want.tobytes()
-    assert (hi + (mid + lo)).tobytes() == want.tobytes()
+        assert operand.dtype == jnp.int8
+        assert operand.shape == (3, 4 * group.rows, 128)
+        operand = np.asarray(operand).astype(np.int32)
+        four = [operand[:, k * group.rows:k * group.rows + r] for k in range(4)]
+        for k in range(4):      # the room up to whole tiles is zeros
+            assert not operand[:, k * group.rows + r:(k + 1) * group.rows].any()
+        bits = (four[0] + (four[1] << 8)) + ((four[2] << 16) + (four[3] << 24))
+        assert bits.dtype == np.int32
+        assert bits.tobytes() == blocks.reshape(3, r, 128).tobytes()
+        lookup_operands = ["int8", "int8", jnp.int32]
     args = (jnp.asarray(local), jnp.asarray(vals), jnp.zeros(3, jnp.int32))
     programs = (
-        jax.make_jaxpr(lambda l, v, at: sparse_blocks.lookup_dot(
+        (jax.make_jaxpr(lambda l, v, at: sparse_blocks.lookup_dot(
             [(length, 3)], range(3), [jnp.asarray(blocks).reshape(3, -1, 128)],
-            l, v, at, interpret=True))(*args),
-        jax.make_jaxpr(lambda l, v, at: sparse_blocks.accumulate(
-            [(length, 3)], range(3), l, v, at, v[0], interpret=True))(*args))
-    for program in programs:
-        dots = [eqn for call in _pallas_calls(program.jaxpr)
-                for eqn in _flat(call.params["jaxpr"])
-                if eqn.primitive.name == "dot_general"]
-        assert dots
-        for eqn in dots:
-            assert [str(v.aval.dtype) for v in eqn.invars] == ["bfloat16"] * 2
-            assert eqn.params["preferred_element_type"] == jnp.float32
+            l, v, at, interpret=True))(*args), lookup_operands),
+        (jax.make_jaxpr(lambda l, v, at: sparse_blocks.accumulate(
+            [(length, 3)], range(3), l, v, at, v[0], interpret=True))(*args),
+         ["bfloat16", "bfloat16", jnp.float32]))
+    for program, (*operands, into) in programs:
+        (dot,) = [eqn for call in _pallas_calls(program.jaxpr)
+                  for eqn in _flat(call.params["jaxpr"])
+                  if eqn.primitive.name == "dot_general"]
+        assert [str(v.aval.dtype) for v in dot.invars] == operands
+        assert dot.params["preferred_element_type"] == into
 
 
 def _flat(jaxpr):
